@@ -86,10 +86,6 @@ sys.path.insert(
 # Chaos runs are hardware-independent; default to CPU unless the
 # caller pinned a platform (the conftest discipline).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    import jax as _jax
-
-    _jax.config.update("jax_platforms", "cpu")
 
 import numpy as np  # noqa: E402
 

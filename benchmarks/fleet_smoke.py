@@ -47,10 +47,6 @@ sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    import jax as _jax
-
-    _jax.config.update("jax_platforms", "cpu")
 
 import numpy as np  # noqa: E402
 

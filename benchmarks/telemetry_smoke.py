@@ -43,10 +43,6 @@ os.environ["SKYLARK_TELEMETRY_DIR"] = _TDIR  # before libskylark import
 # Hardware-independent; default to CPU unless the caller pinned a
 # platform (the conftest discipline).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    import jax as _jax
-
-    _jax.config.update("jax_platforms", "cpu")
 
 import numpy as np  # noqa: E402
 

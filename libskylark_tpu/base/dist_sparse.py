@@ -35,11 +35,10 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from libskylark_tpu.base import errors
-from libskylark_tpu.base.compat import shard_map
 from libskylark_tpu.base.sparse import SparseMatrix
 
 

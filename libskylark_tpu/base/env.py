@@ -828,8 +828,10 @@ HASH_KERNEL = declare(
 PALLAS_VMEM_BUDGET = declare(
     "SKYLARK_PALLAS_VMEM_BUDGET", default=16 * 1024 * 1024,
     parser=parse_int, kind="bytes",
-    doc="Per-core VMEM budget the Pallas kernels plan against "
-        "(~16 MiB on current generations; no runtime query API).")
+    doc="Per-core VMEM budget the Pallas kernels' tile plans target "
+        "(default: Mosaic's 16 MiB scoped limit on a v5e; no "
+        "vmem_limit_bytes is passed, so raising this past the scope "
+        "gets a Mosaic rejection, which raises).")
 
 PALLAS_SCRATCH_CAP = declare(
     "SKYLARK_PALLAS_SCRATCH_CAP", default=8 * 1024 * 1024,

@@ -1,4 +1,4 @@
-"""Counter-based lazy random streams over jax.random.
+"""Counter-based lazy random streams in explicit Threefry ops.
 
 TPU-native analog of the reference's ``random_samples_array_t``
 (ref: base/randgen.hpp:17-193): a *virtual* array of i.i.d. samples in which
@@ -8,11 +8,18 @@ application layout-independent and exactly testable ("sharded apply ==
 single-device apply with the same seed", ref: tests/unit/DenseSketchApplyElementalTest.cpp:44-101).
 
 Implementation: the stream is generated in fixed-size chunks. Chunk ``c`` of a
-stream with allocation key ``k`` is ``sampler(fold_in(fold_in(k, c>>31), c&M), (CHUNK,))``
-— so any contiguous slice can be materialized by generating only its covering
-chunks, on whichever device needs it. The chunk size is an internal constant:
-changing it changes the stream, so it is part of the format (serialized
-streams record it).
+stream with allocation key ``k`` has chunk key ``fold_in(fold_in(k, c>>31), c&M)``;
+its ``CHUNK`` uint32 draws are :func:`threefry.chunk_bits` of that key, and a
+distribution is a pure map from draws to samples (``from_bits``) — so any
+contiguous slice can be materialized by generating only its covering chunks,
+on whichever device needs it. Every step is written in the explicit integer
+ops of base/threefry.py, never through ``jax.random``'s samplers: the bits are
+a function of this file, not of the installed JAX, and the Pallas kernels
+replay the same ops in VMEM. The one exception is :class:`Gamma` (rejection
+sampling has no fixed draw count); tests/test_stream_golden.py pins it with
+the rest so a JAX upgrade that moves it fails loudly. The chunk size is an
+internal constant: changing it — or anything above — changes the stream, so
+it is part of the format (``SketchTransform.STREAM_FORMAT``).
 
 Distributions mirror the reference's set (ref: utility/distributions.hpp):
 normal, uniform real/int, Cauchy, Rademacher, standard Levy (= 1/Gamma(1/2, 2),
@@ -22,12 +29,15 @@ ref: utility/distributions.hpp:17-34), exponential.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 import jax.random as jr
 import numpy as np
+
+from libskylark_tpu.base import threefry as tf
 
 # Elements per generation block. Part of the stream format: changing it
 # changes every stream's values.
@@ -36,13 +46,20 @@ CHUNK = 4096
 _MASK31 = (1 << 31) - 1
 
 
+@jax.jit
+def _chunk_key_data(kd: jax.Array, hi, lo) -> jax.Array:
+    return tf.fold_in(tf.fold_in(kd, hi), lo)
+
+
 def chunk_key(key: jax.Array, cid) -> jax.Array:
     """Key for chunk ``cid`` (host int of any size, or traced int32 < 2^31)."""
+    kd = jr.key_data(key)
     if isinstance(cid, (int, np.integer)):
         hi, lo = int(cid) >> 31, int(cid) & _MASK31
-        return jr.fold_in(jr.fold_in(key, hi), lo)
-    # Traced chunk ids are restricted to < 2^31 (hi word = 0).
-    return jr.fold_in(jr.fold_in(key, 0), cid)
+    else:
+        # Traced chunk ids are restricted to < 2^31 (hi word = 0).
+        hi, lo = 0, cid
+    return jr.wrap_key_data(_chunk_key_data(kd, hi, lo))
 
 
 # ---------------------------------------------------------------------------
@@ -51,18 +68,16 @@ def chunk_key(key: jax.Array, cid) -> jax.Array:
 
 
 class Distribution:
-    """A named, serializable sampler: maps (key, shape, dtype) -> samples."""
+    """A named, serializable sampler: a pure map from uint32 draws to
+    samples. ``draws`` independent 32-bit words feed each sample."""
 
     name: str = "distribution"
+    draws: int = 1
 
-    def sample(self, key: jax.Array, shape, dtype=jnp.float32) -> jax.Array:
-        raise NotImplementedError
-
-    def from_bits(self, bits: jax.Array) -> jax.Array:
-        """Map uint32 bits -> f32 samples (the dense-block fast path; see
-        :func:`dense_block`, which detects support structurally — a
-        distribution without an override keeps the legacy sample() block
-        definition and this method is never called)."""
+    def from_bits(self, *bits: jax.Array) -> jax.Array:
+        """Map ``draws`` uint32 arrays -> samples (f32, or int32 for
+        integer distributions). Shared by the chunk streams, the dense
+        blocks and the kernels' in-VMEM replay."""
         raise NotImplementedError(f"{self.name} has no bit transform")
 
     def to_dict(self) -> dict[str, Any]:
@@ -83,12 +98,7 @@ class Normal(Distribution):
     std: float = 1.0
     name = "normal"
 
-    def sample(self, key, shape, dtype=jnp.float32):
-        return self.mean + self.std * jr.normal(key, shape, dtype)
-
     def from_bits(self, bits):
-        from libskylark_tpu.base import threefry as tf
-
         return self.mean + self.std * tf.bits_to_normal(bits)
 
 
@@ -98,26 +108,25 @@ class Uniform(Distribution):
     high: float = 1.0
     name = "uniform"
 
-    def sample(self, key, shape, dtype=jnp.float32):
-        return jr.uniform(key, shape, dtype, minval=self.low, maxval=self.high)
-
     def from_bits(self, bits):
-        from libskylark_tpu.base import threefry as tf
-
         return tf.bits_to_uniform(bits, self.low, self.high)
 
 
 @dataclasses.dataclass(frozen=True)
 class UniformInt(Distribution):
     """Uniform integers in [low, high] inclusive (boost convention,
-    ref: utility/distributions.hpp:84-100)."""
+    ref: utility/distributions.hpp:84-100). Two draws per sample, so
+    the modulo bias stays negligible at any span
+    (:func:`threefry.bits_to_randint`)."""
 
     low: int = 0
     high: int = 1
     name = "uniform_int"
+    draws = 2
 
-    def sample(self, key, shape, dtype=jnp.int32):
-        return jr.randint(key, shape, self.low, self.high + 1, dtype)
+    def from_bits(self, hi, lo):
+        off = tf.bits_to_randint(hi, lo, self.high - self.low + 1)
+        return self.low + off.astype(jnp.int32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -126,12 +135,7 @@ class Cauchy(Distribution):
     scale: float = 1.0
     name = "cauchy"
 
-    def sample(self, key, shape, dtype=jnp.float32):
-        return self.loc + self.scale * jr.cauchy(key, shape, dtype)
-
     def from_bits(self, bits):
-        from libskylark_tpu.base import threefry as tf
-
         return self.loc + self.scale * tf.bits_to_cauchy(bits)
 
 
@@ -139,12 +143,7 @@ class Cauchy(Distribution):
 class Rademacher(Distribution):
     name = "rademacher"
 
-    def sample(self, key, shape, dtype=jnp.float32):
-        return jr.rademacher(key, shape).astype(dtype)
-
     def from_bits(self, bits):
-        from libskylark_tpu.base import threefry as tf
-
         return tf.bits_to_rademacher(bits)
 
 
@@ -155,9 +154,9 @@ class StandardLevy(Distribution):
 
     name = "standard_levy"
 
-    def sample(self, key, shape, dtype=jnp.float32):
-        z = jr.normal(key, shape, dtype)
-        return 1.0 / jnp.maximum(z * z, jnp.finfo(dtype).tiny)
+    def from_bits(self, bits):
+        z = tf.bits_to_normal(bits)
+        return 1.0 / jnp.maximum(z * z, jnp.finfo(jnp.float32).tiny)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,15 +164,19 @@ class Exponential(Distribution):
     rate: float = 1.0
     name = "exponential"
 
-    def sample(self, key, shape, dtype=jnp.float32):
-        return jr.exponential(key, shape, dtype) / self.rate
+    def from_bits(self, bits):
+        return tf.bits_to_exponential(bits) / self.rate
 
 
 @dataclasses.dataclass(frozen=True)
 class Gamma(Distribution):
+    """Rejection-sampled, so not a fixed map from draws: the one
+    distribution that goes through ``jax.random`` (module docstring)."""
+
     shape_param: float = 1.0
     scale: float = 1.0
     name = "gamma"
+    draws = 0
 
     def sample(self, key, shape, dtype=jnp.float32):
         return self.scale * jr.gamma(key, self.shape_param, shape, dtype)
@@ -199,6 +202,32 @@ _DIST_REGISTRY = {
 # ---------------------------------------------------------------------------
 
 
+def _chunk_samples(kd: jax.Array, dist: Distribution, chunk: int,
+                   dtype) -> jax.Array:
+    """The ``chunk`` samples of the chunk whose key data is ``kd``.
+    One-draw distributions read the chunk key's own draws; a
+    distribution needing ``d`` draws reads those of ``fold_in(kd, i)``,
+    i < d."""
+    if not dist.draws:
+        return dist.sample(jr.wrap_key_data(kd), (chunk,), dtype)
+    if dist.draws == 1:
+        words = (tf.chunk_bits(kd, chunk),)
+    else:
+        words = tuple(tf.chunk_bits(tf.fold_in(kd, i), chunk)
+                      for i in range(dist.draws))
+    return dist.from_bits(*words).astype(dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("dist", "chunk", "dtype"))
+def _chunk_range(kd, hi, lo, *, dist: Distribution, chunk: int, dtype):
+    """The chunks with id words ``(hi[i], lo[i])``, flattened. Jitted
+    (distributions are frozen dataclasses, hence static): the cipher is
+    ~100 integer ops, far too many to dispatch one by one per slice."""
+    return jax.vmap(
+        lambda h, l: _chunk_samples(_chunk_key_data(kd, h, l), dist, chunk,
+                                    dtype))(hi, lo).reshape(-1)
+
+
 def stream_slice(
     key: jax.Array,
     dist: Distribution,
@@ -214,16 +243,16 @@ def stream_slice(
     (ref: base/randgen.hpp:98-115): the result does not depend on what other
     slices anyone else materializes.
     """
+    dtype = jnp.dtype(dtype)
     if stop <= start:
         return jnp.zeros((0,), dtype)
     c0 = start // chunk
     c1 = -(-stop // chunk)
     cids = np.arange(c0, c1, dtype=np.int64)
-    hi = (cids >> 31).astype(np.int32)
-    lo = (cids & _MASK31).astype(np.int32)
-    keys = jax.vmap(lambda h, l: jr.fold_in(jr.fold_in(key, h), l))(hi, lo)
-    vals = jax.vmap(lambda k: dist.sample(k, (chunk,), dtype))(keys)
-    flat = vals.reshape(-1)
+    flat = _chunk_range(
+        jr.key_data(key), (cids >> 31).astype(np.uint32),
+        (cids & _MASK31).astype(np.uint32), dist=dist, chunk=chunk,
+        dtype=dtype)
     return flat[start - c0 * chunk : stop - c0 * chunk]
 
 
@@ -240,10 +269,10 @@ def stream_chunks(
     ``first_cid`` may be a traced int32 (for use inside lax loops over
     panels); ``n_chunks`` must be static. Returns shape (n_chunks * chunk,).
     """
-    cids = first_cid + jnp.arange(n_chunks, dtype=jnp.int32)
-    keys = jax.vmap(lambda c: chunk_key(key, c))(cids)
-    vals = jax.vmap(lambda k: dist.sample(k, (chunk,), dtype))(keys)
-    return vals.reshape(-1)
+    lo = (first_cid + jnp.arange(n_chunks, dtype=jnp.int32)).astype(
+        jnp.uint32)
+    return _chunk_range(jr.key_data(key), jnp.zeros_like(lo), lo, dist=dist,
+                        chunk=chunk, dtype=jnp.dtype(dtype))
 
 
 def dense_block(
@@ -261,24 +290,19 @@ def dense_block(
     trick (ref: sketch/dense_transform_data.hpp:79-152). ``block_id`` may be
     traced.
 
-    Block format (when the distribution has a bit transform): with
-    (k0, k1) = key_data(chunk_key(key, b)), ``half = block_cols // 2`` and
-    counter c[r, j] = r·half + j, Threefry-2x32-20 of (c, c + rows·half)
-    yields two uint32 lanes; the block is
-    ``[from_bits(lane0) | from_bits(lane1)]`` columns. Written in explicit
-    integer ops (base/threefry.py) so the Pallas fused-apply kernel
+    Block format: with (k0, k1) = key_data(chunk_key(key, b)),
+    ``half = block_cols // 2`` and counter c[r, j] = r·half + j,
+    Threefry-2x32-20 of (c, c + rows·half) yields two uint32 lanes; the
+    block is ``[from_bits(lane0) | from_bits(lane1)]`` columns. Written in
+    explicit integer ops (base/threefry.py) so the Pallas fused-apply kernel
     (sketch/pallas_dense.py) can reproduce the exact bits in-kernel.
-    Distributions without a bit transform keep the legacy
-    ``dist.sample(chunk_key(key, b), ...)`` definition.
+    One-draw distributions and even ``block_cols`` only.
     """
-    bkey = chunk_key(key, block_id)
-    has_bit_transform = type(dist).from_bits is not Distribution.from_bits
-    if not has_bit_transform or block_cols % 2:
-        return dist.sample(bkey, (rows, block_cols), dtype)
-
-    from libskylark_tpu.base import threefry as tf
-
-    kd = jr.key_data(bkey).astype(jnp.uint32)
+    if dist.draws != 1 or block_cols % 2:
+        raise ValueError(
+            f"dense_block needs a one-draw distribution and an even block "
+            f"width, got {dist.name} × {block_cols}")
+    kd = jr.key_data(chunk_key(key, block_id))
     half = block_cols // 2
     c = (
         jnp.arange(rows, dtype=jnp.uint32)[:, None] * jnp.uint32(half)

@@ -1,14 +1,16 @@
 """Threefry-2x32-20 counter PRNG, written in plain jnp integer ops.
 
-This is the bit-level definition of the framework's *dense block* stream
-format (ref: base/randgen.hpp Random123 Threefry usage:98-115). It exists as
+This is the bit-level definition of the framework's stream formats — the
+*dense block* panels and the *chunk* streams of base/randgen.py
+(ref: base/randgen.hpp Random123 Threefry usage:98-115). It exists as
 explicit ops — rather than calling ``jax.random`` — so the exact same
 sequence of 32-bit adds/xors/rotations can run in three places with
-identical bits:
+identical bits, on any JAX release:
 
-1. the XLA path (:func:`randgen.dense_block`),
-2. the Pallas TPU kernel that generates sketch panels inside a fused
-   matmul (sketch/pallas_dense.py),
+1. the XLA path (:func:`randgen.dense_block`, :func:`randgen.stream_slice`),
+2. the Pallas TPU kernels that regenerate the streams in VMEM
+   (sketch/pallas_dense.py, pallas_hash.py, pallas_fwht.py,
+   pallas_sparse.py),
 3. any host-side replay (integer ops are bitwise identical on every
    backend).
 
@@ -22,6 +24,7 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
 # rotation schedule for Threefry-2x32 (Salmon et al. Table 2)
 _ROTATIONS = (13, 15, 26, 6, 17, 29, 16, 24)
@@ -59,6 +62,28 @@ def threefry2x32(k0, k1, c0: jnp.ndarray, c1: jnp.ndarray
         x0 = x0 + keys[(group + 1) % 3]
         x1 = x1 + keys[(group + 2) % 3] + (group + 1)
     return x0, x1
+
+
+def fold_in(kd: jnp.ndarray, data) -> jnp.ndarray:
+    """Key data derived from ``kd`` ((2,) uint32) and one 32-bit word:
+    the cipher of counter (0, data) under ``kd``. Bit-equal to
+    ``jax.random.fold_in`` on threefry keys (tests/test_stream_golden.py), but
+    spelled out so no stream key can move with a JAX release."""
+    d = jnp.asarray(data).astype(jnp.uint32)
+    x0, x1 = threefry2x32(kd[0], kd[1], jnp.zeros_like(d), d)
+    return jnp.stack([x0, x1], axis=-1)
+
+
+def chunk_bits(kd: jnp.ndarray, n: int) -> jnp.ndarray:
+    """The ``n`` (even) uint32 draws of one stream chunk under chunk key
+    ``kd``: counter pairs (j, j + n/2), position j on the cipher's first
+    output lane and position j + n/2 on the second — two draws per
+    cipher call. The kernels replay exactly this layout
+    (``pallas_hash._chunk_bits``)."""
+    half = n // 2
+    c = jnp.arange(half, dtype=jnp.uint32)
+    x0, x1 = threefry2x32(kd[0], kd[1], c, c + half)
+    return jnp.concatenate([x0, x1])
 
 
 def bits_to_unit(bits: jnp.ndarray) -> jnp.ndarray:
@@ -100,3 +125,26 @@ def bits_to_rademacher(bits: jnp.ndarray) -> jnp.ndarray:
 
 def bits_to_uniform(bits: jnp.ndarray, low: float, high: float) -> jnp.ndarray:
     return low + bits_to_unit(bits) * (high - low)
+
+
+def bits_to_exponential(bits: jnp.ndarray) -> jnp.ndarray:
+    """uint32 bits → f32 standard exponential: −log(1−u), u ∈ [0, 1)."""
+    return -jnp.log1p(-bits_to_unit(bits))
+
+
+def randint_multiplier(span: int) -> int:
+    """2³² mod ``span`` as (2¹⁶ mod span)² mod span — static Python math.
+    Zero exactly when 2¹⁶ % span == 0 (every pow2 span ≤ 2¹⁶), where the
+    high draw of :func:`bits_to_randint` cancels and a kernel can skip
+    its cipher."""
+    m = (1 << 16) % span
+    return (m * m) % span
+
+
+def bits_to_randint(hi: jnp.ndarray, lo: jnp.ndarray, span: int) -> jnp.ndarray:
+    """Two uint32 draws → uint32 in [0, span): the 64-bit word hi·2³² + lo
+    reduced mod ``span`` in wrapping uint32 arithmetic, so the modulo
+    bias is ~span/2⁶⁴ instead of span/2³²."""
+    sp = np.uint32(span)
+    mult = np.uint32(randint_multiplier(span))
+    return ((hi % sp) * mult + lo % sp) % sp

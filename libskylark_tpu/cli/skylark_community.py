@@ -54,9 +54,6 @@ def _run_query(G, seeds, args):
 
 
 def main(argv=None) -> int:
-    from libskylark_tpu.cli import honor_platform_env
-
-    honor_platform_env()
     args = build_parser().parse_args(argv)
     from libskylark_tpu.ml.graph import Graph
 

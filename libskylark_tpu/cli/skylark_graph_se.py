@@ -32,9 +32,6 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
-    from libskylark_tpu.cli import honor_platform_env
-
-    honor_platform_env()
     args = build_parser().parse_args(argv)
 
     from libskylark_tpu.base.context import Context

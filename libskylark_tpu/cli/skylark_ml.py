@@ -229,9 +229,6 @@ def _test(args) -> int:
 
 
 def main(argv=None) -> int:
-    from libskylark_tpu.cli import honor_platform_env
-
-    honor_platform_env()
     args = build_parser().parse_args(argv)
     if args.testfile:
         return _test(args)

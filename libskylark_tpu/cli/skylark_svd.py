@@ -47,9 +47,6 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
-    from libskylark_tpu.cli import honor_platform_env
-
-    honor_platform_env()
     args = build_parser().parse_args(argv)
     import jax.numpy as jnp
     import numpy as np

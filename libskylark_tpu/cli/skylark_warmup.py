@@ -191,9 +191,6 @@ def _cmd_boot_probe(args) -> int:
 
 
 def main(argv=None) -> int:
-    from libskylark_tpu.cli import honor_platform_env
-
-    honor_platform_env()
     args = build_parser().parse_args(argv)
     if args.cmd == "build":
         return _cmd_build(args)

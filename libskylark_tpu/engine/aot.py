@@ -57,7 +57,7 @@ from typing import Any, Optional
 
 from libskylark_tpu.base import env as _env
 
-AOT_SCHEMA = 1
+AOT_SCHEMA = 2  # 2: artifacts record the device ids they load onto
 
 _MAGIC = b"SKYAOT1\n"
 _SUFFIX = ".skyaot"
@@ -251,8 +251,14 @@ def save(key: Any, executable: Any, *, name: str,
             fh.write(_MAGIC)
             fh.write(struct.pack(">Q", len(hdr)))
             fh.write(hdr)
+            # the devices the program was compiled for, by id: a load
+            # defaults to every device of the backend, which no
+            # single-device executable survives on a multi-device host
+            device_ids = [dev.id for dev in
+                          executable.runtime_executable().local_devices()]
             pickle.dump({"key": key, "payload": payload,
-                         "in_tree": in_tree, "out_tree": out_tree},
+                         "in_tree": in_tree, "out_tree": out_tree,
+                         "device_ids": device_ids},
                         fh, protocol=pickle.HIGHEST_PROTOCOL)
         os.replace(tmp, path)
         return path
@@ -289,6 +295,7 @@ def load_file(path: str) -> tuple[Any, Any, dict]:
     """``(key, executable, header)`` from one artifact file. Raises
     :class:`AotLoadError` on any compat or deserialize problem and
     ``FileNotFoundError`` on a plain miss."""
+    import jax
     from jax.experimental import serialize_executable as _se
 
     header = read_header(path)
@@ -301,8 +308,10 @@ def load_file(path: str) -> tuple[Any, Any, dict]:
             (hlen,) = struct.unpack(">Q", fh.read(8))
             fh.seek(len(_MAGIC) + 8 + hlen)
             doc = pickle.load(fh)
+        by_id = {dev.id: dev for dev in jax.devices()}
         executable = _se.deserialize_and_load(
-            doc["payload"], doc["in_tree"], doc["out_tree"])
+            doc["payload"], doc["in_tree"], doc["out_tree"],
+            execution_devices=[by_id[i] for i in doc["device_ids"]])
     except FileNotFoundError:
         raise                 # a plain miss — the caller compiles
     except Exception as e:  # noqa: BLE001 — deserialize is best-effort;

@@ -153,34 +153,37 @@ _persistent_wired = False
 
 def enable_persistent_cache(path: Optional[str] = None) -> bool:
     """Wire jax's persistent compilation cache at ``path`` (or
-    ``SKYLARK_EXEC_CACHE_DIR``). Returns whether wiring happened. Never
-    raises — the persistent cache is an optimization, not a failure
-    mode."""
+    ``SKYLARK_EXEC_CACHE_DIR``). Returns whether a cache directory is in
+    effect. Never raises — the persistent cache is an optimization, not
+    a failure mode.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set the cache was placed from
+    outside: jax reads that variable itself, and this function — the
+    only place in the tree that sets the directory — leaves it alone
+    (the directory is part of the cache key; a second location never
+    hits)."""
     global _persistent_wired
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        _persistent_wired = True
+        return True
     path = path or _env.EXEC_CACHE_DIR.raw()
     if not path or path.strip().lower() in ("0", "off", "no", "false"):
         return False
     try:
         jax.config.update("jax_compilation_cache_dir", path)
-        try:
-            # jax memoizes a "cache disabled" decision at the first
-            # compile; dropping it makes the next compile re-read the
-            # config — without this, wiring after any eager op (key
-            # fold_in, a warm-up) is silently a no-op
-            from jax.experimental.compilation_cache import compilation_cache
+        # jax memoizes a "cache disabled" decision at the first
+        # compile; dropping it makes the next compile re-read the
+        # config — without this, wiring after any eager op (key
+        # fold_in, a warm-up) is silently a no-op
+        from jax.experimental.compilation_cache import compilation_cache
 
-            compilation_cache.reset_cache()
-        except Exception:
-            pass
-        try:
-            # lower than bench.py's 1.0s TPU threshold: solver pipeline
-            # executables backend-compile in well under a second on CPU
-            # hosts yet are exactly the artifacts worth persisting for
-            # the serve-many processes
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.1)
-        except Exception:
-            pass
+        compilation_cache.reset_cache()
+        # below jax's 1.0 s default: solver pipeline executables
+        # backend-compile in well under a second on CPU hosts yet are
+        # exactly the artifacts worth persisting for the serve-many
+        # processes
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs", 0.1)
         _persistent_wired = True
         return True
     except Exception as e:  # noqa: BLE001 — optimization, not failure

@@ -1054,6 +1054,9 @@ class MicrobatchExecutor:
         self._mesh = mesh
         self._batch_axis = None
         self._ndev = 1
+        # fewest distinct devices that held a flush's output so far
+        # (stats()["mesh"]); None until a sharded flush ran
+        self._flush_devices_min: Optional[int] = None
         if mesh is not None:
             self._batch_axis = tuple(mesh.shape.keys())[0]
             self._ndev = int(mesh.shape[self._batch_axis])
@@ -3629,6 +3632,11 @@ class MicrobatchExecutor:
         # shared-memory transport writes straight out of (fleet/shm:
         # np.copyto from the strided view into the slot, no
         # ascontiguousarray staging copy in between).
+        if self._mesh is not None:
+            spread = len({s.device for s in out.addressable_shards})
+            with self._stats_lock:
+                self._flush_devices_min = min(
+                    self._flush_devices_min or spread, spread)
         out = np.asarray(out)
 
         now = time.monotonic()
@@ -3973,10 +3981,17 @@ class MicrobatchExecutor:
             sp_nnz = dict(sorted(self._sparse_nnz_hist.items()))
             fw_sel = dict(sorted(self._fwht_sel.items()))
             dist_by = dict(self._dist_by_replica)
+            flush_devices_min = self._flush_devices_min
         with self._lock:
             queued = self._pending
         return {
             "state": self.state,
+            # sharded serving: the mesh's batch-axis extent and the
+            # fewest distinct devices that held any flush's output (a
+            # mesh executor that computes on one device reads 1 here)
+            "mesh": ({"devices": self._ndev,
+                      "flush_devices_min": flush_devices_min}
+                     if self._mesh is not None else None),
             "submitted": c.get("submitted", 0),
             "completed": c.get("completed", 0),
             "failed": c.get("failed", 0),

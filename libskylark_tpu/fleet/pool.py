@@ -45,6 +45,7 @@ import warnings
 from typing import Callable, Dict, List, Optional
 
 from libskylark_tpu.base import env as _env
+from libskylark_tpu.base import errors
 from libskylark_tpu.base import locks as _locks
 from libskylark_tpu.engine import bucket as bucketing
 from libskylark_tpu.engine import serve as _serve
@@ -68,6 +69,24 @@ def resolve_backend(backend: Optional[str]) -> str:
     if backend == "auto":
         return "process" if (os.cpu_count() or 1) >= 4 else "thread"
     return backend
+
+
+def _refuse_chip_parent() -> None:
+    """A TPU chip belongs to one process: a parent whose backend is the
+    TPU holds the chips, and process replicas that need them would then
+    fail or hang at boot. Refuse up front. The supported layout keeps
+    the parent off the chip (``JAX_PLATFORMS=cpu``) and seats each
+    replica on its own chip through ``replica_env`` (README "On the
+    chip")."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise errors.UnsupportedError(
+            "process replicas on a TPU backend: this process holds the "
+            "chips its children would need. Start the parent with "
+            "JAX_PLATFORMS=cpu and give each replica its own chip through "
+            "replica_env (e.g. JAX_PLATFORMS=tpu, TPU_VISIBLE_CHIPS=<i>), "
+            "or use backend='thread'")
 
 
 class ReplicaPool:
@@ -121,6 +140,8 @@ class ReplicaPool:
             raise ValueError(
                 f"backend must be 'thread', 'process' or 'auto', "
                 f"got {backend!r}")
+        if backend == "process":
+            _refuse_chip_parent()
         names = list(names) if names else [f"r{i}" for i in range(n)]
         if len(names) != n or len(set(names)) != n:
             raise ValueError(f"need {n} distinct replica names, "
@@ -134,8 +155,8 @@ class ReplicaPool:
         # dict applied to every process replica, or a callable
         # ``name -> dict`` pinning each replica to its own seat in the
         # multihost pool / its own device subset (env overrides like
-        # CUDA_VISIBLE_DEVICES applied at child entry) — the
-        # "one replica, one device subset" knob
+        # TPU_VISIBLE_CHIPS applied at child entry; README "On the
+        # chip") — the "one replica, one device subset" knob
         self._coordinator = coordinator
         self._replica_env = replica_env
         self.warmup_pack = warmup_pack

@@ -25,11 +25,10 @@ in ``jax.jit`` at the call site like any other apply.
 from __future__ import annotations
 
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from libskylark_tpu.base import errors
-from libskylark_tpu.base.compat import pvary, shard_map
 from libskylark_tpu.parallel.mesh import ROWS
 from libskylark_tpu.sketch.dense import BLOCK_COLS, DenseTransform
 
@@ -79,8 +78,7 @@ def _pipeline(T, A, mesh: Mesh, axis: str, seq_axis: int,
         use_pallas = sketch_params.get_use_pallas()
     # Only take the kernel branch when it can actually run — otherwise
     # the key table is dead weight and the fallback loses vma checking.
-    use_pallas = (use_pallas and pd._HAVE_PALLAS
-                  and (interpret or pd.available())
+    use_pallas = (use_pallas and (interpret or pd.available())
                   and pd.supported(T.dist, A.dtype))
 
     # Global block-key table, sharded so each device gets its own slice
@@ -110,8 +108,8 @@ def _pipeline(T, A, mesh: Mesh, axis: str, seq_axis: int,
             out_shape = ((s_dim, A_loc.shape[1]) if columnwise
                          else (A_loc.shape[0], s_dim))
             # the carry must be marked device-varying to match the body
-            # (identity on jax lines without the vma system — compat)
-            acc0 = pvary(jnp.zeros(out_shape, A_loc.dtype), axis)
+            acc0 = lax.pcast(jnp.zeros(out_shape, A_loc.dtype), axis,
+                             to="varying")
             part = lax.fori_loop(0, blocks_per_shard, body, acc0)
         return lax.psum(part, axis)
 

@@ -23,11 +23,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from libskylark_tpu.base import errors
-from libskylark_tpu.base.compat import shard_map
 from libskylark_tpu.base.dist_sparse import DistSparseMatrix
 
 

@@ -253,20 +253,19 @@ class FastRFT(SketchTransform):
         return self._apply_rowwise(A.T).T
 
     def _apply_rowwise(self, A: jnp.ndarray) -> jnp.ndarray:
-        # fused single-kernel chain on TPU (one HBM read of A, one write
-        # of the features — the XLA chain re-touches the intermediate
-        # ~9×; BASELINE.md crossover analysis); any decline or Mosaic
-        # failure falls back to the XLA chain below. features_rows
-        # consults the autotuner plan cache (libskylark_tpu/tune/)
-        # first: a cached plan picks the fused/split variant and regime,
-        # or certifies the XLA chain for this workload (it then declines
-        # and the chain below serves).
+        # The fused single-kernel chain (one HBM read of A, one write of
+        # the features — the XLA chain re-touches the intermediate ~9×;
+        # BASELINE.md crossover analysis) serves only under a cached
+        # autotuner plan that names a kernel variant ("planned"): Mosaic
+        # rejects both variants on the TPU tried so far (PERF.md), so
+        # without a measured plan the XLA chain below is the path. A
+        # planned kernel that fails to compile raises.
         from libskylark_tpu.sketch import params as sketch_params
 
         if sketch_params.get_use_pallas():
             from libskylark_tpu.sketch import pallas_fastfood
 
-            out = pallas_fastfood.features_rows(self, A)
+            out = pallas_fastfood.features_rows(self, A, variant="planned")
             if out is not None:
                 return out
         return self._features_rows(A)

@@ -17,19 +17,20 @@ gemm) while the WHT matmuls ride the MXU. Each WHT runs as the same
 kron-factored two-dot form as fut._wht_matmul (Ha·X·Hb over the
 (a, b)-folded axis) with the contractions always on a minor axis — the
 (a, b) fold is transposed between the dots with a rank-3 minor-axes swap.
-Contractions use pallas_dense._dot, the on-chip-certified bf16x3 /
-f32 / bf16 regime set (±1 Hadamard factors are bf16-exact, so bf16x3 is
-f32-grade here).
+Contractions use pallas_dense._dot's bf16x3 / f32 / bf16 regime set
+(±1 Hadamard factors are bf16-exact, so bf16x3 is f32-grade here).
 
-Like pallas_dense, the kernel is planned against the ~16 MiB VMEM budget
-(the m-tile shrinks rather than failing Mosaic) and every caller falls
-back to the XLA chain when the kernel declines or fails to compile —
-the permutation gather (`jnp.take_along_axis` along the lane axis with
-trace-constant indices) is the one op in this kernel without a
-certified precedent in this repo; until a live window compile-checks
-it, the dispatch treats Mosaic rejection as a normal decline. Exact
-semantics vs the XLA chain are pinned by interpret-mode oracles in
-tests/test_pallas_fastfood.py.
+Like pallas_dense, the kernel is planned against the VMEM budget (the
+m-tile shrinks rather than failing Mosaic) and callers take the XLA
+chain when the kernel declines. On a TPU v5e (jax 0.9.0) Mosaic rejects
+both variants — the fused one's permutation gather
+(`jnp.take_along_axis` along the lane axis: "Shape mismatch in input,
+indices and output") and the split one's WHT fold (`tpu.reshape`
+64×2048 → 4096×32: "unsupported shape cast"; PERF.md) — so the kernel
+is off the default dispatch: only a cached autotuner plan or an explicit
+``variant=`` reaches it, and a launch that fails to compile raises.
+Exact semantics vs the XLA chain are pinned by interpret-mode oracles
+in tests/test_pallas_fastfood.py.
 """
 
 from __future__ import annotations
@@ -38,19 +39,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from libskylark_tpu.base import env as _env
 from libskylark_tpu.sketch.fut import _hadamard_np
 from libskylark_tpu.sketch.pallas_dense import (_VMEM_BUDGET_BYTES, _dot,
-                                                available)
-
-try:  # same import seam as pallas_dense: CPU-only hosts lack TPU pallas
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401 — availability probe
-
-    _PALLAS = True
-except Exception:  # pragma: no cover
-    _PALLAS = False
+                                                available,
+                                                compiler_params)
 
 
 def _wht_split(NB: int) -> tuple[int, int]:
@@ -178,6 +173,7 @@ def _launch(X, bdiag, perms, gdiag, smdiag, shifts, mt, NB, nb,
         ],
         out_specs=pl.BlockSpec((1, mt, NB), lambda blk, t: (blk, t, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, X.shape[0], NB), X.dtype),
+        compiler_params=compiler_params("parallel", "parallel"),
         interpret=interpret,
     )(X, bdiag, perms, gdiag, smdiag, shifts, Ha, Hb)
 
@@ -205,6 +201,7 @@ def _launch_split(X, bdiag, perms, gdiag, smdiag, shifts, mt, NB, nb,
                   diag_spec, ha_spec, hb_spec],
         out_specs=out3,
         out_shape=jax.ShapeDtypeStruct((nb, X.shape[0], NB), X.dtype),
+        compiler_params=compiler_params("parallel", "parallel"),
         interpret=interpret,
     )(X, bdiag, Ha, Hb)
     Wg = jnp.take_along_axis(W1, perms[:, None, :], axis=-1)
@@ -215,6 +212,7 @@ def _launch_split(X, bdiag, perms, gdiag, smdiag, shifts, mt, NB, nb,
                   ha_spec, hb_spec],
         out_specs=out3,
         out_shape=jax.ShapeDtypeStruct((nb, X.shape[0], NB), X.dtype),
+        compiler_params=compiler_params("parallel", "parallel"),
         interpret=interpret,
     )(Wg, gdiag, smdiag, shifts, Ha, Hb)
 
@@ -264,6 +262,7 @@ def _launch_batched(X, bdiag, perms, gdiag, smdiag, shifts, mt, NB, nb,
         out_specs=pl.BlockSpec((1, 1, mt, NB),
                                lambda i, blk, t: (i, blk, t, 0)),
         out_shape=jax.ShapeDtypeStruct((B, nb, X.shape[1], NB), X.dtype),
+        compiler_params=compiler_params("parallel", "parallel", "parallel"),
         interpret=interpret,
     )(X, bdiag, perms, gdiag, smdiag, shifts, Ha, Hb)
 
@@ -275,8 +274,6 @@ def serve_qualify(n_dim: int, s_dim: int, m: int, dtype, fut: str,
     case (the serve layer's decline counter wants the why)."""
     from libskylark_tpu.sketch.frft import block_geometry
 
-    if not _PALLAS:
-        return False, "pallas unavailable"
     if not interpret and not available():
         return False, "backend is not a TPU (interpret-mode only here)"
     if fut != "wht":
@@ -356,7 +353,7 @@ def supported(transform, A) -> bool:
     """Whether the fused kernel may serve this FastRFT apply: WHT core
     in its MXU-matmul regime, f32 single-device eager input (sharded
     applies keep the XLA path, whose partitioning XLA handles)."""
-    if not (_PALLAS and available()):
+    if not available():
         return False
     if getattr(transform, "_fut_name", None) != "wht":
         return False
@@ -374,13 +371,13 @@ def supported(transform, A) -> bool:
     return plan_m_tile(transform._NB, int(A.shape[0])) is not None
 
 
-# which launcher served the last successful features_rows call
-# ("fused" | "split") — diagnostics for the on-chip certification and
-# the bench record; never consulted for dispatch decisions
+# which launcher served the last features_rows call ("fused" | "split")
+# — diagnostics for the on-chip certification and chip_smoke.py; never
+# consulted for dispatch decisions
 last_served_variant: str | None = None
 
 
-def _consult_cache(transform, At):
+def cached_plan(transform, At):
     """Cached autotuner plan for this Fastfood feature map, or None.
     Same precedence/gating as pallas_dense._consult_cache."""
     from libskylark_tpu.sketch import params as sketch_params
@@ -401,24 +398,26 @@ def features_rows(transform, At, *, interpret: bool = False,
                   precision: str | None = None,
                   variant: str = "auto"):
     """The (m, S) Fastfood feature map for row-major input At (m, N)
-    through the fused kernel, or None when the kernel declines or fails
-    (caller falls back to the XLA chain — mirror of
-    pallas_dense.rowwise_apply's contract). ``interpret`` runs the
+    through the fused kernel, or None when the kernel declines (caller
+    takes the XLA chain — mirror of pallas_dense.rowwise_apply's
+    contract). A launch that Mosaic rejects raises: it never turns into
+    another variant or the XLA chain silently. ``interpret`` runs the
     pallas interpreter (CPU-testable exact semantics).
 
     ``variant``: "fused" (single kernel, in-kernel Π gather), "split"
-    (two kernels around an XLA gather — the fallback if Mosaic rejects
-    the in-kernel gather), or "auto" (a cached autotuner plan first —
-    which may also certify the XLA chain, declining the kernel — then
-    fused, then split on failure; under ``interpret`` a fused failure
-    re-raises instead — the interpreter has no Mosaic to reject, so any
-    exception there is a plain bug that must not be masked by the
-    fallback)."""
+    (two kernels around an XLA gather), "auto" (a cached autotuner
+    plan decides — it may also certify the XLA chain, declining the
+    kernel — else fused), or "planned" (as "auto", but without a cached
+    kernel plan the kernel declines). The transform's own dispatch
+    (``FastRFT._apply_rowwise``) asks for "planned": Mosaic rejects both
+    variants on the TPU tried so far (PERF.md), so the kernel is off
+    the default path."""
     import math
 
-    if variant not in ("auto", "fused", "split"):
+    if variant not in ("auto", "planned", "fused", "split"):
         raise ValueError(
-            f"variant must be 'auto', 'fused' or 'split', got {variant!r}")
+            "variant must be 'auto', 'planned', 'fused' or 'split', "
+            f"got {variant!r}")
     if not interpret and not supported(transform, At):
         return None
     T = transform
@@ -433,11 +432,13 @@ def features_rows(transform, At, *, interpret: bool = False,
     # sketch/params.py ``use_plan_cache``)
     prec_open = (precision is None
                  and _env.FASTFOOD_PRECISION.raw() is None)
-    plan = (_consult_cache(T, At)
-            if variant == "auto" or prec_open else None)
-    cache_pinned_variant = False
-    if plan is not None and variant == "auto":
-        if plan.backend == "xla_chain":
+    plan = (cached_plan(T, At)
+            if variant in ("auto", "planned") or prec_open else None)
+    if variant == "planned" and (
+            plan is None or plan.backend not in ("fused", "split")):
+        return None
+    if variant in ("auto", "planned"):
+        if plan is not None and plan.backend == "xla_chain":
             if prec_open:
                 return None  # certified: the XLA chain serves this
             # the caller pinned a kernel regime explicitly (argument or
@@ -446,9 +447,8 @@ def features_rows(transform, At, *, interpret: bool = False,
             # dispatch (mirrors pallas_dense._resolve_knobs' _TAKE_XLA
             # condition)
             plan = None
-        elif plan.backend in ("fused", "split"):
-            variant = plan.backend
-            cache_pinned_variant = True
+        variant = (plan.backend if plan is not None
+                   and plan.backend in ("fused", "split") else "fused")
     if plan is not None and plan.backend != variant:
         # a plan certified for a DIFFERENT backend must not donate its
         # regime to an explicitly requested variant (e.g. cached split/
@@ -506,35 +506,11 @@ def features_rows(transform, At, *, interpret: bool = False,
     sh = jnp.pad(sh, (0, nb * NB - T._S)).reshape(nb, NB)
 
     global last_served_variant
-    launchers = {"fused": (_launch,), "split": (_launch_split,),
-                 "auto": (_launch, _launch_split)}[variant]
-    if cache_pinned_variant and variant == "fused":
-        # a cache-pinned fused plan keeps "auto"'s split fallback: the
-        # cache key is a pow2 shape BUCKET, so a different concrete
-        # shape (or toolchain rev) can still hit the one op without
-        # certified Mosaic precedent (the in-kernel gather) — degrading
-        # to the split kernel (~3x traffic) beats falling all the way
-        # to the XLA chain (~9x). An EXPLICIT variant="fused" argument
-        # stays exact (a certification run must not silently switch).
-        launchers = (_launch, _launch_split)
-    F = None
-    for launch in launchers:
-        try:
-            F = launch(Ap, bdiag, perms, gdiag, smdiag, sh,
-                       mt=mt, NB=NB, nb=nb, precision=precision,
-                       scale=float(T.scale), interpret=interpret)
-            last_served_variant = (
-                "fused" if launch is _launch else "split")
-            break
-        except Exception:
-            if interpret:
-                # the interpreter has no Mosaic rejection to tolerate:
-                # an exception here is a plain bug — surface it rather
-                # than silently degrading the oracle to the other
-                # variant (review finding)
-                raise
-    if F is None:
-        return None
+    launch = _launch if variant == "fused" else _launch_split
+    F = launch(Ap, bdiag, perms, gdiag, smdiag, sh,
+               mt=mt, NB=NB, nb=nb, precision=precision,
+               scale=float(T.scale), interpret=interpret)
+    last_served_variant = variant
     # (nb, m_p, NB) → block-major feature order, un-pad, truncate —
     # identical to FastRFT._features_rows' epilogue
     return jnp.moveaxis(F, 0, 1).reshape(Ap.shape[0], nb * NB)[

@@ -44,7 +44,10 @@ is autotuned per (bucket, capacity, nnz class) through the serve ladder
 (``tune._serve_candidates`` / ``cost._sparse_lane_cost``) and certified
 by ``bench.py --certify-kernels``; Mosaic compile-time rejection
 declines back to XLA (the serve layer's poison-for-the-fingerprint-era
-rule), never fails a request.
+rule, counted as ``mosaic-reject``), never fails a request. On a TPU
+v5e (jax 0.9.0) that rejection is what happens: the Pallas TPU lowering
+refuses the (1, nnz) lane blocks of the (B, nnz) operands — the last
+two block dims must divide (8, 128) or span the array (PERF.md).
 """
 
 from __future__ import annotations
@@ -54,24 +57,16 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from libskylark_tpu.sketch.pallas_dense import (_VMEM_BUDGET_BYTES,
-                                                available)
+                                                available,
+                                                compiler_params)
 from libskylark_tpu.sketch.pallas_hash import (CHUNK, _GEN_COLS,
                                                _MODES, _gen_hv,
                                                _padded_n,
                                                chunk_key_table)
-
-try:  # same import seam as pallas_dense: non-TPU builds may lack pallas
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams")
-
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
 
 # nonzeros contracted per one-hot MXU tile (the lane width of the
 # bucket-tiled contraction)
@@ -108,8 +103,6 @@ def qualify(s_dim: int, n: int, m: int, nnz: int, dtype,
     ``by_reason`` decline labels."""
     if accum not in _MODES:
         return False, f"unknown accum mode {accum!r}"
-    if not _HAVE_PALLAS:
-        return False, "pallas unavailable"
     if interpret or not available():
         return False, ("backend is not a TPU (sparse kernel has no "
                        "interpret-mode serve surface — xla scatter "
@@ -233,8 +226,7 @@ def _sparse_call(keys, data, rows, cols, *, s_dim, n_stream, m,
             (1,) + out_shape[1:], lambda b: (b, 0, 0),
             memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel",)),
+        compiler_params=compiler_params("parallel"),
         interpret=interpret,
     )(keys, data, rows, cols)
 

@@ -92,10 +92,9 @@ def set_use_plan_cache(on: bool) -> None:
 # ``pallas_precision`` — contraction regime inside the fused kernel.
 # "bf16x3" (default): 3-pass error-compensated bf16 split — f32-grade
 # rounding at roughly twice the MXU rate of full-f32 passes;
-# oracle-certified ON CHIP against the XLA path at 1e-4
-# (tests/test_pallas_dense.py::test_fused_on_chip_matches_xla,
-# benchmarks/tpu_validation_r03.txt — the certification the r2 plan
-# required before making it the default). "f32": full-f32 passes
+# checked ON CHIP against the XLA path at 1e-4
+# (tests/test_pallas_dense.py::test_fused_on_chip_matches_xla and, at
+# the headline width, chip_smoke.py). "f32": full-f32 passes
 # (Precision.HIGHEST), the conservative regime. "bf16": single-pass bf16
 # inputs + f32 accumulation — fastest, but rounds the contraction at
 # ~2⁻⁸ relative (outside the oracle for large N); throughput-only work

@@ -288,10 +288,12 @@ class SketchTransform:
         return {}
 
     # Stream-format generation: bumped whenever the bit-level definition of
-    # the virtual random streams changes (chunk size, dense-block threefry
-    # pair layout — see base/randgen.py). Deserialization rejects a
-    # mismatch rather than silently producing a different operator.
-    STREAM_FORMAT = 2
+    # the virtual random streams changes (chunk size, threefry pair
+    # layout, a distribution's draw→sample map — see base/randgen.py).
+    # Deserialization rejects a mismatch rather than silently producing a
+    # different operator. Format 3: chunk streams moved from jax.random's
+    # samplers to the explicit ops of base/threefry.py.
+    STREAM_FORMAT = 3
 
     def to_dict(self) -> dict[str, Any]:
         d = {

@@ -92,12 +92,9 @@ def normalize_device_kind(kind: str) -> str:
 
 
 def current_device_kind() -> str:
-    try:
-        import jax
+    import jax
 
-        return normalize_device_kind(jax.devices()[0].device_kind)
-    except Exception:
-        return "unknown"
+    return normalize_device_kind(jax.devices()[0].device_kind)
 
 
 @dataclasses.dataclass(frozen=True)

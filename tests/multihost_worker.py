@@ -152,7 +152,7 @@ def main() -> None:
     print(f"proc {pid}: randSVD cross-host oracle ok", flush=True)
 
     # raw cross-host collective sanity: psum over the host-spanning axis
-    from libskylark_tpu.base.compat import shard_map
+    from jax import shard_map
 
     gx = jax.make_array_from_callback(
         (n_dev,), sharding,

@@ -179,6 +179,8 @@ class TestArtifactStore:
     def test_persistent_cache_failure_observable(self, monkeypatch):
         import jax as _jax
 
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+
         # the package re-exports the same-named decorator, shadowing
         # the submodule attribute even for `import a.b.c as x`
         _c = sys.modules["libskylark_tpu.engine.compiled"]

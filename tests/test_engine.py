@@ -86,7 +86,7 @@ class TestCompiledWrapper:
         f(A)
         with jtu.count_jit_and_pmap_lowerings() as lowerings:
             f(A)
-        assert lowerings[0] == 0   # the counter is a single-cell list
+        assert lowerings() == 0
         assert engine.stats().hits == 1
 
     def test_key_fn_extras_distinguish_closures(self, fresh_engine):
@@ -352,7 +352,8 @@ class TestCacheThreadSafety:
 
 
 class TestPersistentCacheWiring:
-    def test_enable_persistent_cache(self, tmp_path):
+    def test_enable_persistent_cache(self, tmp_path, monkeypatch):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         prev = jax.config.jax_compilation_cache_dir
         try:
             assert engine.enable_persistent_cache(str(tmp_path))
@@ -360,6 +361,16 @@ class TestPersistentCacheWiring:
         finally:
             jax.config.update("jax_compilation_cache_dir", prev)
 
-    def test_disabled_values(self):
+    def test_cache_placed_from_outside_is_left_alone(self, tmp_path,
+                                                     monkeypatch):
+        """JAX_COMPILATION_CACHE_DIR set: jax's own reading stands; no
+        code path may point the cache anywhere else."""
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/outside")
+        prev = jax.config.jax_compilation_cache_dir
+        assert engine.enable_persistent_cache(str(tmp_path))
+        assert jax.config.jax_compilation_cache_dir == prev
+
+    def test_disabled_values(self, monkeypatch):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         assert not engine.enable_persistent_cache("0")
         assert not engine.enable_persistent_cache("")

@@ -10,7 +10,7 @@ Pallas interpreter executes the same program on CPU):
 2. the fused rowwise/columnwise applies match the XLA path within the
    framework's 1e-4 oracle (ref: tests/unit/test_utils.hpp:48) at the
    "f32" regime (the conservative one; the shipping default "bf16x3" is
-   oracle-certified on chip, benchmarks/tpu_validation_r03.txt),
+   checked on chip: the ``tpu`` tests below and chip_smoke.py),
 3. the single-pass "bf16" regime's contraction gap is quantified: it is
    bounded by the bf16 rounding model but exceeds the 1e-4 oracle —
    which is why it stays opt-in (sketch/params.py),

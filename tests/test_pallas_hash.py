@@ -37,6 +37,7 @@ import jax.random as jr
 from libskylark_tpu import Context, engine, tune
 from libskylark_tpu import sketch as sk
 from libskylark_tpu.base import randgen
+from libskylark_tpu.base import threefry as tf
 from libskylark_tpu.sketch import pallas_hash as ph
 from libskylark_tpu.sketch.hash import cwt_serve_apply
 
@@ -98,10 +99,10 @@ class TestStreamReplication:
 
     def test_randint_multiplier_matches_jax(self):
         # pow2 spans ≤ 2^16 cancel the high draw entirely
-        assert ph._randint_multiplier(16) == 0
-        assert ph._randint_multiplier(1 << 16) == 0
+        assert tf.randint_multiplier(16) == 0
+        assert tf.randint_multiplier(1 << 16) == 0
         # general spans keep jax's double-draw mix
-        assert ph._randint_multiplier(100) == ((65536 % 100) ** 2) % 100
+        assert tf.randint_multiplier(100) == ((65536 % 100) ** 2) % 100
 
 
 class TestBitEquality:

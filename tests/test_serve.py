@@ -227,9 +227,30 @@ class TestFastfoodEndpoint:
         x = rng.standard_normal((60,)).astype(np.float32)
         with _executor(linger_us=500) as ex:
             out = np.asarray(ex.submit_fastfood(T, x).result(timeout=60))
-        ref = np.asarray(T.apply(jnp.asarray(x)[None, :], sk.ROWWISE))
+        X = jnp.asarray(x)[None, :]
         assert out.shape == (32,)
+        # against the apply as one compiled program (what the serve
+        # executable is): the original band
+        ref = np.asarray(jax.jit(lambda X: T.apply(X, sk.ROWWISE))(X))
         np.testing.assert_allclose(out, ref[0], rtol=1e-5, atol=1e-6)
+        # against the op-by-op apply, which sums the two WHT
+        # contractions in another order: features are scale*cos(arg), so
+        # the two may differ by the rounding of arg — held to ONE f32
+        # ulp of the largest argument (Matern's heavy-tailed Sm makes
+        # it ~178 here; arg from the explicit host chain)
+        import scipy.linalg
+
+        H = scipy.linalg.hadamard(T._NB).astype(np.float64)
+        xp = np.zeros(T._NB)
+        xp[:60] = x
+        f32 = jnp.float32
+        arg = (((xp * np.asarray(T._B(f32), np.float64)[0]) @ H)[
+            np.asarray(T._perms())[0]]
+            * np.asarray(T._G(f32), np.float64)[0]) @ H \
+            * np.asarray(T._Sm(f32), np.float64)
+        ulp = float(np.spacing(np.float32(np.abs(arg[:32]).max())))
+        eager = np.asarray(T.apply(X, sk.ROWWISE))
+        assert np.abs(out - eager[0]).max() <= T.scale * ulp
 
     def test_seed_sharing_one_bucket(self, fresh_engine):
         """Transforms differing only by seed coalesce into ONE bucket

@@ -272,12 +272,16 @@ class TestStreamFormatGate:
         with pytest.raises(Exception, match="stream format"):
             sk.deserialize_sketch(d)
 
-    def test_stale_format_rejected(self):
+    @pytest.mark.parametrize("fmt", [1, 2])
+    def test_stale_format_rejected(self, fmt):
+        """Format 2 drew its chunk streams through jax.random's samplers;
+        format 3 (explicit threefry ops) must refuse it."""
         import json as _json
 
         T = sk.JLT(64, 8, Context(seed=1))
         d = _json.loads(T.to_json())
-        d["stream_format"] = 1
+        assert d["stream_format"] == 3
+        d["stream_format"] = fmt
         with pytest.raises(Exception, match="stream format"):
             sk.deserialize_sketch(d)
 
