@@ -42,9 +42,14 @@ class Allocation:
 
     @property
     def key(self) -> jax.Array:
-        k = jr.fold_in(jr.key(self.seed), self.counter)
-        for p in self.path:
-            k = jr.fold_in(k, p)
+        # lazy: telemetry sits above base in the import order
+        from libskylark_tpu.telemetry.trace import span
+
+        with span("stream.key", {"what": "allocation",
+                                 "path_len": len(self.path)}):
+            k = jr.fold_in(jr.key(self.seed), self.counter)
+            for p in self.path:
+                k = jr.fold_in(k, p)
         return k
 
     def child(self, tag: int) -> "Allocation":

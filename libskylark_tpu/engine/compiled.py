@@ -442,7 +442,14 @@ class CompiledFn:
                 static_argnames=self._static_argnames or None,
                 donate_argnums=donate_argnums or None,
             )
-            executable = jitted.lower(*args, **kwargs).compile()
+            # apart: tracing + lowering is paid by every process, the
+            # backend compile only where the persistent cache misses
+            with _telemetry.span("engine.lower",
+                                 attrs={"name": self.name}):
+                lowered = jitted.lower(*args, **kwargs)
+            with _telemetry.span("engine.backend_compile",
+                                 attrs={"name": self.name}):
+                executable = lowered.compile()
         dt = time.perf_counter() - t0
         # always recorded: compiles are seconds-scale (the
         # histogram bump is noise) and the bench snapshot embeds
@@ -458,6 +465,11 @@ class CompiledFn:
     # -- call --
 
     def __call__(self, *args, **kwargs):
+        with _telemetry.span("engine.call",
+                             attrs={"name": self.name}) as sp:
+            return self._call(sp, args, kwargs)
+
+    def _call(self, sp, args, kwargs):
         import jax.numpy as jnp
 
         statics = tuple(
@@ -472,11 +484,14 @@ class CompiledFn:
             a if isinstance(a, jax.Array) else jnp.asarray(a) for a in args
         )
         donate_argnums = self._effective_donate()
-        key = self._key(args, statics, kwargs, donate_argnums)
-        # single-flight: on a cold key exactly one thread materializes
-        # (AOT artifact load, else compile) while concurrent callers of
-        # the same key block in acquire()
-        entry = _CACHE.acquire(key)
+        with _telemetry.span("engine.lookup"):
+            key = self._key(args, statics, kwargs, donate_argnums)
+            # single-flight: on a cold key exactly one thread
+            # materializes (AOT artifact load, else compile) while
+            # concurrent callers of the same key block in acquire()
+            entry = _CACHE.acquire(key)
+        if sp is not None:
+            sp.set_attr("hit", entry is not None)
         if entry is None:
             with self._stats_lock:
                 self.stats.misses += 1
@@ -490,9 +505,10 @@ class CompiledFn:
         else:
             with self._stats_lock:
                 self.stats.hits += 1
-        t0 = time.perf_counter()
-        out = entry.executable(*args)
-        dt = time.perf_counter() - t0  # dispatch wall; async past this
+        with _telemetry.span("engine.execute"):
+            t0 = time.perf_counter()
+            out = entry.executable(*args)
+            dt = time.perf_counter() - t0  # dispatch wall; async past this
         with self._stats_lock:
             self.stats.executions += 1
             self.stats.execute_seconds += dt

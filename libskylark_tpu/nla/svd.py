@@ -33,7 +33,7 @@ from libskylark_tpu.base import errors
 from libskylark_tpu.base.context import Context
 from libskylark_tpu.base.params import Params
 from libskylark_tpu.base.precision import with_solver_precision
-from libskylark_tpu import engine
+from libskylark_tpu import engine, telemetry
 
 
 @dataclasses.dataclass
@@ -166,11 +166,12 @@ def _svd_pipeline(A, key, *, k: int, kp: int, num_iterations: int,
     """The whole tall-dense randomized SVD as one traceable program:
     sketch → fori_loop power iteration → Rayleigh-Ritz
     (ref: nla/svd.hpp:227-324 collapsed into a single trace)."""
+    # the scopes carry the unfused variant's phase names into the HLO's
+    # op_name metadata (trace time only; the instructions do not change)
     n = A.shape[1]
-    S = _jlt_panel(key, n, kp, A.dtype)
-    Q = A @ S.T                                     # range sketch (m, kp)
-    if not skip_qr:
-        Q = _orthonormalize(Q, ortho)
+    with jax.named_scope("SKETCH"):
+        S = _jlt_panel(key, n, kp, A.dtype)
+        Q = A @ S.T                                 # range sketch (m, kp)
 
     def body(_, Q):
         Q = A @ (A.T @ Q)
@@ -178,23 +179,29 @@ def _svd_pipeline(A, key, *, k: int, kp: int, num_iterations: int,
             Q = _orthonormalize(Q, ortho)
         return Q
 
-    Q = lax.fori_loop(0, num_iterations, body, Q)
-    if skip_qr:
-        # one final orthogonalization is always required before projection
-        Q = _orthonormalize(Q, ortho)
+    with jax.named_scope("POWER_ITERATION"):
+        if not skip_qr:
+            Q = _orthonormalize(Q, ortho)
+        Q = lax.fori_loop(0, num_iterations, body, Q)
+        if skip_qr:
+            # one final orthogonalization is always required before
+            # projection
+            Q = _orthonormalize(Q, ortho)
 
-    Bt = A.T @ Q                                    # (n, kp); B = Btᵀ
-    if rr == "svd":
-        Ub, S_, Vt = jnp.linalg.svd(Bt.T, full_matrices=False)
-        return Q @ Ub[:, :k], S_[:k], Vt[:k, :].T
-    # rr == "cqr2": Bᵀ = Qb·Rb (all-gemm tall QR) ⇒ B = Rbᵀ·Qbᵀ; SVD only
-    # the k'×k' factor: Rbᵀ = Ur·S·Vrᵀ ⇒ B = Ur·S·(Qb·Vr)ᵀ. The expensive
-    # n-dimension work is gemms that shard along n.
-    from libskylark_tpu.nla.tsqr import cholesky_qr2
+    with jax.named_scope("RR_PROJECT"):
+        Bt = A.T @ Q                                # (n, kp); B = Btᵀ
+    with jax.named_scope("RR_SMALL"):
+        if rr == "svd":
+            Ub, S_, Vt = jnp.linalg.svd(Bt.T, full_matrices=False)
+            return Q @ Ub[:, :k], S_[:k], Vt[:k, :].T
+        # rr == "cqr2": Bᵀ = Qb·Rb (all-gemm tall QR) ⇒ B = Rbᵀ·Qbᵀ; SVD
+        # only the k'×k' factor: Rbᵀ = Ur·S·Vrᵀ ⇒ B = Ur·S·(Qb·Vr)ᵀ. The
+        # expensive n-dimension work is gemms that shard along n.
+        from libskylark_tpu.nla.tsqr import cholesky_qr2
 
-    Qb, Rb = cholesky_qr2(Bt)
-    Ur, S_, Vrt = jnp.linalg.svd(Rb.T, full_matrices=False)
-    return Q @ Ur[:, :k], S_[:k], Qb @ Vrt.T[:, :k]
+        Qb, Rb = cholesky_qr2(Bt)
+        Ur, S_, Vrt = jnp.linalg.svd(Rb.T, full_matrices=False)
+        return Q @ Ur[:, :k], S_[:k], Qb @ Vrt.T[:, :k]
 
 
 def _symmetric_svd_pipeline(A, key, *, k: int, kp: int,
@@ -203,9 +210,9 @@ def _symmetric_svd_pipeline(A, key, *, k: int, kp: int,
     """Symmetric variant as one program: Gaussian sketch → fori_loop
     power iteration → Rayleigh-Ritz via eigh (ref: nla/svd.hpp:326-396)."""
     n = A.shape[0]
-    S = _jlt_panel(key, n, kp, A.dtype)
-    Q = A @ S.T                                     # (n, kp) range sketch
-    Q = _orthonormalize(Q, ortho)
+    with jax.named_scope("SKETCH"):
+        S = _jlt_panel(key, n, kp, A.dtype)
+        Q = A @ S.T                                 # (n, kp) range sketch
 
     def body(_, Q):
         Q = A @ Q
@@ -213,17 +220,21 @@ def _symmetric_svd_pipeline(A, key, *, k: int, kp: int,
             Q = _orthonormalize(Q, ortho)
         return Q
 
-    Q = lax.fori_loop(0, num_iterations, body, Q)
-    if skip_qr:
+    with jax.named_scope("POWER_ITERATION"):
         Q = _orthonormalize(Q, ortho)
+        Q = lax.fori_loop(0, num_iterations, body, Q)
+        if skip_qr:
+            Q = _orthonormalize(Q, ortho)
 
     # Rayleigh-Ritz: eigendecomposition of QᵀAQ (ref: nla/svd.hpp:175-225)
-    G = Q.T @ (A @ Q)
-    G = 0.5 * (G + G.T)
-    w, Z = jnp.linalg.eigh(G)
-    # take the k largest-magnitude eigenpairs, descending
-    order = jnp.argsort(-jnp.abs(w))[:k]
-    return Q @ Z[:, order], w[order]
+    with jax.named_scope("RR_PROJECT"):
+        G = Q.T @ (A @ Q)
+    with jax.named_scope("RR_SMALL"):
+        G = 0.5 * (G + G.T)
+        w, Z = jnp.linalg.eigh(G)
+        # take the k largest-magnitude eigenpairs, descending
+        order = jnp.argsort(-jnp.abs(w))[:k]
+        return Q @ Z[:, order], w[order]
 
 
 # donate="auto": the operand is consumed only when the user opted in
@@ -312,7 +323,11 @@ def approximate_svd(
             # already inside an outer trace (a user jit): inline the same
             # pipeline — the outer jit owns compilation and caching
             return _svd_pipeline(A, T._alloc.key, **statics)
-        return _svd_compiled(A, T._alloc.key, **statics)
+        with telemetry.span("nla.approximate_svd") as sp:
+            if sp is not None:
+                sp.attrs.update(shape=(m, n), k=k, kp=kp,
+                                num_iterations=statics["num_iterations"])
+            return _svd_compiled(A, T._alloc.key, **statics)
     return _approximate_svd_unfused(A, T, k, params)
 
 

@@ -34,7 +34,9 @@ from jax import lax
 from libskylark_tpu.base import randgen
 from libskylark_tpu.sketch import params as sketch_params
 from libskylark_tpu.sketch.transform import (OperatorCache,
-                                             SketchTransform, register)
+                                             SketchTransform, note_apply,
+                                             register)
+from libskylark_tpu.telemetry import trace as _trace
 
 # Width of a virtual-S column block; part of the stream format.
 BLOCK_COLS = 256
@@ -204,32 +206,36 @@ class DenseTransform(OperatorCache, SketchTransform):
         return 0
 
     def _apply_columnwise(self, A: jnp.ndarray) -> jnp.ndarray:
-        self._note_eager_apply(A, seq_axis=0)
-        S = self._cached_op(A.dtype)
-        if S is not None:
-            return S @ A
-        out = self._try_pallas(A, "columnwise_apply")
-        if out is not None:
-            return out
-        blocksize = self._effective_blocksize(A.dtype)
-        if blocksize:
-            return self._apply_columnwise_blocked(A, blocksize)
-        S = self.s_panel(0, self._N, A.dtype)
-        return S @ A
+        return self._apply_dense(A, seq_axis=0)
 
     def _apply_rowwise(self, A: jnp.ndarray) -> jnp.ndarray:
-        self._note_eager_apply(A, seq_axis=1)
+        return self._apply_dense(A, seq_axis=1)
+
+    def _apply_dense(self, A: jnp.ndarray, seq_axis: int) -> jnp.ndarray:
+        """S·A (``seq_axis`` 0) or A·Sᵀ (1) by the first path that
+        serves: the pinned operator, the fused kernel, the blocked scan,
+        the materialized gemm."""
+        rowwise = seq_axis == 1
+        self._note_eager_apply(A, seq_axis=seq_axis)
         S = self._cached_op(A.dtype)
-        if S is not None:
-            return A @ S.T
-        out = self._try_pallas(A, "rowwise_apply")
-        if out is not None:
-            return out
-        blocksize = self._effective_blocksize(A.dtype)
-        if blocksize:
-            return self._apply_rowwise_blocked(A, blocksize)
-        S = self.s_panel(0, self._N, A.dtype)
-        return A @ S.T
+        path = "cached_op"
+        if S is None:
+            out = self._try_pallas(
+                A, "rowwise_apply" if rowwise else "columnwise_apply")
+            if out is not None:
+                return out
+            blocksize = self._effective_blocksize(A.dtype)
+            if blocksize:
+                note_apply(path="xla_blocked")
+                blocked = (self._apply_rowwise_blocked if rowwise
+                           else self._apply_columnwise_blocked)
+                with _trace.span("sketch.dispatch", {"padded": False}):
+                    return blocked(A, blocksize)
+            path = "xla_full"
+            S = self.s_panel(0, self._N, A.dtype)
+        note_apply(path=path)
+        with _trace.span("sketch.dispatch", {"padded": False}):
+            return (A @ S.T) if rowwise else (S @ A)
 
     def _try_pallas(self, A, which: str):
         return try_pallas_apply(

@@ -33,6 +33,8 @@ from libskylark_tpu.base import randgen, threefry as tf
 from libskylark_tpu.sketch.dense import BLOCK_COLS  # the stream format's
 # panel width — single source of truth (dense.py imports this module only
 # lazily, so no cycle)
+from libskylark_tpu.sketch.transform import note_apply
+from libskylark_tpu.telemetry import trace as _trace
 
 _HALF = BLOCK_COLS // 2
 
@@ -629,12 +631,43 @@ def _qualify(dist, A, seq_axis: int, m_tile: int, interpret: bool,
     return m_tile
 
 
+def _plan(dist, A, s_dim: int, seq_axis: int, m_tile, precision,
+          interpret: bool, rft: bool = False):
+    """The prelude of every fused apply: resolve the knobs
+    (:func:`_resolve_knobs`) and qualify the operand (:func:`_qualify`).
+    Returns ``(m_tile, precision, pipeline)`` with the effective tile, or
+    None when the kernel declines and the caller takes the XLA path."""
+    with _trace.span("sketch.plan") as sp:
+        plan = None
+        knobs = _resolve_knobs(dist, A.shape, A.dtype, s_dim, seq_axis,
+                               m_tile, precision, rft=rft)
+        if knobs is _TAKE_XLA:
+            source = "take_xla"
+        else:
+            m_tile, precision, pipeline, source = knobs
+            mt = _qualify(dist, A, seq_axis=seq_axis, m_tile=m_tile,
+                          interpret=interpret, s_dim=s_dim)
+            if mt is not None:
+                plan = (mt, precision, pipeline)
+        if sp is not None:
+            sp.set_attr("plan_source", source)
+    if plan is not None:
+        note_apply(path="pallas", m_tile=plan[0], precision=plan[1],
+                   plan_source=source)
+    return plan
+
+
 @functools.partial(jax.jit, static_argnames="n")
-def _block_keys(key, n: int) -> jnp.ndarray:
-    """uint32 (n_blocks, 2) Threefry key table for column blocks 0..n/BC."""
+def _block_key_table(key, n: int) -> jnp.ndarray:
     n_blocks = -(-n // BLOCK_COLS)
     return jax.vmap(lambda b: jr.key_data(randgen.chunk_key(key, b)))(
         jnp.arange(n_blocks, dtype=jnp.int32))
+
+
+def _block_keys(key, n: int) -> jnp.ndarray:
+    """uint32 (n_blocks, 2) Threefry key table for column blocks 0..n/BC."""
+    with _trace.span("stream.key", {"what": "block_table"}):
+        return _block_key_table(key, n)
 
 
 def _padded_extents(n: int, m: int, mt: int) -> tuple[int, int]:
@@ -671,22 +704,21 @@ def rowwise_apply(
     (caller takes the XLA path) — including when a cached autotuner plan
     certifies the XLA path for this workload. A Mosaic rejection of a
     planned kernel raises."""
-    knobs = _resolve_knobs(dist, A.shape, A.dtype, s_dim, 1, m_tile,
-                           precision)
-    if knobs is _TAKE_XLA:
+    plan = _plan(dist, A, s_dim, 1, m_tile, precision, interpret)
+    if plan is None:
         return None
-    m_tile, precision, pipeline, _src = knobs
-    mt = _qualify(dist, A, seq_axis=1, m_tile=m_tile, interpret=interpret,
-                  s_dim=s_dim)
-    if mt is None:
-        return None
+    mt, precision, pipeline = plan
     m = A.shape[0]
-    Ap = _padded(A, seq_axis=1, mt=mt)
-    out = _fused_call(Ap, _block_keys(key, A.shape[1]), s_dim=s_dim,
-                      dist_kind=_DIST_KINDS[type(dist)], m_tile=mt,
-                      precision=precision, interpret=interpret,
-                      pipeline=pipeline)
-    return scale * out[:m]
+    keys = _block_keys(key, A.shape[1])
+    with _trace.span("sketch.dispatch") as sp:
+        Ap = _padded(A, seq_axis=1, mt=mt)
+        if sp is not None:
+            sp.set_attr("padded", Ap is not A)
+        out = _fused_call(Ap, keys, s_dim=s_dim,
+                          dist_kind=_DIST_KINDS[type(dist)], m_tile=mt,
+                          precision=precision, interpret=interpret,
+                          pipeline=pipeline)
+        return scale * out[:m]
 
 
 def columnwise_apply(
@@ -701,22 +733,21 @@ def columnwise_apply(
 ) -> Optional[jnp.ndarray]:
     """out = scale · S @ A for A (N, m); same fused generation, transposed
     contraction."""
-    knobs = _resolve_knobs(dist, A.shape, A.dtype, s_dim, 0, m_tile,
-                           precision)
-    if knobs is _TAKE_XLA:
+    plan = _plan(dist, A, s_dim, 0, m_tile, precision, interpret)
+    if plan is None:
         return None
-    m_tile, precision, pipeline, _src = knobs
-    mt = _qualify(dist, A, seq_axis=0, m_tile=m_tile, interpret=interpret,
-                  s_dim=s_dim)
-    if mt is None:
-        return None
+    mt, precision, pipeline = plan
     m = A.shape[1]
-    Ap = _padded(A, seq_axis=0, mt=mt)
-    out = _fused_call_cw(Ap, _block_keys(key, A.shape[0]), s_dim=s_dim,
-                         dist_kind=_DIST_KINDS[type(dist)], m_tile=mt,
-                         precision=precision, interpret=interpret,
-                         pipeline=pipeline)
-    return scale * out[:, :m]
+    keys = _block_keys(key, A.shape[0])
+    with _trace.span("sketch.dispatch") as sp:
+        Ap = _padded(A, seq_axis=0, mt=mt)
+        if sp is not None:
+            sp.set_attr("padded", Ap is not A)
+        out = _fused_call_cw(Ap, keys, s_dim=s_dim,
+                             dist_kind=_DIST_KINDS[type(dist)], m_tile=mt,
+                             precision=precision, interpret=interpret,
+                             pipeline=pipeline)
+        return scale * out[:, :m]
 
 
 def rft_rowwise_apply(
@@ -737,26 +768,25 @@ def rft_rowwise_apply(
     epilogue applied in VMEM (no extra HBM round-trip of the feature
     matrix). ``sc``/``sh`` are (s_dim,) per-feature scales/shifts.
     Returns None when not applicable."""
-    knobs = _resolve_knobs(dist, A.shape, A.dtype, s_dim, 1, m_tile,
-                           precision, rft=True)
-    if knobs is _TAKE_XLA:
+    plan = _plan(dist, A, s_dim, 1, m_tile, precision, interpret, rft=True)
+    if plan is None:
         return None
-    m_tile, precision, pipeline, _src = knobs
-    mt = _qualify(dist, A, seq_axis=1, m_tile=m_tile, interpret=interpret,
-                  s_dim=s_dim)
-    if mt is None:
-        return None
+    mt, precision, pipeline = plan
     m = A.shape[0]
-    Ap = _padded(A, seq_axis=1, mt=mt)
-    out = _fused_call_cos(
-        Ap, _block_keys(key, A.shape[1]),
-        jnp.asarray(sc, jnp.float32).reshape(1, s_dim),
-        jnp.asarray(sh, jnp.float32).reshape(1, s_dim),
-        s_dim=s_dim, dist_kind=_DIST_KINDS[type(dist)], m_tile=mt,
-        precision=precision, inscale=float(inscale),
-        outscale=float(outscale), interpret=interpret,
-        pipeline=pipeline)
-    return out[:m]
+    keys = _block_keys(key, A.shape[1])
+    with _trace.span("sketch.dispatch") as sp:
+        Ap = _padded(A, seq_axis=1, mt=mt)
+        if sp is not None:
+            sp.set_attr("padded", Ap is not A)
+        out = _fused_call_cos(
+            Ap, keys,
+            jnp.asarray(sc, jnp.float32).reshape(1, s_dim),
+            jnp.asarray(sh, jnp.float32).reshape(1, s_dim),
+            s_dim=s_dim, dist_kind=_DIST_KINDS[type(dist)], m_tile=mt,
+            precision=precision, inscale=float(inscale),
+            outscale=float(outscale), interpret=interpret,
+            pipeline=pipeline)
+        return out[:m]
 
 
 def _default_precision() -> str:
@@ -788,15 +818,10 @@ def fused_partial(
     backend/distribution qualification is _qualify's)."""
     if A_loc.shape[seq_axis] != keys.shape[0] * BLOCK_COLS:
         return None
-    knobs = _resolve_knobs(dist, A_loc.shape, A_loc.dtype, s_dim,
-                           seq_axis, m_tile, precision)
-    if knobs is _TAKE_XLA:
+    plan = _plan(dist, A_loc, s_dim, seq_axis, m_tile, precision, interpret)
+    if plan is None:
         return None
-    m_tile, precision, pipeline, _src = knobs
-    mt = _qualify(dist, A_loc, seq_axis=seq_axis, m_tile=m_tile,
-                  interpret=interpret, s_dim=s_dim)
-    if mt is None:
-        return None
+    mt, precision, pipeline = plan
     m = A_loc.shape[1 - seq_axis]
     Ap = _padded(A_loc, seq_axis=seq_axis, mt=mt)
     kw = dict(s_dim=s_dim, dist_kind=_DIST_KINDS[type(dist)], m_tile=mt,

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from libskylark_tpu import __version__
 from libskylark_tpu.base import errors
 from libskylark_tpu.base.context import Allocation, Context
+from libskylark_tpu.telemetry import trace as _trace
 
 
 class Dimension(enum.Enum):
@@ -40,6 +41,14 @@ COLUMNWISE = Dimension.COLUMNWISE
 ROWWISE = Dimension.ROWWISE
 
 _REGISTRY: dict[str, type["SketchTransform"]] = {}
+
+
+def note_apply(**attrs) -> None:
+    """Record on the enclosing ``sketch.apply`` span what the dispatch
+    below it chose (``path``, the kernel's plan). No-op outside one."""
+    sp = _trace.current_span()
+    if sp is not None and sp.name == "sketch.apply":
+        sp.attrs.update(attrs)
 
 
 def register(cls: type["SketchTransform"]) -> type["SketchTransform"]:
@@ -213,15 +222,29 @@ class SketchTransform:
         per-(input,output)-type specializations, e.g.
         sketch/hash_transform_local_sparse.hpp) and produces a dense result.
         """
+        with _trace.span("sketch.apply") as sp:
+            if sp is not None:
+                sp.attrs.update(
+                    family=self.sketch_type,
+                    dimension=getattr(dimension, "value", dimension),
+                    shape=tuple(getattr(A, "shape", ())),
+                    dtype=str(getattr(A, "dtype", type(A).__name__)))
+            return self._apply(A, dimension)
+
+    def _apply(self, A, dimension: Dimension) -> jnp.ndarray:
+        """Validate the operand and dispatch to the ``_apply_*`` of its
+        kind and orientation."""
         from libskylark_tpu.base.dist_sparse import DistSparseMatrix
         from libskylark_tpu.base.sparse import SparseMatrix
 
         if isinstance(A, DistSparseMatrix):
+            note_apply(path="sparse")
             # dimension validation lives in dist_sparse_apply._check_dim
             if dimension == Dimension.COLUMNWISE:
                 return self._apply_columnwise_dist_sparse(A)
             return self._apply_rowwise_dist_sparse(A)
         if isinstance(A, SparseMatrix):
+            note_apply(path="sparse")
             if dimension == Dimension.COLUMNWISE:
                 if A.height != self._N:
                     raise errors.SketchError(

@@ -24,8 +24,10 @@ observability.rst``). Three modules:
 
 Enablement: ``SKYLARK_TELEMETRY=1`` (record, in-memory only),
 ``SKYLARK_TELEMETRY_DIR=<dir>`` (record + JSONL export), or
-:func:`set_enabled`. Disabled cost is one branch per record/span —
-cheap enough that the timing-sensitive tier-1 tests run with it off.
+:func:`set_enabled`. Spans (not counters) are also recorded while a
+``jax.profiler`` session is tracing the process. Disabled cost is one
+branch per record, two per span — cheap enough that the
+timing-sensitive tier-1 tests run with it off.
 """
 
 from __future__ import annotations
@@ -39,6 +41,7 @@ from libskylark_tpu.telemetry.metrics import (
 from libskylark_tpu.telemetry.trace import (
     Span, SpanContext, add_event, add_sink, attach, clear_finished,
     current_span, finished_spans, get_context, new_request_id, span,
+    stage_seconds,
 )
 from libskylark_tpu.telemetry.export import (
     JsonlExporter, get_exporter, install_exporter, prometheus_text,
@@ -58,5 +61,5 @@ __all__ = [
     "finished_spans", "gauge", "get_context", "get_exporter", "histogram",
     "install_exporter", "new_request_id", "prometheus_text",
     "register_collector", "registry", "set_enabled", "shutdown_exporter",
-    "snapshot", "span",
+    "snapshot", "span", "stage_seconds",
 ]

@@ -1,5 +1,6 @@
-"""Declared metric names: the single source of truth the
-``metric-names`` lint rule checks call sites against.
+"""Declared metric and span names: the single source of truth the
+``metric-names`` lint rule checks instrument call sites against, and
+``tests/test_telemetry.py`` checks ``span(...)`` call sites against.
 
 Every counter/gauge/histogram recorded anywhere in
 ``libskylark_tpu`` must be declared here once — (name, kind, one-line
@@ -22,7 +23,7 @@ the Prometheus surface, and counters grow ``_total`` there —
 
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, Tuple
 
 #: name -> kind ("counter" | "gauge" | "histogram")
 METRICS: Dict[str, str] = {
@@ -126,4 +127,40 @@ METRICS: Dict[str, str] = {
     "net.drains": "counter",
 }
 
-__all__ = ["METRICS"]
+#: span name -> (layer of PERF.md §3, the per-layer metric of
+#: BENCHMARK.json it feeds, or "operator" for one that is read by people).
+#: Every string literal in the first argument of a ``span(`` call in the
+#: package is declared here, and every name here has a call site: the
+#: benchmark's readers and ``cellbench/tools/span_gaps.py`` key on these
+#: strings, so a rename fails a test instead of turning a metric into
+#: ``None``. (``PhaseTimer``'s labels are variables under their own gate.)
+SPANS: Dict[str, Tuple[str, str]] = {
+    # the measured apply (sketch/transform.py, dense.py, pallas_dense.py)
+    "sketch.apply": ("sketch kernel", "sketch_host_ms.apply"),
+    "sketch.plan": ("sketch kernel", "sketch_plan_ms.apply"),
+    "sketch.dispatch": ("sketch kernel", "operator"),
+    # base/context.py Allocation.key, pallas_dense._block_keys
+    "stream.key": ("streams", "stream_key_ms.apply"),
+    # the measured solve (nla/svd.py, engine/compiled.py)
+    "nla.approximate_svd": ("solver phases", "operator"),
+    "engine.call": ("solver phases", "operator"),
+    "engine.lookup": ("solver phases", "operator"),
+    "engine.execute": ("solver phases", "operator"),
+    "engine.compile": ("solver phases", "operator"),
+    "engine.lower": ("solver phases", "operator"),
+    "engine.backend_compile": ("solver phases", "operator"),
+    # the serve tier (engine/serve.py)
+    "serve.submit": ("serve flush", "operator"),
+    "serve.flush": ("serve flush", "operator"),
+    "serve.isolation": ("serve flush", "operator"),
+    # fleet/router.py, dist/serve.py
+    "fleet.route": ("fleet", "operator"),
+    "dist.shard_task": ("fleet", "operator"),
+    # net/server.py
+    "net.serve": ("wire", "operator"),
+    # io/chunked.py, io/webhdfs.py
+    "io.chunked.read": ("ingest", "operator"),
+    "io.webhdfs.open": ("ingest", "operator"),
+}
+
+__all__ = ["METRICS", "SPANS"]

@@ -24,17 +24,23 @@ name, so host-side spans line up with the device timeline under
 timelines first-class (FlashSketch's argument: sketch-kernel perf work
 is only trustworthy with them).
 
-Cost discipline: a disabled :func:`span` is one branch returning a
+The gate: a real span opens when telemetry is enabled
+(:func:`~libskylark_tpu.telemetry.metrics.enabled`), **or while a
+``jax.profiler`` session is recording** — whoever traces the process
+gets the program's spans on the profiler's own clock without setting
+anything — or under ``force=True`` (the
+:class:`~libskylark_tpu.utility.timer.PhaseTimer` shim, whose
+``SKYLARK_TPU_PROFILE`` phase timers keep their own enablement). A span
+that is not forced is also skipped while jax is tracing a function: it
+would time the tracing, not the work.
+
+Cost discipline: a disabled :func:`span` is two branches returning a
 shared no-op context manager — no allocation, no contextvar write.
-``force=True`` opens a real span regardless of the global gate; the
-:class:`~libskylark_tpu.utility.timer.PhaseTimer` shim uses it so the
-``SKYLARK_TPU_PROFILE`` phase timers keep their own independent
-enablement.
 
 Finished spans go to the bounded in-memory ring (:func:`finished_spans`
-— tests, debugging) and to every registered sink
-(:func:`add_sink`; the JSONL exporter in
-:mod:`libskylark_tpu.telemetry.export` is one).
+— tests, debugging; :func:`stage_seconds` reads a parent's stages from
+it) and to every registered sink (:func:`add_sink`; the JSONL exporter
+in :mod:`libskylark_tpu.telemetry.export` is one).
 """
 
 from __future__ import annotations
@@ -47,6 +53,14 @@ import threading
 import time
 from collections import deque
 from typing import Callable, Iterator, Optional
+
+import jax.profiler as _jax_profiler
+
+try:  # True outside any jit/scan/shard_map trace of this thread
+    from jax._src.core import trace_state_clean as _not_tracing
+except ImportError:  # pragma: no cover - moved by a jax upgrade: spans
+    def _not_tracing() -> bool:  # then also open under a trace, which
+        return True              # test_apply_under_jit_opens_no_span flags
 
 from libskylark_tpu.base import locks as _locks
 from libskylark_tpu.telemetry import metrics as _metrics
@@ -100,8 +114,8 @@ class Span:
     """One in-flight (then finished) traced operation."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "request_id",
-                 "attrs", "events", "t_wall", "duration_s", "status",
-                 "error", "thread")
+                 "attrs", "events", "t_wall", "t_start_ns", "t_end_ns",
+                 "status", "error", "thread")
 
     def __init__(self, name: str, trace_id: str, parent_id: Optional[str],
                  request_id: Optional[str], attrs: Optional[dict]):
@@ -112,11 +126,20 @@ class Span:
         self.request_id = request_id
         self.attrs = dict(attrs) if attrs else {}
         self.events: list = []
-        self.t_wall = time.time()
-        self.duration_s: Optional[float] = None
+        self.t_wall = time.time()      # for the JSONL export only
+        # start and end on ONE clock (perf_counter_ns), so a parent's
+        # self time is exact arithmetic over the ring
+        self.t_start_ns: Optional[int] = None
+        self.t_end_ns: Optional[int] = None
         self.status = "ok"
         self.error: Optional[str] = None
         self.thread = threading.current_thread().name
+
+    @property
+    def duration_s(self) -> Optional[float]:
+        if self.t_end_ns is None:
+            return None
+        return (self.t_end_ns - self.t_start_ns) * 1e-9
 
     def set_attr(self, key: str, value) -> None:
         self.attrs[key] = value
@@ -195,13 +218,9 @@ def attach(ctx: Optional[SpanContext]) -> Iterator[None]:
         _CURRENT.reset(token)
 
 
-def _jax_annotation(name: str):
-    try:
-        import jax.profiler
-
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # pragma: no cover - jax always importable here
-        return contextlib.nullcontext()
+# True exactly while a ``jax.profiler`` session records (a static method
+# of jaxlib's TraceMe; tens of nanoseconds)
+_session_recording = _jax_profiler.TraceAnnotation.is_enabled
 
 
 class _NoopSpanCm:
@@ -222,7 +241,7 @@ class _SpanCm:
     serve submit path opens one per request and the generator protocol
     costs ~2x a plain __enter__/__exit__ pair)."""
 
-    __slots__ = ("span", "_token", "_ann", "_t0")
+    __slots__ = ("span", "_token", "_ann")
 
     def __init__(self, name: str, attrs: Optional[dict],
                  parent: Optional[SpanContext],
@@ -241,18 +260,17 @@ class _SpanCm:
         self.span = Span(name, trace_id, parent_id, request_id, attrs)
         self._token = None
         self._ann = None
-        self._t0 = 0.0
 
     def __enter__(self) -> Span:
         self._token = _CURRENT.set(self.span)
-        self._ann = _jax_annotation(self.span.name)
+        self._ann = _jax_profiler.TraceAnnotation(self.span.name)
         self._ann.__enter__()
-        self._t0 = time.perf_counter()
+        self.span.t_start_ns = time.perf_counter_ns()
         return self.span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         s = self.span
-        s.duration_s = time.perf_counter() - self._t0
+        s.t_end_ns = time.perf_counter_ns()
         try:
             self._ann.__exit__(exc_type, exc, tb)
         except Exception:  # pragma: no cover - profiler teardown
@@ -270,14 +288,19 @@ def span(name: str, attrs: Optional[dict] = None, *,
          request_id: Optional[str] = None,
          force: bool = False):
     """Open a span (context manager yielding the :class:`Span`, or
-    ``None`` when telemetry is disabled and ``force`` is not set).
+    ``None`` when the gate is shut: telemetry disabled, no
+    ``jax.profiler`` session recording, and ``force`` not set — or jax
+    tracing a function around the call, where the span would time the
+    tracing).
 
     ``parent`` overrides the ambient contextvar parent (cross-thread
     handoff); ``request_id`` pins the id explicitly (else inherited
     from the parent); ``force`` opens a real span regardless of the
-    global gate (the PhaseTimer shim's hook — phase timers keep their
-    own ``SKYLARK_TPU_PROFILE`` enablement)."""
-    if not (force or _metrics.enabled()):
+    gate (the PhaseTimer shim's hook — phase timers keep their own
+    ``SKYLARK_TPU_PROFILE`` enablement)."""
+    if not force and not (
+            (_metrics.enabled() or _session_recording())
+            and _not_tracing()):
         return _NOOP
     return _SpanCm(name, attrs, parent, request_id)
 
@@ -334,8 +357,53 @@ def clear_finished() -> None:
     _FINISHED.clear()
 
 
+def stage_seconds(root_name: str, last: Optional[int] = None
+                  ) -> Optional[list]:
+    """The stages of the last ``last`` finished spans named
+    ``root_name`` (all of them when ``None``), oldest first: for each
+    its ``total_s``, its ``self_s`` (total minus what its direct
+    children cover) and ``children`` — the summed seconds of its
+    direct children by name.
+
+    ``None`` when the ring may have dropped a span from the oldest of
+    them on (the window is then not whole, and a reader must leave its
+    number out rather than report a wrong one)."""
+    spans = list(_FINISHED)
+    roots = [s for s in spans if s.name == root_name]
+    if last is not None:
+        roots = roots[-last:] if last > 0 else []
+    if not roots:
+        return []
+    if len(spans) == _FINISHED.maxlen and (
+            (last is not None and len(roots) < last)
+            or spans[0].t_end_ns >= roots[0].t_start_ns):
+        # a full ring has (or may have) dropped spans; all of them
+        # ended before its oldest did — whole only if that was before
+        # the oldest root began, and no asked-for root is among them
+        return None
+    by_id = {r.span_id: (r, {}, []) for r in roots}
+    for s in spans:
+        if s.parent_id in by_id:
+            root, by_name, covered = by_id[s.parent_id]
+            by_name[s.name] = by_name.get(s.name, 0.0) + s.duration_s
+            covered.append((max(s.t_start_ns, root.t_start_ns),
+                            min(s.t_end_ns, root.t_end_ns)))
+    stages = []
+    for root, by_name, covered in by_id.values():
+        end, inside = 0, 0
+        for a, b in sorted(covered):    # union: children that ran in
+            a = max(a, end)             # other threads may overlap
+            if b > a:
+                inside += b - a
+                end = b
+        stages.append({"total_s": root.duration_s,
+                       "self_s": root.duration_s - inside * 1e-9,
+                       "children": by_name})
+    return stages
+
+
 __all__ = [
     "Span", "SpanContext", "add_event", "add_sink", "attach",
     "clear_finished", "current_span", "finished_spans", "get_context",
-    "new_request_id", "span",
+    "new_request_id", "span", "stage_seconds",
 ]

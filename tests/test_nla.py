@@ -96,6 +96,25 @@ class TestApproximateSVD:
         with pytest.raises(Exception, match="rank"):
             nla.approximate_svd(jnp.eye(4), 0, Context(0))
 
+    @pytest.mark.parametrize("symmetric", [False, True])
+    def test_fused_pipeline_carries_the_phase_names(self, symmetric):
+        """The fused pipelines name their phases as the unfused variant's
+        timers do: each scope is in the op_name metadata of the compiled
+        program (it does not rename the instructions)."""
+        from libskylark_tpu.nla import svd
+
+        statics = dict(k=4, kp=8, num_iterations=1, skip_qr=False,
+                       ortho="cqr2")
+        if symmetric:
+            fn, shape = svd._symmetric_svd_pipeline, (48, 48)
+        else:
+            fn, shape, statics["rr"] = svd._svd_pipeline, (64, 48), "cqr2"
+        A = jax.ShapeDtypeStruct(shape, jnp.float32)
+        text = jax.jit(fn, static_argnames=tuple(statics)).lower(
+            A, jax.random.key(0), **statics).compile().as_text()
+        for phase in ("SKETCH", "POWER_ITERATION", "RR_PROJECT", "RR_SMALL"):
+            assert f"/{phase}/" in text, phase
+
     def test_rr_reductions_agree(self):
         """The CQR2-reduced Rayleigh-Ritz (r5 default — the r4 mesh
         hotspot fix) and the reference-algebra direct SVD of the k'×n

@@ -13,6 +13,7 @@ injected ``serve.flush`` fault plan.
 
 from __future__ import annotations
 
+import functools
 import json
 import threading
 
@@ -479,3 +480,262 @@ class TestEngineIntegration:
                     if s.name == "engine.compile"
                     and s.attrs.get("name") == "telemetry.test_compile"]
         assert len(compiles) == 1
+
+
+# ---------------------------------------------------------------------------
+# spans on the two measured host paths: the apply and the solve
+# ---------------------------------------------------------------------------
+
+
+def _children(spans, parent):
+    return [s for s in spans if s.parent_id == parent.span_id]
+
+
+def _one(spans, name):
+    found = [s for s in spans if s.name == name]
+    assert len(found) == 1, f"{name}: {[s.name for s in spans]}"
+    return found[0]
+
+
+def _apply_operand(m=24, n=512):
+    import jax.numpy as jnp
+
+    return jnp.asarray(
+        np.random.default_rng(3).standard_normal((m, n)), jnp.float32)
+
+
+def _interpreted_kernel(monkeypatch):
+    """Route the dense dispatch into the fused kernel in interpret mode
+    (off the TPU the dispatch declines it; steered here, in the test)."""
+    from libskylark_tpu.sketch import dense as dense_mod
+    from libskylark_tpu.sketch import pallas_dense
+
+    def interpreted(key, dist, A, s_dim, scale, which):
+        return getattr(pallas_dense, which)(key, dist, A, s_dim, scale,
+                                            interpret=True)
+
+    monkeypatch.setattr(dense_mod, "try_pallas_apply", interpreted)
+
+
+class TestHotPathSpans:
+    APPLY_STAGES = {"stream.key", "sketch.plan", "sketch.dispatch"}
+
+    def test_gate_shut_leaves_the_ring_empty(self):
+        from libskylark_tpu import nla
+
+        telemetry.set_enabled(False)
+        sk.JLT(512, 64, Context(5)).apply(_apply_operand(), sk.ROWWISE)
+        nla.approximate_svd(_apply_operand(96, 40), 3, Context(6))
+        assert telemetry.finished_spans() == []
+        assert telemetry.stage_seconds("sketch.apply") == []
+
+    @pytest.mark.parametrize("path", ["pallas", "xla_full"])
+    def test_apply_is_one_root_with_its_stages(self, path, monkeypatch):
+        if path == "pallas":
+            _interpreted_kernel(monkeypatch)
+        T = sk.JLT(512, 64, Context(5))
+        A = _apply_operand()
+        telemetry.set_enabled(True)
+        T.apply(A, sk.ROWWISE).block_until_ready()
+        spans = telemetry.finished_spans()
+        root = _one(spans, "sketch.apply")
+        assert root.parent_id is None
+        kids = _children(spans, root)
+        assert {s.name for s in kids} == self.APPLY_STAGES
+        assert len(kids) == len(spans) - 1          # nothing deeper, no stray
+        for kid in kids:
+            assert root.t_start_ns <= kid.t_start_ns <= kid.t_end_ns \
+                <= root.t_end_ns
+        assert root.duration_s == pytest.approx(
+            (root.t_end_ns - root.t_start_ns) * 1e-9)
+        assert root.attrs["path"] == path
+        assert root.attrs["family"] == "JLT"
+        assert root.attrs["dimension"] == "rowwise"
+        assert root.attrs["shape"] == (24, 512)
+        assert _one(spans, "sketch.plan").attrs["plan_source"] in (
+            "cache", "heuristic")
+        assert _one(spans, "sketch.dispatch").attrs["padded"] is False
+        whats = sorted(s.attrs["what"] for s in kids
+                       if s.name == "stream.key")
+        if path == "pallas":
+            assert whats == ["allocation", "block_table"]
+            assert root.attrs["plan_source"] in ("cache", "heuristic")
+            assert root.attrs["m_tile"] >= 8 and root.attrs["precision"]
+        else:
+            assert whats == ["allocation", "allocation"]
+
+    def test_apply_under_jit_opens_no_span(self):
+        import jax
+
+        T = sk.JLT(512, 64, Context(5))
+        telemetry.set_enabled(True)
+        jax.jit(lambda a: T.apply(a, sk.ROWWISE))(
+            _apply_operand()).block_until_ready()
+        assert telemetry.finished_spans() == []
+
+    def test_profiler_session_opens_the_gate(self, tmp_path):
+        """Telemetry off: a ``jax.profiler`` session alone records the
+        spans, in the ring and as events of the profile's host line,
+        inside the caller's annotation; the gate shuts with the session."""
+        import jax
+        from jax.profiler import ProfileData
+
+        T = sk.JLT(512, 64, Context(5))
+        A = _apply_operand()
+        T.apply(A, sk.ROWWISE).block_until_ready()      # warm, gate shut
+        telemetry.set_enabled(False)
+        assert telemetry.finished_spans() == []
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 1
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            with jax.profiler.TraceAnnotation("test.window"):
+                T.apply(A, sk.ROWWISE).block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        spans = telemetry.finished_spans()
+        root = _one(spans, "sketch.apply")
+        assert {s.name for s in _children(spans, root)} == self.APPLY_STAGES
+        T.apply(A, sk.ROWWISE).block_until_ready()
+        assert len(telemetry.finished_spans()) == len(spans)   # shut again
+
+        (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+        (host,) = [p for p in ProfileData.from_file(str(path)).planes
+                   if p.name == "/host:CPU"]
+        lines = [[(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                  for e in line.events] for line in host.lines]
+        (main,) = [evs for evs in lines
+                   if any(n == "test.window" for n, _, _ in evs)]
+        (_, w0, w1), = [e for e in main if e[0] == "test.window"]
+        for name in self.APPLY_STAGES | {"sketch.apply"}:
+            events = [e for e in main if e[0] == name]
+            assert len(events) == (2 if name == "stream.key" else 1)
+            assert all(w0 <= a <= b <= w1 for _, a, b in events)
+
+    def test_solve_is_one_tree_down_to_the_executable(self):
+        import jax.numpy as jnp
+
+        from libskylark_tpu import nla
+
+        A = jnp.asarray(np.random.default_rng(9).standard_normal((136, 40)),
+                        jnp.float32)
+        telemetry.set_enabled(True)
+        engine.reset()
+
+        def solve():
+            tmod.clear_finished()
+            nla.approximate_svd(A, 3, Context(7))
+            spans = telemetry.finished_spans()
+            root = _one(spans, "nla.approximate_svd")
+            assert root.parent_id is None
+            assert root.attrs["shape"] == (136, 40) and root.attrs["k"] == 3
+            assert sorted(s.name for s in _children(spans, root)) == [
+                "engine.call", "stream.key"]
+            call = _one(spans, "engine.call")
+            assert call.attrs["name"] == "approximate_svd"
+            return spans, call
+
+        spans, call = solve()                       # cold
+        assert call.attrs["hit"] is False
+        assert [s.name for s in _children(spans, call)] == [
+            "engine.lookup", "engine.compile", "engine.execute"]
+        assert [s.name for s in
+                _children(spans, _one(spans, "engine.compile"))] == [
+            "engine.lower", "engine.backend_compile"]
+        spans, call = solve()                       # warm
+        assert call.attrs["hit"] is True
+        assert [s.name for s in _children(spans, call)] == [
+            "engine.lookup", "engine.execute"]
+
+    def test_stage_seconds_sums_children_and_takes_the_last(self):
+        A = _apply_operand()
+        telemetry.set_enabled(True)
+        for _ in range(3):      # a transform each: none pins its operator
+            sk.JLT(512, 64, Context(5)).apply(
+                A, sk.ROWWISE).block_until_ready()
+        spans = telemetry.finished_spans()
+        stages = telemetry.stage_seconds("sketch.apply", last=2)
+        assert len(stages) == 2
+        root = [s for s in spans if s.name == "sketch.apply"][-1]
+        kids = _children(spans, root)
+        keys = [s.duration_s for s in kids if s.name == "stream.key"]
+        assert len(keys) == 2
+        last = stages[-1]
+        assert last["total_s"] == root.duration_s
+        assert last["children"]["stream.key"] == pytest.approx(sum(keys))
+        assert set(last["children"]) == self.APPLY_STAGES
+        assert last["self_s"] == pytest.approx(
+            root.duration_s - sum(s.duration_s for s in kids))
+        assert 0.0 < last["self_s"] < last["total_s"]
+        assert len(telemetry.stage_seconds("sketch.apply")) == 3
+        assert telemetry.stage_seconds("no.such.span") == []
+
+    def test_stage_seconds_is_none_on_a_wrapped_ring(self):
+        telemetry.set_enabled(True)
+        ring = tmod._FINISHED.maxlen
+        with telemetry.span("sketch.apply"):
+            for _ in range(ring + 8):       # the first children fall out
+                with telemetry.span("stream.key"):
+                    pass
+        assert len(telemetry.finished_spans()) == ring
+        assert telemetry.stage_seconds("sketch.apply") is None
+        # a window the ring still holds whole reads again
+        for _ in range(2):
+            with telemetry.span("sketch.apply"):
+                with telemetry.span("stream.key"):
+                    pass
+        assert telemetry.stage_seconds("sketch.apply", last=2) is not None
+        assert len(telemetry.stage_seconds("sketch.apply", last=2)) == 2
+        assert telemetry.stage_seconds("sketch.apply", last=3) is None
+
+
+# ---------------------------------------------------------------------------
+# declared span names (telemetry/names.py SPANS)
+# ---------------------------------------------------------------------------
+
+
+@functools.lru_cache(maxsize=None)
+def _span_literals() -> dict:
+    """{string literal: [file:line, ...]} over the first argument of every
+    ``span(...)`` call in the package."""
+    import ast
+    import pathlib
+
+    import libskylark_tpu
+
+    root = pathlib.Path(libskylark_tpu.__file__).parent
+    found: dict = {}
+    for path in sorted(root.rglob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if not isinstance(node, ast.Call):
+                continue
+            f = node.func
+            if (f.attr if isinstance(f, ast.Attribute)
+                    else getattr(f, "id", None)) != "span":
+                continue
+            first = (node.args[0] if node.args else next(
+                (k.value for k in node.keywords if k.arg == "name"), None))
+            if first is None:
+                continue
+            for leaf in ast.walk(first):
+                if isinstance(leaf, ast.Constant) and isinstance(
+                        leaf.value, str):
+                    found.setdefault(leaf.value, []).append(
+                        f"{path.relative_to(root)}:{node.lineno}")
+    return found
+
+
+class TestSpanNames:
+    from libskylark_tpu.telemetry.names import SPANS
+
+    def test_every_span_call_site_is_declared(self):
+        undeclared = {name: sites for name, sites in _span_literals().items()
+                      if name not in self.SPANS}
+        assert undeclared == {}
+
+    @pytest.mark.parametrize("name", sorted(SPANS))
+    def test_declared_span_has_a_call_site(self, name):
+        assert name in _span_literals(), f"stale declaration {name!r}"
+        layer, feeds = self.SPANS[name]
+        assert layer and feeds
