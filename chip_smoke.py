@@ -233,15 +233,22 @@ def step_sketch() -> None:
                    err=f"{err:.2e}")
 
         # software-pipelined generation: the dispatch takes it under a
-        # cached plan that asks for it, when the operator is too big
-        # for the VMEM operator cache (it is, at this width)
+        # cached plan that asks for it, where every grid step regenerates
+        # its operator block (residency "per_tile": columnwise at this
+        # width, or a single m-tile). A rowwise operand of several
+        # m-tiles keeps the operator in HBM instead and has nothing to
+        # pipeline.
         plan_args = (T.dist, operand.shape, operand.dtype, S, seq_axis)
-        m_tile = pd.effective_plan(*plan_args, interpret=REHEARSE)["m_tile"]
+        plan = pd.effective_plan(*plan_args, interpret=REHEARSE)
+        if plan["operator_residency"] != "per_tile":
+            say(f"sketch.JLT.{name}", plan=plan["plan_id"],
+                operator_residency=plan["operator_residency"])
+            continue
         with planned(
                 tune.dense_workload("normal", operand.shape, operand.dtype,
                                     S, seq_axis),
-                tune.Plan("pallas", m_tile=m_tile, precision="bf16x3",
-                          pipeline=True)):
+                tune.Plan("pallas", m_tile=plan["m_tile"],
+                          precision="bf16x3", pipeline=True)):
             plan = pd.effective_plan(*plan_args, interpret=REHEARSE)
             if not (plan["pipelined"] and plan["plan_source"] == "cache"):
                 raise AssertionError(

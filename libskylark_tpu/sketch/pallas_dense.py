@@ -2,19 +2,26 @@
 
 The hot primitive of the framework (ref: SURVEY.md §3.1 — the reference's
 blocked panel algorithm in sketch/dense_transform_Elemental_mc_mr.hpp with
-``realize_matrix_view`` generating S panels on demand). The XLA path pays
-for panel generation (Threefry + inverse-CDF on the VPU) serialized against
-the matmul; this kernel generates each (S_dim × BLOCK_COLS) panel of S in
-VMEM — exact same bits as :func:`randgen.dense_block`, via the shared
-integer-op Threefry in base/threefry.py — while the MXU contracts the
-previous panels, so generation rides under the matmul.
+``realize_matrix_view`` generating S panels on demand). Each
+(S_dim × BLOCK_COLS) panel of S is generated on the VPU — exact same bits
+as :func:`randgen.dense_block`, via the shared integer-op Threefry in
+base/threefry.py — and contracted on the MXU. Inside one grid step the two
+do NOT overlap (PERF.md §6, PR 27: a step costs generation plus matmul, at
+≈ 46 G entries/s on a v5e), so what matters is how often a panel is made.
+:func:`operator_residency` decides, from shapes only, where the operator
+lives between the m-tiles of one apply: ``"vmem"`` (small S: generated in
+the first m-tile sweep into VMEM scratch), ``"hbm"`` (rowwise, big S: a
+generation kernel writes S once an apply to HBM, scale folded in, as the
+bf16 hi/lo planes the contraction needs, and a contraction kernel streams
+them beside the A tiles) or ``"per_tile"`` (a single m-tile, or the
+columnwise big-S case: regenerated in every grid step). Nothing is kept
+across applies.
 
 Rowwise (out = A·Sᵀ, the regime of BASELINE config 1) and columnwise
-(out = S·A) applies, both with optional pipelined generation; inputs the
-kernel declines (wrong backend, distribution, dtype, no tile inside the
-VMEM plan) take the XLA path in sketch/dense.py. A kernel the dispatch
-selected and Mosaic then rejects is a bug and raises — it never turns
-into the XLA path silently.
+(out = S·A) applies; inputs the kernel declines (wrong backend,
+distribution, dtype, no tile inside the VMEM plan) take the XLA path in
+sketch/dense.py. A kernel the dispatch selected and Mosaic then rejects is
+a bug and raises — it never turns into the XLA path silently.
 """
 
 from __future__ import annotations
@@ -55,10 +62,14 @@ def available() -> bool:
 def compiler_params(*dimension_semantics: str):
     """Mosaic compiler params shared by every ``pallas_call`` in
     sketch/pallas_*.py. No ``vmem_limit_bytes``: the tile plans target
-    Mosaic's default scoped VMEM (``_VMEM_BUDGET_BYTES``), and at the
-    headline widths every kernel that compiles on a v5e compiles inside
-    it (PERF.md, PR 21) — the first VMEM rejection is the reason to pass
-    one, here."""
+    Mosaic's default scoped VMEM (``_VMEM_BUDGET_BYTES``, 16 MiB of a
+    v5e core's 128), and at the headline widths every kernel that
+    compiles on a v5e compiles inside it (PERF.md, PR 21). The one
+    rejection seen since (m_tile 1024 at s_dim 1024, PR 27) was a plan
+    that left the matmul's result tile out, and was cured there
+    (:func:`_vmem_estimate`). Tiles past the default scope are faster
+    (sketch/params.py, m-tile note) and would start here, with the
+    budget the plans read — ROADMAP Queue 1."""
     return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
 
 
@@ -93,6 +104,20 @@ def _accumulate(out_ref, acc, k):
         out_ref[:] += acc
 
 
+def _bf16_dot(a, b, dims):
+    """One bf16 MXU pass with f32 accumulation. Precision pinned
+    explicitly: the package-level default matmul precision is "highest",
+    which on bf16 operands asks Mosaic for an fp32 contraction it can't
+    lower ("Bad lhs type")."""
+    return jax.lax.dot_general(
+        a.astype(jnp.bfloat16),
+        b.astype(jnp.bfloat16),
+        dims,
+        precision=jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _dot(lhs, rhs, dims, precision, gen_side=1):
     """MXU contraction at the requested precision regime.
 
@@ -119,18 +144,7 @@ def _dot(lhs, rhs, dims, precision, gen_side=1):
     1e-4 oracle for large N (quantified in tests/test_pallas_dense.py), so
     callers opt in explicitly for throughput-only work."""
 
-    def bf16_dot(a, b):
-        # precision pinned explicitly: the package-level default matmul
-        # precision is "highest", which on bf16 operands asks Mosaic for
-        # an fp32 contraction it can't lower ("Bad lhs type")
-        return jax.lax.dot_general(
-            a.astype(jnp.bfloat16),
-            b.astype(jnp.bfloat16),
-            dims,
-            precision=jax.lax.Precision.DEFAULT,
-            preferred_element_type=jnp.float32,
-        )
-
+    bf16_dot = functools.partial(_bf16_dot, dims=dims)
     if precision == "bf16":
         return bf16_dot(lhs, rhs)
     if precision == "bf16gen2":
@@ -161,31 +175,74 @@ def _dot(lhs, rhs, dims, precision, gen_side=1):
     )
 
 
-# Per-core VMEM budget the tile plans target: Mosaic's default scoped
-# limit (16 MiB), env-overridable.
+# Per-core VMEM budget the tile plans target: Mosaic's default SCOPED
+# limit (16 MiB — not the core's VMEM, which is 128 MiB on a v5e by
+# pltpu.get_tpu_info(); PERF.md §6, PR 27), env-overridable.
 _VMEM_BUDGET_BYTES = _env.PALLAS_VMEM_BUDGET.get()
 
-# VMEM budget for caching the generated operator across m-tiles. When the
+# Cap on the "vmem" residency (:func:`operator_residency`): when the
 # full virtual S fits, each block is generated ONCE (first m-tile sweep)
-# and every later tile contracts against the cached copy — generation cost
-# amortizes over m instead of being paid per tile. Larger operators fall
-# back to per-tile regeneration. Must leave room for the pipeline's
+# into VMEM scratch and every later tile contracts against the cached
+# copy. A larger operator is kept in HBM instead (rowwise) or regenerated
+# per tile (columnwise). Must leave room for the pipeline's
 # double-buffered A/out tiles inside _VMEM_BUDGET_BYTES (advisor r2
-# medium finding: the old 48 MiB default exceeded whole-VMEM on v5e and
+# medium finding: the old 48 MiB default exceeded the scoped limit and
 # could fail Mosaic compilation outright on the shard_map path).
 _SCRATCH_CAP_BYTES = _env.PALLAS_SCRATCH_CAP.get()
 
 
 def _vmem_estimate(m_tile: int, s_dim: int, scratch_bytes: int) -> int:
-    """Rough per-core VMEM plan for one grid step: double-buffered A tile
-    (m_tile × BLOCK_COLS) and out tile (m_tile × s_dim), the generated
-    operator block + generation temporaries (~4 × s_dim × BLOCK_COLS),
-    plus the optional operator-cache scratch."""
+    """Per-core scoped-VMEM plan for one grid step: double-buffered A tile
+    (m_tile × BLOCK_COLS) and out tile (m_tile × s_dim), ONE more
+    m_tile × s_dim for the matmul result before it is accumulated, the
+    generated operator block + generation temporaries
+    (~4 × s_dim × BLOCK_COLS), plus the optional operator-cache scratch.
+    Held against what Mosaic itself asks for at s_dim = 1024 (least
+    ``vmem_limit_bytes`` that compiles for a v5e, PR 27): m_tile 512
+    plans 11.0 MiB here and needs 9.4 (9.9 under the "hbm" residency);
+    m_tile 1024 plans 18 and needs 17.0 either way — over the 16 MiB
+    scope, which Mosaic refused on the chip when the plan left the
+    result tile out. The "hbm" residency's contraction kernel swaps the
+    generation term for its double-buffered plane tiles and the A-tile
+    split (2.9 MiB against 2.4 at s_dim = 1024), inside the same term,
+    so a tile planned here fits there too."""
     return 4 * (
         2 * m_tile * BLOCK_COLS
-        + 2 * m_tile * s_dim
+        + 3 * m_tile * s_dim
         + 4 * s_dim * BLOCK_COLS
     ) + scratch_bytes
+
+
+def operator_residency(s_dim: int, n: int, m: int, m_tile: int,
+                       rowwise: bool) -> str:
+    """Where the generated operator lives between the m-tiles of ONE
+    apply, from the padded shapes alone — the single rule the
+    ``pallas_call`` sites, :func:`_pipe_fits`, :func:`effective_plan`,
+    the ``sketch.apply`` span and tune/cost.py all read:
+
+    ``"per_tile"``  a single m-tile: nothing to reuse, each block is
+                    generated in the grid step that contracts it. Also
+                    the columnwise big-S case (no cell sends columnwise
+                    traffic to judge an HBM-resident variant on;
+                    ROADMAP Queue 1).
+    ``"vmem"``      S fits the scratch cap and the VMEM plan: generated
+                    during the first m-tile sweep into VMEM scratch.
+    ``"hbm"``       rowwise, S too big for VMEM: generated once an apply
+                    into HBM by its own kernel, streamed by the
+                    contraction kernel (:func:`_planes_call`).
+
+    Reading an entry back costs 4 B ÷ 819 GB/s ≈ 5 ps against ≈ 22 ps to
+    regenerate it (≈ 46 G entries/s on a v5e, PERF.md §6 PR 27), so
+    keeping S pays from the second m-tile on. Nothing is kept ACROSS
+    applies: every apply regenerates, once."""
+    if m // m_tile <= 1:
+        return "per_tile"
+    scratch_bytes = s_dim * n * 4
+    if (scratch_bytes <= _SCRATCH_CAP_BYTES
+            and _vmem_estimate(m_tile, s_dim, scratch_bytes)
+            <= _VMEM_BUDGET_BYTES):
+        return "vmem"
+    return "hbm" if rowwise else "per_tile"
 
 
 def _resolve_block(dist_kind, s_dim, keys_ref, k, s_scr):
@@ -203,13 +260,16 @@ def _resolve_block(dist_kind, s_dim, keys_ref, k, s_scr):
     return s_scr[:, pl.ds(k * BLOCK_COLS, BLOCK_COLS)]
 
 
-def _apply_epilogue(out_ref, epilogue, k, n_blocks):
+def _apply_epilogue(out_ref, epilogue, operand_refs, k, n_blocks):
     """Fused in-VMEM finish after the LAST operator block accumulates
-    (shared by the plain and pipelined kernels). ``epilogue("cos",
-    inscale, outscale, sc_ref, sh_ref)`` → outscale·cos(acc·inscale·sc
-    + sh) (ref: RFT_Elemental.hpp:83-156)."""
-    kind, inscale, outscale, sc_ref, sh_ref = epilogue
+    (shared by every rowwise kernel). ``epilogue = ("cos", inscale,
+    outscale)`` with ``operand_refs = (sc_ref, sh_ref)`` →
+    outscale·cos(acc·inscale·sc + sh) (ref: RFT_Elemental.hpp:83-156,
+    the reference's fused elementwise loops): the output never makes the
+    extra HBM round-trip a separate elementwise op would cost."""
+    kind, inscale, outscale = epilogue
     assert kind == "cos"
+    sc_ref, sh_ref = operand_refs
 
     @pl.when(k == n_blocks - 1)
     def _epilogue():
@@ -217,20 +277,20 @@ def _apply_epilogue(out_ref, epilogue, k, n_blocks):
         out_ref[:] = outscale * jnp.cos(z)
 
 
-def _kernel_pipe(dist_kind, s_dim, n_blocks, precision, keys_ref, a_ref,
-                 out_ref, s_buf, *, rowwise=True, epilogue=None):
+def _kernel_pipe(dist_kind, s_dim, n_blocks, precision, rowwise, epilogue,
+                 keys_ref, a_ref, *refs):
     """Kernel with software-pipelined generation: block k+1 is generated
     into the other half of a double buffer BETWEEN the MXU contraction of
     block k being issued and its result being consumed — the generation
-    is dataflow-independent of the in-flight matmul, so the scheduler can
-    run the VPU (Threefry + inverse-CDF) under the MXU. At the headline
-    config generation is the dominant non-MXU cost (one full operator
-    regeneration per m-tile sweep), so the overlap bounds the step at
-    max(gen, matmul) instead of their sum. One body serves both
-    orientations (``rowwise``: out += A·S_blkᵀ, else out += S_blk·A).
-    Opt-in via SKYLARK_PALLAS_PIPELINE=1 pending an on-chip A/B
-    (scheduling is the compiler's call; interpret-mode equivalence is
-    exact either way)."""
+    is dataflow-independent of the in-flight matmul, so the scheduler MAY
+    run the VPU (Threefry + inverse-CDF) under the MXU. On a v5e it does
+    not: the pipelined variant ran 12.6 ms against the plain kernel's
+    8.5 ms, bit-identical (PR 21). Only the "per_tile" residency
+    regenerates per step, so only there can this engage
+    (:func:`_pipe_fits`); opt-in via SKYLARK_PALLAS_PIPELINE=1 or a cached
+    plan. One body serves both orientations (``rowwise``: out += A·S_blkᵀ,
+    else out += S_blk·A). ``refs`` = (*epilogue operands, out, s_buf)."""
+    *operand_refs, out_ref, s_buf = refs
     k = pl.program_id(1)
 
     @pl.when(k == 0)
@@ -251,7 +311,7 @@ def _kernel_pipe(dist_kind, s_dim, n_blocks, precision, keys_ref, a_ref,
 
     _accumulate(out_ref, acc, k)
     if epilogue is not None:
-        _apply_epilogue(out_ref, epilogue, k, n_blocks)
+        _apply_epilogue(out_ref, epilogue, operand_refs, k, n_blocks)
 
 
 def _pipeline_env() -> bool | None:
@@ -271,34 +331,23 @@ def _pipeline_env() -> bool | None:
     return v == "1"
 
 
-def _kernel(dist_kind, s_dim, m_tile, precision, keys_ref, a_ref, out_ref,
-            s_scr=None, *, epilogue=None, n_blocks=None):
-    """Rowwise: out_tile += A_tile @ S_blkᵀ (S entries are bit-exact; only
-    the contraction rounds, per the ``precision`` regime).
-
-    Optional fused epilogue, applied in VMEM after the LAST operator
-    block accumulates — the output never makes the extra HBM round-trip a
-    separate elementwise op would cost. ``epilogue("cos", inscale,
-    outscale)`` finishes the tile as ``outscale·cos(acc·inscale·sc + sh)``
-    (the random-Fourier featurization; ref: RFT_Elemental.hpp:83-156, the
-    reference's fused elementwise loops) with sc/sh (1, s_dim) VMEM refs
-    threaded by the caller."""
+def _kernel(dist_kind, s_dim, n_blocks, precision, epilogue, keys_ref,
+            a_ref, *refs):
+    """Rowwise, operator generated in the kernel ("vmem" / "per_tile"):
+    out_tile += A_tile @ S_blkᵀ (S entries are bit-exact; only the
+    contraction rounds, per the ``precision`` regime). ``refs`` =
+    (*epilogue operands, out[, operator-cache scratch]); the optional
+    epilogue finishes the tile in VMEM (:func:`_apply_epilogue`)."""
+    n_operands = 0 if epilogue is None else 2
+    operand_refs, out_ref = refs[:n_operands], refs[n_operands]
+    s_scr = refs[n_operands + 1] if len(refs) > n_operands + 1 else None
     k = pl.program_id(1)
     S_blk = _resolve_block(dist_kind, s_dim, keys_ref, k, s_scr)
     acc = _dot(a_ref[:], S_blk, (((1,), (1,)), ((), ())), precision,
                gen_side=1)
     _accumulate(out_ref, acc, k)
     if epilogue is not None:
-        _apply_epilogue(out_ref, epilogue, k, n_blocks)
-
-
-def _kernel_cos(dist_kind, s_dim, m_tile, n_blocks, precision, inscale,
-                outscale, keys_ref, a_ref, sc_ref, sh_ref, out_ref,
-                s_scr=None):
-    """Rowwise + cos featurization (see _kernel's epilogue doc)."""
-    _kernel(dist_kind, s_dim, m_tile, precision, keys_ref, a_ref, out_ref,
-            s_scr, epilogue=("cos", inscale, outscale, sc_ref, sh_ref),
-            n_blocks=n_blocks)
+        _apply_epilogue(out_ref, epilogue, operand_refs, k, n_blocks)
 
 
 def _kernel_cw(dist_kind, s_dim, m_tile, precision, keys_ref, a_ref, out_ref,
@@ -311,87 +360,250 @@ def _kernel_cw(dist_kind, s_dim, m_tile, precision, keys_ref, a_ref, out_ref,
     _accumulate(out_ref, acc, k)
 
 
-def _kernel_pipe_cw(dist_kind, s_dim, n_blocks, precision, keys_ref,
-                    a_ref, out_ref, s_buf):
-    """Columnwise orientation of :func:`_kernel_pipe`."""
-    _kernel_pipe(dist_kind, s_dim, n_blocks, precision, keys_ref, a_ref,
-                 out_ref, s_buf, rowwise=False)
-
-
-def _scratch(s_dim: int, n: int, m: int, m_tile: int):
-    """Scratch shapes for the operator cache, or [] when it doesn't pay
-    (single m-tile → no reuse) or doesn't fit the cap / the whole-kernel
-    VMEM budget."""
-    n_blocks = n // BLOCK_COLS
-    if m // m_tile <= 1:
-        return []
-    scratch_bytes = s_dim * n_blocks * BLOCK_COLS * 4
-    if scratch_bytes > _SCRATCH_CAP_BYTES:
-        return []
-    if _vmem_estimate(m_tile, s_dim, scratch_bytes) > _VMEM_BUDGET_BYTES:
-        return []
-    return [pltpu.VMEM((s_dim, n_blocks * BLOCK_COLS), jnp.float32)]
-
-
-def _pipe_fits(scratch, s_dim: int, m_tile: int,
+def _pipe_fits(residency: str, s_dim: int, m_tile: int,
                pipeline: bool | None = None) -> bool:
     """Pipelined-generation selection predicate — the SINGLE source of
     truth shared by the kernel call sites (via :func:`_select_pipe`) and
     :func:`effective_plan`, so the reported plan can't drift from the
-    executed one: engage when the operator-cache scratch doesn't apply
-    (the big-operator regime), the pipeline is requested — an
-    explicitly set SKYLARK_PALLAS_PIPELINE wins in either direction,
-    else a cached plan's ``pipeline`` flag decides — and the double
-    buffer fits the same VMEM budget _qualify planned against."""
+    executed one: engage when each grid step regenerates its block (the
+    "per_tile" residency), the pipeline is requested — an explicitly set
+    SKYLARK_PALLAS_PIPELINE wins in either direction, else a cached
+    plan's ``pipeline`` flag decides — and the double buffer fits the
+    same VMEM budget _qualify planned against."""
     env = _pipeline_env()
     enabled = env if env is not None else bool(pipeline)
     pipe_bytes = 2 * s_dim * BLOCK_COLS * 4
-    return (not scratch and enabled
+    return (residency == "per_tile" and enabled
             and _vmem_estimate(m_tile, s_dim, pipe_bytes)
             <= _VMEM_BUDGET_BYTES)
 
 
-def _select_pipe(kern, pipe_kern, scratch, s_dim: int, m_tile: int,
-                 pipeline: bool | None = None):
-    """Swap in the pipelined kernel + generation double buffer when
-    :func:`_pipe_fits` says so — over budget, stay on the plain kernel
-    (no fallback seam exists on the shard_map path)."""
-    if pipe_kern is not None and _pipe_fits(scratch, s_dim, m_tile,
-                                            pipeline):
+def _select_pipe(kern, pipe_kern, residency: str, s_dim: int, n: int,
+                 m_tile: int, pipeline: bool | None = None):
+    """(kernel, scratch shapes) of an in-kernel-generation call: the
+    pipelined kernel + generation double buffer when :func:`_pipe_fits`
+    says so — over budget, stay on the plain kernel (no fallback seam
+    exists on the shard_map path)."""
+    if _pipe_fits(residency, s_dim, m_tile, pipeline):
         return pipe_kern, [pltpu.VMEM((2, s_dim, BLOCK_COLS), jnp.float32)]
-    return kern, scratch
+    if residency == "vmem":     # the whole operator, filled by the first sweep
+        return kern, [pltpu.VMEM((s_dim, n), jnp.float32)]
+    return kern, []
 
 
-def _grid_params(scratch):
-    """dimension_semantics for pallas_call: the operator cache needs
-    strictly sequential grid order (the i==0 sweep fills it) — no megacore
-    splitting over the m-tile dimension."""
-    return compiler_params("arbitrary" if scratch else "parallel",
-                           "arbitrary")
+def _grid_params(residency: str):
+    """dimension_semantics for pallas_call: the VMEM operator cache needs
+    strictly sequential grid order (the i==0 sweep fills it) — no
+    megacore splitting over the m-tile dimension."""
+    return compiler_params(
+        "arbitrary" if residency == "vmem" else "parallel", "arbitrary")
 
 
-def _rowwise_pallas_call(A, keys, extra_operands, kern, *, s_dim, m_tile,
-                         interpret, pipe_kern=None, pipeline=None):
-    """Shared rowwise pallas_call plumbing: grid, key-table SMEM spec,
-    A-tile spec, accumulator out spec, operator scratch, compiler params.
-    ``extra_operands`` are (1, s_dim) VMEM vectors threaded to the kernel
-    between a_ref and out_ref (epilogue operands).
+# ---------------------------------------------------------------------------
+# the "hbm" residency: generate once an apply, contract against the planes
+# ---------------------------------------------------------------------------
 
-    When the operator-cache scratch doesn't apply (the big-operator
-    regime) and SKYLARK_PALLAS_PIPELINE=1, ``pipe_kern`` runs instead
-    with a 2-slot generation double buffer; the grid stays parallel over
-    m-tiles (each core's k-sweep is self-contained — the k == 0 prologue
-    refills the buffer per sweep)."""
+
+def _plane_dtypes(precision: str) -> tuple:
+    """The stored form of the operator per contraction regime — what the
+    MXU passes of :func:`_dot` consume: "bf16x3" the bf16 hi/lo pair
+    (2 × 2 B an entry, what one f32 plane takes), "bf16"/"bf16gen2" one
+    bf16 plane, "f32" (and anything else, as in ``_dot``) one f32 plane."""
+    if precision == "bf16x3":
+        return (jnp.bfloat16, jnp.bfloat16)
+    if precision in ("bf16", "bf16gen2"):
+        return (jnp.bfloat16,)
+    return (jnp.float32,)
+
+
+def _tile_scaled(precision: str, scale) -> bool:
+    """"bf16gen2" keeps its definition under "hbm" (sketch/params.py:
+    the operator is scale × the bf16 rounding of the UNIT stream): its
+    plane holds bf16(S) and the scale finishes the tile. Every other
+    regime folds the scale into the planes."""
+    return scale is not None and precision == "bf16gen2"
+
+
+def _kernel_gen(dist_kind, s_dim, scaled, keys_ref, *refs):
+    """Generation kernel: column block k of the operator — the same
+    Threefry counters and bits as :func:`_gen_block` everywhere else —
+    times ``scale``, written as the planes of :func:`_plane_dtypes`."""
+    if scaled:
+        scale_ref, *plane_refs = refs
+    else:
+        scale_ref, plane_refs = None, refs
+    S = _gen_block(dist_kind, s_dim, keys_ref, pl.program_id(0))
+    if scaled:
+        S = S * scale_ref[0]
+    hi = S.astype(plane_refs[0].dtype)
+    plane_refs[0][:] = hi
+    if len(plane_refs) == 2:
+        plane_refs[1][:] = (S - hi.astype(jnp.float32)).astype(
+            plane_refs[1].dtype)
+
+
+def _operator_planes(keys, scale, *, s_dim, dist_kind, precision,
+                     interpret=False):
+    """S (s_dim × n_blocks·BLOCK_COLS), generated once into HBM as the
+    planes ``precision`` contracts with: ``hi = bf16(scale·S)`` and, for
+    "bf16x3", ``lo = bf16(scale·S − hi)``. ``scale`` None (or the
+    "bf16gen2" regime, :func:`_tile_scaled`) stores the unit stream."""
+    n_blocks = keys.shape[0]
+    scaled = scale is not None and not _tile_scaled(precision, scale)
+    operands, in_specs = [keys], [pl.BlockSpec(memory_space=pltpu.SMEM)]
+    if scaled:
+        operands.append(jnp.asarray(scale, jnp.float32).reshape(1))
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+    dtypes = _plane_dtypes(precision)
+    return pl.pallas_call(
+        functools.partial(_kernel_gen, dist_kind, s_dim, scaled),
+        grid=(n_blocks,),
+        in_specs=in_specs,
+        out_specs=[pl.BlockSpec((s_dim, BLOCK_COLS), lambda k: (0, k),
+                                memory_space=pltpu.VMEM) for _ in dtypes],
+        out_shape=[jax.ShapeDtypeStruct((s_dim, n_blocks * BLOCK_COLS), dt)
+                   for dt in dtypes],
+        compiler_params=compiler_params("parallel"),
+        interpret=interpret,
+    )(*operands)
+
+
+def _dot_planes(a, planes, precision):
+    """A_tile · S_blkᵀ against the stored planes: the products
+    :func:`_dot` issues, in its order, with the operator's split already
+    made — only the A tile is split here, on the VPU."""
+    dims = (((1,), (1,)), ((), ()))
+    if planes[0].dtype == jnp.float32:
+        return jax.lax.dot_general(
+            a, planes[0], dims, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+    if precision == "bf16":
+        return _bf16_dot(a, planes[0], dims)
+    a_hi = a.astype(jnp.bfloat16)
+    a_lo = a - a_hi.astype(jnp.float32)
+    if precision == "bf16gen2":
+        return _bf16_dot(a_hi, planes[0], dims) + _bf16_dot(
+            a_lo, planes[0], dims)
+    s_hi, s_lo = planes
+    return _bf16_dot(a_hi, s_hi, dims) + (
+        _bf16_dot(a_hi, s_lo, dims) + _bf16_dot(a_lo, s_hi, dims))
+
+
+def _kernel_planes(n_blocks, precision, n_planes, tile_scaled, epilogue,
+                   *refs):
+    """Contraction kernel of the "hbm" residency: out_tile += A_tile @
+    S_blkᵀ with the (s_dim × k) plane tiles streamed in beside the
+    (m_tile × k) A tile (k: :func:`_plane_step_cols`). No generation, no
+    operator split, no iota inside the m × k loop. ``refs`` = ([scale],
+    a, *planes, *epilogue operands, out)."""
+    refs = list(refs)
+    scale_ref = refs.pop(0) if tile_scaled else None
+    a_ref, plane_refs = refs[0], refs[1:1 + n_planes]
+    *operand_refs, out_ref = refs[1 + n_planes:]
+    k = pl.program_id(1)
+    acc = _dot_planes(a_ref[:], [p[:] for p in plane_refs], precision)
+    _accumulate(out_ref, acc, k)
+    if tile_scaled:
+        @pl.when(k == n_blocks - 1)
+        def _scale():
+            out_ref[:] = scale_ref[0] * out_ref[:]
+    if epilogue is not None:
+        _apply_epilogue(out_ref, epilogue, operand_refs, k, n_blocks)
+
+
+def _plane_step_cols(n: int, m_tile: int, s_dim: int) -> int:
+    """Columns of A and of the planes one contraction step takes: two
+    BLOCK_COLS blocks where n divides and the plan fits, else one. The
+    wider step halves the grid steps and the out tile's
+    read-modify-writes — 23.1 → 22.1 ms an apply at 65536 × 8192 → 1024
+    on a v5e; four blocks bought nothing more (PERF.md §6, PR 27).
+
+    The plan is the contraction kernel's own, fitted to what Mosaic asks
+    for (least ``vmem_limit_bytes`` that compiles for a v5e, eleven
+    shapes, PR 27): 4·(2.9·m_tile·k + 3·m_tile·s_dim) + 9.8·s_dim·k
+    bytes at "bf16x3" — A tile double-buffered plus its hi/lo split, out
+    tile double-buffered plus the matmul result, plane tiles
+    double-buffered plus what is loaded of them; "f32" needs up to
+    8·m_tile·k more, hence the 5."""
+    wide = 2 * BLOCK_COLS
+    plan = 4 * (5 * m_tile * wide + 3 * m_tile * s_dim) + 10 * s_dim * wide
+    if n % wide == 0 and plan <= _VMEM_BUDGET_BYTES:
+        return wide
+    return BLOCK_COLS
+
+
+def _planes_call(A, keys, scale, extra_operands, *, s_dim, dist_kind,
+                 m_tile, precision, interpret, epilogue):
+    """The "hbm" residency: two ``pallas_call``s in one executable. With
+    no scratch the m axis stays "parallel"."""
+    m, n = A.shape
+    k_cols = _plane_step_cols(n, m_tile, s_dim)
+    n_blocks = n // k_cols
+    planes = _operator_planes(keys, scale, s_dim=s_dim, dist_kind=dist_kind,
+                              precision=precision, interpret=interpret)
+    tile_scaled = _tile_scaled(precision, scale)
+    operands, in_specs = [], []
+    if tile_scaled:
+        operands.append(jnp.asarray(scale, jnp.float32).reshape(1))
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+    operands += [A, *planes, *extra_operands]
+    in_specs += [
+        pl.BlockSpec((m_tile, k_cols), lambda i, k: (i, k),
+                     memory_space=pltpu.VMEM),
+    ] + [
+        pl.BlockSpec((s_dim, k_cols), lambda i, k: (0, k),
+                     memory_space=pltpu.VMEM)
+        for _ in planes
+    ] + [
+        pl.BlockSpec((1, s_dim), lambda i, k: (0, 0),
+                     memory_space=pltpu.VMEM)
+        for _ in extra_operands
+    ]
+    return pl.pallas_call(
+        functools.partial(_kernel_planes, n_blocks, precision, len(planes),
+                          tile_scaled, epilogue),
+        grid=(m // m_tile, n_blocks),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec(
+            (m_tile, s_dim), lambda i, k: (i, 0), memory_space=pltpu.VMEM
+        ),
+        out_shape=jax.ShapeDtypeStruct((m, s_dim), jnp.float32),
+        compiler_params=compiler_params("parallel", "arbitrary"),
+        interpret=interpret,
+    )(*operands)
+
+
+def _rowwise_pallas_call(A, keys, scale, extra_operands, *, s_dim, dist_kind,
+                         m_tile, precision, interpret, pipeline=None,
+                         epilogue=None):
+    """``scale``·A·Sᵀ (``scale`` None: unscaled) by the kernel(s) of the
+    operand's :func:`operator_residency`. ``extra_operands`` are the
+    (1, s_dim) VMEM vectors of ``epilogue`` (:func:`_apply_epilogue`).
+
+    "hbm": :func:`_planes_call`, scale folded into the planes. "vmem" /
+    "per_tile": one call that generates in the kernel — grid, key-table
+    SMEM spec, A-tile spec, accumulator out spec, operator scratch — and
+    the scale as a pass over its result. Under "per_tile" with the
+    pipeline requested the 2-slot double-buffered kernel runs instead;
+    the grid stays parallel over m-tiles (each core's k-sweep is
+    self-contained — the k == 0 prologue refills the buffer per sweep)."""
     m, n = A.shape
     n_blocks = n // BLOCK_COLS
-    grid = (m // m_tile, n_blocks)
-    scratch = _scratch(s_dim, n, m, m_tile)
-    grid_params = _grid_params(scratch)
-    kern, scratch = _select_pipe(kern, pipe_kern, scratch, s_dim, m_tile,
-                                 pipeline)
-    return pl.pallas_call(
+    residency = operator_residency(s_dim, n, m, m_tile, rowwise=True)
+    if residency == "hbm":
+        return _planes_call(A, keys, scale, extra_operands, s_dim=s_dim,
+                            dist_kind=dist_kind, m_tile=m_tile,
+                            precision=precision, interpret=interpret,
+                            epilogue=epilogue)
+    kern, scratch = _select_pipe(
+        functools.partial(_kernel, dist_kind, s_dim, n_blocks, precision,
+                          epilogue),
+        functools.partial(_kernel_pipe, dist_kind, s_dim, n_blocks,
+                          precision, True, epilogue),
+        residency, s_dim, n, m_tile, pipeline)
+    out = pl.pallas_call(
         kern,
-        grid=grid,
+        grid=(m // m_tile, n_blocks),
         in_specs=[
             # whole key table in SMEM every step (tiny); indexed by k
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -409,18 +621,10 @@ def _rowwise_pallas_call(A, keys, extra_operands, kern, *, s_dim, m_tile,
         ),
         out_shape=jax.ShapeDtypeStruct((m, s_dim), jnp.float32),
         scratch_shapes=scratch,
-        compiler_params=grid_params,
+        compiler_params=_grid_params(residency),
         interpret=interpret,
     )(keys, A, *extra_operands)
-
-
-def _kernel_pipe_cos(dist_kind, s_dim, n_blocks, precision, inscale,
-                     outscale, keys_ref, a_ref, sc_ref, sh_ref, out_ref,
-                     s_buf):
-    """Pipelined rowwise + cos featurization."""
-    _kernel_pipe(dist_kind, s_dim, n_blocks, precision, keys_ref, a_ref,
-                 out_ref, s_buf,
-                 epilogue=("cos", inscale, outscale, sc_ref, sh_ref))
+    return out if scale is None else scale * out
 
 
 @functools.partial(
@@ -428,14 +632,12 @@ def _kernel_pipe_cos(dist_kind, s_dim, n_blocks, precision, inscale,
     static_argnames=("s_dim", "dist_kind", "m_tile", "precision",
                      "interpret", "pipeline"),
 )
-def _fused_call(A, keys, *, s_dim, dist_kind, m_tile, precision="f32",
-                interpret=False, pipeline=None):
-    kern = functools.partial(_kernel, dist_kind, s_dim, m_tile, precision)
-    pipe = functools.partial(_kernel_pipe, dist_kind, s_dim,
-                             A.shape[1] // BLOCK_COLS, precision)
-    return _rowwise_pallas_call(A, keys, (), kern, s_dim=s_dim,
-                                m_tile=m_tile, interpret=interpret,
-                                pipe_kern=pipe, pipeline=pipeline)
+def _fused_call(A, keys, scale=None, *, s_dim, dist_kind, m_tile,
+                precision="f32", interpret=False, pipeline=None):
+    return _rowwise_pallas_call(A, keys, scale, (), s_dim=s_dim,
+                                dist_kind=dist_kind, m_tile=m_tile,
+                                precision=precision, interpret=interpret,
+                                pipeline=pipeline)
 
 
 @functools.partial(
@@ -446,14 +648,11 @@ def _fused_call(A, keys, *, s_dim, dist_kind, m_tile, precision="f32",
 def _fused_call_cos(A, keys, sc, sh, *, s_dim, dist_kind, m_tile,
                     precision="f32", inscale=1.0, outscale=1.0,
                     interpret=False, pipeline=None):
-    n_blocks = A.shape[1] // BLOCK_COLS
-    kern = functools.partial(_kernel_cos, dist_kind, s_dim, m_tile,
-                             n_blocks, precision, inscale, outscale)
-    pipe = functools.partial(_kernel_pipe_cos, dist_kind, s_dim, n_blocks,
-                             precision, inscale, outscale)
-    return _rowwise_pallas_call(A, keys, (sc, sh), kern, s_dim=s_dim,
-                                m_tile=m_tile, interpret=interpret,
-                                pipe_kern=pipe, pipeline=pipeline)
+    return _rowwise_pallas_call(A, keys, None, (sc, sh), s_dim=s_dim,
+                                dist_kind=dist_kind, m_tile=m_tile,
+                                precision=precision, interpret=interpret,
+                                pipeline=pipeline,
+                                epilogue=("cos", inscale, outscale))
 
 
 @functools.partial(
@@ -465,17 +664,15 @@ def _fused_call_cw(A, keys, *, s_dim, dist_kind, m_tile, precision="f32",
                    interpret=False, pipeline=None):
     n, m = A.shape
     n_blocks = n // BLOCK_COLS
-    grid = (m // m_tile, n_blocks)
-    scratch = _scratch(s_dim, n, m, m_tile)
-    grid_params = _grid_params(scratch)
-    kern = functools.partial(_kernel_cw, dist_kind, s_dim, m_tile, precision)
-    pipe = functools.partial(_kernel_pipe_cw, dist_kind, s_dim, n_blocks,
-                             precision)
-    kern, scratch = _select_pipe(kern, pipe, scratch, s_dim, m_tile,
-                                 pipeline)
+    residency = operator_residency(s_dim, n, m, m_tile, rowwise=False)
+    kern, scratch = _select_pipe(
+        functools.partial(_kernel_cw, dist_kind, s_dim, m_tile, precision),
+        functools.partial(_kernel_pipe, dist_kind, s_dim, n_blocks,
+                          precision, False, None),
+        residency, s_dim, n, m_tile, pipeline)
     return pl.pallas_call(
         kern,
-        grid=grid,
+        grid=(m // m_tile, n_blocks),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(
@@ -488,7 +685,7 @@ def _fused_call_cw(A, keys, *, s_dim, dist_kind, m_tile, precision="f32",
         ),
         out_shape=jax.ShapeDtypeStruct((s_dim, m), jnp.float32),
         scratch_shapes=scratch,
-        compiler_params=grid_params,
+        compiler_params=_grid_params(residency),
         interpret=interpret,
     )(keys, A)
 
@@ -594,7 +791,8 @@ def _qualify(dist, A, seq_axis: int, m_tile: int, interpret: bool,
     size for the (possibly padded) m extent, or None for fallback.
 
     The returned tile is pre-shrunk so the kernel's VMEM plan
-    (:func:`_vmem_estimate`, scratch excluded — _scratch checks itself)
+    (:func:`_vmem_estimate`, scratch excluded — operator_residency
+    checks it)
     fits ``_VMEM_BUDGET_BYTES``: a Mosaic VMEM-exhaustion failure inside a
     jitted shard_map pipeline has no catchable fallback seam, so the
     pre-flight must make compilation succeed, not try/except it (advisor
@@ -652,8 +850,12 @@ def _plan(dist, A, s_dim: int, seq_axis: int, m_tile, precision,
         if sp is not None:
             sp.set_attr("plan_source", source)
     if plan is not None:
+        n_p, m_p = _padded_extents(A.shape[seq_axis], A.shape[1 - seq_axis],
+                                   plan[0])
         note_apply(path="pallas", m_tile=plan[0], precision=plan[1],
-                   plan_source=source)
+                   plan_source=source,
+                   operator_residency=operator_residency(
+                       s_dim, n_p, m_p, plan[0], rowwise=seq_axis == 1))
     return plan
 
 
@@ -714,11 +916,13 @@ def rowwise_apply(
         Ap = _padded(A, seq_axis=1, mt=mt)
         if sp is not None:
             sp.set_attr("padded", Ap is not A)
-        out = _fused_call(Ap, keys, s_dim=s_dim,
+        # the scale is applied inside the call: folded into the planes
+        # under the "hbm" residency, a pass over the result otherwise
+        out = _fused_call(Ap, keys, scale, s_dim=s_dim,
                           dist_kind=_DIST_KINDS[type(dist)], m_tile=mt,
                           precision=precision, interpret=interpret,
                           pipeline=pipeline)
-        return scale * out[:m]
+        return out if out.shape[0] == m else out[:m]
 
 
 def columnwise_apply(
@@ -828,7 +1032,7 @@ def fused_partial(
               precision=precision, interpret=interpret,
               pipeline=pipeline)
     if seq_axis == 1:
-        return _fused_call(Ap, keys, **kw)[:m]
+        return _fused_call(Ap, keys, None, **kw)[:m]
     return _fused_call_cw(Ap, keys, **kw)[:, :m]
 
 
@@ -840,15 +1044,16 @@ def effective_plan(dist, shape, dtype, s_dim: int, seq_axis: int,
     WITHOUT running it. Both tuning knobs can be silently adjusted
     downstream (:func:`_qualify` shrinks an over-budget m-tile;
     :func:`_select_pipe` drops the pipelined kernel when its buffer
-    doesn't fit), so anything recording a measurement labeled with the
+    doesn't fit or the operator is resident), so anything recording a measurement labeled with the
     REQUESTED knobs must ask for the EFFECTIVE ones or the record lies
     about what was measured (e.g. the m-tile/pipeline sweep rows in
     benchmarks/). Runs the SAME plan-cache resolution as the dispatch
     (:func:`_resolve_knobs`), so the report reflects cached plans too.
 
     Returns ``{"kernel": False, "plan_id": "xla"}`` when the apply would
-    take the XLA fallback, else ``kernel/m_tile/operator_cache/
-    pipelined/precision/plan_id/plan_source``."""
+    take the XLA fallback, else ``kernel/m_tile/operator_residency/
+    operator_cache/pipelined/precision/plan_id/plan_source``
+    (``operator_cache`` is ``operator_residency == "vmem"``)."""
     knobs = _resolve_knobs(dist, tuple(shape), jnp.dtype(dtype), s_dim,
                            seq_axis, m_tile, precision)
     if knobs is _TAKE_XLA:
@@ -861,17 +1066,19 @@ def effective_plan(dist, shape, dtype, s_dim: int, seq_axis: int,
     if mt is None:
         return {"kernel": False, "plan_id": "xla",
                 "plan_source": source}
-    # the same padding/scratch/pipeline helpers the pallas_call sites use
+    # the same padding/residency/pipeline helpers the pallas_call sites use
     n_p, m_p = _padded_extents(shape[seq_axis], shape[1 - seq_axis], mt)
-    scratch = _scratch(s_dim, n_p, m_p, mt)
-    pipelined = _pipe_fits(scratch, s_dim, mt, pipeline)
+    residency = operator_residency(s_dim, n_p, m_p, mt,
+                                   rowwise=seq_axis == 1)
+    pipelined = _pipe_fits(residency, s_dim, mt, pipeline)
     # single source of the id format: the same Plan the cache stores
     from libskylark_tpu.tune.plans import Plan
 
     plan_id = Plan("pallas", m_tile=mt, precision=precision,
                    pipeline=pipelined).plan_id()
     return {"kernel": True, "m_tile": mt,
-            "operator_cache": bool(scratch),
+            "operator_residency": residency,
+            "operator_cache": residency == "vmem",
             "pipelined": pipelined,
             "precision": precision,
             "plan_id": plan_id,

@@ -138,16 +138,28 @@ def set_pallas_precision(p: str) -> None:
     _pallas_precision = p
 
 
-# ``pallas_m_tile`` — rows of A per fused-kernel grid step. Larger tiles
-# amortize operator generation over more MXU work at the cost of VMEM:
-# each grid sweep regenerates the whole virtual operator on the VPU
-# (Threefry + inverse-CDF ≈ 50 ops/entry), so at the headline config the
-# generation bill is ~m/m_tile × 0.1 ms/MB — the dominant non-MXU cost
-# (r2 on-chip numbers). 512 halves it vs 256 while keeping the VMEM plan
-# (_vmem_estimate) ≈ 9 MiB at s_dim=1024, inside the 16 MiB budget;
-# _qualify still shrinks per-call when s_dim is larger. Seeded from
-# SKYLARK_PALLAS_MTILE for on-chip sweeps without code changes; invalid
-# values fall back to the default.
+# ``pallas_m_tile`` — rows of A per fused-kernel grid step. What a larger
+# tile buys depends on where the operator lives between m-tiles
+# (pallas_dense.operator_residency). Measured on a v5e at 65536 × 8192
+# → 1024, bf16x3, ms a blocking apply (PERF.md §6, PR 27):
+#   "per_tile" (every m-tile sweep regenerates the whole virtual operator
+#   on the VPU, Threefry + inverse-CDF at ≈ 46 G entries/s, and a step
+#   costs generation PLUS matmul): 43.3 / 31.7 / 26.1 / 22.7 at m_tile
+#   512 / 1024 / 2048 / 4096, i.e. device time 17.2 + 23.2 × 512/m_tile;
+#   "hbm" (rowwise big S: generated once an apply in 0.42 ms, the tile
+#   only sets how often the planes are read back and how many grid steps
+#   run, 0.37 µs each): 24.8 / 23.1 / 22.9 / 22.0 at 256 / 512 / 1024 /
+#   2048, and 22.1 / 21.4 / 21.2 at 512 / 1024 / 2048 with the two-block
+#   k step the kernel now takes (pallas_dense._plane_step_cols).
+# 512 is the largest power of two whose plan fits Mosaic's 16 MiB default
+# scoped VMEM at s_dim = 1024 (_vmem_estimate plans 11 MiB, Mosaic needs
+# 9.4–9.9); 1024 needs 17.0 MiB and Mosaic refused it on the chip. A v5e
+# core has 128 MiB of VMEM, so the larger tiles above ran only with
+# ``vmem_limit_bytes`` raised by the measuring script — no pallas_call in
+# the package passes one (ROADMAP Queue 1). _qualify still shrinks
+# per-call when s_dim is larger. Seeded from SKYLARK_PALLAS_MTILE for
+# on-chip sweeps without code changes; invalid values fall back to the
+# default.
 _PALLAS_M_TILE_DEFAULT = 512
 
 

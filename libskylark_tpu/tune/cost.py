@@ -15,10 +15,12 @@ Two complementary models live here:
    kernel only compiles on TPU). It prices the exact quantities the
    kernel's own documentation identifies as the cost structure
    (sketch/pallas_dense.py, sketch/params.py): MXU passes per
-   contraction regime, operator generation on the VPU (~50 ops/entry,
-   one full regeneration per m-tile sweep unless the operator-cache
-   scratch fits), HBM traffic, and generation/matmul overlap when the
-   pipelined kernel engages.
+   contraction regime, operator generation on the VPU (~50 ops/entry;
+   once an apply when the operator is resident in VMEM or HBM, one full
+   regeneration per m-tile sweep otherwise —
+   ``pallas_dense.operator_residency``), HBM traffic (the resident
+   planes' included), and generation/matmul overlap when the pipelined
+   kernel engages.
 
 Absolute times from the analytic model are NOT predictions — only the
 ORDERING is consumed (rank the candidates, measure the top-k in a live
@@ -89,6 +91,12 @@ RATES = {
 # VPU ops per generated operator entry: Threefry + inverse-CDF ≈ 50
 # (sketch/params.py m-tile analysis; SURVEY §3.1).
 GEN_OPS_PER_ENTRY = 50
+
+# Fixed cost of one Pallas grid step (DMA issue, semaphores, the out
+# tile's read-modify-write). On a v5e the resident-operator kernel took
+# 24.8 ms at m_tile 256 against 23.4 at 512, 4096 steps apart: 0.37 µs a
+# step (PERF.md §6, PR 27).
+GRID_STEP_S = 0.35e-6
 
 # MXU passes per logical f32 contraction at each kernel regime
 # (sketch/pallas_dense._dot): bf16 single pass; bf16gen2 two;
@@ -251,24 +259,30 @@ def rate_provenance(path=_CALIB_AUTO) -> dict:
             for name in RATES}
 
 
-def _dense_operator_cached(m: int, n: int, s: int, m_tile: int) -> bool:
-    """Whether the kernel would serve this plan from the VMEM operator
-    cache — the kernel's OWN decision logic and env-resolved budgets
-    (pallas_dense._scratch / SKYLARK_PALLAS_SCRATCH_CAP /
-    SKYLARK_PALLAS_VMEM_BUDGET), imported lazily so ranking can't
-    drift from dispatch on parts whose budgets were overridden. The
-    import is cycle-safe: pallas_dense only reaches tune lazily inside
-    its dispatch functions."""
-    from libskylark_tpu.sketch.pallas_dense import (_SCRATCH_CAP_BYTES,
-                                                    _VMEM_BUDGET_BYTES,
-                                                    _vmem_estimate)
+def _dense_operator_residency(w: Workload, m_tile: int) -> str:
+    """Where the kernel would keep the generated operator between
+    m-tiles — the kernel's OWN rule and env-resolved budgets
+    (pallas_dense.operator_residency / SKYLARK_PALLAS_SCRATCH_CAP /
+    SKYLARK_PALLAS_VMEM_BUDGET) on the padded extents the kernel sees,
+    imported lazily so ranking can't drift from dispatch on parts whose
+    budgets were overridden. The import is cycle-safe: pallas_dense only
+    reaches tune lazily inside its dispatch functions."""
+    from libskylark_tpu.sketch.pallas_dense import (_padded_extents,
+                                                    operator_residency)
 
-    if m // m_tile <= 1:
-        return False
-    scratch_bytes = s * n * 4
-    if scratch_bytes > _SCRATCH_CAP_BYTES:
-        return False
-    return _vmem_estimate(m_tile, s, scratch_bytes) <= _VMEM_BUDGET_BYTES
+    m, n, s = w.shape
+    n_p, m_p = _padded_extents(n, m, m_tile)
+    return operator_residency(s, n_p, m_p, m_tile,
+                              rowwise=w.op != "dense_columnwise")
+
+
+def _dense_grid_steps(w: Workload, m_tile: int) -> int:
+    """Grid steps of the dense kernel: m-tiles × operator column blocks
+    (the stream format's panel width, sketch/dense.BLOCK_COLS)."""
+    from libskylark_tpu.sketch.dense import BLOCK_COLS
+
+    m, n, _s = w.shape
+    return max(1, -(-m // m_tile)) * -(-n // BLOCK_COLS)
 
 
 def plan_cost(w: Workload, p: Plan, rates: Optional[dict] = None) -> dict:
@@ -305,14 +319,25 @@ def plan_cost(w: Workload, p: Plan, rates: Optional[dict] = None) -> dict:
     m_tile = p.m_tile or 512
     precision = p.precision or "bf16x3"
     flops = 2.0 * m * n * s * MXU_PASSES[precision]
-    sweeps = 1 if _dense_operator_cached(m, n, s, m_tile) \
-        else max(1, -(-m // m_tile))
-    gen_entries = float(n * s * sweeps)
+    m_tiles = max(1, -(-m // m_tile))
+    residency = _dense_operator_residency(w, m_tile)
+    # generated once an apply when resident ("vmem"/"hbm"), once per
+    # m-tile sweep otherwise
+    gen_entries = float(n * s * (m_tiles if residency == "per_tile" else 1))
+    if residency == "hbm":
+        # the planes: written once, streamed back once per m-tile (bf16
+        # hi/lo or one f32 plane 4 B an entry, one bf16 plane 2 B)
+        plane_bytes = n * s * (2.0 if precision in ("bf16", "bf16gen2")
+                               else 4.0)
+        bytes_moved += plane_bytes * (1 + m_tiles)
+        hbm_s = bytes_moved / rates["hbm_bytes_per_s"]
     mxu_s = flops / rates["mxu_flops_per_s"]
     gen_s = gen_entries * GEN_OPS_PER_ENTRY / rates["vpu_ops_per_s"]
-    # the pipelined kernel hides generation under the matmul; the plain
-    # kernel serializes them (sketch/pallas_dense._kernel_pipe doc)
+    # the pipelined kernel is modeled as hiding generation under the
+    # matmul; the plain kernel serializes them (measured: a step costs
+    # generation plus matmul, sketch/params.py m-tile note)
     compute_s = max(mxu_s, gen_s) if p.pipeline else mxu_s + gen_s
+    compute_s += _dense_grid_steps(w, m_tile) * GRID_STEP_S
     modeled = max(hbm_s, compute_s)
     return {"flops": flops, "bytes": bytes_moved,
             "gen_entries": gen_entries, "modeled_s": modeled}

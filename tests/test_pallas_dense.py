@@ -426,13 +426,15 @@ def test_effective_plan_reports_actual_config(monkeypatch):
     # flip plan_source to "cache" — this test pins the HEURISTIC report
     monkeypatch.setattr(sketch_params, "_use_plan_cache", False)
 
-    # headline shape, requested tile fits: honored, operator too big to
-    # cache (32 MiB > cap), no pipeline without the env. The plan also
-    # names itself (plan_id/precision/plan_source — the autotuner
-    # cache's reporting surface).
-    p = pd.effective_plan(dist, (8192, 8192), jnp.float32, 1024,
+    # headline width, requested tile fits: honored; the operator is too
+    # big for the VMEM cache (16 MiB > cap) and there are 8 m-tiles, so
+    # it is generated once into HBM; no pipeline without the env. The
+    # plan also names itself (plan_id/precision/plan_source — the
+    # autotuner cache's reporting surface).
+    p = pd.effective_plan(dist, (8192, 8192), jnp.float32, 512,
                           seq_axis=1, m_tile=1024, interpret=True)
-    assert p == {"kernel": True, "m_tile": 1024, "operator_cache": False,
+    assert p == {"kernel": True, "m_tile": 1024,
+                 "operator_residency": "hbm", "operator_cache": False,
                  "pipelined": False, "precision": "bf16x3",
                  "plan_id": "pallas/mt1024/bf16x3",
                  "plan_source": "heuristic"}
@@ -442,18 +444,35 @@ def test_effective_plan_reports_actual_config(monkeypatch):
     p = pd.effective_plan(dist, (8192, 8192), jnp.float32, 1024,
                           seq_axis=1, m_tile=2048, interpret=True)
     assert p["m_tile"] < 2048
-
-    # pipeline honored only in the big-operator regime with the env set
-    monkeypatch.setenv("SKYLARK_PALLAS_PIPELINE", "1")
+    # ... as is 1024 at s_dim = 1024, which Mosaic refuses in its 16 MiB
+    # scope (17.0 MiB, on the chip, PR 27): the plan counts the matmul's
+    # result tile
     p = pd.effective_plan(dist, (8192, 8192), jnp.float32, 1024,
                           seq_axis=1, m_tile=1024, interpret=True)
+    assert p["m_tile"] == 512
+
+    # pipeline honored only where every grid step regenerates its block
+    # ("per_tile") with the env set: the columnwise big-operator regime
+    # and a single m-tile — not the rowwise headline shape, whose
+    # operator is resident in HBM
+    monkeypatch.setenv("SKYLARK_PALLAS_PIPELINE", "1")
+    p = pd.effective_plan(dist, (8192, 8192), jnp.float32, 1024,
+                          seq_axis=1, m_tile=512, interpret=True)
+    assert p["pipelined"] is False and p["operator_residency"] == "hbm"
+    p = pd.effective_plan(dist, (8192, 8192), jnp.float32, 1024,
+                          seq_axis=0, m_tile=512, interpret=True)
     assert p["pipelined"] is True and p["operator_cache"] is False
+    assert p["operator_residency"] == "per_tile"
+    p = pd.effective_plan(dist, (512, 8192), jnp.float32, 1024,
+                          seq_axis=1, m_tile=512, interpret=True)
+    assert p["pipelined"] is True and p["operator_residency"] == "per_tile"
 
     # small operator: VMEM cache engages and suppresses the pipeline
     # (cache already amortizes generation)
     p = pd.effective_plan(dist, (1024, 1024), jnp.float32, 128,
                           seq_axis=1, m_tile=256, interpret=True)
     assert p["operator_cache"] is True and p["pipelined"] is False
+    assert p["operator_residency"] == "vmem"
 
     # unsupported dtype: the apply would take the XLA fallback
     p = pd.effective_plan(dist, (1024, 1024), jnp.float64, 128,
@@ -506,3 +525,248 @@ def test_bf16gen2_regime_matches_rounded_operator_oracle():
         jlt._alloc.key, jlt.dist, Ac, s, jlt.scale,
         precision="bf16gen2", interpret=True))
     np.testing.assert_allclose(got_cw, want_cw, atol=1e-4, rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# the "hbm" operator residency: S generated once an apply into HBM as the
+# planes the regime contracts with, a contraction kernel streaming them
+# ---------------------------------------------------------------------------
+
+_DISTS = {"normal": randgen.Normal(), "cauchy": randgen.Cauchy(),
+          "rademacher": randgen.Rademacher()}
+
+
+@pytest.fixture
+def force_hbm(monkeypatch):
+    """At test sizes S fits the VMEM operator cache; a zero cap sends every
+    rowwise apply with more than one m-tile to the "hbm" residency."""
+    monkeypatch.setattr(pd, "_SCRATCH_CAP_BYTES", 0)
+
+
+def _panel64(key, dist, s, n):
+    """The stream's definition of S (s × n), unit scale, as float64."""
+    n_p = -(-n // BLOCK_COLS) * BLOCK_COLS
+    unit = randgen.dense_panel(key, dist, s, 0, n_p, BLOCK_COLS, jnp.float32)
+    return np.asarray(unit, np.float64)[:, :n]
+
+
+def _assert_hbm(dist, shape, s, m_tile):
+    plan = pd.effective_plan(dist, shape, jnp.float32, s, 1, m_tile=m_tile,
+                             interpret=True)
+    assert plan["operator_residency"] == "hbm", plan
+    assert plan["operator_cache"] is False and plan["pipelined"] is False
+
+
+@pytest.mark.parametrize("shape", [(64, 512), (50, 1000)],
+                         ids=["aligned", "ragged"])
+@pytest.mark.parametrize("precision", ["bf16x3", "f32"])
+@pytest.mark.parametrize("kind", sorted(_DISTS))
+def test_hbm_residency_matches_oracle(kind, precision, shape, force_hbm):
+    """The two-kernel path against a float64 gemm with the stream's own
+    operator, at the framework's 1e-4 (relative to the largest output:
+    Cauchy entries are heavy-tailed)."""
+    m, n = shape
+    s, dist = 96, _DISTS[kind]
+    scale = 1.0 / np.sqrt(s)
+    key = Context(seed=31).allocate().key
+    A = jnp.asarray(
+        np.random.default_rng(11).standard_normal((m, n)), jnp.float32)
+    _assert_hbm(dist, shape, s, 16)
+    got = pd.rowwise_apply(key, dist, A, s, scale, m_tile=16,
+                           precision=precision, interpret=True)
+    assert got is not None and got.shape == (m, s)
+    want = np.asarray(A, np.float64) @ (scale * _panel64(key, dist, s, n)).T
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4,
+                               atol=1e-4 * float(np.abs(want).max()))
+
+
+@pytest.mark.parametrize("scale", [2.0 ** -5, 1.0 / np.sqrt(96.0), None],
+                         ids=["dyadic", "nondyadic", "unscaled"])
+@pytest.mark.parametrize("kind", sorted(_DISTS))
+def test_hbm_planes_hold_the_scaled_stream(kind, scale):
+    """The planes themselves: ``hi`` is bit-equal to bf16(scale·S) with S
+    the stream's panel, ``hi + lo`` within 2⁻¹⁶ relative of scale·S; the
+    one-plane regimes store scale·S (f32), bf16(scale·S) (bf16) and the
+    bf16 rounding of the UNIT stream (bf16gen2, whose scale finishes the
+    tile instead)."""
+    s, n_blocks, dist = 48, 3, _DISTS[kind]
+    key = Context(seed=32).allocate().key
+    keys = pd._block_keys(key, n_blocks * BLOCK_COLS)
+    unit = randgen.dense_panel(key, dist, s, 0, n_blocks * BLOCK_COLS,
+                               BLOCK_COLS, jnp.float32)
+    want = unit if scale is None else unit * jnp.float32(scale)
+
+    def planes(precision):
+        return pd._operator_planes(keys, scale, s_dim=s, dist_kind=kind,
+                                   precision=precision, interpret=True)
+
+    hi, lo = planes("bf16x3")
+    assert hi.dtype == lo.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(hi.astype(jnp.float32)),
+                                  np.asarray(want.astype(jnp.bfloat16)
+                                             .astype(jnp.float32)))
+    both = np.asarray(hi, np.float64) + np.asarray(lo, np.float64)
+    want64 = np.asarray(want, np.float64)
+    assert np.all(np.abs(both - want64) <= 2.0 ** -16 * np.abs(want64))
+    (full,) = planes("f32")
+    np.testing.assert_array_equal(np.asarray(full), np.asarray(want))
+    (single,) = planes("bf16")
+    np.testing.assert_array_equal(np.asarray(single.astype(jnp.float32)),
+                                  np.asarray(hi.astype(jnp.float32)))
+    (rounded,) = planes("bf16gen2")
+    np.testing.assert_array_equal(
+        np.asarray(rounded.astype(jnp.float32)),
+        np.asarray(unit.astype(jnp.bfloat16).astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("precision", ["bf16x3", "f32", "bf16", "bf16gen2"])
+def test_hbm_equals_per_tile_at_dyadic_scale(precision, monkeypatch,
+                                             force_hbm):
+    """Same input, same tile, the operator resident in HBM against
+    regenerated per tile: with a power-of-two scale the scaled planes are
+    the unit planes shifted, every product and sum scales exactly, and
+    the two agree to float32 rounding of the accumulation."""
+    m, n, s = 64, 768, 64
+    scale = 2.0 ** -3                       # = 1/√64
+    dist = randgen.Normal()
+    key = Context(seed=33).allocate().key
+    A = jnp.asarray(
+        np.random.default_rng(12).standard_normal((m, n)), jnp.float32)
+    _assert_hbm(dist, (m, n), s, 16)
+    kw = dict(m_tile=16, precision=precision, interpret=True)
+    resident = np.asarray(pd.rowwise_apply(key, dist, A, s, scale, **kw))
+    monkeypatch.setattr(pd, "operator_residency",
+                        lambda *a, **k: "per_tile")
+    jax.clear_caches()      # the residency is resolved when the call traces
+    per_tile = np.asarray(pd.rowwise_apply(key, dist, A, s, scale, **kw))
+    jax.clear_caches()
+    np.testing.assert_allclose(resident, per_tile, rtol=2e-6,
+                               atol=2e-6 * float(np.abs(per_tile).max()))
+
+
+@pytest.mark.parametrize("shape", [(24, 512), (13, 300)],
+                         ids=["aligned", "ragged"])
+@pytest.mark.parametrize("precision", ["bf16x3", "f32"])
+def test_hbm_rft_cos_epilogue(shape, precision, force_hbm):
+    """The cos featurization finishes the tile of the contraction kernel
+    as it finishes the generating kernel's (inscale/outscale stay in the
+    epilogue: the RFT planes hold the unit stream)."""
+    from libskylark_tpu.sketch.rft import GaussianRFT
+
+    m, n = shape
+    s = 64
+    T = GaussianRFT(n, s, Context(seed=34), sigma=2.0)
+    A = jnp.asarray(
+        np.random.default_rng(13).standard_normal((m, n)), jnp.float32)
+    _assert_hbm(T.dist, shape, s, 8)
+    want = np.asarray(T.apply(A, ROWWISE))      # XLA path (fixture)
+    got = pd.rft_rowwise_apply(
+        T.subkey(0), T.dist, A, s, T.inscale, T.outscale,
+        np.asarray(T.row_scales()), np.asarray(T.shifts()),
+        m_tile=8, precision=precision, interpret=True)
+    assert got is not None
+    np.testing.assert_allclose(np.asarray(got), want, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("precision", ["bf16x3", "f32"])
+def test_hbm_fused_partial_rowwise(precision, force_hbm):
+    """``fused_partial(seq_axis=1)`` — the shard_map pipeline's per-device
+    body — stays UNSCALED under the "hbm" residency (the caller scales
+    after its psum), against the blocks its key slice names."""
+    m, n, s = 40, 1024, 32
+    dist = randgen.Normal()
+    key = Context(seed=35).allocate().key
+    keys = pd._block_keys(key, n)
+    A = jnp.asarray(
+        np.random.default_rng(14).standard_normal((m, n)), jnp.float32)
+    _assert_hbm(dist, (m, n), s, 8)
+    # a device's shard: the second half of the blocks
+    half = n // 2
+    got = pd.fused_partial(keys[half // BLOCK_COLS:], dist, A[:, half:], s,
+                           seq_axis=1, m_tile=8, precision=precision,
+                           interpret=True)
+    assert got is not None and got.shape == (m, s)
+    want = (np.asarray(A, np.float64)[:, half:]
+            @ _panel64(key, dist, s, n)[:, half:].T)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4,
+                               atol=1e-4 * float(np.abs(want).max()))
+
+
+@pytest.mark.parametrize("shape", [(32, 2048), (21, 700)],
+                         ids=["aligned", "ragged"])
+def test_hbm_bf16gen2_matches_rounded_operator_oracle(shape, force_hbm):
+    """"bf16gen2" keeps its definition under "hbm": the operator is
+    scale × the bf16 rounding of the UNIT stream (s = 96: non-dyadic
+    scale, so rounding the scaled panel would be a different operator),
+    the data side split hi/lo, the scale applied to the finished tile."""
+    m, n = shape
+    s = 96
+    jlt = JLT(n, s, Context(seed=10))
+    A = jnp.asarray(
+        np.random.default_rng(5).standard_normal((m, n)), jnp.float32)
+    _assert_hbm(jlt.dist, shape, s, 8)
+    unit = _panel64(jlt._alloc.key, jlt.dist, s, n)
+    S_rounded = jlt.scale * np.asarray(
+        jnp.asarray(unit, jnp.float32).astype(jnp.bfloat16), np.float64)
+    want = np.asarray(A, np.float64) @ S_rounded.T
+    got = np.asarray(pd.rowwise_apply(
+        jlt._alloc.key, jlt.dist, A, s, jlt.scale, m_tile=8,
+        precision="bf16gen2", interpret=True))
+    np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize(
+    "s_dim,n,m,m_tile,rowwise,want",
+    [(1024, 8192, 65536, 512, True, "hbm"),       # the cell's shape
+     (1024, 8192, 65536, 512, False, "per_tile"),  # columnwise: as before
+     (1024, 8192, 512, 512, True, "per_tile"),     # one m-tile: no reuse
+     (128, 1024, 1024, 256, True, "vmem"),         # small S: VMEM cache
+     (128, 1024, 1024, 256, False, "vmem")],
+    ids=["cell", "columnwise", "single_tile", "small_rw", "small_cw"])
+def test_operator_residency_rule(s_dim, n, m, m_tile, rowwise, want):
+    assert pd.operator_residency(s_dim, n, m, m_tile, rowwise) == want
+
+
+def test_pipelined_rowwise_single_tile_matches_plain(monkeypatch):
+    """The rowwise pipelined kernel engages only under "per_tile" — here
+    a single m-tile — and must equal the plain kernel bit for bit."""
+    m, n, s = 16, 1024, 96
+    jlt = JLT(n, s, Context(seed=21))
+    A = jnp.asarray(
+        np.random.default_rng(9).standard_normal((m, n)), jnp.float32)
+    kw = dict(m_tile=16, precision="f32", interpret=True)
+    monkeypatch.delenv("SKYLARK_PALLAS_PIPELINE", raising=False)
+    plain = np.asarray(pd.rowwise_apply(
+        jlt._alloc.key, jlt.dist, A, s, jlt.scale, **kw))
+    monkeypatch.setenv("SKYLARK_PALLAS_PIPELINE", "1")
+    plan = pd.effective_plan(jlt.dist, (m, n), jnp.float32, s, 1,
+                             m_tile=16, interpret=True)
+    assert plan["pipelined"] and plan["operator_residency"] == "per_tile"
+    jax.clear_caches()
+    piped = np.asarray(pd.rowwise_apply(
+        jlt._alloc.key, jlt.dist, A, s, jlt.scale, **kw))
+    jax.clear_caches()
+    np.testing.assert_array_equal(piped, plain)
+
+
+@pytest.mark.tpu
+@pytest.mark.skipif(not ON_TPU, reason="needs a real TPU backend")
+def test_fused_on_chip_hbm_residency_at_the_cell_shape():
+    """The benchmark cell's shape, Mosaic-compiled: 65536 × 8192 → 1024
+    at the shipping regime takes the "hbm" residency; 256 sampled rows
+    against A_rows·Sᵀ at 1e-4; a second apply is bit-equal to the first
+    (every apply regenerates the operator, nothing is carried over)."""
+    m, n, s = 65536, 8192, 1024
+    jlt = JLT(n, s, Context(seed=27))
+    A = jax.random.normal(jax.random.key(27), (m, n), jnp.float32)
+    plan = pd.effective_plan(jlt.dist, A.shape, A.dtype, s, 1)
+    assert plan["operator_residency"] == "hbm", plan
+    first = pd.rowwise_apply(jlt._alloc.key, jlt.dist, A, s, jlt.scale)
+    assert first is not None
+    rows = np.sort(np.random.default_rng(27).choice(m, 256, replace=False))
+    S = jlt.scale * _panel64(jlt._alloc.key, jlt.dist, s, n)
+    want = np.asarray(A[rows], np.float64) @ S.T
+    np.testing.assert_allclose(np.asarray(first[rows]), want, rtol=1e-4,
+                               atol=1e-4 * float(np.abs(want).max()))
+    second = pd.rowwise_apply(jlt._alloc.key, jlt.dist, A, s, jlt.scale)
+    assert bool(jnp.array_equal(first, second))

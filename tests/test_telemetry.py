@@ -561,8 +561,34 @@ class TestHotPathSpans:
             assert whats == ["allocation", "block_table"]
             assert root.attrs["plan_source"] in ("cache", "heuristic")
             assert root.attrs["m_tile"] >= 8 and root.attrs["precision"]
+            # one m-tile at this shape: nothing to keep between tiles
+            assert root.attrs["operator_residency"] == "per_tile"
         else:
             assert whats == ["allocation", "allocation"]
+            assert "operator_residency" not in root.attrs
+
+    @pytest.mark.parametrize("cap,residency", [(0, "hbm"), (None, "vmem")])
+    def test_apply_span_names_the_operator_residency(self, cap, residency,
+                                                     monkeypatch):
+        """``sketch.apply`` says where the kernel kept the generated
+        operator between m-tiles — what the effective plan reports."""
+        from libskylark_tpu.sketch import pallas_dense
+        from libskylark_tpu.sketch import params as sketch_params
+
+        _interpreted_kernel(monkeypatch)
+        if cap is not None:
+            monkeypatch.setattr(pallas_dense, "_SCRATCH_CAP_BYTES", cap)
+        monkeypatch.setattr(sketch_params, "_pallas_m_tile", 8)
+        T = sk.JLT(512, 64, Context(5))
+        A = _apply_operand()
+        telemetry.set_enabled(True)
+        T.apply(A, sk.ROWWISE).block_until_ready()
+        root = _one(telemetry.finished_spans(), "sketch.apply")
+        assert root.attrs["m_tile"] == 8
+        assert root.attrs["operator_residency"] == residency
+        assert pallas_dense.effective_plan(
+            T.dist, A.shape, A.dtype, 64, 1,
+            interpret=True)["operator_residency"] == residency
 
     def test_apply_under_jit_opens_no_span(self):
         import jax

@@ -86,9 +86,10 @@ class TestCostRanking:
 
     def test_mtile_ordering(self):
         """With zero TPU access, the offline ranking orders the m-tiles
-        (256, 512 at the bf16x3 non-pipelined regime) the way the
-        tuning-knob analysis (sketch/params.py) does: 512 over 256.
-        Not measured on today's kernel."""
+        (256, 512 at the bf16x3 non-pipelined regime) the way the chip
+        does (sketch/params.py m-tile note; 23.4 against 24.8 ms at the
+        cell's shape, PR 27): 512 over 256 — with the operator resident
+        by the grid steps and the plane re-reads alone."""
         w = _flagship_workload()
         ranked = [p.plan_id() for p, _ in tune.rank_candidates(w)]
         i512 = ranked.index("pallas/mt512/bf16x3")
@@ -118,6 +119,56 @@ class TestCostRanking:
         # chain (BASELINE.md crossover) must order the backends
         assert ranked.index("fused/bf16x3") \
             < ranked.index("split/bf16x3") < ranked.index("xla_chain")
+
+
+class TestOperatorResidencyOnePredicate:
+    """Where the generated operator lives between m-tiles is decided in
+    ONE place (``pallas_dense.operator_residency``); the cost model's
+    generation count, the reported plan and the kernel call that is
+    traced all read it, so they cannot disagree."""
+
+    @pytest.mark.parametrize(
+        "shape,s,m_tile,seq_axis,want",
+        [((65536, 8192), 1024, 512, 1, "hbm"),       # the benchmark cell
+         ((1024, 1024), 128, 256, 1, "vmem"),        # small S: VMEM cache
+         ((512, 8192), 1024, 512, 1, "per_tile"),    # one m-tile
+         ((8192, 65536), 1024, 512, 0, "per_tile")],  # columnwise big S
+        ids=["headline_hbm", "small_vmem", "single_tile", "columnwise"])
+    def test_cost_plan_and_kernel_agree(self, shape, s, m_tile, seq_axis,
+                                        want, monkeypatch):
+        import functools
+
+        monkeypatch.setattr(sketch_params, "_use_plan_cache", False)
+        monkeypatch.delenv("SKYLARK_PALLAS_PIPELINE", raising=False)
+        n, m = shape[seq_axis], shape[1 - seq_axis]
+        m_tiles = m // m_tile
+        assert pd.operator_residency(s, n, m, m_tile,
+                                     rowwise=seq_axis == 1) == want
+        # reader 1: the reported plan
+        plan = pd.effective_plan(randgen.Normal(), shape, jnp.float32, s,
+                                 seq_axis, m_tile=m_tile, interpret=True)
+        assert plan["m_tile"] == m_tile
+        assert plan["operator_residency"] == want
+        assert plan["operator_cache"] is (want == "vmem")
+        # reader 2: the cost model generates once when resident
+        w = tune.dense_workload("normal", shape, "float32", s, seq_axis,
+                                device_kind="tpu_v5_lite")
+        cost = tune.plan_cost(w, tune.Plan("pallas", m_tile, "bf16x3"))
+        assert cost["gen_entries"] == float(
+            n * s * (m_tiles if want == "per_tile" else 1))
+        a_and_out = 4.0 * (m * n + m * s)
+        assert cost["bytes"] == a_and_out + (
+            4.0 * n * s * (1 + m_tiles) if want == "hbm" else 0.0)
+        # reader 3: the call that is traced — a generation call plus a
+        # contraction call under "hbm", one fused call otherwise
+        call = pd._fused_call if seq_axis == 1 else pd._fused_call_cw
+        traced = jax.make_jaxpr(functools.partial(
+            call, s_dim=s, dist_kind="normal", m_tile=m_tile,
+            precision="bf16x3", interpret=True))(
+                jax.ShapeDtypeStruct(shape, jnp.float32),
+                jax.ShapeDtypeStruct((n // 256, 2), jnp.uint32))
+        assert str(traced).count("pallas_call") == (2 if want == "hbm"
+                                                    else 1)
 
 
 class TestPlanCacheDisk:
@@ -344,21 +395,30 @@ class TestDispatchConsultsCache:
             self, injected_cache, monkeypatch):
         """SKYLARK_PALLAS_PIPELINE=0 must beat a cached pipeline=True
         plan (the escape hatch when a cached pipelined plan
-        misbehaves); =1 still engages it without any plan."""
+        misbehaves); =1 still engages it without any plan. Columnwise:
+        the orientation whose big operator is regenerated per tile —
+        the rowwise one keeps it in HBM and has nothing to pipeline,
+        whatever plan is cached."""
         big = (4096, 4096)
-        w = tune.dense_workload("normal", big, jnp.dtype("float32"),
-                                1024, 1)
-        injected_cache.put(w, tune.Plan("pallas", 512, "bf16x3",
-                                        pipeline=True),
-                           source="measured", value=1.0)
+        for seq_axis in (0, 1):
+            injected_cache.put(
+                tune.dense_workload("normal", big, jnp.dtype("float32"),
+                                    1024, seq_axis),
+                tune.Plan("pallas", 512, "bf16x3", pipeline=True),
+                source="measured", value=1.0)
+
+        def plan(seq_axis):
+            return pd.effective_plan(randgen.Normal(), big, jnp.float32,
+                                     1024, seq_axis, interpret=True)
+
         monkeypatch.delenv("SKYLARK_PALLAS_PIPELINE", raising=False)
-        plan = pd.effective_plan(randgen.Normal(), big, jnp.float32,
-                                 1024, 1, interpret=True)
-        assert plan["pipelined"] is True          # plan decides
+        assert plan(0)["pipelined"] is True       # plan decides
+        assert plan(0)["operator_residency"] == "per_tile"
+        assert plan(1)["pipelined"] is False      # resident: nothing to hide
+        assert plan(1)["operator_residency"] == "hbm"
+        assert plan(1)["plan_source"] == "cache"
         monkeypatch.setenv("SKYLARK_PALLAS_PIPELINE", "0")
-        plan = pd.effective_plan(randgen.Normal(), big, jnp.float32,
-                                 1024, 1, interpret=True)
-        assert plan["pipelined"] is False         # env=0 wins
+        assert plan(0)["pipelined"] is False      # env=0 wins
 
     def test_gate_disables_consultation(self, injected_cache):
         injected_cache.put(self._workload(),
