@@ -189,6 +189,7 @@ def step_sketch() -> None:
 
     from libskylark_tpu import Context, SparseMatrix, tune
     from libskylark_tpu import sketch as sk
+    from libskylark_tpu.sketch import hash as sk_hash
     from libskylark_tpu.sketch import pallas_dense as pd
     from libskylark_tpu.sketch import pallas_fastfood as pff
 
@@ -308,11 +309,13 @@ def step_sketch() -> None:
     ref = np.zeros((S, M), np.float32)
     np.add.at(ref, h, v[:, None] * Asp.toarray())
     Sm = SparseMatrix.from_scipy(Asp)
+    program = sk_hash._sparse_program()     # one compiled XLA program an apply
+    ran = program.stats.executions
     out, first, run = timed(lambda: C.apply(Sm, sk.COLUMNWISE))
-    out = out.todense() if hasattr(out, "todense") else out
     err = close(out, ref, "CWT sparse vs host scatter")
     report("sketch.CWT.sparse", first, run, nnz=Asp.nnz,
-           backend="xla", err=f"{err:.2e}")
+           backend=(f"xla:{program.name}" if program.stats.executions > ran
+                    else "xla:eager"), err=f"{err:.2e}")
 
     # feature maps at BASELINE.md's shape, dense RFT and Fastfood, each
     # against its explicit operator on the host for some rows

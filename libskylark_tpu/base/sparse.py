@@ -5,17 +5,20 @@ a CSC container with zero-copy attach from scipy buffers, duplicate-summing
 COO construction (ref: set():136), transpose (ref: Transpose:303) and
 read-only column views (ref: view:256).
 
-The device-side representation is COO triplets — on TPU, sparse×dense
-products are dataflow ``segment_sum`` contractions over the nonzeros (the
-XLA-friendly formulation of the reference's CSC scatter loops,
-ref: base/Gemm.hpp:335-519), so the CSC column pointers stay host-side and
-the (row, col, value) arrays are what lands in HBM. All nnz-shaped arrays
-have static shapes, so products are jittable.
+On the device a matrix is placed once per value dtype, as row-major CSR
+lanes (:meth:`SparseMatrix.csr_device`: data, column ids, row pointers,
+the nnz extent zero-padded to its ``engine.bucket.lane_class``) — what the
+compiled sparse hash sketch scatters from — or as the row-major COO triplets
+(:meth:`SparseMatrix.coo`) the sparse×dense products contract over
+(``segment_sum`` over the nonzeros, the XLA-friendly formulation of the
+reference's CSC scatter loops, ref: base/Gemm.hpp:335-519), the row ids
+expanded on the device. All nnz-shaped arrays have static shapes, so
+products are jittable.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,11 +28,12 @@ from libskylark_tpu.base import errors
 
 
 class SparseMatrix:
-    """Immutable local sparse matrix, CSC on host, COO on device.
+    """Immutable local sparse matrix, CSC on host, row-major on device.
 
     Construction never copies the supplied numpy buffers (the reference's
     external-ownership ``attach`` semantics, ref: base/sparse_matrix.hpp:82);
-    device placement happens lazily on first ``coo()``.
+    device placement happens lazily, once per value dtype, at the first
+    ``csr_device()`` or ``coo()``.
     """
 
     def __init__(
@@ -50,7 +54,9 @@ class SparseMatrix:
             )
         if len(self._rowind) != len(self._values):
             raise errors.InvalidParametersError("rowind/values length mismatch")
-        self._coo_cache: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None
+        # device-resident layouts by value dtype: {"csr": the placed
+        # lanes, "coo": the triplets derived from them on first coo()}
+        self._device: dict = {}
 
     # -- constructors --
 
@@ -162,26 +168,57 @@ class SparseMatrix:
 
     # -- conversions --
 
+    def _device_dtype_of(self, dtype):
+        return jax.dtypes.canonicalize_dtype(
+            np.dtype(dtype) if dtype is not None else self.device_dtype)
+
+    def _place_lanes(self, eff, extent: int):
+        """:meth:`csr_parts` on the device, the nnz lanes zero-padded to
+        ``extent``."""
+        data, indices, indptr = self.csr_parts(eff)
+        pad = extent - self.nnz
+        return (_place(np.pad(data, (0, pad))),
+                _place(np.pad(indices, (0, pad))), _place(indptr))
+
+    def csr_device(self, dtype=None) -> Tuple[jax.Array, jax.Array,
+                                              jax.Array]:
+        """Device-resident row-major lanes ``(data, indices, indptr)``:
+        :meth:`csr_parts` with the nnz extent of ``data``/``indices``
+        zero-padded to its ``engine.bucket.lane_class`` (value 0.0 at
+        column 0: exact zeros through every sparse endpoint; under a
+        sixteenth of the lanes), ``indptr`` exact, (height + 1,) int32.
+        Placed on the first call for a value dtype and kept: later calls
+        move nothing from the host. Row blocks whose nnz fall in one class
+        present one shape to a compiled program."""
+        eff = self._device_dtype_of(dtype)
+        layouts = self._device.setdefault(eff, {})
+        if "csr" not in layouts:
+            from libskylark_tpu.engine.bucket import lane_class
+
+            layouts["csr"] = self._place_lanes(eff, lane_class(self.nnz))
+        return layouts["csr"]
+
     def coo(self, dtype=None) -> Tuple[jax.Array, jax.Array, jax.Array]:
-        """Device COO triplets (rows, cols, vals); cached per resolved dtype.
+        """Device COO triplets (rows, cols, vals) in row-major order;
+        cached per resolved dtype. The row ids are expanded on the device
+        from the row pointers (no host ``repeat``). Where
+        :meth:`csr_device`'s lanes are resident the columns and values are
+        their leading ``nnz``; else the exact lanes are placed for this,
+        and the triplets *are* those arrays — a product's operand holds
+        12 B a nonzero on the device and no second layout.
 
         ``dtype=None`` always resolves to :meth:`device_dtype` (the f32
         precision-policy default) — a cache left behind by an explicit-dtype
         call is never returned for a default-dtype request."""
-        eff = jax.dtypes.canonicalize_dtype(
-            np.dtype(dtype) if dtype is not None else self.device_dtype
-        )
-        if self._coo_cache is None or self._coo_cache[2].dtype != eff:
-            counts = np.diff(self._colptr)
-            cols = np.repeat(
-                np.arange(self.width, dtype=np.int32), counts
-            )
-            self._coo_cache = (
-                jnp.asarray(self._rowind),
-                jnp.asarray(cols),
-                jnp.asarray(self._values, dtype=eff),
-            )
-        return self._coo_cache
+        eff = self._device_dtype_of(dtype)
+        layouts = self._device.setdefault(eff, {})
+        if "coo" not in layouts:
+            data, indices, indptr = (layouts.get("csr")
+                                     or self._place_lanes(eff, self.nnz))
+            if data.shape[0] != self.nnz:
+                data, indices = data[:self.nnz], indices[:self.nnz]
+            layouts["coo"] = (_row_ids(indptr, nnz=self.nnz), indices, data)
+        return layouts["coo"]
 
     def csr_parts(self, dtype=None) -> Tuple[np.ndarray, np.ndarray,
                                              np.ndarray]:
@@ -241,6 +278,32 @@ class SparseMatrix:
             f"SparseMatrix({self.height}x{self.width}, nnz={self.nnz}, "
             f"dtype={self.dtype})"
         )
+
+
+def _place(x: np.ndarray) -> jax.Array:
+    """Host buffer → device array: the one place a ``SparseMatrix`` crosses
+    to the device."""
+    return jnp.asarray(x)
+
+
+def csr_row_ids(indptr, nnz_pad: int) -> jnp.ndarray:
+    """Expand a (rows+1,) CSR ``indptr`` into per-nonzero row ids for
+    the leading ``nnz_pad`` lane positions (int32): the row of position
+    j is the number of interior row ends at or before j. Positions past
+    the true nnz (the lane padding; ``indptr`` is monotone-padded with
+    nnz) come out as the last row — their data is 0.0, so that target
+    accumulates exact zeros. Jittable: one scatter of the row ends and a
+    running sum over the static lane extent (a ``searchsorted`` over the
+    lanes gives the same ids and takes 2.8 s for 16.8 M lanes on a v5e
+    against 7 ms, PERF.md PR 28)."""
+    ends = jnp.zeros((nnz_pad,), jnp.int32).at[indptr[1:-1]].add(
+        1, indices_are_sorted=True)     # monotone row pointers: XLA adds no
+                                        # sort (12 s of compile at 19 M lanes)
+    return jnp.cumsum(ends, dtype=jnp.int32)
+
+
+_row_ids = jax.jit(lambda indptr, *, nnz: csr_row_ids(indptr, nnz),
+                   static_argnames=("nnz",))
 
 
 def is_sparse_operand(A) -> bool:

@@ -71,6 +71,24 @@ def nnz_class(nnz: int, floor: int = 64) -> int:
     return pow2_pad(nnz, max(int(floor), 1))
 
 
+def lane_class(nnz: int) -> int:
+    """The lane extent of a **device-resident** sparse operand
+    (``SparseMatrix.csr_device``): max(nnz, 64) rounded up to a
+    thirty-second of its power of two, so under a sixteenth of the lanes
+    are padding and an octave holds sixteen classes. Row blocks of one
+    corpus (nnz within a few percent of each other) share one compiled
+    program, as they would under :func:`nnz_class`; but a resident
+    operand pays for every lane on every apply, and pow2 padding is up to
+    half of them: 19.4 M nonzeros → 2²⁵ lanes, 674 ms an apply on a v5e
+    against 379 ms at this class and 369 ms unpadded, which compiles anew
+    for every operand (PERF.md PR 28). The serve tier keeps
+    :func:`nnz_class`: there a class is a bucket requests coalesce in,
+    and fewer, wider classes are the point."""
+    n = max(int(nnz), 64)
+    granule = pow2_pad(n) >> 5
+    return -(-n // granule) * granule
+
+
 def capacity_class(k: int, max_batch: int, multiple: int = 1) -> int:
     """Batch capacity for a cohort of ``k`` requests: pow2 ≥ k, clamped
     to ``max_batch``, then rounded up to ``multiple`` (the mesh device

@@ -9,14 +9,27 @@ The transform is defined by two virtual streams over the allocation key:
 ``row_value`` — a per-coordinate scaling (Rademacher for CWT, Cauchy for MMT,
 signed reciprocal-exponential for WZT). Where the reference applies these with
 O(nnz) CSC scatter loops (ref: sketch/hash_transform_Elemental.hpp:83-124),
-the TPU-native formulation is a ``segment_sum`` — a dataflow scatter-add XLA
-maps onto the VPU, and which under a sharded input becomes a local
-segment-sum + psum exactly like the reference's local-accumulate + all_reduce
-pattern (ref: sketch/hash_transform_Elemental.hpp:427-607).
+the TPU-native formulation of a dense operand's apply is a ``segment_sum`` —
+a dataflow scatter-add XLA maps onto the VPU, and which under a sharded input
+becomes a local segment-sum + psum exactly like the reference's
+local-accumulate + all_reduce pattern
+(ref: sketch/hash_transform_Elemental.hpp:427-607).
+
+A :class:`~libskylark_tpu.base.sparse.SparseMatrix` operand runs one compiled
+program an apply (``engine.compiled``, name ``sketch.hash_sparse``): the pure
+function the sparse serve flush vmaps
+(:func:`libskylark_tpu.sketch.sparse_serve.cwt_sparse_serve_apply`) on the
+operand's device-resident row-major lanes — both streams generated inside
+the executable from the allocation's key data, a gather of bucket and value
+at each stored nonzero's coordinate, and one O(nnz) scatter-add in row-major
+order, which is the order the dense ``segment_sum`` retires the same terms
+in: the result is bit-equal to ``apply(A.todense())``
+(ref: sketch/hash_transform_local_sparse.hpp:12-152).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -24,6 +37,44 @@ import jax.numpy as jnp
 
 from libskylark_tpu.base import errors, randgen
 from libskylark_tpu.sketch.transform import SketchTransform, register
+from libskylark_tpu.telemetry import metrics as _metrics
+from libskylark_tpu.telemetry import trace as _trace
+
+_SPARSE_NNZ = _metrics.counter(
+    "sketch.sparse_nnz",
+    "Stored nonzeros sketched through the compiled sparse hash apply, "
+    "by family")
+
+
+def value_stream(kind: tuple, key, n: int, dtype) -> jnp.ndarray:
+    """v[0:n] of the hash family ``kind`` — ``("CWT",)``, ``("MMT",)`` or
+    ``("WZT", p)`` — under the allocation key ``key``: a pure function,
+    shared by the transforms' own ``values()`` and the compiled sparse
+    program (where ``kind`` is a static argument)."""
+    def stream(tag, dist):
+        return randgen.stream_slice(jax.random.fold_in(key, tag), dist, 0, n,
+                                    dtype=dtype)
+
+    if kind[0] == "CWT":
+        return stream(1, randgen.Rademacher())
+    if kind[0] == "MMT":
+        return stream(1, randgen.Cauchy())
+    if kind[0] == "WZT":
+        e, pm = stream(1, randgen.Exponential()), stream(2, randgen.Rademacher())
+        return pm * jnp.power(1.0 / e, 1.0 / kind[1])
+    raise errors.InvalidParametersError(f"no hash value stream {kind!r}")
+
+
+@functools.lru_cache(maxsize=None)
+def _sparse_program():
+    """The compiled sparse apply, built at the first sparse operand so that
+    importing the sketch layer never pulls the engine."""
+    from libskylark_tpu.engine.compiled import compiled
+    from libskylark_tpu.sketch.sparse_serve import cwt_sparse_serve_apply
+
+    return compiled(
+        cwt_sparse_serve_apply, name="sketch.hash_sparse",
+        static_argnames=("s_dim", "rowwise", "shape", "values"))
 
 
 def cwt_serve_apply(key_data, A, *, s_dim: int, rowwise: bool) -> jnp.ndarray:
@@ -56,10 +107,6 @@ class HashTransform(SketchTransform):
 
     sketch_type = "HashTransform"
 
-    def _value_stream(self, dtype) -> jnp.ndarray:
-        """Per-coordinate scaling values v[0:N]; overridden per transform."""
-        raise NotImplementedError
-
     def bucket_indices(self) -> jnp.ndarray:
         """h[0:N] — bucket of each input coordinate (sub-stream 0)."""
         return randgen.stream_slice(
@@ -67,8 +114,14 @@ class HashTransform(SketchTransform):
             dtype=jnp.int32,
         )
 
+    def _value_kind(self) -> tuple:
+        """Static description of the value stream (``value_stream``)."""
+        return (self.sketch_type,)
+
     def values(self, dtype=jnp.float32) -> jnp.ndarray:
-        return self._value_stream(dtype)
+        """v[0:N] — per-coordinate scaling (sub-streams 1, 2)."""
+        return value_stream(self._value_kind(), self._alloc.key, self._N,
+                            dtype)
 
     def _apply_columnwise(self, A: jnp.ndarray) -> jnp.ndarray:
         out = self._try_kernel(A, rowwise=False)
@@ -96,22 +149,26 @@ class HashTransform(SketchTransform):
 
         return pallas_hash.try_apply(self, A, rowwise=rowwise)
 
-    # -- sparse input: O(nnz) scatter-add over COO triplets (the dataflow
-    # form of ref: sketch/hash_transform_local_sparse.hpp:12-152) --
+    # -- sparse input: one compiled O(nnz) scatter program an apply (the
+    # dataflow form of ref: sketch/hash_transform_local_sparse.hpp:12-152) --
+
+    def _apply_sparse(self, A, *, rowwise: bool) -> jnp.ndarray:
+        data, indices, indptr = A.csr_device()
+        key_data = jax.random.key_data(self._alloc.key)
+        with _trace.span("sketch.dispatch",
+                         {"path": "sparse", "family": self.sketch_type,
+                          "nnz": A.nnz, "nnz_class": int(data.shape[0])}):
+            out = _sparse_program()(
+                key_data, data, indices, indptr, s_dim=self._S,
+                rowwise=rowwise, shape=A.shape, values=self._value_kind())
+        _SPARSE_NNZ.inc_always(A.nnz, family=self.sketch_type)
+        return out
 
     def _apply_columnwise_sparse(self, A) -> jnp.ndarray:
-        r, c, v = A.coo()
-        h = self.bucket_indices()
-        vs = self.values(v.dtype)
-        out = jnp.zeros((self._S, A.width), v.dtype)
-        return out.at[h[r], c].add(vs[r] * v)
+        return self._apply_sparse(A, rowwise=False)
 
     def _apply_rowwise_sparse(self, A) -> jnp.ndarray:
-        r, c, v = A.coo()
-        h = self.bucket_indices()
-        vs = self.values(v.dtype)
-        out = jnp.zeros((A.height, self._S), v.dtype)
-        return out.at[r, h[c]].add(vs[c] * v)
+        return self._apply_sparse(A, rowwise=True)
 
     # -- distributed sparse input (P4/P5): local scatter + psum (ref:
     # sketch/hash_transform_CombBLAS.hpp:16-632) --
@@ -177,11 +234,6 @@ class CWT(HashTransform):
 
     sketch_type = "CWT"
 
-    def _value_stream(self, dtype):
-        return randgen.stream_slice(
-            self.subkey(1), randgen.Rademacher(), 0, self._N, dtype=dtype
-        )
-
 
 @register
 class MMT(HashTransform):
@@ -189,11 +241,6 @@ class MMT(HashTransform):
     (ref: sketch/MMT_data.hpp:22-60)."""
 
     sketch_type = "MMT"
-
-    def _value_stream(self, dtype):
-        return randgen.stream_slice(
-            self.subkey(1), randgen.Cauchy(), 0, self._N, dtype=dtype
-        )
 
 
 @register
@@ -215,14 +262,8 @@ class WZT(HashTransform):
         self._p = float(p)
         super().__init__(N, S, context)
 
-    def _value_stream(self, dtype):
-        e = randgen.stream_slice(
-            self.subkey(1), randgen.Exponential(), 0, self._N, dtype=dtype
-        )
-        pm = randgen.stream_slice(
-            self.subkey(2), randgen.Rademacher(), 0, self._N, dtype=dtype
-        )
-        return pm * jnp.power(1.0 / e, 1.0 / self._p)
+    def _value_kind(self) -> tuple:
+        return ("WZT", self._p)
 
     def _extra_params(self) -> dict[str, Any]:
         return {"P": self._p}

@@ -58,31 +58,28 @@ import jax
 import jax.numpy as jnp
 
 from libskylark_tpu.base import randgen
-
-
-def csr_row_ids(indptr, nnz_pad: int) -> jnp.ndarray:
-    """Expand a (rows+1,) CSR ``indptr`` into per-nonzero row ids for
-    the leading ``nnz_pad`` lane positions (int32). Positions past the
-    true nnz (the lane padding; ``indptr`` is monotone-padded with nnz)
-    clamp to the last row — their data is 0.0, so the clamped target
-    accumulates exact zeros. Jittable: one ``searchsorted`` over the
-    static lane extent."""
-    j = jnp.arange(nnz_pad, dtype=indptr.dtype)
-    rows = jnp.searchsorted(indptr[1:], j, side="right")
-    return jnp.minimum(rows, indptr.shape[0] - 2).astype(jnp.int32)
+from libskylark_tpu.base.sparse import csr_row_ids
 
 
 def cwt_sparse_serve_apply(key_data, data, indices, indptr, *,
-                           s_dim: int, rowwise: bool,
-                           shape: tuple) -> jnp.ndarray:
+                           s_dim: int, rowwise: bool, shape: tuple,
+                           values: tuple = ("CWT",)) -> jnp.ndarray:
     """One request's CountSketch of a CSR operand: O(nnz) scatter-add,
     bit-equal to ``cwt_serve_apply`` on the densified operand (module
     doc). ``shape`` is the padded (rows, cols) class shape the lanes
     describe; the sketched extent (rows columnwise, cols rowwise) is
     stream-exact under zero-padding, the kept extent is sliced by the
     caller. Returns (s_dim, cols) columnwise / (rows, s_dim) rowwise.
+
+    ``values`` names the value stream (``sketch.hash.value_stream``): the
+    default is the CountSketch's signs; ``("MMT",)`` and ``("WZT", p)``
+    make this the program of those families' direct sparse apply too
+    (``HashTransform.apply`` on a ``SparseMatrix`` compiles exactly this
+    function, one lane, through ``engine.compiled``).
     """
     import jax.random as jr
+
+    from libskylark_tpu.sketch.hash import value_stream
 
     key = jr.wrap_key_data(jnp.asarray(key_data))
     n_rows, n_cols = int(shape[0]), int(shape[1])
@@ -90,18 +87,26 @@ def cwt_sparse_serve_apply(key_data, data, indices, indptr, *,
     h = randgen.stream_slice(
         jax.random.fold_in(key, 0), randgen.UniformInt(0, s_dim - 1),
         0, n, dtype=jnp.int32)
-    v = randgen.stream_slice(
-        jax.random.fold_in(key, 1), randgen.Rademacher(), 0, n,
-        dtype=data.dtype)
+    v = value_stream(values, key, n, data.dtype)
     rows = csr_row_ids(indptr, data.shape[0])
-    cols = indices
+    # the coordinate each nonzero is hashed by, its bucket and its term
+    by = indices if rowwise else rows
+    if values == ("CWT",):
+        # v = ±1: the sign rides the bucket's top bit, so a nonzero costs
+        # one gather instead of two, and −x is exactly (−1)·x
+        packed = h.astype(jnp.uint32) | ((v < 0).astype(jnp.uint32) << 31)
+        got = packed[by]
+        bucket = (got & 0x7FFFFFFF).astype(jnp.int32)
+        term = jnp.where((got >> 31) != 0, -data, data)
+    else:
+        bucket, term = h[by], v[by] * data
     if rowwise:
         # out[r, h[c]] += v[c]·val — CSR row-major order IS the dense
         # segment-sum's coordinate order per output cell
         out = jnp.zeros((n_rows, s_dim), data.dtype)
-        return out.at[rows, h[cols]].add(v[cols] * data)
+        return out.at[rows, bucket].add(term)
     out = jnp.zeros((s_dim, n_cols), data.dtype)
-    return out.at[h[rows], cols].add(v[rows] * data)
+    return out.at[bucket, indices].add(term)
 
 
 def scatter_dense(data, indices, indptr, *, shape: tuple) -> jnp.ndarray:

@@ -46,6 +46,10 @@ METRICS: Dict[str, str] = {
     "resilience.faults_fired": "counter",
     "resilience.retries": "counter",
     "resilience.health_transitions": "counter",
+    # the compiled sparse hash apply (sketch/hash.py): stored nonzeros
+    # sketched, by family — the benchmark's cross-check of the nnz that
+    # sparse_nnz_rate.apply reads from the sketch.dispatch spans
+    "sketch.sparse_nnz": "counter",
     # sparse serve operands (engine/serve.py, docs/serving)
     "serve.sparse_submits": "counter",
     "serve.sparse_densified": "counter",
@@ -135,10 +139,12 @@ METRICS: Dict[str, str] = {
 #: strings, so a rename fails a test instead of turning a metric into
 #: ``None``. (``PhaseTimer``'s labels are variables under their own gate.)
 SPANS: Dict[str, Tuple[str, str]] = {
-    # the measured apply (sketch/transform.py, dense.py, pallas_dense.py)
+    # the measured apply (sketch/transform.py, dense.py, pallas_dense.py,
+    # hash.py); the sparse hash apply's sketch.dispatch carries path, family,
+    # nnz and nnz_class, which sparse_nnz_rate.apply reads
     "sketch.apply": ("sketch kernel", "sketch_host_ms.apply"),
     "sketch.plan": ("sketch kernel", "sketch_plan_ms.apply"),
-    "sketch.dispatch": ("sketch kernel", "operator"),
+    "sketch.dispatch": ("sketch kernel", "sketch_dispatch_ms.apply"),
     # base/context.py Allocation.key, pallas_dense._block_keys
     "stream.key": ("streams", "stream_key_ms.apply"),
     # the measured solve (nla/svd.py, engine/compiled.py)
