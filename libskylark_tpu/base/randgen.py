@@ -12,7 +12,9 @@ stream with allocation key ``k`` has chunk key ``fold_in(fold_in(k, c>>31), c&M)
 its ``CHUNK`` uint32 draws are :func:`threefry.chunk_bits` of that key, and a
 distribution is a pure map from draws to samples (``from_bits``) — so any
 contiguous slice can be materialized by generating only its covering chunks,
-on whichever device needs it. Every step is written in the explicit integer
+on whichever device needs it (:func:`stream_slice`), and the elements at
+arbitrary indices can be computed where they are needed with no table at all
+(:func:`stream_at`). Every step is written in the explicit integer
 ops of base/threefry.py, never through ``jax.random``'s samplers: the bits are
 a function of this file, not of the installed JAX, and the Pallas kernels
 replay the same ops in VMEM. The one exception is :class:`Gamma` (rejection
@@ -80,6 +82,13 @@ class Distribution:
         blocks and the kernels' in-VMEM replay."""
         raise NotImplementedError(f"{self.name} has no bit transform")
 
+    def live_draws(self) -> tuple:
+        """The draws ``from_bits`` depends on. A draw not named here
+        cancels for this instance's parameters, so a caller that pays a
+        cipher per element (:func:`stream_at`) may pass zeros in its
+        place."""
+        return tuple(range(self.draws))
+
     def to_dict(self) -> dict[str, Any]:
         d = dataclasses.asdict(self)  # type: ignore[call-overload]
         d["distribution"] = self.name
@@ -127,6 +136,11 @@ class UniformInt(Distribution):
     def from_bits(self, hi, lo):
         off = tf.bits_to_randint(hi, lo, self.high - self.low + 1)
         return self.low + off.astype(jnp.int32)
+
+    def live_draws(self):
+        # 2³² ≡ 0 mod a power-of-two span ≤ 2¹⁶: the high draw cancels
+        dead = tf.randint_multiplier(self.high - self.low + 1) == 0
+        return (1,) if dead else (0, 1)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -254,6 +268,56 @@ def stream_slice(
         (cids & _MASK31).astype(np.uint32), dist=dist, chunk=chunk,
         dtype=dtype)
     return flat[start - c0 * chunk : stop - c0 * chunk]
+
+
+def stream_at(key: jax.Array, dist: Distribution, idx,
+              dtype=jnp.float32) -> jax.Array:
+    """Elements ``idx`` of the virtual stream: bit-equal to
+    ``stream_slice(key, dist, 0, n, dtype)[idx]`` for any ``n`` past the
+    largest index, computed at each index and never read from a table.
+
+    ``idx`` is an integer array of any shape (traced or not, non-negative;
+    64-bit indices reach chunk ids past 2³¹). Each element re-derives what
+    :func:`stream_slice` derives once a chunk — the chunk key, for a
+    distribution of several draws the key of each draw, then the one
+    cipher call that holds its position (:func:`threefry.chunk_bits`'
+    layout) — so an element costs 2 cipher calls (one draw) or ``1 + 2 ×``
+    live draws, whatever the stream's extent. On a TPU that is a fraction
+    of a nanosecond where an element gather costs several. Fixed-draw
+    distributions only.
+    """
+    if not dist.draws:
+        raise ValueError(f"stream_at needs a fixed-draw distribution, got "
+                         f"{dist.name}")
+    idx = jnp.asarray(idx)
+    if not jnp.issubdtype(idx.dtype, jnp.integer):
+        raise TypeError(f"stream_at takes integer indices, got {idx.dtype}")
+    half = CHUNK // 2
+    cid = idx >> (CHUNK.bit_length() - 1)
+    pos = (idx & (CHUNK - 1)).astype(jnp.uint32)
+    j = pos & (half - 1)
+
+    def fold(k0, k1, word):         # threefry.fold_in, for array keys
+        return tf.threefry2x32(k0, k1, jnp.zeros_like(word), word)
+
+    def draw(k0, k1):
+        x0, x1 = tf.threefry2x32(k0, k1, j, j + half)
+        return jnp.where(pos < half, x0, x1)
+
+    # chunk_key's two folds: the high word is zero for 32-bit indices, and
+    # its fold a scalar
+    kd = jr.key_data(key)
+    hi = (cid >> 31) if idx.dtype.itemsize > 4 else jnp.zeros((), jnp.uint32)
+    ck = fold(*fold(kd[0], kd[1], hi), (cid & _MASK31).astype(jnp.uint32))
+    if dist.draws == 1:
+        words = (draw(*ck),)
+    else:
+        live = dist.live_draws()
+        words = tuple(
+            draw(*fold(*ck, jnp.full_like(pos, d))) if d in live
+            else jnp.zeros_like(pos)
+            for d in range(dist.draws))
+    return dist.from_bits(*words).astype(dtype)
 
 
 def stream_chunks(

@@ -19,12 +19,14 @@ A :class:`~libskylark_tpu.base.sparse.SparseMatrix` operand runs one compiled
 program an apply (``engine.compiled``, name ``sketch.hash_sparse``): the pure
 function the sparse serve flush vmaps
 (:func:`libskylark_tpu.sketch.sparse_serve.cwt_sparse_serve_apply`) on the
-operand's device-resident row-major lanes — both streams generated inside
-the executable from the allocation's key data, a gather of bucket and value
-at each stored nonzero's coordinate, and one O(nnz) scatter-add in row-major
-order, which is the order the dense ``segment_sum`` retires the same terms
-in: the result is bit-equal to ``apply(A.todense())``
-(ref: sketch/hash_transform_local_sparse.hpp:12-152).
+operand's device-resident row-major lanes — each stored nonzero's bucket
+computed at its lane from the allocation's key data and the nonzero's
+coordinate (``randgen.stream_at``: the counter cipher, no table of the
+stream and no gather; the CountSketch's sign likewise, MMT's and WZT's
+values still gathered from their stream's table), and one O(nnz)
+scatter-add in row-major order, which is the order the dense
+``segment_sum`` retires the same terms in: the result is bit-equal to
+``apply(A.todense())`` (ref: sketch/hash_transform_local_sparse.hpp:12-152).
 """
 
 from __future__ import annotations
@@ -153,14 +155,18 @@ class HashTransform(SketchTransform):
     # dataflow form of ref: sketch/hash_transform_local_sparse.hpp:12-152) --
 
     def _apply_sparse(self, A, *, rowwise: bool) -> jnp.ndarray:
+        from libskylark_tpu.sketch.sparse_serve import lookup
+
         data, indices, indptr = A.csr_device()
         key_data = jax.random.key_data(self._alloc.key)
+        values = self._value_kind()
         with _trace.span("sketch.dispatch",
                          {"path": "sparse", "family": self.sketch_type,
-                          "nnz": A.nnz, "nnz_class": int(data.shape[0])}):
+                          "nnz": A.nnz, "nnz_class": int(data.shape[0]),
+                          "lookup": lookup(values)}):
             out = _sparse_program()(
                 key_data, data, indices, indptr, s_dim=self._S,
-                rowwise=rowwise, shape=A.shape, values=self._value_kind())
+                rowwise=rowwise, shape=A.shape, values=values)
         _SPARSE_NNZ.inc_always(A.nnz, family=self.sketch_type)
         return out
 

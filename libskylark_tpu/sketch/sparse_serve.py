@@ -13,11 +13,14 @@ static, mirroring ``sketch.hash.cwt_serve_apply`` / ``sketch.dense
 
 Exactness contract (the CI sparse-serve gate pins it):
 
-- **CWT** (:func:`cwt_sparse_serve_apply`): the scatter-add runs over
-  the CSR nonzeros in row-major order — exactly the order in which the
-  dense reference's ``segment_sum`` retires the same nonzero terms
-  (dense zero entries contribute exact ±0.0, which never perturbs an
-  accumulator) — so the sparse flush is **bit-equal** to
+- **CWT** (:func:`cwt_sparse_serve_apply`): each nonzero's bucket and
+  sign are computed at its lane from the key data and the nonzero's
+  coordinate (``randgen.stream_at`` — the integers the dense path's
+  ``stream_slice`` tabulates, with no table and no gather), and the
+  scatter-add runs over the CSR nonzeros in row-major order — exactly
+  the order in which the dense reference's ``segment_sum`` retires the
+  same nonzero terms (dense zero entries contribute exact ±0.0, which
+  never perturbs an accumulator) — so the sparse flush is **bit-equal** to
   ``transform.apply(A.todense())`` at any shape and to the densified
   request through the dense serve path. Padded lane entries carry
   value 0.0 at clamped position 0: exact zeros, any capacity class.
@@ -61,6 +64,15 @@ from libskylark_tpu.base import randgen
 from libskylark_tpu.base.sparse import csr_row_ids
 
 
+def lookup(values: tuple) -> str:
+    """How the program of the value stream ``values`` finds a stored
+    nonzero's bucket and value: ``"lane"`` — both computed at the lane
+    (``randgen.stream_at``; the CountSketch) — or ``"lane+table"`` — the
+    bucket computed, the value gathered from its stream's table (MMT, WZT:
+    transcendental maps). The sparse ``sketch.dispatch`` span carries it."""
+    return "lane" if values == ("CWT",) else "lane+table"
+
+
 def cwt_sparse_serve_apply(key_data, data, indices, indptr, *,
                            s_dim: int, rowwise: bool, shape: tuple,
                            values: tuple = ("CWT",)) -> jnp.ndarray:
@@ -75,7 +87,8 @@ def cwt_sparse_serve_apply(key_data, data, indices, indptr, *,
     default is the CountSketch's signs; ``("MMT",)`` and ``("WZT", p)``
     make this the program of those families' direct sparse apply too
     (``HashTransform.apply`` on a ``SparseMatrix`` compiles exactly this
-    function, one lane, through ``engine.compiled``).
+    function, one lane, through ``engine.compiled``); :func:`lookup` says
+    which of bucket and value each of them computes at the lane.
     """
     import jax.random as jr
 
@@ -83,23 +96,23 @@ def cwt_sparse_serve_apply(key_data, data, indices, indptr, *,
 
     key = jr.wrap_key_data(jnp.asarray(key_data))
     n_rows, n_cols = int(shape[0]), int(shape[1])
-    n = n_cols if rowwise else n_rows
-    h = randgen.stream_slice(
-        jax.random.fold_in(key, 0), randgen.UniformInt(0, s_dim - 1),
-        0, n, dtype=jnp.int32)
-    v = value_stream(values, key, n, data.dtype)
     rows = csr_row_ids(indptr, data.shape[0])
-    # the coordinate each nonzero is hashed by, its bucket and its term
+    # the coordinate each nonzero is hashed by; its bucket h(by) is
+    # computed at the lane from the counter cipher, not gathered from a
+    # table of the stream (an element gather is 7 ns a lane on a v5e)
     by = indices if rowwise else rows
-    if values == ("CWT",):
-        # v = ±1: the sign rides the bucket's top bit, so a nonzero costs
-        # one gather instead of two, and −x is exactly (−1)·x
-        packed = h.astype(jnp.uint32) | ((v < 0).astype(jnp.uint32) << 31)
-        got = packed[by]
-        bucket = (got & 0x7FFFFFFF).astype(jnp.int32)
-        term = jnp.where((got >> 31) != 0, -data, data)
+    bucket = randgen.stream_at(
+        jax.random.fold_in(key, 0), randgen.UniformInt(0, s_dim - 1), by,
+        dtype=jnp.int32)
+    if lookup(values) == "lane":
+        # v = ±1 computed at the lane too, and −x is exactly (−1)·x
+        sign = randgen.stream_at(jax.random.fold_in(key, 1),
+                                 randgen.Rademacher(), by)
+        term = jnp.where(sign < 0, -data, data)
     else:
-        bucket, term = h[by], v[by] * data
+        # MMT/WZT: transcendental maps, still one table and one gather
+        n = n_cols if rowwise else n_rows
+        term = value_stream(values, key, n, data.dtype)[by] * data
     if rowwise:
         # out[r, h[c]] += v[c]·val — CSR row-major order IS the dense
         # segment-sum's coordinate order per output cell

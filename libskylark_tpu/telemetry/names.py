@@ -141,7 +141,8 @@ METRICS: Dict[str, str] = {
 SPANS: Dict[str, Tuple[str, str]] = {
     # the measured apply (sketch/transform.py, dense.py, pallas_dense.py,
     # hash.py); the sparse hash apply's sketch.dispatch carries path, family,
-    # nnz and nnz_class, which sparse_nnz_rate.apply reads
+    # nnz and nnz_class, which sparse_nnz_rate.apply reads, and for the
+    # operator lookup (sparse_serve.lookup: what is computed at the lane)
     "sketch.apply": ("sketch kernel", "sketch_host_ms.apply"),
     "sketch.plan": ("sketch kernel", "sketch_plan_ms.apply"),
     "sketch.dispatch": ("sketch kernel", "sketch_dispatch_ms.apply"),

@@ -13,14 +13,20 @@ Oracles:
 - *one program, placed once*: the second apply of an operand compiles nothing
   and moves nothing from the host;
 - the ``sketch.dispatch`` span and the ``sketch.sparse_nnz`` counter carry
-  the operand's nnz.
+  the operand's nnz, and the span how the program looks a nonzero up;
+- *computed, not gathered*: the CountSketch program's jaxpr holds no
+  ``gather`` (bucket and sign are ``randgen.stream_at`` at the lane), the
+  MMT and WZT programs' exactly one (their value stream's table).
 """
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 import scipy.sparse as sp
 
@@ -30,11 +36,13 @@ from libskylark_tpu import sketch as sk
 from libskylark_tpu.base import sparse as sparse_mod
 from libskylark_tpu.base.sparse import SparseMatrix
 from libskylark_tpu.engine import bucket
+from libskylark_tpu.sketch.sparse_serve import cwt_sparse_serve_apply
 from libskylark_tpu.telemetry import metrics, trace
 
 FAMILIES = [(sk.CWT, {}), (sk.MMT, {}), (sk.WZT, {"p": 1.5})]
 DIMENSIONS = [sk.ROWWISE, sk.COLUMNWISE]
 N, S = 301, 24      # N is not a multiple of 128 (nor of the stream's chunk)
+S_DIMS = [S, 32]    # a power of two: the bucket's high draw cancels, unciphered
 SEED, COUNTER = 11, 0
 
 
@@ -80,28 +88,31 @@ def fresh():
 @pytest.mark.parametrize("dimension", DIMENSIONS)
 @pytest.mark.parametrize("family,kwargs", FAMILIES)
 class TestAgainstTheOracles:
-    def transform(self, family, kwargs):
-        return family(N, S, Context(SEED), **kwargs)
+    def transform(self, family, kwargs, s_dim=S):
+        return family(N, s_dim, Context(SEED), **kwargs)
 
-    def test_matches_the_plain_reference(self, fresh, family, kwargs, dimension):
-        T = self.transform(family, kwargs)
+    @pytest.mark.parametrize("s_dim", S_DIMS)
+    def test_matches_the_plain_reference(self, fresh, family, kwargs, dimension,
+                                         s_dim):
+        T = self.transform(family, kwargs, s_dim)
         A, X = operand(dimension)
-        h, v = reference.streams(SEED, COUNTER, N, S)
+        h, v = reference.streams(SEED, COUNTER, N, s_dim)
         if family is not sk.CWT:        # the reference holds the sign law only
             v = T.values()
-        want = np.asarray(reference.apply_rows(X.toarray(), h, v, S))
+        want = np.asarray(reference.apply_rows(X.toarray(), h, v, s_dim))
         got = np.asarray(T.apply(A, dimension))
         if dimension == sk.COLUMNWISE:
             got = got.T
-        assert got.shape == want.shape == (37, S)
+        assert got.shape == want.shape == (37, s_dim)
         scale = np.abs(want).max()
         assert np.abs(got - want).max() <= 1e-6 * scale
         assert not got[3].any()         # the empty row stays empty
         assert np.count_nonzero(got[5]) == 1    # one feature, one bucket
 
+    @pytest.mark.parametrize("s_dim", S_DIMS)
     def test_bit_equal_to_the_densified_apply(self, fresh, family, kwargs,
-                                              dimension):
-        T = self.transform(family, kwargs)
+                                              dimension, s_dim):
+        T = self.transform(family, kwargs, s_dim)
         A, _ = operand(dimension)
         assert np.array_equal(np.asarray(T.apply(A, dimension)),
                               np.asarray(T.apply(A.todense(), dimension)))
@@ -141,7 +152,9 @@ class TestAgainstTheOracles:
         assert root.attrs["path"] == "sparse"
         assert dispatch.attrs == {"path": "sparse", "family": T.sketch_type,
                                   "nnz": A.nnz,
-                                  "nnz_class": bucket.lane_class(A.nnz)}
+                                  "nnz_class": bucket.lane_class(A.nnz),
+                                  "lookup": ("lane" if family is sk.CWT
+                                             else "lane+table")}
         assert counter.value(family=T.sketch_type) - before == A.nnz
         # the one enqueue is the engine's call, under the dispatch span
         call = next(s for s in spans if s.name == "engine.call")
@@ -155,6 +168,28 @@ class TestAgainstTheOracles:
         root = next(s for s in spans if s.name == "sketch.apply")
         assert [s.name for s in spans if s.parent_id == root.span_id] == [
             "stream.key", "sketch.dispatch"]
+
+
+    def test_lookup_is_computed_not_gathered(self, family, kwargs, dimension):
+        T = self.transform(family, kwargs)
+        A, _ = operand(dimension)
+        program = functools.partial(
+            cwt_sparse_serve_apply, s_dim=S, rowwise=dimension == sk.ROWWISE,
+            shape=A.shape, values=T._value_kind())
+        jaxpr = jax.make_jaxpr(program)(
+            jax.random.key_data(T._alloc.key), *A.csr_device())
+        assert _count(jaxpr.jaxpr, "scatter-add") == 2     # row ids, result
+        assert _count(jaxpr.jaxpr, "gather") == (0 if family is sk.CWT else 1)
+
+
+def _count(jaxpr, primitive: str) -> int:
+    """Equations of ``primitive`` in ``jaxpr`` and every jaxpr inside it."""
+    found = 0
+    for eqn in jaxpr.eqns:
+        found += eqn.primitive.name == primitive
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            found += _count(inner, primitive)
+    return found
 
 
 @pytest.mark.parametrize("dimension", DIMENSIONS)

@@ -91,6 +91,65 @@ def test_stream_chunks_equals_stream_slice():
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
+# n = 47,236 spans twelve chunks; the indices are unsorted, repeat, and hold
+# both ends of a chunk's two cipher lanes, a chunk boundary and the last element
+AT_N = 47_236
+AT_IDX = np.array([4096, 0, 2047, 30_000, 2048, 4095, AT_N - 1, 2047, 0,
+                   8191, 8192, 12, 45_056, 30_000, 4097], np.int32)
+AT_DISTS = {
+    "uniform_int_pow2": (randgen.UniformInt(0, 1023), "int32"),   # high draw dead
+    "uniform_int_1000": (randgen.UniformInt(0, 999), "int32"),
+    "uniform_int_above_2_16": (randgen.UniformInt(0, 99_999), "int32"),
+    "rademacher": (randgen.Rademacher(), "float32"),
+    "uniform_int_offset": (randgen.UniformInt(-5, 58), "int32"),  # low != 0, span 64
+}
+
+
+@pytest.mark.parametrize("how", ["eager", "jit", "vmap"])
+@pytest.mark.parametrize("family", sorted(AT_DISTS))
+def test_stream_at_equals_the_indexed_slice(family, how):
+    """``stream_at`` computes at each index what ``stream_slice`` tabulates:
+    the same bits, from a table never built."""
+    dist, dtype = AT_DISTS[family]
+    key = Context(seed=42).allocate().key
+    want = np.asarray(randgen.stream_slice(key, dist, 0, AT_N, dtype=dtype))
+
+    def at(idx):
+        return randgen.stream_at(key, dist, idx, dtype=dtype)
+
+    if how == "vmap":       # three lanes of their own indices, 2-D inside
+        idx = np.stack([AT_IDX, AT_IDX[::-1], (AT_IDX * 7) % AT_N])
+        got = jax.vmap(jax.jit(at))(idx.reshape(3, 5, 3))
+        assert got.shape == (3, 5, 3)
+        got = np.asarray(got).reshape(3, -1)
+    else:
+        idx = AT_IDX
+        got = np.asarray((jax.jit(at) if how == "jit" else at)(idx))
+    assert got.dtype == np.dtype(dtype)
+    assert np.array_equal(got, want[idx])
+
+
+def test_stream_at_reaches_chunk_ids_past_2_31_with_64_bit_indices():
+    """``chunk_key``'s high word: zero for every 32-bit index, the chunk
+    id's bits 31 and up for a 64-bit one."""
+    key = Context(seed=42).allocate().key
+    base = (1 << 43) + 4090         # chunk 2³¹, six elements short of its end
+    for dist, dtype in AT_DISTS.values():
+        want = randgen.stream_slice(key, dist, base, base + 12, dtype=dtype)
+        with jax.enable_x64():
+            got = randgen.stream_at(
+                key, dist, base + np.arange(12, dtype=np.int64), dtype=dtype)
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_stream_at_needs_fixed_draws_and_integer_indices():
+    key = Context(seed=42).allocate().key
+    with pytest.raises(ValueError, match="fixed-draw"):
+        randgen.stream_at(key, randgen.Gamma(1.5, 2.0), AT_IDX)
+    with pytest.raises(TypeError, match="integer"):
+        randgen.stream_at(key, randgen.Rademacher(), AT_IDX.astype(np.float32))
+
+
 def test_key_derivation_matches_installed_jax():
     """``threefry.fold_in`` is the cipher ``jax.random.fold_in`` runs on
     threefry keys: allocation keys (Context, still ``jax.random``) and
