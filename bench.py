@@ -152,10 +152,10 @@ def run(m: int = 8192, n: int = 8192, s: int = 1024, repeats: int = 5,
                 with jax.profiler.trace(trace_dir):
                     float(f2(A))
 
-        # the plan the kernel ACTUALLY ran (tuning knobs can be silently
-        # adjusted: _qualify shrinks over-budget m-tiles, _select_pipe
-        # drops an unfittable pipeline buffer) — recorded so sweep rows
-        # label measurements with the effective config, not the request
+        # the plan the kernel ACTUALLY ran (the tile can be silently
+        # adjusted: _qualify shrinks over-budget m-tiles) — recorded so
+        # sweep rows label measurements with the effective config, not
+        # the request
         plan = (dict(pd.effective_plan(jlt.dist, (m, n), A.dtype, s,
                                        seq_axis=1, precision=precision),
                      runtime_verified=True)
@@ -167,30 +167,6 @@ def run(m: int = 8192, n: int = 8192, s: int = 1024, repeats: int = 5,
     bytes_moved = 4 * (m * n + m * s)
     gbps = bytes_moved / best / 1e9
     return gbps, best, plan
-
-
-def _record_plan_measurement(plan: dict, m: int, n: int, s: int,
-                             gbps: float) -> None:
-    """Feed a kernel measurement into the autotuner plan cache
-    (libskylark_tpu/tune/) so the next dispatch serves the measured
-    winner — ``python bench.py --record-plan``, never by default: the
-    cache is a tracked file. Only kernel plans qualify (the XLA path is
-    recorded by its absence); best-value-wins semantics live in the
-    cache."""
-    if not plan.get("kernel"):
-        return
-    from libskylark_tpu import tune
-
-    if plan.get("precision") not in tune.plans.ORACLE_PRECISIONS:
-        # a cached winner is served by the DEFAULT dispatch, which must
-        # never auto-select a regime outside the 1e-4 oracle
-        return
-    w = tune.dense_workload("normal", (m, n), "float32", s, seq_axis=1)
-    p = tune.Plan("pallas", m_tile=plan["m_tile"],
-                  precision=plan.get("precision"),
-                  pipeline=bool(plan.get("pipelined")))
-    tune.record_measurement(w, p, gbps, unit="GB/s",
-                            extra={"metric": METRIC})
 
 
 # ---------------------------------------------------------------------------
@@ -2511,8 +2487,6 @@ def main() -> int:
         print("bench.py: the dispatch declined the fused kernel at the "
               f"headline shape ({plan}); nothing reported", file=sys.stderr)
         return 1
-    if "--record-plan" in sys.argv:
-        _record_plan_measurement(plan, m, n, s, gbps)
     flops = 2.0 * m * n * s / secs
     rec = {
         "metric": METRIC,

@@ -123,8 +123,6 @@ def bench_jlt(scale: str):
         gbps, secs, plan = bench.run(m=1024, n=1024, s=128, repeats=2,
                                      precision=precision)
     # plan_id top-level: every measurement names the plan that served it
-    # (bench.run also feeds kernel measurements back into the tune/
-    # plan cache — see bench._record_plan_measurement)
     return {"metric": "jlt_sketch_apply_GBps", "value": round(gbps, 3),
             "unit": "GB/s", "precision": precision, "plan": plan,
             "plan_id": plan.get("plan_id")}
@@ -223,24 +221,11 @@ def bench_frft(scale: str):
     ):
         f = jax.jit(lambda X, T=T: jnp.sum(jnp.abs(T.apply(X, ROWWISE))))
         out[tag] = round(n / _time_scalar(f, X) / 1e6, 3)
-    # inside jit the dispatch sees a tracer and takes the XLA chain; when
-    # a cached autotuner plan puts the fused kernel (pallas_fastfood) on
-    # the EAGER path, time that too — the record must say which path
-    # each number describes
-    from libskylark_tpu.sketch import pallas_fastfood as pf
-
-    rec = {"metric": "frft_feature_map_Mrows_per_s", "value": out["frft"],
-           "unit": "Mrows/s", "rft_same_config": out["rft"],
-           "speedup_vs_rft": round(out["frft"] / out["rft"], 3),
-           "path": "xla_chain_jit"}
-    if pf.features_rows(T_frft, X, variant="planned") is not None:
-        g = (lambda X: jnp.sum(jnp.abs(
-            pf.features_rows(T_frft, X, variant="planned"))))
-        out["frft_fused_kernel"] = round(n / _time_scalar(g, X) / 1e6, 3)
-        rec["fused_kernel_Mrows_per_s"] = out["frft_fused_kernel"]
-        rec["fused_speedup_vs_rft"] = round(
-            out["frft_fused_kernel"] / out["rft"], 3)
-    return rec
+    # the FastRFT apply is the XLA chain on every path; the record says so
+    return {"metric": "frft_feature_map_Mrows_per_s", "value": out["frft"],
+            "unit": "Mrows/s", "rft_same_config": out["rft"],
+            "speedup_vs_rft": round(out["frft"] / out["rft"], 3),
+            "path": "xla_chain_jit"}
 
 
 def bench_nla(scale: str):

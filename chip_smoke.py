@@ -117,21 +117,6 @@ def xla_reference():
         sketch_params.set_use_pallas(prev)
 
 
-@contextlib.contextmanager
-def planned(workload, plan):
-    """One in-memory autotuner plan (nothing is saved): how a regime the
-    dispatch takes only under a cached plan is selected here."""
-    from libskylark_tpu import tune
-
-    cache = tune.PlanCache()
-    cache.put(workload, plan, source="chip_smoke")
-    prev = tune.set_cache(cache)
-    try:
-        yield
-    finally:
-        tune.set_cache(prev)
-
-
 def rft_oracle(R, n: int, X) -> np.ndarray:
     """scale·cos(X·Wᵀ ⊙ sc + b) on the host in float64, from the
     transform's explicit frequency panel."""
@@ -187,11 +172,10 @@ def step_sketch() -> None:
     import jax.numpy as jnp
     import scipy.sparse as sp
 
-    from libskylark_tpu import Context, SparseMatrix, tune
+    from libskylark_tpu import Context, SparseMatrix
     from libskylark_tpu import sketch as sk
     from libskylark_tpu.sketch import hash as sk_hash
     from libskylark_tpu.sketch import pallas_dense as pd
-    from libskylark_tpu.sketch import pallas_fastfood as pff
 
     rng = np.random.default_rng(1)
     A = jnp.asarray(rng.standard_normal((M, N), dtype=np.float32))
@@ -228,38 +212,16 @@ def step_sketch() -> None:
 
         for precision in ("f32", "bf16x3"):
             sk.params.set_pallas_precision(precision)
-            plain, first, run, err = fused(precision)
+            _out, first, run, err = fused(precision)
             report(f"sketch.JLT.{name}", first, run, shape=f"{M}x{N}->{S}",
                    backend=f"pallas_dense.{name}", precision=precision,
                    err=f"{err:.2e}")
 
-        # software-pipelined generation: the dispatch takes it under a
-        # cached plan that asks for it, where every grid step regenerates
-        # its operator block (residency "per_tile": columnwise at this
-        # width, or a single m-tile). A rowwise operand of several
-        # m-tiles keeps the operator in HBM instead and has nothing to
-        # pipeline.
-        plan_args = (T.dist, operand.shape, operand.dtype, S, seq_axis)
-        plan = pd.effective_plan(*plan_args, interpret=REHEARSE)
-        if plan["operator_residency"] != "per_tile":
-            say(f"sketch.JLT.{name}", plan=plan["plan_id"],
-                operator_residency=plan["operator_residency"])
-            continue
-        with planned(
-                tune.dense_workload("normal", operand.shape, operand.dtype,
-                                    S, seq_axis),
-                tune.Plan("pallas", m_tile=plan["m_tile"],
-                          precision="bf16x3", pipeline=True)):
-            plan = pd.effective_plan(*plan_args, interpret=REHEARSE)
-            if not (plan["pipelined"] and plan["plan_source"] == "cache"):
-                raise AssertionError(
-                    f"JLT {name}: planned pipeline not selected: {plan}")
-            out, first, run, err = fused("pipelined")
-        report(f"sketch.JLT.{name}", first, run, shape=f"{M}x{N}->{S}",
-               backend=f"pallas_dense.{name}", precision="bf16x3",
-               plan=plan["plan_id"], err=f"{err:.2e}",
-               same_bits_as_unpipelined=bool(
-                   np.array_equal(np.asarray(out), np.asarray(plain))))
+        # where the operator lives between m-tiles at this orientation
+        plan = pd.effective_plan(T.dist, operand.shape, operand.dtype, S,
+                                 seq_axis, interpret=REHEARSE)
+        say(f"sketch.JLT.{name}", plan=plan["plan_id"],
+            operator_residency=plan["operator_residency"])
 
     # random Fourier features at the same width: generation + matmul +
     # cos epilogue in one kernel, against the host oracle on some rows
@@ -328,12 +290,9 @@ def step_sketch() -> None:
             (sk.FastGaussianRFT(RFT_D, RFT_D, Context(seed=5), sigma=sigma),
              lambda R: fastfood_oracle(R, X[:rows]))):
         name = type(R).sketch_type
-        pff.last_served_variant = None
         served = launches(dense_launchers)
         out, first, run = timed(lambda: R.apply(X, sk.ROWWISE))
         backend = (served() or ["xla"])[0]
-        if pff.last_served_variant:
-            backend = f"pallas_fastfood.{pff.last_served_variant}"
         err = close(np.asarray(out)[:rows], oracle(R),
                     f"{name} vs host oracle")
         report(f"sketch.{name}", first, run,

@@ -530,8 +530,9 @@ PLAN_CACHE = declare(
 USE_PLAN_CACHE = declare(
     "SKYLARK_USE_PLAN_CACHE", default=True, parser=parse_bool_default_on,
     kind="flag",
-    doc="Consult the plan cache at dispatch time (default on); "
-        "``0`` disables all cached-plan consultation.")
+    doc="The serve tier consults the plan cache when it picks a "
+        "bucket's flush kernel (default on); ``0`` disables all "
+        "cached-plan consultation.")
 
 COST_CALIB = declare(
     "SKYLARK_COST_CALIB", default=None, parser=parse_path_or_off,
@@ -800,11 +801,6 @@ NET_RETRY_BACKOFF_S = declare(
 
 # -- sketch kernels ---------------------------------------------------------
 
-PALLAS_MTILE = declare(
-    "SKYLARK_PALLAS_MTILE", default=None, parser=parse_int, kind="int",
-    doc="Explicit Pallas m-tile (>= 8); a valid value is a user pin "
-        "that beats any cached plan (on-chip sweeps).")
-
 MATMUL_PRECISION = declare(
     "SKYLARK_MATMUL_PRECISION", default=None, kind="choice",
     doc="Ambient jax matmul precision installed at package import "
@@ -813,31 +809,13 @@ MATMUL_PRECISION = declare(
 FASTFOOD_PRECISION = declare(
     "SKYLARK_FASTFOOD_PRECISION", default=None, kind="choice",
     doc="Contraction regime inside the fused fastfood kernel "
-        "(``f32`` | ``bf16x3`` | ``bf16``); overrides cached plans.")
-
-PALLAS_PIPELINE = declare(
-    "SKYLARK_PALLAS_PIPELINE", default=None, kind="choice",
-    doc="Tri-state pipelined-kernel override: unset lets a cached plan "
-        "decide, ``1`` forces on, anything else forces off.")
+        "(``f32`` | ``bf16x3`` | ``bf16``); a ``precision=`` argument "
+        "beats it.")
 
 HASH_KERNEL = declare(
     "SKYLARK_HASH_KERNEL", default=None, kind="choice",
     doc="CWT/CountSketch flush kernel override: ``pallas``/``mxu``/"
         "``1``, ``pallas_exact``/``exact``, else the XLA scatter.")
-
-PALLAS_VMEM_BUDGET = declare(
-    "SKYLARK_PALLAS_VMEM_BUDGET", default=16 * 1024 * 1024,
-    parser=parse_int, kind="bytes",
-    doc="Per-core VMEM budget the Pallas kernels' tile plans target "
-        "(default: Mosaic's 16 MiB scoped limit on a v5e; no "
-        "vmem_limit_bytes is passed, so raising this past the scope "
-        "gets a Mosaic rejection, which raises).")
-
-PALLAS_SCRATCH_CAP = declare(
-    "SKYLARK_PALLAS_SCRATCH_CAP", default=8 * 1024 * 1024,
-    parser=parse_int, kind="bytes",
-    doc="VMEM cap for caching the generated operator across m-tiles "
-        "(must leave room for the double-buffered pipeline tiles).")
 
 AUTO_MATERIALIZE = declare(
     "SKYLARK_AUTO_MATERIALIZE", default=True,

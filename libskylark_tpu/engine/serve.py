@@ -361,7 +361,7 @@ def _pallas_native() -> bool:
 
 def _parse_plan_token(token: str):
     """Invert :meth:`libskylark_tpu.tune.Plan.plan_id` for warmup-pack
-    kernel restoration (``pallas/mt128/pipe`` → a Plan). None when the
+    kernel restoration (``pallas/mt128/f32`` → a Plan). None when the
     token is not a plan id this build understands. The real decoder
     lives next to the encoder (``Plan.from_plan_id``) so the formats
     cannot drift apart; this wrapper only narrows the backends to the

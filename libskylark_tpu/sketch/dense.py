@@ -138,10 +138,8 @@ def try_pallas_apply(key, dist, A, s_dim: int, scale: float, which: str):
     """Fused generation+matmul TPU kernel (sketch/pallas_dense.py) for any
     virtual operator in the dense-block stream format — the dense
     transforms and the RFT frequency matrices share this dispatch.
-    Returns None when the backend/input don't qualify — or when a cached
-    autotuner plan (libskylark_tpu/tune/) certifies the XLA path for
-    this workload; the kernel-side resolution also fills m_tile /
-    precision from the cache before the heuristic defaults."""
+    Returns None when the backend/input don't qualify; the kernel-side
+    resolution fills m_tile / precision from the sketch.params setters."""
     if not pallas_ambient_ok(A):
         return None
     from libskylark_tpu.sketch import pallas_dense

@@ -247,27 +247,16 @@ class FastRFT(SketchTransform):
             self.shifts(dt), self.scale, scal, NB, nb, self._fut_apply)
 
     def _apply_columnwise(self, A: jnp.ndarray) -> jnp.ndarray:
-        # route through the rowwise dispatch so the fused kernel serves
-        # this orientation too (the transpose feeds the kernel's
-        # row-major tile layout either way)
+        # the chain is written for row-major input; the transpose feeds
+        # it either way
         return self._apply_rowwise(A.T).T
 
     def _apply_rowwise(self, A: jnp.ndarray) -> jnp.ndarray:
-        # The fused single-kernel chain (one HBM read of A, one write of
-        # the features — the XLA chain re-touches the intermediate ~9×;
-        # BASELINE.md crossover analysis) serves only under a cached
-        # autotuner plan that names a kernel variant ("planned"): Mosaic
-        # rejects both variants on the TPU tried so far (PERF.md), so
-        # without a measured plan the XLA chain below is the path. A
-        # planned kernel that fails to compile raises.
-        from libskylark_tpu.sketch import params as sketch_params
-
-        if sketch_params.get_use_pallas():
-            from libskylark_tpu.sketch import pallas_fastfood
-
-            out = pallas_fastfood.features_rows(self, A, variant="planned")
-            if out is not None:
-                return out
+        # The XLA chain. The fused single-kernel chain
+        # (sketch/pallas_fastfood.py: one HBM read of A, one write of the
+        # features — this chain re-touches the intermediate ~9×;
+        # BASELINE.md crossover analysis) is not on this path: Mosaic
+        # rejects both its variants on the TPU tried so far (PERF.md).
         return self._features_rows(A)
 
     def _extra_params(self) -> dict[str, Any]:

@@ -35,8 +35,8 @@ import jax.random as jr
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from libskylark_tpu.base import env as _env
 from libskylark_tpu.base import randgen, threefry as tf
+from libskylark_tpu.sketch import params as sketch_params
 from libskylark_tpu.sketch.dense import BLOCK_COLS  # the stream format's
 # panel width — single source of truth (dense.py imports this module only
 # lazily, so no cycle)
@@ -44,14 +44,6 @@ from libskylark_tpu.sketch.transform import note_apply
 from libskylark_tpu.telemetry import trace as _trace
 
 _HALF = BLOCK_COLS // 2
-
-
-def _DEFAULT_M_TILE() -> int:
-    """Tuning knob lives in sketch/params.py (runtime get/set, env-seeded
-    via SKYLARK_PALLAS_MTILE)."""
-    from libskylark_tpu.sketch import params as sketch_params
-
-    return sketch_params.get_pallas_m_tile()
 
 
 def available() -> bool:
@@ -177,18 +169,20 @@ def _dot(lhs, rhs, dims, precision, gen_side=1):
 
 # Per-core VMEM budget the tile plans target: Mosaic's default SCOPED
 # limit (16 MiB — not the core's VMEM, which is 128 MiB on a v5e by
-# pltpu.get_tpu_info(); PERF.md §6, PR 27), env-overridable.
-_VMEM_BUDGET_BYTES = _env.PALLAS_VMEM_BUDGET.get()
+# pltpu.get_tpu_info(); PERF.md §6, PR 27). No pallas_call passes
+# ``vmem_limit_bytes``, so a plan past the scope is a Mosaic rejection,
+# which raises.
+_VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 
 # Cap on the "vmem" residency (:func:`operator_residency`): when the
 # full virtual S fits, each block is generated ONCE (first m-tile sweep)
 # into VMEM scratch and every later tile contracts against the cached
 # copy. A larger operator is kept in HBM instead (rowwise) or regenerated
-# per tile (columnwise). Must leave room for the pipeline's
-# double-buffered A/out tiles inside _VMEM_BUDGET_BYTES (advisor r2
-# medium finding: the old 48 MiB default exceeded the scoped limit and
-# could fail Mosaic compilation outright on the shard_map path).
-_SCRATCH_CAP_BYTES = _env.PALLAS_SCRATCH_CAP.get()
+# per tile (columnwise). Must leave room for Mosaic's double-buffered
+# A/out tiles inside _VMEM_BUDGET_BYTES (advisor r2 medium finding: the
+# old 48 MiB default exceeded the scoped limit and could fail Mosaic
+# compilation outright on the shard_map path).
+_SCRATCH_CAP_BYTES = 8 * 1024 * 1024
 
 
 def _vmem_estimate(m_tile: int, s_dim: int, scratch_bytes: int) -> int:
@@ -217,8 +211,8 @@ def operator_residency(s_dim: int, n: int, m: int, m_tile: int,
                        rowwise: bool) -> str:
     """Where the generated operator lives between the m-tiles of ONE
     apply, from the padded shapes alone — the single rule the
-    ``pallas_call`` sites, :func:`_pipe_fits`, :func:`effective_plan`,
-    the ``sketch.apply`` span and tune/cost.py all read:
+    ``pallas_call`` sites, :func:`effective_plan`, the ``sketch.apply``
+    span and tune/cost.py all read:
 
     ``"per_tile"``  a single m-tile: nothing to reuse, each block is
                     generated in the grid step that contracts it. Also
@@ -277,60 +271,6 @@ def _apply_epilogue(out_ref, epilogue, operand_refs, k, n_blocks):
         out_ref[:] = outscale * jnp.cos(z)
 
 
-def _kernel_pipe(dist_kind, s_dim, n_blocks, precision, rowwise, epilogue,
-                 keys_ref, a_ref, *refs):
-    """Kernel with software-pipelined generation: block k+1 is generated
-    into the other half of a double buffer BETWEEN the MXU contraction of
-    block k being issued and its result being consumed — the generation
-    is dataflow-independent of the in-flight matmul, so the scheduler MAY
-    run the VPU (Threefry + inverse-CDF) under the MXU. On a v5e it does
-    not: the pipelined variant ran 12.6 ms against the plain kernel's
-    8.5 ms, bit-identical (PR 21). Only the "per_tile" residency
-    regenerates per step, so only there can this engage
-    (:func:`_pipe_fits`); opt-in via SKYLARK_PALLAS_PIPELINE=1 or a cached
-    plan. One body serves both orientations (``rowwise``: out += A·S_blkᵀ,
-    else out += S_blk·A). ``refs`` = (*epilogue operands, out, s_buf)."""
-    *operand_refs, out_ref, s_buf = refs
-    k = pl.program_id(1)
-
-    @pl.when(k == 0)
-    def _first():
-        s_buf[0] = _gen_block(dist_kind, s_dim, keys_ref, 0)
-
-    S_blk = s_buf[k % 2]
-    if rowwise:
-        acc = _dot(a_ref[:], S_blk, (((1,), (1,)), ((), ())), precision,
-                   gen_side=1)
-    else:
-        acc = _dot(S_blk, a_ref[:], (((1,), (0,)), ((), ())), precision,
-                   gen_side=0)
-
-    @pl.when(k + 1 < n_blocks)
-    def _next():
-        s_buf[(k + 1) % 2] = _gen_block(dist_kind, s_dim, keys_ref, k + 1)
-
-    _accumulate(out_ref, acc, k)
-    if epilogue is not None:
-        _apply_epilogue(out_ref, epilogue, operand_refs, k, n_blocks)
-
-
-def _pipeline_env() -> bool | None:
-    """Tri-state SKYLARK_PALLAS_PIPELINE: None when unset (a cached
-    plan may decide), True for "1", False for any other set value — an
-    EXPLICITLY set env must beat a cached plan in either direction
-    (=0 is the escape hatch when a cached pipelined plan misbehaves).
-    Read at TRACE time: _fused_call's jit cache is keyed by shapes and
-    static args only, so toggle the env before the first call of a
-    given shape (the bench A/Bs in separate processes)."""
-    # deliberate trace-time env read (see docstring): the pipeline
-    # regime is resolved once per (shape, statics) trace and the env
-    # contract is toggle-before-first-call — not a flapping key
-    v = _env.PALLAS_PIPELINE.raw()  # skylark-lint: disable=jit-purity
-    if v is None:
-        return None
-    return v == "1"
-
-
 def _kernel(dist_kind, s_dim, n_blocks, precision, epilogue, keys_ref,
             a_ref, *refs):
     """Rowwise, operator generated in the kernel ("vmem" / "per_tile"):
@@ -360,35 +300,13 @@ def _kernel_cw(dist_kind, s_dim, m_tile, precision, keys_ref, a_ref, out_ref,
     _accumulate(out_ref, acc, k)
 
 
-def _pipe_fits(residency: str, s_dim: int, m_tile: int,
-               pipeline: bool | None = None) -> bool:
-    """Pipelined-generation selection predicate — the SINGLE source of
-    truth shared by the kernel call sites (via :func:`_select_pipe`) and
-    :func:`effective_plan`, so the reported plan can't drift from the
-    executed one: engage when each grid step regenerates its block (the
-    "per_tile" residency), the pipeline is requested — an explicitly set
-    SKYLARK_PALLAS_PIPELINE wins in either direction, else a cached
-    plan's ``pipeline`` flag decides — and the double buffer fits the
-    same VMEM budget _qualify planned against."""
-    env = _pipeline_env()
-    enabled = env if env is not None else bool(pipeline)
-    pipe_bytes = 2 * s_dim * BLOCK_COLS * 4
-    return (residency == "per_tile" and enabled
-            and _vmem_estimate(m_tile, s_dim, pipe_bytes)
-            <= _VMEM_BUDGET_BYTES)
-
-
-def _select_pipe(kern, pipe_kern, residency: str, s_dim: int, n: int,
-                 m_tile: int, pipeline: bool | None = None):
-    """(kernel, scratch shapes) of an in-kernel-generation call: the
-    pipelined kernel + generation double buffer when :func:`_pipe_fits`
-    says so — over budget, stay on the plain kernel (no fallback seam
-    exists on the shard_map path)."""
-    if _pipe_fits(residency, s_dim, m_tile, pipeline):
-        return pipe_kern, [pltpu.VMEM((2, s_dim, BLOCK_COLS), jnp.float32)]
-    if residency == "vmem":     # the whole operator, filled by the first sweep
-        return kern, [pltpu.VMEM((s_dim, n), jnp.float32)]
-    return kern, []
+def _operator_scratch(residency: str, s_dim: int, n: int) -> list:
+    """Scratch shapes of an in-kernel-generation call: the whole
+    operator under "vmem" (filled by the first m-tile sweep), else
+    none."""
+    if residency == "vmem":
+        return [pltpu.VMEM((s_dim, n), jnp.float32)]
+    return []
 
 
 def _grid_params(residency: str):
@@ -574,8 +492,7 @@ def _planes_call(A, keys, scale, extra_operands, *, s_dim, dist_kind,
 
 
 def _rowwise_pallas_call(A, keys, scale, extra_operands, *, s_dim, dist_kind,
-                         m_tile, precision, interpret, pipeline=None,
-                         epilogue=None):
+                         m_tile, precision, interpret, epilogue=None):
     """``scale``·A·Sᵀ (``scale`` None: unscaled) by the kernel(s) of the
     operand's :func:`operator_residency`. ``extra_operands`` are the
     (1, s_dim) VMEM vectors of ``epilogue`` (:func:`_apply_epilogue`).
@@ -583,10 +500,7 @@ def _rowwise_pallas_call(A, keys, scale, extra_operands, *, s_dim, dist_kind,
     "hbm": :func:`_planes_call`, scale folded into the planes. "vmem" /
     "per_tile": one call that generates in the kernel — grid, key-table
     SMEM spec, A-tile spec, accumulator out spec, operator scratch — and
-    the scale as a pass over its result. Under "per_tile" with the
-    pipeline requested the 2-slot double-buffered kernel runs instead;
-    the grid stays parallel over m-tiles (each core's k-sweep is
-    self-contained — the k == 0 prologue refills the buffer per sweep)."""
+    the scale as a pass over its result."""
     m, n = A.shape
     n_blocks = n // BLOCK_COLS
     residency = operator_residency(s_dim, n, m, m_tile, rowwise=True)
@@ -595,14 +509,9 @@ def _rowwise_pallas_call(A, keys, scale, extra_operands, *, s_dim, dist_kind,
                             dist_kind=dist_kind, m_tile=m_tile,
                             precision=precision, interpret=interpret,
                             epilogue=epilogue)
-    kern, scratch = _select_pipe(
+    out = pl.pallas_call(
         functools.partial(_kernel, dist_kind, s_dim, n_blocks, precision,
                           epilogue),
-        functools.partial(_kernel_pipe, dist_kind, s_dim, n_blocks,
-                          precision, True, epilogue),
-        residency, s_dim, n, m_tile, pipeline)
-    out = pl.pallas_call(
-        kern,
         grid=(m // m_tile, n_blocks),
         in_specs=[
             # whole key table in SMEM every step (tiny); indexed by k
@@ -620,7 +529,7 @@ def _rowwise_pallas_call(A, keys, scale, extra_operands, *, s_dim, dist_kind,
             (m_tile, s_dim), lambda i, k: (i, 0), memory_space=pltpu.VMEM
         ),
         out_shape=jax.ShapeDtypeStruct((m, s_dim), jnp.float32),
-        scratch_shapes=scratch,
+        scratch_shapes=_operator_scratch(residency, s_dim, n),
         compiler_params=_grid_params(residency),
         interpret=interpret,
     )(keys, A, *extra_operands)
@@ -630,48 +539,41 @@ def _rowwise_pallas_call(A, keys, scale, extra_operands, *, s_dim, dist_kind,
 @functools.partial(
     jax.jit,
     static_argnames=("s_dim", "dist_kind", "m_tile", "precision",
-                     "interpret", "pipeline"),
+                     "interpret"),
 )
 def _fused_call(A, keys, scale=None, *, s_dim, dist_kind, m_tile,
-                precision="f32", interpret=False, pipeline=None):
+                precision="f32", interpret=False):
     return _rowwise_pallas_call(A, keys, scale, (), s_dim=s_dim,
                                 dist_kind=dist_kind, m_tile=m_tile,
-                                precision=precision, interpret=interpret,
-                                pipeline=pipeline)
+                                precision=precision, interpret=interpret)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("s_dim", "dist_kind", "m_tile", "precision",
-                     "inscale", "outscale", "interpret", "pipeline"),
+                     "inscale", "outscale", "interpret"),
 )
 def _fused_call_cos(A, keys, sc, sh, *, s_dim, dist_kind, m_tile,
                     precision="f32", inscale=1.0, outscale=1.0,
-                    interpret=False, pipeline=None):
+                    interpret=False):
     return _rowwise_pallas_call(A, keys, None, (sc, sh), s_dim=s_dim,
                                 dist_kind=dist_kind, m_tile=m_tile,
                                 precision=precision, interpret=interpret,
-                                pipeline=pipeline,
                                 epilogue=("cos", inscale, outscale))
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("s_dim", "dist_kind", "m_tile", "precision",
-                     "interpret", "pipeline"),
+                     "interpret"),
 )
 def _fused_call_cw(A, keys, *, s_dim, dist_kind, m_tile, precision="f32",
-                   interpret=False, pipeline=None):
+                   interpret=False):
     n, m = A.shape
     n_blocks = n // BLOCK_COLS
     residency = operator_residency(s_dim, n, m, m_tile, rowwise=False)
-    kern, scratch = _select_pipe(
-        functools.partial(_kernel_cw, dist_kind, s_dim, m_tile, precision),
-        functools.partial(_kernel_pipe, dist_kind, s_dim, n_blocks,
-                          precision, False, None),
-        residency, s_dim, n, m_tile, pipeline)
     return pl.pallas_call(
-        kern,
+        functools.partial(_kernel_cw, dist_kind, s_dim, m_tile, precision),
         grid=(m // m_tile, n_blocks),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -684,7 +586,7 @@ def _fused_call_cw(A, keys, *, s_dim, dist_kind, m_tile, precision="f32",
             (s_dim, m_tile), lambda j, k: (0, j), memory_space=pltpu.VMEM
         ),
         out_shape=jax.ShapeDtypeStruct((s_dim, m), jnp.float32),
-        scratch_shapes=scratch,
+        scratch_shapes=_operator_scratch(residency, s_dim, n),
         compiler_params=_grid_params(residency),
         interpret=interpret,
     )(keys, A)
@@ -697,76 +599,18 @@ _DIST_KINDS = {
 }
 
 
-def _consult_cache(dist, shape, dtype, s_dim: int, seq_axis: int,
-                   rft: bool = False):
-    """Cached autotuner plan for this apply, or None. Gated on
-    params.use_plan_cache; never raises (a broken cache must not take
-    down a sketch apply)."""
-    from libskylark_tpu.sketch import params as sketch_params
-
-    if not sketch_params.get_use_plan_cache():
-        return None
-    kind = _DIST_KINDS.get(type(dist))
-    if kind is None or not supported(dist, dtype):
-        return None
-    try:
-        from libskylark_tpu import tune
-
-        return tune.plan_for(tune.dense_workload(
-            kind, shape, dtype, s_dim, seq_axis, rft=rft))
-    except Exception:
-        return None
-
-
-# marker: the cached plan says the XLA path serves this workload better
-# than the kernel — dispatch declines and the caller falls back
-_TAKE_XLA = object()
-
-
-def _resolve_knobs(dist, shape, dtype, s_dim: int, seq_axis: int,
-                   m_tile, precision, rft: bool = False):
-    """Apply the documented dispatch precedence (sketch/params.py
-    ``use_plan_cache`` doc) to the two tuning knobs: explicit call-site
-    argument > explicit user override (env/setter) > cached plan >
-    heuristic default. Returns ``(m_tile, precision, pipeline, source)``
-    with ``pipeline`` None (env decides) unless a cached plan pins it,
-    or the :data:`_TAKE_XLA` marker when a consulted plan certifies the
-    XLA path for this workload (only when the user overrode NO knob —
-    m-tile, precision, or the pipeline env; an explicit override means
-    a sweep/pin and must reach the kernel)."""
-    from libskylark_tpu.sketch import params as sketch_params
-
-    mt_open = m_tile is None and not sketch_params.pallas_m_tile_overridden()
-    prec_open = (precision is None
-                 and not sketch_params.pallas_precision_overridden())
-    plan = (_consult_cache(dist, shape, dtype, s_dim, seq_axis, rft=rft)
-            if mt_open or prec_open else None)
-    if plan is not None and plan.backend != "pallas":
-        if mt_open and prec_open and _pipeline_env() is None:
-            return _TAKE_XLA
-        plan = None
-    source = "heuristic"
-    pipeline = None
-    if plan is not None:
-        source = "cache"
-        if mt_open and plan.m_tile:
-            m_tile = plan.m_tile
-        # oracle-grade regimes ONLY: the cache file is a committed,
-        # hand-editable artifact, and the default dispatch must never
-        # auto-select a regime outside the 1e-4 determinism oracle
-        # (bf16/bf16gen2 stay call-site/setter opt-in) — nor pass an
-        # unknown string through _dot's silent HIGHEST fall-through
-        # under a mislabeling plan_id
-        from libskylark_tpu.tune.plans import ORACLE_PRECISIONS
-
-        if prec_open and plan.precision in ORACLE_PRECISIONS:
-            precision = plan.precision
-        pipeline = plan.pipeline or None
+def _resolve_knobs(m_tile, precision):
+    """The two tuning knobs of an apply: the call-site argument, else the
+    sketch.params setter (whose default is the heuristic). Returns
+    ``(m_tile, precision, source)`` — ``source`` is "arg" when the call
+    site gave either knob, "heuristic" otherwise. The tile is a request:
+    :func:`_qualify` fits it to the operand."""
+    source = "heuristic" if m_tile is None and precision is None else "arg"
     if m_tile is None:
-        m_tile = _DEFAULT_M_TILE()
+        m_tile = sketch_params.get_pallas_m_tile()
     if precision is None:
-        precision = _default_precision()
-    return m_tile, precision, pipeline, source
+        precision = sketch_params.get_pallas_precision()
+    return m_tile, precision, source
 
 
 def supported(dist, dtype) -> bool:
@@ -794,7 +638,7 @@ def _qualify(dist, A, seq_axis: int, m_tile: int, interpret: bool,
     (:func:`_vmem_estimate`, scratch excluded — operator_residency
     checks it)
     fits ``_VMEM_BUDGET_BYTES``: a Mosaic VMEM-exhaustion failure inside a
-    jitted shard_map pipeline has no catchable fallback seam, so the
+    jitted shard_map program has no catchable fallback seam, so the
     pre-flight must make compilation succeed, not try/except it (advisor
     r2 medium finding).
 
@@ -810,7 +654,7 @@ def _qualify(dist, A, seq_axis: int, m_tile: int, interpret: bool,
     m = _pad_to(max(A.shape[1 - seq_axis], 8), 8)
     # power-of-two tile ≥ 8: the halving search below then always
     # terminates at a divisor of the 8-aligned m (a non-pow2 request,
-    # e.g. SKYLARK_PALLAS_MTILE=100, would otherwise collapse to 1)
+    # e.g. the argument m_tile=100, would otherwise collapse to 1)
     m_tile = max(8, 1 << (max(m_tile, 8).bit_length() - 1))
     m_tile = min(m_tile, m)
     while m % m_tile:
@@ -830,33 +674,25 @@ def _qualify(dist, A, seq_axis: int, m_tile: int, interpret: bool,
 
 
 def _plan(dist, A, s_dim: int, seq_axis: int, m_tile, precision,
-          interpret: bool, rft: bool = False):
+          interpret: bool):
     """The prelude of every fused apply: resolve the knobs
     (:func:`_resolve_knobs`) and qualify the operand (:func:`_qualify`).
-    Returns ``(m_tile, precision, pipeline)`` with the effective tile, or
-    None when the kernel declines and the caller takes the XLA path."""
+    Returns ``(m_tile, precision)`` with the effective tile, or None when
+    the kernel declines and the caller takes the XLA path."""
     with _trace.span("sketch.plan") as sp:
-        plan = None
-        knobs = _resolve_knobs(dist, A.shape, A.dtype, s_dim, seq_axis,
-                               m_tile, precision, rft=rft)
-        if knobs is _TAKE_XLA:
-            source = "take_xla"
-        else:
-            m_tile, precision, pipeline, source = knobs
-            mt = _qualify(dist, A, seq_axis=seq_axis, m_tile=m_tile,
-                          interpret=interpret, s_dim=s_dim)
-            if mt is not None:
-                plan = (mt, precision, pipeline)
+        m_tile, precision, source = _resolve_knobs(m_tile, precision)
+        mt = _qualify(dist, A, seq_axis=seq_axis, m_tile=m_tile,
+                      interpret=interpret, s_dim=s_dim)
         if sp is not None:
             sp.set_attr("plan_source", source)
-    if plan is not None:
-        n_p, m_p = _padded_extents(A.shape[seq_axis], A.shape[1 - seq_axis],
-                                   plan[0])
-        note_apply(path="pallas", m_tile=plan[0], precision=plan[1],
-                   plan_source=source,
-                   operator_residency=operator_residency(
-                       s_dim, n_p, m_p, plan[0], rowwise=seq_axis == 1))
-    return plan
+    if mt is None:
+        return None
+    n_p, m_p = _padded_extents(A.shape[seq_axis], A.shape[1 - seq_axis], mt)
+    note_apply(path="pallas", m_tile=mt, precision=precision,
+               plan_source=source,
+               operator_residency=operator_residency(
+                   s_dim, n_p, m_p, mt, rowwise=seq_axis == 1))
+    return mt, precision
 
 
 @functools.partial(jax.jit, static_argnames="n")
@@ -903,13 +739,12 @@ def rowwise_apply(
 ) -> Optional[jnp.ndarray]:
     """out = scale · A @ Sᵀ with S the virtual (s_dim × N) matrix of
     :func:`randgen.dense_block`. Returns None when the kernel declines
-    (caller takes the XLA path) — including when a cached autotuner plan
-    certifies the XLA path for this workload. A Mosaic rejection of a
-    planned kernel raises."""
+    (caller takes the XLA path). A Mosaic rejection of a planned kernel
+    raises."""
     plan = _plan(dist, A, s_dim, 1, m_tile, precision, interpret)
     if plan is None:
         return None
-    mt, precision, pipeline = plan
+    mt, precision = plan
     m = A.shape[0]
     keys = _block_keys(key, A.shape[1])
     with _trace.span("sketch.dispatch") as sp:
@@ -920,8 +755,7 @@ def rowwise_apply(
         # under the "hbm" residency, a pass over the result otherwise
         out = _fused_call(Ap, keys, scale, s_dim=s_dim,
                           dist_kind=_DIST_KINDS[type(dist)], m_tile=mt,
-                          precision=precision, interpret=interpret,
-                          pipeline=pipeline)
+                          precision=precision, interpret=interpret)
         return out if out.shape[0] == m else out[:m]
 
 
@@ -940,7 +774,7 @@ def columnwise_apply(
     plan = _plan(dist, A, s_dim, 0, m_tile, precision, interpret)
     if plan is None:
         return None
-    mt, precision, pipeline = plan
+    mt, precision = plan
     m = A.shape[1]
     keys = _block_keys(key, A.shape[0])
     with _trace.span("sketch.dispatch") as sp:
@@ -949,8 +783,7 @@ def columnwise_apply(
             sp.set_attr("padded", Ap is not A)
         out = _fused_call_cw(Ap, keys, s_dim=s_dim,
                              dist_kind=_DIST_KINDS[type(dist)], m_tile=mt,
-                             precision=precision, interpret=interpret,
-                             pipeline=pipeline)
+                             precision=precision, interpret=interpret)
         return scale * out[:, :m]
 
 
@@ -972,10 +805,10 @@ def rft_rowwise_apply(
     epilogue applied in VMEM (no extra HBM round-trip of the feature
     matrix). ``sc``/``sh`` are (s_dim,) per-feature scales/shifts.
     Returns None when not applicable."""
-    plan = _plan(dist, A, s_dim, 1, m_tile, precision, interpret, rft=True)
+    plan = _plan(dist, A, s_dim, 1, m_tile, precision, interpret)
     if plan is None:
         return None
-    mt, precision, pipeline = plan
+    mt, precision = plan
     m = A.shape[0]
     keys = _block_keys(key, A.shape[1])
     with _trace.span("sketch.dispatch") as sp:
@@ -988,15 +821,8 @@ def rft_rowwise_apply(
             jnp.asarray(sh, jnp.float32).reshape(1, s_dim),
             s_dim=s_dim, dist_kind=_DIST_KINDS[type(dist)], m_tile=mt,
             precision=precision, inscale=float(inscale),
-            outscale=float(outscale), interpret=interpret,
-            pipeline=pipeline)
+            outscale=float(outscale), interpret=interpret)
         return out[:m]
-
-
-def _default_precision() -> str:
-    from libskylark_tpu.sketch import params as sketch_params
-
-    return sketch_params.get_pallas_precision()
 
 
 def fused_partial(
@@ -1011,7 +837,7 @@ def fused_partial(
 ) -> Optional[jnp.ndarray]:
     """UNSCALED contraction of a local shard against the operator blocks
     keyed by ``keys`` (n_blocks_local, 2) — the building block that lets
-    the ``shard_map`` panel pipeline (parallel/shard_apply.py) run the
+    the ``shard_map`` panel apply (parallel/shard_apply.py) run the
     fused kernel per device: each device passes its own slice of the
     global key table, contracts its shard, and the caller psums.
 
@@ -1025,12 +851,11 @@ def fused_partial(
     plan = _plan(dist, A_loc, s_dim, seq_axis, m_tile, precision, interpret)
     if plan is None:
         return None
-    mt, precision, pipeline = plan
+    mt, precision = plan
     m = A_loc.shape[1 - seq_axis]
     Ap = _padded(A_loc, seq_axis=seq_axis, mt=mt)
     kw = dict(s_dim=s_dim, dist_kind=_DIST_KINDS[type(dist)], m_tile=mt,
-              precision=precision, interpret=interpret,
-              pipeline=pipeline)
+              precision=precision, interpret=interpret)
     if seq_axis == 1:
         return _fused_call(Ap, keys, None, **kw)[:m]
     return _fused_call_cw(Ap, keys, **kw)[:, :m]
@@ -1041,47 +866,35 @@ def effective_plan(dist, shape, dtype, s_dim: int, seq_axis: int,
                    interpret: bool = False,
                    precision: str | None = None) -> dict:
     """The plan a fused apply with these arguments would actually run —
-    WITHOUT running it. Both tuning knobs can be silently adjusted
-    downstream (:func:`_qualify` shrinks an over-budget m-tile;
-    :func:`_select_pipe` drops the pipelined kernel when its buffer
-    doesn't fit or the operator is resident), so anything recording a measurement labeled with the
-    REQUESTED knobs must ask for the EFFECTIVE ones or the record lies
-    about what was measured (e.g. the m-tile/pipeline sweep rows in
-    benchmarks/). Runs the SAME plan-cache resolution as the dispatch
-    (:func:`_resolve_knobs`), so the report reflects cached plans too.
+    WITHOUT running it. The requested tile can be adjusted downstream
+    (:func:`_qualify` shrinks an over-budget m-tile), so anything
+    recording a measurement labeled with the REQUESTED knobs must ask
+    for the EFFECTIVE ones or the record lies about what was measured
+    (e.g. the m-tile sweep rows in benchmarks/). Runs the SAME
+    resolution as the dispatch (:func:`_resolve_knobs`).
 
     Returns ``{"kernel": False, "plan_id": "xla"}`` when the apply would
     take the XLA fallback, else ``kernel/m_tile/operator_residency/
-    operator_cache/pipelined/precision/plan_id/plan_source``
+    operator_cache/precision/plan_id/plan_source``
     (``operator_cache`` is ``operator_residency == "vmem"``)."""
-    knobs = _resolve_knobs(dist, tuple(shape), jnp.dtype(dtype), s_dim,
-                           seq_axis, m_tile, precision)
-    if knobs is _TAKE_XLA:
-        return {"kernel": False, "plan_id": "xla",
-                "plan_source": "cache"}
-    m_tile, precision, pipeline, source = knobs
+    m_tile, precision, source = _resolve_knobs(m_tile, precision)
     A = jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
     mt = _qualify(dist, A, seq_axis=seq_axis, m_tile=m_tile,
                   interpret=interpret, s_dim=s_dim)
     if mt is None:
         return {"kernel": False, "plan_id": "xla",
                 "plan_source": source}
-    # the same padding/residency/pipeline helpers the pallas_call sites use
+    # the same padding/residency helpers the pallas_call sites use
     n_p, m_p = _padded_extents(shape[seq_axis], shape[1 - seq_axis], mt)
     residency = operator_residency(s_dim, n_p, m_p, mt,
                                    rowwise=seq_axis == 1)
-    pipelined = _pipe_fits(residency, s_dim, mt, pipeline)
-    # single source of the id format: the same Plan the cache stores
-    from libskylark_tpu.tune.plans import Plan
-
-    plan_id = Plan("pallas", m_tile=mt, precision=precision,
-                   pipeline=pipelined).plan_id()
     return {"kernel": True, "m_tile": mt,
             "operator_residency": residency,
             "operator_cache": residency == "vmem",
-            "pipelined": pipelined,
             "precision": precision,
-            "plan_id": plan_id,
+            # the label bench records carry; tune/plans.py's
+            # Plan.plan_id writes the same string for the same plan
+            "plan_id": f"pallas/mt{mt}/{precision}",
             "plan_source": source}
 
 
@@ -1184,7 +997,7 @@ def serve_qualify(dist, s_dim: int, n: int, m: int, dtype,
         return False, f"distribution/dtype unsupported ({dtype})"
     lane = jax.ShapeDtypeStruct((m, n), jnp.dtype(dtype))
     mt = _qualify(dist, lane, seq_axis=1,
-                  m_tile=m_tile or _DEFAULT_M_TILE(),
+                  m_tile=m_tile or sketch_params.get_pallas_m_tile(),
                   interpret=interpret, s_dim=s_dim)
     if mt is None:
         return False, "no m-tile fits the VMEM budget"
@@ -1210,14 +1023,14 @@ def serve_batched_apply(key_data, scale, A, *, dist, s_dim: int,
     lane = jax.ShapeDtypeStruct(
         (m, n) if rowwise else (n, m), A.dtype)
     mt = _qualify(dist, lane, seq_axis=1 if rowwise else 0,
-                  m_tile=m_tile or _DEFAULT_M_TILE(),
+                  m_tile=m_tile or sketch_params.get_pallas_m_tile(),
                   interpret=interpret, s_dim=s_dim)
     if mt is None:
         raise ValueError(
             f"batched dense kernel unqualified for s_dim={s_dim} "
             f"shape {A.shape}")
     if precision is None:
-        precision = _default_precision()
+        precision = sketch_params.get_pallas_precision()
     n_p, m_p = _padded_extents(n, m, mt)
     pads = [(0, 0), (0, 0), (0, 0)]
     pads[n_axis] = (0, n_p - n)

@@ -27,8 +27,8 @@ both variants — the fused one's permutation gather
 (`jnp.take_along_axis` along the lane axis: "Shape mismatch in input,
 indices and output") and the split one's WHT fold (`tpu.reshape`
 64×2048 → 4096×32: "unsupported shape cast"; PERF.md) — so the kernel
-is off the default dispatch: only a cached autotuner plan or an explicit
-``variant=`` reaches it, and a launch that fails to compile raises.
+is off the default dispatch: only an explicit call reaches it, and a
+launch that fails to compile raises.
 Exact semantics vs the XLA chain are pinned by interpret-mode oracles
 in tests/test_pallas_fastfood.py.
 """
@@ -377,26 +377,9 @@ def supported(transform, A) -> bool:
 last_served_variant: str | None = None
 
 
-def cached_plan(transform, At):
-    """Cached autotuner plan for this Fastfood feature map, or None.
-    Same precedence/gating as pallas_dense._consult_cache."""
-    from libskylark_tpu.sketch import params as sketch_params
-
-    if not sketch_params.get_use_plan_cache():
-        return None
-    try:
-        from libskylark_tpu import tune
-
-        return tune.plan_for(tune.fastfood_workload(
-            type(transform).sketch_type, At.shape, At.dtype,
-            transform._S))
-    except Exception:
-        return None
-
-
 def features_rows(transform, At, *, interpret: bool = False,
                   precision: str | None = None,
-                  variant: str = "auto"):
+                  variant: str = "fused"):
     """The (m, S) Fastfood feature map for row-major input At (m, N)
     through the fused kernel, or None when the kernel declines (caller
     takes the XLA chain — mirror of pallas_dense.rowwise_apply's
@@ -404,20 +387,16 @@ def features_rows(transform, At, *, interpret: bool = False,
     another variant or the XLA chain silently. ``interpret`` runs the
     pallas interpreter (CPU-testable exact semantics).
 
-    ``variant``: "fused" (single kernel, in-kernel Π gather), "split"
-    (two kernels around an XLA gather), "auto" (a cached autotuner
-    plan decides — it may also certify the XLA chain, declining the
-    kernel — else fused), or "planned" (as "auto", but without a cached
-    kernel plan the kernel declines). The transform's own dispatch
-    (``FastRFT._apply_rowwise``) asks for "planned": Mosaic rejects both
-    variants on the TPU tried so far (PERF.md), so the kernel is off
-    the default path."""
+    ``variant``: "fused" (single kernel, in-kernel Π gather) or "split"
+    (two kernels around an XLA gather), given by the caller. Mosaic
+    rejects both on the TPU tried so far (PERF.md), so the transform's
+    own dispatch (``FastRFT._apply_rowwise``) takes the XLA chain and
+    this kernel is reached only by an explicit call."""
     import math
 
-    if variant not in ("auto", "planned", "fused", "split"):
+    if variant not in ("fused", "split"):
         raise ValueError(
-            "variant must be 'auto', 'planned', 'fused' or 'split', "
-            f"got {variant!r}")
+            f"variant must be 'fused' or 'split', got {variant!r}")
     if not interpret and not supported(transform, At):
         return None
     T = transform
@@ -426,34 +405,6 @@ def features_rows(transform, At, *, interpret: bool = False,
     mt = plan_m_tile(NB, m)
     if mt is None:
         return None
-    # cached plan: consulted only for the decisions the caller left open
-    # (explicit variant/precision arguments and the env override below
-    # always win — the documented dispatch precedence,
-    # sketch/params.py ``use_plan_cache``)
-    prec_open = (precision is None
-                 and _env.FASTFOOD_PRECISION.raw() is None)
-    plan = (cached_plan(T, At)
-            if variant in ("auto", "planned") or prec_open else None)
-    if variant == "planned" and (
-            plan is None or plan.backend not in ("fused", "split")):
-        return None
-    if variant in ("auto", "planned"):
-        if plan is not None and plan.backend == "xla_chain":
-            if prec_open:
-                return None  # certified: the XLA chain serves this
-            # the caller pinned a kernel regime explicitly (argument or
-            # SKYLARK_FASTFOOD_PRECISION): a sweep/pin must reach the
-            # kernel — the cached decline applies only to fully-open
-            # dispatch (mirrors pallas_dense._resolve_knobs' _TAKE_XLA
-            # condition)
-            plan = None
-        variant = (plan.backend if plan is not None
-                   and plan.backend in ("fused", "split") else "fused")
-    if plan is not None and plan.backend != variant:
-        # a plan certified for a DIFFERENT backend must not donate its
-        # regime to an explicitly requested variant (e.g. cached split/
-        # f32 would silently run an explicit fused certification at f32)
-        plan = None
     if precision is None:
         precision = _env.FASTFOOD_PRECISION.raw()
     if precision is None:
@@ -475,15 +426,7 @@ def features_rows(transform, At, *, interpret: bool = False,
                        "high": "bf16x3", "bfloat16_3x": "bf16x3",
                        "bfloat16": "bf16"}
         if pinned is None:
-            # no user pin: a cached plan's regime (oracle-grade only —
-            # same read-time guard as pallas_dense._resolve_knobs; the
-            # committed cache file must not be able to opt the default
-            # dispatch into bf16), else the default
-            from libskylark_tpu.tune.plans import ORACLE_PRECISIONS
-
-            precision = (plan.precision if plan is not None
-                         and plan.precision in ORACLE_PRECISIONS
-                         else "bf16x3")
+            precision = "bf16x3"
         elif pinned in _PIN_REGIME:
             precision = _PIN_REGIME[pinned]
         else:

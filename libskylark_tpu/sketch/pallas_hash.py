@@ -60,10 +60,10 @@ Like every kernel in this tree, dispatch DECLINES (returns None /
 ``qualify`` explains why) rather than failing: callers keep the XLA
 scatter. On a TPU v5e the batched kernel compiles and matches its XLA
 twin (rel-max 1.6e-7 at 8 × (8192, 512) → 1024) but does not beat it
-(PERF.md), so only an explicit override or a measured plan-cache entry
-routes traffic here. A selected kernel that Mosaic rejects raises on the
-direct-apply path; the serve layer counts it (``mosaic-reject``) and
-serves the XLA program.
+(PERF.md), so only an explicit override routes a direct apply here (the
+serve tier also takes it under a measured plan-cache entry). A selected
+kernel that Mosaic rejects raises on the direct-apply path; the serve
+layer counts it (``mosaic-reject``) and serves the XLA program.
 """
 
 from __future__ import annotations
@@ -472,10 +472,9 @@ def try_apply(transform, A, *, rowwise: bool) -> Optional[jnp.ndarray]:
     """Direct-apply dispatch hook for ``HashTransform``: run the kernel
     when (a) it's a CWT on a qualifying f32 single-device operand on a
     TPU backend, and (b) an explicit override (``SKYLARK_HASH_KERNEL``
-    = pallas | pallas_exact) or a measured plan-cache entry picks it.
-    Returns None to decline — the caller keeps the XLA scatter; with no
-    plan and no override it declines (module docstring). A selected
-    kernel that fails to compile raises."""
+    = pallas | pallas_exact) picks it. Returns None to decline — the
+    caller keeps the XLA scatter; with no override it declines (module
+    docstring). A selected kernel that fails to compile raises."""
     from libskylark_tpu.base import env as _env
     from libskylark_tpu.sketch import params as sketch_params
 
@@ -487,30 +486,13 @@ def try_apply(transform, A, *, rowwise: bool) -> Optional[jnp.ndarray]:
 
     if not pallas_ambient_ok(A):
         return None
-    accum = None
-    env = _env.HASH_KERNEL.raw()
-    if env is not None:
-        env = env.strip().lower()
-        if env in ("pallas", "mxu", "1"):
-            accum = "mxu"
-        elif env in ("pallas_exact", "exact"):
-            accum = "exact"
-        else:
-            return None  # explicit xla/off
-    elif sketch_params.get_use_plan_cache():
-        try:
-            from libskylark_tpu import tune
-
-            w = tune.hash_workload(
-                "CWT", A.shape, A.dtype, transform.sketch_dim,
-                seq_axis=1 if rowwise else 0)
-            plan = tune.plan_for(w)
-        except Exception:
-            plan = None
-        if plan is not None and plan.backend == "pallas":
-            accum = "mxu"
-    if accum is None:
-        return None
+    env = (_env.HASH_KERNEL.raw() or "").strip().lower()
+    if env in ("pallas", "mxu", "1"):
+        accum = "mxu"
+    elif env in ("pallas_exact", "exact"):
+        accum = "exact"
+    else:
+        return None  # unset, or explicit xla/off
     n = A.shape[1] if rowwise else A.shape[0]
     m = A.shape[0] if rowwise else A.shape[1]
     ok, _why = qualify(transform.sketch_dim, n, m, A.dtype)

@@ -69,14 +69,16 @@ def set_use_pallas(on: bool) -> None:
     _use_pallas = bool(on)
 
 
-# ``use_plan_cache`` — consult the persistent autotuner plan cache
-# (libskylark_tpu/tune/) at dispatch time, BEFORE the heuristic
-# defaults below. Precedence at every dispatch site: explicit call-site
-# argument > explicit user override (env SKYLARK_PALLAS_MTILE /
-# set_pallas_m_tile / set_pallas_precision — a sweep or a pin must beat
-# a cached winner) > cached plan > heuristic default. Disabled entirely
-# with SKYLARK_USE_PLAN_CACHE=0 (or set_use_plan_cache(False)); the
-# cache file location is SKYLARK_PLAN_CACHE (tune/cache.py).
+# ``use_plan_cache`` — the serve tier (engine/serve.py) consults the
+# persistent autotuner plan cache (the ``tune`` package) when it picks
+# a bucket's flush kernel: explicit ``kernel=`` / env pin > cached plan
+# > XLA. The eager applies in sketch/ never read it: there the kernel,
+# tile and regime are the call-site argument, else the setters below
+# (set_pallas_m_tile / set_pallas_precision), else one rule from the
+# device and the shapes (pallas_dense._qualify / operator_residency).
+# Disabled entirely with SKYLARK_USE_PLAN_CACHE=0 (or
+# set_use_plan_cache(False)); the cache file location is
+# SKYLARK_PLAN_CACHE (tune/cache.py).
 _use_plan_cache = _env.USE_PLAN_CACHE.get()
 
 
@@ -110,22 +112,11 @@ def set_use_plan_cache(on: bool) -> None:
 # from the f32 stream at ~2⁻⁸, it is strictly opt-in and its oracle
 # compares against an XLA apply of the SAME rounded operator
 # (tests/test_pallas_dense.py).
-_PALLAS_PRECISION_DEFAULT = "bf16x3"
-_pallas_precision = _PALLAS_PRECISION_DEFAULT
+_pallas_precision = "bf16x3"
 
 
 def get_pallas_precision() -> str:
     return _pallas_precision
-
-
-def pallas_precision_overridden() -> bool:
-    """True when the runtime regime differs from the shipping default —
-    an explicit pin beats a cached plan's precision (``use_plan_cache``
-    precedence). A pin whose value EQUALS the default is
-    indistinguishable and not detected (the same documented limit as
-    base/precision.ambient_precision_pinned_by_user; such callers pass
-    ``precision=`` at the call site, which always wins)."""
-    return _pallas_precision != _PALLAS_PRECISION_DEFAULT
 
 
 def set_pallas_precision(p: str) -> None:
@@ -157,34 +148,13 @@ def set_pallas_precision(p: str) -> None:
 # core has 128 MiB of VMEM, so the larger tiles above ran only with
 # ``vmem_limit_bytes`` raised by the measuring script — no pallas_call in
 # the package passes one (ROADMAP Queue 1). _qualify still shrinks
-# per-call when s_dim is larger. Seeded from SKYLARK_PALLAS_MTILE for
-# on-chip sweeps without code changes; invalid values fall back to the
-# default.
-_PALLAS_M_TILE_DEFAULT = 512
-
-
-def _env_m_tile() -> int:
-    v = _env.PALLAS_MTILE.get(_PALLAS_M_TILE_DEFAULT)
-    return v if v >= 8 else _PALLAS_M_TILE_DEFAULT
-
-
-_pallas_m_tile = _env_m_tile()
+# per-call when s_dim is larger. A sweep passes ``m_tile=`` or calls
+# set_pallas_m_tile.
+_pallas_m_tile = 512
 
 
 def get_pallas_m_tile() -> int:
     return _pallas_m_tile
-
-
-def pallas_m_tile_overridden() -> bool:
-    """True when the user set the tile explicitly — a one-shot
-    SKYLARK_PALLAS_MTILE (valid value; a typo degrades to the default
-    INCLUDING cache consultation) or a runtime set_pallas_m_tile away
-    from the shipping default. An on-chip sweep's env override must
-    beat a cached winner or the sweep can't explore."""
-    if _pallas_m_tile != _PALLAS_M_TILE_DEFAULT:
-        return True
-    v = _env.PALLAS_MTILE.get()
-    return v is not None and v >= 8
 
 
 def set_pallas_m_tile(t: int) -> None:

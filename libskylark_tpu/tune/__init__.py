@@ -1,5 +1,5 @@
 """Sketch-apply autotuner: candidate plans, offline cost ranking, and a
-persistent plan cache the dispatchers consult before their heuristics.
+persistent plan cache the serve tier consults before its default.
 
 The flow (designed for scarce TPU access — see ISSUE/ROADMAP):
 
@@ -7,15 +7,15 @@ The flow (designed for scarce TPU access — see ISSUE/ROADMAP):
    every plan for a workload; :func:`rank_candidates` orders them with
    the hardware-free cost model (:mod:`tune.cost`); :func:`autotune_topk`
    returns the short list a live window should actually measure.
-2. **Live window**: measure the top-k (bench.py does this for the
-   headline config) and :func:`record_measurement` the winner — the
-   cache persists to disk (``benchmarks/plan_cache.json`` by default).
-3. **Dispatch**: the sketch dispatchers (sketch/pallas_dense.py,
-   sketch/pallas_fastfood.py via sketch/frft.py) call :func:`plan_for`
-   before falling back to their heuristics. Explicit call-site
-   arguments and the one-shot env overrides (``SKYLARK_PALLAS_MTILE``
-   et al.) still take precedence — the cache fills in only what the
-   caller left unspecified.
+2. **Live window**: measure the top-k and :func:`record_measurement`
+   the winner — the cache persists to disk
+   (``benchmarks/plan_cache.json`` by default).
+3. **Dispatch**: the serve tier (engine/serve.py, engine/warmup.py)
+   calls :func:`plan_for` when it picks a bucket's flush kernel, after
+   an explicit ``kernel=`` argument and the env pins and before its XLA
+   default. The eager applies in sketch/ do not read the cache: their
+   kernel, tile and regime are the call-site argument, else the
+   ``sketch.params`` setter, else one rule from the device and shapes.
 
 ``SKYLARK_PLAN_CACHE`` points the cache elsewhere (or ``0`` disables
 persistence); :func:`libskylark_tpu.sketch.params.set_use_plan_cache`

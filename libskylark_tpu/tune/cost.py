@@ -18,9 +18,8 @@ Two complementary models live here:
    contraction regime, operator generation on the VPU (~50 ops/entry;
    once an apply when the operator is resident in VMEM or HBM, one full
    regeneration per m-tile sweep otherwise —
-   ``pallas_dense.operator_residency``), HBM traffic (the resident
-   planes' included), and generation/matmul overlap when the pipelined
-   kernel engages.
+   ``pallas_dense.operator_residency``) and HBM traffic (the resident
+   planes' included).
 
 Absolute times from the analytic model are NOT predictions — only the
 ORDERING is consumed (rank the candidates, measure the top-k in a live
@@ -261,12 +260,11 @@ def rate_provenance(path=_CALIB_AUTO) -> dict:
 
 def _dense_operator_residency(w: Workload, m_tile: int) -> str:
     """Where the kernel would keep the generated operator between
-    m-tiles — the kernel's OWN rule and env-resolved budgets
-    (pallas_dense.operator_residency / SKYLARK_PALLAS_SCRATCH_CAP /
-    SKYLARK_PALLAS_VMEM_BUDGET) on the padded extents the kernel sees,
-    imported lazily so ranking can't drift from dispatch on parts whose
-    budgets were overridden. The import is cycle-safe: pallas_dense only
-    reaches tune lazily inside its dispatch functions."""
+    m-tiles — the kernel's OWN rule and budgets
+    (pallas_dense.operator_residency) on the padded extents the kernel
+    sees, so ranking can't drift from dispatch. sketch/ never imports
+    tune/, so the import has no cycle to hide; it stays lazy to keep
+    jax.experimental.pallas off ``import libskylark_tpu.tune``."""
     from libskylark_tpu.sketch.pallas_dense import (_padded_extents,
                                                     operator_residency)
 
@@ -333,10 +331,9 @@ def plan_cost(w: Workload, p: Plan, rates: Optional[dict] = None) -> dict:
         hbm_s = bytes_moved / rates["hbm_bytes_per_s"]
     mxu_s = flops / rates["mxu_flops_per_s"]
     gen_s = gen_entries * GEN_OPS_PER_ENTRY / rates["vpu_ops_per_s"]
-    # the pipelined kernel is modeled as hiding generation under the
-    # matmul; the plain kernel serializes them (measured: a step costs
-    # generation plus matmul, sketch/params.py m-tile note)
-    compute_s = max(mxu_s, gen_s) if p.pipeline else mxu_s + gen_s
+    # the kernel serializes them (measured: a step costs generation
+    # plus matmul, sketch/params.py m-tile note)
+    compute_s = mxu_s + gen_s
     compute_s += _dense_grid_steps(w, m_tile) * GRID_STEP_S
     modeled = max(hbm_s, compute_s)
     return {"flops": flops, "bytes": bytes_moved,

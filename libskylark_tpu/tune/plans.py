@@ -4,9 +4,8 @@ A **workload** names a sketch-apply hot-path invocation abstractly enough
 to be cached across processes: ``(device_kind, op, transform, dtype,
 shape bucket)``. A **plan** names every tuning decision the dispatchers
 can make for it: which backend serves the apply (fused Pallas kernel vs
-the XLA path; fused vs split Fastfood variant), the Pallas ``m_tile``,
-the contraction-precision regime, and whether the pipelined-generation
-kernel engages.
+the XLA path; fused vs split Fastfood variant), the Pallas ``m_tile``
+and the contraction-precision regime.
 
 Shapes are bucketed to the next power of two so one certified plan
 serves a neighborhood of shapes — the kernels' own qualification
@@ -149,7 +148,6 @@ class Plan:
     backend: str
     m_tile: Optional[int] = None
     precision: Optional[str] = None
-    pipeline: bool = False
 
     def plan_id(self) -> str:
         """Deterministic short id — the label bench records carry and
@@ -159,8 +157,6 @@ class Plan:
             parts.append(f"mt{self.m_tile}")
         if self.precision is not None:
             parts.append(self.precision)
-        if self.pipeline:
-            parts.append("pipe")
         return "/".join(parts)
 
     @classmethod
@@ -172,24 +168,24 @@ class Plan:
         Returns None for a token this build does not understand — an
         unknown backend, an empty part, or (since every known
         component is matched explicitly) more than one free-form
-        precision part."""
+        precision part. A ``pipe`` part (the pipelined-generation
+        kernel, deleted in PR 30) is skipped: packs and cache files
+        written by older trees are input from outside the program."""
         parts = str(token).split("/")
         if not parts or parts[0] not in known_backends:
             return None
         m_tile = None
         precision = None
-        pipeline = False
         for p in parts[1:]:
             if p.startswith("mt") and p[2:].isdigit():
                 m_tile = int(p[2:])
             elif p == "pipe":
-                pipeline = True
+                continue
             elif p and precision is None:
                 precision = p
             else:
                 return None
-        return cls(backend=parts[0], m_tile=m_tile,
-                   precision=precision, pipeline=pipeline)
+        return cls(backend=parts[0], m_tile=m_tile, precision=precision)
 
     def to_dict(self) -> dict:
         d = {"backend": self.backend}
@@ -197,18 +193,16 @@ class Plan:
             d["m_tile"] = int(self.m_tile)
         if self.precision is not None:
             d["precision"] = self.precision
-        if self.pipeline:
-            d["pipeline"] = True
         return d
 
     @classmethod
     def from_dict(cls, d: dict) -> "Plan":
+        # a stored "pipeline" key (older trees) is ignored
         return cls(
             backend=str(d["backend"]),
             m_tile=(int(d["m_tile"]) if d.get("m_tile") is not None
                     else None),
             precision=d.get("precision"),
-            pipeline=bool(d.get("pipeline", False)),
         )
 
 
@@ -219,9 +213,7 @@ def _dense_candidates(w: Workload, precisions: Sequence[str]
         for mt in DENSE_M_TILES:
             if mt > m:
                 continue
-            for pipe in (False, True):
-                yield Plan("pallas", m_tile=mt, precision=prec,
-                           pipeline=pipe)
+            yield Plan("pallas", m_tile=mt, precision=prec)
     yield Plan("xla")
 
 
@@ -266,8 +258,7 @@ def _serve_candidates(w: Workload) -> Iterator[Plan]:
 def enumerate_candidates(w: Workload,
                          allow_fast: bool = False) -> list[Plan]:
     """Every plan worth ranking for ``w``. The dense list crosses
-    m-tiles × precision regimes × pipeline on/off, plus the XLA
-    fallback; Fastfood crosses variant × precision plus the XLA chain;
+    m-tiles × precision regimes, plus the XLA fallback; Fastfood crosses variant × precision plus the XLA chain;
     hash and serve buckets cross the scatter-free kernel vs the XLA
     path. ``allow_fast`` adds the accuracy-opt-in regimes (never
     auto-selected by default — see module doc)."""

@@ -253,6 +253,82 @@ def test_env_table_matches_committed(tmp_path):
                         "script/lint --env-table"
 
 
+def test_removed_pallas_knobs_are_gone_everywhere():
+    """Four knobs left in PR 30 (the pipelined-generation switch, the
+    m-tile's env twin, two budgets no caller set): each is gone from the
+    registry, the generated table and the lint baseline together, so
+    none can come back in one place and be read from another."""
+    from libskylark_tpu.base import env as sk_env
+
+    # spelled in parts: the names appear nowhere in the tree any more
+    removed = ["SKYLARK_PALLAS_" + tail for tail in
+               ("PIPELINE", "MTILE", "VMEM_BUDGET", "SCRATCH_CAP")]
+    with open(os.path.join(REPO, "docs", "env_vars.rst")) as fh:
+        table = fh.read()
+    with open(os.path.join(REPO, "libskylark_tpu", "analysis",
+                           "baseline.json")) as fh:
+        baseline = fh.read()
+    for name in removed:
+        attr = name[len("SKYLARK_"):]
+        assert name not in sk_env.REGISTRY, name
+        assert not hasattr(sk_env, attr), attr
+        assert name not in table, name
+        assert attr not in baseline, attr
+
+
+# ---------------------------------------------------------------------------
+# layering: only engine/ reaches the autotuner
+# ---------------------------------------------------------------------------
+
+_PKG = os.path.join(REPO, "libskylark_tpu")
+_TOP_LEVEL = sorted(
+    d for d in os.listdir(_PKG)
+    if os.path.isfile(os.path.join(_PKG, d, "__init__.py")))
+
+
+def _imports_of_tune(path):
+    """(line, statement) of every import of libskylark_tpu.tune in one
+    file, at any depth (a function-level import counts)."""
+    import ast
+
+    with open(path) as fh:
+        tree = ast.parse(fh.read(), filename=path)
+    hits = []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            names = [a.name for a in node.names]
+        elif isinstance(node, ast.ImportFrom) and node.level == 0:
+            names = [node.module] + [f"{node.module}.{a.name}"
+                                     for a in node.names]
+        else:
+            continue
+        if any(n == "libskylark_tpu.tune"
+               or n.startswith("libskylark_tpu.tune.") for n in names):
+            hits.append((node.lineno, ast.unparse(node)))
+    return hits
+
+
+def test_layering_covers_every_package():
+    assert {"engine", "tune", "sketch", "base"} <= set(_TOP_LEVEL)
+
+
+@pytest.mark.parametrize(
+    "package", [p for p in _TOP_LEVEL if p not in ("engine", "tune")])
+def test_only_engine_imports_tune(package):
+    """``tune/`` ranks and caches plans for the serve tier; ``engine/``
+    is its only importer. The kernels below (``sketch/``) decide from
+    the argument, the setter and the shapes, and what ``tune/`` needs of
+    them it imports from them, never the reverse."""
+    hits = []
+    for root, _dirs, files in os.walk(os.path.join(_PKG, package)):
+        for f in files:
+            if f.endswith(".py"):
+                path = os.path.join(root, f)
+                hits += [(os.path.relpath(path, REPO), *h)
+                         for h in _imports_of_tune(path)]
+    assert not hits, hits
+
+
 # ---------------------------------------------------------------------------
 # runtime lock-order witness
 # ---------------------------------------------------------------------------

@@ -41,5 +41,9 @@ def test_rehearsal_runs_every_step_on_cpu():
                  "solve.fast_least_squares", "train.admm_krr.job",
                  "mesh.shard_apply.rowwise", "mesh.dryrun_multichip"):
         assert want in steps, (want, sorted(steps))
-    assert sum("plan=pallas/" in ln and "/pipe " in ln for ln in lines) == 2
+    # each JLT orientation names the plan it ran and where the operator
+    # lived; the pipelined-generation leg is gone
+    assert sum("plan=pallas/" in ln and "operator_residency=" in ln
+               for ln in lines) == 2
+    assert not any("/pipe" in ln for ln in lines)
     assert "failed=none" in lines[-1]

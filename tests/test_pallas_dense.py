@@ -268,57 +268,6 @@ def test_rft_projection_rides_the_kernel():
     np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
 
 
-def test_pipelined_variant_matches_plain(monkeypatch):
-    """SKYLARK_PALLAS_PIPELINE=1 routes big-operator applies through the
-    double-buffered generation kernel (_kernel_pipe); its output must be
-    identical to the plain kernel's (same blocks, same contraction — only
-    the generation scheduling differs), incl. the fused cos epilogue."""
-    from libskylark_tpu.sketch.rft import GaussianRFT
-
-    m, n, s = 64, 1024, 96
-    ctx = Context(seed=21)
-    jlt = JLT(n, s, ctx)
-    A = jnp.asarray(
-        np.random.default_rng(9).standard_normal((m, n)), jnp.float32
-    )
-    # baselines at the SAME m_tile/scratch config as the pipelined runs
-    # below: XLA's CPU gemm may reassociate differently per program
-    # shape, so equality is only a pipeline-scheduling oracle when the
-    # two sides differ in NOTHING but the pipeline toggle
-    monkeypatch.setattr(pd, "_SCRATCH_CAP_BYTES", 0)
-    plain = np.asarray(pd.rowwise_apply(
-        jlt._alloc.key, jlt.dist, A, s, jlt.scale,
-        m_tile=16, precision="f32", interpret=True))
-    T = GaussianRFT(n, s, Context(seed=22), sigma=2.0)
-    plain_cos = np.asarray(pd.rft_rowwise_apply(
-        T.subkey(0), T.dist, A, s, T.inscale, T.outscale,
-        np.asarray(T.row_scales()), np.asarray(T.shifts()),
-        m_tile=16, precision="f32", interpret=True))
-    A_c = jnp.asarray(
-        np.random.default_rng(10).standard_normal((n, 48)), jnp.float32
-    )
-    # columnwise baseline BEFORE the pipeline env engages (else both
-    # sides would run the pipe kernel and a defect would self-compare)
-    plain_c = np.asarray(pd.columnwise_apply(
-        jlt._alloc.key, jlt.dist, A_c, s, jlt.scale,
-        m_tile=16, precision="f32", interpret=True))
-
-    monkeypatch.setenv("SKYLARK_PALLAS_PIPELINE", "1")
-    piped = np.asarray(pd.rowwise_apply(
-        jlt._alloc.key, jlt.dist, A, s, jlt.scale,
-        m_tile=16, precision="f32", interpret=True))
-    piped_cos = np.asarray(pd.rft_rowwise_apply(
-        T.subkey(0), T.dist, A, s, T.inscale, T.outscale,
-        np.asarray(T.row_scales()), np.asarray(T.shifts()),
-        m_tile=16, precision="f32", interpret=True))
-    np.testing.assert_array_equal(piped, plain)
-    np.testing.assert_array_equal(piped_cos, plain_cos)
-    piped_c = np.asarray(pd.columnwise_apply(
-        jlt._alloc.key, jlt.dist, A_c, s, jlt.scale,
-        m_tile=16, precision="f32", interpret=True))
-    np.testing.assert_array_equal(piped_c, plain_c)
-
-
 @pytest.mark.tpu
 @pytest.mark.skipif(not ON_TPU, reason="needs a real TPU backend")
 @pytest.mark.parametrize("precision", ["f32", "bf16x3"])
@@ -380,64 +329,27 @@ def test_fused_on_chip_rft_epilogue():
     np.testing.assert_allclose(np.asarray(got), want, atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.tpu
-@pytest.mark.skipif(not ON_TPU, reason="needs a real TPU backend")
-def test_fused_on_chip_pipelined(monkeypatch):
-    """The double-buffered generation pipeline, Mosaic-compiled: must be
-    bit-identical to the plain kernel on chip (same blocks, same
-    contraction — only instruction scheduling differs)."""
-    m, n, s = 256, 2048, 128
-    jlt = JLT(n, s, Context(seed=17))
-    A = jnp.asarray(
-        np.random.default_rng(9).standard_normal((m, n)), jnp.float32
-    )
-    # same m_tile both sides: tile shape could legitimately change MXU
-    # accumulation scheduling; only the pipeline flag may differ. An
-    # ambient SKYLARK_PALLAS_PIPELINE=1 (e.g. a debugging run) must not
-    # make the baseline take the pipe kernel and self-compare.
-    monkeypatch.delenv("SKYLARK_PALLAS_PIPELINE", raising=False)
-    jax.clear_caches()
-    monkeypatch.setattr(pd, "_SCRATCH_CAP_BYTES", 0)
-    plain = np.asarray(pd.rowwise_apply(
-        jlt._alloc.key, jlt.dist, A, s, jlt.scale,
-        m_tile=32, precision="bf16x3"))
-    monkeypatch.setenv("SKYLARK_PALLAS_PIPELINE", "1")
-    # the pipeline flag is read at TRACE time and both calls share static
-    # args — drop the jit cache so the second call really retraces
-    jax.clear_caches()
-    piped = np.asarray(pd.rowwise_apply(
-        jlt._alloc.key, jlt.dist, A, s, jlt.scale,
-        m_tile=32, precision="bf16x3"))
-    np.testing.assert_array_equal(piped, plain)
-
-
-def test_effective_plan_reports_actual_config(monkeypatch):
+def test_effective_plan_reports_actual_config():
     """effective_plan must report what the kernel would RUN, not what was
-    requested: _qualify silently shrinks over-budget m-tiles and
-    _select_pipe can drop the pipeline buffer, so sweep records labeled
-    with requested knobs would lie about the measurement (the m-tile
-    sweep in benchmarks/ keys its rows off this)."""
-    from libskylark_tpu.sketch import params as sketch_params
-
+    requested: _qualify silently shrinks over-budget m-tiles, so sweep
+    records labeled with requested knobs would lie about the measurement
+    (the m-tile sweep in benchmarks/ keys its rows off this)."""
     dist = randgen.Normal()
-    monkeypatch.delenv("SKYLARK_PALLAS_PIPELINE", raising=False)
-    # isolate from the COMMITTED plan cache: on a v5e host the seeded
-    # flagship entry would hit the headline-shape workload below and
-    # flip plan_source to "cache" — this test pins the HEURISTIC report
-    monkeypatch.setattr(sketch_params, "_use_plan_cache", False)
 
     # headline width, requested tile fits: honored; the operator is too
     # big for the VMEM cache (16 MiB > cap) and there are 8 m-tiles, so
-    # it is generated once into HBM; no pipeline without the env. The
-    # plan also names itself (plan_id/precision/plan_source — the
-    # autotuner cache's reporting surface).
+    # it is generated once into HBM. The plan also names itself
+    # (plan_id/precision/plan_source) and says who chose the knobs.
     p = pd.effective_plan(dist, (8192, 8192), jnp.float32, 512,
                           seq_axis=1, m_tile=1024, interpret=True)
     assert p == {"kernel": True, "m_tile": 1024,
                  "operator_residency": "hbm", "operator_cache": False,
-                 "pipelined": False, "precision": "bf16x3",
+                 "precision": "bf16x3",
                  "plan_id": "pallas/mt1024/bf16x3",
-                 "plan_source": "heuristic"}
+                 "plan_source": "arg"}
+    # the id is the one tune/plans.py writes for the same plan
+    from libskylark_tpu.tune.plans import Plan
+    assert p["plan_id"] == Plan("pallas", 1024, "bf16x3").plan_id()
 
     # requested tile exceeds the VMEM plan: pre-shrunk, and the plan says
     # so (this is the silent adjustment the record must surface)
@@ -451,34 +363,102 @@ def test_effective_plan_reports_actual_config(monkeypatch):
                           seq_axis=1, m_tile=1024, interpret=True)
     assert p["m_tile"] == 512
 
-    # pipeline honored only where every grid step regenerates its block
-    # ("per_tile") with the env set: the columnwise big-operator regime
-    # and a single m-tile — not the rowwise headline shape, whose
-    # operator is resident in HBM
-    monkeypatch.setenv("SKYLARK_PALLAS_PIPELINE", "1")
+    # a non-power-of-two request is floored to one, not collapsed to 1
+    p = pd.effective_plan(dist, (8192, 8192), jnp.float32, 512,
+                          seq_axis=1, m_tile=100, interpret=True)
+    assert p["m_tile"] == 64
+
+    # every grid step regenerates its block ("per_tile") in the
+    # columnwise big-operator regime and for a single m-tile — not at the
+    # rowwise headline shape, whose operator is resident in HBM
     p = pd.effective_plan(dist, (8192, 8192), jnp.float32, 1024,
                           seq_axis=1, m_tile=512, interpret=True)
-    assert p["pipelined"] is False and p["operator_residency"] == "hbm"
+    assert p["operator_residency"] == "hbm"
     p = pd.effective_plan(dist, (8192, 8192), jnp.float32, 1024,
                           seq_axis=0, m_tile=512, interpret=True)
-    assert p["pipelined"] is True and p["operator_cache"] is False
     assert p["operator_residency"] == "per_tile"
+    assert p["operator_cache"] is False
     p = pd.effective_plan(dist, (512, 8192), jnp.float32, 1024,
                           seq_axis=1, m_tile=512, interpret=True)
-    assert p["pipelined"] is True and p["operator_residency"] == "per_tile"
+    assert p["operator_residency"] == "per_tile"
 
-    # small operator: VMEM cache engages and suppresses the pipeline
-    # (cache already amortizes generation)
+    # small operator: the VMEM cache engages
     p = pd.effective_plan(dist, (1024, 1024), jnp.float32, 128,
                           seq_axis=1, m_tile=256, interpret=True)
-    assert p["operator_cache"] is True and p["pipelined"] is False
+    assert p["operator_cache"] is True
     assert p["operator_residency"] == "vmem"
 
     # unsupported dtype: the apply would take the XLA fallback
     p = pd.effective_plan(dist, (1024, 1024), jnp.float64, 128,
                           seq_axis=1, m_tile=256, interpret=True)
-    assert p == {"kernel": False, "plan_id": "xla",
-                 "plan_source": "heuristic"}
+    assert p == {"kernel": False, "plan_id": "xla", "plan_source": "arg"}
+
+
+@pytest.fixture
+def restore_knobs():
+    mt, prec = (sketch_params.get_pallas_m_tile(),
+                sketch_params.get_pallas_precision())
+    yield
+    sketch_params.set_pallas_m_tile(mt)
+    sketch_params.set_pallas_precision(prec)
+
+
+def _precedence_apply(kind, **knobs):
+    """One interpreted apply of ``kind`` at (64 x 1024) -> 96 and the
+    (shape, seq_axis) its plan is asked for."""
+    from libskylark_tpu.sketch.rft import GaussianRFT
+
+    m, n, s = 64, 1024, 96
+    rng = np.random.default_rng(31)
+    if kind == "rft":
+        T = GaussianRFT(n, s, Context(seed=22), sigma=2.0)
+        A = jnp.asarray(rng.standard_normal((m, n)), jnp.float32)
+        out = pd.rft_rowwise_apply(
+            T.subkey(0), T.dist, A, s, T.inscale, T.outscale,
+            np.asarray(T.row_scales()), np.asarray(T.shifts()),
+            interpret=True, **knobs)
+        return np.asarray(out), T.dist, A.shape, 1
+    jlt = JLT(n, s, Context(seed=21))
+    if kind == "rowwise":
+        A = jnp.asarray(rng.standard_normal((m, n)), jnp.float32)
+        apply, seq_axis = pd.rowwise_apply, 1
+    else:
+        A = jnp.asarray(rng.standard_normal((n, m)), jnp.float32)
+        apply, seq_axis = pd.columnwise_apply, 0
+    out = apply(jlt._alloc.key, jlt.dist, A, s, jlt.scale, interpret=True,
+                **knobs)
+    return np.asarray(out), jlt.dist, A.shape, seq_axis
+
+
+@pytest.mark.parametrize("given", ["arg", "setter", "default"])
+@pytest.mark.parametrize("kind", ["rowwise", "columnwise", "rft"])
+def test_knob_precedence(kind, given, monkeypatch, restore_knobs):
+    """The tile and the regime of an apply are the call-site argument,
+    else the sketch.params setter, else the default — and nothing else:
+    the plan reported, the plan the apply notes on its span and the bits
+    of the result all follow the same knobs."""
+    if given == "arg":
+        # the setter says otherwise: the argument must win
+        sketch_params.set_pallas_m_tile(32)
+        sketch_params.set_pallas_precision("bf16")
+        knobs, want = dict(m_tile=16, precision="f32"), (16, "f32", "arg")
+    elif given == "setter":
+        sketch_params.set_pallas_m_tile(32)
+        sketch_params.set_pallas_precision("f32")
+        knobs, want = {}, (32, "f32", "heuristic")
+    else:
+        # the default tile, 512, clamped to the 64 rows there are
+        knobs, want = {}, (64, "bf16x3", "heuristic")
+    noted = []
+    monkeypatch.setattr(pd, "note_apply", lambda **kw: noted.append(kw))
+    got, dist, shape, seq_axis = _precedence_apply(kind, **knobs)
+    plan = pd.effective_plan(dist, shape, jnp.float32, 96, seq_axis,
+                             interpret=True, **knobs)
+    assert (plan["m_tile"], plan["precision"], plan["plan_source"]) == want
+    assert [(n["m_tile"], n["precision"], n["plan_source"])
+            for n in noted] == [want]
+    explicit, *_ = _precedence_apply(kind, m_tile=want[0], precision=want[1])
+    np.testing.assert_array_equal(got, explicit)
 
 
 def test_bf16gen2_regime_matches_rounded_operator_oracle():
@@ -554,7 +534,7 @@ def _assert_hbm(dist, shape, s, m_tile):
     plan = pd.effective_plan(dist, shape, jnp.float32, s, 1, m_tile=m_tile,
                              interpret=True)
     assert plan["operator_residency"] == "hbm", plan
-    assert plan["operator_cache"] is False and plan["pipelined"] is False
+    assert plan["operator_cache"] is False
 
 
 @pytest.mark.parametrize("shape", [(64, 512), (50, 1000)],
@@ -725,28 +705,6 @@ def test_hbm_bf16gen2_matches_rounded_operator_oracle(shape, force_hbm):
     ids=["cell", "columnwise", "single_tile", "small_rw", "small_cw"])
 def test_operator_residency_rule(s_dim, n, m, m_tile, rowwise, want):
     assert pd.operator_residency(s_dim, n, m, m_tile, rowwise) == want
-
-
-def test_pipelined_rowwise_single_tile_matches_plain(monkeypatch):
-    """The rowwise pipelined kernel engages only under "per_tile" — here
-    a single m-tile — and must equal the plain kernel bit for bit."""
-    m, n, s = 16, 1024, 96
-    jlt = JLT(n, s, Context(seed=21))
-    A = jnp.asarray(
-        np.random.default_rng(9).standard_normal((m, n)), jnp.float32)
-    kw = dict(m_tile=16, precision="f32", interpret=True)
-    monkeypatch.delenv("SKYLARK_PALLAS_PIPELINE", raising=False)
-    plain = np.asarray(pd.rowwise_apply(
-        jlt._alloc.key, jlt.dist, A, s, jlt.scale, **kw))
-    monkeypatch.setenv("SKYLARK_PALLAS_PIPELINE", "1")
-    plan = pd.effective_plan(jlt.dist, (m, n), jnp.float32, s, 1,
-                             m_tile=16, interpret=True)
-    assert plan["pipelined"] and plan["operator_residency"] == "per_tile"
-    jax.clear_caches()
-    piped = np.asarray(pd.rowwise_apply(
-        jlt._alloc.key, jlt.dist, A, s, jlt.scale, **kw))
-    jax.clear_caches()
-    np.testing.assert_array_equal(piped, plain)
 
 
 @pytest.mark.tpu

@@ -122,11 +122,14 @@ class TestInterpretOracle:
         want = jnp.swapaxes(Y, 1, 2).reshape(mt, NB)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
-    def test_invalid_variant_raises_valueerror(self):
+    @pytest.mark.parametrize("variant", ["Split", "auto", "planned"])
+    def test_invalid_variant_raises_valueerror(self, variant):
+        """"fused" or "split", given by the caller: nothing else — the
+        cache-steered "auto" and "planned" went with the cache."""
         T = FastGaussianRFT(512, 512, Context(seed=15))
         with pytest.raises(ValueError, match="variant"):
             pf.features_rows(T, _X(8, 512), interpret=True,
-                             variant="Split")
+                             variant=variant)
 
     def test_deterministic_across_calls(self):
         T = FastGaussianRFT(512, 512, Context(seed=12))

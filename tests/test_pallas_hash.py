@@ -213,6 +213,31 @@ class TestQualifyAndDispatch:
         out = T.apply(A, sk.COLUMNWISE)
         assert np.isfinite(np.asarray(out)).all()
 
+    def test_try_apply_takes_only_the_env_pin(self, monkeypatch,
+                                              mem_plan_cache):
+        """Where the kernel qualifies (a TPU, played here), a cached
+        "pallas" plan for the workload does not steer an eager apply:
+        only ``SKYLARK_HASH_KERNEL`` routes it to the kernel."""
+        T = sk.CWT(40, 16, Context(seed=0))
+        A = jnp.asarray(np.ones((40, 3), np.float32))
+        mem_plan_cache.put(
+            tune.hash_workload("CWT", A.shape, A.dtype, 16, seq_axis=0),
+            tune.Plan("pallas"), source="measured", value=1.0)
+        monkeypatch.setattr(ph, "qualify", lambda *a, **k: (True, "ok"))
+        calls = []
+        monkeypatch.setattr(
+            ph, "cwt_apply",
+            lambda kd, A, **kw: calls.append(kw["accum"]) or "served")
+        monkeypatch.delenv("SKYLARK_HASH_KERNEL", raising=False)
+        assert ph.try_apply(T, A, rowwise=False) is None
+        monkeypatch.setenv("SKYLARK_HASH_KERNEL", "xla")
+        assert ph.try_apply(T, A, rowwise=False) is None
+        monkeypatch.setenv("SKYLARK_HASH_KERNEL", "pallas_exact")
+        assert ph.try_apply(T, A, rowwise=False) == "served"
+        monkeypatch.setenv("SKYLARK_HASH_KERNEL", "pallas")
+        assert ph.try_apply(T, A, rowwise=False) == "served"
+        assert calls == ["exact", "mxu"]
+
 
 class TestTuneServeBuckets:
     def test_hash_candidates_and_cpu_ranking(self):

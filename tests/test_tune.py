@@ -1,8 +1,8 @@
 """Autotuner subsystem tests (libskylark_tpu/tune/): plan-cache disk
 round-trip, deterministic offline cost ranking (including the r03
-m-tile ordering reproduced with zero TPU access), and the dispatch
-precedence — an injected cache entry must override the heuristic, and
-every explicit override must beat the cache."""
+m-tile ordering reproduced with zero TPU access), and what the eager
+dispatch in sketch/ does beside it — the cache never steers it: the
+argument beats the setter beats the default."""
 
 import json
 
@@ -14,7 +14,6 @@ import pytest
 from libskylark_tpu import tune
 from libskylark_tpu.base import randgen
 from libskylark_tpu.base.context import Context
-from libskylark_tpu.sketch import JLT
 from libskylark_tpu.sketch import params as sketch_params
 from libskylark_tpu.sketch import pallas_dense as pd
 
@@ -53,10 +52,14 @@ class TestWorkloadAndPlans:
         assert w1.bucket() == (128, 1024, 128)
 
     def test_plan_id_and_dict_roundtrip(self):
-        p = tune.Plan("pallas", m_tile=512, precision="bf16x3",
-                      pipeline=True)
-        assert p.plan_id() == "pallas/mt512/bf16x3/pipe"
+        p = tune.Plan("pallas", m_tile=512, precision="bf16x3")
+        assert p.plan_id() == "pallas/mt512/bf16x3"
         assert tune.Plan.from_dict(p.to_dict()) == p
+        assert tune.Plan.from_plan_id(p.plan_id()) == p
+        # what an older tree stored for its pipelined-generation kernel
+        # still parses, to the plan without it
+        assert tune.Plan.from_dict(dict(p.to_dict(), pipeline=True)) == p
+        assert tune.Plan.from_plan_id("pallas/mt512/bf16x3/pipe") == p
         assert tune.Plan.from_dict(tune.Plan("xla").to_dict()) == \
             tune.Plan("xla")
 
@@ -86,7 +89,7 @@ class TestCostRanking:
 
     def test_mtile_ordering(self):
         """With zero TPU access, the offline ranking orders the m-tiles
-        (256, 512 at the bf16x3 non-pipelined regime) the way the chip
+        (256, 512 at the bf16x3 regime) the way the chip
         does (sketch/params.py m-tile note; 23.4 against 24.8 ms at the
         cell's shape, PR 27): 512 over 256 — with the operator resident
         by the grid steps and the plane re-reads alone."""
@@ -135,11 +138,9 @@ class TestOperatorResidencyOnePredicate:
          ((8192, 65536), 1024, 512, 0, "per_tile")],  # columnwise big S
         ids=["headline_hbm", "small_vmem", "single_tile", "columnwise"])
     def test_cost_plan_and_kernel_agree(self, shape, s, m_tile, seq_axis,
-                                        want, monkeypatch):
+                                        want):
         import functools
 
-        monkeypatch.setattr(sketch_params, "_use_plan_cache", False)
-        monkeypatch.delenv("SKYLARK_PALLAS_PIPELINE", raising=False)
         n, m = shape[seq_axis], shape[1 - seq_axis]
         m_tiles = m // m_tile
         assert pd.operator_residency(s, n, m, m_tile,
@@ -263,186 +264,56 @@ class TestPlanCacheDisk:
         assert tune.default_cache_path() == "/tmp/custom.json"
 
 
-class TestDispatchConsultsCache:
-    """The acceptance criterion: an injected cache entry provably
-    overrides the heuristic at the dispatch sites."""
+class TestEagerDispatchIgnoresCache:
+    """The eager applies in sketch/ never read the plan cache: the knobs
+    are the call-site argument, else the sketch.params setter, else the
+    default, whatever entry the cache holds for the workload."""
 
     SHAPE = (64, 1024)
     S = 96
 
-    def _workload(self, seq_axis=1):
-        return tune.dense_workload("normal", self.SHAPE,
-                                   jnp.dtype("float32"), self.S,
-                                   seq_axis)
+    def _inject(self, cache):
+        cache.put(tune.dense_workload("normal", self.SHAPE,
+                                      jnp.dtype("float32"), self.S, 1),
+                  tune.Plan("pallas", 16, "f32"),
+                  source="measured", value=1.0)
 
-    def test_effective_plan_heuristic_without_cache(self, injected_cache):
+    def test_effective_plan_heuristic_whatever_is_cached(
+            self, injected_cache):
+        self._inject(injected_cache)
         plan = pd.effective_plan(randgen.Normal(), self.SHAPE,
                                  jnp.float32, self.S, 1, interpret=True)
         assert plan["kernel"] and plan["plan_source"] == "heuristic"
         assert plan["m_tile"] == 64  # default 512 clamped to m
+        assert plan["precision"] == "bf16x3"
 
-    def test_injected_entry_overrides_heuristic(self, injected_cache):
-        injected_cache.put(self._workload(),
-                           tune.Plan("pallas", 16, "f32"),
-                           source="measured", value=1.0)
-        plan = pd.effective_plan(randgen.Normal(), self.SHAPE,
-                                 jnp.float32, self.S, 1, interpret=True)
-        assert plan["plan_source"] == "cache"
-        assert plan["m_tile"] == 16 and plan["precision"] == "f32"
-        assert plan["plan_id"] == "pallas/mt16/f32"
-
-    def test_cached_xla_decision_declines_kernel(self, injected_cache):
-        injected_cache.put(self._workload(), tune.Plan("xla"),
-                           source="measured", value=2.0)
-        jlt = JLT(self.SHAPE[1], self.S, Context(seed=0))
-        A = jnp.asarray(np.random.default_rng(0).standard_normal(
-            self.SHAPE), jnp.float32)
-        assert pd.rowwise_apply(jlt._alloc.key, jlt.dist, A, self.S,
-                                jlt.scale, interpret=True) is None
-        plan = pd.effective_plan(randgen.Normal(), self.SHAPE,
-                                 jnp.float32, self.S, 1, interpret=True)
-        assert plan == {"kernel": False, "plan_id": "xla",
-                        "plan_source": "cache"}
-
-    def test_apply_serves_cached_knobs_bit_equal(self, injected_cache):
-        """The cached plan changes the SCHEDULE, never the bits: an
-        interpret-mode apply under an injected m-tile equals the
-        heuristic apply exactly."""
-        jlt = JLT(self.SHAPE[1], self.S, Context(seed=0))
-        A = jnp.asarray(np.random.default_rng(0).standard_normal(
-            self.SHAPE), jnp.float32)
-        base = pd.rowwise_apply(jlt._alloc.key, jlt.dist, A, self.S,
-                                jlt.scale, precision="f32",
-                                interpret=True)
-        injected_cache.put(self._workload(),
-                           tune.Plan("pallas", 16, "f32"),
-                           source="measured", value=1.0)
-        cached = pd.rowwise_apply(jlt._alloc.key, jlt.dist, A, self.S,
-                                  jlt.scale, interpret=True)
-        assert cached is not None
-        np.testing.assert_allclose(np.asarray(cached), np.asarray(base),
-                                   rtol=2e-6, atol=1e-5)
-
-    def test_explicit_arg_beats_cache(self, injected_cache):
-        injected_cache.put(self._workload(),
-                           tune.Plan("pallas", 16, "f32"),
-                           source="measured", value=1.0)
-        plan = pd.effective_plan(randgen.Normal(), self.SHAPE,
-                                 jnp.float32, self.S, 1, m_tile=32,
-                                 interpret=True)
-        assert plan["m_tile"] == 32          # arg wins
-        assert plan["precision"] == "f32"    # open knob: cache fills it
-
-    def test_env_override_beats_cache(self, injected_cache, monkeypatch):
-        injected_cache.put(self._workload(),
-                           tune.Plan("pallas", 16, "f32"),
-                           source="measured", value=1.0)
-        monkeypatch.setenv("SKYLARK_PALLAS_MTILE", "32")
-        assert sketch_params.pallas_m_tile_overridden()
+    def test_explicit_arg_beats_setter(self, injected_cache):
+        self._inject(injected_cache)
+        sketch_params.set_pallas_m_tile(8)
         try:
             plan = pd.effective_plan(randgen.Normal(), self.SHAPE,
-                                     jnp.float32, self.S, 1,
+                                     jnp.float32, self.S, 1, m_tile=32,
                                      interpret=True)
-            # env tile wins; the global still holds the import-time
-            # value, so the heuristic default (512→clamped 64) serves —
-            # the point is the CACHED 16 must NOT
-            assert plan["m_tile"] != 16
         finally:
-            monkeypatch.delenv("SKYLARK_PALLAS_MTILE")
+            sketch_params.set_pallas_m_tile(512)
+        assert plan["m_tile"] == 32          # arg wins
+        assert plan["plan_source"] == "arg"
+        assert plan["precision"] == "bf16x3"  # open knob: the setter's
 
-    def test_runtime_setter_beats_cache(self, injected_cache):
-        injected_cache.put(self._workload(),
-                           tune.Plan("pallas", 16, "f32"),
-                           source="measured", value=1.0)
+    def test_runtime_setter_beats_default(self, injected_cache):
+        self._inject(injected_cache)
         sketch_params.set_pallas_m_tile(32)
         try:
             plan = pd.effective_plan(randgen.Normal(), self.SHAPE,
                                      jnp.float32, self.S, 1,
                                      interpret=True)
-            assert plan["m_tile"] == 32
         finally:
             sketch_params.set_pallas_m_tile(512)
-
-    def test_cached_fast_regime_not_served_by_default_dispatch(
-            self, injected_cache):
-        """Read-time guard: the cache file is a committed, hand-editable
-        artifact — an entry carrying a throughput-only (or bogus)
-        regime must NOT opt the default dispatch out of the 1e-4
-        oracle; only the m-tile is taken."""
-        for bad in ("bf16", "bf16gen2", "bf16x9"):
-            injected_cache.put(self._workload(),
-                               tune.Plan("pallas", 16, bad),
-                               source="measured", value=1.0)
-            plan = pd.effective_plan(randgen.Normal(), self.SHAPE,
-                                     jnp.float32, self.S, 1,
-                                     interpret=True)
-            assert plan["m_tile"] == 16           # tile still served
-            assert plan["precision"] == "bf16x3"  # regime: default
-
-    def test_pipeline_env_one_beats_cached_xla_decision(
-            self, injected_cache, monkeypatch):
-        """SKYLARK_PALLAS_PIPELINE=1 is an explicit override like the
-        m-tile/precision knobs: a cached backend:'xla' plan must not
-        silently route the A/B to the XLA path."""
-        injected_cache.put(self._workload(), tune.Plan("xla"),
-                           source="ranked")
-        monkeypatch.setenv("SKYLARK_PALLAS_PIPELINE", "1")
-        plan = pd.effective_plan(randgen.Normal(), self.SHAPE,
-                                 jnp.float32, self.S, 1, interpret=True)
-        assert plan["kernel"] is True
-
-    def test_pipeline_env_zero_overrides_cached_plan(
-            self, injected_cache, monkeypatch):
-        """SKYLARK_PALLAS_PIPELINE=0 must beat a cached pipeline=True
-        plan (the escape hatch when a cached pipelined plan
-        misbehaves); =1 still engages it without any plan. Columnwise:
-        the orientation whose big operator is regenerated per tile —
-        the rowwise one keeps it in HBM and has nothing to pipeline,
-        whatever plan is cached."""
-        big = (4096, 4096)
-        for seq_axis in (0, 1):
-            injected_cache.put(
-                tune.dense_workload("normal", big, jnp.dtype("float32"),
-                                    1024, seq_axis),
-                tune.Plan("pallas", 512, "bf16x3", pipeline=True),
-                source="measured", value=1.0)
-
-        def plan(seq_axis):
-            return pd.effective_plan(randgen.Normal(), big, jnp.float32,
-                                     1024, seq_axis, interpret=True)
-
-        monkeypatch.delenv("SKYLARK_PALLAS_PIPELINE", raising=False)
-        assert plan(0)["pipelined"] is True       # plan decides
-        assert plan(0)["operator_residency"] == "per_tile"
-        assert plan(1)["pipelined"] is False      # resident: nothing to hide
-        assert plan(1)["operator_residency"] == "hbm"
-        assert plan(1)["plan_source"] == "cache"
-        monkeypatch.setenv("SKYLARK_PALLAS_PIPELINE", "0")
-        assert plan(0)["pipelined"] is False      # env=0 wins
-
-    def test_gate_disables_consultation(self, injected_cache):
-        injected_cache.put(self._workload(),
-                           tune.Plan("pallas", 16, "f32"),
-                           source="measured", value=1.0)
-        sketch_params.set_use_plan_cache(False)
-        plan = pd.effective_plan(randgen.Normal(), self.SHAPE,
-                                 jnp.float32, self.S, 1, interpret=True)
+        assert plan["m_tile"] == 32
         assert plan["plan_source"] == "heuristic"
-        assert plan["m_tile"] == 64
-
-    def test_columnwise_consults_its_own_key(self, injected_cache):
-        # columnwise workload: input (N, m) = (1024, 64), contracted
-        # axis 0
-        w = tune.dense_workload("normal", (1024, 64),
-                                jnp.dtype("float32"), self.S, 0)
-        injected_cache.put(w, tune.Plan("pallas", 16, "f32"),
-                           source="measured", value=1.0)
-        plan = pd.effective_plan(randgen.Normal(), (1024, 64),
-                                 jnp.float32, self.S, 0, interpret=True)
-        assert plan["m_tile"] == 16 and plan["plan_source"] == "cache"
 
 
-class TestFastfoodDispatchConsultsCache:
+class TestFastfoodExplicitCall:
     def _transform(self):
         from libskylark_tpu.sketch.frft import FastGaussianRFT
 
@@ -452,124 +323,62 @@ class TestFastfoodDispatchConsultsCache:
         return jnp.asarray(np.random.default_rng(3).standard_normal(
             (32, 512)), jnp.float32)
 
-    def test_cached_xla_chain_declines(self, injected_cache):
-        from libskylark_tpu.sketch import pallas_fastfood as pf
-
-        T, A = self._transform(), self._input()
-        w = tune.fastfood_workload("FastGaussianRFT", A.shape, A.dtype,
-                                   T._S)
-        injected_cache.put(w, tune.Plan("xla_chain"), source="measured")
-        assert pf.features_rows(T, A, interpret=True) is None
-
-    def test_explicit_precision_pin_beats_cached_xla_chain(
+    def test_explicit_call_reaches_kernel_whatever_is_cached(
             self, injected_cache):
-        """A cached xla_chain decline applies only to fully-open
-        dispatch: a caller pinning a kernel regime (argument or env)
-        must still reach the kernel — otherwise a precision sweep
-        silently measures the XLA chain under a kernel label."""
+        """The kernel is reached by an explicit call and by nothing
+        else: a cached xla_chain entry for the workload does not decline
+        it — otherwise a precision sweep silently measures the XLA chain
+        under a kernel label."""
         from libskylark_tpu.sketch import pallas_fastfood as pf
 
         T, A = self._transform(), self._input()
         w = tune.fastfood_workload("FastGaussianRFT", A.shape, A.dtype,
                                    T._S)
         injected_cache.put(w, tune.Plan("xla_chain"), source="measured")
-        out = pf.features_rows(T, A, interpret=True, precision="f32")
-        assert out is not None
-        ref = T._features_rows(A)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-4)
-
-    def test_cached_variant_selected(self, injected_cache):
-        from libskylark_tpu.sketch import pallas_fastfood as pf
-
-        T, A = self._transform(), self._input()
-        w = tune.fastfood_workload("FastGaussianRFT", A.shape, A.dtype,
-                                   T._S)
-        injected_cache.put(w, tune.Plan("split", precision="f32"),
-                           source="measured")
-        out = pf.features_rows(T, A, interpret=True)
-        assert out is not None
-        assert pf.last_served_variant == "split"
-        # oracle: the cached variant computes the same features as the
-        # XLA chain
-        ref = T._features_rows(A)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-4)
-
-
-    def test_planned_variant_needs_a_kernel_plan(self, injected_cache):
-        """The transform's own dispatch asks for "planned": no cached
-        kernel plan, no kernel (it compiles on no chip tried so far);
-        a cached variant is served."""
-        from libskylark_tpu.sketch import pallas_fastfood as pf
-
-        T, A = self._transform(), self._input()
-        assert pf.features_rows(T, A, interpret=True,
-                                variant="planned") is None
-        w = tune.fastfood_workload("FastGaussianRFT", A.shape, A.dtype,
-                                   T._S)
-        injected_cache.put(w, tune.Plan("xla_chain"), source="measured")
-        assert pf.features_rows(T, A, interpret=True,
-                                variant="planned") is None
-        injected_cache.put(w, tune.Plan("split", precision="f32"),
-                           source="measured")
-        out = pf.features_rows(T, A, interpret=True, variant="planned")
+        pf.last_served_variant = None
+        out = pf.features_rows(T, A, interpret=True, precision="f32",
+                               variant="split")
         assert out is not None and pf.last_served_variant == "split"
-        np.testing.assert_allclose(np.asarray(out),
-                                   np.asarray(T._features_rows(A)),
+        ref = T._features_rows(A)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-4)
 
-    def test_cache_pinned_fused_rejection_is_loud(
-            self, injected_cache, monkeypatch):
-        """A planned kernel that Mosaic rejects must raise — never turn
-        into the split variant or the XLA chain silently (on the chip a
-        silent fallback serves a different program than the plan, the
-        record and the caller believe)."""
+    @pytest.mark.parametrize("variant,launcher",
+                             [("fused", "_launch"),
+                              ("split", "_launch_split")])
+    def test_requested_variant_rejection_is_loud(self, variant, launcher,
+                                                 monkeypatch):
+        """An explicitly requested variant that Mosaic rejects must
+        raise — never turn into the other variant or the XLA chain
+        silently (on the chip a silent fallback serves a different
+        program than the record and the caller believe)."""
         from libskylark_tpu.sketch import pallas_fastfood as pf
 
         T, A = self._transform(), self._input()
-        w = tune.fastfood_workload("FastGaussianRFT", A.shape, A.dtype,
-                                   T._S)
-        injected_cache.put(w, tune.Plan("fused", precision="f32"),
-                           source="measured")
         monkeypatch.setattr(pf, "supported", lambda *a: True)
         monkeypatch.setattr(
-            pf, "_launch",
+            pf, launcher,
             lambda *a, **k: (_ for _ in ()).throw(
                 RuntimeError("simulated Mosaic rejection")))
         with pytest.raises(RuntimeError, match="simulated Mosaic"):
-            pf.features_rows(T, A, precision="f32")
+            pf.features_rows(T, A, precision="f32", variant=variant)
 
+    def test_transform_apply_takes_the_xla_chain(self, monkeypatch):
+        """``FastRFT.apply`` does not reach the kernel: Mosaic rejects
+        both variants on a v5e, so the transform's own path is the XLA
+        chain wherever it runs."""
+        from libskylark_tpu.sketch import ROWWISE, COLUMNWISE
+        from libskylark_tpu.sketch import pallas_fastfood as pf
 
-class TestBenchFeedback:
-    def test_bench_records_measurement_into_cache(self, injected_cache):
-        import bench
+        def no_kernel(*a, **k):
+            raise AssertionError("FastRFT.apply reached the kernel")
 
-        bench._record_plan_measurement(
-            {"kernel": True, "m_tile": 512, "precision": "bf16x3",
-             "pipelined": False, "plan_id": "pallas/mt512/bf16x3"},
-            8192, 8192, 1024, 86.3)
-        w = _flagship_workload(device_kind=tune.current_device_kind())
-        ent = injected_cache.entry(w)
-        assert ent and ent["source"] == "measured"
-        assert ent["value"] == 86.3
-        assert tune.Plan.from_dict(ent["plan"]).m_tile == 512
-
-    def test_fast_regimes_never_recorded(self, injected_cache):
-        import bench
-
-        bench._record_plan_measurement(
-            {"kernel": True, "m_tile": 512, "precision": "bf16",
-             "pipelined": False}, 8192, 8192, 1024, 120.0)
-        w = _flagship_workload(device_kind=tune.current_device_kind())
-        assert injected_cache.entry(w) is None
-
-    def test_xla_fallback_never_recorded(self, injected_cache):
-        import bench
-
-        bench._record_plan_measurement({"kernel": False}, 8192, 8192,
-                                       1024, 50.0)
-        assert injected_cache.entries == {}
+        monkeypatch.setattr(pf, "features_rows", no_kernel)
+        T, A = self._transform(), self._input()
+        ref = np.asarray(T._features_rows(A))
+        np.testing.assert_array_equal(np.asarray(T.apply(A, ROWWISE)), ref)
+        np.testing.assert_array_equal(
+            np.asarray(T.apply(A.T, COLUMNWISE)), ref.T)
 
 
 class TestCostCalibration:

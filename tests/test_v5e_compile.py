@@ -14,7 +14,6 @@ import pytest
 
 from libskylark_tpu.base import randgen
 from libskylark_tpu.sketch import pallas_dense as pd
-from libskylark_tpu.sketch import params as sketch_params
 from libskylark_tpu.sketch.dense import BLOCK_COLS
 
 ROWS, N = 65536, 8192           # the jlt_apply cell's panel
@@ -31,12 +30,6 @@ def one_chip():
     except Exception as e:  # noqa: BLE001 — no compiler, or the library is held
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     return SingleDeviceSharding(topo.devices[0])
-
-
-@pytest.fixture(autouse=True)
-def _heuristic_plan(monkeypatch):
-    monkeypatch.setattr(sketch_params, "_use_plan_cache", False)
-    monkeypatch.delenv("SKYLARK_PALLAS_PIPELINE", raising=False)
 
 
 def _compile(call, one_chip, shape, s_dim, seq_axis, precision, *operands,
