@@ -278,6 +278,25 @@ def step_sketch() -> None:
     report("sketch.CWT.sparse", first, run, nnz=Asp.nnz,
            backend=(f"xla:{program.name}" if program.stats.executions > ran
                     else "xla:eager"), err=f"{err:.2e}")
+    # the same program rowwise: on a TPU the kernel that builds each result
+    # tile in VMEM adds the terms up, held here to XLA's scatter-add of the
+    # same lanes (the terms of a cell in another order: last ulp)
+    from libskylark_tpu.sketch import sparse_serve
+
+    Srows = SparseMatrix.from_scipy(Asp.T.tocsr())
+    lanes = Srows.csr_device()
+    kernel = sparse_serve.sparse_kernel(Srows.shape, S, int(lanes[0].shape[0]),
+                                        lanes[0].dtype, True)
+    if not REHEARSE and kernel != "pallas_rows":
+        raise AssertionError(f"rowwise sparse CWT took {kernel} on a TPU")
+    ref = jax.jit(sparse_serve.cwt_sparse_serve_apply,
+                  static_argnames=("s_dim", "rowwise", "shape"))(
+        jax.random.key_data(C._alloc.key), *lanes, s_dim=S, rowwise=True,
+        shape=Srows.shape)
+    out, first, run = timed(lambda: C.apply(Srows, sk.ROWWISE))
+    err = close(out, ref, "CWT sparse rowwise vs xla scatter", tol=1e-6)
+    report("sketch.CWT.sparse_rows", first, run, nnz=Asp.nnz,
+           kernel=kernel, err=f"{err:.2e}")
 
     # feature maps at BASELINE.md's shape, dense RFT and Fastfood, each
     # against its explicit operator on the host for some rows

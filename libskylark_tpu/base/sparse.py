@@ -57,6 +57,8 @@ class SparseMatrix:
         # device-resident layouts by value dtype: {"csr": the placed
         # lanes, "coo": the triplets derived from them on first coo()}
         self._device: dict = {}
+        # the canonical scipy CSR this was attached from, when it was one
+        self._row_major = None
 
     # -- constructors --
 
@@ -66,8 +68,14 @@ class SparseMatrix:
         zero-copy when already CSC — ref: python sketch.py _ScipyAdapter)."""
         import scipy.sparse as sp
 
-        A = A.tocsc()
-        return cls(A.indptr, A.indices, A.data, A.shape)
+        csc = A.tocsc()
+        out = cls(csc.indptr, csc.indices, csc.data, csc.shape)
+        if A.format == "csr" and A.has_canonical_format:
+            # attached by reference, like a CSC's buffers: the row-major
+            # lanes are this matrix's own (1.9 s of conversions a 19 M-nnz
+            # row block otherwise, on every first placement — PERF.md PR 31)
+            out._row_major = A
+        return out
 
     @classmethod
     def from_coo(
@@ -234,9 +242,11 @@ class SparseMatrix:
         f32 precision-policy default)."""
         eff = np.dtype(dtype) if dtype is not None else np.dtype(
             jax.dtypes.canonicalize_dtype(self.device_dtype))
-        A = self.to_scipy().tocsr()
-        A.sum_duplicates()
-        A.sort_indices()
+        A = self._row_major
+        if A is None:
+            A = self.to_scipy().tocsr()
+            A.sum_duplicates()
+            A.sort_indices()
         return (np.asarray(A.data, dtype=eff),
                 np.asarray(A.indices, dtype=np.int32),
                 np.asarray(A.indptr, dtype=np.int32))

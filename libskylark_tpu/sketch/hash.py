@@ -27,6 +27,12 @@ values still gathered from their stream's table), and one O(nnz)
 scatter-add in row-major order, which is the order the dense
 ``segment_sum`` retires the same terms in: the result is bit-equal to
 ``apply(A.todense())`` (ref: sketch/hash_transform_local_sparse.hpp:12-152).
+On a TPU the rowwise apply adds the terms up in a Pallas kernel instead,
+each tile of result rows in VMEM from its own run of lanes
+(``sparse_serve.sparse_kernel`` says where; ``sketch/pallas_sparse.py``
+``hash_rows_apply``): the same exact products, the terms of one cell added
+in another order — last ulp against the scatter, a cell with one term to
+the bit.
 """
 
 from __future__ import annotations
@@ -45,7 +51,7 @@ from libskylark_tpu.telemetry import trace as _trace
 _SPARSE_NNZ = _metrics.counter(
     "sketch.sparse_nnz",
     "Stored nonzeros sketched through the compiled sparse hash apply, "
-    "by family")
+    "by family and kernel")
 
 
 def value_stream(kind: tuple, key, n: int, dtype) -> jnp.ndarray:
@@ -76,7 +82,7 @@ def _sparse_program():
 
     return compiled(
         cwt_sparse_serve_apply, name="sketch.hash_sparse",
-        static_argnames=("s_dim", "rowwise", "shape", "values"))
+        static_argnames=("s_dim", "rowwise", "shape", "values", "kernel"))
 
 
 def cwt_serve_apply(key_data, A, *, s_dim: int, rowwise: bool) -> jnp.ndarray:
@@ -155,19 +161,21 @@ class HashTransform(SketchTransform):
     # dataflow form of ref: sketch/hash_transform_local_sparse.hpp:12-152) --
 
     def _apply_sparse(self, A, *, rowwise: bool) -> jnp.ndarray:
-        from libskylark_tpu.sketch.sparse_serve import lookup
+        from libskylark_tpu.sketch.sparse_serve import lookup, sparse_kernel
 
         data, indices, indptr = A.csr_device()
         key_data = jax.random.key_data(self._alloc.key)
         values = self._value_kind()
+        kernel = sparse_kernel(A.shape, self._S, int(data.shape[0]),
+                               data.dtype, rowwise)
         with _trace.span("sketch.dispatch",
                          {"path": "sparse", "family": self.sketch_type,
                           "nnz": A.nnz, "nnz_class": int(data.shape[0]),
-                          "lookup": lookup(values)}):
+                          "lookup": lookup(values), "kernel": kernel}):
             out = _sparse_program()(
                 key_data, data, indices, indptr, s_dim=self._S,
-                rowwise=rowwise, shape=A.shape, values=values)
-        _SPARSE_NNZ.inc_always(A.nnz, family=self.sketch_type)
+                rowwise=rowwise, shape=A.shape, values=values, kernel=kernel)
+        _SPARSE_NNZ.inc_always(A.nnz, family=self.sketch_type, kernel=kernel)
         return out
 
     def _apply_columnwise_sparse(self, A) -> jnp.ndarray:

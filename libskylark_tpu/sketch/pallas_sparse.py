@@ -284,3 +284,269 @@ def cwt_sparse_apply(key_data, data, rows, cols, *, s_dim: int,
         jnp.asarray(cols)[None], s_dim=s_dim, rowwise=rowwise,
         shape=shape, accum=accum, interpret=interpret)
     return out[0]
+
+
+# ---------------------------------------------------------------------------
+# rowwise hash sketch of CSR lanes: every result tile built in VMEM
+# ---------------------------------------------------------------------------
+#
+# The direct sparse apply's program (``sketch.hash_sparse``) takes this
+# kernel in place of ``out.at[rows, bucket].add(term)`` — XLA's sort plus
+# element scatter into HBM, 222 of 235 ms at the cwt_sparse_apply cell —
+# wherever :func:`rows_plan` fits. It needs no table and no gather: bucket
+# and term are computed at the lane by the XLA prologue (``randgen
+# .stream_at``), and CSR lanes are already in row order, so a tile of R
+# result rows is fed by one contiguous run of lanes,
+# ``indptr[R·t] … indptr[R·(t+1)]``, and no row id is ever stored: a
+# lane's row within its tile is the count of the tile's inner row starts
+# at or before it.
+#
+# The v5e has no vector scatter, so a tile is accumulated by compares and a
+# contraction. With s_dim = H·128, split the bucket as b = hi·128 + lo. For
+# each chunk of 128 lanes build two factors from lane-oriented vectors
+# broadcast along sublanes,
+#
+#     A[j, l]  = term_l · [hi_l·R + row_l − R·t = j]     (R·H × 128)
+#     B[lo, l] = [lo_l = lo]                             (128 × 128)
+#
+# and add A·Bᵀ (the contraction over the lane axis) to an (R·H × 128)
+# float32 accumulator: ``acc[hi·R : hi·R + R, :]`` is the tile's
+# ``Z[R·t : R·t + R, hi·128 : hi·128 + 128]``. The term goes through the
+# MXU as three bfloat16 pieces that sum to the float32 value exactly, B is
+# 0/1, so every product is exact and only the ORDER in which the terms of
+# one cell are added differs from the scatter's (last ulp); a cell with a
+# single term holds it to the bit. Lanes of a chunk that belong to a
+# neighbouring tile, and the lane padding, are masked by position and add
+# nothing. A non-finite value poisons its tile's rows (0·inf in the
+# contraction), the caveat ``pallas_hash``'s "mxu" mode documents; values
+# under 2⁻¹⁰⁰ may lose low bits (a bfloat16 piece goes subnormal).
+#
+# One grid step builds ``_ROWS_A_STEP`` result rows: it streams its run of
+# lanes from HBM through a double-buffered ring of ``_ROWS_BLOCK``-chunk
+# blocks (the lane arrays are a (lanes/128, 128) view, read 8-chunk
+# aligned), fetches its row starts into SMEM a step ahead, and writes its
+# block of the result once, in natural layout.
+
+LANES = 128
+_ROWS_A_STEP = 256      # result rows a grid step: 1024 steps at 262144 rows
+_ROWS_BLOCK = 128       # chunks a DMA (64 KiB an array)
+# chunks an iteration of the inner loop, so that the MXU results of one
+# overlap the factor building of the next: at the cell 34.5 ms unrolled by
+# 1, 20.6 by 4, 17.4 by 6, 17.7 by 8, 21.9 by 12 (an iteration may overrun
+# its tile, and the overrun is masked work) — PERF.md PR 31
+_ROWS_UNROLL = 6
+# entries of the scalar-prefetched table of tile starts: a quarter of the
+# v5e's 1 MiB of SMEM (the whole ``indptr`` of the cell is 4 B too many)
+_ROWS_MAX_TABLE = 1 << 16
+_STARTS_WINDOW = 1024   # row starts a DMA into SMEM: 8 rows of the
+                        # (rows/128, 128) view, so every read is tile-aligned
+
+
+def rows_plan(n_rows: int, s_dim: int, lanes: int, dtype) -> Optional[tuple]:
+    """``(R, H, G)`` — rows a tile, 128-wide bucket groups, tiles a grid
+    step — when the kernel fits a rowwise apply of these extents, else
+    None: float32 values, ``s_dim`` = H·128 with H ≤ 16 (R = 8 rows a tile,
+    16 where H is odd: the bfloat16 factor A wants R·H a multiple of 16),
+    the row count a multiple of R, the lane extent a multiple of 1024
+    (every ``lane_class`` ≥ 2¹⁵ is) and at least a block, the table of tile
+    starts inside SMEM. Shapes only: whether the backend compiles Mosaic
+    kernels is the caller's question."""
+    h = s_dim // LANES
+    if (jnp.dtype(dtype) != jnp.float32
+            or s_dim != h * LANES or not 1 <= h <= 16
+            or lanes < _ROWS_BLOCK * LANES or lanes % 1024):
+        return None
+    r = 16 if h % 2 else 8
+    n_tiles = n_rows // r
+    if n_rows < r or n_rows % r or n_tiles >= _ROWS_MAX_TABLE:
+        return None
+    g = _ROWS_A_STEP // r
+    while n_tiles % g:
+        g //= 2
+    return r, h, g
+
+
+def _kernel_rows(R, H, G, n_chunks, tptr, term_hbm, bucket_hbm, starts_hbm,
+                 out_ref, tbuf, bbuf, acc_ref, sem, sbuf, ssem):
+    """One grid step: G row tiles from their contiguous run of lanes."""
+    NB, U, J = _ROWS_BLOCK, _ROWS_UNROLL, R * H
+    step = pl.program_id(0)
+    t0 = step * G
+    run_lo, run_hi = tptr[t0], tptr[t0 + G]
+    first = (run_lo >> 10) << 3         # the run's first chunk, 8-aligned
+    n_blocks = jnp.where(
+        run_hi > run_lo, (((run_hi + LANES - 1) >> 7) - first + NB - 1) // NB,
+        0)
+
+    def starts_copy(of_step, slot):
+        window = (of_step * (G * R)) // _STARTS_WINDOW
+        return pltpu.make_async_copy(
+            starts_hbm.at[pl.ds(pl.multiple_of(window * 8, 8), 8)],
+            sbuf.at[slot], ssem.at[slot])
+
+    def block_copies(k, slot):
+        # the last block is read from where it still ends inside the lanes
+        src = pl.ds(pl.multiple_of(
+            jnp.minimum(first + k * NB, n_chunks - NB), 8), NB)
+        return (pltpu.make_async_copy(term_hbm.at[src],
+                                      tbuf.at[slot, pl.ds(0, NB)],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(bucket_hbm.at[src],
+                                      bbuf.at[slot, pl.ds(0, NB)],
+                                      sem.at[1, slot]))
+
+    @pl.when(step == 0)
+    def _own_starts():
+        starts_copy(0, 0).start()
+
+    @pl.when(step + 1 < pl.num_programs(0))
+    def _next_starts():
+        starts_copy(step + 1, (step + 1) % 2).start()
+
+    @pl.when(n_blocks > 0)
+    def _first_block():
+        for c in block_copies(0, 0):
+            c.start()
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    starts_copy(step, step % 2).wait()
+
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    j_of = jax.lax.broadcasted_iota(jnp.int32, (J, LANES), 0)
+    lo_of = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+    contract_lanes = (((1,), (1,)), ((), ()))
+
+    def block(k, carry):
+        slot = k % 2
+
+        @pl.when(k + 1 < n_blocks)
+        def _next_block():
+            for c in block_copies(k + 1, 1 - slot):
+                c.start()
+
+        for c in block_copies(k, slot):
+            c.wait()
+        block_lo = first + k * NB
+        base = jnp.minimum(block_lo, n_chunks - NB)     # chunk of ring row 0
+
+        def tile(g, carry):
+            p0, p1 = tptr[t0 + g], tptr[t0 + g + 1]
+            lo = jnp.maximum(p0 >> 7, block_lo)
+            hi = jnp.minimum((p1 + LANES - 1) >> 7, block_lo + NB)
+            # the unrolled loop may overrun hi, into a neighbour's lanes
+            # or rows of the ring no copy wrote: masked, like the borders
+            p_end = jnp.minimum(p1, hi * LANES)
+            row0 = ((t0 + g) * R) % _STARTS_WINDOW
+            inner = [sbuf[step % 2, (row0 + r) >> 7, (row0 + r) & (LANES - 1)]
+                     for r in range(1, R)]
+
+            def chunk(c, acc):
+                i = c - base
+                pos = c * LANES + lane
+                mine = (pos >= p0) & (pos < p_end)
+                x = jnp.where(mine, tbuf[slot, pl.ds(i, 1), :], 0.0)
+                bucket = bbuf[slot, pl.ds(i, 1), :]
+                row = jnp.zeros((1, LANES), jnp.int32)
+                for start in inner:
+                    row = row + (pos >= start).astype(jnp.int32)
+                # x = x0 + x1 + x2 exactly, each piece a bfloat16
+                x0 = x.astype(jnp.bfloat16).astype(jnp.float32)
+                rest = x - x0
+                x1 = rest.astype(jnp.bfloat16).astype(jnp.float32)
+                x2 = rest - x1
+                # the mask as float32 and a product: Mosaic refuses
+                # where(mask, x, 0) with x broadcast along sublanes
+                at_j = (j_of == jnp.where(mine, (bucket >> 7) * R + row, -1)
+                        ).astype(jnp.float32)
+                a3 = jnp.concatenate(
+                    [(at_j * piece).astype(jnp.bfloat16)
+                     for piece in (x0, x1, x2)], axis=0)
+                b = (lo_of == (bucket & (LANES - 1))
+                     ).astype(jnp.float32).astype(jnp.bfloat16)
+                # one bfloat16 pass, said aloud: the package's default
+                # matmul precision is float32, which Mosaic refuses for
+                # bfloat16 operands; the products are exact either way
+                part = jax.lax.dot_general(
+                    a3, b, contract_lanes,
+                    precision=jax.lax.Precision.DEFAULT,
+                    preferred_element_type=jnp.float32)
+                return acc + ((part[:J] + part[J:2 * J]) + part[2 * J:])
+
+            def chunks(it, acc):
+                for u in range(U):
+                    acc = chunk(lo + it * U + u, acc)
+                return acc
+
+            @pl.when((hi > lo) & (p1 > p0))
+            def _accumulate():
+                acc_ref[g] += jax.lax.fori_loop(
+                    0, (hi - lo + U - 1) // U, chunks,
+                    jnp.zeros((J, LANES), jnp.float32))
+
+            return carry
+
+        jax.lax.fori_loop(0, G, tile, 0)
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+
+    def write(g, carry):
+        # a loop, not G·H static copies: Mosaic's lowering takes 5 ms a
+        # ref access on the host, 2.6 s of a first apply for 512 of them
+        rows = pl.ds(pl.multiple_of(g * R, R), R)
+        for h in range(H):
+            out_ref[rows, h * LANES:(h + 1) * LANES] = (
+                acc_ref[g, h * R:(h + 1) * R, :])
+        return carry
+
+    jax.lax.fori_loop(0, G, write, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("n_rows", "s_dim", "plan",
+                                             "interpret"))
+def _rows_call(tile_ptr, term, bucket, starts, *, n_rows, s_dim, plan,
+               interpret):
+    R, H, G = plan
+    NB = _ROWS_BLOCK
+    lane_array = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        functools.partial(_kernel_rows, R, H, G, term.shape[0]),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n_rows // (G * R),),
+            in_specs=[lane_array, lane_array, lane_array],
+            out_specs=pl.BlockSpec((G * R, s_dim), lambda s, tptr: (s, 0)),
+            scratch_shapes=[
+                # the ring: two blocks, and what an unrolled loop overruns
+                pltpu.VMEM((2, NB + 8, LANES), jnp.float32),
+                pltpu.VMEM((2, NB + 8, LANES), jnp.int32),
+                pltpu.VMEM((G, R * H, LANES), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((2, 8, LANES), jnp.int32),
+                pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((n_rows, s_dim), jnp.float32),
+        # sequential: a step starts the next step's copy of its row starts
+        compiler_params=compiler_params("arbitrary"),
+        interpret=interpret,
+    )(tile_ptr, term, bucket, starts)
+
+
+def hash_rows_apply(term, bucket, indptr, *, n_rows: int, s_dim: int,
+                    interpret: bool = False) -> jnp.ndarray:
+    """``zeros((n_rows, s_dim)).at[rows, bucket].add(term)`` for CSR lanes
+    in row order — ``term``/``bucket`` (lanes,), ``indptr`` (n_rows + 1,);
+    the row of a lane is what ``indptr`` says — by the kernel above.
+    Traceable: the sparse program calls it after its lane prologue.
+    :func:`rows_plan` must fit. Differs from the scatter only in the order
+    the terms of one cell are added."""
+    lanes = term.shape[0]
+    plan = rows_plan(n_rows, s_dim, lanes, term.dtype)
+    if plan is None:
+        raise ValueError(
+            f"rowwise sparse kernel does not fit rows={n_rows} "
+            f"s_dim={s_dim} lanes={lanes} dtype={term.dtype}")
+    starts = jnp.pad(indptr[:-1], (0, -n_rows % _STARTS_WINDOW))
+    return _rows_call(
+        indptr[::plan[0]], term.reshape(-1, LANES),
+        bucket.reshape(-1, LANES), starts.reshape(-1, LANES),
+        n_rows=n_rows, s_dim=s_dim, plan=plan, interpret=interpret)

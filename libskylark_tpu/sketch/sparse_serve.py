@@ -42,10 +42,12 @@ Exactness contract (the CI sparse-serve gate pins it):
 
 The CWT path is where sparsity pays: O(nnz) scatter work instead of the
 dense path's O(N·m) segment-sum — the committed
-``benchmarks/results_sparse_cpu.json`` A/B quantifies it. On TPU the
-scatter-free Pallas sparse kernel (:mod:`libskylark_tpu.sketch
-.pallas_sparse`) replaces this scatter per the serve ladder's
-autotuned selection.
+``benchmarks/results_sparse_cpu.json`` A/B quantifies it. On a TPU the
+direct rowwise apply (``HashTransform.apply`` on a ``SparseMatrix``)
+replaces this scatter by the kernel that builds each result tile in VMEM
+(:func:`libskylark_tpu.sketch.pallas_sparse.hash_rows_apply`) wherever
+:func:`sparse_kernel` finds its shapes; the serve flush's vmapped lanes,
+the columnwise apply and the CPU keep the scatter.
 
 The CSR lane format (and :func:`scatter_dense`) is also the intake of
 the **graph serve endpoints** (docs/qos): ``submit_graph_ase`` /
@@ -73,9 +75,28 @@ def lookup(values: tuple) -> str:
     return "lane" if values == ("CWT",) else "lane+table"
 
 
+def sparse_kernel(shape: tuple, s_dim: int, lanes: int, dtype,
+                  rowwise: bool) -> str:
+    """Which program accumulates a direct sparse apply's terms:
+    ``"pallas_rows"`` — the kernel that builds each result tile in VMEM
+    (:func:`libskylark_tpu.sketch.pallas_sparse.hash_rows_apply`) — on a
+    TPU, rowwise, where its plan fits (float32, ``s_dim`` a multiple of 128
+    up to 2048, rows a multiple of 8 — 16 where ``s_dim`` is an odd
+    multiple — lanes a multiple of 1024 and at least 16384), else
+    ``"xla_scatter"``. Decided from what the apply can observe, by
+    ``HashTransform._apply_sparse``; the sparse ``sketch.dispatch`` span
+    and the ``sketch.sparse_nnz`` counter carry it."""
+    from libskylark_tpu.sketch import pallas_sparse
+
+    fits = rowwise and pallas_sparse.available() and pallas_sparse.rows_plan(
+        int(shape[0]), s_dim, lanes, dtype) is not None
+    return "pallas_rows" if fits else "xla_scatter"
+
+
 def cwt_sparse_serve_apply(key_data, data, indices, indptr, *,
                            s_dim: int, rowwise: bool, shape: tuple,
-                           values: tuple = ("CWT",)) -> jnp.ndarray:
+                           values: tuple = ("CWT",),
+                           kernel: str = "xla_scatter") -> jnp.ndarray:
     """One request's CountSketch of a CSR operand: O(nnz) scatter-add,
     bit-equal to ``cwt_serve_apply`` on the densified operand (module
     doc). ``shape`` is the padded (rows, cols) class shape the lanes
@@ -89,6 +110,11 @@ def cwt_sparse_serve_apply(key_data, data, indices, indptr, *,
     (``HashTransform.apply`` on a ``SparseMatrix`` compiles exactly this
     function, one lane, through ``engine.compiled``); :func:`lookup` says
     which of bucket and value each of them computes at the lane.
+
+    ``kernel`` (:func:`sparse_kernel`) names what adds the terms up: the
+    default scatter-add, bit-equal as above, or ``"pallas_rows"``, which
+    differs from it in the order the terms of one cell are added (a cell
+    with one term holds it to the bit) and is interpreted off the TPU.
     """
     import jax.random as jr
 
@@ -96,7 +122,10 @@ def cwt_sparse_serve_apply(key_data, data, indices, indptr, *,
 
     key = jr.wrap_key_data(jnp.asarray(key_data))
     n_rows, n_cols = int(shape[0]), int(shape[1])
-    rows = csr_row_ids(indptr, data.shape[0])
+    # a lane's row: stored for the scatter and for the columnwise lookup;
+    # the rowwise kernel reads it off ``indptr`` tile by tile
+    rows = (None if kernel == "pallas_rows"
+            else csr_row_ids(indptr, data.shape[0]))
     # the coordinate each nonzero is hashed by; its bucket h(by) is
     # computed at the lane from the counter cipher, not gathered from a
     # table of the stream (an element gather is 7 ns a lane on a v5e)
@@ -113,6 +142,12 @@ def cwt_sparse_serve_apply(key_data, data, indices, indptr, *,
         # MMT/WZT: transcendental maps, still one table and one gather
         n = n_cols if rowwise else n_rows
         term = value_stream(values, key, n, data.dtype)[by] * data
+    if kernel == "pallas_rows":
+        from libskylark_tpu.sketch import pallas_sparse
+
+        return pallas_sparse.hash_rows_apply(
+            term, bucket, indptr, n_rows=n_rows, s_dim=s_dim,
+            interpret=not pallas_sparse.available())
     if rowwise:
         # out[r, h[c]] += v[c]·val — CSR row-major order IS the dense
         # segment-sum's coordinate order per output cell

@@ -47,8 +47,9 @@ METRICS: Dict[str, str] = {
     "resilience.retries": "counter",
     "resilience.health_transitions": "counter",
     # the compiled sparse hash apply (sketch/hash.py): stored nonzeros
-    # sketched, by family — the benchmark's cross-check of the nnz that
-    # sparse_nnz_rate.apply reads from the sketch.dispatch spans
+    # sketched, by family and kernel (sparse_serve.sparse_kernel) — the
+    # benchmark's cross-check of the nnz that sparse_nnz_rate.apply reads
+    # from the sketch.dispatch spans
     "sketch.sparse_nnz": "counter",
     # sparse serve operands (engine/serve.py, docs/serving)
     "serve.sparse_submits": "counter",
@@ -143,6 +144,8 @@ SPANS: Dict[str, Tuple[str, str]] = {
     # hash.py); the sparse hash apply's sketch.dispatch carries path, family,
     # nnz and nnz_class, which sparse_nnz_rate.apply reads, and for the
     # operator lookup (sparse_serve.lookup: what is computed at the lane)
+    # and kernel (sparse_serve.sparse_kernel: "pallas_rows" | "xla_scatter",
+    # what adds the terms up)
     "sketch.apply": ("sketch kernel", "sketch_host_ms.apply"),
     "sketch.plan": ("sketch kernel", "sketch_plan_ms.apply"),
     "sketch.dispatch": ("sketch kernel", "sketch_dispatch_ms.apply"),

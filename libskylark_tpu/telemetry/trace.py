@@ -179,7 +179,11 @@ class Span:
 _CURRENT: "contextvars.ContextVar[Optional[object]]" = \
     contextvars.ContextVar("skylark_telemetry_span", default=None)
 
-_FINISHED: "deque[Span]" = deque(maxlen=2048)
+# room for a whole traced window of the benchmark's fastest-turning cell:
+# cwt_sparse_apply finishes ≈ 430 applies of six spans each in its 10 s
+# (PERF.md PR 31; 42 before), and a wrapped ring costs the run its span
+# metrics (``stage_seconds`` gives None)
+_FINISHED: "deque[Span]" = deque(maxlen=16384)
 _SINKS: "list[Callable[[Span], None]]" = []
 _SINK_LOCK = _locks.make_lock("telemetry.sink")
 

@@ -35,7 +35,8 @@ def test_rehearsal_runs_every_step_on_cpu():
     steps = {ln.split()[2] for ln in lines}
     for want in ("sketch.JLT.rowwise", "sketch.JLT.columnwise",
                  "sketch.GaussianRFT.fused", "sketch.FJLT_wht.rowwise",
-                 "sketch.CWT.sparse", "sketch.GaussianRFT",
+                 "sketch.CWT.sparse", "sketch.CWT.sparse_rows",
+                 "sketch.GaussianRFT",
                  "sketch.FastGaussianRFT", "serve.sketch[pallas]",
                  "serve.solve", "solve.approximate_svd",
                  "solve.fast_least_squares", "train.admm_krr.job",
@@ -46,4 +47,7 @@ def test_rehearsal_runs_every_step_on_cpu():
     assert sum("plan=pallas/" in ln and "operator_residency=" in ln
                for ln in lines) == 2
     assert not any("/pipe" in ln for ln in lines)
+    # the rowwise sparse leg says which program added the terms up
+    assert sum("sketch.CWT.sparse_rows" in ln and "kernel=xla_scatter" in ln
+               for ln in lines) == 1
     assert "failed=none" in lines[-1]

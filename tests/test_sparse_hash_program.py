@@ -13,7 +13,9 @@ Oracles:
 - *one program, placed once*: the second apply of an operand compiles nothing
   and moves nothing from the host;
 - the ``sketch.dispatch`` span and the ``sketch.sparse_nnz`` counter carry
-  the operand's nnz, and the span how the program looks a nonzero up;
+  the operand's nnz and the kernel that adds the terms up (off the TPU the
+  scatter; tests/test_pallas_sparse_rows.py has the rule), and the span how
+  the program looks a nonzero up;
 - *computed, not gathered*: the CountSketch program's jaxpr holds no
   ``gather`` (bucket and sign are ``randgen.stream_at`` at the lane), the
   MMT and WZT programs' exactly one (their value stream's table).
@@ -141,7 +143,7 @@ class TestAgainstTheOracles:
         T = self.transform(family, kwargs)
         A, _ = operand(dimension)
         counter = metrics.registry().counter("sketch.sparse_nnz")
-        before = counter.value(family=T.sketch_type)
+        before = counter.value(family=T.sketch_type, kernel="xla_scatter")
         telemetry.set_enabled(True)
         T.apply(A, dimension).block_until_ready()
         spans = trace.finished_spans()
@@ -154,8 +156,10 @@ class TestAgainstTheOracles:
                                   "nnz": A.nnz,
                                   "nnz_class": bucket.lane_class(A.nnz),
                                   "lookup": ("lane" if family is sk.CWT
-                                             else "lane+table")}
-        assert counter.value(family=T.sketch_type) - before == A.nnz
+                                             else "lane+table"),
+                                  "kernel": "xla_scatter"}
+        assert counter.value(family=T.sketch_type,
+                             kernel="xla_scatter") - before == A.nnz
         # the one enqueue is the engine's call, under the dispatch span
         call = next(s for s in spans if s.name == "engine.call")
         assert call.parent_id == dispatch.span_id

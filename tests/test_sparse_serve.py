@@ -639,6 +639,39 @@ class TestCsrParts:
         assert np.array_equal(np.asarray(B.todense()),
                               np.asarray(A.todense()))
 
+    @pytest.mark.parametrize("source", ["canonical_csr", "unsorted_csr",
+                                        "csc", "coo"])
+    def test_lanes_do_not_depend_on_the_source_format(self, source):
+        """A canonical CSR is attached as the row-major lanes themselves
+        (no CSC round trip); every other source converts — to the same
+        parts, bit for bit."""
+        import scipy.sparse as sp
+
+        rng = np.random.default_rng(19)
+        r, c = rng.integers(0, 40, 300), rng.integers(0, 9, 300)
+        v = rng.standard_normal(300).astype(np.float32)
+        _, first = np.unique(r * 9 + c, return_index=True)  # one a coordinate
+        r, c, v = r[first], c[first], v[first]
+        coo = sp.coo_matrix((v, (r, c)), shape=(40, 9))
+        if source == "unsorted_csr":
+            # CSR parts by hand: rows in order, their columns descending
+            by_row = np.argsort(r[::-1], kind="stable")
+            r, c, v = r[::-1], c[::-1], v[::-1]
+            indptr = np.concatenate([[0], np.cumsum(np.bincount(
+                r, minlength=40))])
+            X = sp.csr_matrix((v[by_row], c[by_row], indptr), shape=(40, 9))
+            assert not X.has_canonical_format
+        else:
+            X = {"canonical_csr": coo.tocsr(), "csc": coo.tocsc(),
+                 "coo": coo}[source]
+        want = SparseMatrix.from_scipy(X.tocsc()).csr_parts()
+        A = SparseMatrix.from_scipy(X)
+        assert (A._row_major is not None) == (source == "canonical_csr")
+        got = A.csr_parts()
+        assert all(np.array_equal(g, w) and g.dtype == w.dtype
+                   for g, w in zip(got, want))
+        assert np.array_equal(np.asarray(A.todense()), X.toarray())
+
     def test_density(self):
         rng = np.random.default_rng(18)
         A = _rand_sparse(rng, 100, 10, nnz=10)

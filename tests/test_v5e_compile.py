@@ -1,4 +1,5 @@
-"""The fused dense kernels compile for a TPU v5e at the benchmark's widths —
+"""The sketch kernels of both cells — the fused dense ones and the rowwise
+sparse hash kernel — compile for a TPU v5e at the benchmark's widths —
 without a chip: the TPU compiler is installed here and compiles for a
 DESCRIBED topology, so what Mosaic would refuse on the chip (a tile past
 its 16 MiB scoped VMEM, a misaligned slice) is refused in tier-1. Nothing
@@ -8,15 +9,22 @@ One file, the topology described inside a fixture: only one process may
 hold the TPU library, and every xdist worker imports every test file.
 """
 
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 
 from libskylark_tpu.base import randgen
 from libskylark_tpu.sketch import pallas_dense as pd
+from libskylark_tpu.sketch import pallas_sparse, sparse_serve
 from libskylark_tpu.sketch.dense import BLOCK_COLS
 
 ROWS, N = 65536, 8192           # the jlt_apply cell's panel
+# a cwt_sparse_apply block: rows, features, lane_class of its ~19.4 M nonzeros
+SPARSE_ROWS, SPARSE_N, SPARSE_LANES = 262144, 47236, 19922944
+KERNEL = 'custom_call_target="tpu_custom_call"'
 
 
 @pytest.fixture(scope="module")
@@ -51,7 +59,7 @@ def _compile(call, one_chip, shape, s_dim, seq_axis, precision, *operands,
         *[arg(*o) for o in operands],
         s_dim=s_dim, dist_kind="normal", m_tile=plan["m_tile"],
         precision=precision, **statics).compile()
-    return plan, compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    return plan, compiled.as_text().count(KERNEL)
 
 
 @pytest.mark.parametrize("precision", ["bf16x3", "f32", "bf16gen2", "bf16"])
@@ -94,3 +102,48 @@ def test_generating_kernels_still_compile(one_chip, shape, seq_axis, call,
     plan, kernels = _compile(getattr(pd, call), one_chip, shape, s_dim,
                              seq_axis, "bf16x3", *operands)
     assert plan["operator_residency"] == residency and kernels == 1
+
+
+def test_cell_shape_sparse_rows_program(one_chip, monkeypatch):
+    """The whole ``sketch.hash_sparse`` program of the cwt_sparse_apply cell
+    under the rowwise kernel: the lane prologue and ONE Mosaic call — no
+    sort, no scatter, no row ids left in the executable."""
+    # off the TPU the program would interpret the kernel
+    monkeypatch.setattr(pallas_sparse, "available", lambda: True)
+    assert sparse_serve.sparse_kernel(
+        (SPARSE_ROWS, SPARSE_N), 1024, SPARSE_LANES, jnp.float32,
+        True) == "pallas_rows"
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    program = jax.jit(functools.partial(
+        sparse_serve.cwt_sparse_serve_apply, s_dim=1024, rowwise=True,
+        shape=(SPARSE_ROWS, SPARSE_N), kernel="pallas_rows"))
+    text = program.lower(
+        arg((2,), jnp.uint32), arg((SPARSE_LANES,), jnp.float32),
+        arg((SPARSE_LANES,), jnp.int32),
+        arg((SPARSE_ROWS + 1,), jnp.int32)).compile().as_text()
+    assert text.count(KERNEL) == 1
+    assert not re.search(r"\b(sort|scatter|reduce-window)\(", text)
+
+
+@pytest.mark.parametrize("s_dim,rows,plan", [
+    (128, SPARSE_ROWS, (16, 1, 16)), (384, 4096, (16, 3, 16)),
+    (2048, SPARSE_ROWS, (8, 16, 32)), (1024, 24, (8, 8, 1)),
+    (1024, (8 << 16) - 8, (8, 8, 1))])      # the largest table of tile starts
+def test_sparse_rows_kernel_other_shapes(one_chip, s_dim, rows, plan):
+    lanes = 1 << 20
+    assert pallas_sparse.rows_plan(rows, s_dim, lanes, jnp.float32) == plan
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = pallas_sparse._rows_call.lower(
+        arg((rows // plan[0] + 1,), jnp.int32),
+        arg((lanes // 128, 128), jnp.float32),
+        arg((lanes // 128, 128), jnp.int32),
+        arg((-(-rows // 1024) * 8, 128), jnp.int32),
+        n_rows=rows, s_dim=s_dim, plan=plan,
+        interpret=False).compile().as_text()
+    assert text.count(KERNEL) == 1
