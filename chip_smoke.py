@@ -16,7 +16,8 @@ itself serves, a host numpy oracle; any failure exits non-zero. The last stdout 
 
 Width is the repo's headline shape (BASELINE config 1 scaled to one chip):
 m, n, s = 8192, 8192, 1024, f32, JLT; feature maps at BASELINE.md's
-16384 x 4096 -> 4096. Writes only ``chiprun_out/chip_smoke/`` and the
+16384 x 4096 -> 4096 and at the rft_features_apply cell's 32768 x 440 ->
+16384. Writes only ``chiprun_out/chip_smoke/`` and the
 compile cache (``JAX_COMPILATION_CACHE_DIR`` when set, else
 ``benchmarks/.jax_cache``).
 """
@@ -158,6 +159,19 @@ def launches(jitted: dict):
                     if f._cache_size() > before[k]]
 
 
+def feature_kernels(family: str):
+    """Snapshot of the program's ``sketch.features`` counter for
+    ``family``; the returned function lists the kernels whose count moved
+    since — which kernel served a feature map's apply."""
+    from libskylark_tpu.telemetry import metrics
+
+    counter = metrics.registry().counter("sketch.features")
+    names = ("pallas_planes", "pallas_generate", "xla")
+    before = {k: counter.value(family=family, kernel=k) for k in names}
+    return lambda: [k for k in names
+                    if counter.value(family=family, kernel=k) > before[k]]
+
+
 def report(step: str, first_s: float, run_s: float, **fields) -> None:
     say(step, compile_s=f"{max(first_s - run_s, 0.0):.2f}",
         run_s=f"{run_s:.4f}", **fields)
@@ -225,24 +239,42 @@ def step_sketch() -> None:
 
     # random Fourier features at the same width: generation + matmul +
     # cos epilogue in one kernel, against the host oracle on some rows
+    # ... and at speech widths, where the map EXPANDS: 440 inputs (ragged:
+    # padded to 512 inside the program) to a 16384-feature block, the
+    # result tiled along s (the cell rft_features_apply's shape). The
+    # apply is one program (sketch.rft_features); its counter names the
+    # kernel that served, and it must be the fused one.
     rows = min(M, 256)
-    R = sk.GaussianRFT(N, S, Context(seed=16), sigma=float(np.sqrt(N)))
-    served = launches(dense_launchers)
-    if REHEARSE:
-        out, first, run = timed(lambda: pd.rft_rowwise_apply(
-            R.subkey(0), R.dist, A, S, R.inscale, R.outscale,
-            R.row_scales(), R.shifts(), interpret=True))
-    else:
-        out, first, run = timed(lambda: R.apply(A, sk.ROWWISE))
-    by = served()
-    if by != ["pallas_dense.rft_cos"]:
-        raise AssertionError(
-            f"GaussianRFT {M}x{N}->{S}: served by {by or 'xla'}, not the "
-            "fused cos-epilogue kernel")
-    err = close(np.asarray(out)[:rows], rft_oracle(R, N, A[:rows]),
-                "GaussianRFT (cos epilogue) vs host oracle")
-    report("sketch.GaussianRFT.fused", first, run, shape=f"{M}x{N}->{S}",
-           backend=by[0], precision="bf16x3", err=f"{err:.2e}")
+    wide = (64, 440, 4096) if REHEARSE else (32768, 440, 16384)
+    for (fm, fn, fs), sigma in (((M, N, S), float(np.sqrt(N))), (wide, 30.0)):
+        Xf = A if (fm, fn) == (M, N) else jnp.asarray(
+            rng.standard_normal((fm, fn), dtype=np.float32))
+        R = sk.GaussianRFT(fn, fs, Context(seed=16), sigma=sigma)
+        plan = pd.effective_plan(R.dist, Xf.shape, Xf.dtype, fs, 1,
+                                 interpret=REHEARSE, epilogue=True)
+        if REHEARSE:
+            served = launches(dense_launchers)
+            out, first, run = timed(lambda: pd.rft_rowwise_apply(
+                R.subkey(0), R.dist, Xf, fs, R.inscale, R.outscale,
+                R.row_scales(), R.shifts(), interpret=True))
+            by = served()
+            fused = by == ["pallas_dense.rft_cos"]
+        else:
+            served = feature_kernels("GaussianRFT")
+            out, first, run = timed(lambda: R.apply(Xf, sk.ROWWISE))
+            by = served()
+            fused = by in (["pallas_planes"], ["pallas_generate"])
+        if not fused or not plan["kernel"] or (
+                fs == wide[2] and plan["s_tile"] >= fs):
+            raise AssertionError(
+                f"GaussianRFT {fm}x{fn}->{fs}: served by {by or 'xla'} "
+                f"under plan {plan}, not the fused cos-epilogue kernel")
+        err = close(np.asarray(out)[:rows], rft_oracle(R, fn, Xf[:rows]),
+                    "GaussianRFT (cos epilogue) vs host oracle")
+        report("sketch.GaussianRFT.fused", first, run,
+               shape=f"{fm}x{fn}->{fs}", backend=by[0], plan=plan["plan_id"],
+               operator_residency=plan["operator_residency"],
+               err=f"{err:.2e}")
 
     # SRHT: FJLT with the Walsh-Hadamard mixer, against its dense
     # operator panel on a slice of rows
@@ -309,7 +341,7 @@ def step_sketch() -> None:
             (sk.FastGaussianRFT(RFT_D, RFT_D, Context(seed=5), sigma=sigma),
              lambda R: fastfood_oracle(R, X[:rows]))):
         name = type(R).sketch_type
-        served = launches(dense_launchers)
+        served = feature_kernels(name)      # Fastfood counts nothing: xla
         out, first, run = timed(lambda: R.apply(X, sk.ROWWISE))
         backend = (served() or ["xla"])[0]
         err = close(np.asarray(out)[:rows], oracle(R),

@@ -135,9 +135,9 @@ def pallas_serves_eager(A, dist, s_dim: int,
 
 
 def try_pallas_apply(key, dist, A, s_dim: int, scale: float, which: str):
-    """Fused generation+matmul TPU kernel (sketch/pallas_dense.py) for any
-    virtual operator in the dense-block stream format — the dense
-    transforms and the RFT frequency matrices share this dispatch.
+    """Fused generation+matmul TPU kernel (sketch/pallas_dense.py) for a
+    virtual operator in the dense-block stream format (the feature maps
+    of sketch/rft.py plan the same kernels inside their own program).
     Returns None when the backend/input don't qualify; the kernel-side
     resolution fills m_tile / precision from the sketch.params setters."""
     if not pallas_ambient_ok(A):

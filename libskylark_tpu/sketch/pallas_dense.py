@@ -27,7 +27,7 @@ a bug and raises — it never turns into the XLA path silently.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -65,15 +65,19 @@ def compiler_params(*dimension_semantics: str):
     return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
 
 
-def _gen_block(dist_kind, s_dim, keys_ref, k):
-    """Generate operator column block k (s_dim, BLOCK_COLS) in VMEM —
-    bit-identical to randgen.dense_block's threefry-pair layout."""
+def _gen_block(dist_kind, s_dim, keys_ref, k, row0=0, rows=None):
+    """Generate rows [row0, row0 + rows) of operator column block k
+    (default: all s_dim) in VMEM — bit-identical to randgen.dense_block's
+    threefry-pair layout. Rows of S are independent in the stream
+    (c[r, j] = r·128 + j), so an s-tile is the same counters offset by
+    its first row; ``row0`` may be traced."""
+    rows = s_dim if rows is None else rows
     k0 = keys_ref[k, 0]
     k1 = keys_ref[k, 1]
-    c = (
-        jax.lax.broadcasted_iota(jnp.uint32, (s_dim, _HALF), 0) * _HALF
-        + jax.lax.broadcasted_iota(jnp.uint32, (s_dim, _HALF), 1)
-    )
+    r = jax.lax.broadcasted_iota(jnp.uint32, (rows, _HALF), 0)
+    if not (isinstance(row0, int) and row0 == 0):
+        r = r + jnp.asarray(row0).astype(jnp.uint32)
+    c = r * _HALF + jax.lax.broadcasted_iota(jnp.uint32, (rows, _HALF), 1)
     b0, b1 = tf.threefry2x32(k0, k1, c, c + s_dim * _HALF)
     if dist_kind == "normal":
         s0, s1 = tf.bits_to_normal(b0), tf.bits_to_normal(b1)
@@ -83,7 +87,7 @@ def _gen_block(dist_kind, s_dim, keys_ref, k):
         s0, s1 = tf.bits_to_rademacher(b0), tf.bits_to_rademacher(b1)
     else:
         raise NotImplementedError(dist_kind)
-    return jnp.concatenate([s0, s1], axis=1)  # (s_dim, BLOCK_COLS)
+    return jnp.concatenate([s0, s1], axis=1)  # (rows, BLOCK_COLS)
 
 
 def _accumulate(out_ref, acc, k):
@@ -185,30 +189,32 @@ _VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 _SCRATCH_CAP_BYTES = 8 * 1024 * 1024
 
 
-def _vmem_estimate(m_tile: int, s_dim: int, scratch_bytes: int) -> int:
+def _vmem_estimate(m_tile: int, s_tile: int, scratch_bytes: int) -> int:
     """Per-core scoped-VMEM plan for one grid step: double-buffered A tile
-    (m_tile × BLOCK_COLS) and out tile (m_tile × s_dim), ONE more
-    m_tile × s_dim for the matmul result before it is accumulated, the
+    (m_tile × BLOCK_COLS) and out tile (m_tile × s_tile), ONE more
+    m_tile × s_tile for the matmul result before it is accumulated, the
     generated operator block + generation temporaries
-    (~4 × s_dim × BLOCK_COLS), plus the optional operator-cache scratch.
-    Held against what Mosaic itself asks for at s_dim = 1024 (least
+    (~4 × s_tile × BLOCK_COLS), plus the optional operator-cache scratch.
+    ``s_tile`` is the width of the result tile: the whole s_dim unless
+    :func:`_tile_plan` had to tile it.
+    Held against what Mosaic itself asks for at s_tile = 1024 (least
     ``vmem_limit_bytes`` that compiles for a v5e, PR 27): m_tile 512
     plans 11.0 MiB here and needs 9.4 (9.9 under the "hbm" residency);
     m_tile 1024 plans 18 and needs 17.0 either way — over the 16 MiB
     scope, which Mosaic refused on the chip when the plan left the
     result tile out. The "hbm" residency's contraction kernel swaps the
     generation term for its double-buffered plane tiles and the A-tile
-    split (2.9 MiB against 2.4 at s_dim = 1024), inside the same term,
+    split (2.9 MiB against 2.4 at s_tile = 1024), inside the same term,
     so a tile planned here fits there too."""
     return 4 * (
         2 * m_tile * BLOCK_COLS
-        + 3 * m_tile * s_dim
-        + 4 * s_dim * BLOCK_COLS
+        + 3 * m_tile * s_tile
+        + 4 * s_tile * BLOCK_COLS
     ) + scratch_bytes
 
 
 def operator_residency(s_dim: int, n: int, m: int, m_tile: int,
-                       rowwise: bool) -> str:
+                       rowwise: bool, s_tile: Optional[int] = None) -> str:
     """Where the generated operator lives between the m-tiles of ONE
     apply, from the padded shapes alone — the single rule the
     ``pallas_call`` sites, :func:`effective_plan`, the ``sketch.apply``
@@ -216,14 +222,17 @@ def operator_residency(s_dim: int, n: int, m: int, m_tile: int,
 
     ``"per_tile"``  a single m-tile: nothing to reuse, each block is
                     generated in the grid step that contracts it. Also
-                    the columnwise big-S case (no cell sends columnwise
-                    traffic to judge an HBM-resident variant on;
+                    the columnwise big-S case (the cell ``jlt_apply_cw``
+                    is what an HBM-resident variant would be judged on;
                     ROADMAP Queue 1).
     ``"vmem"``      S fits the scratch cap and the VMEM plan: generated
                     during the first m-tile sweep into VMEM scratch.
     ``"hbm"``       rowwise, S too big for VMEM: generated once an apply
                     into HBM by its own kernel, streamed by the
                     contraction kernel (:func:`_planes_call`).
+
+    ``s_tile`` (default: s_dim) is the result tile's width under an
+    s-tiled plan; the operator kept is always the whole (s_dim × n).
 
     Reading an entry back costs 4 B ÷ 819 GB/s ≈ 5 ps against ≈ 22 ps to
     regenerate it (≈ 46 G entries/s on a v5e, PERF.md §6 PR 27), so
@@ -233,61 +242,90 @@ def operator_residency(s_dim: int, n: int, m: int, m_tile: int,
         return "per_tile"
     scratch_bytes = s_dim * n * 4
     if (scratch_bytes <= _SCRATCH_CAP_BYTES
-            and _vmem_estimate(m_tile, s_dim, scratch_bytes)
+            and _vmem_estimate(m_tile, s_tile or s_dim, scratch_bytes)
             <= _VMEM_BUDGET_BYTES):
         return "vmem"
     return "hbm" if rowwise else "per_tile"
 
 
-def _resolve_block(dist_kind, s_dim, keys_ref, k, s_scr):
-    """Operator block k: from the VMEM cache when present (filled during
-    the first m-tile sweep), else regenerated in place."""
+def _resolve_block(dist_kind, s_dim, keys_ref, k, s_scr, row0=0, rows=None):
+    """Rows [row0, row0 + rows) of operator block k (default: all): from
+    the VMEM cache when present (filled during the first m-tile sweep),
+    else regenerated in place."""
     if s_scr is None:
-        return _gen_block(dist_kind, s_dim, keys_ref, k)
+        return _gen_block(dist_kind, s_dim, keys_ref, k, row0, rows)
+    at = (slice(None) if rows is None else pl.ds(row0, rows),
+          pl.ds(k * BLOCK_COLS, BLOCK_COLS))
 
     @pl.when(pl.program_id(0) == 0)
     def _gen():
-        s_scr[:, pl.ds(k * BLOCK_COLS, BLOCK_COLS)] = _gen_block(
-            dist_kind, s_dim, keys_ref, k
-        )
+        s_scr[at] = _gen_block(dist_kind, s_dim, keys_ref, k, row0, rows)
 
-    return s_scr[:, pl.ds(k * BLOCK_COLS, BLOCK_COLS)]
+    return s_scr[at]
 
 
-def _apply_epilogue(out_ref, epilogue, operand_refs, k, n_blocks):
-    """Fused in-VMEM finish after the LAST operator block accumulates
-    (shared by every rowwise kernel). ``epilogue = ("cos", inscale,
-    outscale)`` with ``operand_refs = (sc_ref, sh_ref)`` →
+def _finisher(scale_ref, epilogue, operand_refs):
+    """What finishes a result tile in VMEM once its last k step is in
+    (shared by every rowwise kernel), or None: the tile scale of
+    :func:`_tile_scaled`, then the fused epilogue — ``epilogue = ("cos",
+    inscale, outscale)`` with ``operand_refs = (sc_ref, sh_ref)`` →
     outscale·cos(acc·inscale·sc + sh) (ref: RFT_Elemental.hpp:83-156,
     the reference's fused elementwise loops): the output never makes the
     extra HBM round-trip a separate elementwise op would cost."""
-    kind, inscale, outscale = epilogue
-    assert kind == "cos"
-    sc_ref, sh_ref = operand_refs
+    if scale_ref is None and epilogue is None:
+        return None
 
-    @pl.when(k == n_blocks - 1)
-    def _epilogue():
-        z = out_ref[:] * inscale * sc_ref[:] + sh_ref[:]
-        out_ref[:] = outscale * jnp.cos(z)
+    def finish(acc):
+        if scale_ref is not None:
+            acc = scale_ref[0] * acc
+        if epilogue is not None:
+            kind, inscale, outscale = epilogue
+            assert kind == "cos"
+            sc_ref, sh_ref = operand_refs
+            acc = outscale * jnp.cos(acc * inscale * sc_ref[:] + sh_ref[:])
+        return acc
+
+    return finish
 
 
-def _kernel(dist_kind, s_dim, n_blocks, precision, epilogue, keys_ref,
-            a_ref, *refs):
+def _store(out_ref, acc, k, n_blocks, finish):
+    """out_tile (+)= acc over the k steps of one result tile, ``finish``
+    (:func:`_finisher`) applied after the last. A contraction that is one
+    step deep writes its tile once, finished."""
+    if n_blocks == 1:
+        out_ref[:] = acc if finish is None else finish(acc)
+        return
+    _accumulate(out_ref, acc, k)
+    if finish is not None:
+        @pl.when(k == n_blocks - 1)
+        def _finish():
+            out_ref[:] = finish(out_ref[:])
+
+
+def _row0(s_dim, s_tile, j):
+    """First operator row of s-tile ``j`` (a plain 0 when S is not tiled:
+    the kernel is then the untiled one, to the instruction)."""
+    return 0 if s_tile == s_dim else j * s_tile
+
+
+def _kernel(dist_kind, s_dim, s_tile, n_blocks, precision, epilogue,
+            keys_ref, a_ref, *refs):
     """Rowwise, operator generated in the kernel ("vmem" / "per_tile"):
     out_tile += A_tile @ S_blkᵀ (S entries are bit-exact; only the
-    contraction rounds, per the ``precision`` regime). ``refs`` =
+    contraction rounds, per the ``precision`` regime), S_blk the
+    ``s_tile`` rows of block k this result tile takes. ``refs`` =
     (*epilogue operands, out[, operator-cache scratch]); the optional
-    epilogue finishes the tile in VMEM (:func:`_apply_epilogue`)."""
+    epilogue finishes the tile in VMEM (:func:`_finisher`)."""
     n_operands = 0 if epilogue is None else 2
     operand_refs, out_ref = refs[:n_operands], refs[n_operands]
     s_scr = refs[n_operands + 1] if len(refs) > n_operands + 1 else None
-    k = pl.program_id(1)
-    S_blk = _resolve_block(dist_kind, s_dim, keys_ref, k, s_scr)
+    k = pl.program_id(2)
+    S_blk = _resolve_block(dist_kind, s_dim, keys_ref, k, s_scr,
+                           _row0(s_dim, s_tile, pl.program_id(1)), s_tile)
     acc = _dot(a_ref[:], S_blk, (((1,), (1,)), ((), ())), precision,
                gen_side=1)
-    _accumulate(out_ref, acc, k)
-    if epilogue is not None:
-        _apply_epilogue(out_ref, epilogue, operand_refs, k, n_blocks)
+    _store(out_ref, acc, k, n_blocks,
+           _finisher(None, epilogue, operand_refs))
 
 
 def _kernel_cw(dist_kind, s_dim, m_tile, precision, keys_ref, a_ref, out_ref,
@@ -309,12 +347,14 @@ def _operator_scratch(residency: str, s_dim: int, n: int) -> list:
     return []
 
 
-def _grid_params(residency: str):
+def _grid_params(residency: str, *inner: str):
     """dimension_semantics for pallas_call: the VMEM operator cache needs
     strictly sequential grid order (the i==0 sweep fills it) — no
-    megacore splitting over the m-tile dimension."""
-    return compiler_params(
-        "arbitrary" if residency == "vmem" else "parallel", "arbitrary")
+    megacore splitting over the m-tile dimension (nor, rowwise, over the
+    s-tiles under it: ``inner``)."""
+    if residency == "vmem":
+        return compiler_params(*["arbitrary"] * (len(inner) + 2))
+    return compiler_params("parallel", *inner, "arbitrary")
 
 
 # ---------------------------------------------------------------------------
@@ -342,15 +382,17 @@ def _tile_scaled(precision: str, scale) -> bool:
     return scale is not None and precision == "bf16gen2"
 
 
-def _kernel_gen(dist_kind, s_dim, scaled, keys_ref, *refs):
-    """Generation kernel: column block k of the operator — the same
-    Threefry counters and bits as :func:`_gen_block` everywhere else —
-    times ``scale``, written as the planes of :func:`_plane_dtypes`."""
+def _kernel_gen(dist_kind, s_dim, s_tile, scaled, keys_ref, *refs):
+    """Generation kernel: the ``s_tile`` rows of column block k of the
+    operator that grid step (j, k) owns — the same Threefry counters and
+    bits as :func:`_gen_block` everywhere else — times ``scale``, written
+    as the planes of :func:`_plane_dtypes`."""
     if scaled:
         scale_ref, *plane_refs = refs
     else:
         scale_ref, plane_refs = None, refs
-    S = _gen_block(dist_kind, s_dim, keys_ref, pl.program_id(0))
+    S = _gen_block(dist_kind, s_dim, keys_ref, pl.program_id(1),
+                   _row0(s_dim, s_tile, pl.program_id(0)), s_tile)
     if scaled:
         S = S * scale_ref[0]
     hi = S.astype(plane_refs[0].dtype)
@@ -361,11 +403,13 @@ def _kernel_gen(dist_kind, s_dim, scaled, keys_ref, *refs):
 
 
 def _operator_planes(keys, scale, *, s_dim, dist_kind, precision,
-                     interpret=False):
+                     s_tile=None, interpret=False):
     """S (s_dim × n_blocks·BLOCK_COLS), generated once into HBM as the
     planes ``precision`` contracts with: ``hi = bf16(scale·S)`` and, for
     "bf16x3", ``lo = bf16(scale·S − hi)``. ``scale`` None (or the
-    "bf16gen2" regime, :func:`_tile_scaled`) stores the unit stream."""
+    "bf16gen2" regime, :func:`_tile_scaled`) stores the unit stream. A
+    grid step makes ``s_tile`` rows (default: all) of one column block."""
+    s_tile = s_tile or s_dim
     n_blocks = keys.shape[0]
     scaled = scale is not None and not _tile_scaled(precision, scale)
     operands, in_specs = [keys], [pl.BlockSpec(memory_space=pltpu.SMEM)]
@@ -374,14 +418,14 @@ def _operator_planes(keys, scale, *, s_dim, dist_kind, precision,
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
     dtypes = _plane_dtypes(precision)
     return pl.pallas_call(
-        functools.partial(_kernel_gen, dist_kind, s_dim, scaled),
-        grid=(n_blocks,),
+        functools.partial(_kernel_gen, dist_kind, s_dim, s_tile, scaled),
+        grid=(s_dim // s_tile, n_blocks),
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((s_dim, BLOCK_COLS), lambda k: (0, k),
+        out_specs=[pl.BlockSpec((s_tile, BLOCK_COLS), lambda j, k: (j, k),
                                 memory_space=pltpu.VMEM) for _ in dtypes],
         out_shape=[jax.ShapeDtypeStruct((s_dim, n_blocks * BLOCK_COLS), dt)
                    for dt in dtypes],
-        compiler_params=compiler_params("parallel"),
+        compiler_params=compiler_params("parallel", "parallel"),
         interpret=interpret,
     )(*operands)
 
@@ -410,55 +454,52 @@ def _dot_planes(a, planes, precision):
 def _kernel_planes(n_blocks, precision, n_planes, tile_scaled, epilogue,
                    *refs):
     """Contraction kernel of the "hbm" residency: out_tile += A_tile @
-    S_blkᵀ with the (s_dim × k) plane tiles streamed in beside the
+    S_blkᵀ with the (s_tile × k) plane tiles streamed in beside the
     (m_tile × k) A tile (k: :func:`_plane_step_cols`). No generation, no
-    operator split, no iota inside the m × k loop. ``refs`` = ([scale],
-    a, *planes, *epilogue operands, out)."""
+    operator split, no iota inside the m × s × k loop. ``refs`` =
+    ([scale], a, *planes, *epilogue operands, out)."""
     refs = list(refs)
     scale_ref = refs.pop(0) if tile_scaled else None
     a_ref, plane_refs = refs[0], refs[1:1 + n_planes]
     *operand_refs, out_ref = refs[1 + n_planes:]
-    k = pl.program_id(1)
     acc = _dot_planes(a_ref[:], [p[:] for p in plane_refs], precision)
-    _accumulate(out_ref, acc, k)
-    if tile_scaled:
-        @pl.when(k == n_blocks - 1)
-        def _scale():
-            out_ref[:] = scale_ref[0] * out_ref[:]
-    if epilogue is not None:
-        _apply_epilogue(out_ref, epilogue, operand_refs, k, n_blocks)
+    _store(out_ref, acc, pl.program_id(2), n_blocks,
+           _finisher(scale_ref, epilogue, operand_refs))
 
 
-def _plane_step_cols(n: int, m_tile: int, s_dim: int) -> int:
+def _plane_step_cols(n: int, m_tile: int, s_tile: int) -> int:
     """Columns of A and of the planes one contraction step takes: two
     BLOCK_COLS blocks where n divides and the plan fits, else one. The
     wider step halves the grid steps and the out tile's
     read-modify-writes — 23.1 → 22.1 ms an apply at 65536 × 8192 → 1024
-    on a v5e; four blocks bought nothing more (PERF.md §6, PR 27).
+    on a v5e; four blocks bought nothing more (PERF.md §6, PR 27). At
+    n ≤ 512 (the feature maps' input widths) the wide step is the whole
+    contraction: the tile is written once, finished (:func:`_store`).
 
     The plan is the contraction kernel's own, fitted to what Mosaic asks
     for (least ``vmem_limit_bytes`` that compiles for a v5e, eleven
-    shapes, PR 27): 4·(2.9·m_tile·k + 3·m_tile·s_dim) + 9.8·s_dim·k
+    shapes, PR 27): 4·(2.9·m_tile·k + 3·m_tile·s_tile) + 9.8·s_tile·k
     bytes at "bf16x3" — A tile double-buffered plus its hi/lo split, out
     tile double-buffered plus the matmul result, plane tiles
     double-buffered plus what is loaded of them; "f32" needs up to
     8·m_tile·k more, hence the 5."""
     wide = 2 * BLOCK_COLS
-    plan = 4 * (5 * m_tile * wide + 3 * m_tile * s_dim) + 10 * s_dim * wide
+    plan = 4 * (5 * m_tile * wide + 3 * m_tile * s_tile) + 10 * s_tile * wide
     if n % wide == 0 and plan <= _VMEM_BUDGET_BYTES:
         return wide
     return BLOCK_COLS
 
 
-def _planes_call(A, keys, scale, extra_operands, *, s_dim, dist_kind,
+def _planes_call(A, keys, scale, extra_operands, *, s_dim, s_tile, dist_kind,
                  m_tile, precision, interpret, epilogue):
     """The "hbm" residency: two ``pallas_call``s in one executable. With
-    no scratch the m axis stays "parallel"."""
+    no scratch the m and s axes stay "parallel"."""
     m, n = A.shape
-    k_cols = _plane_step_cols(n, m_tile, s_dim)
+    k_cols = _plane_step_cols(n, m_tile, s_tile)
     n_blocks = n // k_cols
     planes = _operator_planes(keys, scale, s_dim=s_dim, dist_kind=dist_kind,
-                              precision=precision, interpret=interpret)
+                              precision=precision, s_tile=s_tile,
+                              interpret=interpret)
     tile_scaled = _tile_scaled(precision, scale)
     operands, in_specs = [], []
     if tile_scaled:
@@ -466,99 +507,112 @@ def _planes_call(A, keys, scale, extra_operands, *, s_dim, dist_kind,
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
     operands += [A, *planes, *extra_operands]
     in_specs += [
-        pl.BlockSpec((m_tile, k_cols), lambda i, k: (i, k),
+        pl.BlockSpec((m_tile, k_cols), lambda i, j, k: (i, k),
                      memory_space=pltpu.VMEM),
     ] + [
-        pl.BlockSpec((s_dim, k_cols), lambda i, k: (0, k),
+        pl.BlockSpec((s_tile, k_cols), lambda i, j, k: (j, k),
                      memory_space=pltpu.VMEM)
         for _ in planes
     ] + [
-        pl.BlockSpec((1, s_dim), lambda i, k: (0, 0),
+        pl.BlockSpec((1, s_tile), lambda i, j, k: (0, j),
                      memory_space=pltpu.VMEM)
         for _ in extra_operands
     ]
     return pl.pallas_call(
         functools.partial(_kernel_planes, n_blocks, precision, len(planes),
                           tile_scaled, epilogue),
-        grid=(m // m_tile, n_blocks),
+        grid=(m // m_tile, s_dim // s_tile, n_blocks),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (m_tile, s_dim), lambda i, k: (i, 0), memory_space=pltpu.VMEM
+            (m_tile, s_tile), lambda i, j, k: (i, j), memory_space=pltpu.VMEM
         ),
         out_shape=jax.ShapeDtypeStruct((m, s_dim), jnp.float32),
-        compiler_params=compiler_params("parallel", "arbitrary"),
+        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(*operands)
 
 
 def _rowwise_pallas_call(A, keys, scale, extra_operands, *, s_dim, dist_kind,
-                         m_tile, precision, interpret, epilogue=None):
-    """``scale``·A·Sᵀ (``scale`` None: unscaled) by the kernel(s) of the
-    operand's :func:`operator_residency`. ``extra_operands`` are the
-    (1, s_dim) VMEM vectors of ``epilogue`` (:func:`_apply_epilogue`).
+                         m_tile, precision, interpret, s_tile=None,
+                         epilogue=None):
+    """``scale``·A·Sᵀ (``scale`` None: unscaled) of the UNPADDED A by the
+    kernel(s) of the operand's :func:`operator_residency`, the zero
+    padding and the slice back inside the caller's one executable
+    (:func:`_padded`: exact). ``extra_operands`` are the (1, s_dim) VMEM
+    vectors of ``epilogue`` (:func:`_finisher`); ``s_tile`` (default:
+    s_dim) is the result tile's width (:func:`_tile_plan`).
 
     "hbm": :func:`_planes_call`, scale folded into the planes. "vmem" /
     "per_tile": one call that generates in the kernel — grid, key-table
     SMEM spec, A-tile spec, accumulator out spec, operator scratch — and
     the scale as a pass over its result."""
+    s_tile = s_tile or s_dim
+    rows = A.shape[0]
+    A = _padded(A, seq_axis=1, mt=m_tile)
     m, n = A.shape
     n_blocks = n // BLOCK_COLS
-    residency = operator_residency(s_dim, n, m, m_tile, rowwise=True)
+    residency = operator_residency(s_dim, n, m, m_tile, rowwise=True,
+                                   s_tile=s_tile)
     if residency == "hbm":
-        return _planes_call(A, keys, scale, extra_operands, s_dim=s_dim,
-                            dist_kind=dist_kind, m_tile=m_tile,
-                            precision=precision, interpret=interpret,
-                            epilogue=epilogue)
+        out = _planes_call(A, keys, scale, extra_operands, s_dim=s_dim,
+                           s_tile=s_tile, dist_kind=dist_kind, m_tile=m_tile,
+                           precision=precision, interpret=interpret,
+                           epilogue=epilogue)
+        return out if m == rows else out[:rows]
     out = pl.pallas_call(
-        functools.partial(_kernel, dist_kind, s_dim, n_blocks, precision,
-                          epilogue),
-        grid=(m // m_tile, n_blocks),
+        functools.partial(_kernel, dist_kind, s_dim, s_tile, n_blocks,
+                          precision, epilogue),
+        grid=(m // m_tile, s_dim // s_tile, n_blocks),
         in_specs=[
             # whole key table in SMEM every step (tiny); indexed by k
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(
-                (m_tile, BLOCK_COLS), lambda i, k: (i, k),
+                (m_tile, BLOCK_COLS), lambda i, j, k: (i, k),
                 memory_space=pltpu.VMEM,
             ),
         ] + [
-            pl.BlockSpec((1, s_dim), lambda i, k: (0, 0),
+            pl.BlockSpec((1, s_tile), lambda i, j, k: (0, j),
                          memory_space=pltpu.VMEM)
             for _ in extra_operands
         ],
         out_specs=pl.BlockSpec(
-            (m_tile, s_dim), lambda i, k: (i, 0), memory_space=pltpu.VMEM
+            (m_tile, s_tile), lambda i, j, k: (i, j), memory_space=pltpu.VMEM
         ),
         out_shape=jax.ShapeDtypeStruct((m, s_dim), jnp.float32),
         scratch_shapes=_operator_scratch(residency, s_dim, n),
-        compiler_params=_grid_params(residency),
+        compiler_params=_grid_params(residency, "parallel"),
         interpret=interpret,
     )(keys, A, *extra_operands)
+    if m != rows:
+        out = out[:rows]
     return out if scale is None else scale * out
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("s_dim", "dist_kind", "m_tile", "precision",
-                     "interpret"),
+                     "interpret", "s_tile"),
 )
 def _fused_call(A, keys, scale=None, *, s_dim, dist_kind, m_tile,
-                precision="f32", interpret=False):
+                precision="f32", interpret=False, s_tile=None):
     return _rowwise_pallas_call(A, keys, scale, (), s_dim=s_dim,
                                 dist_kind=dist_kind, m_tile=m_tile,
-                                precision=precision, interpret=interpret)
+                                precision=precision, interpret=interpret,
+                                s_tile=s_tile)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("s_dim", "dist_kind", "m_tile", "precision",
-                     "inscale", "outscale", "interpret"),
+                     "inscale", "outscale", "interpret", "s_tile"),
 )
 def _fused_call_cos(A, keys, sc, sh, *, s_dim, dist_kind, m_tile,
                     precision="f32", inscale=1.0, outscale=1.0,
-                    interpret=False):
+                    interpret=False, s_tile=None):
     return _rowwise_pallas_call(A, keys, None, (sc, sh), s_dim=s_dim,
                                 dist_kind=dist_kind, m_tile=m_tile,
                                 precision=precision, interpret=interpret,
+                                s_tile=s_tile,
                                 epilogue=("cos", inscale, outscale))
 
 
@@ -629,15 +683,59 @@ def _pad_to(x: int, mult: int) -> int:
     return -(-x // mult) * mult
 
 
+def _tile_fits(m_tile: int, s_tile: int, epilogue: bool) -> bool:
+    """The VMEM plan of one (m_tile × s_tile) result tile
+    (:func:`_vmem_estimate`, scratch excluded — operator_residency checks
+    it) against the scope. A fused ``epilogue`` counts one more result
+    tile, its temporaries: for the cos at 512 × 1536 Mosaic asks 17.0 MiB
+    where the plan without them says 16.0 (compiled for a v5e, PR 32)."""
+    return _vmem_estimate(
+        m_tile, s_tile,
+        4 * m_tile * s_tile if epilogue else 0) <= _VMEM_BUDGET_BYTES
+
+
+def _fit_rows(m: int, m_tile: int, s_tile: int,
+              epilogue: bool = False) -> Optional[int]:
+    """The requested row tile fitted to ``m`` rows and to the VMEM plan
+    of a result tile ``s_tile`` wide (:func:`_tile_fits`), or None when
+    no tile fits."""
+    m = _pad_to(max(m, 8), 8)
+    # power-of-two tile ≥ 8: the halving search below then always
+    # terminates at a divisor of the 8-aligned m (a non-pow2 request,
+    # e.g. the argument m_tile=100, would otherwise collapse to 1)
+    m_tile = max(8, 1 << (max(m_tile, 8).bit_length() - 1))
+    m_tile = min(m_tile, m)
+    while m % m_tile:
+        m_tile //= 2
+    if not _tile_fits(m_tile, s_tile, epilogue):
+        # scan smaller valid tiles — ≥ 8, multiples of 8 (sublane
+        # tiling), divisors of the padded m — largest first. (m_tile may
+        # be the non-power-of-2 m itself via min(m_tile, m), so blind
+        # halving could skip valid tiles or land misaligned.)
+        for t in range(min(m_tile - 8, _pad_to(m_tile // 2, 8)), 7, -8):
+            if m % t == 0 and _tile_fits(t, s_tile, epilogue):
+                return t
+        # no valid tile fits (the generation term scales with s_tile
+        # alone)
+        return None
+    return m_tile
+
+
+def _admits(dist, dtype, interpret: bool) -> bool:
+    """Backend + distribution + dtype: what no tile plan can cure."""
+    return (interpret or available()) and supported(dist, dtype)
+
+
 def _qualify(dist, A, seq_axis: int, m_tile: int, interpret: bool,
-             s_dim: int = 0):
+             s_dim: int = 0, epilogue: bool = False):
     """Common qualification: backend + distribution. Returns the m-tile
-    size for the (possibly padded) m extent, or None for fallback.
+    size for the (possibly padded) m extent under a FULL-width result
+    tile, or None for fallback (the rowwise applies go on to tile s:
+    :func:`_tile_plan`).
 
     The returned tile is pre-shrunk so the kernel's VMEM plan
-    (:func:`_vmem_estimate`, scratch excluded — operator_residency
-    checks it)
-    fits ``_VMEM_BUDGET_BYTES``: a Mosaic VMEM-exhaustion failure inside a
+    (:func:`_fit_rows`) fits ``_VMEM_BUDGET_BYTES``: a Mosaic
+    VMEM-exhaustion failure inside a
     jitted shard_map program has no catchable fallback seam, so the
     pre-flight must make compilation succeed, not try/except it (advisor
     r2 medium finding).
@@ -647,52 +745,78 @@ def _qualify(dist, A, seq_axis: int, m_tile: int, interpret: bool,
     zero; padded A rows produce output rows that are sliced away) — the
     parity requirement the reference exercises at np∈{5,7}
     (ref: tests/unit/CMakeLists.txt:31-33)."""
-    if not interpret and not available():
+    if not _admits(dist, A.dtype, interpret):
         return None
-    if not supported(dist, A.dtype):
+    return _fit_rows(A.shape[1 - seq_axis], m_tile, s_dim, epilogue)
+
+
+# Lane width of the result: an s-tile is a multiple of it.
+_LANES = 128
+
+
+def _tile_plan(dist, A, seq_axis: int, m_tile: int, interpret: bool,
+               s_dim: int, epilogue: bool = False):
+    """``(m_tile, s_tile)`` of an unbatched apply, from the shapes alone,
+    or None (XLA serves). Where a row tile takes the full-width result
+    (:func:`_qualify`) that is the plan, ``s_tile == s_dim``, as it
+    always was. Where none does — s_dim ≥ 3999: the result tile and the
+    generated block both grow with s_dim — a ROWWISE apply tiles s
+    instead: the requested row tile unshrunk, and the widest s-tile the
+    same VMEM plan admits among the divisors of s_dim that are multiples
+    of 128 lanes (s_dim = 16384 at m_tile 512: 1024, the untiled plan of
+    s_dim = 1024). Rows of S are independent in the stream, so each
+    s-tile generates, or streams from the planes, just its own rows."""
+    if not _admits(dist, A.dtype, interpret):
         return None
-    m = _pad_to(max(A.shape[1 - seq_axis], 8), 8)
-    # power-of-two tile ≥ 8: the halving search below then always
-    # terminates at a divisor of the 8-aligned m (a non-pow2 request,
-    # e.g. the argument m_tile=100, would otherwise collapse to 1)
-    m_tile = max(8, 1 << (max(m_tile, 8).bit_length() - 1))
-    m_tile = min(m_tile, m)
-    while m % m_tile:
-        m_tile //= 2
-    if _vmem_estimate(m_tile, s_dim, 0) > _VMEM_BUDGET_BYTES:
-        # scan smaller valid tiles — ≥ 8, multiples of 8 (sublane
-        # tiling), divisors of the padded m — largest first. (m_tile may
-        # be the non-power-of-2 m itself via min(m_tile, m), so blind
-        # halving could skip valid tiles or land misaligned.)
-        for t in range(min(m_tile - 8, _pad_to(m_tile // 2, 8)), 7, -8):
-            if m % t == 0 and _vmem_estimate(t, s_dim, 0) <= _VMEM_BUDGET_BYTES:
-                return t
-        # no valid tile fits (the generation term scales with s_dim
-        # alone) — XLA fallback instead of a Mosaic abort
+    m = A.shape[1 - seq_axis]
+    mt = _fit_rows(m, m_tile, s_dim, epilogue)
+    if mt is not None:
+        return mt, s_dim
+    if seq_axis != 1 or s_dim % _LANES:
         return None
-    return m_tile
+    mt = _fit_rows(m, m_tile, _LANES, epilogue)
+    if mt is None:
+        return None
+    # no full-width tile fits at 8 rows, so none past 4096 columns does;
+    # the narrowest, 128, fits by the line above
+    widths = range(min(s_dim // 2, 4096) // _LANES * _LANES, 0, -_LANES)
+    return mt, next(st for st in widths
+                    if s_dim % st == 0 and _tile_fits(mt, st, epilogue))
+
+
+class Plan(NamedTuple):
+    """What a fused apply will run (:func:`_plan`); hashable, so a
+    compiled program takes it as a static."""
+    m_tile: int
+    s_tile: int
+    precision: str
+    operator_residency: str
+    interpret: bool = False
 
 
 def _plan(dist, A, s_dim: int, seq_axis: int, m_tile, precision,
-          interpret: bool):
+          interpret: bool, epilogue: bool = False) -> Optional[Plan]:
     """The prelude of every fused apply: resolve the knobs
-    (:func:`_resolve_knobs`) and qualify the operand (:func:`_qualify`).
-    Returns ``(m_tile, precision)`` with the effective tile, or None when
-    the kernel declines and the caller takes the XLA path."""
+    (:func:`_resolve_knobs`) and plan the tiles (:func:`_tile_plan`;
+    ``epilogue``: the kernel finishes its tiles with the cos).
+    Returns the :class:`Plan` with the effective tiles, or None when the
+    kernel declines and the caller takes the XLA path."""
     with _trace.span("sketch.plan") as sp:
         m_tile, precision, source = _resolve_knobs(m_tile, precision)
-        mt = _qualify(dist, A, seq_axis=seq_axis, m_tile=m_tile,
-                      interpret=interpret, s_dim=s_dim)
+        tiles = _tile_plan(dist, A, seq_axis=seq_axis, m_tile=m_tile,
+                           interpret=interpret, s_dim=s_dim,
+                           epilogue=epilogue)
         if sp is not None:
             sp.set_attr("plan_source", source)
-    if mt is None:
+    if tiles is None:
         return None
+    mt, st = tiles
     n_p, m_p = _padded_extents(A.shape[seq_axis], A.shape[1 - seq_axis], mt)
-    note_apply(path="pallas", m_tile=mt, precision=precision,
-               plan_source=source,
-               operator_residency=operator_residency(
-                   s_dim, n_p, m_p, mt, rowwise=seq_axis == 1))
-    return mt, precision
+    residency = operator_residency(s_dim, n_p, m_p, mt,
+                                   rowwise=seq_axis == 1, s_tile=st)
+    note_apply(path="pallas", m_tile=mt, s_tile=st, precision=precision,
+               plan_source=source, operator_residency=residency)
+    return Plan(mt, st, precision, residency, interpret)
 
 
 @functools.partial(jax.jit, static_argnames="n")
@@ -727,6 +851,11 @@ def _padded(A, seq_axis: int, mt: int):
     return jnp.pad(A, pads)
 
 
+def _is_padded(A, seq_axis: int, mt: int) -> bool:
+    return _padded_extents(A.shape[seq_axis], A.shape[1 - seq_axis],
+                           mt) != (A.shape[seq_axis], A.shape[1 - seq_axis])
+
+
 def rowwise_apply(
     key: jax.Array,
     dist,
@@ -744,19 +873,17 @@ def rowwise_apply(
     plan = _plan(dist, A, s_dim, 1, m_tile, precision, interpret)
     if plan is None:
         return None
-    mt, precision = plan
-    m = A.shape[0]
     keys = _block_keys(key, A.shape[1])
     with _trace.span("sketch.dispatch") as sp:
-        Ap = _padded(A, seq_axis=1, mt=mt)
         if sp is not None:
-            sp.set_attr("padded", Ap is not A)
-        # the scale is applied inside the call: folded into the planes
-        # under the "hbm" residency, a pass over the result otherwise
-        out = _fused_call(Ap, keys, scale, s_dim=s_dim,
-                          dist_kind=_DIST_KINDS[type(dist)], m_tile=mt,
-                          precision=precision, interpret=interpret)
-        return out if out.shape[0] == m else out[:m]
+            sp.set_attr("padded", _is_padded(A, 1, plan.m_tile))
+        # one executable: the padding (where the operand is ragged), the
+        # kernel(s) and the scale — folded into the planes under the
+        # "hbm" residency, a pass over the result otherwise
+        return _fused_call(A, keys, scale, s_dim=s_dim,
+                           dist_kind=_DIST_KINDS[type(dist)],
+                           m_tile=plan.m_tile, s_tile=plan.s_tile,
+                           precision=plan.precision, interpret=interpret)
 
 
 def columnwise_apply(
@@ -774,7 +901,7 @@ def columnwise_apply(
     plan = _plan(dist, A, s_dim, 0, m_tile, precision, interpret)
     if plan is None:
         return None
-    mt, precision = plan
+    mt, precision = plan.m_tile, plan.precision
     m = A.shape[1]
     keys = _block_keys(key, A.shape[0])
     with _trace.span("sketch.dispatch") as sp:
@@ -804,25 +931,41 @@ def rft_rowwise_apply(
     ``outscale · cos((A @ (inscale·S)ᵀ) ⊙ sc + sh)`` with the cos
     epilogue applied in VMEM (no extra HBM round-trip of the feature
     matrix). ``sc``/``sh`` are (s_dim,) per-feature scales/shifts.
-    Returns None when not applicable."""
-    plan = _plan(dist, A, s_dim, 1, m_tile, precision, interpret)
+    Returns None when not applicable. The kernel alone, on given
+    vectors; ``RFT.apply`` runs the same kernel inside its one program
+    (:func:`features_rows`), the vectors generated there."""
+    plan = _plan(dist, A, s_dim, 1, m_tile, precision, interpret,
+                 epilogue=True)
     if plan is None:
         return None
-    mt, precision = plan
-    m = A.shape[0]
     keys = _block_keys(key, A.shape[1])
     with _trace.span("sketch.dispatch") as sp:
-        Ap = _padded(A, seq_axis=1, mt=mt)
         if sp is not None:
-            sp.set_attr("padded", Ap is not A)
-        out = _fused_call_cos(
-            Ap, keys,
+            sp.set_attr("padded", _is_padded(A, 1, plan.m_tile))
+        return _fused_call_cos(
+            A, keys,
             jnp.asarray(sc, jnp.float32).reshape(1, s_dim),
             jnp.asarray(sh, jnp.float32).reshape(1, s_dim),
-            s_dim=s_dim, dist_kind=_DIST_KINDS[type(dist)], m_tile=mt,
-            precision=precision, inscale=float(inscale),
+            s_dim=s_dim, dist_kind=_DIST_KINDS[type(dist)],
+            m_tile=plan.m_tile, s_tile=plan.s_tile,
+            precision=plan.precision, inscale=float(inscale),
             outscale=float(outscale), interpret=interpret)
-        return out[:m]
+
+
+def features_rows(key, dist, A, s_dim: int, inscale: float, outscale: float,
+                  sc, sh, plan: Plan) -> jnp.ndarray:
+    """The body of :func:`rft_rowwise_apply` under a plan already made
+    (:func:`_plan`), traceable: the block-key table, the padding, the
+    kernel(s) and the slice back, for the caller's one executable
+    (sketch/rft.py ``sketch.rft_features``)."""
+    return _rowwise_pallas_call(
+        A, _block_key_table(key, A.shape[1]), None,
+        (sc.astype(jnp.float32).reshape(1, s_dim),
+         sh.astype(jnp.float32).reshape(1, s_dim)),
+        s_dim=s_dim, dist_kind=_DIST_KINDS[type(dist)], m_tile=plan.m_tile,
+        s_tile=plan.s_tile, precision=plan.precision,
+        interpret=plan.interpret,
+        epilogue=("cos", float(inscale), float(outscale)))
 
 
 def fused_partial(
@@ -851,50 +994,55 @@ def fused_partial(
     plan = _plan(dist, A_loc, s_dim, seq_axis, m_tile, precision, interpret)
     if plan is None:
         return None
-    mt, precision = plan
     m = A_loc.shape[1 - seq_axis]
-    Ap = _padded(A_loc, seq_axis=seq_axis, mt=mt)
-    kw = dict(s_dim=s_dim, dist_kind=_DIST_KINDS[type(dist)], m_tile=mt,
-              precision=precision, interpret=interpret)
+    kw = dict(s_dim=s_dim, dist_kind=_DIST_KINDS[type(dist)],
+              m_tile=plan.m_tile, precision=plan.precision,
+              interpret=interpret)
     if seq_axis == 1:
-        return _fused_call(Ap, keys, None, **kw)[:m]
+        return _fused_call(A_loc, keys, None, s_tile=plan.s_tile, **kw)
+    Ap = _padded(A_loc, seq_axis=seq_axis, mt=plan.m_tile)
     return _fused_call_cw(Ap, keys, **kw)[:, :m]
 
 
 def effective_plan(dist, shape, dtype, s_dim: int, seq_axis: int,
                    m_tile: int | None = None,
                    interpret: bool = False,
-                   precision: str | None = None) -> dict:
+                   precision: str | None = None,
+                   epilogue: bool = False) -> dict:
     """The plan a fused apply with these arguments would actually run —
     WITHOUT running it. The requested tile can be adjusted downstream
-    (:func:`_qualify` shrinks an over-budget m-tile), so anything
-    recording a measurement labeled with the REQUESTED knobs must ask
-    for the EFFECTIVE ones or the record lies about what was measured
+    (:func:`_tile_plan` shrinks an over-budget m-tile, or tiles s), so
+    anything recording a measurement labeled with the REQUESTED knobs must
+    ask for the EFFECTIVE ones or the record lies about what was measured
     (e.g. the m-tile sweep rows in benchmarks/). Runs the SAME
     resolution as the dispatch (:func:`_resolve_knobs`).
 
     Returns ``{"kernel": False, "plan_id": "xla"}`` when the apply would
-    take the XLA fallback, else ``kernel/m_tile/operator_residency/
-    operator_cache/precision/plan_id/plan_source``
-    (``operator_cache`` is ``operator_residency == "vmem"``)."""
+    take the XLA fallback, else ``kernel/m_tile/s_tile/
+    operator_residency/operator_cache/precision/plan_id/plan_source``
+    (``operator_cache`` is ``operator_residency == "vmem"``; ``s_tile``
+    is ``s_dim`` unless the plan tiles s). ``epilogue``: the plan of a
+    feature map's apply, whose kernel finishes its tiles with the cos."""
     m_tile, precision, source = _resolve_knobs(m_tile, precision)
     A = jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
-    mt = _qualify(dist, A, seq_axis=seq_axis, m_tile=m_tile,
-                  interpret=interpret, s_dim=s_dim)
-    if mt is None:
+    tiles = _tile_plan(dist, A, seq_axis=seq_axis, m_tile=m_tile,
+                       interpret=interpret, s_dim=s_dim, epilogue=epilogue)
+    if tiles is None:
         return {"kernel": False, "plan_id": "xla",
                 "plan_source": source}
+    mt, st = tiles
     # the same padding/residency helpers the pallas_call sites use
     n_p, m_p = _padded_extents(shape[seq_axis], shape[1 - seq_axis], mt)
     residency = operator_residency(s_dim, n_p, m_p, mt,
-                                   rowwise=seq_axis == 1)
-    return {"kernel": True, "m_tile": mt,
+                                   rowwise=seq_axis == 1, s_tile=st)
+    tile_id = f"mt{mt}" if st == s_dim else f"mt{mt}/st{st}"
+    return {"kernel": True, "m_tile": mt, "s_tile": st,
             "operator_residency": residency,
             "operator_cache": residency == "vmem",
             "precision": precision,
             # the label bench records carry; tune/plans.py's
             # Plan.plan_id writes the same string for the same plan
-            "plan_id": f"pallas/mt{mt}/{precision}",
+            "plan_id": f"pallas/{tile_id}/{precision}",
             "plan_source": source}
 
 

@@ -7,23 +7,84 @@ b ~ U[0, 2π), and per-row ``scales`` that default to 1 (Matern overrides them
 with sqrt(2ν / χ²(2ν)) samples to realize multivariate-t frequencies,
 ref: RFT_data.hpp:335-346).
 
-The cos is fused by XLA into the matmul epilogue — the hand-written OpenMP
-elementwise loops of the reference (ref: RFT_Elemental.hpp:83-156) disappear.
+A dense apply is ONE compiled program (``sketch.rft_features``,
+:func:`rft_features`): frequencies, scales and shifts generated from the
+allocation's key, the projection and the featurization — the hand-written
+OpenMP elementwise loops of the reference (ref: RFT_Elemental.hpp:83-156)
+disappear. On a TPU a rowwise apply of normal frequencies runs that program
+as the fused kernels of sketch/pallas_dense.py (the operator generated once
+an apply, the contraction, and the cos finishing each result tile in VMEM;
+the result tiled along s where s_dim is wide); everywhere else it is W, one
+XLA matmul and the elementwise tail, fused by XLA.
 
 Sub-streams of the allocation: 0 = W entries, 1 = shifts, 2 = scales (Matern).
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 
 from libskylark_tpu.base import randgen
 from libskylark_tpu.sketch.dense import BLOCK_COLS
-from libskylark_tpu.sketch.transform import (OperatorCache,
-                                             SketchTransform, register)
+from libskylark_tpu.sketch.transform import (_REGISTRY, OperatorCache,
+                                             SketchTransform, note_apply,
+                                             register)
+from libskylark_tpu.telemetry import metrics as _metrics
+from libskylark_tpu.telemetry import trace as _trace
+
+_FEATURES = _metrics.counter(
+    "sketch.features",
+    "feature values produced by dense RFT applies, by family and kernel")
+
+
+class _ProgramAllocation:
+    """Stands in for the transform's Allocation inside the compiled
+    program: the key is the program's argument, so one executable serves
+    every transform of a family and shape."""
+
+    def __init__(self, key):
+        self.key = key
+
+
+def rft_features(key_data, A, *pinned, spec, rowwise: bool, plan=None):
+    """One dense feature-map apply as a pure function of the transform's
+    raw key data ((2,) uint32): frequencies, scales and shifts from the
+    key, projection, featurization. ``spec`` = (sketch_type, N, S, sorted
+    hyper-parameters) rebuilds the transform around the traced key;
+    ``pinned`` is the materialized W where the caller holds one. ``plan``
+    (a :class:`pallas_dense.Plan`) runs the rowwise projection and the cos
+    on the fused kernels (:func:`pallas_dense.features_rows`); None is the
+    XLA route."""
+    sketch_type, n, s, extra = spec
+    T = _REGISTRY[sketch_type]._from_parts(
+        n, s, _ProgramAllocation(jax.random.wrap_key_data(key_data)),
+        dict(extra))
+    if plan is not None:
+        from libskylark_tpu.sketch import pallas_dense
+
+        return pallas_dense.features_rows(
+            T.subkey(0), T.dist, A, s, T.inscale, T.outscale,
+            T.row_scales(jnp.float32), T.shifts(jnp.float32),
+            plan).astype(A.dtype)
+    W = pinned[0] if pinned else T.w_panel(0, n, A.dtype)
+    if rowwise:
+        return T._featurize(A @ W.T, feature_axis=1)
+    return T._featurize(W @ A, feature_axis=0)
+
+
+@functools.lru_cache(maxsize=None)
+def _features_program():
+    """The compiled apply, built at the first dense operand so that
+    importing the sketch layer never pulls the engine."""
+    from libskylark_tpu.engine.compiled import compiled
+
+    return compiled(rft_features, name="sketch.rft_features",
+                    static_argnames=("spec", "rowwise", "plan"))
 
 
 class RFT(OperatorCache, SketchTransform):
@@ -37,10 +98,12 @@ class RFT(OperatorCache, SketchTransform):
     def _materialize_changes_numerics(self, A, seq_axis=None) -> bool:
         from libskylark_tpu.sketch.dense import pallas_serves_eager
 
-        return pallas_serves_eager(A, self.dist, self._S, seq_axis)
+        return (seq_axis != 0 and self._kernel_family()
+                and pallas_serves_eager(A, self.dist, self._S, 1))
 
     sketch_type = "RFT"
     dist: randgen.Distribution = randgen.Normal()
+    epilogue = "cos"    # the elementwise map of _featurize, by name
 
     @property
     def inscale(self) -> float:
@@ -84,76 +147,66 @@ class RFT(OperatorCache, SketchTransform):
         sh = self.shifts(dt).reshape(shape)
         return self.outscale * jnp.cos(WA * sc + sh)
 
-    def _project_columnwise(self, A: jnp.ndarray) -> jnp.ndarray:
-        """W·A — the pinned W when materialized; on TPU via the fused
-        generation+matmul kernel (W is in the same dense-block stream
-        format as the dense transforms); XLA panel materialization
-        otherwise."""
-        from libskylark_tpu.sketch.dense import try_pallas_apply
-
-        W = self._cached_op(A.dtype)
-        if W is not None:
-            return W @ A
-        out = try_pallas_apply(
-            self.subkey(0), self.dist, A, self._S, self.inscale,
-            "columnwise_apply",
-        )
-        if out is not None:
-            return out
-        return self.w_panel(0, self._N, A.dtype) @ A
-
-    def _project_rowwise(self, A: jnp.ndarray) -> jnp.ndarray:
-        from libskylark_tpu.sketch.dense import try_pallas_apply
-
-        W = self._cached_op(A.dtype)
-        if W is not None:
-            return A @ W.T
-        out = try_pallas_apply(
-            self.subkey(0), self.dist, A, self._S, self.inscale,
-            "rowwise_apply",
-        )
-        if out is not None:
-            return out
-        return A @ self.w_panel(0, self._N, A.dtype).T
-
     def _apply_columnwise(self, A: jnp.ndarray) -> jnp.ndarray:
         self._note_eager_apply(A, seq_axis=0)
-        return self._featurize(self._project_columnwise(A), feature_axis=0)
+        return self._features(A, rowwise=False)
 
     def _apply_rowwise(self, A: jnp.ndarray) -> jnp.ndarray:
         self._note_eager_apply(A, seq_axis=1)
-        if self._op_cache is None:
-            out = self._try_fused_rowwise(A)
-            if out is not None:
-                return out
-        return self._featurize(self._project_rowwise(A), feature_axis=1)
+        return self._features(A, rowwise=True)
 
-    def _try_fused_rowwise(self, A):
-        """Fully-fused TPU path: generation + matmul + cos epilogue in one
-        kernel (pallas_dense.rft_rowwise_apply) — the feature matrix never
-        round-trips HBM between projection and featurization.
-
-        Normal-frequency transforms only (Gaussian/Matern): Cauchy
+    def _kernel_family(self) -> bool:
+        """Normal-frequency cos maps only (Gaussian/Matern): Cauchy
         frequencies (Laplacian) produce heavy-tailed phases where f32
-        ``cos`` is ill-conditioned, so tiny contraction-order differences
-        break the 1e-4 oracle — those keep the two-step path whose
-        projection is bit-compatible with the XLA panels."""
+        ``cos`` is ill-conditioned, so the kernel's contraction order
+        breaks the 1e-4 oracle — those keep XLA's float32 ``highest``
+        projection."""
+        return self.epilogue == "cos" and type(self.dist) is randgen.Normal
+
+    def _kernel_plan(self, A, interpret: bool = False):
+        """The static ``plan`` of :func:`rft_features` when the fused
+        kernels serve this rowwise apply (pallas_dense._plan: a TPU,
+        float32, a single device, a tile plan from the shapes), else None."""
         from libskylark_tpu.sketch.dense import pallas_ambient_ok
 
-        if type(self.dist) is not randgen.Normal:
-            return None
-        if not pallas_ambient_ok(A):
+        if not self._kernel_family() or not pallas_ambient_ok(A):
             return None
         from libskylark_tpu.sketch import pallas_dense
 
-        out = pallas_dense.rft_rowwise_apply(
-            self.subkey(0), self.dist, A, self._S,
-            self.inscale, self.outscale,
-            self.row_scales(jnp.float32), self.shifts(jnp.float32),
-        )
-        if out is None:
-            return None
-        return out.astype(A.dtype)
+        return pallas_dense._plan(self.dist, A, self._S, 1, None, None,
+                                  interpret, epilogue=True)
+
+    def _features(self, A: jnp.ndarray, rowwise: bool) -> jnp.ndarray:
+        """The dense apply: one ``sketch.rft_features`` program — the
+        kernel route (rowwise, nothing pinned, :meth:`_kernel_plan`) or
+        the XLA route, W generated in the program or the pinned one."""
+        W = self._cached_op(A.dtype)
+        plan = self._kernel_plan(A) if rowwise and W is None else None
+        rows = A.shape[0] if rowwise else A.shape[1]
+        attrs = {"path": "features", "family": self.sketch_type,
+                 "epilogue": self.epilogue, "kernel": "xla",
+                 "features": rows * self._S}
+        if plan is not None:
+            attrs.update(
+                kernel=("pallas_planes" if plan.operator_residency == "hbm"
+                        else "pallas_generate"),
+                m_tile=plan.m_tile, s_tile=plan.s_tile,
+                operator_residency=plan.operator_residency)
+        else:
+            note_apply(path="cached_op" if W is not None else "xla_full")
+        key_data = jax.random.key_data(self._alloc.key)
+        args = (key_data, A) if W is None else (key_data, A, W)
+        spec = (self.sketch_type, self._N, self._S,
+                tuple(sorted(self._extra_params().items())))
+        if any(isinstance(a, jax.core.Tracer) for a in args):
+            # inside a caller's trace: part of the caller's program
+            return rft_features(*args, spec=spec, rowwise=rowwise, plan=plan)
+        with _trace.span("sketch.dispatch", attrs):
+            out = _features_program()(*args, spec=spec, rowwise=rowwise,
+                                      plan=plan)
+        _FEATURES.inc_always(attrs["features"], family=self.sketch_type,
+                             kernel=attrs["kernel"])
+        return out
 
     # -- sparse input: project with the segment-sum spmm kernels --
 
@@ -296,6 +349,8 @@ class ExpSemigroupRLT(RFT):
     @property
     def outscale(self) -> float:
         return math.sqrt(1.0 / self._S)
+
+    epilogue = "exp"
 
     def _featurize(self, WA: jnp.ndarray, feature_axis: int) -> jnp.ndarray:
         return self.outscale * jnp.exp(-WA)

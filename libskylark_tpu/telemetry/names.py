@@ -51,6 +51,11 @@ METRICS: Dict[str, str] = {
     # benchmark's cross-check of the nnz that sparse_nnz_rate.apply reads
     # from the sketch.dispatch spans
     "sketch.sparse_nnz": "counter",
+    # the compiled dense feature-map apply (sketch/rft.py): feature values
+    # produced (rows × s), by family and kernel ("pallas_planes" |
+    # "pallas_generate" | "xla") — the cross-check of the features that
+    # feature_rate.apply reads from the sketch.dispatch spans
+    "sketch.features": "counter",
     # sparse serve operands (engine/serve.py, docs/serving)
     "serve.sparse_submits": "counter",
     "serve.sparse_densified": "counter",
@@ -145,7 +150,10 @@ SPANS: Dict[str, Tuple[str, str]] = {
     # nnz and nnz_class, which sparse_nnz_rate.apply reads, and for the
     # operator lookup (sparse_serve.lookup: what is computed at the lane)
     # and kernel (sparse_serve.sparse_kernel: "pallas_rows" | "xla_scatter",
-    # what adds the terms up)
+    # what adds the terms up); the feature maps' (sketch/rft.py) carries
+    # path="features", family, epilogue, kernel, features (= rows × s, which
+    # feature_rate.apply reads) and, on the kernel route, m_tile, s_tile
+    # and operator_residency
     "sketch.apply": ("sketch kernel", "sketch_host_ms.apply"),
     "sketch.plan": ("sketch kernel", "sketch_plan_ms.apply"),
     "sketch.dispatch": ("sketch kernel", "sketch_dispatch_ms.apply"),

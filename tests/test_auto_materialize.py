@@ -152,8 +152,12 @@ def test_unsupported_kernel_inputs_still_auto_pin(A, monkeypatch):
 
     # VMEM/tile decline (review finding): an f32 apply whose s_dim
     # exceeds every valid tile's VMEM budget falls back to XLA too —
-    # the veto must mirror that via effective_plan, not just supported()
-    assert not dense_mod.pallas_serves_eager(A, T.dist, 1 << 16, 1)
+    # the veto must mirror that via effective_plan, not just supported().
+    # Columnwise, where the result tile keeps its full height; a rowwise
+    # apply tiles s instead, unless s_dim is no multiple of 128 lanes
+    assert not dense_mod.pallas_serves_eager(A.T, T.dist, 1 << 16, 0)
+    assert not dense_mod.pallas_serves_eager(A, T.dist, (1 << 16) + 8, 1)
+    assert dense_mod.pallas_serves_eager(A, T.dist, 1 << 16, 1)
     # while a plannable config (small s_dim) IS vetoed
     assert dense_mod.pallas_serves_eager(A, T.dist, 16, 1)
 

@@ -44,8 +44,14 @@ def test_rehearsal_runs_every_step_on_cpu():
         assert want in steps, (want, sorted(steps))
     # each JLT orientation names the plan it ran and where the operator
     # lived; the pipelined-generation leg is gone
-    assert sum("plan=pallas/" in ln and "operator_residency=" in ln
-               for ln in lines) == 2
+    assert sum("sketch.JLT." in ln and "plan=pallas/" in ln
+               and "operator_residency=" in ln for ln in lines) == 2
+    # the feature map at the headline width and at speech widths (440
+    # inputs, the result tiled along s): the fused cos kernel both times
+    fused = [ln for ln in lines if "sketch.GaussianRFT.fused" in ln]
+    assert len(fused) == 2 and all("backend=pallas_dense.rft_cos" in ln
+                                   for ln in fused)
+    assert "x440->" in fused[1] and "/st" in fused[1]
     assert not any("/pipe" in ln for ln in lines)
     # the rowwise sparse leg says which program added the terms up
     assert sum("sketch.CWT.sparse_rows" in ln and "kernel=xla_scatter" in ln

@@ -228,7 +228,7 @@ def test_rft_fully_fused_epilogue(shape):
     production apply (XLA path) — incl. ragged shapes. Normal-frequency
     transforms only: Cauchy frequencies (Laplacian) give heavy-tailed
     phases where f32 cos is ill-conditioned, so the fused path is gated
-    off for them (rft.py _try_fused_rowwise)."""
+    off for them (rft.py _kernel_family)."""
     from libskylark_tpu.sketch.rft import GaussianRFT
 
     m, n = shape
@@ -342,7 +342,7 @@ def test_effective_plan_reports_actual_config():
     # (plan_id/precision/plan_source) and says who chose the knobs.
     p = pd.effective_plan(dist, (8192, 8192), jnp.float32, 512,
                           seq_axis=1, m_tile=1024, interpret=True)
-    assert p == {"kernel": True, "m_tile": 1024,
+    assert p == {"kernel": True, "m_tile": 1024, "s_tile": 512,
                  "operator_residency": "hbm", "operator_cache": False,
                  "precision": "bf16x3",
                  "plan_id": "pallas/mt1024/bf16x3",

@@ -1,6 +1,6 @@
-"""The sketch kernels of both cells — the fused dense ones and the rowwise
-sparse hash kernel — compile for a TPU v5e at the benchmark's widths —
-without a chip: the TPU compiler is installed here and compiles for a
+"""The sketch kernels of the cells — the fused dense ones (the s-tiled
+feature-map kernels among them) and the rowwise sparse hash kernel —
+compile for a TPU v5e at the benchmark's widths — without a chip: the TPU compiler is installed here and compiles for a
 DESCRIBED topology, so what Mosaic would refuse on the chip (a tile past
 its 16 MiB scoped VMEM, a misaligned slice) is refused in tier-1. Nothing
 runs, so nothing here is a time or a result.
@@ -22,6 +22,8 @@ from libskylark_tpu.sketch import pallas_sparse, sparse_serve
 from libskylark_tpu.sketch.dense import BLOCK_COLS
 
 ROWS, N = 65536, 8192           # the jlt_apply cell's panel
+# an rft_features_apply panel: examples, inputs (ragged), one feature block
+FEATURE_ROWS, FEATURE_N, FEATURE_S = 32768, 440, 16384
 # a cwt_sparse_apply block: rows, features, lane_class of its ~19.4 M nonzeros
 SPARSE_ROWS, SPARSE_N, SPARSE_LANES = 262144, 47236, 19922944
 KERNEL = 'custom_call_target="tpu_custom_call"'
@@ -88,6 +90,86 @@ def test_cell_shape_rft_cos_hbm(one_chip):
         ((1, 1024), jnp.float32), ((1, 1024), jnp.float32),
         inscale=0.5, outscale=0.25)
     assert plan["operator_residency"] == "hbm" and kernels == 2
+
+
+def _feature_plan():
+    plan = pd.effective_plan(randgen.Normal(), (FEATURE_ROWS, FEATURE_N),
+                             jnp.float32, FEATURE_S, 1, precision="bf16x3",
+                             interpret=True, epilogue=True)
+    assert (plan["m_tile"], plan["s_tile"], plan["operator_residency"]) == (
+        512, 1024, "hbm")
+    return plan
+
+
+def test_feature_cell_shape_s_tiled_cos(one_chip):
+    """32768 × 440 → 16384, the kernels alone on the UNPADDED operand: a
+    generation call over (s-tile, column block) and a contraction call over
+    (row tile, s-tile) whose one 512-deep step ends in the cos."""
+    plan = _feature_plan()
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = pd._fused_call_cos.lower(
+        arg((FEATURE_ROWS, FEATURE_N), jnp.float32), arg((2, 2), jnp.uint32),
+        arg((1, FEATURE_S), jnp.float32), arg((1, FEATURE_S), jnp.float32),
+        s_dim=FEATURE_S, dist_kind="normal", m_tile=plan["m_tile"],
+        s_tile=plan["s_tile"], precision="bf16x3", inscale=1.0 / 30.0,
+        outscale=0.011).compile().as_text()
+    assert text.count(KERNEL) == 2
+
+
+@pytest.mark.parametrize("m_tile,s_dim,want", [
+    (512, 5120, (512, 1280, "hbm")), (256, 4096, (256, 1024, "vmem")),
+    (128, 3200, (64, 3200, "hbm")), (512, 12288, (512, 1024, "hbm"))])
+def test_other_feature_widths_compile(one_chip, m_tile, s_dim, want):
+    """What the plan gives at other widths, tiled and not, under both
+    residencies. The plan counts the cos's temporaries: without them it
+    let 512 × 1536 through, for which Mosaic asks 17.0 MiB of its 16."""
+    assert not pd._tile_fits(512, 1536, True) and pd._tile_fits(512, 1536, False)
+    plan = pd.effective_plan(randgen.Normal(), (4096, FEATURE_N),
+                             jnp.float32, s_dim, 1, m_tile=m_tile,
+                             interpret=True, epilogue=True)
+    assert (plan["m_tile"], plan["s_tile"],
+            plan["operator_residency"]) == want
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = pd._fused_call_cos.lower(
+        arg((4096, FEATURE_N), jnp.float32), arg((2, 2), jnp.uint32),
+        arg((1, s_dim), jnp.float32), arg((1, s_dim), jnp.float32),
+        s_dim=s_dim, dist_kind="normal", m_tile=plan["m_tile"],
+        s_tile=plan["s_tile"], precision="bf16x3", inscale=0.5,
+        outscale=0.25).compile().as_text()
+    assert text.count(KERNEL) == (2 if want[2] == "hbm" else 1)
+
+
+@pytest.mark.parametrize("kernel_route", [True, False],
+                         ids=["pallas_planes", "xla"])
+def test_feature_cell_whole_program(one_chip, kernel_route):
+    """``sketch.rft_features`` at the cell's shape, both routes: key data
+    and the unpadded panel in, the 2 GiB feature block out — shifts, the
+    block keys and the pad to 512 columns inside the one executable."""
+    from libskylark_tpu.sketch import rft
+
+    plan = _feature_plan()
+    program = jax.jit(functools.partial(
+        rft.rft_features,
+        spec=("GaussianRFT", FEATURE_N, FEATURE_S, (("sigma", 30.0),)),
+        rowwise=True,
+        plan=(pd.Plan(plan["m_tile"], plan["s_tile"], "bf16x3",
+                      plan["operator_residency"])
+              if kernel_route else None)))
+    compiled = program.lower(
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
+        jax.ShapeDtypeStruct((FEATURE_ROWS, FEATURE_N), jnp.float32,
+                             sharding=one_chip)).compile()
+    assert compiled.as_text().count(KERNEL) == (2 if kernel_route else 0)
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == FEATURE_ROWS * FEATURE_S * 4
+    # the padded operand and the planes; never a second feature block
+    assert memory.temp_size_in_bytes < 256 << 20
 
 
 @pytest.mark.parametrize("shape,seq_axis,call,residency", [
