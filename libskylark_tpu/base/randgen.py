@@ -53,15 +53,20 @@ def _chunk_key_data(kd: jax.Array, hi, lo) -> jax.Array:
     return tf.fold_in(tf.fold_in(kd, hi), lo)
 
 
-def chunk_key(key: jax.Array, cid) -> jax.Array:
-    """Key for chunk ``cid`` (host int of any size, or traced int32 < 2^31)."""
-    kd = jr.key_data(key)
+def chunk_key_data(kd: jax.Array, cid) -> jax.Array:
+    """Raw key words of chunk ``cid`` (host int of any size, or traced
+    int32 < 2^31) under the raw key words ``kd`` ((2,) uint32)."""
     if isinstance(cid, (int, np.integer)):
         hi, lo = int(cid) >> 31, int(cid) & _MASK31
     else:
         # Traced chunk ids are restricted to < 2^31 (hi word = 0).
         hi, lo = 0, cid
-    return jr.wrap_key_data(_chunk_key_data(kd, hi, lo))
+    return _chunk_key_data(kd, hi, lo)
+
+
+def chunk_key(key: jax.Array, cid) -> jax.Array:
+    """Key for chunk ``cid`` (:func:`chunk_key_data` around typed keys)."""
+    return jr.wrap_key_data(chunk_key_data(jr.key_data(key), cid))
 
 
 # ---------------------------------------------------------------------------

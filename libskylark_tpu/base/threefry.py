@@ -36,8 +36,32 @@ _PARITY = 0x1BD11BDA
 # dtype and trace cleanly both in XLA and inside kernels.
 
 
-def _rotl(x: jnp.ndarray, r: int) -> jnp.ndarray:
-    return (x << r) | (x >> (32 - r))
+def _same(x):
+    return x
+
+
+def _rotl(x, r: int, wrap=_same):
+    return wrap(x << r) | (x >> (32 - r))
+
+
+def _cipher(k0, k1, x0, x1, wrap=_same):
+    """The 20 rounds on counter words (x0, x1) under key (k0, k1): the one
+    definition of the schedule, for uint32 arrays (traced or not — they
+    wrap by themselves, ``wrap`` stays the identity and adds no op) and,
+    with ``wrap`` = :func:`_wrap32`, for plain Python ints on the host."""
+    ks2 = k0 ^ k1 ^ _PARITY
+    x0 = wrap(x0 + k0)
+    x1 = wrap(x1 + k1)
+    keys = (k0, k1, ks2)
+    for group in range(5):
+        r0, r1, r2, r3 = _ROTATIONS[:4] if group % 2 == 0 else _ROTATIONS[4:]
+        for r in (r0, r1, r2, r3):
+            x0 = wrap(x0 + x1)
+            x1 = _rotl(x1, r, wrap) ^ x0
+        # key injection after each 4-round group
+        x0 = wrap(x0 + keys[(group + 1) % 3])
+        x1 = wrap(x1 + keys[(group + 2) % 3] + (group + 1))
+    return x0, x1
 
 
 def threefry2x32(k0, k1, c0: jnp.ndarray, c1: jnp.ndarray
@@ -47,21 +71,8 @@ def threefry2x32(k0, k1, c0: jnp.ndarray, c1: jnp.ndarray
     ``c0``/``c1`` are uint32 arrays; ``k0``/``k1`` are uint32 scalars
     (python ints, numpy scalars, or traced values — e.g. SMEM reads inside
     a Pallas kernel). Returns two uint32 arrays of c0's shape — 64 random
-    bits per counter.
-    """
-    ks2 = k0 ^ k1 ^ _PARITY
-    x0 = c0.astype(jnp.uint32) + k0
-    x1 = c1.astype(jnp.uint32) + k1
-    keys = (k0, k1, ks2)
-    for group in range(5):
-        r0, r1, r2, r3 = _ROTATIONS[:4] if group % 2 == 0 else _ROTATIONS[4:]
-        for r in (r0, r1, r2, r3):
-            x0 = x0 + x1
-            x1 = _rotl(x1, r) ^ x0
-        # key injection after each 4-round group
-        x0 = x0 + keys[(group + 1) % 3]
-        x1 = x1 + keys[(group + 2) % 3] + (group + 1)
-    return x0, x1
+    bits per counter."""
+    return _cipher(k0, k1, c0.astype(jnp.uint32), c1.astype(jnp.uint32))
 
 
 def fold_in(kd: jnp.ndarray, data) -> jnp.ndarray:
@@ -72,6 +83,36 @@ def fold_in(kd: jnp.ndarray, data) -> jnp.ndarray:
     d = jnp.asarray(data).astype(jnp.uint32)
     x0, x1 = threefry2x32(kd[0], kd[1], jnp.zeros_like(d), d)
     return jnp.stack([x0, x1], axis=-1)
+
+
+# -- the host replay: the same cipher on Python ints, no device involved --
+
+_MASK32 = 0xFFFFFFFF
+
+
+def _wrap32(x: int) -> int:
+    return x & _MASK32
+
+
+def seed_words(seed: int) -> Tuple[int, int]:
+    """Key words of the root key of ``seed``: (0, low 32 bits of the seed's
+    two's complement), for every seed a 64-bit signed integer holds —
+    ``jax.random.key(seed)`` under JAX's default 32-bit mode, which drops
+    the high word (2**32 + 5 seeds like 5, -1 like 2**32 - 1). A seed
+    outside 64 signed bits is an OverflowError, as it is there. Serialized
+    transforms store the seed, so the rule is part of the stream format."""
+    return 0, int(np.int64(int(seed))) & _MASK32
+
+
+def fold_in_words(kd: Tuple[int, int], data: int) -> Tuple[int, int]:
+    """:func:`fold_in` on the host: ``kd`` and the result are pairs of
+    Python ints. ``data`` outside [0, 2**32) is an OverflowError, as it is
+    for ``jax.random.fold_in``."""
+    data = int(data)
+    if not 0 <= data <= _MASK32:
+        raise OverflowError(
+            f"Python integer {data} out of bounds for uint32")
+    return _cipher(kd[0], kd[1], 0, data, _wrap32)
 
 
 def chunk_bits(kd: jnp.ndarray, n: int) -> jnp.ndarray:

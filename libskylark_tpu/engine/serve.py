@@ -139,7 +139,6 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
-import functools
 import itertools
 import math
 import sys
@@ -661,15 +660,13 @@ def _sparse_solve_statics(transform, A, B, method, pad_floor):
                      "nnz_class": nnz_cls, "dtype": dtype}
 
 
-@functools.lru_cache(maxsize=1024)
 def _seed_key_data(seed: int) -> np.ndarray:
-    """Raw PRNG key data of ``jax.random.key(seed)`` as a host array —
-    the key material of the seed-addressed endpoints (graph_ase,
-    condest). Cached: the key derivation is a host-synced jax op worth
-    paying once per seed, not once per request."""
-    import jax.random as jr
+    """Raw key data of the root key of ``seed`` (``jax.random.key(seed)``'s
+    words, by base/threefry.py's rule) as a host array — the key material
+    of the seed-addressed endpoints (graph_ase, condest)."""
+    from libskylark_tpu.base import threefry
 
-    return np.asarray(jr.key_data(jr.key(int(seed))), dtype=np.uint32)
+    return np.array(threefry.seed_words(seed), dtype=np.uint32)
 
 
 def _coerce_adjacency(A):
@@ -1950,21 +1947,10 @@ class MicrobatchExecutor:
 
     @staticmethod
     def _key_data(transform) -> np.ndarray:
-        """Raw key data of the transform's allocation, cached on the
-        transform — submit is on the request hot path and the key
-        derivation is a (host-synced) jax op worth paying once per
-        transform, not once per request."""
-        kd = getattr(transform, "_serve_key_data", None)
-        if kd is None:
-            import jax.random as jr
-
-            kd = np.asarray(jr.key_data(transform.allocation.key),
-                            dtype=np.uint32)
-            try:
-                transform._serve_key_data = kd
-            except Exception:
-                pass
-        return kd
+        """Raw key data of the transform's allocation as a host array —
+        submit is on the request hot path; the allocation keeps its
+        words (base/context.py), no device involved."""
+        return transform.allocation.key_words
 
     def _prep_sketch(self, transform, A, dimension=None, _derived=None):
         statics, info = _derived or _sketch_statics(

@@ -83,7 +83,8 @@ def _pipeline(T, A, mesh: Mesh, axis: str, seq_axis: int,
 
     # Global block-key table, sharded so each device gets its own slice
     # (same bits as T.s_block — see pallas_dense._block_keys).
-    keys_all = pd._block_keys(T._alloc.key, pad_N) if use_pallas else None
+    keys_all = (pd._block_keys(T._alloc.key_data, pad_N) if use_pallas
+                else None)
 
     def local(A_loc, keys_loc):
         d = lax.axis_index(axis)

@@ -237,7 +237,7 @@ class DenseTransform(OperatorCache, SketchTransform):
 
     def _try_pallas(self, A, which: str):
         return try_pallas_apply(
-            self._alloc.key, self.dist, A, self._S, self.scale, which
+            self._alloc.key_data, self.dist, A, self._S, self.scale, which
         )
 
     # -- sparse input (ref: sketch/dense_transform_Mixed.hpp:19) --

@@ -164,7 +164,7 @@ class HashTransform(SketchTransform):
         from libskylark_tpu.sketch.sparse_serve import lookup, sparse_kernel
 
         data, indices, indptr = A.csr_device()
-        key_data = jax.random.key_data(self._alloc.key)
+        key_data = self._alloc.key_data
         values = self._value_kind()
         kernel = sparse_kernel(A.shape, self._S, int(data.shape[0]),
                                data.dtype, rowwise)

@@ -588,6 +588,15 @@ def _rowwise_pallas_call(A, keys, scale, extra_operands, *, s_dim, dist_kind,
     return out if scale is None else scale * out
 
 
+def _block_table(keys, n: int) -> jnp.ndarray:
+    """The (n_blocks, 2) block-key table of a fused call, traceable:
+    ``keys`` itself where the caller holds a table (a shard's slice of
+    the global one, parallel/shard_apply.py), derived from the
+    allocation's (2,) key words otherwise — a dozen cipher calls inside
+    the apply's own program, not a dispatch before it."""
+    return keys if keys.ndim == 2 else _block_key_table(keys, n)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("s_dim", "dist_kind", "m_tile", "precision",
@@ -595,10 +604,10 @@ def _rowwise_pallas_call(A, keys, scale, extra_operands, *, s_dim, dist_kind,
 )
 def _fused_call(A, keys, scale=None, *, s_dim, dist_kind, m_tile,
                 precision="f32", interpret=False, s_tile=None):
-    return _rowwise_pallas_call(A, keys, scale, (), s_dim=s_dim,
-                                dist_kind=dist_kind, m_tile=m_tile,
-                                precision=precision, interpret=interpret,
-                                s_tile=s_tile)
+    return _rowwise_pallas_call(A, _block_table(keys, A.shape[1]), scale, (),
+                                s_dim=s_dim, dist_kind=dist_kind,
+                                m_tile=m_tile, precision=precision,
+                                interpret=interpret, s_tile=s_tile)
 
 
 @functools.partial(
@@ -609,10 +618,10 @@ def _fused_call(A, keys, scale=None, *, s_dim, dist_kind, m_tile,
 def _fused_call_cos(A, keys, sc, sh, *, s_dim, dist_kind, m_tile,
                     precision="f32", inscale=1.0, outscale=1.0,
                     interpret=False, s_tile=None):
-    return _rowwise_pallas_call(A, keys, None, (sc, sh), s_dim=s_dim,
-                                dist_kind=dist_kind, m_tile=m_tile,
-                                precision=precision, interpret=interpret,
-                                s_tile=s_tile,
+    return _rowwise_pallas_call(A, _block_table(keys, A.shape[1]), None,
+                                (sc, sh), s_dim=s_dim, dist_kind=dist_kind,
+                                m_tile=m_tile, precision=precision,
+                                interpret=interpret, s_tile=s_tile,
                                 epilogue=("cos", inscale, outscale))
 
 
@@ -643,7 +652,7 @@ def _fused_call_cw(A, keys, *, s_dim, dist_kind, m_tile, precision="f32",
         scratch_shapes=_operator_scratch(residency, s_dim, n),
         compiler_params=_grid_params(residency),
         interpret=interpret,
-    )(keys, A)
+    )(_block_table(keys, n), A)
 
 
 _DIST_KINDS = {
@@ -820,16 +829,30 @@ def _plan(dist, A, s_dim: int, seq_axis: int, m_tile, precision,
 
 
 @functools.partial(jax.jit, static_argnames="n")
-def _block_key_table(key, n: int) -> jnp.ndarray:
+def _block_key_table(kd, n: int) -> jnp.ndarray:
+    """uint32 (n_blocks, 2) Threefry key table for column blocks 0..n/BC
+    of the stream under raw key words ``kd`` ((2,) uint32): block b's
+    words are those of ``randgen.chunk_key(key, b)``."""
     n_blocks = -(-n // BLOCK_COLS)
-    return jax.vmap(lambda b: jr.key_data(randgen.chunk_key(key, b)))(
+    return jax.vmap(lambda b: randgen.chunk_key_data(kd, b))(
         jnp.arange(n_blocks, dtype=jnp.int32))
 
 
+def _key_data(key):
+    """The raw (2,) uint32 words of ``key``. The entry points below take
+    the allocation's typed key or its ``Allocation.key_data``; a typed key
+    is unwrapped here, one eager op where no trace is active — the hot
+    caller (sketch/dense.py) hands the words."""
+    if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
+        return jr.key_data(key)
+    return key
+
+
 def _block_keys(key, n: int) -> jnp.ndarray:
-    """uint32 (n_blocks, 2) Threefry key table for column blocks 0..n/BC."""
+    """:func:`_block_key_table` as a dispatch of its own, for the caller
+    that shards the table (parallel/shard_apply.py)."""
     with _trace.span("stream.key", {"what": "block_table"}):
-        return _block_key_table(key, n)
+        return _block_key_table(_key_data(key), n)
 
 
 def _padded_extents(n: int, m: int, mt: int) -> tuple[int, int]:
@@ -873,14 +896,15 @@ def rowwise_apply(
     plan = _plan(dist, A, s_dim, 1, m_tile, precision, interpret)
     if plan is None:
         return None
-    keys = _block_keys(key, A.shape[1])
+    kd = _key_data(key)
     with _trace.span("sketch.dispatch") as sp:
         if sp is not None:
             sp.set_attr("padded", _is_padded(A, 1, plan.m_tile))
-        # one executable: the padding (where the operand is ragged), the
-        # kernel(s) and the scale — folded into the planes under the
-        # "hbm" residency, a pass over the result otherwise
-        return _fused_call(A, keys, scale, s_dim=s_dim,
+        # one executable: the block-key table, the padding (where the
+        # operand is ragged), the kernel(s) and the scale — folded into
+        # the planes under the "hbm" residency, a pass over the result
+        # otherwise
+        return _fused_call(A, kd, scale, s_dim=s_dim,
                            dist_kind=_DIST_KINDS[type(dist)],
                            m_tile=plan.m_tile, s_tile=plan.s_tile,
                            precision=plan.precision, interpret=interpret)
@@ -903,12 +927,12 @@ def columnwise_apply(
         return None
     mt, precision = plan.m_tile, plan.precision
     m = A.shape[1]
-    keys = _block_keys(key, A.shape[0])
+    kd = _key_data(key)
     with _trace.span("sketch.dispatch") as sp:
         Ap = _padded(A, seq_axis=0, mt=mt)
         if sp is not None:
             sp.set_attr("padded", Ap is not A)
-        out = _fused_call_cw(Ap, keys, s_dim=s_dim,
+        out = _fused_call_cw(Ap, kd, s_dim=s_dim,
                              dist_kind=_DIST_KINDS[type(dist)], m_tile=mt,
                              precision=precision, interpret=interpret)
         return scale * out[:, :m]
@@ -938,12 +962,12 @@ def rft_rowwise_apply(
                  epilogue=True)
     if plan is None:
         return None
-    keys = _block_keys(key, A.shape[1])
+    kd = _key_data(key)
     with _trace.span("sketch.dispatch") as sp:
         if sp is not None:
             sp.set_attr("padded", _is_padded(A, 1, plan.m_tile))
         return _fused_call_cos(
-            A, keys,
+            A, kd,
             jnp.asarray(sc, jnp.float32).reshape(1, s_dim),
             jnp.asarray(sh, jnp.float32).reshape(1, s_dim),
             s_dim=s_dim, dist_kind=_DIST_KINDS[type(dist)],
@@ -959,7 +983,7 @@ def features_rows(key, dist, A, s_dim: int, inscale: float, outscale: float,
     kernel(s) and the slice back, for the caller's one executable
     (sketch/rft.py ``sketch.rft_features``)."""
     return _rowwise_pallas_call(
-        A, _block_key_table(key, A.shape[1]), None,
+        A, _block_key_table(_key_data(key), A.shape[1]), None,
         (sc.astype(jnp.float32).reshape(1, s_dim),
          sh.astype(jnp.float32).reshape(1, s_dim)),
         s_dim=s_dim, dist_kind=_DIST_KINDS[type(dist)], m_tile=plan.m_tile,
@@ -1185,9 +1209,8 @@ def serve_batched_apply(key_data, scale, A, *, dist, s_dim: int,
     pads[3 - n_axis] = (0, m_p - m)
     Ap = jnp.pad(A, pads) if (n_p != n or m_p != m) else A
     B = A.shape[0]
-    keys = jax.vmap(
-        lambda k: _block_keys(jr.wrap_key_data(k), n))(
-            jnp.asarray(key_data, jnp.uint32))
+    keys = jax.vmap(lambda k: _block_key_table(k, n))(
+        jnp.asarray(key_data, jnp.uint32))
     out = _batched_call(
         Ap, keys.reshape(B * keys.shape[1], 2),
         jnp.asarray(scale, jnp.float32).reshape(B),

@@ -498,9 +498,6 @@ def try_apply(transform, A, *, rowwise: bool) -> Optional[jnp.ndarray]:
     ok, _why = qualify(transform.sketch_dim, n, m, A.dtype)
     if not ok:
         return None
-    import numpy as np
-
-    kd = np.asarray(jax.random.key_data(transform.allocation.key),
-                    dtype=np.uint32)
-    return cwt_apply(kd, A, s_dim=transform.sketch_dim, rowwise=rowwise,
+    return cwt_apply(transform.allocation.key_words, A,
+                     s_dim=transform.sketch_dim, rowwise=rowwise,
                      accum=accum)

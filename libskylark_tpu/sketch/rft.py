@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from libskylark_tpu.base import randgen
+from libskylark_tpu.base import threefry as tf
 from libskylark_tpu.sketch.dense import BLOCK_COLS
 from libskylark_tpu.sketch.transform import (_REGISTRY, OperatorCache,
                                              SketchTransform, note_apply,
@@ -44,11 +45,18 @@ _FEATURES = _metrics.counter(
 
 class _ProgramAllocation:
     """Stands in for the transform's Allocation inside the compiled
-    program: the key is the program's argument, so one executable serves
-    every transform of a family and shape."""
+    program: the key words are the program's argument, so one executable
+    serves every transform of a family and shape."""
 
-    def __init__(self, key):
-        self.key = key
+    def __init__(self, key_data):
+        self.key_data = key_data
+
+    @property
+    def key(self):
+        return jax.random.wrap_key_data(self.key_data)
+
+    def child(self, tag: int) -> "_ProgramAllocation":
+        return _ProgramAllocation(tf.fold_in(self.key_data, tag))
 
 
 def rft_features(key_data, A, *pinned, spec, rowwise: bool, plan=None):
@@ -62,13 +70,12 @@ def rft_features(key_data, A, *pinned, spec, rowwise: bool, plan=None):
     XLA route."""
     sketch_type, n, s, extra = spec
     T = _REGISTRY[sketch_type]._from_parts(
-        n, s, _ProgramAllocation(jax.random.wrap_key_data(key_data)),
-        dict(extra))
+        n, s, _ProgramAllocation(key_data), dict(extra))
     if plan is not None:
         from libskylark_tpu.sketch import pallas_dense
 
         return pallas_dense.features_rows(
-            T.subkey(0), T.dist, A, s, T.inscale, T.outscale,
+            T._alloc.child(0).key_data, T.dist, A, s, T.inscale, T.outscale,
             T.row_scales(jnp.float32), T.shifts(jnp.float32),
             plan).astype(A.dtype)
     W = pinned[0] if pinned else T.w_panel(0, n, A.dtype)
@@ -194,7 +201,7 @@ class RFT(OperatorCache, SketchTransform):
                 operator_residency=plan.operator_residency)
         else:
             note_apply(path="cached_op" if W is not None else "xla_full")
-        key_data = jax.random.key_data(self._alloc.key)
+        key_data = self._alloc.key_data
         args = (key_data, A) if W is None else (key_data, A, W)
         spec = (self.sketch_type, self._N, self._S,
                 tuple(sorted(self._extra_params().items())))

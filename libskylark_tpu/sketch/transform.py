@@ -208,7 +208,7 @@ class SketchTransform:
     def subkey(self, tag: int) -> jax.Array:
         """Sub-stream key ``tag`` of this transform's allocation; the analog
         of the reference's sequential counter advancement during build."""
-        return jax.random.fold_in(self._alloc.key, tag)
+        return self._alloc.child(tag).key
 
     # -- apply --
 

@@ -56,6 +56,12 @@ METRICS: Dict[str, str] = {
     # "pallas_generate" | "xla") — the cross-check of the features that
     # feature_rate.apply reads from the sketch.dispatch spans
     "sketch.features": "counter",
+    # accesses of an allocation's key material (base/context.py), by
+    # result ("hit": kept from an earlier access | "miss": derived now),
+    # always on — over a benchmark window every access is a hit (the
+    # misses are in warm-up); the count beside the stream.key spans'
+    # attribute cached
+    "stream.key_cache": "counter",
     # sparse serve operands (engine/serve.py, docs/serving)
     "serve.sparse_submits": "counter",
     "serve.sparse_densified": "counter",
@@ -157,7 +163,11 @@ SPANS: Dict[str, Tuple[str, str]] = {
     "sketch.apply": ("sketch kernel", "sketch_host_ms.apply"),
     "sketch.plan": ("sketch kernel", "sketch_plan_ms.apply"),
     "sketch.dispatch": ("sketch kernel", "sketch_dispatch_ms.apply"),
-    # base/context.py Allocation.key, pallas_dense._block_keys
+    # every access of an allocation's key material (base/context.py
+    # Allocation.key / key_data / key_words), attribute cached (True: kept
+    # from an earlier access, False: derived now), and the block-key
+    # table where it is a dispatch of its own (pallas_dense._block_keys,
+    # the sharded apply's; a fused apply derives it inside its program)
     "stream.key": ("streams", "stream_key_ms.apply"),
     # the measured solve (nla/svd.py, engine/compiled.py)
     "nla.approximate_svd": ("solver phases", "operator"),

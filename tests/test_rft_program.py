@@ -143,6 +143,8 @@ def test_off_the_kernel_one_compile_one_dispatch(fresh):
     # and the one enqueue is the engine's call under the dispatch span
     assert [s.name for s in kids] == ["sketch.plan", "stream.key",
                                       "sketch.dispatch"]
+    assert kids[1].attrs["what"] == "allocation"
+    assert isinstance(kids[1].attrs["cached"], bool)
     dispatch = kids[2]
     calls = [s for s in spans if s.name == "engine.call"]
     assert len(calls) == 1 and calls[0].parent_id == dispatch.span_id
@@ -219,6 +221,8 @@ def test_kernel_route_spans_and_counter(fresh, interpreted, monkeypatch, scope,
     kids = [s_ for s_ in spans if s_.parent_id == root.span_id]
     assert [s_.name for s_ in kids] == ["sketch.plan", "stream.key",
                                         "sketch.dispatch"]
+    # one key access an apply: the block-key table is the program's
+    assert kids[1].attrs["what"] == "allocation"
     dispatch = kids[2].attrs
     assert dispatch["path"] == "features" and dispatch["epilogue"] == "cos"
     assert dispatch["family"] == "GaussianRFT" and dispatch["kernel"] == kernel

@@ -170,8 +170,11 @@ class TestAgainstTheOracles:
         T.apply(A, dimension).block_until_ready()
         spans = trace.finished_spans()
         root = next(s for s in spans if s.name == "sketch.apply")
-        assert [s.name for s in spans if s.parent_id == root.span_id] == [
-            "stream.key", "sketch.dispatch"]
+        kids = [s for s in spans if s.parent_id == root.span_id]
+        assert [s.name for s in kids] == ["stream.key", "sketch.dispatch"]
+        # ... from what the first apply kept: no derivation, no dispatch
+        assert kids[0].attrs == {"what": "allocation", "path_len": 0,
+                                 "cached": True}
 
 
     def test_lookup_is_computed_not_gathered(self, family, kwargs, dimension):

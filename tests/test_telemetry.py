@@ -557,8 +557,11 @@ class TestHotPathSpans:
         assert _one(spans, "sketch.dispatch").attrs["padded"] is False
         whats = sorted(s.attrs["what"] for s in kids
                        if s.name == "stream.key")
+        assert all(isinstance(s.attrs["cached"], bool) for s in kids
+                   if s.name == "stream.key")
         if path == "pallas":
-            assert whats == ["allocation", "block_table"]
+            # the block-key table is derived inside the apply's program
+            assert whats == ["allocation"]
             assert root.attrs["plan_source"] in ("cache", "heuristic")
             assert root.attrs["m_tile"] >= 8 and root.attrs["precision"]
             # one m-tile at this shape: nothing to keep between tiles
@@ -759,6 +762,38 @@ class TestSpanNames:
         undeclared = {name: sites for name, sites in _span_literals().items()
                       if name not in self.SPANS}
         assert undeclared == {}
+
+    def test_stream_key_says_whether_it_hit(self):
+        """The ``stream.key`` span of an allocation sets ``cached`` and
+        the access is counted in ``stream.key_cache{result}``, declared
+        and created once: what reads the share of hits keys on both."""
+        import ast
+        import pathlib
+
+        import libskylark_tpu
+        from libskylark_tpu.telemetry.names import METRICS
+
+        assert METRICS["stream.key_cache"] == "counter"
+        source = (pathlib.Path(libskylark_tpu.__file__).parent
+                  / "base" / "context.py").read_text()
+        (block,) = [
+            node for node in ast.walk(ast.parse(source))
+            if isinstance(node, ast.With) and any(
+                isinstance(leaf, ast.Constant) and leaf.value == "stream.key"
+                for item in node.items for leaf in ast.walk(item))]
+        attrs = [call.args[0].value for call in ast.walk(block)
+                 if isinstance(call, ast.Call)
+                 and getattr(call.func, "attr", None) == "set_attr"]
+        assert attrs == ["cached"]
+        created = [call for call in ast.walk(ast.parse(source))
+                   if isinstance(call, ast.Call)
+                   and getattr(call.func, "attr", None) == "counter"]
+        assert [c.args[0].value for c in created] == ["stream.key_cache"]
+        labels = {kw.arg for call in ast.walk(ast.parse(source))
+                  if isinstance(call, ast.Call)
+                  and getattr(call.func, "attr", None) == "inc_always"
+                  for kw in call.keywords}
+        assert labels == {"result"}
 
     @pytest.mark.parametrize("name", sorted(SPANS))
     def test_declared_span_has_a_call_site(self, name):

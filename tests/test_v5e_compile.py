@@ -55,9 +55,9 @@ def _compile(call, one_chip, shape, s_dim, seq_axis, precision, *operands,
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    n = shape[seq_axis]
+    # the allocation's key words: the program derives its block-key table
     compiled = call.lower(
-        arg(shape, jnp.float32), arg((n // BLOCK_COLS, 2), jnp.uint32),
+        arg(shape, jnp.float32), arg((2,), jnp.uint32),
         *[arg(*o) for o in operands],
         s_dim=s_dim, dist_kind="normal", m_tile=plan["m_tile"],
         precision=precision, **statics).compile()
