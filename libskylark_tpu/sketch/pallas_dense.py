@@ -10,12 +10,12 @@ do NOT overlap (PERF.md §6, PR 27: a step costs generation plus matmul, at
 ≈ 46 G entries/s on a v5e), so what matters is how often a panel is made.
 :func:`operator_residency` decides, from shapes only, where the operator
 lives between the m-tiles of one apply: ``"vmem"`` (small S: generated in
-the first m-tile sweep into VMEM scratch), ``"hbm"`` (rowwise, big S: a
-generation kernel writes S once an apply to HBM, scale folded in, as the
-bf16 hi/lo planes the contraction needs, and a contraction kernel streams
-them beside the A tiles) or ``"per_tile"`` (a single m-tile, or the
-columnwise big-S case: regenerated in every grid step). Nothing is kept
-across applies.
+the first m-tile sweep into VMEM scratch), ``"hbm"`` (big S, either
+orientation: a generation kernel writes S once an apply to HBM, scale
+folded in, as the bf16 hi/lo planes the contraction needs, and a
+contraction kernel streams them beside the A tiles) or ``"per_tile"`` (a
+single m-tile: generated in the grid step that contracts it). Nothing is
+kept across applies.
 
 Rowwise (out = A·Sᵀ, the regime of BASELINE config 1) and columnwise
 (out = S·A) applies; inputs the kernel declines (wrong backend,
@@ -181,8 +181,8 @@ _VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 # Cap on the "vmem" residency (:func:`operator_residency`): when the
 # full virtual S fits, each block is generated ONCE (first m-tile sweep)
 # into VMEM scratch and every later tile contracts against the cached
-# copy. A larger operator is kept in HBM instead (rowwise) or regenerated
-# per tile (columnwise). Must leave room for Mosaic's double-buffered
+# copy. A larger operator is kept in HBM instead (the "hbm" residency).
+# Must leave room for Mosaic's double-buffered
 # A/out tiles inside _VMEM_BUDGET_BYTES (advisor r2 medium finding: the
 # old 48 MiB default exceeded the scoped limit and could fail Mosaic
 # compilation outright on the shard_map path).
@@ -214,22 +214,21 @@ def _vmem_estimate(m_tile: int, s_tile: int, scratch_bytes: int) -> int:
 
 
 def operator_residency(s_dim: int, n: int, m: int, m_tile: int,
-                       rowwise: bool, s_tile: Optional[int] = None) -> str:
+                       s_tile: Optional[int] = None) -> str:
     """Where the generated operator lives between the m-tiles of ONE
-    apply, from the padded shapes alone — the single rule the
-    ``pallas_call`` sites, :func:`effective_plan`, the ``sketch.apply``
-    span and tune/cost.py all read:
+    apply, from the padded shapes alone, whichever the orientation (``m``
+    is the extent that is tiled: rows of a rowwise operand, columns of a
+    columnwise one) — the single rule the ``pallas_call`` sites,
+    :func:`effective_plan`, the ``sketch.apply`` span and tune/cost.py
+    all read:
 
     ``"per_tile"``  a single m-tile: nothing to reuse, each block is
-                    generated in the grid step that contracts it. Also
-                    the columnwise big-S case (the cell ``jlt_apply_cw``
-                    is what an HBM-resident variant would be judged on;
-                    ROADMAP Queue 1).
+                    generated in the grid step that contracts it.
     ``"vmem"``      S fits the scratch cap and the VMEM plan: generated
                     during the first m-tile sweep into VMEM scratch.
-    ``"hbm"``       rowwise, S too big for VMEM: generated once an apply
-                    into HBM by its own kernel, streamed by the
-                    contraction kernel (:func:`_planes_call`).
+    ``"hbm"``       S too big for VMEM: generated once an apply into HBM
+                    by its own kernel, streamed by the contraction
+                    kernel (:func:`_planes_call`).
 
     ``s_tile`` (default: s_dim) is the result tile's width under an
     s-tiled plan; the operator kept is always the whole (s_dim × n).
@@ -245,7 +244,7 @@ def operator_residency(s_dim: int, n: int, m: int, m_tile: int,
             and _vmem_estimate(m_tile, s_tile or s_dim, scratch_bytes)
             <= _VMEM_BUDGET_BYTES):
         return "vmem"
-    return "hbm" if rowwise else "per_tile"
+    return "hbm"
 
 
 def _resolve_block(dist_kind, s_dim, keys_ref, k, s_scr, row0=0, rows=None):
@@ -430,44 +429,54 @@ def _operator_planes(keys, scale, *, s_dim, dist_kind, precision,
     )(*operands)
 
 
-def _dot_planes(a, planes, precision):
-    """A_tile · S_blkᵀ against the stored planes: the products
-    :func:`_dot` issues, in its order, with the operator's split already
-    made — only the A tile is split here, on the VPU."""
-    dims = (((1,), (1,)), ((), ()))
+def _dot_planes(a, planes, precision, gen_side=1):
+    """The data tile ``a`` against the stored planes — A_tile · S_blkᵀ
+    (``gen_side`` 1: the operator is the rhs) or S_blk · A_blk (0: the
+    lhs): the products :func:`_dot` issues for that side, in its order,
+    with the operator's split already made — only the data tile is split
+    here, on the VPU."""
+    dims = (((1,), (1 if gen_side else 0,)), ((), ()))
+
+    def sides(x, s):
+        return (x, s) if gen_side else (s, x)
+
     if planes[0].dtype == jnp.float32:
         return jax.lax.dot_general(
-            a, planes[0], dims, precision=jax.lax.Precision.HIGHEST,
+            *sides(a, planes[0]), dims, precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
     if precision == "bf16":
-        return _bf16_dot(a, planes[0], dims)
+        return _bf16_dot(*sides(a, planes[0]), dims)
     a_hi = a.astype(jnp.bfloat16)
     a_lo = a - a_hi.astype(jnp.float32)
     if precision == "bf16gen2":
-        return _bf16_dot(a_hi, planes[0], dims) + _bf16_dot(
-            a_lo, planes[0], dims)
-    s_hi, s_lo = planes
-    return _bf16_dot(a_hi, s_hi, dims) + (
-        _bf16_dot(a_hi, s_lo, dims) + _bf16_dot(a_lo, s_hi, dims))
+        return _bf16_dot(*sides(a_hi, planes[0]), dims) + _bf16_dot(
+            *sides(a_lo, planes[0]), dims)
+    lhs, rhs = sides((a_hi, a_lo), planes)
+    return _bf16_dot(lhs[0], rhs[0], dims) + (
+        _bf16_dot(lhs[0], rhs[1], dims) + _bf16_dot(lhs[1], rhs[0], dims))
 
 
 def _kernel_planes(n_blocks, precision, n_planes, tile_scaled, epilogue,
-                   *refs):
+                   gen_side, *refs):
     """Contraction kernel of the "hbm" residency: out_tile += A_tile @
     S_blkᵀ with the (s_tile × k) plane tiles streamed in beside the
-    (m_tile × k) A tile (k: :func:`_plane_step_cols`). No generation, no
-    operator split, no iota inside the m × s × k loop. ``refs`` =
-    ([scale], a, *planes, *epilogue operands, out)."""
+    (m_tile × k) A tile (k: :func:`_plane_step_cols`), or — ``gen_side``
+    0, columnwise — out_tile += S_blk @ A_blk with the (s_dim × k) plane
+    tiles beside the (k × m_tile) A tile. No generation, no operator
+    split, no iota inside the m × s × k loop; k is the last grid axis.
+    ``refs`` = ([scale], a, *planes, *epilogue operands, out)."""
     refs = list(refs)
     scale_ref = refs.pop(0) if tile_scaled else None
     a_ref, plane_refs = refs[0], refs[1:1 + n_planes]
     *operand_refs, out_ref = refs[1 + n_planes:]
-    acc = _dot_planes(a_ref[:], [p[:] for p in plane_refs], precision)
-    _store(out_ref, acc, pl.program_id(2), n_blocks,
+    acc = _dot_planes(a_ref[:], [p[:] for p in plane_refs], precision,
+                      gen_side)
+    _store(out_ref, acc, pl.program_id(2 if gen_side else 1), n_blocks,
            _finisher(scale_ref, epilogue, operand_refs))
 
 
-def _plane_step_cols(n: int, m_tile: int, s_tile: int) -> int:
+def _plane_step_cols(n: int, m_tile: int, s_tile: int,
+                     lhs_f32: bool = False) -> int:
     """Columns of A and of the planes one contraction step takes: two
     BLOCK_COLS blocks where n divides and the plan fits, else one. The
     wider step halves the grid steps and the out tile's
@@ -482,52 +491,68 @@ def _plane_step_cols(n: int, m_tile: int, s_tile: int) -> int:
     bytes at "bf16x3" — A tile double-buffered plus its hi/lo split, out
     tile double-buffered plus the matmul result, plane tiles
     double-buffered plus what is loaded of them; "f32" needs up to
-    8·m_tile·k more, hence the 5."""
-    wide = 2 * BLOCK_COLS
-    plan = 4 * (5 * m_tile * wide + 3 * m_tile * s_tile) + 10 * s_tile * wide
-    if n % wide == 0 and plan <= _VMEM_BUDGET_BYTES:
-        return wide
-    return BLOCK_COLS
+    8·m_tile·k more, hence the 5. A columnwise step holds the same three
+    tiles transposed (A k × m_tile, out s_dim × m_tile, planes
+    s_dim × k) and asks for less (14.0 MiB of the 16.0 planned at
+    512 × 1024 × 512, "bf16x3") — but for ``lhs_f32``: the "f32" regime's
+    plane tile is there the LEFT operand of the HIGHEST contraction,
+    which Mosaic splits whole, 20 B an entry and not 10 (sixteen shapes,
+    PR 36). That regime alone steps down to half a block where one does
+    not fit (s_dim 1536 at m_tile 512 asks 18.5 MiB at 256 columns)."""
+    per_plane_entry = 20 if lhs_f32 else 10
+    for cols in (2 * BLOCK_COLS, BLOCK_COLS):
+        plan = (4 * (5 * m_tile * cols + 3 * m_tile * s_tile)
+                + per_plane_entry * s_tile * cols)
+        if n % cols == 0 and plan <= _VMEM_BUDGET_BYTES:
+            return cols
+    return BLOCK_COLS // 2 if lhs_f32 else BLOCK_COLS
 
 
 def _planes_call(A, keys, scale, extra_operands, *, s_dim, s_tile, dist_kind,
-                 m_tile, precision, interpret, epilogue):
-    """The "hbm" residency: two ``pallas_call``s in one executable. With
-    no scratch the m and s axes stay "parallel"."""
-    m, n = A.shape
-    k_cols = _plane_step_cols(n, m_tile, s_tile)
+                 m_tile, precision, interpret, epilogue, rowwise=True):
+    """The "hbm" residency: two ``pallas_call``s in one executable — the
+    planes of S (s_dim × n), then ``scale``·A·Sᵀ of the padded A (m, n)
+    over the grid (row tile, s-tile, k) or, ``rowwise`` False,
+    ``scale``·S·A of A (n, m) over (column tile, k), the result tile all
+    s_dim rows. With no scratch every axis but k stays "parallel"."""
+    m, n = A.shape if rowwise else A.shape[::-1]
+    k_cols = _plane_step_cols(
+        n, m_tile, s_tile,
+        not rowwise and _plane_dtypes(precision)[0] == jnp.float32)
     n_blocks = n // k_cols
     planes = _operator_planes(keys, scale, s_dim=s_dim, dist_kind=dist_kind,
                               precision=precision, s_tile=s_tile,
                               interpret=interpret)
     tile_scaled = _tile_scaled(precision, scale)
+    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    if rowwise:
+        grid = (m // m_tile, s_dim // s_tile, n_blocks)
+        a_spec = vmem((m_tile, k_cols), lambda i, j, k: (i, k))
+        plane_spec = vmem((s_tile, k_cols), lambda i, j, k: (j, k))
+        out_spec = vmem((m_tile, s_tile), lambda i, j, k: (i, j))
+        out_shape = (m, s_dim)
+    else:
+        grid = (m // m_tile, n_blocks)
+        a_spec = vmem((k_cols, m_tile), lambda j, k: (k, j))
+        plane_spec = vmem((s_dim, k_cols), lambda j, k: (0, k))
+        out_spec = vmem((s_dim, m_tile), lambda j, k: (0, j))
+        out_shape = (s_dim, m)
     operands, in_specs = [], []
     if tile_scaled:
         operands.append(jnp.asarray(scale, jnp.float32).reshape(1))
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
     operands += [A, *planes, *extra_operands]
-    in_specs += [
-        pl.BlockSpec((m_tile, k_cols), lambda i, j, k: (i, k),
-                     memory_space=pltpu.VMEM),
-    ] + [
-        pl.BlockSpec((s_tile, k_cols), lambda i, j, k: (j, k),
-                     memory_space=pltpu.VMEM)
-        for _ in planes
-    ] + [
-        pl.BlockSpec((1, s_tile), lambda i, j, k: (0, j),
-                     memory_space=pltpu.VMEM)
-        for _ in extra_operands
-    ]
+    in_specs += [a_spec] + [plane_spec for _ in planes] + [
+        vmem((1, s_tile), lambda i, j, k: (0, j)) for _ in extra_operands]
     return pl.pallas_call(
         functools.partial(_kernel_planes, n_blocks, precision, len(planes),
-                          tile_scaled, epilogue),
-        grid=(m // m_tile, s_dim // s_tile, n_blocks),
+                          tile_scaled, epilogue, int(rowwise)),
+        grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (m_tile, s_tile), lambda i, j, k: (i, j), memory_space=pltpu.VMEM
-        ),
-        out_shape=jax.ShapeDtypeStruct((m, s_dim), jnp.float32),
-        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
+        out_specs=out_spec,
+        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
+        compiler_params=compiler_params(*["parallel"] * (len(grid) - 1),
+                                        "arbitrary"),
         interpret=interpret,
     )(*operands)
 
@@ -551,8 +576,7 @@ def _rowwise_pallas_call(A, keys, scale, extra_operands, *, s_dim, dist_kind,
     A = _padded(A, seq_axis=1, mt=m_tile)
     m, n = A.shape
     n_blocks = n // BLOCK_COLS
-    residency = operator_residency(s_dim, n, m, m_tile, rowwise=True,
-                                   s_tile=s_tile)
+    residency = operator_residency(s_dim, n, m, m_tile, s_tile)
     if residency == "hbm":
         out = _planes_call(A, keys, scale, extra_operands, s_dim=s_dim,
                            s_tile=s_tile, dist_kind=dist_kind, m_tile=m_tile,
@@ -630,29 +654,47 @@ def _fused_call_cos(A, keys, sc, sh, *, s_dim, dist_kind, m_tile,
     static_argnames=("s_dim", "dist_kind", "m_tile", "precision",
                      "interpret"),
 )
-def _fused_call_cw(A, keys, *, s_dim, dist_kind, m_tile, precision="f32",
-                   interpret=False):
+def _fused_call_cw(A, keys, scale=None, *, s_dim, dist_kind, m_tile,
+                   precision="f32", interpret=False):
+    """``scale``·S·A (``scale`` None: unscaled) of the UNPADDED A (n, m),
+    one executable — the columnwise twin of :func:`_rowwise_pallas_call`:
+    the block-key table, the zero padding and the slice back where the
+    operand is ragged (:func:`_padded`: exact), and the kernel(s) of the
+    operand's :func:`operator_residency`. "hbm": :func:`_planes_call`,
+    the scale folded into the planes. "vmem" / "per_tile": one call that
+    generates in the kernel, the scale a pass over its result. The result
+    tile is always all s_dim rows (no s-tile columnwise)."""
+    cols = A.shape[1]
+    A = _padded(A, seq_axis=0, mt=m_tile)
     n, m = A.shape
-    n_blocks = n // BLOCK_COLS
-    residency = operator_residency(s_dim, n, m, m_tile, rowwise=False)
-    return pl.pallas_call(
-        functools.partial(_kernel_cw, dist_kind, s_dim, m_tile, precision),
-        grid=(m // m_tile, n_blocks),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(
-                (BLOCK_COLS, m_tile), lambda j, k: (k, j),
-                memory_space=pltpu.VMEM,
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (s_dim, m_tile), lambda j, k: (0, j), memory_space=pltpu.VMEM
-        ),
-        out_shape=jax.ShapeDtypeStruct((s_dim, m), jnp.float32),
-        scratch_shapes=_operator_scratch(residency, s_dim, n),
-        compiler_params=_grid_params(residency),
-        interpret=interpret,
-    )(_block_table(keys, n), A)
+    keys = _block_table(keys, n)
+    residency = operator_residency(s_dim, n, m, m_tile)
+    if residency == "hbm":
+        out = _planes_call(A, keys, scale, (), s_dim=s_dim, s_tile=s_dim,
+                           dist_kind=dist_kind, m_tile=m_tile,
+                           precision=precision, interpret=interpret,
+                           epilogue=None, rowwise=False)
+        scale = None        # applied: in the planes, or to each tile
+    else:
+        out = pl.pallas_call(
+            functools.partial(_kernel_cw, dist_kind, s_dim, m_tile,
+                              precision),
+            grid=(m // m_tile, n // BLOCK_COLS),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec((BLOCK_COLS, m_tile), lambda j, k: (k, j),
+                             memory_space=pltpu.VMEM),
+            ],
+            out_specs=pl.BlockSpec((s_dim, m_tile), lambda j, k: (0, j),
+                                   memory_space=pltpu.VMEM),
+            out_shape=jax.ShapeDtypeStruct((s_dim, m), jnp.float32),
+            scratch_shapes=_operator_scratch(residency, s_dim, n),
+            compiler_params=_grid_params(residency),
+            interpret=interpret,
+        )(keys, A)
+    if m != cols:
+        out = out[:, :cols]
+    return out if scale is None else scale * out
 
 
 _DIST_KINDS = {
@@ -821,8 +863,7 @@ def _plan(dist, A, s_dim: int, seq_axis: int, m_tile, precision,
         return None
     mt, st = tiles
     n_p, m_p = _padded_extents(A.shape[seq_axis], A.shape[1 - seq_axis], mt)
-    residency = operator_residency(s_dim, n_p, m_p, mt,
-                                   rowwise=seq_axis == 1, s_tile=st)
+    residency = operator_residency(s_dim, n_p, m_p, mt, st)
     note_apply(path="pallas", m_tile=mt, s_tile=st, precision=precision,
                plan_source=source, operator_residency=residency)
     return Plan(mt, st, precision, residency, interpret)
@@ -920,22 +961,22 @@ def columnwise_apply(
     precision: str | None = None,
     interpret: bool = False,
 ) -> Optional[jnp.ndarray]:
-    """out = scale · S @ A for A (N, m); same fused generation, transposed
-    contraction."""
+    """out = scale · S @ A for A (N, m): the same kernels as
+    :func:`rowwise_apply`, the contraction transposed — under the "hbm"
+    residency S is generated once an apply, not once a column tile.
+    Returns None when the kernel declines (caller takes the XLA path)."""
     plan = _plan(dist, A, s_dim, 0, m_tile, precision, interpret)
     if plan is None:
         return None
-    mt, precision = plan.m_tile, plan.precision
-    m = A.shape[1]
     kd = _key_data(key)
     with _trace.span("sketch.dispatch") as sp:
-        Ap = _padded(A, seq_axis=0, mt=mt)
         if sp is not None:
-            sp.set_attr("padded", Ap is not A)
-        out = _fused_call_cw(Ap, kd, s_dim=s_dim,
-                             dist_kind=_DIST_KINDS[type(dist)], m_tile=mt,
-                             precision=precision, interpret=interpret)
-        return scale * out[:, :m]
+            sp.set_attr("padded", _is_padded(A, 0, plan.m_tile))
+        # one executable, as rowwise: table, padding, kernel(s), scale
+        return _fused_call_cw(A, kd, scale, s_dim=s_dim,
+                              dist_kind=_DIST_KINDS[type(dist)],
+                              m_tile=plan.m_tile, precision=plan.precision,
+                              interpret=interpret)
 
 
 def rft_rowwise_apply(
@@ -1018,14 +1059,12 @@ def fused_partial(
     plan = _plan(dist, A_loc, s_dim, seq_axis, m_tile, precision, interpret)
     if plan is None:
         return None
-    m = A_loc.shape[1 - seq_axis]
     kw = dict(s_dim=s_dim, dist_kind=_DIST_KINDS[type(dist)],
               m_tile=plan.m_tile, precision=plan.precision,
               interpret=interpret)
     if seq_axis == 1:
         return _fused_call(A_loc, keys, None, s_tile=plan.s_tile, **kw)
-    Ap = _padded(A_loc, seq_axis=seq_axis, mt=plan.m_tile)
-    return _fused_call_cw(Ap, keys, **kw)[:, :m]
+    return _fused_call_cw(A_loc, keys, None, **kw)
 
 
 def effective_plan(dist, shape, dtype, s_dim: int, seq_axis: int,
@@ -1057,8 +1096,7 @@ def effective_plan(dist, shape, dtype, s_dim: int, seq_axis: int,
     mt, st = tiles
     # the same padding/residency helpers the pallas_call sites use
     n_p, m_p = _padded_extents(shape[seq_axis], shape[1 - seq_axis], mt)
-    residency = operator_residency(s_dim, n_p, m_p, mt,
-                                   rowwise=seq_axis == 1, s_tile=st)
+    residency = operator_residency(s_dim, n_p, m_p, mt, st)
     tile_id = f"mt{mt}" if st == s_dim else f"mt{mt}/st{st}"
     return {"kernel": True, "m_tile": mt, "s_tile": st,
             "operator_residency": residency,

@@ -129,19 +129,23 @@ def set_pallas_precision(p: str) -> None:
     _pallas_precision = p
 
 
-# ``pallas_m_tile`` — rows of A per fused-kernel grid step. What a larger
-# tile buys depends on where the operator lives between m-tiles
-# (pallas_dense.operator_residency). Measured on a v5e at 65536 × 8192
-# → 1024, bf16x3, ms a blocking apply (PERF.md §6, PR 27):
-#   "per_tile" (every m-tile sweep regenerates the whole virtual operator
+# ``pallas_m_tile`` — rows (columnwise: columns) of A per fused-kernel
+# grid step. What a larger tile buys depends on where the operator lives
+# between m-tiles (pallas_dense.operator_residency). Measured on a v5e at
+# 65536 × 8192 → 1024, bf16x3, ms a blocking apply (PERF.md §6, PR 27):
+#   regenerated in every m-tile sweep (no residency does that to a big
+#   S any more — "per_tile" is a single tile: the whole virtual operator
 #   on the VPU, Threefry + inverse-CDF at ≈ 46 G entries/s, and a step
 #   costs generation PLUS matmul): 43.3 / 31.7 / 26.1 / 22.7 at m_tile
 #   512 / 1024 / 2048 / 4096, i.e. device time 17.2 + 23.2 × 512/m_tile;
-#   "hbm" (rowwise big S: generated once an apply in 0.42 ms, the tile
-#   only sets how often the planes are read back and how many grid steps
-#   run, 0.37 µs each): 24.8 / 23.1 / 22.9 / 22.0 at 256 / 512 / 1024 /
-#   2048, and 22.1 / 21.4 / 21.2 at 512 / 1024 / 2048 with the two-block
-#   k step the kernel now takes (pallas_dense._plane_step_cols).
+#   "hbm" (big S: generated once an apply in 0.42 ms, the tile only sets
+#   how often the planes are read back and how many grid steps run,
+#   0.37 µs each): 24.8 / 23.1 / 22.9 / 22.0 at 256 / 512 / 1024 / 2048,
+#   and 22.1 / 21.4 / 21.2 at 512 / 1024 / 2048 with the two-block k
+#   step the kernel now takes (pallas_dense._plane_step_cols).
+#   Columnwise, 8192 × 65536 → 1024 × 65536 (PR 36): 42.7 regenerated,
+#   20.2 under "hbm" at 512 columns a tile and a step, 21.4 at 256 × 512
+#   or 512 × 256, 23.0 at 256 × 256.
 # 512 is the largest power of two whose plan fits Mosaic's 16 MiB default
 # scoped VMEM at s_dim = 1024 (_vmem_estimate plans 11 MiB, Mosaic needs
 # 9.4–9.9); 1024 needs 17.0 MiB and Mosaic refused it on the chip. A v5e

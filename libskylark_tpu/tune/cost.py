@@ -270,8 +270,7 @@ def _dense_operator_residency(w: Workload, m_tile: int) -> str:
 
     m, n, s = w.shape
     n_p, m_p = _padded_extents(n, m, m_tile)
-    return operator_residency(s, n_p, m_p, m_tile,
-                              rowwise=w.op != "dense_columnwise")
+    return operator_residency(s, n_p, m_p, m_tile)
 
 
 def _dense_grid_steps(w: Workload, m_tile: int) -> int:

@@ -21,6 +21,7 @@ An on-chip variant runs when the default backend is a real TPU
 """
 
 import functools
+import inspect
 
 import jax
 import jax.numpy as jnp
@@ -368,15 +369,15 @@ def test_effective_plan_reports_actual_config():
                           seq_axis=1, m_tile=100, interpret=True)
     assert p["m_tile"] == 64
 
-    # every grid step regenerates its block ("per_tile") in the
-    # columnwise big-operator regime and for a single m-tile — not at the
-    # rowwise headline shape, whose operator is resident in HBM
+    # every grid step regenerates its block ("per_tile") only for a
+    # single m-tile — not at the headline shape, whose operator is
+    # resident in HBM in either orientation
     p = pd.effective_plan(dist, (8192, 8192), jnp.float32, 1024,
                           seq_axis=1, m_tile=512, interpret=True)
     assert p["operator_residency"] == "hbm"
     p = pd.effective_plan(dist, (8192, 8192), jnp.float32, 1024,
                           seq_axis=0, m_tile=512, interpret=True)
-    assert p["operator_residency"] == "per_tile"
+    assert p["operator_residency"] == "hbm"
     assert p["operator_cache"] is False
     p = pd.effective_plan(dist, (512, 8192), jnp.float32, 1024,
                           seq_axis=1, m_tile=512, interpret=True)
@@ -519,7 +520,7 @@ _DISTS = {"normal": randgen.Normal(), "cauchy": randgen.Cauchy(),
 @pytest.fixture
 def force_hbm(monkeypatch):
     """At test sizes S fits the VMEM operator cache; a zero cap sends every
-    rowwise apply with more than one m-tile to the "hbm" residency."""
+    apply with more than one m-tile to the "hbm" residency."""
     monkeypatch.setattr(pd, "_SCRATCH_CAP_BYTES", 0)
 
 
@@ -530,9 +531,9 @@ def _panel64(key, dist, s, n):
     return np.asarray(unit, np.float64)[:, :n]
 
 
-def _assert_hbm(dist, shape, s, m_tile):
-    plan = pd.effective_plan(dist, shape, jnp.float32, s, 1, m_tile=m_tile,
-                             interpret=True)
+def _assert_hbm(dist, shape, s, m_tile, seq_axis=1):
+    plan = pd.effective_plan(dist, shape, jnp.float32, s, seq_axis,
+                             m_tile=m_tile, interpret=True)
     assert plan["operator_residency"] == "hbm", plan
     assert plan["operator_cache"] is False
 
@@ -599,29 +600,109 @@ def test_hbm_planes_hold_the_scaled_stream(kind, scale):
         np.asarray(unit.astype(jnp.bfloat16).astype(jnp.float32)))
 
 
+@pytest.mark.parametrize("seq_axis", [1, 0], ids=["rowwise", "columnwise"])
 @pytest.mark.parametrize("precision", ["bf16x3", "f32", "bf16", "bf16gen2"])
-def test_hbm_equals_per_tile_at_dyadic_scale(precision, monkeypatch,
+def test_hbm_equals_per_tile_at_dyadic_scale(precision, seq_axis, monkeypatch,
                                              force_hbm):
     """Same input, same tile, the operator resident in HBM against
     regenerated per tile: with a power-of-two scale the scaled planes are
     the unit planes shifted, every product and sum scales exactly, and
-    the two agree to float32 rounding of the accumulation."""
-    m, n, s = 64, 768, 64
+    the two agree to float32 rounding of the accumulation. Columnwise the
+    tile is 128 columns (a lane's width), four of them."""
+    m, n, s = (64, 768, 64) if seq_axis else (512, 768, 64)
+    m_tile = 16 if seq_axis else 128
     scale = 2.0 ** -3                       # = 1/√64
     dist = randgen.Normal()
     key = Context(seed=33).allocate().key
     A = jnp.asarray(
         np.random.default_rng(12).standard_normal((m, n)), jnp.float32)
-    _assert_hbm(dist, (m, n), s, 16)
-    kw = dict(m_tile=16, precision=precision, interpret=True)
-    resident = np.asarray(pd.rowwise_apply(key, dist, A, s, scale, **kw))
+    if not seq_axis:
+        A = A.T
+    apply = pd.rowwise_apply if seq_axis else pd.columnwise_apply
+    _assert_hbm(dist, A.shape, s, m_tile, seq_axis)
+    kw = dict(m_tile=m_tile, precision=precision, interpret=True)
+    resident = np.asarray(apply(key, dist, A, s, scale, **kw))
     monkeypatch.setattr(pd, "operator_residency",
                         lambda *a, **k: "per_tile")
     jax.clear_caches()      # the residency is resolved when the call traces
-    per_tile = np.asarray(pd.rowwise_apply(key, dist, A, s, scale, **kw))
+    per_tile = np.asarray(apply(key, dist, A, s, scale, **kw))
     jax.clear_caches()
     np.testing.assert_allclose(resident, per_tile, rtol=2e-6,
                                atol=2e-6 * float(np.abs(per_tile).max()))
+
+
+@pytest.mark.parametrize("precision", ["bf16x3", "f32"])
+@pytest.mark.parametrize("kind", sorted(_DISTS))
+def test_hbm_columnwise_matches_oracle(kind, precision, force_hbm):
+    """S·A by the generation call and the columnwise contraction call
+    against a float64 gemm with the stream's own operator, the scale (no
+    power of two) folded into the planes, at the framework's 1e-4."""
+    n, m, s, dist = 768, 48, 96, _DISTS[kind]
+    scale = 1.0 / np.sqrt(s)
+    key = Context(seed=36).allocate().key
+    A = jnp.asarray(
+        np.random.default_rng(15).standard_normal((n, m)), jnp.float32)
+    _assert_hbm(dist, (n, m), s, 16, seq_axis=0)
+    got = pd.columnwise_apply(key, dist, A, s, scale, m_tile=16,
+                              precision=precision, interpret=True)
+    assert got is not None and got.shape == (s, m)
+    want = (scale * _panel64(key, dist, s, n)) @ np.asarray(A, np.float64)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4,
+                               atol=1e-4 * float(np.abs(want).max()))
+
+
+def test_hbm_columnwise_f32_steps_down_to_half_a_block(force_hbm,
+                                                       monkeypatch):
+    """The contraction step's ladder (:func:`_plane_step_cols`): two
+    blocks where the plan fits, else one; the columnwise "f32" regime,
+    whose plane tile is the split left operand, counts it double and
+    alone goes down to 128 columns — the same S·A to the rounding of the
+    accumulation."""
+    assert pd._plane_step_cols(8192, 512, 1024) == 2 * BLOCK_COLS
+    assert pd._plane_step_cols(8192, 512, 1536) == BLOCK_COLS
+    assert pd._plane_step_cols(768, 512, 1024) == BLOCK_COLS
+    assert pd._plane_step_cols(8192, 256, 1024, True) == 2 * BLOCK_COLS
+    assert pd._plane_step_cols(8192, 512, 1024, True) == BLOCK_COLS
+    assert pd._plane_step_cols(8192, 512, 1536, True) == BLOCK_COLS // 2
+    n, m, s = 512, 32, 64
+    dist = randgen.Normal()
+    key = Context(seed=39).allocate().key
+    A = jnp.asarray(
+        np.random.default_rng(18).standard_normal((n, m)), jnp.float32)
+    kw = dict(m_tile=8, precision="f32", interpret=True)
+    whole = np.asarray(pd.columnwise_apply(key, dist, A, s, 0.125, **kw))
+    monkeypatch.setattr(pd, "_plane_step_cols", lambda *a: BLOCK_COLS // 2)
+    jax.clear_caches()
+    stepped = np.asarray(pd.columnwise_apply(key, dist, A, s, 0.125, **kw))
+    jax.clear_caches()
+    np.testing.assert_allclose(stepped, whole, rtol=2e-6,
+                               atol=2e-6 * float(np.abs(whole).max()))
+
+
+@pytest.mark.parametrize("precision", ["bf16x3", "f32", "bf16gen2"])
+def test_hbm_columnwise_ragged_equals_rowwise_of_transpose(precision,
+                                                           force_hbm):
+    """A ragged columnwise operand (n no multiple of 256, m no multiple
+    of the tile) is padded and sliced inside the one program, and S·A is
+    (Aᵀ·Sᵀ)ᵀ of the rowwise kernels: the same planes, the same products,
+    the same k steps — equal to the rounding of the accumulation. A
+    second apply is bit-equal to the first (nothing is kept across
+    applies, every apply regenerates the same planes)."""
+    n, m, s = 700, 52, 96
+    jlt = JLT(n, s, Context(seed=37))
+    A = jnp.asarray(
+        np.random.default_rng(16).standard_normal((n, m)), jnp.float32)
+    _assert_hbm(jlt.dist, (n, m), s, 8, seq_axis=0)
+    kw = dict(m_tile=8, precision=precision, interpret=True)
+    key = jlt._alloc.key
+    got = pd.columnwise_apply(key, jlt.dist, A, s, jlt.scale, **kw)
+    assert got is not None and got.shape == (s, m)
+    want = np.asarray(pd.rowwise_apply(key, jlt.dist, A.T, s, jlt.scale,
+                                       **kw)).T
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-6,
+                               atol=2e-6 * float(np.abs(want).max()))
+    again = pd.columnwise_apply(key, jlt.dist, A, s, jlt.scale, **kw)
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(got))
 
 
 @pytest.mark.parametrize("shape", [(24, 512), (13, 300)],
@@ -672,6 +753,37 @@ def test_hbm_fused_partial_rowwise(precision, force_hbm):
                                atol=1e-4 * float(np.abs(want).max()))
 
 
+@pytest.mark.parametrize("precision", ["bf16x3", "f32"])
+def test_hbm_fused_partial_columnwise(precision, force_hbm):
+    """``fused_partial(seq_axis=0)`` under "hbm": each device's shard
+    against the blocks its slice of the key TABLE names (the table, not
+    the key words, reaches the generation call), UNSCALED, in one
+    dispatch (a ragged column count padded and sliced inside it); the two
+    shards' partials sum to the whole S·A, as the caller's psum does."""
+    n, m, s = 1024, 44, 32
+    dist = randgen.Normal()
+    key = Context(seed=38).allocate().key
+    keys = pd._block_keys(key, n)
+    A = jnp.asarray(
+        np.random.default_rng(17).standard_normal((n, m)), jnp.float32)
+    half = n // 2
+    _assert_hbm(dist, (half, m), s, 8, seq_axis=0)
+    parts = [pd.fused_partial(keys[sl.start // BLOCK_COLS:
+                                   sl.stop // BLOCK_COLS], dist, A[sl], s,
+                              seq_axis=0, m_tile=8, precision=precision,
+                              interpret=True)
+             for sl in (slice(0, half), slice(half, n))]
+    assert all(p is not None and p.shape == (s, m) for p in parts)
+    S = _panel64(key, dist, s, n)
+    want = S[:, half:] @ np.asarray(A, np.float64)[half:]
+    np.testing.assert_allclose(np.asarray(parts[1]), want, rtol=1e-4,
+                               atol=1e-4 * float(np.abs(want).max()))
+    whole = S @ np.asarray(A, np.float64)
+    np.testing.assert_allclose(np.asarray(parts[0] + parts[1]), whole,
+                               rtol=1e-4,
+                               atol=1e-4 * float(np.abs(whole).max()))
+
+
 @pytest.mark.parametrize("shape", [(32, 2048), (21, 700)],
                          ids=["aligned", "ragged"])
 def test_hbm_bf16gen2_matches_rounded_operator_oracle(shape, force_hbm):
@@ -696,15 +808,19 @@ def test_hbm_bf16gen2_matches_rounded_operator_oracle(shape, force_hbm):
 
 
 @pytest.mark.parametrize(
-    "s_dim,n,m,m_tile,rowwise,want",
-    [(1024, 8192, 65536, 512, True, "hbm"),       # the cell's shape
-     (1024, 8192, 65536, 512, False, "per_tile"),  # columnwise: as before
-     (1024, 8192, 512, 512, True, "per_tile"),     # one m-tile: no reuse
-     (128, 1024, 1024, 256, True, "vmem"),         # small S: VMEM cache
-     (128, 1024, 1024, 256, False, "vmem")],
-    ids=["cell", "columnwise", "single_tile", "small_rw", "small_cw"])
-def test_operator_residency_rule(s_dim, n, m, m_tile, rowwise, want):
-    assert pd.operator_residency(s_dim, n, m, m_tile, rowwise) == want
+    "s_dim,n,m,m_tile,want",
+    [(1024, 8192, 65536, 512, "hbm"),       # both dense cells' shape
+     (1024, 8192, 1024, 512, "hbm"),        # from the second m-tile on
+     (1024, 8192, 512, 512, "per_tile"),    # one m-tile: no reuse
+     (128, 1024, 1024, 256, "vmem"),        # small S: VMEM cache
+     (128, 1024, 256, 256, "per_tile")],
+    ids=["cell", "two_tiles", "single_tile", "small", "small_single_tile"])
+def test_operator_residency_rule(s_dim, n, m, m_tile, want):
+    """One rule for both orientations: ``m`` is the tiled extent (rows of
+    a rowwise operand, columns of a columnwise one)."""
+    assert pd.operator_residency(s_dim, n, m, m_tile) == want
+    assert "rowwise" not in inspect.signature(
+        pd.operator_residency).parameters
 
 
 @pytest.mark.tpu
@@ -727,4 +843,27 @@ def test_fused_on_chip_hbm_residency_at_the_cell_shape():
     np.testing.assert_allclose(np.asarray(first[rows]), want, rtol=1e-4,
                                atol=1e-4 * float(np.abs(want).max()))
     second = pd.rowwise_apply(jlt._alloc.key, jlt.dist, A, s, jlt.scale)
+    assert bool(jnp.array_equal(first, second))
+
+
+@pytest.mark.tpu
+@pytest.mark.skipif(not ON_TPU, reason="needs a real TPU backend")
+def test_fused_on_chip_hbm_residency_at_the_columnwise_cell_shape():
+    """The jlt_apply_cw cell's shape, Mosaic-compiled: 8192 × 65536 →
+    1024 × 65536 at the shipping regime takes the "hbm" residency
+    columnwise too; 256 sampled columns against S·A[:, idx] at 1e-4; a
+    second apply is bit-equal to the first."""
+    n, m, s = 8192, 65536, 1024
+    jlt = JLT(n, s, Context(seed=28))
+    A = jax.random.normal(jax.random.key(28), (n, m), jnp.float32)
+    plan = pd.effective_plan(jlt.dist, A.shape, A.dtype, s, 0)
+    assert plan["operator_residency"] == "hbm", plan
+    first = pd.columnwise_apply(jlt._alloc.key, jlt.dist, A, s, jlt.scale)
+    assert first is not None and first.shape == (s, m)
+    cols = np.sort(np.random.default_rng(28).choice(m, 256, replace=False))
+    S = jlt.scale * _panel64(jlt._alloc.key, jlt.dist, s, n)
+    want = S @ np.asarray(A[:, cols], np.float64)
+    np.testing.assert_allclose(np.asarray(first[:, cols]), want, rtol=1e-4,
+                               atol=1e-4 * float(np.abs(want).max()))
+    second = pd.columnwise_apply(jlt._alloc.key, jlt.dist, A, s, jlt.scale)
     assert bool(jnp.array_equal(first, second))

@@ -135,7 +135,7 @@ class TestOperatorResidencyOnePredicate:
         [((65536, 8192), 1024, 512, 1, "hbm"),       # the benchmark cell
          ((1024, 1024), 128, 256, 1, "vmem"),        # small S: VMEM cache
          ((512, 8192), 1024, 512, 1, "per_tile"),    # one m-tile
-         ((8192, 65536), 1024, 512, 0, "per_tile")],  # columnwise big S
+         ((8192, 65536), 1024, 512, 0, "hbm")],      # columnwise big S
         ids=["headline_hbm", "small_vmem", "single_tile", "columnwise"])
     def test_cost_plan_and_kernel_agree(self, shape, s, m_tile, seq_axis,
                                         want):
@@ -143,8 +143,7 @@ class TestOperatorResidencyOnePredicate:
 
         n, m = shape[seq_axis], shape[1 - seq_axis]
         m_tiles = m // m_tile
-        assert pd.operator_residency(s, n, m, m_tile,
-                                     rowwise=seq_axis == 1) == want
+        assert pd.operator_residency(s, n, m, m_tile) == want
         # reader 1: the reported plan
         plan = pd.effective_plan(randgen.Normal(), shape, jnp.float32, s,
                                  seq_axis, m_tile=m_tile, interpret=True)

@@ -42,11 +42,11 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(call, one_chip, shape, s_dim, seq_axis, precision, *operands,
-             **statics):
+def _executable(call, one_chip, shape, s_dim, seq_axis, precision, *operands,
+                **statics):
     """Lower and compile ``call`` on the operand the dispatch would hand
     it (the tile :func:`effective_plan` resolves); returns the plan and
-    the number of Mosaic custom calls in the executable."""
+    the compiled executable."""
     plan = pd.effective_plan(randgen.Normal(), shape, jnp.float32, s_dim,
                              seq_axis, precision=precision, interpret=True,
                              m_tile=statics.pop("m_tile", None))
@@ -61,6 +61,13 @@ def _compile(call, one_chip, shape, s_dim, seq_axis, precision, *operands,
         *[arg(*o) for o in operands],
         s_dim=s_dim, dist_kind="normal", m_tile=plan["m_tile"],
         precision=precision, **statics).compile()
+    return plan, compiled
+
+
+def _compile(*args, **statics):
+    """:func:`_executable`, reduced to the plan and the number of Mosaic
+    custom calls in the executable."""
+    plan, compiled = _executable(*args, **statics)
     return plan, compiled.as_text().count(KERNEL)
 
 
@@ -71,6 +78,40 @@ def test_cell_shape_rowwise_hbm(one_chip, precision):
                              precision, ((), jnp.float32))
     assert plan["operator_residency"] == "hbm" and plan["m_tile"] == 512
     assert kernels == 2
+
+
+@pytest.mark.parametrize("precision", ["bf16x3", "f32", "bf16gen2", "bf16"])
+def test_cell_shape_columnwise_hbm(one_chip, precision):
+    """The jlt_apply_cw cell, 8192 × 65536 → 1024 × 65536: the same
+    generation call and the columnwise contraction call, the scale folded
+    into the planes — no pass over the 256 MiB result outside them, and
+    no temporary but the planes (2 × 16 MiB at "bf16x3")."""
+    plan, compiled = _executable(pd._fused_call_cw, one_chip, (N, ROWS), 1024,
+                                 0, precision, ((), jnp.float32))
+    assert plan["operator_residency"] == "hbm" and plan["m_tile"] == 512
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 2
+    assert not re.search(r"f32\[1024,65536\]\S* multiply\(", text)
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == 1024 * ROWS * 4
+    assert memory.temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("s_dim,m_tile,precision,k_cols", [
+    (512, 1024, "bf16x3", 256), (2048, None, "bf16x3", 256),
+    (1536, None, "bf16x3", 256), (1024, 256, "bf16x3", 512),
+    (1536, None, "f32", 128), (2048, None, "f32", 128)])
+def test_other_widths_columnwise_hbm(one_chip, s_dim, m_tile, precision,
+                                     k_cols):
+    """Sketch sizes around the headline one, columnwise, at the tile and
+    the k step the plan lets through: the "f32" regime's plane tile is
+    the split left operand there, and past s_dim 1024 only a half-block
+    step fits Mosaic's scope (18.5 MiB at 1536 × 512 × 256)."""
+    plan, kernels = _compile(pd._fused_call_cw, one_chip, (N, ROWS), s_dim, 0,
+                             precision, ((), jnp.float32), m_tile=m_tile)
+    assert plan["operator_residency"] == "hbm" and kernels == 2
+    assert pd._plane_step_cols(N, plan["m_tile"], s_dim,
+                               precision == "f32") == k_cols
 
 
 @pytest.mark.parametrize("s_dim,m_tile", [(512, 1024), (2048, None),
@@ -173,16 +214,15 @@ def test_feature_cell_whole_program(one_chip, kernel_route):
 
 
 @pytest.mark.parametrize("shape,seq_axis,call,residency", [
-    ((N, ROWS), 0, "_fused_call_cw", "per_tile"),     # columnwise big S
+    ((N, 512), 0, "_fused_call_cw", "per_tile"),      # one column tile
     ((512, N), 1, "_fused_call", "per_tile"),         # one m-tile
     ((4096, 1024), 1, "_fused_call", "vmem"),         # small S
 ])
 def test_generating_kernels_still_compile(one_chip, shape, seq_axis, call,
                                           residency):
     s_dim = 1024 if residency == "per_tile" else 128
-    operands = (((), jnp.float32),) if call == "_fused_call" else ()
     plan, kernels = _compile(getattr(pd, call), one_chip, shape, s_dim,
-                             seq_axis, "bf16x3", *operands)
+                             seq_axis, "bf16x3", ((), jnp.float32))
     assert plan["operator_residency"] == residency and kernels == 1
 
 
