@@ -14,8 +14,16 @@ sketch's entries are a pure function of (seed, counter), independent of the
 data layout (ref: base/randgen.hpp:98-115, base/context.hpp:19-194).
 """
 
+import time as _time
+
+# set-up accounting (telemetry/setup.py): the package's own import record
+# starts here, and the telemetry package goes first so that its import
+# watch is on sys.meta_path ahead of every other import below
+_T0_NS = _time.perf_counter_ns()
+
 __version__ = "0.1.0"
 
+from libskylark_tpu import telemetry
 from libskylark_tpu.base.precision import install_default_matmul_precision
 
 # f32 matmuls must actually be f32 on TPU (default lowering is one bf16
@@ -27,7 +35,8 @@ from libskylark_tpu.base.context import Context
 from libskylark_tpu.base import errors
 from libskylark_tpu.base.sparse import SparseMatrix
 from libskylark_tpu.base.dist_sparse import DistSparseMatrix, distribute_sparse
-from libskylark_tpu import telemetry
+
+telemetry.setup.package_imported()
 
 __all__ = [
     "Context", "errors", "telemetry", "__version__",

@@ -32,6 +32,12 @@ timing-sensitive tier-1 tests run with it off.
 
 from __future__ import annotations
 
+# first, and standard library only: its import watch and its compile
+# listener time everything below (and everything after this package)
+from libskylark_tpu.telemetry import setup
+
+setup.start()
+
 from libskylark_tpu.base import env as _env
 from libskylark_tpu.telemetry.metrics import (
     DEFAULT_BUCKETS, Counter, Gauge, Histogram, MetricsRegistry, counter,
@@ -48,6 +54,8 @@ from libskylark_tpu.telemetry.export import (
     shutdown_exporter,
 )
 
+setup.bind_counters()
+
 # Auto-install the JSONL exporter when the environment asks for it —
 # first telemetry import (the engine pulls this package) wires the
 # whole export path with zero host code.
@@ -60,6 +68,6 @@ __all__ = [
     "attach", "clear_finished", "counter", "current_span", "enabled",
     "finished_spans", "gauge", "get_context", "get_exporter", "histogram",
     "install_exporter", "new_request_id", "prometheus_text",
-    "register_collector", "registry", "set_enabled", "shutdown_exporter",
-    "snapshot", "span", "stage_seconds",
+    "register_collector", "registry", "set_enabled", "setup",
+    "shutdown_exporter", "snapshot", "span", "stage_seconds",
 ]

@@ -33,6 +33,11 @@ METRICS: Dict[str, str] = {
     "engine.persistent_cache_failures": "counter",
     # telemetry's own bookkeeping (telemetry/trace.py)
     "telemetry.spans": "counter",
+    # set-up accounting (telemetry/setup.py), by phase (SETUP_PHASES
+    # below), always on: each record's own seconds summed, and the count of
+    # records — what summary() gives, for whoever scrapes instead of calls
+    "setup.seconds": "counter",
+    "setup.events": "counter",
     # tune (tune/cache.py)
     "tune.plan_cache_lookups": "counter",
     # ml (ml/admm.py)
@@ -191,4 +196,23 @@ SPANS: Dict[str, Tuple[str, str]] = {
     "io.webhdfs.open": ("ingest", "operator"),
 }
 
-__all__ = ["METRICS", "SPANS"]
+#: phase of a set-up record (telemetry/setup.py ``PHASES``) -> the
+#: per-layer metric of BENCHMARK.json that reads it (layer "set-up" of
+#: PERF.md §3; each moves ``setup_s``), or "operator". The readers
+#: (``cellbench/setup_stages.py``) key on these strings.
+SETUP_PHASES: Dict[str, str] = {
+    # exec_module of the package's modules and of what they import first
+    "import": "setup_import_s",
+    # jax's jaxpr_trace_duration and jaxpr_to_mlir_module_duration: paid
+    # by every process, persistent cache or not
+    "trace": "setup_lower_s",
+    "lower": "setup_lower_s",
+    # jax's backend_compile_duration: the compile on a checkout's first
+    # run, the cache load (inside it) on every later one
+    "backend_compile": "setup_compile_s",
+    # cache_retrieval_time_sec: a detail of the backend_compile record it
+    # lies inside, never added to it
+    "cache_load": "operator",
+}
+
+__all__ = ["METRICS", "SETUP_PHASES", "SPANS"]
