@@ -37,6 +37,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from libskylark_tpu.base import randgen, threefry as tf
 from libskylark_tpu.sketch import params as sketch_params
+from libskylark_tpu.sketch.cos_turns import TURN, cos_turns
 from libskylark_tpu.sketch.dense import BLOCK_COLS  # the stream format's
 # panel width — single source of truth (dense.py imports this module only
 # lazily, so no cycle)
@@ -266,11 +267,15 @@ def _resolve_block(dist_kind, s_dim, keys_ref, k, s_scr, row0=0, rows=None):
 def _finisher(scale_ref, epilogue, operand_refs):
     """What finishes a result tile in VMEM once its last k step is in
     (shared by every rowwise kernel), or None: the tile scale of
-    :func:`_tile_scaled`, then the fused epilogue — ``epilogue = ("cos",
-    inscale, outscale)`` with ``operand_refs = (sc_ref, sh_ref)`` →
-    outscale·cos(acc·inscale·sc + sh) (ref: RFT_Elemental.hpp:83-156,
-    the reference's fused elementwise loops): the output never makes the
-    extra HBM round-trip a separate elementwise op would cost."""
+    :func:`_tile_scaled`, then the fused epilogue of
+    :func:`_cos_epilogue` — ``epilogue = ("cos_turns", outscale)`` with
+    ``operand_refs = (sc_ref, sh_ref)``, both in turns →
+    outscale·cos(2π·(acc·sc + sh)) by :func:`cos_turns` (ref:
+    RFT_Elemental.hpp:83-156, the reference's fused elementwise loops):
+    the output never makes the extra HBM round-trip a separate
+    elementwise op would cost, and the cosine is an exact reduction and
+    one polynomial, not the stock lowering (11 of the 20 ms of an
+    ``rft_features_apply`` apply on a v5e, PERF.md §6 PR 38)."""
     if scale_ref is None and epilogue is None:
         return None
 
@@ -278,27 +283,66 @@ def _finisher(scale_ref, epilogue, operand_refs):
         if scale_ref is not None:
             acc = scale_ref[0] * acc
         if epilogue is not None:
-            kind, inscale, outscale = epilogue
-            assert kind == "cos"
+            kind, outscale = epilogue
+            assert kind == "cos_turns"
             sc_ref, sh_ref = operand_refs
-            acc = outscale * jnp.cos(acc * inscale * sc_ref[:] + sh_ref[:])
+            acc = cos_turns(acc * sc_ref[:] + sh_ref[:], outscale)
         return acc
 
     return finish
 
 
+def _cos_epilogue(sc, sh, inscale: float, outscale: float):
+    """``(extra_operands, epilogue)`` of a rowwise call that finishes its
+    tiles with outscale·cos(acc·inscale·sc + sh), ``sc`` / ``sh`` the
+    (s_dim,) per-feature scales and shifts in radians: the two
+    (1, s_dim) vectors are handed over in turns, ``inscale`` folded in —
+    s-length work inside the caller's executable, once an apply, which
+    also takes a multiply a value out of the epilogue."""
+    sc = sc.astype(jnp.float32).reshape(1, -1) * (inscale / TURN)
+    sh = sh.astype(jnp.float32).reshape(1, -1) / TURN
+    return (sc, sh), ("cos_turns", float(outscale))
+
+
+# Entries of a result tile the finisher takes at a time: 64 rows of a
+# 1024-wide tile, 256 KiB an intermediate.
+_FINISH_SLAB = 64 * 1024
+
+
+def _finish_in_place(out_ref, finish):
+    """``finish`` over the finished tile, a slab of whole rows
+    (``_FINISH_SLAB`` entries, at least a sublane group) at a time, the
+    slabs unrolled. Applied to the whole tile at once every intermediate
+    of the elementwise chain is a tile-sized VMEM temporary: the
+    polynomial cosine then asks Mosaic for 19.5 MiB of its 16 where a
+    512 × 1024 tile is accumulated over k steps; in slabs the temporaries
+    are a slab each. Unrolled, because straight-line code is what
+    Mosaic's scheduler lays under the MXU's passes — the cosine then
+    costs 0.1 ms of a 9.9 ms apply on a v5e, against 2.8 ms as a
+    ``fori_loop`` over slabs (a block of its own: nothing overlaps it).
+    Few slabs, because each is traced and lowered again: 64 of 8 rows
+    put 0.37 s on every process's set-up (PERF.md §6, PR 38)."""
+    m_tile, s_tile = out_ref.shape
+    step = max(8, _FINISH_SLAB // s_tile // 8 * 8)
+    for row in range(0, m_tile, step):
+        rows = slice(row, min(row + step, m_tile))
+        out_ref[rows, :] = finish(out_ref[rows, :])
+
+
 def _store(out_ref, acc, k, n_blocks, finish):
     """out_tile (+)= acc over the k steps of one result tile, ``finish``
-    (:func:`_finisher`) applied after the last. A contraction that is one
-    step deep writes its tile once, finished."""
+    (:func:`_finisher`) applied in place after the last
+    (:func:`_finish_in_place`)."""
     if n_blocks == 1:
-        out_ref[:] = acc if finish is None else finish(acc)
+        out_ref[:] = acc
+        if finish is not None:
+            _finish_in_place(out_ref, finish)
         return
     _accumulate(out_ref, acc, k)
     if finish is not None:
         @pl.when(k == n_blocks - 1)
         def _finish():
-            out_ref[:] = finish(out_ref[:])
+            _finish_in_place(out_ref, finish)
 
 
 def _row0(s_dim, s_tile, j):
@@ -642,11 +686,12 @@ def _fused_call(A, keys, scale=None, *, s_dim, dist_kind, m_tile,
 def _fused_call_cos(A, keys, sc, sh, *, s_dim, dist_kind, m_tile,
                     precision="f32", inscale=1.0, outscale=1.0,
                     interpret=False, s_tile=None):
+    operands, epilogue = _cos_epilogue(sc, sh, inscale, outscale)
     return _rowwise_pallas_call(A, _block_table(keys, A.shape[1]), None,
-                                (sc, sh), s_dim=s_dim, dist_kind=dist_kind,
+                                operands, s_dim=s_dim, dist_kind=dist_kind,
                                 m_tile=m_tile, precision=precision,
                                 interpret=interpret, s_tile=s_tile,
-                                epilogue=("cos", inscale, outscale))
+                                epilogue=epilogue)
 
 
 @functools.partial(
@@ -738,8 +783,13 @@ def _tile_fits(m_tile: int, s_tile: int, epilogue: bool) -> bool:
     """The VMEM plan of one (m_tile × s_tile) result tile
     (:func:`_vmem_estimate`, scratch excluded — operator_residency checks
     it) against the scope. A fused ``epilogue`` counts one more result
-    tile, its temporaries: for the cos at 512 × 1536 Mosaic asks 17.0 MiB
-    where the plan without them says 16.0 (compiled for a v5e, PR 32)."""
+    tile, its temporaries: for the stock cos over the whole tile Mosaic
+    asked 17.2 MiB at 512 × 1536 where the plan without them says 16.0
+    (compiled for a v5e, PR 32). Since the finisher works in slabs
+    (:func:`_finish_in_place`) it asks 14.0 there, 13.8 at 512 × 1024
+    over 16 k steps (15.8 before) and 9.6 at the feature cell's plan, as
+    before (least ``vmem_limit_bytes`` that compiles, PR 38): the term is
+    room now, kept because the tiles are not that change's to move."""
     return _vmem_estimate(
         m_tile, s_tile,
         4 * m_tile * s_tile if epilogue else 0) <= _VMEM_BUDGET_BYTES
@@ -1023,14 +1073,12 @@ def features_rows(key, dist, A, s_dim: int, inscale: float, outscale: float,
     (:func:`_plan`), traceable: the block-key table, the padding, the
     kernel(s) and the slice back, for the caller's one executable
     (sketch/rft.py ``sketch.rft_features``)."""
+    operands, epilogue = _cos_epilogue(sc, sh, inscale, outscale)
     return _rowwise_pallas_call(
-        A, _block_key_table(_key_data(key), A.shape[1]), None,
-        (sc.astype(jnp.float32).reshape(1, s_dim),
-         sh.astype(jnp.float32).reshape(1, s_dim)),
+        A, _block_key_table(_key_data(key), A.shape[1]), None, operands,
         s_dim=s_dim, dist_kind=_DIST_KINDS[type(dist)], m_tile=plan.m_tile,
         s_tile=plan.s_tile, precision=plan.precision,
-        interpret=plan.interpret,
-        epilogue=("cos", float(inscale), float(outscale)))
+        interpret=plan.interpret, epilogue=epilogue)
 
 
 def fused_partial(

@@ -13,9 +13,11 @@ allocation's key, the projection and the featurization — the hand-written
 OpenMP elementwise loops of the reference (ref: RFT_Elemental.hpp:83-156)
 disappear. On a TPU a rowwise apply of normal frequencies runs that program
 as the fused kernels of sketch/pallas_dense.py (the operator generated once
-an apply, the contraction, and the cos finishing each result tile in VMEM;
-the result tiled along s where s_dim is wide); everywhere else it is W, one
-XLA matmul and the elementwise tail, fused by XLA.
+an apply, the contraction, and the cos finishing each result tile in VMEM —
+sketch/cos_turns.py: the phase formed in turns, reduced exactly, one short
+polynomial; the result tiled along s where s_dim is wide); everywhere else
+it is W, one XLA matmul and the elementwise tail with the stock ``jnp.cos``,
+fused by XLA.
 
 Sub-streams of the allocation: 0 = W entries, 1 = shifts, 2 = scales (Matern).
 """
@@ -190,13 +192,16 @@ class RFT(OperatorCache, SketchTransform):
         W = self._cached_op(A.dtype)
         plan = self._kernel_plan(A) if rowwise and W is None else None
         rows = A.shape[0] if rowwise else A.shape[1]
+        # finisher: what computes the elementwise map — the stock
+        # jnp.cos / jnp.exp of _featurize, or the kernels' cos_turns
         attrs = {"path": "features", "family": self.sketch_type,
-                 "epilogue": self.epilogue, "kernel": "xla",
-                 "features": rows * self._S}
+                 "epilogue": self.epilogue, "finisher": self.epilogue,
+                 "kernel": "xla", "features": rows * self._S}
         if plan is not None:
             attrs.update(
                 kernel=("pallas_planes" if plan.operator_residency == "hbm"
                         else "pallas_generate"),
+                finisher="cos_turns",
                 m_tile=plan.m_tile, s_tile=plan.s_tile,
                 operator_residency=plan.operator_residency)
         else:

@@ -163,8 +163,10 @@ SPANS: Dict[str, Tuple[str, str]] = {
     # and kernel (sparse_serve.sparse_kernel: "pallas_rows" | "xla_scatter",
     # what adds the terms up); the feature maps' (sketch/rft.py) carries
     # path="features", family, epilogue, kernel, features (= rows × s, which
-    # feature_rate.apply reads) and, on the kernel route, m_tile, s_tile
-    # and operator_residency
+    # feature_rate.apply reads), finisher (what computes the elementwise
+    # map: "cos_turns" = sketch/cos_turns.py on the kernel route, "cos" |
+    # "exp" = the stock jnp function on the XLA route; for the operator)
+    # and, on the kernel route, m_tile, s_tile and operator_residency
     "sketch.apply": ("sketch kernel", "sketch_host_ms.apply"),
     "sketch.plan": ("sketch kernel", "sketch_plan_ms.apply"),
     "sketch.dispatch": ("sketch kernel", "sketch_dispatch_ms.apply"),

@@ -134,6 +134,33 @@ def test_s_tiled_cos_kernel_equals_the_xla_program(shape, s, residency,
                                    rtol=1e-4)
 
 
+@pytest.mark.parametrize("residency,m_tile", [
+    ("hbm", 8), ("per_tile", 64), ("vmem", 8)])
+def test_fused_cosine_against_a_float64_map(residency, m_tile, small_scope,
+                                            monkeypatch):
+    """The epilogue where the contraction's 1e-4 cannot hide it: the
+    "f32" regime against the whole map in float64 — W, b and the scales
+    as the stream defines them — on the feature cell's kind of phases
+    (σ = √(2n): a projection of deviation 0.7 rad, shifts in [0, 2π)).
+    What is left is the rounding of the phase itself, ≈ |t|·2⁻²⁴ turns."""
+    _residency(monkeypatch, residency)
+    m, n, s = 40, 440, 1024
+    T = GaussianRFT(n, s, Context(seed=38), sigma=(2.0 * n) ** 0.5)
+    A = _operand(m, n, seed=6)
+    p = _plan((m, n), s, m_tile=m_tile)
+    assert p["s_tile"] < s and p["operator_residency"] == residency
+    got = pd.rft_rowwise_apply(
+        T.subkey(0), T.dist, A, s, T.inscale, T.outscale,
+        np.asarray(T.row_scales()), np.asarray(T.shifts()),
+        m_tile=m_tile, precision="f32", interpret=True)
+    W = np.asarray(T.w_panel(0, n), np.float64)         # inscale folded in
+    projection = np.asarray(A, np.float64) @ W.T
+    assert 0.6 < projection.std() < 0.8
+    want = np.cos(projection + np.asarray(T.shifts(), np.float64))
+    err = np.abs(np.asarray(got, np.float64) / T.outscale - want)
+    assert err.max() <= 2e-6
+
+
 @pytest.mark.parametrize("residency,m_tile", [("hbm", 8), ("per_tile", 64)])
 def test_s_tiled_projection_is_bit_equal_to_the_untiled(residency, m_tile,
                                                         monkeypatch):

@@ -181,6 +181,34 @@ def test_ragged_width_is_padded_inside_the_program(interpreted):
     assert _count(jaxpr.jaxpr, "pallas_call") >= 1
 
 
+@pytest.mark.parametrize("m_tile,calls", [(64, 1), (8, 2)],
+                         ids=["per_tile", "hbm"])
+def test_only_the_xla_route_holds_the_stock_cosine(interpreted, monkeypatch,
+                                                   m_tile, calls):
+    """The kernel route's whole program — kernels included — has no
+    ``cos`` primitive: its epilogue is sketch/cos_turns.py. The XLA route
+    of the same program keeps ``jnp.cos``."""
+    from libskylark_tpu.sketch import params as sketch_params
+
+    monkeypatch.setattr(pallas_dense, "_SCRATCH_CAP_BYTES", 0)
+    monkeypatch.setattr(sketch_params, "_pallas_m_tile", m_tile)
+    T = sk.GaussianRFT(N, S, Context(SEED), sigma=SIGMA)
+    X = examples()
+    plan = T._kernel_plan(X)
+    assert plan.operator_residency == ("hbm" if calls == 2 else "per_tile")
+
+    def program(plan):
+        return jax.make_jaxpr(
+            lambda k, a: rft.rft_features(
+                k, a, spec=("GaussianRFT", N, S, (("sigma", SIGMA),)),
+                rowwise=True, plan=plan))(T.allocation.key_data, X).jaxpr
+
+    kernels, xla = program(plan), program(None)
+    assert _count(kernels, "pallas_call") == calls
+    assert _count(kernels, "cos") == 0 and _count(kernels, "round") >= 1
+    assert _count(xla, "cos") == 1 and _count(xla, "pallas_call") == 0
+
+
 def _count(jaxpr, primitive: str) -> int:
     found = 0
     for eqn in jaxpr.eqns:
@@ -225,6 +253,7 @@ def test_kernel_route_spans_and_counter(fresh, interpreted, monkeypatch, scope,
     assert kids[1].attrs["what"] == "allocation"
     dispatch = kids[2].attrs
     assert dispatch["path"] == "features" and dispatch["epilogue"] == "cos"
+    assert dispatch["finisher"] == "cos_turns"
     assert dispatch["family"] == "GaussianRFT" and dispatch["kernel"] == kernel
     assert dispatch["features"] == 40 * s
     assert dispatch["operator_residency"] == residency
@@ -256,7 +285,8 @@ def test_other_frequencies_keep_the_xla_route(fresh, interpreted, family, kw,
     dispatch = next(s for s in trace.finished_spans()
                     if s.name == "sketch.dispatch").attrs
     assert dispatch == {"path": "features", "family": T.sketch_type,
-                        "epilogue": epilogue, "kernel": "xla",
+                        "epilogue": epilogue, "finisher": epilogue,
+                        "kernel": "xla",
                         "features": X.shape[0] * S}
     assert counter.value(family=T.sketch_type,
                          kernel="xla") - before == X.shape[0] * S
@@ -272,9 +302,10 @@ def test_columnwise_and_pinned_applies_do_not_plan_a_kernel(interpreted):
         T.apply(X.T, sk.COLUMNWISE).block_until_ready()
         T.materialize()
         T.apply(X, sk.ROWWISE).block_until_ready()
-        kernels_seen = [s.attrs["kernel"] for s in trace.finished_spans()
+        kernels_seen = [(s.attrs["kernel"], s.attrs["finisher"])
+                        for s in trace.finished_spans()
                         if s.name == "sketch.dispatch"]
     finally:
         telemetry.set_enabled(False)
         trace.clear_finished()
-    assert kernels_seen == ["xla", "xla"]
+    assert kernels_seen == [("xla", "cos"), ("xla", "cos")]
