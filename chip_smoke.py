@@ -286,6 +286,17 @@ def step_sketch() -> None:
     err = close(np.asarray(out)[:rows], ref, "FJLT(wht) vs operator panel")
     report("sketch.FJLT_wht.rowwise", first, run, shape=f"{M}x{N}->{S}",
            backend=(served() or ["xla"])[0], err=f"{err:.2e}")
+    # the same sketch columnwise (the Blendenpik call): on a TPU the
+    # block-mix kernel of sketch/pallas_wht.py, then the sampled rows
+    At = jnp.asarray(A.T)
+    kernel = F.mix_plan(At, False)[0]
+    if jax.default_backend() == "tpu" and kernel != "pallas_blocks":
+        raise AssertionError(f"columnwise FJLT(wht) planned {kernel}")
+    out, first, run = timed(lambda: F.apply(At, sk.COLUMNWISE))
+    err = close(np.asarray(out)[:, :rows], ref.T,
+                "FJLT(wht) columnwise vs operator panel")
+    report("sketch.FJLT_wht.columnwise", first, run, shape=f"{N}x{M}->{S}",
+           backend=kernel, err=f"{err:.2e}")
 
     # CountSketch, dense operand and the same operand as a SparseMatrix
     C = sk.CWT(N, S, Context(seed=3))

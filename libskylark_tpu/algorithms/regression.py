@@ -168,13 +168,14 @@ def _accel_transform(m: int, n: int, context: Context,
     eagerly (advances the Context counter) so the compiled solve phases
     can be keyed on its serialization digest."""
     from libskylark_tpu import sketch as sk
+    from libskylark_tpu.sketch.fjlt import solver_fut
 
     s = int(params.sketch_size_factor * n)
     s = min(max(s, n + 1), m)
     if gaussian:
         return sk.JLT(m, s, context)
     if params.sketch == "fjlt":
-        return sk.FJLT(m, s, context)
+        return sk.FJLT(m, s, context, fut=solver_fut(m))
     if params.sketch == "jlt":
         return sk.JLT(m, s, context)
     if params.sketch == "cwt":

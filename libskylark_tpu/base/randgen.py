@@ -143,7 +143,7 @@ class UniformInt(Distribution):
         return self.low + off.astype(jnp.int32)
 
     def live_draws(self):
-        # 2³² ≡ 0 mod a power-of-two span ≤ 2¹⁶: the high draw cancels
+        # 2³² ≡ 0 mod every power-of-two span (≤ 2³²): the high draw cancels
         dead = tf.randint_multiplier(self.high - self.low + 1) == 0
         return (1,) if dead else (0, 1)
 

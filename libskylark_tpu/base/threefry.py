@@ -175,9 +175,9 @@ def bits_to_exponential(bits: jnp.ndarray) -> jnp.ndarray:
 
 def randint_multiplier(span: int) -> int:
     """2³² mod ``span`` as (2¹⁶ mod span)² mod span — static Python math.
-    Zero exactly when 2¹⁶ % span == 0 (every pow2 span ≤ 2¹⁶), where the
-    high draw of :func:`bits_to_randint` cancels and a kernel can skip
-    its cipher."""
+    Zero for every power-of-two span up to 2³² (2³² mod 2²⁰ = 0: the
+    square of 2¹⁶ is reduced again), where the high draw of
+    :func:`bits_to_randint` cancels and a kernel can skip its cipher."""
     m = (1 << 16) % span
     return (m * m) % span
 

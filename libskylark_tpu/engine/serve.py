@@ -212,7 +212,7 @@ _FWHT_FLUSHES = _metrics.counter(
     "serve.fwht_flushes",
     "SRHT-family sketch_apply flushes by resolved flush backend "
     "(pallas = the in-kernel FWHT butterfly, xla = the panel-free "
-    "fwht_sketch lowering)")
+    "mix-and-sample program, fjlt.srht_serve_apply)")
 _CM_SUBMITS = _metrics.counter(
     "serve.compressed_matmul_submits",
     "Compressed approximate-matmul submissions reaching the flush "
@@ -387,7 +387,7 @@ def _sketch_family(transform):
     if isinstance(transform, CWT):
         return "CWT", None
     if isinstance(transform, FJLT):
-        # the serve family is the SRHT: the panel-free fwht_sketch
+        # the serve family is the SRHT: the panel-free mix-and-sample
         # program (and the in-kernel FWHT butterfly behind it) is
         # closed-form only for the Sylvester-Hadamard mixer — the
         # same restriction operator_panel/fold_rows carry

@@ -22,11 +22,13 @@ def approximate_least_squares(
     sketch: str = "fjlt",
 ):
     """Sketch-and-solve least squares (Drineas et al.); default sketch size
-    4×Width(A) with an FJLT (ref: nla/least_squares.hpp:41-83). Sparse
+    4×Width(A) with an FJLT (ref: nla/least_squares.hpp:41-83; the Hadamard
+    mixer where Height(A) is a power of two, else the DCT). Sparse
     operands (``SparseMatrix``/``DistSparseMatrix``) default to a CWT
     sketch (the FJLT needs a dense fast transform)."""
     from libskylark_tpu import sketch as sk
     from libskylark_tpu.base.sparse import is_sparse_operand
+    from libskylark_tpu.sketch.fjlt import solver_fut
 
     if is_sparse_operand(A):
         if sketch == "fjlt":
@@ -37,7 +39,7 @@ def approximate_least_squares(
     s = int(sketch_size) if sketch_size else 4 * n
     s = min(max(s, n + 1), m)
     if sketch == "fjlt":
-        T = sk.FJLT(m, s, context)
+        T = sk.FJLT(m, s, context, fut=solver_fut(m))
     elif sketch == "cwt":
         T = sk.CWT(m, s, context)
     elif sketch == "jlt":

@@ -13,50 +13,161 @@ sqrt(N/S)). Under a sharded input the FUT runs independently per column shard
 (the transform acts along the N axis, which is materialized locally when the
 input is column-sharded; for row-sharded inputs XLA re-lays out, the analog of
 the reference's [VC,*] → [*,VR] redistribution).
+
+With the Walsh-Hadamard mixer (``fut="wht"``, the SRHT), a float32 operand and
+a power-of-two axis, an apply is ONE compiled program
+(``sketch.fjlt_mix_sample``, :func:`fjlt_mix_sample`): D and the sampled
+coordinates generated inside from the allocation's key words, the axis mixed
+in full inside blocks of rows and the Kronecker factor above the blocks taken
+at the sampled rows only — on a TPU, columnwise, the blocks in one Pallas pass
+over the operand (sketch/pallas_wht.py), elsewhere on XLA without a
+transposed copy (sketch/fut.py ``wht_blocks``). Its workspace is at most one
+operand-sized array; the serve tier's lanes (:func:`srht_serve_apply`) are the
+same function on XLA. Every other mixer and dtype, and an operand that lies on
+several devices, keeps the eager composition (``fut.sign_mix_sample``).
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from libskylark_tpu.base import errors, randgen
+from libskylark_tpu.base import threefry as tf
 from libskylark_tpu.sketch import fut as _fut
 from libskylark_tpu.sketch.fut import make_fut
 from libskylark_tpu.sketch.transform import SketchTransform, register
+from libskylark_tpu.telemetry import metrics as _metrics
+from libskylark_tpu.telemetry import trace as _trace
+
+_MIXED = _metrics.counter(
+    "sketch.mixed_elements",
+    "operand entries sign-and-Hadamard mixed by the compiled FJLT apply "
+    "(transform axis × free axis), by family and kernel")
+
+#: Free-axis entries the XLA route mixes at a time: its workspace is a few
+#: (N × tile) float32 temporaries, whatever the operand's other extent. No
+#: wider tile read faster on a v5e, and one whose temporary reaches 64 MiB
+#: (a serve flush's lanes counted) takes twice as long (PR 39, ms at 128 /
+#: 256 / 1024 / 2048: rowwise 8192 × 8192 3.8 / — / 3.7 / 7.1, rowwise 4096 ×
+#: 65536 13.7 / 26.2 / 40.7 / —, columnwise 65536 × 1000 3.7 / 6.7 / 10.8 / —).
+MIX_TILE = 128
+#: Rows of the transform axis mixed in full; the factor above them is
+#: computed at the sampled rows only (``fut.sample_outer``).
+MIX_BLOCK = 16384
+
+
+def fjlt_mix_sample(key_data, A, *, s_dim: int, rowwise: bool, kernel: str,
+                    block: int, tile: int):
+    """One FJLT/``wht`` apply as a pure function of the allocation's raw key
+    words ((2,) uint32): ``√(N/s) · (H_N · (D ⊙ A) / √N)[idx]`` along the
+    transform axis (rows columnwise, columns rowwise) of a float32 A.
+
+    D (sub-stream 0) and the sampled coordinates (sub-stream 1) are the
+    positional streams :meth:`FJLT.diagonal` / :meth:`FJLT.sample_indices`
+    read, generated here from the key. H_N = H_a ⊗ H_block: the axis is
+    mixed in full inside blocks of ``block`` rows and the outer factor
+    taken at the s sampled rows only (sketch/fut.py). ``kernel``:
+
+    * ``"pallas_blocks"`` — columnwise on a TPU: one pass of
+      :func:`pallas_wht.mix_blocks` over the operand writes the
+      block-mixed matrix, the one operand-sized workspace of an apply;
+    * ``"xla_bf16x3"`` | ``"xla_f32"`` — everything else: the free axis
+      walked ``tile`` entries at a time (a rowwise tile transposed by
+      itself), so the workspace is a few (N × tile) temporaries; the
+      Hadamard factors contract as exact bfloat16 against a three-way split
+      operand on a TPU, in float32 off it.
+    """
+    n = A.shape[1] if rowwise else A.shape[0]
+    m = A.shape[0] if rowwise else A.shape[1]
+
+    def stream(tag, dist, stop, dtype):
+        key = jax.random.wrap_key_data(tf.fold_in(key_data, tag))
+        return randgen.stream_slice(key, dist, 0, stop, dtype=dtype)
+
+    D = stream(0, randgen.Rademacher(), n, A.dtype)
+    idx = stream(1, randgen.UniformInt(0, n - 1), s_dim, jnp.int32)
+    scale = 1.0 / math.sqrt(s_dim)
+
+    if kernel == "pallas_blocks":
+        from libskylark_tpu.sketch import pallas_wht
+
+        Y = pallas_wht.mix_blocks(A, D, block=block, tile=tile)
+        return scale * _fut.sample_outer(Y, idx, block)
+
+    def mixed(X):                                   # X (N, w) → (s, w)
+        Y = _fut.wht_blocks(D[:, None] * X, block, kernel == "xla_bf16x3")
+        return scale * _fut.sample_outer(Y, idx, block)
+
+    def columns(lo, w):
+        """The sketch of free-axis entries [lo, lo + w), in A's layout."""
+        if rowwise:
+            return mixed(jax.lax.dynamic_slice(A, (lo, 0), (w, n)).T).T
+        return mixed(jax.lax.dynamic_slice(A, (0, lo), (n, w)))
+
+    if m <= tile:
+        return columns(0, m)
+    full, rest = divmod(m, tile)
+    out = jnp.zeros((m, s_dim) if rowwise else (s_dim, m), A.dtype)
+
+    def place(out, lo, part):
+        return jax.lax.dynamic_update_slice(
+            out, part, (lo, 0) if rowwise else (0, lo))
+
+    out = jax.lax.fori_loop(
+        0, full, lambda j, o: place(o, j * tile, columns(j * tile, tile)), out)
+    if rest:
+        out = place(out, full * tile, columns(full * tile, rest))
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def _mix_program():
+    """The compiled apply, built at the first operand so that importing
+    the sketch layer never pulls the engine."""
+    from libskylark_tpu.engine.compiled import compiled
+
+    return compiled(fjlt_mix_sample, name="sketch.fjlt_mix_sample",
+                    static_argnames=("s_dim", "rowwise", "kernel", "block",
+                                     "tile"))
+
+
+def solver_fut(n: int) -> str:
+    """The mixer the least-squares solvers give their FJLT over an axis of
+    ``n`` (Blendenpik's choices are WHT, DCT and DHT): the Hadamard one
+    where ``n`` is a power of two — the compiled, memory-bounded program
+    above, at heights whose DCT (``lax.fft`` over complex copies of the
+    operand) no longer fits —, else the DCT, which takes any ``n``."""
+    return "wht" if n > 0 and not n & (n - 1) else "dct"
+
+
+def _xla_plan(n: int, dtype) -> tuple:
+    """(kernel, block, tile) of :func:`fjlt_mix_sample` on XLA for a
+    transform axis of ``n``: the bfloat16 split is the MXU's and float32's."""
+    split = jax.default_backend() == "tpu" and dtype == jnp.float32
+    return "xla_bf16x3" if split else "xla_f32", min(n, MIX_BLOCK), MIX_TILE
 
 
 def srht_serve_apply(key_data, A, *, s_dim: int, rowwise: bool):
     """Panel-free SRHT serve program (the ``sketch_apply`` executable
-    body for the FJLT/``wht`` family, docs/serving).
-
-    Rebuilds the Rademacher diagonal (sub-stream 0) and the sampled
-    coordinates (sub-stream 1) from the raw key data with the same
-    positional :func:`randgen.stream_slice` calls the transform's own
-    ``diagonal()`` / ``sample_indices()`` make — bit-identical streams
-    — then contracts through :func:`fut.fwht_sketch` instead of a
-    materialized operator panel. The transform axis is the exact
-    (never padded) extent: the FWHT length defines the operator, so
-    ``_sketch_statics`` pads only the free axis for this family."""
-    import jax
-    import jax.random as jr
-
-    key = jr.wrap_key_data(jnp.asarray(key_data))
+    body for the FJLT/``wht`` family, docs/serving):
+    :func:`fjlt_mix_sample` on XLA — vmap-batchable, a pure function of
+    the raw key data, the same streams and the same contraction as the
+    transform's own apply. The transform axis is the exact (never padded)
+    extent: the FWHT length defines the operator, so ``_sketch_statics``
+    pads only the free axis for this family."""
     n = A.shape[1] if rowwise else A.shape[0]
     if n & (n - 1):
         raise ValueError(f"SRHT serve requires power-of-2 n, got {n}")
-    D = randgen.stream_slice(
-        jax.random.fold_in(key, 0), randgen.Rademacher(), 0, n,
-        dtype=A.dtype)
-    idx = randgen.stream_slice(
-        jax.random.fold_in(key, 1), randgen.UniformInt(0, n - 1),
-        0, s_dim, dtype=jnp.int32)
-    return _fut.fwht_sketch(
-        A, D, idx, 1.0 / math.sqrt(n), math.sqrt(n / s_dim),
-        axis=1 if rowwise else 0)
+    kernel, block, tile = _xla_plan(n, A.dtype)
+    return fjlt_mix_sample(jnp.asarray(key_data), A, s_dim=s_dim,
+                           rowwise=rowwise, kernel=kernel, block=block,
+                           tile=tile)
 
 
 def _popcount_parity(a: np.ndarray) -> np.ndarray:
@@ -251,17 +362,72 @@ class FJLT(SketchTransform):
             off += block
         return (1.0 / math.sqrt(self._S)) * out
 
+    def mix_plan(self, A, rowwise: bool):
+        """(kernel, block, tile) of :func:`fjlt_mix_sample` for this
+        operand, from the shapes and the device alone, or None where the
+        eager composition below serves: another mixer than ``wht``, another
+        dtype than float32, an axis that is no power of two, an operand
+        that lies on more than one device (the composition's FUT runs a
+        column shard at a time under XLA's partitioner; the program's tile
+        walk would slice the sharded axis). A traced operand shows no
+        placement and is taken as the dense sketches take it
+        (``dense.pallas_ambient_ok``): the kernel on a process of one
+        device, else the XLA route, which is right under any sharding —
+        but where the caller's operand is in fact sharded along its free
+        axis, the partitioner gathers it whole for the tile walk."""
+        if (self._fut_name != "wht" or A.dtype != jnp.float32
+                or self._N & (self._N - 1)):
+            return None
+        traced = isinstance(A, jax.core.Tracer)
+        if not traced and len(A.devices()) > 1:
+            return None
+        on_tpu = jax.default_backend() == "tpu"
+        if on_tpu and not rowwise and (not traced or jax.device_count() == 1):
+            from libskylark_tpu.sketch import pallas_wht    # pulls pallas
+
+            served = pallas_wht.plan(A.shape, A.dtype)
+            if served is not None:
+                return ("pallas_blocks",) + served
+        return _xla_plan(self._N, A.dtype)
+
+    def _mix_sample(self, A, rowwise: bool):
+        """The ``wht`` apply as the one ``sketch.fjlt_mix_sample`` program
+        (under a caller's trace: part of the caller's program); None where
+        :meth:`mix_plan` declines."""
+        plan = self.mix_plan(A, rowwise)
+        if plan is None:
+            return None
+        kernel, block, tile = plan
+        statics = dict(s_dim=self._S, rowwise=rowwise, kernel=kernel,
+                       block=block, tile=tile)
+        key_data = self._alloc.key_data
+        if isinstance(A, jax.core.Tracer):
+            return fjlt_mix_sample(key_data, A, **statics)
+        columns = A.shape[0] if rowwise else A.shape[1]
+        factors = (self._N // block,) + _fut.block_factors(block)
+        attrs = {"path": "fut", "family": self.sketch_type,
+                 "fut": self._fut_name, "kernel": kernel,
+                 "factors": factors, "elements": self._N * columns,
+                 "sampled": self._S * columns}
+        with _trace.span("sketch.dispatch", attrs):
+            out = _mix_program()(key_data, A, **statics)
+        _MIXED.inc_always(attrs["elements"], family=self.sketch_type,
+                          kernel=kernel)
+        return out
+
+    def _apply_axis(self, A: jnp.ndarray, axis: int) -> jnp.ndarray:
+        out = self._mix_sample(A, rowwise=axis == 1)
+        if out is not None:
+            return out
+        return _fut.sign_mix_sample(
+            self._fut.apply, A, self.diagonal(A.dtype), self.sample_indices(),
+            self._fut.scale(), math.sqrt(self._N / self._S), axis)
+
     def _apply_columnwise(self, A: jnp.ndarray) -> jnp.ndarray:
-        D = self.diagonal(A.dtype)
-        mixed = self._fut.apply(self._fut.scale() * D[:, None] * A, axis=0)
-        scale = math.sqrt(self._N / self._S)
-        return scale * mixed[self.sample_indices(), :]
+        return self._apply_axis(A, 0)
 
     def _apply_rowwise(self, A: jnp.ndarray) -> jnp.ndarray:
-        D = self.diagonal(A.dtype)
-        mixed = self._fut.apply(self._fut.scale() * D[None, :] * A, axis=1)
-        scale = math.sqrt(self._N / self._S)
-        return scale * mixed[:, self.sample_indices()]
+        return self._apply_axis(A, 1)
 
     def _extra_params(self) -> dict[str, Any]:
         return {"fut": self._fut_name}

@@ -163,33 +163,140 @@ def wht(A: jnp.ndarray, axis: int = 0, precision=None) -> jnp.ndarray:
 fwht = wht
 
 
+def sign_mix_sample(apply, A: jnp.ndarray, diag: jnp.ndarray,
+                    idx: jnp.ndarray, fut_scale: float, samp_scale: float,
+                    axis: int = 0) -> jnp.ndarray:
+    """``samp_scale · gather(apply(fut_scale · diag ⊙ A, axis), idx)``: the
+    FJLT as the plain composition sign → transform → sample, for any
+    mixer ``apply(X, axis)``, the whole mixed operand in memory. ``axis``
+    is the transform axis: 0 for columnwise operands ``(n, m)``, 1 for
+    rowwise ``(m, n)``. What ``FJLT.apply`` runs where the compiled
+    mix-and-sample program (sketch/fjlt.py) declines."""
+    if axis == 0:
+        return samp_scale * apply(fut_scale * diag[:, None] * A, 0)[idx, :]
+    return samp_scale * apply(fut_scale * diag[None, :] * A, 1)[:, idx]
+
+
 def fwht_sketch(A: jnp.ndarray, diag: jnp.ndarray, idx: jnp.ndarray,
                 fut_scale: float, samp_scale: float, axis: int = 0,
                 precision=None) -> jnp.ndarray:
-    """Fused sign→FWHT→sample composition: the panel-free SRHT program.
+    """:func:`sign_mix_sample` with the Walsh-Hadamard mixer: the oracle
+    of the dyadic battery (tests/test_fwht.py). It is *bit-equal* to the
+    ``operator_panel`` matmul reference whenever every intermediate is
+    exactly representable (integer-valued operands with ``n`` and ``s``
+    even powers of two), and there the compiled program
+    (``fjlt.fjlt_mix_sample``, which the serve tier and ``FJLT.apply``
+    run) is bit-equal to it."""
+    return sign_mix_sample(partial(wht, precision=precision), A, diag, idx,
+                           fut_scale, samp_scale, axis)
 
-    Computes ``samp_scale · gather(fwht(fut_scale · diag ⊙ A, axis),
-    idx)`` with the multiplications and the gather composed in exactly
-    the order of ``FJLT._apply_columnwise`` / ``_apply_rowwise`` — the
-    fused path is *bit-equal* to the separate diag→FWHT→gather
-    composition (same op sequence, just one traced program), and
-    bit-equal to the ``operator_panel`` matmul reference whenever every
-    intermediate is exactly representable (integer-valued operands with
-    ``n`` and ``s`` even powers of two; the dyadic battery in
-    tests/test_fwht.py pins this).
 
-    ``diag`` is the length-``n`` Rademacher sign diagonal fused into
-    the first butterfly stage; ``idx`` the ``s`` sampled coordinates
-    gathered out of the last. ``axis`` is the contracted (transform)
-    axis: 0 for columnwise operands ``(n, m)``, 1 for rowwise
-    ``(m, n)``."""
-    if axis == 0:
-        mixed = wht(fut_scale * diag[:, None] * A, axis=0,
-                    precision=precision)
-        return samp_scale * mixed[idx, :]
-    mixed = wht(fut_scale * diag[None, :] * A, axis=1,
-                precision=precision)
-    return samp_scale * mixed[:, idx]
+# ---------------------------------------------------------------------------
+# the mix-and-sample contraction of a long transform axis (FJLT / SRHT)
+# ---------------------------------------------------------------------------
+#
+# H_N = H_a ⊗ H_R over the row-major fold i = p·R + r (Sylvester ordering
+# is kron-associative). A long axis is transformed in full inside each
+# block of R consecutive rows (:func:`wht_blocks`: factors the MXU holds,
+# the operand never transposed — the transform axis is already the fold's
+# leading axes) and only the sampled rows of the last, outer factor are
+# computed (:func:`sample_outer`): s rows of a·R, a signed sum of a rows
+# each, instead of one more whole stage.
+
+#: Widest Hadamard factor a stage contracts against (log2): the MXU's side.
+_FACTOR_LOG2 = 7
+
+
+def block_factors(block: int) -> tuple:
+    """The Kronecker split of a block of ``block`` (a power of two) rows,
+    outer factor first: the fewest factors of at most 2⁷, as even as they
+    come (2¹⁴ → (128, 128), 2¹¹ → (64, 32))."""
+    k = block.bit_length() - 1
+    if block <= 0 or block != 1 << k:
+        raise ValueError(f"WHT block must be a power of two, got {block}")
+    stages = max(1, -(-k // _FACTOR_LOG2))
+    base, extra = divmod(k, stages)
+    return tuple(1 << (base + (i < extra)) for i in range(stages))
+
+
+def _hadamard_stage(H: np.ndarray, X: jnp.ndarray, bf16_split: bool):
+    """``einsum("ij,gjrw->girw", H, X)``, H a dense ±1 factor. Every
+    entry of H is exact in bfloat16, so under ``bf16_split`` only the
+    operand carries the float32: X = hi + mid + lo in bfloat16 (3 × 8
+    significant bits, the whole float32 significand) and three
+    single-pass products with float32 accumulation — half the MXU passes
+    of a float32 ``highest`` contraction, which splits both sides. Off
+    the MXU (the CPU) one float32 product."""
+    if not bf16_split:
+        return jnp.einsum("ij,gjrw->girw", jnp.asarray(H, X.dtype), X,
+                          precision=jax.lax.Precision.HIGHEST)
+    Hb = jnp.asarray(H, jnp.bfloat16)
+
+    def bf16_part(x):
+        # reduce_precision, not a cast and back: XLA may keep the excess
+        # precision of a convert pair, and the split would be X + 0 + 0
+        return jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+
+    hi = bf16_part(X)
+    mid = bf16_part(X - hi)
+    lo = X - hi - mid
+
+    def one(part):
+        return jnp.einsum("ij,gjrw->girw", Hb, part.astype(jnp.bfloat16),
+                          precision=jax.lax.Precision.DEFAULT,
+                          preferred_element_type=jnp.float32)
+
+    return one(hi) + (one(mid) + one(lo))
+
+
+def wht_blocks(X: jnp.ndarray, block: int, bf16_split: bool = False):
+    """Unnormalized WHT of X (N, w) along axis 0 inside each block of
+    ``block`` consecutive rows (``block`` divides N): one contraction a
+    factor of :func:`block_factors`, each against the fold's own leading
+    axes — no ``moveaxis``, no transposed copy."""
+    n, w = X.shape
+    inner = block
+    for f in block_factors(block):
+        inner //= f
+        X = _hadamard_stage(_hadamard_np(f), X.reshape(-1, f, inner, w),
+                            bf16_split).reshape(n, w)
+    return X
+
+
+#: Bytes of gathered rows :func:`sample_outer` holds at a time (on a v5e,
+#: 2²⁰ × 1024 by 4096 samples: 64 MiB 5.3 ms, 256 MiB 8.3).
+_SAMPLE_CHUNK_BYTES = 1 << 26
+
+
+def sample_outer(Y: jnp.ndarray, idx: jnp.ndarray, block: int) -> jnp.ndarray:
+    """Rows ``idx`` of ``(H_a ⊗ I) · Y`` for Y (a·block, w) already
+    transformed inside its blocks: the last Kronecker factor at the
+    sampled rows only. Row p·block + r of the full transform is
+    Σ_q (−1)^popcount(p & q) · Y[q·block + r], so a sample costs a gather
+    of a rows and their signed sum. The gathered rows are held ``chunk``
+    samples at a time (≤ ``_SAMPLE_CHUNK_BYTES``)."""
+    n, w = Y.shape
+    a = n // block
+    if a == 1:
+        return Y[idx]
+    s = idx.shape[0]
+    shift = block.bit_length() - 1
+    q = jnp.arange(a, dtype=jnp.int32)[:, None]
+
+    def rows(ix):
+        par = jax.lax.population_count((ix >> shift)[None, :] & q)
+        sign = (1 - 2 * (par & 1)).astype(Y.dtype)
+        # whole rows of Y as it lies (a gather along the block axis of a
+        # 3-D view has XLA re-lay the operand out: one more copy of it)
+        at = (q * block + (ix & (block - 1))[None, :]).reshape(-1)
+        return jnp.sum(sign[:, :, None] * Y[at].reshape(a, -1, w), axis=0)
+
+    chunk = max(8, _SAMPLE_CHUNK_BYTES // (a * w * Y.dtype.itemsize))
+    if chunk >= s:
+        return rows(idx)
+    pad = -s % chunk
+    out = jax.lax.map(rows, jnp.pad(idx, (0, pad)).reshape(-1, chunk))
+    return out.reshape(-1, w)[:s]
 
 
 class FUT:

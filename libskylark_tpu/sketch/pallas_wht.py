@@ -1,0 +1,141 @@
+"""Pallas TPU kernel: sign and Hadamard-mix a long columnwise axis, block by
+block, in one pass over the operand.
+
+The full stages of the FJLT's mix-and-sample contraction (sketch/fut.py,
+``wht_blocks``) for an operand whose transform axis is its rows: a grid
+step holds ``block`` consecutive rows × ``tile`` columns in VMEM and
+leaves there H_block · (D ⊙ A). The axis inside a block folds as (group,
+128):
+
+* the inner factor runs on the MXU, group by group: (H_128 · diag(d_g)) ·
+  A_g — the Rademacher signs of the group's 128 rows scale the *columns*
+  of the ±1 factor, which stays exact in bfloat16, so only the operand is
+  split (hi + mid + lo, three single-pass products, float32 accumulation);
+* the outer factor is whole-vreg butterflies over the groups on the VPU:
+  u ± v of (128 × tile) slabs — no lane or sublane ever moves.
+
+The operand is read once and the mixed matrix written once (the one
+workspace of an apply); ``fut.sample_outer`` then gathers the sampled rows
+of the last, across-block factor. :mod:`pallas_fwht` (the serve tier's
+kernel) holds the *whole* axis of a lane block in VMEM and stops at 2048
+samples; this one bounds VMEM by the block, not by the axis.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from libskylark_tpu.sketch.fut import _hadamard_np
+
+GROUP = 128                 # rows the MXU factor mixes: the MXU's side
+_SLAB = 32                  # rows a butterfly step holds of each group
+_LANES = 128
+#: rows × columns a grid step holds. The scoped VMEM asked for is the in
+#: and out tiles double-buffered (4 × 16 MiB at 16384 × 256) plus room for
+#: the per-group temporaries; a v5e core has 128 MiB. On a v5e (PR 39, ms a
+#: pass over 2²⁰ × 1024, the gather of the factor above the block beside it):
+#: 16384 × 128 21.1 + 5.3, 8192 × 256 15.8 + 9.9, 16384 × 256 16.9 + 5.3,
+#: 4096 × 512 14.2 + ≈ 20; a kernel that only copies takes 13.8.
+BLOCK_ROWS = 16384
+TILE_COLS = 256
+_VMEM_SLACK_BYTES = 16 * 1024 * 1024
+
+
+def plan(shape: tuple, dtype, interpret: bool = False):
+    """(block, tile) when the kernel serves a columnwise operand of
+    ``shape`` = (N, m), else None: a TPU (or interpret mode), float32, N a
+    power of two of at least 8 groups, m a multiple of a lane's width."""
+    n, m = shape
+    if not interpret and jax.default_backend() != "tpu":
+        return None
+    if jnp.dtype(dtype) != jnp.float32 or n & (n - 1):
+        return None
+    if n < 8 * GROUP or m % _LANES:
+        return None
+    return min(n, BLOCK_ROWS), TILE_COLS if m % TILE_COLS == 0 else _LANES
+
+
+def _split_dot(hd, x):
+    """hd (bf16, exact) · x (float32) at float32 grade: x = hi + mid + lo."""
+    def dot(part):
+        return jax.lax.dot_general(
+            hd, part, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.DEFAULT,
+            preferred_element_type=jnp.float32)
+
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return dot(hi) + (dot(mid) + dot(lo))
+
+
+def _kernel(groups: int, h_ref, d_ref, a_ref, y_ref):
+    def mix(g, carry):
+        rows = pl.ds(pl.multiple_of(g * GROUP, GROUP), GROUP)
+        hd = (h_ref[...] * d_ref[pl.ds(g, 1), :]).astype(jnp.bfloat16)
+        y_ref[rows, :] = _split_dot(hd, a_ref[rows, :])
+        return carry
+
+    jax.lax.fori_loop(0, groups, mix, 0)
+
+    # the factor over the groups: butterflies of whole (rows × tile) slabs,
+    # two stages a pass where two are left (one load and one store a vreg
+    # for both), _SLAB rows at a time so that a quad's sixteen vregs and
+    # their sums stay in registers
+    subs = GROUP // _SLAB
+    h = 1
+    while h < groups:
+        radix = 4 if 2 * h < groups else 2
+
+        def fly(i, carry, h=h, radix=radix):
+            t, sub = i // subs, i % subs
+            g0 = (t // h) * (radix * h) + t % h
+
+            def at(k):
+                return pl.ds(pl.multiple_of(
+                    (g0 + k * h) * GROUP + sub * _SLAB, _SLAB), _SLAB)
+
+            x = [y_ref[at(k), :] for k in range(radix)]
+            if radix == 2:
+                y_ref[at(0), :] = x[0] + x[1]
+                y_ref[at(1), :] = x[0] - x[1]
+                return carry
+            s0, d0, s1, d1 = x[0] + x[1], x[0] - x[1], x[2] + x[3], x[2] - x[3]
+            y_ref[at(0), :] = s0 + s1
+            y_ref[at(1), :] = d0 + d1
+            y_ref[at(2), :] = s0 - s1
+            y_ref[at(3), :] = d0 - d1
+            return carry
+
+        jax.lax.fori_loop(0, groups // radix * subs, fly, 0)
+        h *= radix
+
+
+@functools.partial(jax.jit, static_argnames=("block", "tile", "interpret"))
+def mix_blocks(A, D, *, block: int, tile: int, interpret: bool = False):
+    """H_block · (D ⊙ A) inside each block of ``block`` rows of A (N, m),
+    unnormalized, as a float32 (N, m) array. ``D`` (N,) holds the signs."""
+    n, m = A.shape
+    groups = block // GROUP
+    return pl.pallas_call(
+        functools.partial(_kernel, groups),
+        grid=(n // block, m // tile),
+        in_specs=[
+            pl.BlockSpec((GROUP, GROUP), lambda p, j: (0, 0)),
+            pl.BlockSpec((groups, GROUP), lambda p, j: (p, 0)),
+            pl.BlockSpec((block, tile), lambda p, j: (p, j)),
+        ],
+        out_specs=pl.BlockSpec((block, tile), lambda p, j: (p, j)),
+        out_shape=jax.ShapeDtypeStruct((n, m), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=16 * block * tile + _VMEM_SLACK_BYTES),
+        interpret=interpret,
+    )(jnp.asarray(_hadamard_np(GROUP), jnp.float32),
+      D.astype(jnp.float32).reshape(n // GROUP, GROUP), A)

@@ -61,6 +61,11 @@ METRICS: Dict[str, str] = {
     # "pallas_generate" | "xla") — the cross-check of the features that
     # feature_rate.apply reads from the sketch.dispatch spans
     "sketch.features": "counter",
+    # the compiled FJLT/wht apply (sketch/fjlt.py): operand entries sign-
+    # and Hadamard-mixed (transform axis × free axis), by family and kernel
+    # ("pallas_blocks" | "xla_bf16x3" | "xla_f32") — the cross-check of
+    # the elements that mix_rate.apply reads from the sketch.dispatch spans
+    "sketch.mixed_elements": "counter",
     # accesses of an allocation's key material (base/context.py), by
     # result ("hit": kept from an earlier access | "miss": derived now),
     # always on — over a benchmark window every access is a hit (the
@@ -166,7 +171,12 @@ SPANS: Dict[str, Tuple[str, str]] = {
     # feature_rate.apply reads), finisher (what computes the elementwise
     # map: "cos_turns" = sketch/cos_turns.py on the kernel route, "cos" |
     # "exp" = the stock jnp function on the XLA route; for the operator)
-    # and, on the kernel route, m_tile, s_tile and operator_residency
+    # and, on the kernel route, m_tile, s_tile and operator_residency; the
+    # FJLT/wht apply's (sketch/fjlt.py) carries path="fut", family, fut,
+    # kernel ("pallas_blocks" = pallas_wht.mix_blocks then the gather |
+    # "xla_bf16x3" | "xla_f32"), factors (the Kronecker split of the axis,
+    # the sampled outer factor first), elements (= axis × columns mixed,
+    # which mix_rate.apply reads) and sampled (= s × columns kept)
     "sketch.apply": ("sketch kernel", "sketch_host_ms.apply"),
     "sketch.plan": ("sketch kernel", "sketch_plan_ms.apply"),
     "sketch.dispatch": ("sketch kernel", "sketch_dispatch_ms.apply"),

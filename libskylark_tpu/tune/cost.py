@@ -442,10 +442,11 @@ def _sparse_lane_cost(m: int, n: int, s: int, nnz: int, p: Plan,
 def _srht_lane_cost(m: int, n: int, s: int, p: Plan,
                     rates: dict) -> dict:
     """One SRHT serve lane (m kept extent, n pow2 transform extent, s
-    sampled rows). XLA: the panel-free ``fwht_sketch`` lowering — the
-    kron-factored WHT is two HIGHEST matmuls against factors of size
-    ~sqrt(n) each (4·m·n·sqrt(n) flops), the sign diagonal and sample
-    gather ride the VPU. Pallas (sketch/pallas_fwht.py): log-n
+    sampled rows). XLA: the panel-free lowering, priced as a
+    kron-factored WHT of two HIGHEST matmuls against factors of size
+    ~sqrt(n) each (4·m·n·sqrt(n) flops; ``fjlt.srht_serve_apply`` contracts
+    factors of at most 128, fewer flops past n = 16384), the sign
+    diagonal and sample gather ride the VPU. Pallas (sketch/pallas_fwht.py): log-n
     butterfly sweeps fold into one H_128 MXU factor plus the one-hot
     sample gather, all at HIGHEST; the Threefry streams regenerate
     once per m-tile sweep and serialize against the MXU (no pipelined

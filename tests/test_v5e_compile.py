@@ -269,3 +269,54 @@ def test_sparse_rows_kernel_other_shapes(one_chip, s_dim, rows, plan):
         n_rows=rows, s_dim=s_dim, plan=plan,
         interpret=False).compile().as_text()
     assert text.count(KERNEL) == 1
+
+
+# -- the fjlt_apply_cw cell: the Blendenpik sketch's block-mix kernel --------
+
+FJLT_ROWS, FJLT_COLS, FJLT_S = 1 << 20, 1024, 4096
+
+
+def test_cell_shape_fjlt_mix_sample(one_chip):
+    """FJLT(2²⁰, 4096, wht) columnwise of 1,048,576 × 1024 as the one
+    program: the block-mix kernel (16384 × 256 tiles, 80 MiB of the core's
+    VMEM asked for) and the gather of the sampled rows — no workspace beyond
+    the one block-mixed matrix (4 GiB) and a few gathered chunks, no copy of
+    it in another layout."""
+    from libskylark_tpu.sketch import fjlt, pallas_wht
+
+    block, tile = pallas_wht.plan((FJLT_ROWS, FJLT_COLS), jnp.float32,
+                                  interpret=True)
+    assert (block, tile) == (16384, 256)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    program = jax.jit(functools.partial(
+        fjlt.fjlt_mix_sample, s_dim=FJLT_S, rowwise=False,
+        kernel="pallas_blocks", block=block, tile=tile))
+    compiled = program.lower(arg((2,), jnp.uint32),
+                             arg((FJLT_ROWS, FJLT_COLS), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 1
+    operand = FJLT_ROWS * FJLT_COLS * 4
+    assert not re.search(r"f32\[64,16384,1024\]\S* copy\(", text)
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == FJLT_S * FJLT_COLS * 4
+    assert operand <= memory.temp_size_in_bytes < operand + (160 << 20)
+
+
+@pytest.mark.parametrize("rows,cols,plan", [
+    (1 << 13, 8192, (8192, 256)), (1 << 16, 384, (16384, 128)),
+    (1 << 10, 128, (1024, 128))])
+def test_fjlt_block_kernel_other_shapes(one_chip, rows, cols, plan):
+    from libskylark_tpu.sketch import pallas_wht
+
+    assert pallas_wht.plan((rows, cols), jnp.float32, interpret=True) == plan
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = pallas_wht.mix_blocks.lower(
+        arg((rows, cols), jnp.float32), arg((rows,), jnp.float32),
+        block=plan[0], tile=plan[1]).compile().as_text()
+    assert text.count(KERNEL) == 1
