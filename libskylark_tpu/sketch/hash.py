@@ -171,7 +171,8 @@ class HashTransform(SketchTransform):
         with _trace.span("sketch.dispatch",
                          {"path": "sparse", "family": self.sketch_type,
                           "nnz": A.nnz, "nnz_class": int(data.shape[0]),
-                          "lookup": lookup(values), "kernel": kernel}):
+                          "lookup": lookup(values), "kernel": kernel,
+                          "walk": "flat"}):
             out = _sparse_program()(
                 key_data, data, indices, indptr, s_dim=self._S,
                 rowwise=rowwise, shape=A.shape, values=values, kernel=kernel)

@@ -166,7 +166,10 @@ SPANS: Dict[str, Tuple[str, str]] = {
     # nnz and nnz_class, which sparse_nnz_rate.apply reads, and for the
     # operator lookup (sparse_serve.lookup: what is computed at the lane)
     # and kernel (sparse_serve.sparse_kernel: "pallas_rows" | "xla_scatter",
-    # what adds the terms up); the feature maps' (sketch/rft.py) carries
+    # what adds the terms up) and walk ("flat": the rows kernel's body that
+    # visits each (tile, chunk) pair once, pallas_sparse._kernel_rows — a
+    # program older than PR 41 has no such key); the feature maps'
+    # (sketch/rft.py) carries
     # path="features", family, epilogue, kernel, features (= rows × s, which
     # feature_rate.apply reads), finisher (what computes the elementwise
     # map: "cos_turns" = sketch/cos_turns.py on the kernel route, "cos" |

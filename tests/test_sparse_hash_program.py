@@ -157,7 +157,10 @@ class TestAgainstTheOracles:
                                   "nnz_class": bucket.lane_class(A.nnz),
                                   "lookup": ("lane" if family is sk.CWT
                                              else "lane+table"),
-                                  "kernel": "xla_scatter"}
+                                  "kernel": "xla_scatter",
+                                  # the rows kernel's body, whichever
+                                  # kernel this apply took (PR 41)
+                                  "walk": "flat"}
         assert counter.value(family=T.sketch_type,
                              kernel="xla_scatter") - before == A.nnz
         # the one enqueue is the engine's call, under the dispatch span
