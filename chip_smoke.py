@@ -298,6 +298,28 @@ def step_sketch() -> None:
     report("sketch.FJLT_wht.columnwise", first, run, shape=f"{N}x{M}->{S}",
            backend=kernel, err=f"{err:.2e}")
 
+    # the FJLT's default mixer, the DCT, at a height that is no power of two
+    # (the least-squares solvers' default sketch): the blocked DFT of
+    # sketch/fut.py in one program, against the dense sampled-cosine operator
+    # built on the host in float64
+    Nd, Sd, Md = (1000, 64, 40) if REHEARSE else (96000, 1024, 384)
+    Fd = sk.FJLT(Nd, Sd, Context(seed=22))
+    Ad = jnp.asarray(rng.standard_normal((Nd, Md), dtype=np.float32))
+    plan = Fd.mix_plan(Ad, False)
+    if plan is None or plan[0] != "xla_dft":
+        raise AssertionError(f"columnwise FJLT(dct) of {Nd} planned {plan}")
+    out, first, run = timed(lambda: Fd.apply(Ad, sk.COLUMNWISE))
+    k = np.asarray(Fd.sample_indices(), np.int64)[:, None]
+    j = np.arange(Nd, dtype=np.int64)[None, :]
+    operator = (np.cos(np.pi * ((k * (2 * j + 1)) % (4 * Nd)) / (2.0 * Nd))
+                * np.asarray(Fd.diagonal(), np.float64)[None, :]
+                * np.sqrt(2.0 / Sd))
+    err = close(out, operator @ np.asarray(Ad, np.float64),
+                "FJLT(dct) columnwise vs dense cosine operator", tol=2e-6)
+    report("sketch.FJLT_dct.columnwise", first, run, shape=f"{Nd}x{Md}->{Sd}",
+           backend=plan[0], factors="x".join(map(str, plan[1])),
+           err=f"{err:.2e}")
+
     # CountSketch, dense operand and the same operand as a SparseMatrix
     C = sk.CWT(N, S, Context(seed=3))
     h = np.asarray(C.bucket_indices())

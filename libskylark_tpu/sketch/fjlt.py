@@ -14,17 +14,32 @@ sqrt(N/S)). Under a sharded input the FUT runs independently per column shard
 input is column-sharded; for row-sharded inputs XLA re-lays out, the analog of
 the reference's [VC,*] → [*,VR] redistribution).
 
-With the Walsh-Hadamard mixer (``fut="wht"``, the SRHT), a float32 operand and
-a power-of-two axis, an apply is ONE compiled program
+An apply of a float32 operand on one device is ONE compiled program
 (``sketch.fjlt_mix_sample``, :func:`fjlt_mix_sample`): D and the sampled
-coordinates generated inside from the allocation's key words, the axis mixed
-in full inside blocks of rows and the Kronecker factor above the blocks taken
-at the sampled rows only — on a TPU, columnwise, the blocks in one Pallas pass
-over the operand (sketch/pallas_wht.py), elsewhere on XLA without a
-transposed copy (sketch/fut.py ``wht_blocks``). Its workspace is at most one
-operand-sized array; the serve tier's lanes (:func:`srht_serve_apply`) are the
-same function on XLA. Every other mixer and dtype, and an operand that lies on
-several devices, keeps the eager composition (``fut.sign_mix_sample``).
+coordinates generated inside from the allocation's key words, the inner
+factors of the transform contracted in full a tile of the free axis at a time
+and the last, outer factor taken at the sampled rows only.
+
+* ``fut="wht"`` (the SRHT), an axis that is a power of two: the axis mixed in
+  full inside blocks of rows and the Kronecker factor above the blocks sampled
+  — on a TPU, columnwise, the blocks in one Pallas pass over the operand
+  (sketch/pallas_wht.py), elsewhere on XLA without a transposed copy
+  (sketch/fut.py ``wht_blocks``); its workspace is at most one operand-sized
+  array, and the serve tier's lanes (:func:`srht_serve_apply`) are the same
+  function on XLA;
+* ``fut="dct"`` (the default, upstream's FFTW mixer) and ``"dht"``, an axis
+  ``fut.dft_factors`` splits — N = R·f1·f2 with f1, f2 ≤ 128 and R ≤ 256, so
+  every N ≤ 2²² with such a split (10⁶ = 100·125·80), no prime factor past
+  256: a tile's rows gathered once into the order the stages contract
+  (``fut.dft_source_rows``), the DFT behind the transform in two dense stages
+  of float32 factors on the MXU (``fut.dft_blocks``, half of its outputs: the
+  input is real) and R rows against their twiddles a sampled output
+  (``fut.sample_outer_dft``); its workspace is a few (N × tile) arrays, never
+  an operand-sized complex one.
+
+Every other height and dtype, and an operand that lies on several devices,
+keeps the eager composition (``fut.sign_mix_sample``: for the DCT ``lax.fft``
+along a moved axis over complex copies of the whole operand).
 """
 
 from __future__ import annotations
@@ -47,7 +62,7 @@ from libskylark_tpu.telemetry import trace as _trace
 
 _MIXED = _metrics.counter(
     "sketch.mixed_elements",
-    "operand entries sign-and-Hadamard mixed by the compiled FJLT apply "
+    "operand entries sign-and-transform mixed by the compiled FJLT apply "
     "(transform axis × free axis), by family and kernel")
 
 #: Free-axis entries the XLA route mixes at a time: its workspace is a few
@@ -60,28 +75,43 @@ MIX_TILE = 128
 #: Rows of the transform axis mixed in full; the factor above them is
 #: computed at the sampled rows only (``fut.sample_outer``).
 MIX_BLOCK = 16384
+#: Free-axis entries the DCT / DHT route transforms at a time: the tile, its
+#: gathered rows and the two stages' results, N × tile float32 each. Wider is
+#: faster here, unlike the Hadamard route: the two gathers cost by the row,
+#: not by the byte (10⁶ × 1024 on a v5e: 200 ms an apply at 128, 155 at 256);
+#: 512 would not leave two resident operands their room.
+DFT_TILE = 256
 
 
-def fjlt_mix_sample(key_data, A, *, s_dim: int, rowwise: bool, kernel: str,
-                    block: int, tile: int):
-    """One FJLT/``wht`` apply as a pure function of the allocation's raw key
-    words ((2,) uint32): ``√(N/s) · (H_N · (D ⊙ A) / √N)[idx]`` along the
-    transform axis (rows columnwise, columns rowwise) of a float32 A.
+def fjlt_mix_sample(key_data, A, *tables, s_dim: int, rowwise: bool,
+                    kernel: str, tile: int, block: int = 0,
+                    fut: str = "wht", factors: tuple = ()):
+    """One FJLT apply as a pure function of the allocation's raw key words
+    ((2,) uint32): ``√(N/s) · (scale·F_N · (D ⊙ A))[idx]`` along the
+    transform axis (rows columnwise, columns rowwise) of a float32 A, for
+    the mixer ``fut``: ``H_N/√N`` (``"wht"``), the unnormalized DCT-II
+    ``/√(2N)`` (``"dct"``) or the DHT ``/√N`` (``"dht"``).
 
     D (sub-stream 0) and the sampled coordinates (sub-stream 1) are the
     positional streams :meth:`FJLT.diagonal` / :meth:`FJLT.sample_indices`
-    read, generated here from the key. H_N = H_a ⊗ H_block: the axis is
-    mixed in full inside blocks of ``block`` rows and the outer factor
-    taken at the s sampled rows only (sketch/fut.py). ``kernel``:
+    read, generated here from the key. ``kernel``:
 
-    * ``"pallas_blocks"`` — columnwise on a TPU: one pass of
-      :func:`pallas_wht.mix_blocks` over the operand writes the
-      block-mixed matrix, the one operand-sized workspace of an apply;
-    * ``"xla_bf16x3"`` | ``"xla_f32"`` — everything else: the free axis
-      walked ``tile`` entries at a time (a rowwise tile transposed by
+    * ``"pallas_blocks"`` — ``wht`` columnwise on a TPU: H_N = H_a ⊗
+      H_block, one pass of :func:`pallas_wht.mix_blocks` over the operand
+      writes the block-mixed matrix, the one operand-sized workspace of an
+      apply, and the outer factor is taken at the s sampled rows only;
+    * ``"xla_bf16x3"`` | ``"xla_f32"`` — ``wht`` everywhere else: the free
+      axis walked ``tile`` entries at a time (a rowwise tile transposed by
       itself), so the workspace is a few (N × tile) temporaries; the
       Hadamard factors contract as exact bfloat16 against a three-way split
-      operand on a TPU, in float32 off it.
+      operand on a TPU, in float32 off it;
+    * ``"xla_dft"`` — ``dct`` | ``dht`` over ``factors`` = (R, f1, f2) of
+      ``fut.dft_factors``: the same walk of the free axis, a tile's rows
+      gathered from the operand in the stages' order, its DFT in two dense
+      stages with float32 on both sides and the outer factor R at the
+      sampled outputs (sketch/fut.py). ``tables`` are
+      ``fut.dft_tables(factors)`` on the operand's device; a caller that
+      leaves them out (a trace) gets them as constants of its program.
     """
     n = A.shape[1] if rowwise else A.shape[0]
     m = A.shape[0] if rowwise else A.shape[1]
@@ -100,9 +130,21 @@ def fjlt_mix_sample(key_data, A, *, s_dim: int, rowwise: bool, kernel: str,
         Y = pallas_wht.mix_blocks(A, D, block=block, tile=tile)
         return scale * _fut.sample_outer(Y, idx, block)
 
-    def mixed(X):                                   # X (N, w) → (s, w)
-        Y = _fut.wht_blocks(D[:, None] * X, block, kernel == "xla_bf16x3")
-        return scale * _fut.sample_outer(Y, idx, block)
+    if kernel == "xla_dft":
+        tables = tables or _fut.dft_tables(factors)
+        scale /= math.sqrt(2.0) if fut == "dct" else 1.0
+        source = _fut.dft_source_rows(n, factors[1], fut)
+        signs = D[source][:, None]
+
+        def mixed(X):                               # X (N, w) → (s, w)
+            # whole rows of the tile, gathered (a gather of row pieces from
+            # the operand itself runs a row at a time: 19.6 s an apply)
+            Z = _fut.dft_blocks(signs * X[source], factors, tables)
+            return _fut.sample_outer_dft(Z, idx, n, factors, fut, scale)
+    else:
+        def mixed(X):
+            Y = _fut.wht_blocks(D[:, None] * X, block, kernel == "xla_bf16x3")
+            return scale * _fut.sample_outer(Y, idx, block)
 
     def columns(lo, w):
         """The sketch of free-axis entries [lo, lo + w), in A's layout."""
@@ -134,15 +176,25 @@ def _mix_program():
 
     return compiled(fjlt_mix_sample, name="sketch.fjlt_mix_sample",
                     static_argnames=("s_dim", "rowwise", "kernel", "block",
-                                     "tile"))
+                                     "tile", "fut", "factors"))
+
+
+@functools.lru_cache(maxsize=8)
+def _dft_tables_on(factors: tuple, device) -> tuple:
+    """``fut.dft_tables(factors)`` placed on ``device`` once: built at the
+    first operand of that split, an argument of every apply after it."""
+    return tuple(jax.device_put(t, device) for t in _fut.dft_tables(factors))
 
 
 def solver_fut(n: int) -> str:
     """The mixer the least-squares solvers give their FJLT over an axis of
     ``n`` (Blendenpik's choices are WHT, DCT and DHT): the Hadamard one
-    where ``n`` is a power of two — the compiled, memory-bounded program
-    above, at heights whose DCT (``lax.fft`` over complex copies of the
-    operand) no longer fits —, else the DCT, which takes any ``n``."""
+    where ``n`` is a power of two (±1 factors, the Pallas pass), else the
+    DCT, upstream's own, which takes any ``n``. Either is the compiled,
+    memory-bounded program above wherever ``fut.dft_factors(n)`` splits
+    ``n`` (every power of two does; so does a multiple of 1000 up to 2²²
+    with no prime factor past 256); a height it declines is mixed by the
+    eager composition, ``lax.fft`` over complex copies of the operand."""
     return "wht" if n > 0 and not n & (n - 1) else "dct"
 
 
@@ -222,7 +274,13 @@ class RFUT(SketchTransform):
 
 @register
 class FJLT(SketchTransform):
-    """Fast Johnson-Lindenstrauss transform (ref: sketch/FJLT_data.hpp)."""
+    """Fast Johnson-Lindenstrauss transform (ref: sketch/FJLT_data.hpp):
+    ``fut`` = ``"dct"`` (the default, as upstream's FFTW build), ``"dht"``
+    or ``"wht"``. A float32 operand on one device is sketched by one
+    compiled, memory-bounded program at every height N the mixer's rule
+    takes (:meth:`mix_plan`: ``wht`` the powers of two; ``dct`` / ``dht``
+    N = R·f1·f2 with f1, f2 ≤ 128 and R ≤ 256), by the eager composition
+    elsewhere."""
 
     sketch_type = "FJLT"
 
@@ -363,24 +421,33 @@ class FJLT(SketchTransform):
         return (1.0 / math.sqrt(self._S)) * out
 
     def mix_plan(self, A, rowwise: bool):
-        """(kernel, block, tile) of :func:`fjlt_mix_sample` for this
-        operand, from the shapes and the device alone, or None where the
-        eager composition below serves: another mixer than ``wht``, another
-        dtype than float32, an axis that is no power of two, an operand
-        that lies on more than one device (the composition's FUT runs a
-        column shard at a time under XLA's partitioner; the program's tile
-        walk would slice the sharded axis). A traced operand shows no
-        placement and is taken as the dense sketches take it
-        (``dense.pallas_ambient_ok``): the kernel on a process of one
-        device, else the XLA route, which is right under any sharding —
-        but where the caller's operand is in fact sharded along its free
-        axis, the partitioner gathers it whole for the tile walk."""
-        if (self._fut_name != "wht" or A.dtype != jnp.float32
-                or self._N & (self._N - 1)):
+        """(kernel, split, tile) of :func:`fjlt_mix_sample` for this
+        operand, from the shapes and the device alone — ``split`` the rows
+        mixed in full (``wht``) or the factors (R, f1, f2) of the blocked
+        DFT (``dct`` / ``dht``) — or None where the eager composition below
+        serves: another dtype than float32; an axis the mixer's rule
+        declines — ``wht`` takes the powers of two, ``dct`` and ``dht``
+        every N = R·f1·f2 with 2 ≤ f1 ≤ 128, f2 ≤ 128, R ≤ 256
+        (``fut.dft_factors``: to 2²², no prime factor past 256; 1,000,000 =
+        100·125·80) —; an operand that lies on more than one device (the
+        composition's FUT runs a column shard at a time under XLA's
+        partitioner; the program's tile walk would slice the sharded axis).
+        A traced operand shows no placement and is taken as the dense
+        sketches take it (``dense.pallas_ambient_ok``): the kernel on a
+        process of one device, else the XLA route, which is right under any
+        sharding — but where the caller's operand is in fact sharded along
+        its free axis, the partitioner gathers it whole for the tile walk."""
+        if A.dtype != jnp.float32:
             return None
+        hadamard = self._fut_name == "wht"
+        factors = None if hadamard else _fut.dft_factors(self._N)
+        if (self._N & (self._N - 1)) if hadamard else (factors is None):
+            return None                 # a height the mixer's rule declines
         traced = isinstance(A, jax.core.Tracer)
         if not traced and len(A.devices()) > 1:
             return None
+        if not hadamard:
+            return "xla_dft", factors, DFT_TILE
         on_tpu = jax.default_backend() == "tpu"
         if on_tpu and not rowwise and (not traced or jax.device_count() == 1):
             from libskylark_tpu.sketch import pallas_wht    # pulls pallas
@@ -391,26 +458,33 @@ class FJLT(SketchTransform):
         return _xla_plan(self._N, A.dtype)
 
     def _mix_sample(self, A, rowwise: bool):
-        """The ``wht`` apply as the one ``sketch.fjlt_mix_sample`` program
-        (under a caller's trace: part of the caller's program); None where
+        """The apply as the one ``sketch.fjlt_mix_sample`` program (under a
+        caller's trace: part of the caller's program); None where
         :meth:`mix_plan` declines."""
         plan = self.mix_plan(A, rowwise)
         if plan is None:
             return None
-        kernel, block, tile = plan
+        kernel, split, tile = plan
         statics = dict(s_dim=self._S, rowwise=rowwise, kernel=kernel,
-                       block=block, tile=tile)
+                       tile=tile)
+        if kernel == "xla_dft":
+            statics.update(fut=self._fut_name, factors=split)
+            factors = split
+        else:
+            statics.update(block=split)
+            factors = (self._N // split,) + _fut.block_factors(split)
         key_data = self._alloc.key_data
         if isinstance(A, jax.core.Tracer):
             return fjlt_mix_sample(key_data, A, **statics)
+        tables = (_dft_tables_on(split, next(iter(A.devices())))
+                  if kernel == "xla_dft" else ())
         columns = A.shape[0] if rowwise else A.shape[1]
-        factors = (self._N // block,) + _fut.block_factors(block)
         attrs = {"path": "fut", "family": self.sketch_type,
                  "fut": self._fut_name, "kernel": kernel,
                  "factors": factors, "elements": self._N * columns,
                  "sampled": self._S * columns}
         with _trace.span("sketch.dispatch", attrs):
-            out = _mix_program()(key_data, A, **statics)
+            out = _mix_program()(key_data, A, *tables, **statics)
         _MIXED.inc_always(attrs["elements"], family=self.sketch_type,
                           kernel=kernel)
         return out

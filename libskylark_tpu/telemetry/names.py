@@ -177,9 +177,12 @@ SPANS: Dict[str, Tuple[str, str]] = {
     # and, on the kernel route, m_tile, s_tile and operator_residency; the
     # FJLT/wht apply's (sketch/fjlt.py) carries path="fut", family, fut,
     # kernel ("pallas_blocks" = pallas_wht.mix_blocks then the gather |
-    # "xla_bf16x3" | "xla_f32"), factors (the Kronecker split of the axis,
-    # the sampled outer factor first), elements (= axis × columns mixed,
-    # which mix_rate.apply reads) and sampled (= s × columns kept)
+    # "xla_bf16x3" | "xla_f32" for fut="wht"; "xla_dft" = the blocked DFT of
+    # fut.dft_blocks / sample_outer_dft for fut="dct" | "dht"), factors (the
+    # Kronecker split of the axis, for "xla_dft" the split (R, f1, f2) of
+    # fut.dft_factors; the sampled outer factor first), elements (= axis ×
+    # columns mixed, which mix_rate.apply reads) and sampled (= s × columns
+    # kept)
     "sketch.apply": ("sketch kernel", "sketch_host_ms.apply"),
     "sketch.plan": ("sketch kernel", "sketch_plan_ms.apply"),
     "sketch.dispatch": ("sketch kernel", "sketch_dispatch_ms.apply"),

@@ -1,0 +1,409 @@
+"""The FJLT apply with the default mixer (``fut="dct"``; ``"dht"`` beside it)
+as the one compiled mix-and-sample program (``sketch.fjlt_mix_sample`` on the
+``"xla_dft"`` route: sketch/fjlt.py, sketch/fut.py ``dft_factors`` /
+``dft_tables`` / ``dft_source_rows`` / ``dft_blocks`` / ``sample_outer_dft``),
+on the CPU:
+
+- the blocked transform against the cosine sum of the definition and against
+  ``fut.dct`` / ``fut.dht`` — heights of one, two and three factors, factors
+  that are no multiple of 8, a height the rule declines;
+- the sampled outer factor against the full transform then a gather;
+- *plain reference*: ``cellbench/references/dct_fjlt.py`` (imports nothing of
+  the program; a float64 DCT on the host) — both orientations, ragged free
+  extents, under a caller's ``jit``, and the eager composition
+  ``fut.sign_mix_sample`` beside it;
+- one program, no recompile, the span's attributes and the counter, the
+  solvers' programs, the sampled coordinates on a span that is no power of two.
+"""
+
+import json
+import math
+import pathlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from cellbench.references import dct_fjlt as reference
+from libskylark_tpu import sketch as sk
+from libskylark_tpu.base import randgen, threefry
+from libskylark_tpu.base.context import Context
+from libskylark_tpu.sketch import fjlt, fut
+
+# the configuration's limit
+REL_MAX = json.loads((pathlib.Path(__file__).parent.parent / "cellbench/configs"
+                      / "fjlt_blendenpik_dct_m1000000_n1024.json").read_text()
+                     )["limits"]["rel_max"]
+
+
+def _operand(n, m, seed=0):
+    return jnp.asarray(np.random.default_rng(seed).standard_normal((n, m)),
+                       jnp.float32)
+
+
+def _rel(got, ref):
+    got, ref = np.asarray(got, np.float64), np.asarray(ref, np.float64)
+    return float(np.max(np.abs(got - ref)) / np.max(np.abs(ref)))
+
+
+def _definition(x, mixer):
+    """The transform of the columns of x (n, w) by its sum, float64, the
+    phase reduced in int64 before the cosine."""
+    n = x.shape[0]
+    k = np.arange(n, dtype=np.int64)[:, None]
+    j = np.arange(n, dtype=np.int64)[None, :]
+    if mixer == "dct":                   # FFTW REDFT10
+        C = 2.0 * np.cos(np.pi * ((k * (2 * j + 1)) % (4 * n)) / (2.0 * n))
+    else:                                # cas(2πjk/n)
+        t = 2.0 * np.pi * ((k * j) % n) / n
+        C = np.cos(t) + np.sin(t)
+    return C @ np.asarray(x, np.float64)
+
+
+def _blocked(X, factors, mixer, idx=None):
+    n = X.shape[0]
+    idx = jnp.arange(n, dtype=jnp.int32) if idx is None else idx
+    source = fut.dft_source_rows(n, factors[1], mixer)
+    Z = fut.dft_blocks(X[source], factors, fut.dft_tables(factors))
+    return fut.sample_outer_dft(Z, idx, n, factors, mixer, 1.0)
+
+
+# -- the rule ---------------------------------------------------------------
+
+
+@pytest.mark.parametrize("n,factors", [
+    (1_000_000, (100, 125, 80)), (1000, (10, 20, 5)), (999, (9, 37, 3)),
+    (1 << 20, (128, 128, 64)), (1 << 22, (256, 128, 128)), (2, (1, 2, 1)),
+    (1009, None), (2 * 521, None), (3, (1, 3, 1)), ((1 << 22) + 2, None)])
+def test_dft_factors(n, factors):
+    assert fut.dft_factors(n) == factors
+    if factors:
+        r, f1, f2 = factors
+        assert r * f1 * f2 == n and 2 <= f1 <= 128 and f2 <= 128 and r <= 256
+        assert r * n < 1 << 31 and 32 * n < 1 << 31     # the int32 phases
+
+
+def test_dft_tables_are_the_dft_factors_and_cached():
+    F1, T2 = fut.dft_tables((3, 6, 5))
+    assert F1.shape == (8, 6) and T2.shape == (4, 10, 10)
+    assert F1.dtype == T2.dtype == np.float32
+    k, a = np.arange(4)[:, None], np.arange(6)[None, :]
+    np.testing.assert_allclose(F1[0::2], np.cos(2 * np.pi * k * a / 6), atol=1e-7)
+    np.testing.assert_allclose(F1[1::2], -np.sin(2 * np.pi * k * a / 6), atol=1e-7)
+    # κ1 = 0: no twiddle, the plain DFT_5 as [[re, −im], [im, re]]
+    q = np.arange(5)
+    W = np.exp(-2j * np.pi * np.outer(q, q) / 5)
+    np.testing.assert_allclose(T2[0, :5, :5], W.real, atol=1e-7)
+    np.testing.assert_allclose(T2[0, :5, 5:], -W.imag, atol=1e-7)
+    np.testing.assert_allclose(T2[0, 5:, :5], W.imag, atol=1e-7)
+    # κ1 = 2: the twiddle ω_30^{2b} folded in
+    tw = W * np.exp(-2j * np.pi * 2 * q / 30)[None, :]
+    np.testing.assert_allclose(T2[2, 5:, 5:], tw.real, atol=1e-7)
+    assert fut.dft_tables((3, 6, 5))[0] is F1
+    assert len(fut.dft_tables((4, 6, 1))) == 1
+
+
+@pytest.mark.parametrize("n,f1", [(12, 4), (15, 3), (1000, 50)])
+def test_source_rows_are_makhouls_order_with_the_stage_digit_last(n, f1):
+    x = np.arange(n)
+    v = np.concatenate([x[::2], x[1::2][::-1]])          # Makhoul's order
+    want = v.reshape(f1, n // f1).T.reshape(-1)          # [(b, r), a]
+    assert np.array_equal(np.asarray(fut.dft_source_rows(n, f1, "dct")), want)
+    plain = x.reshape(f1, n // f1).T.reshape(-1)
+    assert np.array_equal(np.asarray(fut.dft_source_rows(n, f1, "dht")), plain)
+
+
+def test_cis_turns_to_an_ulp_whatever_the_period():
+    for period in (7, 4_000_000, 4 * (1 << 22)):
+        p = np.unique(np.concatenate([
+            np.arange(0, min(period, 64)), np.arange(max(0, period - 64), period),
+            np.random.default_rng(period).integers(0, period, 4096),
+            (np.arange(9) * period) // 8 % period]))
+        cos, sin = fut._cis_turns(jnp.asarray(p, jnp.int32), period)
+        t = 2.0 * np.pi * p.astype(np.float64) / period
+        assert np.max(np.abs(np.asarray(cos, np.float64) - np.cos(t))) < 1.5e-7
+        assert np.max(np.abs(np.asarray(sin, np.float64) - np.sin(t))) < 1.5e-7
+
+
+# -- the blocked transform ----------------------------------------------------
+
+
+@pytest.mark.parametrize("n,factors", [
+    (1000, None),                # the rule's own split
+    (1000, (20, 50, 1)),         # one inner stage
+    (1000, (10, 10, 10)),        # three factors
+    (999, (3, 9, 37)),           # odd, no factor a multiple of 8
+    (1155, (7, 15, 11)),
+    (2058, (6, 7, 49)),
+    (1024, (4, 16, 16)),
+    (250, (1, 125, 2)),          # no outer factor: every output's own row
+    (12, None)])
+@pytest.mark.parametrize("mixer", ["dct", "dht"])
+def test_blocked_transform_against_the_definition_and_the_eager_one(
+        n, factors, mixer):
+    factors = factors or fut.dft_factors(n)
+    X = _operand(n, 5, n)
+    got = _blocked(X, factors, mixer)
+    assert got.shape == (n, 5)
+    assert _rel(got, _definition(X, mixer)) < 6e-7
+    eager = fut.dct(X, 0) if mixer == "dct" else fut.dht(X, 0)
+    assert _rel(got, eager) < 2e-6
+
+
+@pytest.mark.parametrize("n,factors", [(1000, (8, 5, 25)), (1536, (24, 8, 8)),
+                                       (2000, (250, 8, 1))])
+def test_sampled_outer_factor_against_full_transform_then_gather(n, factors):
+    X = _operand(n, 9, 3)
+    idx = jnp.asarray(np.random.default_rng(n).integers(0, n, 300), jnp.int32)
+    idx = idx.at[:4].set(jnp.asarray([0, n - 1, n // 2, 1]))
+    full = np.asarray(_blocked(X, factors, "dct"))
+    sampled = np.asarray(_blocked(X, factors, "dct", idx))
+    np.testing.assert_allclose(sampled, full[np.asarray(idx)], rtol=0, atol=1e-5)
+    # the mirrored half (κ1 > f1/2) is read through the conjugate
+    r, f1, f2 = factors
+    mirrored = np.asarray(idx) % (f1 * f2) % f1 > f1 // 2
+    assert mirrored.any() and not mirrored.all()
+
+
+def test_the_gathered_rows_are_held_a_chunk_at_a_time(monkeypatch):
+    n, factors = 1000, (8, 5, 25)
+    X = _operand(n, 40, 4)
+    idx = jnp.asarray(np.random.default_rng(1).integers(0, n, 77), jnp.int32)
+    whole = _blocked(X, factors, "dct", idx)
+    monkeypatch.setattr(fut, "_SAMPLE_CHUNK_BYTES", 8 * 2 * 8 * 40 * 4)
+    # the same rows against the same weights; the sum's order is the chunk's
+    assert _rel(_blocked(X, factors, "dct", idx), whole) < 1e-6
+
+
+# -- the program --------------------------------------------------------------
+
+
+SHAPES = [(1000, 64), (1000, 256), (3000, 512), (999, 64), (6000, 6000)]
+
+
+@pytest.mark.parametrize("n,s", SHAPES)
+@pytest.mark.parametrize("m", [37, 130])
+def test_program_against_the_plain_reference_both_orientations(n, s, m):
+    seed = n + s + m
+    A = _operand(n, m, seed)
+    T = sk.FJLT(n, s, Context(seed))                    # the default mixer
+    assert T.mix_plan(A, False)[0] == "xla_dft"
+    D, idx = reference.streams(seed, 0, n, s)
+    ref = reference.apply_cols(A, D, idx)
+    assert ref.shape == (s, m)
+    assert _rel(T.apply(A, sk.COLUMNWISE), ref) < REL_MAX
+    assert _rel(T.apply(A.T, sk.ROWWISE).T, ref) < REL_MAX
+    if s == n:
+        assert len(np.unique(np.asarray(idx))) < s      # repeats among them
+
+
+@pytest.mark.parametrize("mixer", ["dct", "dht"])
+@pytest.mark.parametrize("n", [1000, 1155, 1 << 10])
+def test_program_agrees_with_the_eager_composition(mixer, n):
+    s, m = 128, 2 * fjlt.DFT_TILE + 20         # two tiles and a ragged rest
+    A = _operand(n, m, n)
+    T = sk.FJLT(n, s, Context(n), fut=mixer)
+    eager = fut.sign_mix_sample(
+        T._fut.apply, A, T.diagonal(), T.sample_indices(), T._fut.scale(),
+        math.sqrt(n / s), 0)
+    assert _rel(T.apply(A, sk.COLUMNWISE), eager) < 2e-6
+    assert _rel(T.apply(A.T, sk.ROWWISE).T, eager) < 2e-6
+
+
+def test_three_factors_through_the_program():
+    n, s, m = 1000, 96, 140
+    A = _operand(n, m, 2)
+    T = sk.FJLT(n, s, Context(4))
+    D, idx = reference.streams(4, 0, n, s)
+    ref = reference.apply_cols(A, D, idx)
+    for factors in ((10, 10, 10), (8, 5, 25), (1, 20, 50)):
+        got = fjlt.fjlt_mix_sample(
+            T.allocation.key_data, A, s_dim=s, rowwise=False, kernel="xla_dft",
+            tile=64, fut="dct", factors=factors)
+        assert _rel(got, ref) < REL_MAX
+
+
+def test_bf16_table_control_fails_the_configurations_rel_max():
+    """A cosine table rounded to bfloat16 — what a DFT factor contracted in
+    one bfloat16 pass would serve — and an operand cut to two of its three
+    bfloat16 parts are refused by the limit the program passes."""
+    n, s = 3000, 256
+    A = _operand(n, 32, 9)
+    T = sk.FJLT(n, s, Context(21))
+    D, idx = reference.streams(21, 0, n, s)
+    ref = reference.apply_cols(A, D, idx)
+    assert _rel(T.apply(A, sk.COLUMNWISE), ref) < REL_MAX
+    table = reference.cosine_sum_cols(A, D, idx, "highest", "bf16")
+    assert _rel(table, ref) > 100 * REL_MAX
+    assert _rel(reference.apply_cols(A, D, idx, "bf16x2"), ref) > 1.5 * REL_MAX
+
+
+def test_a_height_the_rule_declines_and_other_dtypes_keep_the_eager_route():
+    A = _operand(1009, 8, 1)                            # a prime past 256
+    T = sk.FJLT(1009, 64, Context(1))
+    assert T.mix_plan(A, False) is None
+    program = fjlt._mix_program()
+    before = program.stats.executions
+    out = T.apply(A, sk.COLUMNWISE)
+    assert program.stats.executions == before and out.shape == (64, 8)
+    D, idx = reference.streams(1, 0, 1009, 64)
+    assert _rel(out, reference.apply_cols(A, D, idx)) < 5e-6
+    U = sk.FJLT(1000, 64, Context(1))
+    B = _operand(1000, 8, 2)
+    assert U.mix_plan(B.astype(jnp.bfloat16), False) is None
+    assert U.mix_plan(B, True) == ("xla_dft", (10, 20, 5), fjlt.DFT_TILE)
+    low = U.apply(B.astype(jnp.bfloat16), sk.COLUMNWISE)
+    assert low.dtype == jnp.bfloat16 and low.shape == (64, 8)
+
+
+def test_an_operand_on_several_devices_keeps_the_eager_composition():
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    devices = jax.devices()
+    if len(devices) < 2:
+        pytest.skip("needs two devices")
+    n, s = 1000, 128
+    A = _operand(n, 16, 5)
+    T = sk.FJLT(n, s, Context(15))
+    sharded = jax.device_put(
+        A, NamedSharding(Mesh(np.asarray(devices[:2]), ("c",)), P(None, "c")))
+    assert T.mix_plan(sharded, False) is None
+    assert T.mix_plan(A, False) is not None
+    program = fjlt._mix_program()
+    before = program.stats.executions
+    out = T.apply(sharded, sk.COLUMNWISE)
+    assert program.stats.executions == before
+    assert _rel(out, T.apply(A, sk.COLUMNWISE)) < 2e-6
+    # an operand that lies on another device than the first: its own tables
+    moved = jax.device_put(A, devices[1])
+    assert np.array_equal(np.asarray(T.apply(moved, sk.COLUMNWISE)),
+                          np.asarray(T.apply(A, sk.COLUMNWISE)))
+
+
+def test_under_a_callers_jit_it_is_part_of_the_callers_program():
+    n, s = 1000, 128
+    A = _operand(n, 16, 2)
+    T = sk.FJLT(n, s, Context(6))
+    program = fjlt._mix_program()
+    before = program.stats.executions
+    inside = jax.jit(lambda x: T.apply(x, sk.COLUMNWISE))(A)
+    assert program.stats.executions == before
+    assert _rel(inside, T.apply(A, sk.COLUMNWISE)) < 1e-6
+    assert program.stats.executions == before + 1
+
+
+def test_one_program_an_apply_and_no_recompile_on_the_second():
+    from libskylark_tpu import engine
+
+    n, s = 3000, 256
+    A, B = _operand(n, 40, 1), _operand(n, 40, 2)
+    T = sk.FJLT(n, s, Context(8))
+    T.apply(A, sk.COLUMNWISE).block_until_ready()
+    program = fjlt._mix_program()
+    compiles, ran = engine.stats().compiles, program.stats.executions
+    tables = fjlt._dft_tables_on(fut.dft_factors(n), next(iter(A.devices())))
+    # another transform of the shape, another operand: the key is an argument,
+    # the tables the same device arrays
+    U = sk.FJLT(n, s, Context(9))
+    U.apply(B, sk.COLUMNWISE).block_until_ready()
+    T.apply(B, sk.COLUMNWISE).block_until_ready()
+    assert engine.stats().compiles == compiles
+    assert program.stats.executions == ran + 2
+    assert fjlt._dft_tables_on(fut.dft_factors(n), next(iter(A.devices())))[0] is tables[0]
+
+
+def test_span_attributes_and_the_counter():
+    from libskylark_tpu import telemetry
+    from libskylark_tpu.telemetry import metrics, trace
+
+    n, s, m = 6000, 128, 24
+    A = _operand(n, m, 1)
+    T = sk.FJLT(n, s, Context(3))
+    factors = fut.dft_factors(n)
+    before_enabled = metrics._ENABLED
+    counted = fjlt._MIXED.value(family="FJLT", kernel="xla_dft")
+    trace.clear_finished()
+    telemetry.set_enabled(True)
+    try:
+        T.apply(A, sk.COLUMNWISE).block_until_ready()
+        spans = {sp.name: sp for sp in trace.finished_spans()}
+    finally:
+        metrics._ENABLED = before_enabled
+        trace.clear_finished()
+    dispatch, apply = spans["sketch.dispatch"], spans["sketch.apply"]
+    assert dispatch.parent_id == apply.span_id
+    assert dispatch.attrs == {
+        "path": "fut", "family": "FJLT", "fut": "dct", "kernel": "xla_dft",
+        "factors": factors, "elements": n * m, "sampled": s * m}
+    assert factors[0] * factors[1] * factors[2] == n
+    assert "sketch.plan" not in spans
+    assert fjlt._MIXED.value(family="FJLT", kernel="xla_dft") == counted + n * m
+
+
+def test_the_kernel_name_is_declared():
+    import inspect
+
+    from libskylark_tpu.telemetry import names
+
+    assert names.METRICS["sketch.mixed_elements"] == "counter"
+    assert '"xla_dft"' in inspect.getsource(names)
+
+
+# -- the solvers that send it -------------------------------------------------
+
+
+@pytest.mark.parametrize("solver", ["fast", "approximate"])
+def test_the_solvers_run_the_program_inside_theirs(solver, monkeypatch):
+    from libskylark_tpu.nla import least_squares
+
+    calls = []
+    inner = fjlt.fjlt_mix_sample
+
+    def counted(key_data, A, *tables, **statics):
+        calls.append((A.shape, statics["kernel"], statics["fut"], tables))
+        return inner(key_data, A, *tables, **statics)
+
+    monkeypatch.setattr(fjlt, "fjlt_mix_sample", counted)
+    m, n = 5000, 12                         # no power of two: the DCT
+    rng = np.random.default_rng(3)
+    A = _operand(m, n, 10)
+    x = jnp.asarray(rng.standard_normal((n, 2)), jnp.float32)
+    B = A @ x + 1e-3 * _operand(m, 2, 11)
+    exact = jnp.linalg.lstsq(A, B)[0]
+    if solver == "fast":
+        X, iters = least_squares.fast_least_squares(A, B, Context(31))
+        assert int(iters) > 0
+        assert _rel(X, exact) < 1e-4
+        assert calls == [((m, n), "xla_dft", "dct", ())]
+    else:
+        X = least_squares.approximate_least_squares(A, B, Context(32))
+        assert _rel(X, exact) < 5e-2
+        assert calls == [((m, n), "xla_dft", "dct", ()),
+                         ((m, 2), "xla_dft", "dct", ())]
+
+
+# -- the sample indices' draw -------------------------------------------------
+
+
+def test_the_programs_samples_on_a_span_that_is_no_power_of_two():
+    """N = 1,000,000: 2³² mod N ≠ 0, so the high word of each draw counts
+    (``UniformInt``'s other path); the coordinates the program reads are the
+    plain reference's, and a wrong multiplier would not be."""
+    n, s = 1_000_000, 4096 + 100            # past one chunk of the stream
+    assert threefry.randint_multiplier(n) == (1 << 32) % n != 0
+    dist = randgen.UniformInt(0, n - 1)
+    assert dist.live_draws() != (1,)
+    T = sk.FJLT(n, s, Context(77))
+    D, idx = reference.streams(77, 0, n, s)
+    got = np.asarray(T.sample_indices())
+    assert np.array_equal(got, np.asarray(idx))
+    assert np.array_equal(np.asarray(T.diagonal()), np.asarray(D))
+    assert got.min() >= 0 and got.max() < n and got.max() > n - n // 16
+    # what the compiled program draws from the key words is the same stream
+    key = jax.random.wrap_key_data(threefry.fold_in(T.allocation.key_data, 1))
+    inside = randgen.stream_slice(key, dist, 0, s, dtype=jnp.int32)
+    assert np.array_equal(np.asarray(inside), got)
+    # low word alone (the power-of-two shortcut) is another stream
+    low_only = np.asarray(reference.streams(77, 0, 1 << 20, s)[1])
+    assert not np.array_equal(low_only % n, got)

@@ -216,17 +216,29 @@ def test_kernel_route_through_apply(interpreted):
 
 
 def test_other_mixers_dtypes_and_axes_keep_the_eager_route():
+    """Each mixer's own rule: ``wht`` the powers of two, ``dct`` / ``dht``
+    the heights ``fut.dft_factors`` splits (tests/test_fjlt_dct_program.py);
+    past it, and for another dtype, the eager composition."""
     A = _operand(1 << 10, 8, 1)
-    assert sk.FJLT(1 << 10, 64, Context(1), fut="dct").mix_plan(A, False) is None
-    assert sk.FJLT(1 << 10, 64, Context(1), fut="dht").mix_plan(A, False) is None
+    for name in ("dct", "dht"):           # served since the blocked DFT
+        assert sk.FJLT(1 << 10, 64, Context(1), fut=name).mix_plan(A, False) == (
+            "xla_dft", fut.dft_factors(1 << 10), fjlt.DFT_TILE)
     T = sk.FJLT(1 << 10, 64, Context(1), fut="wht")
     assert T.mix_plan(A.astype(jnp.bfloat16), False) is None
     assert T.mix_plan(A, False) == ("xla_f32", 1 << 10, fjlt.MIX_TILE)
     assert fjlt._xla_plan(1 << 20, jnp.float32) == (
         "xla_f32", fjlt.MIX_BLOCK, fjlt.MIX_TILE)
-    for name in ("dct", "dht"):
-        R = sk.FJLT(1000, 64, Context(1), fut=name)
-        assert R.apply(_operand(1000, 8), sk.COLUMNWISE).shape == (64, 8)
+    program = fjlt._mix_program()
+    for name in ("dct", "dht"):           # 1009 is prime: no split, eager
+        R = sk.FJLT(1009, 64, Context(1), fut=name)
+        assert fut.dft_factors(1009) is None
+        assert R.mix_plan(_operand(1009, 8), False) is None
+        before = program.stats.executions
+        assert R.apply(_operand(1009, 8), sk.COLUMNWISE).shape == (64, 8)
+        assert program.stats.executions == before
+    with pytest.raises(ValueError):       # the Hadamard mixer has no such height
+        sk.FJLT(1000, 64, Context(1), fut="wht").apply(
+            _operand(1000, 8), sk.COLUMNWISE)
     low = T.apply(A.astype(jnp.bfloat16), sk.COLUMNWISE)
     assert low.dtype == jnp.bfloat16 and low.shape == (64, 8)
 
@@ -294,11 +306,15 @@ def test_the_least_squares_solvers_mix_a_power_of_two_height_with_hadamard():
     from libskylark_tpu.algorithms import regression
 
     assert fjlt.solver_fut(1 << 20) == "wht" and fjlt.solver_fut(1000) == "dct"
+    assert fjlt.solver_fut(1_000_000) == "dct"
     params = regression.AcceleratedParams()
     assert regression._accel_transform(
         1 << 10, 8, Context(1), params)._fut_name == "wht"
-    assert regression._accel_transform(
-        1000, 8, Context(1), params)._fut_name == "dct"
+    other = regression._accel_transform(1000, 8, Context(1), params)
+    assert other._fut_name == "dct"
+    # either mixer is the one compiled program at a height its rule takes
+    assert other.mix_plan(_operand(1000, 8), False)[0] == "xla_dft"
+    assert fut.dft_factors(1_000_000) == (100, 125, 80)
 
 
 def _spans_of(call):

@@ -320,3 +320,40 @@ def test_fjlt_block_kernel_other_shapes(one_chip, rows, cols, plan):
         arg((rows, cols), jnp.float32), arg((rows,), jnp.float32),
         block=plan[0], tile=plan[1]).compile().as_text()
     assert text.count(KERNEL) == 1
+
+
+# -- the fjlt_dct_apply_cw cell: the Blendenpik sketch with the DCT ------------
+
+DCT_ROWS = 1_000_000
+
+
+def test_cell_shape_fjlt_dct_mix_sample(one_chip):
+    """FJLT(1,000,000, 4096) — the default mixer — columnwise of 1,000,000 ×
+    1024 as the one program: the blocked DFT on XLA (no Mosaic call), a tile
+    of 256 columns at a time. Beside the operand and the result it holds a
+    few (N × tile) float32 arrays — under 5 GB of the ≈ 7 that two resident
+    operands leave of the chip — and never an operand-sized or a complex
+    one."""
+    from libskylark_tpu.sketch import fjlt, fut
+
+    factors = fut.dft_factors(DCT_ROWS)
+    assert factors == (100, 125, 80) and fjlt.DFT_TILE == 256
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    program = jax.jit(functools.partial(
+        fjlt.fjlt_mix_sample, s_dim=FJLT_S, rowwise=False, kernel="xla_dft",
+        tile=fjlt.DFT_TILE, fut="dct", factors=factors))
+    tables = fut.dft_tables(factors)
+    compiled = program.lower(
+        arg((2,), jnp.uint32), arg((DCT_ROWS, FJLT_COLS), jnp.float32),
+        *[arg(t.shape, jnp.float32) for t in tables]).compile()
+    text = compiled.as_text()
+    assert KERNEL not in text
+    assert not re.search(r"\bc64\[", text)                # no complex array
+    assert not re.search(r"f32\[1000000,1024\]\S* (copy|fusion)\(", text)
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == FJLT_S * FJLT_COLS * 4
+    tile_bytes = DCT_ROWS * fjlt.DFT_TILE * 4
+    assert 2 * tile_bytes < memory.temp_size_in_bytes < 5 * tile_bytes < 5 << 30
