@@ -301,7 +301,10 @@ def step_sketch() -> None:
     # the FJLT's default mixer, the DCT, at a height that is no power of two
     # (the least-squares solvers' default sketch): the blocked DFT of
     # sketch/fut.py in one program, against the dense sampled-cosine operator
-    # built on the host in float64
+    # built on the host in float64. 96000 = 40·75·32: stage one's 75 rows a
+    # slab are padded to 80 and its 38 outputs to 40 (whole 8-row tiles),
+    # a 33rd block of slabs keeps the gathered rows off whole index tiles
+    # (fut.dft_pads), and 384 columns are one tile (fjlt.dft_tile: 512)
     Nd, Sd, Md = (1000, 64, 40) if REHEARSE else (96000, 1024, 384)
     Fd = sk.FJLT(Nd, Sd, Context(seed=22))
     Ad = jnp.asarray(rng.standard_normal((Nd, Md), dtype=np.float32))
@@ -317,7 +320,7 @@ def step_sketch() -> None:
     err = close(out, operator @ np.asarray(Ad, np.float64),
                 "FJLT(dct) columnwise vs dense cosine operator", tol=2e-6)
     report("sketch.FJLT_dct.columnwise", first, run, shape=f"{Nd}x{Md}->{Sd}",
-           backend=plan[0], factors="x".join(map(str, plan[1])),
+           backend=plan[0], factors="x".join(map(str, plan[1])), tile=plan[2],
            err=f"{err:.2e}")
 
     # CountSketch, dense operand and the same operand as a SparseMatrix

@@ -30,12 +30,15 @@ and the last, outer factor taken at the sampled rows only.
 * ``fut="dct"`` (the default, upstream's FFTW mixer) and ``"dht"``, an axis
   ``fut.dft_factors`` splits — N = R·f1·f2 with f1, f2 ≤ 128 and R ≤ 256, so
   every N ≤ 2²² with such a split (10⁶ = 100·125·80), no prime factor past
-  256: a tile's rows gathered once into the order the stages contract
+  256: a tile of the free axis (:func:`dft_tile` of N) sliced with the signs,
+  its rows gathered once into the order the stages contract
   (``fut.dft_source_rows``), the DFT behind the transform in two dense stages
   of float32 factors on the MXU (``fut.dft_blocks``, half of its outputs: the
   input is real) and R rows against their twiddles a sampled output
-  (``fut.sample_outer_dft``); its workspace is a few (N × tile) arrays, never
-  an operand-sized complex one.
+  (``fut.sample_outer_dft``); every array between the stages lies on whole
+  (8, 128) tiles (``fut.dft_pads``), so that no pass only moves data, and the
+  workspace is two (N × tile) arrays at a time, never an operand-sized or a
+  complex one.
 
 Every other height and dtype, and an operand that lies on several devices,
 keeps the eager composition (``fut.sign_mix_sample``: for the DCT ``lax.fft``
@@ -75,12 +78,9 @@ MIX_TILE = 128
 #: Rows of the transform axis mixed in full; the factor above them is
 #: computed at the sampled rows only (``fut.sample_outer``).
 MIX_BLOCK = 16384
-#: Free-axis entries the DCT / DHT route transforms at a time: the tile, its
-#: gathered rows and the two stages' results, N × tile float32 each. Wider is
-#: faster here, unlike the Hadamard route: the two gathers cost by the row,
-#: not by the byte (10⁶ × 1024 on a v5e: 200 ms an apply at 128, 155 at 256);
-#: 512 would not leave two resident operands their room.
-DFT_TILE = 256
+#: The widest tile of the DCT / DHT route (:func:`dft_tile`): none wider was
+#: read on the chip at a height where it fits beside two resident operands.
+_DFT_TILE_MAX = 512
 
 
 def fjlt_mix_sample(key_data, A, *tables, s_dim: int, rowwise: bool,
@@ -106,9 +106,9 @@ def fjlt_mix_sample(key_data, A, *tables, s_dim: int, rowwise: bool,
       Hadamard factors contract as exact bfloat16 against a three-way split
       operand on a TPU, in float32 off it;
     * ``"xla_dft"`` — ``dct`` | ``dht`` over ``factors`` = (R, f1, f2) of
-      ``fut.dft_factors``: the same walk of the free axis, a tile's rows
-      gathered from the operand in the stages' order, its DFT in two dense
-      stages with float32 on both sides and the outer factor R at the
+      ``fut.dft_factors``: the same walk of the free axis, a tile sliced
+      with the signs, its rows gathered in the stages' order, its DFT in two
+      dense stages with float32 on both sides and the outer factor R at the
       sampled outputs (sketch/fut.py). ``tables`` are
       ``fut.dft_tables(factors)`` on the operand's device; a caller that
       leaves them out (a trace) gets them as constants of its program.
@@ -133,13 +133,15 @@ def fjlt_mix_sample(key_data, A, *tables, s_dim: int, rowwise: bool,
     if kernel == "xla_dft":
         tables = tables or _fut.dft_tables(factors)
         scale /= math.sqrt(2.0) if fut == "dct" else 1.0
-        source = _fut.dft_source_rows(n, factors[1], fut)
-        signs = D[source][:, None]
+        source = _fut.dft_source_rows(n, factors, fut)
 
         def mixed(X):                               # X (N, w) → (s, w)
-            # whole rows of the tile, gathered (a gather of row pieces from
-            # the operand itself runs a row at a time: 19.6 s an apply)
-            Z = _fut.dft_blocks(signs * X[source], factors, tables)
+            # the signs ride in the pass that slices the tile out of the
+            # operand (D[source] is a gather of N scalars at a row's price:
+            # 6.6 ms an apply); then whole rows of the tile, gathered (a
+            # gather of row pieces from the operand itself runs a row at a
+            # time: 19.6 s an apply)
+            Z = _fut.dft_blocks((D[:, None] * X)[source], factors, tables)
             return _fut.sample_outer_dft(Z, idx, n, factors, fut, scale)
     else:
         def mixed(X):
@@ -196,6 +198,29 @@ def solver_fut(n: int) -> str:
     with no prime factor past 256); a height it declines is mixed by the
     eager composition, ``lax.fft`` over complex copies of the operand."""
     return "wht" if n > 0 and not n & (n - 1) else "dct"
+
+
+#: What the DCT / DHT route's temporaries may take of the device beside the
+#: operand and the result, and the most they take an entry of a (N × tile)
+#: tile: two arrays of N × tile float32 with their pads at a time — the
+#: sliced tile and its gathered rows, the gathered rows and stage one's
+#: result, then the two stages' results (v5e compile, ``memory_analysis``:
+#: 8.3 bytes an entry at 10⁶ = 100·125·80, 9.1 at 2²¹ = 128³, where h = 65
+#: is padded to 72).
+_DFT_TEMP_BYTES = 5.0e9
+_DFT_ENTRY_BYTES = 9.1
+
+
+def dft_tile(n: int) -> int:
+    """Free-axis entries the DCT / DHT route transforms at a time over a
+    transform axis of ``n``: the widest multiple of 128, to
+    ``_DFT_TILE_MAX``, whose temporaries stay under ``_DFT_TEMP_BYTES`` —
+    512 to n = 10⁶ (4.26 GB), 256 at 2²¹, 128 at 2²² (4.87 GB each). Wider
+    is faster here, unlike the Hadamard route: the two row gathers cost by
+    the row, not by the byte (10⁶ × 1024 on a v5e: 90.0 ms an apply at 256,
+    80.8 at 384, 74.5 at 512; ``PERF.md`` §5)."""
+    lanes = int(_DFT_TEMP_BYTES / (_DFT_ENTRY_BYTES * n)) // 128
+    return 128 * max(1, min(lanes, _DFT_TILE_MAX // 128))
 
 
 def _xla_plan(n: int, dtype) -> tuple:
@@ -447,7 +472,7 @@ class FJLT(SketchTransform):
         if not traced and len(A.devices()) > 1:
             return None
         if not hadamard:
-            return "xla_dft", factors, DFT_TILE
+            return "xla_dft", factors, dft_tile(self._N)
         on_tpu = jax.default_backend() == "tpu"
         if on_tpu and not rowwise and (not traced or jax.device_count() == 1):
             from libskylark_tpu.sketch import pallas_wht    # pulls pallas
@@ -481,8 +506,8 @@ class FJLT(SketchTransform):
         columns = A.shape[0] if rowwise else A.shape[1]
         attrs = {"path": "fut", "family": self.sketch_type,
                  "fut": self._fut_name, "kernel": kernel,
-                 "factors": factors, "elements": self._N * columns,
-                 "sampled": self._S * columns}
+                 "factors": factors, "tile": tile,
+                 "elements": self._N * columns, "sampled": self._S * columns}
         with _trace.span("sketch.dispatch", attrs):
             out = _mix_program()(key_data, A, *tables, **statics)
         _MIXED.inc_always(attrs["elements"], family=self.sketch_type,

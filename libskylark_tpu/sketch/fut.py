@@ -320,13 +320,21 @@ def sample_outer(Y: jnp.ndarray, idx: jnp.ndarray, block: int) -> jnp.ndarray:
 #
 # A tile's rows come into the stages in ONE row gather
 # (:func:`dft_source_rows`: Makhoul's order and the digit a brought next to
-# the free axis, which is where the MXU contracts — any other place costs a
-# transposed copy of the tile before the stage and one after it), the two
-# inner stages (:func:`dft_blocks`) are one dense contraction each and, v
-# being real, only for κ1 ≤ f1/2 (Z[N1 − k1] = conj Z[k1]): N real numbers
-# in, N out.
+# the free axis, which is where the MXU contracts), the two inner stages
+# (:func:`dft_blocks`) are one dense contraction each and, v being real, only
+# for κ1 ≤ f1/2 (Z[N1 − k1] = conj Z[k1]): N real numbers in, N out.
 # The outer factor, its twiddles and Makhoul's are one dot of 2R real terms
 # a sampled output (:func:`sample_outer_dft`): s outputs, never the N.
+#
+# Every array the stages pass between them lies on whole (8, 128) tiles of
+# the chip, 8 rows by 128 free-axis entries: a digit that stands next to the
+# free axis is padded to a multiple of 8 (:func:`dft_pads` — zero columns and
+# rows of the factors, so zeros are added up and nothing is dropped), and
+# re|im stands above such a digit, never under it. Then each fold between
+# the gather, the stages and the sample is a bitcast. Where a digit of 125
+# or a row (κ1, re|im) of 126 meets the 8 sublanes, or a trailing 2 the tile,
+# XLA re-lays the whole tile out in a pass of its own (10⁶ × 1024 on a v5e:
+# two such passes, 25 of 121 ms an apply).
 
 #: Longest inner DFT factor (a dense f × f factor on the MXU) and longest
 #: outer factor (rows gathered and summed a sampled output).
@@ -343,10 +351,9 @@ def dft_factors(n: int):
     and no prime factor past 256). Among the splits the cheapest by a count
     of what a column costs — stage one f1, stage two 2·f2 (complex on both
     sides), 2·R for the rows gathered a sample —, the smaller f2 on a tie.
-    On a v5e at 10⁶ × 1024 the count's choice (100, 125, 80) reads 121.8 ms
-    an apply, (100, 100, 100) 122.1, (80, 125, 100) 128.3, (64, 125, 125)
-    133.1, (125, 125, 64) 155.6: what XLA copies between the stages moves
-    more than the count sees."""
+    The count does not see the padding (:func:`dft_pads`: at 10⁶ the choice
+    (100, 125, 80) gathers 2.4 % more rows and contracts 1.6 % more
+    batches)."""
     best = None
     for f1 in range(2, min(n, _DFT_FACTOR_MAX) + 1):
         if n % f1:
@@ -362,15 +369,46 @@ def dft_factors(n: int):
     return best and best[1]
 
 
+def _pad_to(x: int, mult: int = 8) -> int:
+    return -(-x // mult) * mult
+
+
+#: The v5e compiler lays a gather's indices in tiles of 1024 and, where they
+#: fill whole tiles, gathers 128 rows a step with a quarter of the buffers it
+#: takes otherwise (256 rows a step): 12–15 ns a row against 6–8, whatever
+#: the row's length (10⁶ × 256 from 1,024,000 rows 50 ms, from 1,000,000 24).
+_GATHER_INDEX_TILE = 1024
+
+
+def dft_pads(factors: tuple) -> tuple:
+    """``(f1p, hp, f2p, blocks)``: the extents the stages' arrays carry.
+    For the digits that stand next to the free axis — a of stage one's input
+    (f1) and κ1 of its result (h = f1//2 + 1 values), each up to a multiple
+    of 8, the rows of a tile; κ2 of stage two's result (f2) up to a multiple
+    of 4, so that its rows (re | im, κ2) are whole tiles (1 where f2 = 1: no
+    stage two). And ``blocks`` of R slabs of stage one's input, the digit b:
+    f2, or one more where f2·R·f1p gathered rows would fill whole index
+    tiles (``_GATHER_INDEX_TILE``) and f2 + 1 blocks do not (never where
+    f2 = 1). The pads are zero columns and rows of :func:`dft_tables`."""
+    r, f1, f2 = factors
+    f1p = _pad_to(f1)
+    slab = r * f1p
+    more = f2 * slab % _GATHER_INDEX_TILE == 0 and slab % _GATHER_INDEX_TILE
+    return (f1p, _pad_to(f1 // 2 + 1), _pad_to(f2, 4) if f2 > 1 else 1,
+            f2 + bool(more))
+
+
 @functools.lru_cache(maxsize=8)
 def dft_tables(factors: tuple) -> tuple:
     """The inner stages' factors as float32 host arrays, from float64
-    phases reduced in integers: ``F1`` (2h, f1), h = f1//2 + 1, rows
-    (κ1, re | im) of ω_{f1}^{a·κ1}; and, where f2 > 1, ``T2`` (h, 2·f2,
-    2·f2): for each κ1 the complex factor ω_{f2}^{b·κ2}·ω_{f1·f2}^{b·κ1}
+    phases reduced in integers, zero wherever an index is a pad
+    (:func:`dft_pads`): ``F1`` (2·hp, f1p), rows (re | im, κ1) of
+    ω_{f1}^{a·κ1} for κ1 ≤ f1/2; and, where f2 > 1, ``T2`` (hp, 2·f2p,
+    2·blocks): for each κ1 the complex factor ω_{f2}^{b·κ2}·ω_{f1·f2}^{b·κ1}
     (stage two with the twiddle between the stages folded in) as the real
     matrix [[re, −im], [im, re]] over (re | im, κ2) × (re | im, b)."""
     _, f1, f2 = factors
+    f1p, hp, f2p, blocks = dft_pads(factors)
     h = f1 // 2 + 1
 
     def cis(phase, period):                 # e^{−2πi·phase/period}
@@ -378,50 +416,59 @@ def dft_tables(factors: tuple) -> tuple:
         return np.cos(t), -np.sin(t)
 
     k1, a = np.arange(h)[:, None], np.arange(f1)[None, :]
-    re, im = cis(k1 * a, f1)
-    F1 = np.stack([re, im], axis=1).reshape(2 * h, f1).astype(np.float32)
+    F1 = np.zeros((2, hp, f1p), np.float32)
+    F1[:, :h, :f1] = cis(k1 * a, f1)
+    F1 = F1.reshape(2 * hp, f1p)
     if f2 == 1:
         return (F1,)
     k1 = np.arange(h)[:, None, None]
     k2, b = np.arange(f2)[None, :, None], np.arange(f2)[None, None, :]
     re, im = cis(b * k2 * f1 + b * k1, f1 * f2)
-    T2 = np.concatenate([np.concatenate([re, -im], axis=2),
-                         np.concatenate([im, re], axis=2)], axis=1)
-    return F1, T2.astype(np.float32)
+    T2 = np.zeros((hp, 2, f2p, 2, blocks), np.float32)
+    T2[:h, 0, :f2, 0, :f2], T2[:h, 0, :f2, 1, :f2] = re, -im
+    T2[:h, 1, :f2, 0, :f2], T2[:h, 1, :f2, 1, :f2] = im, re
+    return F1, T2.reshape(hp, 2 * f2p, 2 * blocks)
 
 
-def dft_source_rows(n: int, f1: int, mixer: str) -> jnp.ndarray:
-    """The row of the operand that stands at row (b·R + r)·f1 + a of the
-    stages' input (int32, length n): v[(a·f2 + b)·R + r] — the digit stage
-    one contracts brought next to the free axis — with v Makhoul's order
-    for the DCT (the even rows, then the odd ones from the last back) and
-    the operand's own for the DHT."""
-    i = jnp.arange(n, dtype=jnp.int32)
-    j = (i % f1) * (n // f1) + i // f1
+def dft_source_rows(n: int, factors: tuple, mixer: str) -> jnp.ndarray:
+    """The row of the operand that stands at row (b·R + r)·f1p + a of the
+    stages' input (int32, length blocks·R·f1p of :func:`dft_pads`):
+    v[(a·f2 + b)·R + r] — the digit stage one contracts brought next to the
+    free axis, in whole tiles of rows — with v Makhoul's order for the DCT
+    (the even rows, then the odd ones from the last back) and the operand's
+    own for the DHT. A pad names a row of the operand too (a ≥ f1 the slab's
+    last again, b ≥ f2 the first block's): its column of the factor is zero,
+    and every output of the transform depends on every row already."""
+    r, f1, f2 = factors
+    f1p, _, _, blocks = dft_pads(factors)
+    i = jnp.arange(blocks * r * f1p, dtype=jnp.int32)
+    j = jnp.minimum(i % f1p, f1 - 1) * (f2 * r) + i // f1p % (f2 * r)
     if mixer != "dct":
         return j
     return jnp.where(j < (n + 1) // 2, 2 * j, 2 * (n - 1 - j) + 1)
 
 
 def dft_blocks(U: jnp.ndarray, factors: tuple, tables) -> jnp.ndarray:
-    """The inner stages of the DFT of a real v along axis 0, for U (N, w)
-    = v in the row order of :func:`dft_source_rows`: Z for κ1 ≤ f1/2 only,
-    as a (2·h·f2·R, w) array whose row (κ1, r, re|im, κ2) is
-    ((κ1·R + r)·2 + re|im)·f2 + κ2 — with f2 = 1, (r·h + κ1)·2 + re|im.
-    Both sides of each contraction carry float32 (``highest``: a DFT
-    factor is not exact in bfloat16); each result is written in the order
-    the contraction leaves it, the next reader's indices follow it."""
+    """The inner stages of the DFT of a real v along axis 0, for U
+    (blocks·R·f1p, w) = v in the row order of :func:`dft_source_rows`: Z for
+    κ1 ≤ f1/2 only, as a (hp·R·2·f2p, w) array whose row (κ1, r, re|im, κ2)
+    is ((κ1·R + r)·2 + re|im)·f2p + κ2 — with f2 = 1 a (R·2·hp, w) one, row
+    (r·2 + re|im)·hp + κ1 — zero at the pads (:func:`dft_pads`).
+    Both sides of each contraction carry float32 (``highest``: a DFT factor
+    is not exact in bfloat16); each result is written in the order the
+    contraction leaves it, the next reader's indices follow it, and every
+    fold here splits or joins whole tiles of rows."""
     r, f1, f2 = factors
+    f1p, hp, f2p, blocks = dft_pads(factors)
     w = U.shape[1]
-    h = f1 // 2 + 1
-    hp = jax.lax.Precision.HIGHEST
+    highest = jax.lax.Precision.HIGHEST
     Z = jnp.einsum("ka,xaw->xkw", jnp.asarray(tables[0]),
-                   U.reshape(f2 * r, f1, w), precision=hp)
+                   U.reshape(blocks * r, f1p, w), precision=highest)
     if f2 > 1:
-        T2 = jnp.asarray(tables[1]).reshape(h, 2 * f2, 2, f2)
-        Z = jnp.einsum("kcpb,brkpw->krcw", T2, Z.reshape(f2, r, h, 2, w),
-                       precision=hp)
-    return Z.reshape(2 * h * f2 * r, w)
+        T2 = jnp.asarray(tables[1]).reshape(hp, 2 * f2p, 2, blocks)
+        Z = jnp.einsum("kcpb,brpkw->krcw", T2, Z.reshape(blocks, r, 2, hp, w),
+                       precision=highest)
+    return Z.reshape(-1, w)
 
 
 def _cis_turns(p: jnp.ndarray, period: int):
@@ -457,8 +504,9 @@ def sample_outer_dft(Z: jnp.ndarray, idx: jnp.ndarray, n: int,
     gathered rows held ``chunk`` samples at a time (≤
     ``_SAMPLE_CHUNK_BYTES``)."""
     r, f1, f2 = factors
+    _, hp, f2p, _ = dft_pads(factors)
+    under = f2p if f2 > 1 else hp
     w = Z.shape[1]
-    h = f1 // 2 + 1
     j = jnp.arange(r, dtype=jnp.int32)[None, :]
 
     def rows(ix):
@@ -475,9 +523,10 @@ def sample_outer_dft(Z: jnp.ndarray, idx: jnp.ndarray, n: int,
             cos, sin = _cis_turns(phase, n)
             on_re, on_im = cos + sin, sin - cos
         on_im = jnp.where(mirrored, -on_im, on_im)
-        # the re rows (:func:`dft_blocks`' order), the im rows f2 after them
-        at = ((ka * r + j) * (2 * f2) + kb) if f2 > 1 else (j * h + ka) * 2
-        at = jnp.concatenate([at, at + f2], axis=1).reshape(-1)
+        # the re rows (:func:`dft_blocks`' order), the im rows the padded
+        # extent of the digit under re|im after them
+        at = ((ka * r + j) * 2 * under + kb) if f2 > 1 else j * 2 * under + ka
+        at = jnp.concatenate([at, at + under], axis=1).reshape(-1)
         weight = jnp.float32(scale) * jnp.concatenate([on_re, on_im], axis=1)
         # whole rows of Z as it lies, multiplied and added up in float32
         return jnp.sum(weight[:, :, None] * Z[at].reshape(-1, 2 * r, w),

@@ -180,9 +180,13 @@ SPANS: Dict[str, Tuple[str, str]] = {
     # "xla_bf16x3" | "xla_f32" for fut="wht"; "xla_dft" = the blocked DFT of
     # fut.dft_blocks / sample_outer_dft for fut="dct" | "dht"), factors (the
     # Kronecker split of the axis, for "xla_dft" the split (R, f1, f2) of
-    # fut.dft_factors; the sampled outer factor first), elements (= axis ×
-    # columns mixed, which mix_rate.apply reads) and sampled (= s × columns
-    # kept)
+    # fut.dft_factors as the axis has it — the stages' arrays carry f1, its
+    # half and f2 padded to whole tiles of rows, fut.dft_pads, and the span
+    # does not say so; the sampled outer factor first), tile (the free-axis
+    # entries a pass of the walk takes: the Pallas pass's, MIX_TILE, or
+    # fjlt.dft_tile of the axis; for the operator), elements (= axis ×
+    # columns mixed, which mix_rate.apply reads: the operand's, no pad
+    # counted) and sampled (= s × columns kept)
     "sketch.apply": ("sketch kernel", "sketch_host_ms.apply"),
     "sketch.plan": ("sketch kernel", "sketch_plan_ms.apply"),
     "sketch.dispatch": ("sketch kernel", "sketch_dispatch_ms.apply"),

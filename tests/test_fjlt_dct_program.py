@@ -6,7 +6,8 @@ on the CPU:
 
 - the blocked transform against the cosine sum of the definition and against
   ``fut.dct`` / ``fut.dht`` — heights of one, two and three factors, factors
-  that are no multiple of 8, a height the rule declines;
+  that are no multiple of 8 (the stages' arrays are padded to whole (8, 128)
+  tiles: ``fut.dft_pads``) and factors that are, a height the rule declines;
 - the sampled outer factor against the full transform then a gather;
 - *plain reference*: ``cellbench/references/dct_fjlt.py`` (imports nothing of
   the program; a float64 DCT on the host) — both orientations, ragged free
@@ -64,7 +65,7 @@ def _definition(x, mixer):
 def _blocked(X, factors, mixer, idx=None):
     n = X.shape[0]
     idx = jnp.arange(n, dtype=jnp.int32) if idx is None else idx
-    source = fut.dft_source_rows(n, factors[1], mixer)
+    source = fut.dft_source_rows(n, factors, mixer)
     Z = fut.dft_blocks(X[source], factors, fut.dft_tables(factors))
     return fut.sample_outer_dft(Z, idx, n, factors, mixer, 1.0)
 
@@ -86,32 +87,83 @@ def test_dft_factors(n, factors):
 
 def test_dft_tables_are_the_dft_factors_and_cached():
     F1, T2 = fut.dft_tables((3, 6, 5))
-    assert F1.shape == (8, 6) and T2.shape == (4, 10, 10)
+    # f1 = 6 → 8 columns, h = 4 → 8 rows a part, f2 = 5 → 8 rows a part
+    assert fut.dft_pads((3, 6, 5)) == (8, 8, 8, 5)
+    assert F1.shape == (16, 8) and T2.shape == (8, 16, 10)
     assert F1.dtype == T2.dtype == np.float32
     k, a = np.arange(4)[:, None], np.arange(6)[None, :]
-    np.testing.assert_allclose(F1[0::2], np.cos(2 * np.pi * k * a / 6), atol=1e-7)
-    np.testing.assert_allclose(F1[1::2], -np.sin(2 * np.pi * k * a / 6), atol=1e-7)
+    np.testing.assert_allclose(F1[:4, :6], np.cos(2 * np.pi * k * a / 6), atol=1e-7)
+    np.testing.assert_allclose(F1[8:12, :6], -np.sin(2 * np.pi * k * a / 6), atol=1e-7)
     # κ1 = 0: no twiddle, the plain DFT_5 as [[re, −im], [im, re]]
     q = np.arange(5)
     W = np.exp(-2j * np.pi * np.outer(q, q) / 5)
     np.testing.assert_allclose(T2[0, :5, :5], W.real, atol=1e-7)
     np.testing.assert_allclose(T2[0, :5, 5:], -W.imag, atol=1e-7)
-    np.testing.assert_allclose(T2[0, 5:, :5], W.imag, atol=1e-7)
+    np.testing.assert_allclose(T2[0, 8:13, :5], W.imag, atol=1e-7)
     # κ1 = 2: the twiddle ω_30^{2b} folded in
     tw = W * np.exp(-2j * np.pi * 2 * q / 30)[None, :]
-    np.testing.assert_allclose(T2[2, 5:, 5:], tw.real, atol=1e-7)
+    np.testing.assert_allclose(T2[2, 8:13, 5:], tw.real, atol=1e-7)
     assert fut.dft_tables((3, 6, 5))[0] is F1
     assert len(fut.dft_tables((4, 6, 1))) == 1
 
 
-@pytest.mark.parametrize("n,f1", [(12, 4), (15, 3), (1000, 50)])
-def test_source_rows_are_makhouls_order_with_the_stage_digit_last(n, f1):
+@pytest.mark.parametrize("factors,pads", [
+    # the cell: 125 → 128, h = 63 → 64, and 80·100·128 rows would fill whole
+    # index tiles of 1024 where 81·100·128 do not
+    ((100, 125, 80), (128, 64, 80, 81)),
+    ((40, 75, 32), (80, 40, 32, 33)),        # chip_smoke's DCT leg
+    ((128, 128, 64), (128, 72, 64, 64)),     # a block more would not help
+    ((7, 14, 11), (16, 8, 12, 11)),          # h = 8 whole, f1 and 2·f2 = 22 not
+    ((64, 125, 125), (128, 64, 128, 125)),   # 2·f2 = 250 → 256
+    ((20, 50, 1), (56, 32, 1, 1)),           # one inner stage
+    ((4, 64, 4), (64, 40, 4, 5)),            # 4·4·64 rows are an index tile
+    ((16, 64, 1), (64, 40, 1, 1)),           # no block to add to one stage
+    ((1, 2, 1), (8, 8, 1, 1))])
+def test_the_pads_of_the_tables_are_exactly_zero(factors, pads):
+    """Every digit that stands next to the free axis fills whole 8-row tiles
+    (2·f2p rows for (re | im, κ2)); what the tables hold there is 0.0, so the
+    padded layout adds zeros up and drops nothing. The gathered rows fill no
+    whole number of 1024-index tiles wherever a block more can see to it."""
+    r, f1, f2 = factors
+    f1p, hp, f2p, blocks = fut.dft_pads(factors)
+    assert (f1p, hp, f2p, blocks) == pads
+    h = f1 // 2 + 1
+    assert f1p % 8 == 0 and hp % 8 == 0 and f1 <= f1p < f1 + 8 and h <= hp < h + 8
+    assert (2 * f2p) % 8 == 0 and f2 <= f2p < f2 + 4 if f2 > 1 else f2p == 1
+    assert blocks in (f2, f2 + 1)
+    assert (blocks * r * f1p) % 1024 or (r * f1p) % 1024 == 0
+    tables = fut.dft_tables(factors)
+    F1 = tables[0].reshape(2, hp, f1p)
+    assert not F1[:, h:].any() and not F1[:, :, f1:].any()
+    assert np.abs(F1[:, :h, :f1]).sum(axis=(0, 2)).min() >= 1.0   # no live κ1 is
+    if f2 > 1:
+        T2 = tables[1].reshape(hp, 2, f2p, 2, blocks)
+        assert not T2[h:].any() and not T2[:, :, f2:].any()
+        assert not T2[..., f2:].any()
+        assert np.abs(T2[:h, :, :f2]).sum(axis=(1, 3, 4)).min() >= 1.0
+
+
+@pytest.mark.parametrize("n,factors", [
+    (12, (1, 4, 3)), (15, (5, 3, 1)), (1000, (4, 50, 5)), (96, (3, 8, 4)),
+    (1000, (8, 125, 1)),
+    (1024, (4, 64, 4)),             # a block more: 5·4·64 rows
+    (1024, (16, 64, 1))])
+def test_source_rows_are_makhouls_order_with_the_stage_digit_last(n, factors):
+    r, f1, f2 = factors
+    f1p, _, _, blocks = fut.dft_pads(factors)
     x = np.arange(n)
     v = np.concatenate([x[::2], x[1::2][::-1]])          # Makhoul's order
-    want = v.reshape(f1, n // f1).T.reshape(-1)          # [(b, r), a]
-    assert np.array_equal(np.asarray(fut.dft_source_rows(n, f1, "dct")), want)
-    plain = x.reshape(f1, n // f1).T.reshape(-1)
-    assert np.array_equal(np.asarray(fut.dft_source_rows(n, f1, "dht")), plain)
+    for mixer, order in (("dct", v), ("dht", x)):
+        got = np.asarray(fut.dft_source_rows(n, factors, mixer))
+        assert got.shape == (blocks * r * f1p,) and got.dtype == np.int32
+        slabs = got.reshape(blocks * r, f1p)             # [(b, r), a]
+        live = slabs[:f2 * r]
+        assert np.array_equal(live[:, :f1], order.reshape(f1, n // f1).T)
+        # a pad names a row of the operand (its column of the factor is zero)
+        assert slabs.min() >= 0 and slabs.max() < n
+        assert np.array_equal(live[:, f1:],
+                              np.repeat(live[:, f1 - 1:f1], f1p - f1, axis=1))
+        assert np.array_equal(slabs[f2 * r:], live[:(blocks - f2) * r])
 
 
 def test_cis_turns_to_an_ulp_whatever_the_period():
@@ -138,7 +190,15 @@ def test_cis_turns_to_an_ulp_whatever_the_period():
     (2058, (6, 7, 49)),
     (1024, (4, 16, 16)),
     (250, (1, 125, 2)),          # no outer factor: every output's own row
-    (12, None)])
+    (12, None),
+    (1024, (2, 16, 32)),         # f1 a multiple of 8, h = 9 is not
+    (1344, (4, 14, 24)),         # h = 8 is, f1 is not
+    (2000, (10, 8, 25)),         # 2·f2 = 50 is no multiple of 8
+    (1250, (10, 125, 1)),        # one inner stage whose rows are padded
+    (1920, (8, 16, 15)),         # f1 a multiple of 8 under an odd f2
+    (9600, (4, 75, 32)),         # chip_smoke's inner factors: 75 → 80, 38 → 40
+    (1024, (4, 64, 4)),          # 4·4·64 rows are an index tile: a block more
+    (1024, (16, 64, 1))])        # one stage of whole tiles: nothing padded but h
 @pytest.mark.parametrize("mixer", ["dct", "dht"])
 def test_blocked_transform_against_the_definition_and_the_eager_one(
         n, factors, mixer):
@@ -151,14 +211,18 @@ def test_blocked_transform_against_the_definition_and_the_eager_one(
     assert _rel(got, eager) < 2e-6
 
 
-@pytest.mark.parametrize("n,factors", [(1000, (8, 5, 25)), (1536, (24, 8, 8)),
-                                       (2000, (250, 8, 1))])
-def test_sampled_outer_factor_against_full_transform_then_gather(n, factors):
+@pytest.mark.parametrize("n,factors,mixer", [
+    (1000, (8, 5, 25), "dct"), (1536, (24, 8, 8), "dct"),
+    (2000, (250, 8, 1), "dct"),
+    (2000, (16, 125, 1), "dct"),      # f2 = 1 under padded rows: (r, re|im, κ1)
+    (2000, (16, 125, 1), "dht"), (1155, (7, 15, 11), "dht")])
+def test_sampled_outer_factor_against_full_transform_then_gather(
+        n, factors, mixer):
     X = _operand(n, 9, 3)
     idx = jnp.asarray(np.random.default_rng(n).integers(0, n, 300), jnp.int32)
     idx = idx.at[:4].set(jnp.asarray([0, n - 1, n // 2, 1]))
-    full = np.asarray(_blocked(X, factors, "dct"))
-    sampled = np.asarray(_blocked(X, factors, "dct", idx))
+    full = np.asarray(_blocked(X, factors, mixer))
+    sampled = np.asarray(_blocked(X, factors, mixer, idx))
     np.testing.assert_allclose(sampled, full[np.asarray(idx)], rtol=0, atol=1e-5)
     # the mirrored half (κ1 > f1/2) is read through the conjugate
     r, f1, f2 = factors
@@ -201,7 +265,7 @@ def test_program_against_the_plain_reference_both_orientations(n, s, m):
 @pytest.mark.parametrize("mixer", ["dct", "dht"])
 @pytest.mark.parametrize("n", [1000, 1155, 1 << 10])
 def test_program_agrees_with_the_eager_composition(mixer, n):
-    s, m = 128, 2 * fjlt.DFT_TILE + 20         # two tiles and a ragged rest
+    s, m = 128, 2 * fjlt.dft_tile(n) + 20         # two tiles and a ragged rest
     A = _operand(n, m, n)
     T = sk.FJLT(n, s, Context(n), fut=mixer)
     eager = fut.sign_mix_sample(
@@ -211,17 +275,71 @@ def test_program_agrees_with_the_eager_composition(mixer, n):
     assert _rel(T.apply(A.T, sk.ROWWISE).T, eager) < 2e-6
 
 
-def test_three_factors_through_the_program():
+@pytest.mark.parametrize("factors", [
+    (10, 10, 10), (8, 5, 25), (1, 20, 50),
+    (5, 8, 25),                  # f1 a multiple of 8, 2·f2 = 50 is not
+    (8, 125, 1),                 # one stage, its rows padded 63 → 64
+    (20, 2, 25),                 # the shortest stage one
+    (25, 40, 1)])                # one stage, f1 a multiple of 8, h = 21 not
+def test_forced_splits_through_the_program(factors):
+    """A split the rule would not choose, through the program in tiles of 64
+    with a ragged last one (140 = 2·64 + 12), both orientations."""
     n, s, m = 1000, 96, 140
     A = _operand(n, m, 2)
     T = sk.FJLT(n, s, Context(4))
     D, idx = reference.streams(4, 0, n, s)
     ref = reference.apply_cols(A, D, idx)
-    for factors in ((10, 10, 10), (8, 5, 25), (1, 20, 50)):
-        got = fjlt.fjlt_mix_sample(
-            T.allocation.key_data, A, s_dim=s, rowwise=False, kernel="xla_dft",
-            tile=64, fut="dct", factors=factors)
-        assert _rel(got, ref) < REL_MAX
+    statics = dict(s_dim=s, kernel="xla_dft", tile=64, fut="dct",
+                   factors=factors)
+    got = fjlt.fjlt_mix_sample(T.allocation.key_data, A, rowwise=False,
+                               **statics)
+    assert _rel(got, ref) < REL_MAX
+    got = fjlt.fjlt_mix_sample(T.allocation.key_data, A.T, rowwise=True,
+                               **statics)
+    assert _rel(got.T, ref) < REL_MAX
+
+
+@pytest.mark.parametrize("m", [40, 140])       # under the tile; 2·64 + 12
+@pytest.mark.parametrize("rowwise", [False, True])
+@pytest.mark.parametrize("mixer", ["dct", "dht"])
+def test_program_against_the_dense_operator(mixer, rowwise, m):
+    """Both mixers, both orientations, a width under the tile and a ragged
+    last tile, on a split none of whose digits fills whole tiles (1155 =
+    7·15·11), against the float64 cosine / Hartley sum of the definition."""
+    n, s = 1155, 96
+    A = _operand(n, m, 5)
+    T = sk.FJLT(n, s, Context(12), fut=mixer)
+    kernel, factors, _ = T.mix_plan(A, rowwise)
+    assert kernel == "xla_dft" and all(d % 4 for d in factors[1:])
+    D = np.asarray(T.diagonal(), np.float64)[:, None]
+    scale = math.sqrt(n / s) * T._fut.scale()
+    ref = scale * _definition(D * np.asarray(A, np.float64), mixer)[
+        np.asarray(T.sample_indices())]
+    got = fjlt.fjlt_mix_sample(
+        T.allocation.key_data, A.T if rowwise else A, s_dim=s, rowwise=rowwise,
+        kernel=kernel, tile=64, fut=mixer, factors=factors)
+    assert _rel(got.T if rowwise else got, ref) < REL_MAX
+
+
+@pytest.mark.parametrize("n,tile", [
+    (1_000_000, 512),            # the cell
+    (1 << 21, 256), (1 << 22, 128), (1_200_000, 384),
+    (500_000, 512),              # no wider tile than the chip has read
+    (1000, 512), (2, 512)])      # a short axis
+def test_the_tile_follows_the_axis(n, tile):
+    """The widest multiple of 128 columns, to 512, whose temporaries — at
+    their largest two of the stages' padded arrays — stay under the budget."""
+    assert fjlt.dft_tile(n) == tile
+    r, f1, f2 = factors = fut.dft_factors(n)
+    f1p, hp, f2p, blocks = fut.dft_pads(factors)
+    gathered, one = blocks * r * f1p, blocks * r * 2 * hp
+    two = hp * r * 2 * f2p if f2 > 1 else 0
+    held = 4 * tile * max(n + gathered, gathered + one, one + two)
+    assert held <= fjlt._DFT_TEMP_BYTES
+    assert held <= fjlt._DFT_ENTRY_BYTES * n * tile or n < 1 << 18
+    A = jnp.zeros((n, 1), jnp.float32)
+    assert sk.FJLT(n, 64, Context(0)).mix_plan(A, False) == (
+        "xla_dft", factors, tile)
 
 
 def test_bf16_table_control_fails_the_configurations_rel_max():
@@ -252,7 +370,7 @@ def test_a_height_the_rule_declines_and_other_dtypes_keep_the_eager_route():
     U = sk.FJLT(1000, 64, Context(1))
     B = _operand(1000, 8, 2)
     assert U.mix_plan(B.astype(jnp.bfloat16), False) is None
-    assert U.mix_plan(B, True) == ("xla_dft", (10, 20, 5), fjlt.DFT_TILE)
+    assert U.mix_plan(B, True) == ("xla_dft", (10, 20, 5), fjlt.dft_tile(1000))
     low = U.apply(B.astype(jnp.bfloat16), sk.COLUMNWISE)
     assert low.dtype == jnp.bfloat16 and low.shape == (64, 8)
 
@@ -335,7 +453,8 @@ def test_span_attributes_and_the_counter():
     assert dispatch.parent_id == apply.span_id
     assert dispatch.attrs == {
         "path": "fut", "family": "FJLT", "fut": "dct", "kernel": "xla_dft",
-        "factors": factors, "elements": n * m, "sampled": s * m}
+        "factors": factors, "tile": fjlt.dft_tile(n), "elements": n * m,
+        "sampled": s * m}
     assert factors[0] * factors[1] * factors[2] == n
     assert "sketch.plan" not in spans
     assert fjlt._MIXED.value(family="FJLT", kernel="xla_dft") == counted + n * m

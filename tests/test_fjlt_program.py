@@ -222,7 +222,7 @@ def test_other_mixers_dtypes_and_axes_keep_the_eager_route():
     A = _operand(1 << 10, 8, 1)
     for name in ("dct", "dht"):           # served since the blocked DFT
         assert sk.FJLT(1 << 10, 64, Context(1), fut=name).mix_plan(A, False) == (
-            "xla_dft", fut.dft_factors(1 << 10), fjlt.DFT_TILE)
+            "xla_dft", fut.dft_factors(1 << 10), fjlt.dft_tile(1 << 10))
     T = sk.FJLT(1 << 10, 64, Context(1), fut="wht")
     assert T.mix_plan(A.astype(jnp.bfloat16), False) is None
     assert T.mix_plan(A, False) == ("xla_f32", 1 << 10, fjlt.MIX_TILE)
@@ -430,7 +430,8 @@ def test_span_attributes_and_the_counter():
     assert dispatch.parent_id == apply.span_id
     assert dispatch.attrs == {
         "path": "fut", "family": "FJLT", "fut": "wht", "kernel": "xla_f32",
-        "factors": (2, 128, 128), "elements": n * m, "sampled": s * m}
+        "factors": (2, 128, 128), "tile": fjlt.MIX_TILE, "elements": n * m,
+        "sampled": s * m}
     assert spans["stream.key"].attrs["cached"] in (True, False)
     assert fjlt._MIXED.value(family="FJLT", kernel="xla_f32") == counted + n * m
 

@@ -10,6 +10,7 @@ hold the TPU library, and every xdist worker imports every test file.
 """
 
 import functools
+import math
 import re
 
 import jax
@@ -327,24 +328,47 @@ def test_fjlt_block_kernel_other_shapes(one_chip, rows, cols, plan):
 DCT_ROWS = 1_000_000
 
 
+def _instructions(text):
+    """``(computation, name, elements, opcode, shape)`` of every array-valued
+    instruction of a compiled module's text — a fusion is one instruction of
+    the computation that calls it, and what it fuses is listed under its own
+    ``fused_computation``."""
+    computation = None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            computation = head.group(1)
+            continue
+        op = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\w+\[([\d,]*)\])\S* "
+                      r"([\w\-]+)\(", line)
+        if op:
+            elements = math.prod(int(d) for d in op.group(3).split(",") if d)
+            yield computation, op.group(1), elements, op.group(4), op.group(2)
+
+
 def test_cell_shape_fjlt_dct_mix_sample(one_chip):
     """FJLT(1,000,000, 4096) — the default mixer — columnwise of 1,000,000 ×
     1024 as the one program: the blocked DFT on XLA (no Mosaic call), a tile
-    of 256 columns at a time. Beside the operand and the result it holds a
-    few (N × tile) float32 arrays — under 5 GB of the ≈ 7 that two resident
-    operands leave of the chip — and never an operand-sized or a complex
-    one."""
+    of 512 columns at a time. Every array the stages pass between them lies
+    on whole (8, 128) tiles, so no pass over a tile only moves data: outside
+    the fusions that compute (the slice with the signs, the row gather, the
+    two contractions) the tile loop holds no tile-sized ``reshape``, ``copy``
+    or ``transpose``, and the signs are no gather of N scalars. Beside the
+    operand and the result it holds two (N × tile) float32 arrays with their
+    pads at a time — 4.26 GB of the ≈ 7 that two resident operands leave of
+    the chip — and never an operand-sized or a complex one."""
     from libskylark_tpu.sketch import fjlt, fut
 
     factors = fut.dft_factors(DCT_ROWS)
-    assert factors == (100, 125, 80) and fjlt.DFT_TILE == 256
+    tile = fjlt.dft_tile(DCT_ROWS)
+    assert factors == (100, 125, 80) and tile == 512
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     program = jax.jit(functools.partial(
         fjlt.fjlt_mix_sample, s_dim=FJLT_S, rowwise=False, kernel="xla_dft",
-        tile=fjlt.DFT_TILE, fut="dct", factors=factors))
+        tile=tile, fut="dct", factors=factors))
     tables = fut.dft_tables(factors)
     compiled = program.lower(
         arg((2,), jnp.uint32), arg((DCT_ROWS, FJLT_COLS), jnp.float32),
@@ -353,7 +377,24 @@ def test_cell_shape_fjlt_dct_mix_sample(one_chip):
     assert KERNEL not in text
     assert not re.search(r"\bc64\[", text)                # no complex array
     assert not re.search(r"f32\[1000000,1024\]\S* (copy|fusion)\(", text)
+    called = [i for i in _instructions(text) if "fused_computation" not in i[0]]
+    moved = [i for i in called if i[2] >= DCT_ROWS * tile // 2
+             and i[3] in ("reshape", "copy", "transpose")]
+    assert not moved, moved
+    # the tile's passes: slice × signs, row gather, stage one, stage two
+    passes = [i for i in called if i[2] >= DCT_ROWS * tile // 2
+              and i[3] == "fusion"]
+    assert len(passes) == 4, passes
+    assert not re.search(r"f32\[1000000\]\S* gather\(", text)
+    # both row gathers 256 rows a step: 81 blocks of 100 slabs of 128 rows
+    # fill no whole number of 1024-index tiles, where 80 blocks would, and
+    # this compiler gathers those 128 rows a step, half as fast on the chip
+    steps = re.findall(r"f32\[\d+,512\]\S* fusion\(.*/gather\".*"
+                       r"\"integer_config\":\{\"integer\":\"(\d+)\"", text)
+    assert steps == ["256", "256"], steps
     memory = compiled.memory_analysis()
     assert memory.output_size_in_bytes == FJLT_S * FJLT_COLS * 4
-    tile_bytes = DCT_ROWS * fjlt.DFT_TILE * 4
-    assert 2 * tile_bytes < memory.temp_size_in_bytes < 5 * tile_bytes < 5 << 30
+    tile_bytes = DCT_ROWS * tile * 4
+    # 4.26 GB: two padded tiles (1.037 of a tile each) and 16 MB of the rest
+    assert 2 * tile_bytes < memory.temp_size_in_bytes < 2.1 * tile_bytes
+    assert memory.temp_size_in_bytes < fjlt._DFT_TEMP_BYTES
