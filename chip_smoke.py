@@ -304,7 +304,9 @@ def step_sketch() -> None:
     # built on the host in float64. 96000 = 40·75·32: stage one's 75 rows a
     # slab are padded to 80 and its 38 outputs to 40 (whole 8-row tiles),
     # a 33rd block of slabs keeps the gathered rows off whole index tiles
-    # (fut.dft_pads), and 384 columns are one tile (fjlt.dft_tile: 512)
+    # (fut.dft_pads); whole rows of the operand (384 columns) are gathered
+    # where they lie, and all 40 slabs of the sampled digit fit one pass
+    # (fjlt.dft_slabs)
     Nd, Sd, Md = (1000, 64, 40) if REHEARSE else (96000, 1024, 384)
     Fd = sk.FJLT(Nd, Sd, Context(seed=22))
     Ad = jnp.asarray(rng.standard_normal((Nd, Md), dtype=np.float32))

@@ -30,15 +30,15 @@ and the last, outer factor taken at the sampled rows only.
 * ``fut="dct"`` (the default, upstream's FFTW mixer) and ``"dht"``, an axis
   ``fut.dft_factors`` splits — N = R·f1·f2 with f1, f2 ≤ 128 and R ≤ 256, so
   every N ≤ 2²² with such a split (10⁶ = 100·125·80), no prime factor past
-  256: a tile of the free axis (:func:`dft_tile` of N) sliced with the signs,
-  its rows gathered once into the order the stages contract
-  (``fut.dft_source_rows``), the DFT behind the transform in two dense stages
-  of float32 factors on the MXU (``fut.dft_blocks``, half of its outputs: the
-  input is real) and R rows against their twiddles a sampled output
-  (``fut.sample_outer_dft``); every array between the stages lies on whole
-  (8, 128) tiles (``fut.dft_pads``), so that no pass only moves data, and the
-  workspace is two (N × tile) arrays at a time, never an operand-sized or a
-  complex one.
+  256: the walk runs over the sampled digit r, ρ of its R slabs a pass
+  (:func:`dft_slabs`, by memory) — whole rows of the operand gathered once
+  into the order the stages contract (``fut.dft_source_rows``; columnwise no
+  slice of the operand is taken first), the signs taken at the gathered rows,
+  the DFT behind the transform in two dense stages of float32 factors on the
+  MXU (``fut.dft_blocks``, half of its outputs: the input is real) and the
+  pass's 2ρ rows against their twiddles a sampled output, added up over the
+  passes (``fut.sample_outer_dft``); every array between the stages lies on
+  whole (8, 128) tiles (``fut.dft_pads``), so that no pass only moves data.
 
 Every other height and dtype, and an operand that lies on several devices,
 keeps the eager composition (``fut.sign_mix_sample``: for the DCT ``lax.fft``
@@ -78,8 +78,8 @@ MIX_TILE = 128
 #: Rows of the transform axis mixed in full; the factor above them is
 #: computed at the sampled rows only (``fut.sample_outer``).
 MIX_BLOCK = 16384
-#: The widest tile of the DCT / DHT route (:func:`dft_tile`): none wider was
-#: read on the chip at a height where it fits beside two resident operands.
+#: The widest cut tile of the DCT / DHT route (:func:`dft_tile`: a rowwise
+#: operand's): none wider was read on the chip where the tile is a copy.
 _DFT_TILE_MAX = 512
 
 
@@ -106,12 +106,12 @@ def fjlt_mix_sample(key_data, A, *tables, s_dim: int, rowwise: bool,
       Hadamard factors contract as exact bfloat16 against a three-way split
       operand on a TPU, in float32 off it;
     * ``"xla_dft"`` — ``dct`` | ``dht`` over ``factors`` = (R, f1, f2) of
-      ``fut.dft_factors``: the same walk of the free axis, a tile sliced
-      with the signs, its rows gathered in the stages' order, its DFT in two
-      dense stages with float32 on both sides and the outer factor R at the
-      sampled outputs (sketch/fut.py). ``tables`` are
-      ``fut.dft_tables(factors)`` on the operand's device; a caller that
-      leaves them out (a trace) gets them as constants of its program.
+      ``fut.dft_factors``: whole rows of the operand (``tile`` = its free
+      axis; a rowwise one's tile, transposed) gathered in the stages' order
+      with their signs, :func:`dft_slabs` of the R slabs a pass, their DFT in
+      two dense ``highest`` stages, their part of the outer factor at the
+      sampled outputs (sketch/fut.py). ``tables``: a pass's ``fut.dft_tables``
+      on the operand's device; left out (a trace), constants of the program.
     """
     n = A.shape[1] if rowwise else A.shape[0]
     m = A.shape[0] if rowwise else A.shape[1]
@@ -131,18 +131,18 @@ def fjlt_mix_sample(key_data, A, *tables, s_dim: int, rowwise: bool,
         return scale * _fut.sample_outer(Y, idx, block)
 
     if kernel == "xla_dft":
-        tables = tables or _fut.dft_tables(factors)
+        slabs = dft_slabs(n, factors, tile, m, rowwise)
+        part = (slabs,) + factors[1:]               # a pass, to the stages
+        tables = tables or _fut.dft_tables(part)
         scale /= math.sqrt(2.0) if fut == "dct" else 1.0
-        source = _fut.dft_source_rows(n, factors, fut)
 
         def mixed(X):                               # X (N, w) → (s, w)
-            # the signs ride in the pass that slices the tile out of the
-            # operand (D[source] is a gather of N scalars at a row's price:
-            # 6.6 ms an apply); then whole rows of the tile, gathered (a
-            # gather of row pieces from the operand itself runs a row at a
-            # time: 19.6 s an apply)
-            Z = _fut.dft_blocks((D[:, None] * X)[source], factors, tables)
-            return _fut.sample_outer_dft(Z, idx, n, factors, fut, scale)
+            # the walk over the sampled digit, ``slabs`` of its R slabs a
+            # pass: whole rows of X gathered once, with their signs, the two
+            # stages, and the passes' parts of the outer factor added up —
+            # columnwise X is the operand itself, no slice of it taken
+            return _dft_passes(X, D, idx, tables, fut=fut, factors=factors,
+                               slabs=slabs, scale=scale)
     else:
         def mixed(X):
             Y = _fut.wht_blocks(D[:, None] * X, block, kernel == "xla_bf16x3")
@@ -170,6 +170,30 @@ def fjlt_mix_sample(key_data, A, *tables, s_dim: int, rowwise: bool,
     return out
 
 
+def _dft_passes(X, D, idx, tables, *, fut: str, factors: tuple, slabs: int,
+                scale: float):
+    """``scale`` · rows ``idx`` of the DCT / DHT of ``D ⊙ X`` along axis 0 of
+    X (N, w), N = R·f1·f2 = ``factors``: R ÷ ``slabs`` passes, each the
+    blocked DFT of ``slabs`` slabs of the sampled digit (``tables`` are the
+    pass's, ``fut.dft_tables``) and their part of the outer factor's sum."""
+    n, part = X.shape[0], (slabs,) + factors[1:]
+    signs = _fut.dft_source_signs(D, factors, fut, slabs)
+
+    def passed(first):                              # slabs [first, first + ρ)
+        # the signs follow the gathered rows by a fold of their own and are
+        # multiplied where stage one reads its input: no pass over the tile
+        source = _fut.dft_source_rows(n, factors, fut, slabs, first)
+        of_rows = jax.lax.dynamic_slice_in_dim(signs, first, slabs, 1)
+        Z = _fut.dft_blocks(of_rows.reshape(-1, 1) * X[source], part, tables)
+        return _fut.sample_outer_dft(Z, idx, n, factors, fut, scale, first)
+
+    if slabs == factors[0]:
+        return passed(0)
+    return jax.lax.fori_loop(
+        0, factors[0] // slabs, lambda t, out: out + passed(t * slabs),
+        jnp.zeros((idx.shape[0], X.shape[1]), X.dtype))
+
+
 @functools.lru_cache(maxsize=None)
 def _mix_program():
     """The compiled apply, built at the first operand so that importing
@@ -183,8 +207,9 @@ def _mix_program():
 
 @functools.lru_cache(maxsize=8)
 def _dft_tables_on(factors: tuple, device) -> tuple:
-    """``fut.dft_tables(factors)`` placed on ``device`` once: built at the
-    first operand of that split, an argument of every apply after it."""
+    """``fut.dft_tables(factors)`` — of a pass, (ρ, f1, f2) — placed on
+    ``device`` once: built at the first operand of that split and walk, an
+    argument of every apply after it."""
     return tuple(jax.device_put(t, device) for t in _fut.dft_tables(factors))
 
 
@@ -201,24 +226,56 @@ def solver_fut(n: int) -> str:
 
 
 #: What the DCT / DHT route's temporaries may take of the device beside the
-#: operand and the result, and the most they take an entry of a (N × tile)
-#: tile: two arrays of N × tile float32 with their pads at a time — the
-#: sliced tile and its gathered rows, the gathered rows and stage one's
-#: result, then the two stages' results (v5e compile, ``memory_analysis``:
-#: 8.3 bytes an entry at 10⁶ = 100·125·80, 9.1 at 2²¹ = 128³, where h = 65
-#: is padded to 72).
+#: operand and the result (:func:`_dft_pass_bytes`; v5e compile,
+#: ``memory_analysis``: 4.26 GB at 10⁶ = 100·125·80 × 1024 on ρ = 50).
 _DFT_TEMP_BYTES = 5.0e9
+#: The most an entry of a cut (N × tile) tile takes where one pass has all
+#: R slabs: two arrays of N × tile float32 with their pads (8.3 bytes at
+#: 10⁶, 9.1 at 2²¹ = 128³, where h = 65 is padded to 72).
 _DFT_ENTRY_BYTES = 9.1
 
 
+def _dft_pass_bytes(n: int, factors: tuple, slabs: int, w: int,
+                    copied: bool) -> int:
+    """What a pass of the DCT / DHT route over ``slabs`` slabs of the
+    sampled digit and ``w`` free-axis entries holds, two float32 arrays at
+    a time — the gathered rows and stage one's result, then the two stages'
+    results (``fut.dft_pads`` of the pass) — and, where it reads a cut tile
+    (``copied``), the (n × w) copy: beside them while another pass will
+    read it, beside the gathered rows alone where one pass takes all R."""
+    r, f1, f2 = factors
+    f1p, hp, f2p, blocks = _fut.dft_pads((slabs, f1, f2))
+    gathered, one = blocks * slabs * f1p, blocks * slabs * 2 * hp
+    two = hp * slabs * 2 * f2p if f2 > 1 else 0
+    rows = max(gathered + one, one + two)
+    if copied:
+        rows = rows + n if slabs < r else max(rows, n + gathered)
+    return 4 * w * rows
+
+
+def dft_slabs(n: int, factors: tuple, tile: int, m: int, rowwise: bool) -> int:
+    """ρ: the slabs of the sampled digit r (of the R = ``factors[0]``) a
+    pass of the DCT / DHT route takes, over ``tile`` of the ``m`` free-axis
+    entries of an operand with a transform axis of ``n``: the largest
+    divisor of R whose temporaries (:func:`_dft_pass_bytes`; the tile is a
+    copy where it is cut or a rowwise operand's, transposed) stay under
+    ``_DFT_TEMP_BYTES``. What an apply gathers does not depend on ρ: it is
+    memory's alone. 10⁶ × 1024 columnwise: 50 (4.25 GB; all 100 to 602
+    columns, one slab to ≈ 60,000)."""
+    w, copied = min(tile, m), rowwise or m > tile
+    return next((d for d in range(factors[0], 1, -1) if factors[0] % d == 0
+                 and _dft_pass_bytes(n, factors, d, w, copied)
+                 <= _DFT_TEMP_BYTES), 1)
+
+
 def dft_tile(n: int) -> int:
-    """Free-axis entries the DCT / DHT route transforms at a time over a
-    transform axis of ``n``: the widest multiple of 128, to
-    ``_DFT_TILE_MAX``, whose temporaries stay under ``_DFT_TEMP_BYTES`` —
-    512 to n = 10⁶ (4.26 GB), 256 at 2²¹, 128 at 2²² (4.87 GB each). Wider
-    is faster here, unlike the Hadamard route: the two row gathers cost by
-    the row, not by the byte (10⁶ × 1024 on a v5e: 90.0 ms an apply at 256,
-    80.8 at 384, 74.5 at 512; ``PERF.md`` §5)."""
+    """Free-axis entries of a cut tile of the DCT / DHT route over a
+    transform axis of ``n`` — a rowwise operand's, which is transposed
+    first, and a columnwise one's past the width whose whole rows one slab
+    fits at: the widest multiple of 128, to ``_DFT_TILE_MAX``, that
+    ``_DFT_ENTRY_BYTES`` an entry keep under ``_DFT_TEMP_BYTES`` — 512 to
+    n = 10⁶, 256 at 2²¹, 128 at 2²². The wider the better, to rows of
+    2 KB: the two row gathers cost by the row there (``PERF.md`` §5)."""
     lanes = int(_DFT_TEMP_BYTES / (_DFT_ENTRY_BYTES * n)) // 128
     return 128 * max(1, min(lanes, _DFT_TILE_MAX // 128))
 
@@ -449,7 +506,10 @@ class FJLT(SketchTransform):
         """(kernel, split, tile) of :func:`fjlt_mix_sample` for this
         operand, from the shapes and the device alone — ``split`` the rows
         mixed in full (``wht``) or the factors (R, f1, f2) of the blocked
-        DFT (``dct`` / ``dht``) — or None where the eager composition below
+        DFT (``dct`` / ``dht``), ``tile`` the free-axis entries a pass takes
+        (``dct`` / ``dht``: a columnwise operand's whole rows wherever one
+        slab of the sampled digit fits the budget, else :func:`dft_tile`)
+        — or None where the eager composition below
         serves: another dtype than float32; an axis the mixer's rule
         declines — ``wht`` takes the powers of two, ``dct`` and ``dht``
         every N = R·f1·f2 with 2 ≤ f1 ≤ 128, f2 ≤ 128, R ≤ 256
@@ -472,7 +532,11 @@ class FJLT(SketchTransform):
         if not traced and len(A.devices()) > 1:
             return None
         if not hadamard:
-            return "xla_dft", factors, dft_tile(self._N)
+            # whole rows of a columnwise operand wherever a slab of them fits
+            m = A.shape[0] if rowwise else A.shape[1]
+            whole = not rowwise and _dft_pass_bytes(
+                self._N, factors, 1, m, False) <= _DFT_TEMP_BYTES
+            return "xla_dft", factors, m if whole else dft_tile(self._N)
         on_tpu = jax.default_backend() == "tpu"
         if on_tpu and not rowwise and (not traced or jax.device_count() == 1):
             from libskylark_tpu.sketch import pallas_wht    # pulls pallas
@@ -501,13 +565,16 @@ class FJLT(SketchTransform):
         key_data = self._alloc.key_data
         if isinstance(A, jax.core.Tracer):
             return fjlt_mix_sample(key_data, A, **statics)
-        tables = (_dft_tables_on(split, next(iter(A.devices())))
-                  if kernel == "xla_dft" else ())
         columns = A.shape[0] if rowwise else A.shape[1]
         attrs = {"path": "fut", "family": self.sketch_type,
                  "fut": self._fut_name, "kernel": kernel,
                  "factors": factors, "tile": tile,
                  "elements": self._N * columns, "sampled": self._S * columns}
+        tables = ()
+        if kernel == "xla_dft":
+            attrs["slabs"] = dft_slabs(self._N, split, tile, columns, rowwise)
+            tables = _dft_tables_on((attrs["slabs"],) + split[1:],
+                                    next(iter(A.devices())))
         with _trace.span("sketch.dispatch", attrs):
             out = _mix_program()(key_data, A, *tables, **statics)
         _MIXED.inc_always(attrs["elements"], family=self.sketch_type,

@@ -268,12 +268,12 @@ def wht_blocks(X: jnp.ndarray, block: int, bf16_split: bool = False):
 _SAMPLE_CHUNK_BYTES = 1 << 26
 
 
-def _sampled_in_chunks(rows, idx: jnp.ndarray, sample_bytes: int, w: int):
+def _sampled_in_chunks(rows, idx, sample_bytes: int, w: int, each: int = 0):
     """``rows(idx)`` (s, w), the samples taken ``chunk`` at a time so that
-    what ``rows`` gathers for them (``sample_bytes`` a sample) stays within
-    ``_SAMPLE_CHUNK_BYTES``."""
+    what ``rows`` gathers for them (``sample_bytes`` a sample, ``each`` rows)
+    stays about ``_SAMPLE_CHUNK_BYTES`` (:func:`_sample_chunk`)."""
     s = idx.shape[0]
-    chunk = max(8, _SAMPLE_CHUNK_BYTES // sample_bytes)
+    chunk = _sample_chunk(sample_bytes, each)
     if chunk >= s:
         return rows(idx)
     pad = -s % chunk
@@ -318,13 +318,23 @@ def sample_outer(Y: jnp.ndarray, idx: jnp.ndarray, block: int) -> jnp.ndarray:
 #   V_k = Σ_r ω_N^{r·k} · Z[k1, r],
 #   Z[k1, r] = Σ_b ω_{f2}^{b·κ2} ω_{f1·f2}^{b·κ1} · Σ_a ω_{f1}^{a·κ1} v[a, b, r].
 #
-# A tile's rows come into the stages in ONE row gather
+# The stages do not mix the sampled digit r: stage one contracts a, stage two
+# b, and the outer factor is a sum over r that is wanted at the s sampled
+# outputs only — a sum that can be added up a few r at a time. So a pass
+# takes ρ of the R slabs of r (the caller's choice, by memory) and, of the
+# free axis, everything the operand's rows hold: its rows come into the
+# stages in ONE gather of whole rows of the operand itself
 # (:func:`dft_source_rows`: Makhoul's order and the digit a brought next to
-# the free axis, which is where the MXU contracts), the two inner stages
-# (:func:`dft_blocks`) are one dense contraction each and, v being real, only
-# for κ1 ≤ f1/2 (Z[N1 − k1] = conj Z[k1]): N real numbers in, N out.
-# The outer factor, its twiddles and Makhoul's are one dot of 2R real terms
-# a sampled output (:func:`sample_outer_dft`): s outputs, never the N.
+# the free axis, which is where the MXU contracts; no slice of the operand
+# is taken first: a gather of rows of 2 KB and more moves its bytes as fast
+# as a copy would), their signs by the same fold of the sign vector
+# (:func:`dft_source_signs`), the two inner stages (:func:`dft_blocks`, which
+# see the pass as the split (ρ, f1, f2) of a shorter axis) are one dense
+# contraction each and, v being real, only for κ1 ≤ f1/2 (Z[N1 − k1] =
+# conj Z[k1]): ρ·f1·f2 real numbers in, as many out. The outer factor, its
+# twiddles and Makhoul's are one dot of 2ρ real terms a sampled output and
+# pass (:func:`sample_outer_dft`), added up over the passes: s outputs,
+# never the N.
 #
 # Every array the stages pass between them lies on whole (8, 128) tiles of
 # the chip, 8 rows by 128 free-axis entries: a digit that stands next to the
@@ -373,29 +383,57 @@ def _pad_to(x: int, mult: int = 8) -> int:
     return -(-x // mult) * mult
 
 
-#: The v5e compiler lays a gather's indices in tiles of 1024 and, where they
-#: fill whole tiles, gathers 128 rows a step with a quarter of the buffers it
-#: takes otherwise (256 rows a step): 12–15 ns a row against 6–8, whatever
-#: the row's length (10⁶ × 256 from 1,024,000 rows 50 ms, from 1,000,000 24).
+#: The v5e compiler lays a gather's indices in tiles of 1024 and, where
+#: their count ends within a step or two of a tile's end, gathers 128 rows a
+#: step with a quarter of the buffers it takes otherwise (256 rows a step):
+#: 12–15 ns a row against 6–8 at rows of 1 KB (10⁶ × 256 from 1,024,000 rows
+#: 50 ms, from 1,000,000 24). Compiled here, the slack up to the tile's end
+#: at which it still does: 0 (every whole count), 84 (16,300), 127 (16,257),
+#: 192 (33,600), 255 (33,537); and no longer at 168 (32,600), 200 (16,184),
+#: 256 (33,536). No rule was found for the reach; 256 is the widest seen.
 _GATHER_INDEX_TILE = 1024
 
 
+def _gathers_fast(count: int) -> bool:
+    """Whether a gather of ``count`` whole rows surely runs 256 rows a
+    step: its indices end more than 256 short of an index tile's end."""
+    return -count % _GATHER_INDEX_TILE > 256
+
+
+def _sample_chunk(sample_bytes: int, each: int = 0) -> int:
+    """Samples :func:`_sampled_in_chunks` takes at a time: what fits
+    ``_SAMPLE_CHUNK_BYTES`` at ``sample_bytes`` a sample — for a caller that
+    says how many rows it gathers a sample (``each``), the next multiple of
+    8 past that (at most 64 samples past) whose rows the compiler gathers
+    fast (:func:`_gathers_fast`; 10⁶ × 1024 on ρ = 50: 168 samples of 100
+    rows, 69 MB, not 163)."""
+    chunk = max(8, _SAMPLE_CHUNK_BYTES // sample_bytes)
+    if not each:
+        return chunk
+    chunk = _pad_to(chunk)                  # whole tiles of samples
+    return next((c for c in range(chunk, chunk + 64, 8)
+                 if _gathers_fast(c * each)), chunk)
+
+
 def dft_pads(factors: tuple) -> tuple:
-    """``(f1p, hp, f2p, blocks)``: the extents the stages' arrays carry.
+    """``(f1p, hp, f2p, blocks)``: the extents the stages' arrays carry, for
+    the split ``factors`` = (R, f1, f2) of an axis or (ρ, f1, f2) of a pass
+    over ρ of its R slabs (only ``blocks`` follows the first factor).
     For the digits that stand next to the free axis — a of stage one's input
     (f1) and κ1 of its result (h = f1//2 + 1 values), each up to a multiple
     of 8, the rows of a tile; κ2 of stage two's result (f2) up to a multiple
     of 4, so that its rows (re | im, κ2) are whole tiles (1 where f2 = 1: no
-    stage two). And ``blocks`` of R slabs of stage one's input, the digit b:
-    f2, or one more where f2·R·f1p gathered rows would fill whole index
-    tiles (``_GATHER_INDEX_TILE``) and f2 + 1 blocks do not (never where
-    f2 = 1). The pads are zero columns and rows of :func:`dft_tables`."""
+    stage two). And ``blocks`` of slabs of stage one's input, the digit b:
+    f2, or one more where the f2·ρ·f1p rows a pass gathers would fill whole
+    index tiles (``_GATHER_INDEX_TILE``) and f2 + 1 blocks do not (never
+    where f2 = 1). The pads are zero columns and rows of :func:`dft_tables`."""
     r, f1, f2 = factors
     f1p = _pad_to(f1)
     slab = r * f1p
-    more = f2 * slab % _GATHER_INDEX_TILE == 0 and slab % _GATHER_INDEX_TILE
+    more = (f2 > 1 and not _gathers_fast(f2 * slab)
+            and _gathers_fast((f2 + 1) * slab))
     return (f1p, _pad_to(f1 // 2 + 1), _pad_to(f2, 4) if f2 > 1 else 1,
-            f2 + bool(more))
+            f2 + more)
 
 
 @functools.lru_cache(maxsize=8)
@@ -430,29 +468,69 @@ def dft_tables(factors: tuple) -> tuple:
     return F1, T2.reshape(hp, 2 * f2p, 2 * blocks)
 
 
-def dft_source_rows(n: int, factors: tuple, mixer: str) -> jnp.ndarray:
-    """The row of the operand that stands at row (b·R + r)·f1p + a of the
-    stages' input (int32, length blocks·R·f1p of :func:`dft_pads`):
-    v[(a·f2 + b)·R + r] — the digit stage one contracts brought next to the
-    free axis, in whole tiles of rows — with v Makhoul's order for the DCT
-    (the even rows, then the odd ones from the last back) and the operand's
-    own for the DHT. A pad names a row of the operand too (a ≥ f1 the slab's
-    last again, b ≥ f2 the first block's): its column of the factor is zero,
-    and every output of the transform depends on every row already."""
+def dft_source_rows(n: int, factors: tuple, mixer: str, slabs: int = 0,
+                    first=0) -> jnp.ndarray:
+    """The row of the operand that stands at row (b·ρ + r − first)·f1p + a
+    of the stages' input for the pass over the ρ = ``slabs`` slabs r ∈
+    [first, first + ρ) of the sampled digit (all R from 0 by default;
+    ``first`` may be traced): int32, length blocks·ρ·f1p of
+    :func:`dft_pads` (ρ, f1, f2), v[(a·f2 + b)·R + r] — the digit stage one
+    contracts brought next to the free axis, in whole tiles of rows — with v
+    Makhoul's order for the DCT (the even rows, then the odd ones from the
+    last back) and the operand's own for the DHT. A pad names a row of the
+    operand too (a ≥ f1 the slab's last again, b ≥ f2 the first block's): its
+    column of the factor is zero, and every output of the transform depends
+    on every row already."""
     r, f1, f2 = factors
-    f1p, _, _, blocks = dft_pads(factors)
-    i = jnp.arange(blocks * r * f1p, dtype=jnp.int32)
-    j = jnp.minimum(i % f1p, f1 - 1) * (f2 * r) + i // f1p % (f2 * r)
+    slabs = slabs or r
+    f1p, _, _, blocks = dft_pads((slabs, f1, f2))
+    i = jnp.arange(blocks * slabs * f1p, dtype=jnp.int32)
+    slab = i // f1p                                     # b·ρ + r − first
+    j = (jnp.minimum(i % f1p, f1 - 1) * (f2 * r) + slab // slabs % f2 * r
+         + slab % slabs + first)
     if mixer != "dct":
         return j
     return jnp.where(j < (n + 1) // 2, 2 * j, 2 * (n - 1 - j) + 1)
 
 
+def _makhoul_order(D: jnp.ndarray) -> jnp.ndarray:
+    """A vector's even entries, then its odd ones from the last back — each
+    256 entries parted (evens | odds) by an exact 0 / 1 permutation on the
+    MXU: a strided slice of a vector compiles to a gather of scalars on a
+    v5e, and that costs a scalar what a row gather costs a row (10⁶: 11 ms)."""
+    n = D.shape[0]
+    lane = np.arange(256)
+    P = np.zeros((256, 256), np.float32)
+    P[lane, lane // 2 + 128 * (lane % 2)] = 1.0
+    E = jnp.dot(jnp.pad(D, (0, -n % 256)).reshape(-1, 256),
+                jnp.asarray(P, D.dtype), precision=jax.lax.Precision.HIGHEST)
+    even, odd = E[:, :128].reshape(-1), E[:, 128:].reshape(-1)
+    return jnp.concatenate([even[:(n + 1) // 2], odd[:n // 2][::-1]])
+
+
+def dft_source_signs(D: jnp.ndarray, factors: tuple, mixer: str,
+                     slabs: int = 0) -> jnp.ndarray:
+    """The entries of a vector D over the axis at the rows
+    :func:`dft_source_rows` names, for every pass of ``slabs`` slabs at
+    once, as (blocks, R, f1p): the slabs [first, first + ρ) of axis 1,
+    flattened, are ``D[dft_source_rows(n, factors, mixer, slabs, first)]``
+    with zeros at the pads. No gather (its price is above): the rows' own
+    fold (a, b, r) → (b, r, a) of D itself, which a vector can afford."""
+    r, f1, f2 = factors
+    f1p, _, _, blocks = dft_pads((slabs or r, f1, f2))
+    if mixer == "dct":
+        D = _makhoul_order(D)
+    return jnp.pad(D.reshape(f1, f2, r).transpose(1, 2, 0),
+                   ((0, blocks - f2), (0, 0), (0, f1p - f1)))
+
+
 def dft_blocks(U: jnp.ndarray, factors: tuple, tables) -> jnp.ndarray:
     """The inner stages of the DFT of a real v along axis 0, for U
-    (blocks·R·f1p, w) = v in the row order of :func:`dft_source_rows`: Z for
-    κ1 ≤ f1/2 only, as a (hp·R·2·f2p, w) array whose row (κ1, r, re|im, κ2)
-    is ((κ1·R + r)·2 + re|im)·f2p + κ2 — with f2 = 1 a (R·2·hp, w) one, row
+    (blocks·R·f1p, w) = v in the row order of :func:`dft_source_rows` — or,
+    with ``factors`` = (ρ, f1, f2) and their tables, the ρ slabs of one pass:
+    the stages leave the sampled digit alone. Z for κ1 ≤ f1/2 only, as a
+    (hp·R·2·f2p, w) array whose row (κ1, r, re|im, κ2) is
+    ((κ1·R + r)·2 + re|im)·f2p + κ2 — with f2 = 1 a (R·2·hp, w) one, row
     (r·2 + re|im)·hp + κ1 — zero at the pads (:func:`dft_pads`).
     Both sides of each contraction carry float32 (``highest``: a DFT factor
     is not exact in bfloat16); each result is written in the order the
@@ -493,46 +571,52 @@ def _cis_turns(p: jnp.ndarray, period: int):
 
 
 def sample_outer_dft(Z: jnp.ndarray, idx: jnp.ndarray, n: int,
-                     factors: tuple, mixer: str, scale: float) -> jnp.ndarray:
+                     factors: tuple, mixer: str, scale: float,
+                     first=0) -> jnp.ndarray:
     """``scale`` · rows ``idx`` of the unnormalized DCT-II (``mixer``
     ``"dct"``; FFTW REDFT10: y_k = 2·Σ_j x_j·cos(πk(2j+1)/2N)) or DHT
-    (``"dht"``) of an axis of ``n``, from Z = :func:`dft_blocks` of it:
-    the outer factor at the sampled outputs only. Output k reads the R
-    rows r of Z[k mod f1·f2] (re and im; the conjugate's where κ1 > f1/2)
-    against e^{−2πi·(r·k mod n)/n}, for the DCT times e^{−iπk/2n}: phases
-    reduced in int32 (R·n < 2³¹ by the rule of :func:`dft_factors`), the
-    gathered rows held ``chunk`` samples at a time (≤
-    ``_SAMPLE_CHUNK_BYTES``)."""
-    r, f1, f2 = factors
+    (``"dht"``) of an axis of ``n`` = R·f1·f2, from Z = :func:`dft_blocks`
+    of it: the outer factor at the sampled outputs only — or, from the Z of
+    the pass over the slabs r ∈ [first, first + ρ) (ρ read off Z's rows,
+    ``first`` may be traced), those slabs' part of it, the passes' parts to
+    be added up. Output k reads the rows r of Z[k mod f1·f2] (re and im; the
+    conjugate's where κ1 > f1/2) against e^{−2πi·(r·k mod n)/n}, for the DCT
+    times e^{−iπk/2n}: phases reduced in int32 (R·n < 2³¹ by the rule of
+    :func:`dft_factors`), the gathered rows held ``chunk`` samples at a time
+    (≤ ``_SAMPLE_CHUNK_BYTES``)."""
+    _, f1, f2 = factors
     _, hp, f2p, _ = dft_pads(factors)
     under = f2p if f2 > 1 else hp
-    w = Z.shape[1]
-    j = jnp.arange(r, dtype=jnp.int32)[None, :]
+    r, w = Z.shape[0] // (2 * hp * f2p), Z.shape[1]     # the pass's slabs
+    j = jnp.arange(r, dtype=jnp.int32)[:, None]
 
     def rows(ix):
         k1 = ix % (f1 * f2)
-        ka, kb = (k1 % f1)[:, None], (k1 // f1)[:, None]
+        ka, kb = (k1 % f1)[None, :], (k1 // f1)[None, :]
         mirrored = ka > f1 // 2              # Z[k1] = conj Z[f1·f2 − k1]
         ka = jnp.where(mirrored, f1 - ka, ka)
         kb = jnp.where(mirrored, f2 - 1 - kb, kb)
-        phase = (ix[:, None] * j) % n
+        phase = (ix[None, :] * (j + first)) % n
         if mixer == "dct":
-            cos, sin = _cis_turns((4 * phase + ix[:, None]) % (4 * n), 4 * n)
+            cos, sin = _cis_turns((4 * phase + ix[None, :]) % (4 * n), 4 * n)
             on_re, on_im = 2.0 * cos, 2.0 * sin
         else:                                # Re V − Im V
             cos, sin = _cis_turns(phase, n)
             on_re, on_im = cos + sin, sin - cos
         on_im = jnp.where(mirrored, -on_im, on_im)
         # the re rows (:func:`dft_blocks`' order), the im rows the padded
-        # extent of the digit under re|im after them
+        # extent of the digit under re|im after them; the samples stand
+        # next to the free axis (whole tiles of them: the fold below is a
+        # bitcast whatever 2ρ is) and the sum runs over the leading axis
         at = ((ka * r + j) * 2 * under + kb) if f2 > 1 else j * 2 * under + ka
-        at = jnp.concatenate([at, at + under], axis=1).reshape(-1)
-        weight = jnp.float32(scale) * jnp.concatenate([on_re, on_im], axis=1)
+        at = jnp.concatenate([at, at + under], axis=0).reshape(-1)
+        weight = jnp.float32(scale) * jnp.concatenate([on_re, on_im], axis=0)
         # whole rows of Z as it lies, multiplied and added up in float32
-        return jnp.sum(weight[:, :, None] * Z[at].reshape(-1, 2 * r, w),
-                       axis=1)
+        return jnp.sum(weight[:, :, None] * Z[at].reshape(2 * r, -1, w),
+                       axis=0)
 
-    return _sampled_in_chunks(rows, idx, 2 * r * w * Z.dtype.itemsize, w)
+    return _sampled_in_chunks(rows, idx, 2 * r * w * Z.dtype.itemsize, w,
+                              each=2 * r)
 
 
 class FUT:

@@ -183,8 +183,11 @@ SPANS: Dict[str, Tuple[str, str]] = {
     # fut.dft_factors as the axis has it — the stages' arrays carry f1, its
     # half and f2 padded to whole tiles of rows, fut.dft_pads, and the span
     # does not say so; the sampled outer factor first), tile (the free-axis
-    # entries a pass of the walk takes: the Pallas pass's, MIX_TILE, or
-    # fjlt.dft_tile of the axis; for the operator), elements (= axis ×
+    # entries a pass of the walk takes: the Pallas pass's, MIX_TILE, or for
+    # "xla_dft" a columnwise operand's whole free axis, else fjlt.dft_tile
+    # of the axis; for the operator), slabs ("xla_dft" only: fjlt.dft_slabs,
+    # the ρ of the R slabs of the sampled digit a pass takes — R ÷ slabs
+    # passes an apply or a tile; for the operator), elements (= axis ×
     # columns mixed, which mix_rate.apply reads: the operand's, no pad
     # counted) and sampled (= s × columns kept)
     "sketch.apply": ("sketch kernel", "sketch_host_ms.apply"),

@@ -20,6 +20,7 @@ on the CPU:
 import json
 import math
 import pathlib
+import types
 
 import jax
 import jax.numpy as jnp
@@ -131,7 +132,8 @@ def test_the_pads_of_the_tables_are_exactly_zero(factors, pads):
     assert f1p % 8 == 0 and hp % 8 == 0 and f1 <= f1p < f1 + 8 and h <= hp < h + 8
     assert (2 * f2p) % 8 == 0 and f2 <= f2p < f2 + 4 if f2 > 1 else f2p == 1
     assert blocks in (f2, f2 + 1)
-    assert (blocks * r * f1p) % 1024 or (r * f1p) % 1024 == 0
+    assert fut._gathers_fast(blocks * r * f1p) or f2 == 1 or not (
+        fut._gathers_fast(f2 * r * f1p) or fut._gathers_fast((f2 + 1) * r * f1p))
     tables = fut.dft_tables(factors)
     F1 = tables[0].reshape(2, hp, f1p)
     assert not F1[:, h:].any() and not F1[:, :, f1:].any()
@@ -143,27 +145,54 @@ def test_the_pads_of_the_tables_are_exactly_zero(factors, pads):
         assert np.abs(T2[:h, :, :f2]).sum(axis=(1, 3, 4)).min() >= 1.0
 
 
-@pytest.mark.parametrize("n,factors", [
-    (12, (1, 4, 3)), (15, (5, 3, 1)), (1000, (4, 50, 5)), (96, (3, 8, 4)),
-    (1000, (8, 125, 1)),
-    (1024, (4, 64, 4)),             # a block more: 5·4·64 rows
-    (1024, (16, 64, 1))])
-def test_source_rows_are_makhouls_order_with_the_stage_digit_last(n, factors):
+@pytest.mark.parametrize("n,factors,slabs", [
+    (12, (1, 4, 3), 1), (15, (5, 3, 1), 5), (1000, (4, 50, 5), 4),
+    (96, (3, 8, 4), 3), (1000, (8, 125, 1), 8),
+    (1024, (4, 64, 4), 4),          # a block more: 5·4·64 rows
+    (1024, (16, 64, 1), 16),
+    # the walk over the sampled digit: ρ of the R slabs a pass
+    (15, (5, 3, 1), 1), (1000, (4, 50, 5), 2), (1000, (4, 50, 5), 1),
+    (96, (3, 8, 4), 1), (1000, (8, 125, 1), 4),
+    (1024, (4, 64, 4), 2),          # 4·2·64 rows are no index tile: 4 blocks
+    (1024, (16, 64, 1), 8), (6000, (16, 25, 15), 4)])
+def test_source_rows_are_makhouls_order_with_the_stage_digit_last(
+        n, factors, slabs):
+    """A pass over ρ = ``slabs`` slabs of the sampled digit from ``first``
+    names, slab by slab, the rows the walk over all R names for those slabs:
+    the passes together are Makhoul's order with the digit a last."""
     r, f1, f2 = factors
-    f1p, _, _, blocks = fut.dft_pads(factors)
+    f1p, _, _, blocks = fut.dft_pads((slabs, f1, f2))
     x = np.arange(n)
     v = np.concatenate([x[::2], x[1::2][::-1]])          # Makhoul's order
+    D = np.random.default_rng(n).choice([-1.0, 1.0], n).astype(np.float32)
     for mixer, order in (("dct", v), ("dht", x)):
-        got = np.asarray(fut.dft_source_rows(n, factors, mixer))
-        assert got.shape == (blocks * r * f1p,) and got.dtype == np.int32
-        slabs = got.reshape(blocks * r, f1p)             # [(b, r), a]
-        live = slabs[:f2 * r]
-        assert np.array_equal(live[:, :f1], order.reshape(f1, n // f1).T)
-        # a pad names a row of the operand (its column of the factor is zero)
-        assert slabs.min() >= 0 and slabs.max() < n
-        assert np.array_equal(live[:, f1:],
-                              np.repeat(live[:, f1 - 1:f1], f1p - f1, axis=1))
-        assert np.array_equal(slabs[f2 * r:], live[:(blocks - f2) * r])
+        whole = order.reshape(f1, f2, r)                 # [a, b, r]
+        for first in range(0, r, slabs):
+            got = np.asarray(fut.dft_source_rows(
+                n, factors, mixer, slabs, jnp.int32(first)))
+            assert got.shape == (blocks * slabs * f1p,)
+            assert got.dtype == np.int32
+            rows = got.reshape(blocks, slabs, f1p)       # [b, r − first, a]
+            live = rows[:f2]
+            assert np.array_equal(
+                live[:, :, :f1],
+                whole[:, :, first:first + slabs].transpose(1, 2, 0))
+            # a pad names a row of the operand (its column of the factor is
+            # zero): the slab's last again, the first block's slabs again
+            assert rows.min() >= 0 and rows.max() < n
+            assert np.array_equal(
+                live[:, :, f1:],
+                np.repeat(live[:, :, f1 - 1:f1], f1p - f1, axis=2))
+            assert np.array_equal(rows[f2:], live[:blocks - f2])
+            # the signs of those rows by a fold of the sign vector itself,
+            # no gather; zero at the pads
+            signs = np.asarray(fut.dft_source_signs(
+                jnp.asarray(D), factors, mixer, slabs))[:, first:first + slabs]
+            assert signs.shape == (blocks, slabs, f1p)
+            assert np.array_equal(signs[:f2, :, :f1], D[live[:, :, :f1]])
+            assert not signs[f2:].any() and not signs[:, :, f1:].any()
+    if slabs == r:                  # the default: every slab, from the first
+        assert np.array_equal(fut.dft_source_rows(n, factors, "dht"), got)
 
 
 def test_cis_turns_to_an_ulp_whatever_the_period():
@@ -321,25 +350,87 @@ def test_program_against_the_dense_operator(mixer, rowwise, m):
     assert _rel(got.T if rowwise else got, ref) < REL_MAX
 
 
-@pytest.mark.parametrize("n,tile", [
-    (1_000_000, 512),            # the cell
-    (1 << 21, 256), (1 << 22, 128), (1_200_000, 384),
-    (500_000, 512),              # no wider tile than the chip has read
-    (1000, 512), (2, 512)])      # a short axis
-def test_the_tile_follows_the_axis(n, tile):
-    """The widest multiple of 128 columns, to 512, whose temporaries — at
-    their largest two of the stages' padded arrays — stay under the budget."""
-    assert fjlt.dft_tile(n) == tile
+@pytest.mark.parametrize("mixer", ["dct", "dht"])
+@pytest.mark.parametrize("n,factors,m,rowwise,held,tile,slabs", [
+    # whole rows of a columnwise operand (a ragged 37 of them), 2 passes
+    (1000, (10, 20, 5), 37, False, (5, 37, False), 37, 5),
+    (1000, (10, 20, 5), 37, False, (1, 37, False), 37, 1),      # and 10
+    # a rowwise operand: its tile is a transposed copy, 128 + a ragged 12
+    (1000, (10, 20, 5), 140, True, (2, 128, True), 128, 2),
+    # a free axis too wide for whole rows of even one slab: cut as well
+    (1000, (10, 20, 5), 800, False, (2, 128, True), 128, 2),
+    # 5 blocks at ρ = 4 (an index tile), 4 at ρ = 2; one inner stage
+    (1024, (4, 64, 4), 24, False, (2, 24, False), 24, 2),
+    (1024, (16, 64, 1), 24, True, (8, 24, True), 128, 8),
+    (6000, (16, 25, 15), 50, False, (4, 50, False), 50, 4)])
+def test_the_walk_over_the_sampled_digit_in_several_passes(
+        monkeypatch, mixer, n, factors, m, rowwise, held, tile, slabs):
+    """The budget shrunk to what ``held`` = (ρ, w, copied) takes: the plan's
+    two tile extents follow, and the outer sum added up ρ slabs at a time is
+    the float64 sum of the definition within the configuration's limit."""
+    monkeypatch.setattr(fjlt, "_DFT_TEMP_BYTES",
+                        fjlt._dft_pass_bytes(n, factors, *held))
+    s = 96
+    A = _operand(n, m, n + m)
+    T = sk.FJLT(n, s, Context(n + slabs), fut=mixer)
+    if factors == fut.dft_factors(n):
+        assert T.mix_plan(A.T if rowwise else A, rowwise) == (
+            "xla_dft", factors, tile)
+    assert fjlt.dft_slabs(n, factors, tile, m, rowwise) == slabs < factors[0]
+    D = np.asarray(T.diagonal(), np.float64)[:, None]
+    scale = math.sqrt(n / s) * T._fut.scale()
+    ref = scale * _definition(D * np.asarray(A, np.float64), mixer)[
+        np.asarray(T.sample_indices())]
+    got = fjlt.fjlt_mix_sample(
+        T.allocation.key_data, A.T if rowwise else A, s_dim=s, rowwise=rowwise,
+        kernel="xla_dft", tile=tile, fut=mixer, factors=factors)
+    assert _rel(got.T if rowwise else got, ref) < REL_MAX
+    if mixer == "dct":                       # and the plain reference's DCT
+        plain = reference.apply_cols(A, *reference.streams(n + slabs, 0, n, s))
+        assert _rel(got.T if rowwise else got, plain) < REL_MAX
+
+
+@pytest.mark.parametrize("n,m,rowwise,tile,slabs", [
+    (1_000_000, 1024, False, 1024, 50),      # the cell: whole rows, two passes
+    (1_000_000, 602, False, 602, 100),       # the widest rows one pass takes
+    (1_000_000, 603, False, 603, 50),
+    (1_000_000, 128, False, 128, 100),
+    (1_000_000, 4096, False, 4096, 10),
+    (1_000_000, 60_000, False, 60_000, 1),   # one slab of whole rows fits
+    (1_000_000, 61_000, False, 512, 100),    # none does: the free axis is cut
+    (1_000_000, 1024, True, 512, 100),       # a rowwise tile is transposed
+    (1 << 21, 512, False, 512, 64), (1 << 21, 512, True, 256, 128),
+    (1 << 22, 256, False, 256, 128), (1 << 22, 256, True, 128, 256),
+    (1_200_000, 1024, True, 384, 100),
+    (500_000, 2048, False, 2048, 40), (500_000, 2048, True, 512, 80),
+    (96_000, 384, False, 384, 40),           # chip_smoke's DCT leg: one pass
+    (1000, 40, False, 40, 10), (1000, 700, True, 512, 10),
+    (2, 3, False, 3, 1)])                    # a short axis
+def test_the_tile_follows_the_axis(n, m, rowwise, tile, slabs):
+    """Columnwise, whole rows of the operand wherever one slab of the
+    sampled digit fits the budget, else — and rowwise, whose tile is
+    transposed first — the cut tile: the widest multiple of 128 columns, to
+    512, that fits as a copy, all R slabs in its one pass. Then the most
+    slabs a pass, a divisor of R, whose temporaries — two of the stages'
+    padded arrays at a time and, while another pass will read it, the copy
+    — stay under the budget."""
     r, f1, f2 = factors = fut.dft_factors(n)
-    f1p, hp, f2p, blocks = fut.dft_pads(factors)
-    gathered, one = blocks * r * f1p, blocks * r * 2 * hp
-    two = hp * r * 2 * f2p if f2 > 1 else 0
-    held = 4 * tile * max(n + gathered, gathered + one, one + two)
-    assert held <= fjlt._DFT_TEMP_BYTES
-    assert held <= fjlt._DFT_ENTRY_BYTES * n * tile or n < 1 << 18
-    A = jnp.zeros((n, 1), jnp.float32)
-    assert sk.FJLT(n, 64, Context(0)).mix_plan(A, False) == (
+    # what the plan reads of an operand, without its gigabytes
+    A = types.SimpleNamespace(shape=(m, n) if rowwise else (n, m),
+                              dtype=jnp.dtype("float32"), devices=lambda: {0})
+    assert sk.FJLT(n, 64, Context(0)).mix_plan(A, rowwise) == (
         "xla_dft", factors, tile)
+    assert fjlt.dft_slabs(n, factors, tile, m, rowwise) == slabs
+    assert r % slabs == 0
+    w, copied = min(m, tile), rowwise or m > tile
+    held = fjlt._dft_pass_bytes(n, factors, slabs, w, copied)
+    f1p, _, _, blocks = fut.dft_pads((slabs, f1, f2))
+    assert 2 * 4 * w * blocks * slabs * f1p <= held <= fjlt._DFT_TEMP_BYTES
+    more = [d for d in range(slabs + 1, r + 1) if r % d == 0]
+    assert not more or fjlt._dft_pass_bytes(
+        n, factors, more[0], w, copied) > fjlt._DFT_TEMP_BYTES
+    if tile < m:
+        assert tile == fjlt.dft_tile(n) and tile % 128 == 0 and tile <= 512
 
 
 def test_bf16_table_control_fails_the_configurations_rel_max():
@@ -439,6 +530,8 @@ def test_span_attributes_and_the_counter():
     A = _operand(n, m, 1)
     T = sk.FJLT(n, s, Context(3))
     factors = fut.dft_factors(n)
+    wide = jnp.tile(A.T, (30, 1))           # rowwise: the tile is cut
+    hadamard = sk.FJLT(1024, s, Context(3), fut="wht")
     before_enabled = metrics._ENABLED
     counted = fjlt._MIXED.value(family="FJLT", kernel="xla_dft")
     trace.clear_finished()
@@ -446,18 +539,26 @@ def test_span_attributes_and_the_counter():
     try:
         T.apply(A, sk.COLUMNWISE).block_until_ready()
         spans = {sp.name: sp for sp in trace.finished_spans()}
+        T.apply(wide, sk.ROWWISE).block_until_ready()
+        hadamard.apply(A[:1024], sk.COLUMNWISE).block_until_ready()
+        _, row, wht = [sp.attrs for sp in trace.finished_spans()
+                       if sp.name == "sketch.dispatch"]
     finally:
         metrics._ENABLED = before_enabled
         trace.clear_finished()
     dispatch, apply = spans["sketch.dispatch"], spans["sketch.apply"]
     assert dispatch.parent_id == apply.span_id
+    # whole rows of the columnwise operand, every slab of the sampled digit
     assert dispatch.attrs == {
         "path": "fut", "family": "FJLT", "fut": "dct", "kernel": "xla_dft",
-        "factors": factors, "tile": fjlt.dft_tile(n), "elements": n * m,
-        "sampled": s * m}
+        "factors": factors, "tile": m, "slabs": factors[0],
+        "elements": n * m, "sampled": s * m}
     assert factors[0] * factors[1] * factors[2] == n
     assert "sketch.plan" not in spans
-    assert fjlt._MIXED.value(family="FJLT", kernel="xla_dft") == counted + n * m
+    assert (row["tile"], row["slabs"]) == (fjlt.dft_tile(n), factors[0])
+    assert "slabs" not in wht               # the Hadamard route has none
+    assert fjlt._MIXED.value(family="FJLT", kernel="xla_dft") == (
+        counted + n * m + n * 30 * m)
 
 
 def test_the_kernel_name_is_declared():

@@ -329,10 +329,10 @@ DCT_ROWS = 1_000_000
 
 
 def _instructions(text):
-    """``(computation, name, elements, opcode, shape)`` of every array-valued
-    instruction of a compiled module's text — a fusion is one instruction of
-    the computation that calls it, and what it fuses is listed under its own
-    ``fused_computation``."""
+    """``(computation, name, elements, opcode, shape, operands)`` of every
+    array-valued instruction of a compiled module's text — a fusion is one
+    instruction of the computation that calls it, and what it fuses is
+    listed under its own ``fused_computation``."""
     computation = None
     for line in text.splitlines():
         head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
@@ -340,61 +340,90 @@ def _instructions(text):
             computation = head.group(1)
             continue
         op = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\w+\[([\d,]*)\])\S* "
-                      r"([\w\-]+)\(", line)
+                      r"([\w\-]+)\(([^)]*)\)", line)
         if op:
             elements = math.prod(int(d) for d in op.group(3).split(",") if d)
-            yield computation, op.group(1), elements, op.group(4), op.group(2)
+            yield (computation, op.group(1), elements, op.group(4),
+                   op.group(2), re.findall(r"%([\w.\-]+)", op.group(5)))
 
 
-def test_cell_shape_fjlt_dct_mix_sample(one_chip):
+@pytest.mark.parametrize("cols,slabs", [
+    (FJLT_COLS, 50),            # the cell: two passes of 50 slabs
+    (128, 100)])                # short rows: every slab in one pass, no loop
+def test_cell_shape_fjlt_dct_mix_sample(one_chip, cols, slabs):
     """FJLT(1,000,000, 4096) — the default mixer — columnwise of 1,000,000 ×
-    1024 as the one program: the blocked DFT on XLA (no Mosaic call), a tile
-    of 512 columns at a time. Every array the stages pass between them lies
-    on whole (8, 128) tiles, so no pass over a tile only moves data: outside
-    the fusions that compute (the slice with the signs, the row gather, the
-    two contractions) the tile loop holds no tile-sized ``reshape``, ``copy``
-    or ``transpose``, and the signs are no gather of N scalars. Beside the
-    operand and the result it holds two (N × tile) float32 arrays with their
-    pads at a time — 4.26 GB of the ≈ 7 that two resident operands leave of
-    the chip — and never an operand-sized or a complex one."""
+    1024 as the one program: the blocked DFT on XLA (no Mosaic call), walked
+    over the sampled digit, 50 of its 100 slabs a pass. A pass is three
+    tile-sized fusions — the gather of whole rows of the operand itself (no
+    slice of it first, no op over an operand-shaped array), stage one with
+    the signs of the gathered rows as an operand of its own fusion (no sign
+    pass, no gather of scalars anywhere), stage two — and, every array
+    between them lying on whole (8, 128) tiles, no tile-sized ``reshape``,
+    ``copy`` or ``transpose``. Both row gathers run 256 rows a step. Beside
+    the operand and the result the program holds two of a pass's arrays at a
+    time — 4.26 GB of the ≈ 7 that two resident operands leave of the chip —
+    and never an operand-sized or a complex one. The same at 128 columns,
+    where all 100 slabs fit one pass."""
     from libskylark_tpu.sketch import fjlt, fut
 
-    factors = fut.dft_factors(DCT_ROWS)
-    tile = fjlt.dft_tile(DCT_ROWS)
-    assert factors == (100, 125, 80) and tile == 512
+    r, f1, f2 = factors = fut.dft_factors(DCT_ROWS)
+    assert factors == (100, 125, 80)
+    assert fjlt.dft_slabs(DCT_ROWS, factors, cols, cols, False) == slabs
+    part = (slabs, f1, f2)
+    f1p, _, _, blocks = fut.dft_pads(part)
+    gathered = blocks * slabs * f1p             # rows a pass gathers
+    assert (f1p, blocks) == (128, 81)
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     program = jax.jit(functools.partial(
         fjlt.fjlt_mix_sample, s_dim=FJLT_S, rowwise=False, kernel="xla_dft",
-        tile=tile, fut="dct", factors=factors))
-    tables = fut.dft_tables(factors)
+        tile=cols, fut="dct", factors=factors))
+    tables = fut.dft_tables(part)
     compiled = program.lower(
-        arg((2,), jnp.uint32), arg((DCT_ROWS, FJLT_COLS), jnp.float32),
+        arg((2,), jnp.uint32), arg((DCT_ROWS, cols), jnp.float32),
         *[arg(t.shape, jnp.float32) for t in tables]).compile()
     text = compiled.as_text()
     assert KERNEL not in text
     assert not re.search(r"\bc64\[", text)                # no complex array
-    assert not re.search(r"f32\[1000000,1024\]\S* (copy|fusion)\(", text)
-    called = [i for i in _instructions(text) if "fused_computation" not in i[0]]
-    moved = [i for i in called if i[2] >= DCT_ROWS * tile // 2
-             and i[3] in ("reshape", "copy", "transpose")]
+    called = {i[1]: i for i in _instructions(text)
+              if "fused_computation" not in i[0]}
+    # the operand is read where it lies: nothing computes an array of its
+    # shape (the parent sliced the tile out of it with the signs)
+    over = [i for i in called.values() if i[4].startswith("f32[1000000,")
+            and i[3] not in ("parameter", "get-tuple-element", "bitcast")]
+    assert not over, over
+    tile_sized = [i for i in called.values() if i[2] >= gathered * cols // 2]
+    moved = [i for i in tile_sized if i[3] in ("reshape", "copy", "transpose")]
     assert not moved, moved
-    # the tile's passes: slice × signs, row gather, stage one, stage two
-    passes = [i for i in called if i[2] >= DCT_ROWS * tile // 2
-              and i[3] == "fusion"]
-    assert len(passes) == 4, passes
-    assert not re.search(r"f32\[1000000\]\S* gather\(", text)
-    # both row gathers 256 rows a step: 81 blocks of 100 slabs of 128 rows
-    # fill no whole number of 1024-index tiles, where 80 blocks would, and
-    # this compiler gathers those 128 rows a step, half as fast on the chip
-    steps = re.findall(r"f32\[\d+,512\]\S* fusion\(.*/gather\".*"
-                       r"\"integer_config\":\{\"integer\":\"(\d+)\"", text)
+    # a pass: the row gather, stage one, stage two
+    passes = [i for i in tile_sized if i[3] == "fusion"]
+    assert len(passes) == 3, passes
+    assert [i[4] for i in passes] == [
+        f"f32[{gathered},{cols}]", f"f32[{blocks * slabs},{cols},128]",
+        f"f32[64,{slabs},{cols},160,1]"]
+    # the signs, folded as the rows are gathered (no gather of scalars,
+    # which costs a scalar what it costs a row), enter stage one's fusion
+    def source(name):
+        while called[name][3] == "bitcast":
+            name = called[name][5][0]
+        return called[name]
+    entering = [source(name) for name in passes[1][5]]
+    assert passes[0] in entering
+    assert [i[4] for i in entering if i is not passes[0]
+            and i[2] == gathered] == [f"f32[{blocks * slabs},128]"]
+    assert not re.search(r"f32\[\d+\]\S* gather\(", text)
+    # both row gathers 256 rows a step: 81 blocks of 50 (100) slabs of 128
+    # rows, and the sample chunk's 2ρ rows a sample, fill no whole number of
+    # 1024-index tiles (fut._gathers_fast)
+    steps = re.findall(r"f32\[\d+,%d\]\S* fusion\(.*/gather\".*"
+                       r"\"integer_config\":\{\"integer\":\"(\d+)\"" % cols,
+                       text)
     assert steps == ["256", "256"], steps
     memory = compiled.memory_analysis()
-    assert memory.output_size_in_bytes == FJLT_S * FJLT_COLS * 4
-    tile_bytes = DCT_ROWS * tile * 4
-    # 4.26 GB: two padded tiles (1.037 of a tile each) and 16 MB of the rest
-    assert 2 * tile_bytes < memory.temp_size_in_bytes < 2.1 * tile_bytes
-    assert memory.temp_size_in_bytes < fjlt._DFT_TEMP_BYTES
+    assert memory.output_size_in_bytes == FJLT_S * cols * 4
+    # two of a pass's arrays (4.25 GB at the cell) and 16 MB of the rest
+    assert (2 * gathered * cols * 4 < memory.temp_size_in_bytes
+            < 2 * gathered * cols * 4 + (32 << 20))
+    assert memory.temp_size_in_bytes < min(fjlt._DFT_TEMP_BYTES, 4.3e9)
