@@ -1895,9 +1895,10 @@ def _sparse(n_requests: int = 32, max_batch: int = 8,
             "CWT is where sparsity pays: O(nnz) scatter vs the dense "
             "path's O(N*m) segment-sum. The JLT sparse flush "
             "densifies in-executable (bit-equal matmul), so its edge "
-            "is only the avoided host densify + dense stacking; "
-            "kernel-level sparse wins (pallas_sparse) open up on "
-            "real silicon via bench.py --certify-kernels."),
+            "is only the avoided host densify + dense stacking. On "
+            "a TPU the direct rowwise apply takes the rows kernel "
+            "(pallas_sparse.hash_rows_apply); the serve flush keeps "
+            "the scatter."),
         "telemetry": _telemetry_snapshot(),
     }
     print(json.dumps(rec), flush=True)
@@ -2263,7 +2264,7 @@ def _dist_serve(n_requests: int = 4, n_replicas: int = 4,
 
 def _certify_kernels(rounds: int = 5, capacity: int = 8) -> int:
     """Serve-kernel certification (``python bench.py --certify-kernels``):
-    for each of the five Pallas serve kernels, at one real serve-bucket
+    for each of the three Pallas serve kernels, at one real serve-bucket
     shape and through the entry the serve layer compiles (``qualify`` +
     the batched launcher): compile it with Mosaic, compare it with its
     XLA twin, time one flush of each. Per kernel the outcome is
@@ -2286,11 +2287,8 @@ def _certify_kernels(rounds: int = 5, capacity: int = 8) -> int:
     from libskylark_tpu import tune
     from libskylark_tpu.base import randgen
     from libskylark_tpu.sketch import (pallas_dense, pallas_fastfood,
-                                       pallas_fwht, pallas_hash,
-                                       pallas_sparse)
-    from libskylark_tpu.sketch import sparse_serve
+                                       pallas_hash)
     from libskylark_tpu.sketch.dense import serve_apply
-    from libskylark_tpu.sketch.fjlt import srht_serve_apply
     from libskylark_tpu.sketch.frft import fastfood_serve_apply
     from libskylark_tpu.sketch.hash import cwt_serve_apply
 
@@ -2354,39 +2352,6 @@ def _certify_kernels(rounds: int = 5, capacity: int = 8) -> int:
         jax.vmap(partial(fastfood_serve_apply, **geo)),
         (kdj, jnp.asarray(
             rng.standard_normal((B, m, d), dtype=np.float32)))))
-
-    n, m, s_dim, nnz = ((4096, 16, 32, 1024) if small
-                        else (65536, 64, 1024, 16384))
-    data = rng.standard_normal((B, nnz)).astype(np.float32)
-    rows = np.sort(rng.integers(0, n, (B, nnz)).astype(np.int32), axis=1)
-    cols = rng.integers(0, m, (B, nnz)).astype(np.int32)
-    ptr = np.stack([np.searchsorted(rows[b], np.arange(n + 1))
-                    for b in range(B)]).astype(np.int32)
-    geo = dict(s_dim=s_dim, rowwise=False, shape=(n, m))
-    buckets.append((
-        f"pallas_sparse cwt_cw_{n}x{m}_s{s_dim}_z{nnz}",
-        tune.serve_workload("sparse_sketch_apply", "CWT", "float32",
-                            (n, m), s_dim, B, rowwise=False, nnz=nnz),
-        pallas_sparse.qualify(s_dim, n, m, nnz, "float32"),
-        # the kernel reads COO rows, the XLA twin CSR row pointers
-        lambda k, dd, r, c, p, geo=geo:
-            pallas_sparse.cwt_sparse_apply_batched(
-                k, dd, r, c, accum="mxu", **geo),
-        jax.vmap(lambda k, dd, r, c, p, geo=geo:
-                 sparse_serve.cwt_sparse_serve_apply(k, dd, c, p, **geo)),
-        tuple(map(jnp.asarray, (kd, data, rows, cols, ptr)))))
-
-    m, n, s_dim = (8, 4096, 256) if small else (256, 8192, 1024)
-    geo = dict(s_dim=s_dim, rowwise=True)
-    buckets.append((
-        f"pallas_fwht srht_rw_{m}x{n}_s{s_dim}",
-        tune.serve_workload("sketch_apply", "SRHT", "float32", (m, n),
-                            s_dim, B, rowwise=True),
-        pallas_fwht.qualify(s_dim, n, m, "float32"),
-        partial(pallas_fwht.srht_apply_batched, **geo),
-        jax.vmap(partial(srht_serve_apply, **geo)),
-        (kdj, jnp.asarray(
-            rng.standard_normal((B, m, n), dtype=np.float32)))))
 
     results, mismatched, written = {}, [], 0
     for name, w, (ok, why), pallas_fn, xla_fn, args in buckets:

@@ -1,24 +1,17 @@
 """FWHT-serve smoke — the CI gate for the panel-free SRHT tier.
 
-A fast battery asserting the in-kernel FWHT contract end to end:
+A fast battery asserting the panel-free SRHT contract end to end:
 
 - **offline tuning**: every SRHT (bucket, capacity class) workload is
   ranked by the hardware-free cost model into an in-memory plan cache
-  (the committed ``benchmarks/plan_cache.json`` is never touched); on
-  a CPU host the decision must be "xla" for every bucket — the
-  interpret penalty certifies the honest outcome off-silicon. The
-  ``serve_cmm`` workload must enumerate exactly its one XLA candidate;
+  (the committed ``benchmarks/plan_cache.json`` is never touched); the
+  decision is "xla" for every bucket — the SRHT flush has one program,
+  the vmapped lane function. The ``serve_cmm`` workload must enumerate
+  exactly its one XLA candidate;
 - **zero recompiles with selection enabled**: warm the capacity
   ladder, then two measured SRHT + compressed-matmul storms run with
-  ZERO engine cache misses and ZERO recompiles;
-- **dyadic bit-equality of the kernel path**: a forced-pallas
-  (interpret-mode) SRHT flush on integer-lattice operands at
-  ``n = 4^k``, ``s = 4^j`` is bit-equal to the capacity-1 forced-XLA
-  dispatch, request by request — one flipped in-kernel Threefry sign
-  or swapped sample coordinate would break it;
-- **min-n decline accounting**: a transform below
-  ``SKYLARK_FWHT_MIN_N`` under a pallas pin declines (counted reason)
-  back to the XLA program, bit-equal to the reference;
+  ZERO engine cache misses and ZERO recompiles, each request bit-equal
+  to its own capacity-1 dispatch on integer-lattice operands;
 - **compressed matmul**: the ``(estimate, bound)`` future resolves
   with the estimate inside the bound on well-conditioned data, and the
   sparse-A CWT lane is bit-equal to its densified twin.
@@ -84,12 +77,10 @@ def main() -> int:
             if ent is None or ent.get("source") != "ranked":
                 violations.append(
                     f"srht/b{cap}: no ranked plan-cache entry")
-            if (jax.default_backend() != "tpu"
-                    and plan.backend != "xla"):
+            if plan.backend != "xla":
                 violations.append(
-                    f"srht/b{cap}: tuner picked {plan.backend!r} on a "
-                    "non-TPU host — the interpret penalty must "
-                    "certify XLA off-silicon")
+                    f"srht/b{cap}: tuner picked {plan.backend!r} — "
+                    "the SRHT flush has no batched kernel")
         w_cm = tune.serve_workload(
             "compressed_matmul", "CWT", "float32", (32, 1500), 256, 1,
             nnz=16)
@@ -139,66 +130,18 @@ def main() -> int:
                 "no SRHT flushes attributed — serve.fwht_flushes went "
                 "inert")
 
-        # -- dyadic bit-equality: forced kernel vs capacity-1 XLA -------
-        with engine.MicrobatchExecutor(max_batch=MAX_BATCH,
-                                       linger_us=5000,
-                                       kernel="pallas") as exp:
-            pfuts = [exp.submit_sketch(t, A, dimension=sk.ROWWISE)
-                     for t, A in zip(ts, ops)]
-            pouts = [np.asarray(f.result(timeout=600)) for f in pfuts]
-            pstats = exp.stats()["fwht"]["by_backend"]
-        if not pstats.get("pallas", {}).get("flushes"):
-            violations.append(
-                "forced-pallas executor served no pallas SRHT flushes "
-                f"(by_backend={pstats})")
-        with engine.MicrobatchExecutor(max_batch=1, linger_us=100,
-                                       kernel="xla") as ex1:
+        # -- lane invariance: a storm's request vs its capacity-1 run ---
+        with engine.MicrobatchExecutor(max_batch=1,
+                                       linger_us=100) as ex1:
             xouts = [np.asarray(ex1.submit_sketch(
                 t, A, dimension=sk.ROWWISE).result(timeout=300))
                 for t, A in zip(ts, ops)]
-        for i, (p, x) in enumerate(zip(pouts, xouts)):
-            if not np.array_equal(p, x):
-                violations.append(
-                    f"SRHT request {i}: in-kernel FWHT flush not "
-                    "bit-equal to capacity-1 XLA dispatch on dyadic "
-                    "operands")
-                break
         for i, (s_out, x) in enumerate(zip(sel_outs, xouts)):
             if not np.array_equal(np.asarray(s_out), x):
                 violations.append(
                     f"SRHT request {i}: selection-enabled flush not "
                     "bit-equal to capacity-1 XLA dispatch")
                 break
-
-        # -- min-n decline accounting under a pallas pin ----------------
-        os.environ["SKYLARK_FWHT_KERNEL"] = "pallas"
-        try:
-            t_small = FJLT(1024, 64, Context(seed=91), fut="wht")
-            a_small = rng.integers(-4, 5, size=(4, 1024)).astype(
-                np.float32)
-            with engine.MicrobatchExecutor(max_batch=1,
-                                           linger_us=100) as exd:
-                out = np.asarray(exd.submit_sketch(
-                    t_small, a_small,
-                    dimension=sk.ROWWISE).result(timeout=300))
-                dstats = exd.stats()
-        finally:
-            del os.environ["SKYLARK_FWHT_KERNEL"]
-        if not np.array_equal(
-                out, np.asarray(t_small.apply(a_small, sk.ROWWISE))):
-            violations.append("declined min-n flush diverged from the "
-                              "transform's own apply")
-        declined = dstats["kernel"]["by_reason"]
-        if not any("fwht-min-n" in k.replace("_", "-")
-                   for k in declined):
-            violations.append(
-                "no fwht-min-n decline counted under the pallas pin "
-                f"(by_reason={declined})")
-        if dstats["fwht"]["by_backend"].get("xla", {}).get(
-                "flushes") != 1:
-            violations.append(
-                "declined flush not attributed to the xla backend "
-                f"({dstats['fwht']['by_backend']})")
 
         # -- compressed matmul: bound + sparse/dense twin ---------------
         with engine.MicrobatchExecutor(max_batch=1,
@@ -236,8 +179,6 @@ def main() -> int:
         "selection_flushes_by_backend": {
             k: v["flushes"]
             for k, v in fwht_flushes["by_backend"].items()},
-        "forced_pallas_flushes_by_backend": {
-            k: v["flushes"] for k, v in pstats.items()},
         "misses_after_warmup": misses,
         "recompiles_after_warmup": recompiles,
         "cm_error": err,

@@ -565,38 +565,7 @@ SPARSE_NNZ_FLOOR = declare(
         "flood of tiny sparse requests coalesces into a single "
         "bucket instead of one per exact nnz.")
 
-SPARSE_KERNEL = declare(
-    "SKYLARK_SPARSE_KERNEL", default=None, kind="choice", propagate=True,
-    parser=lambda raw: (raw.strip().lower()
-                        if raw.strip().lower() in SERVE_KERNEL_BACKENDS
-                        else None),
-    doc="Flush-kernel pin for the sparse serve family only "
-        "(``pallas`` | ``xla``); sits between the executor "
-        "``kernel=`` argument and ``SKYLARK_SERVE_KERNEL`` in the "
-        "sparse buckets' precedence. Anything else degrades to the "
-        "general precedence chain.")
-
-# -- panel-free FWHT tier (sketch/pallas_fwht, docs/performance) ------------
-
-FWHT_KERNEL = declare(
-    "SKYLARK_FWHT_KERNEL", default=None, kind="choice", propagate=True,
-    parser=lambda raw: (raw.strip().lower()
-                        if raw.strip().lower() in SERVE_KERNEL_BACKENDS
-                        else None),
-    doc="Flush-kernel pin for the SRHT/FWHT serve family only "
-        "(``pallas`` | ``xla``); sits between the executor "
-        "``kernel=`` argument and ``SKYLARK_SERVE_KERNEL`` in the "
-        "SRHT buckets' precedence, mirroring "
-        "``SKYLARK_SPARSE_KERNEL``. Anything else degrades to the "
-        "general precedence chain.")
-
-FWHT_MIN_N = declare(
-    "SKYLARK_FWHT_MIN_N", default=4096, parser=parse_positive_int,
-    kind="int", propagate=True,
-    doc="Minimum transform length n for the in-kernel Pallas FWHT "
-        "path (``sketch.pallas_fwht``); shorter transforms decline "
-        "to the XLA lowering — below roughly one stream chunk the "
-        "butterfly's in-kernel generation overhead beats nothing.")
+# -- compressed matmul (engine/serve.py, docs/performance) ------------------
 
 FWHT_CM_SDIM = declare(
     "SKYLARK_FWHT_CM_SDIM", default=256, parser=parse_positive_int,

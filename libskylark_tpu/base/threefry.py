@@ -9,8 +9,7 @@ identical bits, on any JAX release:
 
 1. the XLA path (:func:`randgen.dense_block`, :func:`randgen.stream_slice`),
 2. the Pallas TPU kernels that regenerate the streams in VMEM
-   (sketch/pallas_dense.py, pallas_hash.py, pallas_fwht.py,
-   pallas_sparse.py),
+   (sketch/pallas_dense.py, pallas_hash.py),
 3. any host-side replay (integer ops are bitwise identical on every
    backend).
 

@@ -18,8 +18,9 @@ carry a pow2 **nnz class** next to the dims/dtype — ragged-nnz
 cohorts coalesce into one flush executable, bit-equal to the dense
 reference (``todense()`` → ``transform.apply``), with operands past
 ``SKYLARK_SPARSE_MIN_DENSITY`` auto-densified onto the dense path
-(counted). Sparse CWT buckets participate in the flush-kernel ladder
-via :mod:`libskylark_tpu.sketch.pallas_sparse`.
+(counted). A sparse flush is ``jax.vmap`` of the lane program (the XLA
+scatter) at every shape; a pallas intent on a sparse bucket declines to
+it, counted.
 
 Flush kernels: the sketch-apply and fastfood buckets can flush through
 the endpoint's **batched Pallas kernel** (one ``pallas_call`` over the
@@ -173,9 +174,12 @@ ENDPOINTS = ("sketch_apply", "fastfood_features", "solve_l2_sketched",
              "condest", "lowrank", "rlsc_predict",
              "compressed_matmul")
 
-# endpoints with a batched Pallas flush kernel behind the selection
-# seam (arg > env > plan cache > default); the others always flush
-# through the vmapped XLA path
+# endpoints behind the selection seam (arg > env > plan cache >
+# default), whose flush runs at the ambient matmul precision and is
+# counted by backend; the others always flush through the vmapped XLA
+# path under solver_precision(). The sparse sketch endpoint is here for
+# the precision and the counters: it has no batched kernel, so a pallas
+# intent on it resolves to a counted decline
 _KERNEL_ENDPOINTS = ("sketch_apply", "fastfood_features",
                      "sparse_sketch_apply")
 
@@ -194,9 +198,8 @@ _SPARSE_DENSIFIED = _metrics.counter(
     "(operand density >= SKYLARK_SPARSE_MIN_DENSITY)")
 _SPARSE_KERNEL_FLUSHES = _metrics.counter(
     "serve.sparse_kernel_flushes",
-    "Sparse-bucket flushes by resolved flush backend (pallas = the "
-    "scatter-free sparse-CountSketch kernel, xla = the O(nnz) "
-    "scatter)")
+    "Sparse-bucket flushes by resolved flush backend (xla = the "
+    "O(nnz) scatter, the one program a sparse flush has)")
 _SPARSE_NNZ_HIST = _metrics.histogram(
     "serve.sparse_nnz_class",
     "pow2 nnz class of accepted sparse submissions — the sparse "
@@ -211,8 +214,8 @@ _SPARSE_NNZ_HIST = _metrics.histogram(
 _FWHT_FLUSHES = _metrics.counter(
     "serve.fwht_flushes",
     "SRHT-family sketch_apply flushes by resolved flush backend "
-    "(pallas = the in-kernel FWHT butterfly, xla = the panel-free "
-    "mix-and-sample program, fjlt.srht_serve_apply)")
+    "(xla = the panel-free mix-and-sample program, "
+    "fjlt.srht_serve_apply, the one program an SRHT flush has)")
 _CM_SUBMITS = _metrics.counter(
     "serve.compressed_matmul_submits",
     "Compressed approximate-matmul submissions reaching the flush "
@@ -370,6 +373,14 @@ def _parse_plan_token(token: str):
     return Plan.from_plan_id(token, known_backends=_KERNEL_BACKENDS)
 
 
+def _lane_program_only(statics) -> bool:
+    """Sparse and SRHT sketch buckets have no batched kernel: their
+    flush is ``jax.vmap`` of the lane program (``sparse_serve`` /
+    ``fjlt.srht_serve_apply``), so a pallas intent on one declines."""
+    return (statics[0] == "sparse_sketch_apply"
+            or statics[:2] == ("sketch_apply", "SRHT"))
+
+
 def _decline_slug(msg: str) -> str:
     """Compact label-value form of a kernel decline reason (the
     ``by_reason`` Prometheus label set must not carry free prose)."""
@@ -388,9 +399,8 @@ def _sketch_family(transform):
         return "CWT", None
     if isinstance(transform, FJLT):
         # the serve family is the SRHT: the panel-free mix-and-sample
-        # program (and the in-kernel FWHT butterfly behind it) is
-        # closed-form only for the Sylvester-Hadamard mixer — the
-        # same restriction operator_panel/fold_rows carry
+        # program is closed-form only for the Sylvester-Hadamard mixer
+        # — the same restriction operator_panel/fold_rows carry
         if transform._fut_name != "wht":
             raise _errors.UnsupportedError(
                 "FJLT serves panel-free only with the 'wht' "
@@ -2748,11 +2758,6 @@ class MicrobatchExecutor:
             return tune.serve_workload(
                 "fastfood_features", ctx["family"], ctx["dtype"],
                 ctx["padded"], ctx["s_dim"], capacity)
-        if endpoint == "sparse_sketch_apply":
-            return tune.serve_workload(
-                "sparse_sketch_apply", ctx["family"], ctx["dtype"],
-                ctx["padded"], ctx["s_dim"], capacity,
-                rowwise=ctx["rowwise"], nnz=ctx["nnz_class"])
         return None
 
     def _qualify_serve_kernel(self, b: _Bucket,
@@ -2764,18 +2769,8 @@ class MicrobatchExecutor:
         ctx = b.ctx
         endpoint = b.statics[0]
         interpret = not _pallas_native()
-        if endpoint == "sparse_sketch_apply":
-            if ctx["family"] != "CWT":
-                return False, ("dense-family sparse flush has no "
-                               "kernel (in-executable densify serves)")
-            from libskylark_tpu.sketch import pallas_sparse
-
-            padded, rowwise = ctx["padded"], ctx["rowwise"]
-            n = padded[1] if rowwise else padded[0]
-            m = padded[0] if rowwise else padded[1]
-            return pallas_sparse.qualify(
-                ctx["s_dim"], n, m, ctx["nnz_class"], ctx["dtype"],
-                interpret=interpret)
+        if _lane_program_only(b.statics):
+            return False, "no batched kernel: the lane program serves"
         if endpoint == "fastfood_features":
             from libskylark_tpu.sketch import pallas_fastfood
 
@@ -2785,19 +2780,6 @@ class MicrobatchExecutor:
         padded, rowwise = ctx["padded"], ctx["rowwise"]
         n = padded[1] if rowwise else padded[0]
         m = padded[0] if rowwise else padded[1]
-        if ctx["family"] == "SRHT":
-            # n is the exact transform extent for this family
-            # (_sketch_statics pads the free axis only)
-            min_n = _env.FWHT_MIN_N.get()
-            if n < min_n:
-                return False, (f"n={n} below SKYLARK_FWHT_MIN_N="
-                               f"{min_n} (short transforms beat the "
-                               "in-kernel generation overhead)")
-            from libskylark_tpu.sketch import pallas_fwht
-
-            return pallas_fwht.qualify(ctx["s_dim"], n, m,
-                                       ctx["dtype"],
-                                       interpret=interpret)
         if ctx["family"] == "CWT":
             from libskylark_tpu.sketch import pallas_hash
 
@@ -2837,25 +2819,8 @@ class MicrobatchExecutor:
         if got is not None:
             return got
         plan = None
-        sparse_pin = (_env.SPARSE_KERNEL.get()
-                      if b.statics[0] == "sparse_sketch_apply" else None)
-        # the FWHT-family pin (SKYLARK_FWHT_KERNEL) plays the same
-        # role for the SRHT buckets SKYLARK_SPARSE_KERNEL plays for
-        # the sparse ones: route just this family without disturbing
-        # the rest of the ladder
-        fwht_pin = (_env.FWHT_KERNEL.get()
-                    if (b.statics[0] == "sketch_apply"
-                        and b.statics[1] == "SRHT") else None)
         if self.kernel is not None:
             choice, source = self.kernel, "arg"
-        elif fwht_pin is not None:
-            choice, source = fwht_pin, "env"
-        elif sparse_pin is not None:
-            # the sparse-family pin (SKYLARK_SPARSE_KERNEL) sits
-            # between the executor argument and the general
-            # SKYLARK_SERVE_KERNEL: an operator can route just the
-            # sparse buckets without disturbing the dense ladder
-            choice, source = sparse_pin, "env"
         elif _serve_kernel_env() is not None:
             choice, source = _serve_kernel_env(), "env"
         else:
@@ -2928,24 +2893,12 @@ class MicrobatchExecutor:
         if self.kernel is not None or _serve_kernel_env() is not None:
             return False
         statics = tuple(statics)
-        if (statics and statics[0] == "sparse_sketch_apply"
-                and _env.SPARSE_KERNEL.get() is not None):
-            # the sparse-family pin outranks a pack decision exactly
-            # like the general pin does: the memo is consulted before
-            # the pin in _resolve_flush_kernel, so seeding it would
-            # silently override the operator's sparse routing
-            return False
-        if (len(statics) > 1 and statics[0] == "sketch_apply"
-                and statics[1] == "SRHT"
-                and _env.FWHT_KERNEL.get() is not None):
-            # same rule for the FWHT-family pin
-            return False
         if not sketch_params.get_use_plan_cache():
             return False
         value = None
         if token == "xla":
             value = ("xla", None, "pack", None)
-        else:
+        elif not _lane_program_only(statics):
             plan = _parse_plan_token(token)
             if plan is not None:
                 value = (plan.backend, plan, "pack", None)
@@ -3028,13 +2981,6 @@ class MicrobatchExecutor:
                             kd, A, s_dim=s_dim, rowwise=rowwise,
                             accum="exact" if interpret else "mxu",
                             interpret=interpret)
-                    if ctx["family"] == "SRHT":
-                        from libskylark_tpu.sketch import pallas_fwht
-
-                        return pallas_fwht.srht_apply_batched(
-                            kd, A, s_dim=s_dim, rowwise=rowwise,
-                            m_tile=plan.m_tile if plan else None,
-                            interpret=interpret)
                     from libskylark_tpu.sketch import pallas_dense
 
                     return pallas_dense.serve_batched_apply(
@@ -3096,23 +3042,10 @@ class MicrobatchExecutor:
                         kd, scale, data, indices, indptr, dist=dist,
                         s_dim=s_dim, rowwise=rowwise, shape=padded)
 
+            # no batched kernel: the flush is the vmapped lane program
             inner_sp = jax.vmap(one_sp)
 
             def batched_sparse(kd, scale, data, indices, indptr):
-                backend, _plan, _src, _why = self._resolve_flush_kernel(
-                    b, int(data.shape[0]))
-                if backend == "pallas":
-                    from libskylark_tpu.sketch import pallas_sparse
-
-                    interpret = not _pallas_native()
-                    nnz_pad = int(data.shape[1])
-                    rows = jax.vmap(
-                        lambda p: _ssrv.csr_row_ids(p, nnz_pad))(indptr)
-                    return pallas_sparse.cwt_sparse_apply_batched(
-                        kd, data, rows, indices, s_dim=s_dim,
-                        rowwise=rowwise, shape=padded,
-                        accum="exact" if interpret else "mxu",
-                        interpret=interpret)
                 return inner_sp(kd, scale, data, indices, indptr)
 
             return engine_compile(

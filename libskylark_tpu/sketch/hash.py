@@ -150,9 +150,9 @@ class HashTransform(SketchTransform):
     def _try_kernel(self, A, *, rowwise: bool):
         """Scatter-free Pallas dispatch (sketch/pallas_hash.py) — CWT on
         a qualifying TPU operand, routed only by an explicit override
-        (``SKYLARK_HASH_KERNEL``) or a certified plan-cache entry;
-        None declines and the ``segment_sum`` scatter below serves
-        (see the kernel module's dispatch doc)."""
+        (``SKYLARK_HASH_KERNEL``); None declines and the
+        ``segment_sum`` scatter below serves (see
+        ``pallas_hash.try_apply``)."""
         from libskylark_tpu.sketch import pallas_hash
 
         return pallas_hash.try_apply(self, A, rowwise=rowwise)

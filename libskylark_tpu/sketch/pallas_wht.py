@@ -16,9 +16,8 @@ leaves there H_block · (D ⊙ A). The axis inside a block folds as (group,
 
 The operand is read once and the mixed matrix written once (the one
 workspace of an apply); ``fut.sample_outer`` then gathers the sampled rows
-of the last, across-block factor. :mod:`pallas_fwht` (the serve tier's
-kernel) holds the *whole* axis of a lane block in VMEM and stops at 2048
-samples; this one bounds VMEM by the block, not by the axis.
+of the last, across-block factor. VMEM is bounded by the block, not by
+the axis.
 """
 
 from __future__ import annotations

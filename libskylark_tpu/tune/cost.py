@@ -44,7 +44,7 @@ from libskylark_tpu.base import locks as _locks
 from libskylark_tpu.tune.plans import (FASTFOOD_OPS, HASH_OPS,
                                        SERVE_DENSE_FAMILIES, SERVE_OPS,
                                        SPARSE_SERVE_OPS, Plan, Workload,
-                                       normalize_device_kind)
+                                       normalize_device_kind, xla_only)
 
 # --------------------------------------------------------------------------
 # compiled-HLO analysis (promoted from benchmarks/hlo_cost.py)
@@ -411,62 +411,35 @@ def _serve_dense_lane_cost(m: int, n: int, s: int, p: Plan,
                              compute_s)}
 
 
-def _sparse_lane_cost(m: int, n: int, s: int, nnz: int, p: Plan,
+def _sparse_lane_cost(m: int, n: int, s: int, nnz: int,
                       rates: dict) -> dict:
     """One sparse-CSR serve lane (m kept extent, n sketched extent, s
     buckets, nnz the pow2 nonzero class — the quantity every term here
-    scales with, which is the whole point of the sparse path). XLA: the
+    scales with, which is the whole point of the sparse path): the
     O(nnz) ``scatter-add`` — nnz update rows retired serially by the
-    scatter unit — plus the 2·n stream generation. Pallas (sketch/
-    pallas_sparse.py): ceil(nnz/128) bucket-tiled one-hot MXU
-    contractions at HIGHEST (~6 bf16 passes of (s×128)·(128×m) each),
-    same generation bill, gather on the VPU; no pipelined variant, so
-    generation serializes against the MXU."""
+    scatter unit — plus the 2·n stream generation."""
     bytes_moved = 4.0 * (3 * nnz + m * s)  # CSR lanes in, dense out
     hbm_s = bytes_moved / rates["hbm_bytes_per_s"]
     gen_entries = 2.0 * n                  # h + v streams (full extent)
     gen_s = gen_entries * GEN_OPS_PER_ENTRY / rates["vpu_ops_per_s"]
-    if p.backend == "xla":
-        scatter_s = nnz / rates["scatter_rows_per_s"]
-        return {"flops": 2.0 * nnz, "bytes": bytes_moved,
-                "gen_entries": gen_entries,
-                "modeled_s": max(hbm_s, scatter_s + gen_s)}
-    tiles = max(1, -(-nnz // 128))
-    flops = 2.0 * s * 128.0 * m * tiles * MXU_PASSES["f32"]
-    mxu_s = flops / rates["mxu_flops_per_s"]
-    return {"flops": flops, "bytes": bytes_moved,
+    scatter_s = nnz / rates["scatter_rows_per_s"]
+    return {"flops": 2.0 * nnz, "bytes": bytes_moved,
             "gen_entries": gen_entries,
-            "modeled_s": max(hbm_s, mxu_s + gen_s)}
+            "modeled_s": max(hbm_s, scatter_s + gen_s)}
 
 
-def _srht_lane_cost(m: int, n: int, s: int, p: Plan,
-                    rates: dict) -> dict:
+def _srht_lane_cost(m: int, n: int, s: int, rates: dict) -> dict:
     """One SRHT serve lane (m kept extent, n pow2 transform extent, s
-    sampled rows). XLA: the panel-free lowering, priced as a
-    kron-factored WHT of two HIGHEST matmuls against factors of size
-    ~sqrt(n) each (4·m·n·sqrt(n) flops; ``fjlt.srht_serve_apply`` contracts
-    factors of at most 128, fewer flops past n = 16384), the sign
-    diagonal and sample gather ride the VPU. Pallas (sketch/pallas_fwht.py): log-n
-    butterfly sweeps fold into one H_128 MXU factor plus the one-hot
-    sample gather, all at HIGHEST; the Threefry streams regenerate
-    once per m-tile sweep and serialize against the MXU (no pipelined
-    variant)."""
+    sampled rows): the panel-free lowering, priced as a kron-factored
+    WHT of two HIGHEST matmuls against factors of size ~sqrt(n) each
+    (4·m·n·sqrt(n) flops; ``fjlt.srht_serve_apply`` contracts factors
+    of at most 128, fewer flops past n = 16384), the sign diagonal and
+    sample gather ride the VPU."""
     bytes_moved = 4.0 * (m * n + m * s)
     hbm_s = bytes_moved / rates["hbm_bytes_per_s"]
-    if p.backend == "xla":
-        root = math.sqrt(float(n))
-        flops = 4.0 * m * n * root * MXU_PASSES["f32"]
-        gen_entries = float(n + s)     # sign diagonal + sample indices
-        compute_s = (flops / rates["mxu_flops_per_s"]
-                     + gen_entries * GEN_OPS_PER_ENTRY
-                     / rates["vpu_ops_per_s"])
-        return {"flops": flops, "bytes": bytes_moved,
-                "gen_entries": gen_entries,
-                "modeled_s": max(hbm_s, compute_s)}
-    m_tile = p.m_tile or 256
-    flops = (2.0 * m * n * 128.0 + 2.0 * m * n * s) * MXU_PASSES["f32"]
-    sweeps = max(1, -(-m // m_tile))
-    gen_entries = float((n + s) * sweeps)
+    root = math.sqrt(float(n))
+    flops = 4.0 * m * n * root * MXU_PASSES["f32"]
+    gen_entries = float(n + s)     # sign diagonal + sample indices
     compute_s = (flops / rates["mxu_flops_per_s"]
                  + gen_entries * GEN_OPS_PER_ENTRY
                  / rates["vpu_ops_per_s"])
@@ -480,17 +453,16 @@ def _cmm_cost(w: Workload, p: Plan, rates: dict) -> dict:
     down the shared contraction (A·Sᵀ and S·B) and multiply the
     (m×s)·(s×p) estimates. Always-XLA (the flush composes two existing
     sketch programs plus a small GEMM — there is no fused kernel), so
-    a pallas plan is a caller bug, not a rankable candidate. The
-    workload's ``nnz`` slot carries the kept extent of B (p) — the
-    shape triple only has room for (m, n, s)."""
-    if p.backend != "xla":
-        raise ValueError(
-            "serve_cmm has no pallas kernel; only the XLA flush exists")
+    a pallas plan is a caller bug, not a rankable candidate
+    (:func:`_hash_or_serve_cost` refuses it). The workload's ``nnz``
+    slot carries the kept extent of B (p) — the shape triple only has
+    room for (m, n, s)."""
     m, n, s = w.bucket()
     pk = max(int(w.nnz), 1)            # kept extent of B, pow2 class
-    lane = _srht_lane_cost if w.transform == "SRHT" else _hash_lane_cost
-    ska = lane(m, n, s, p, rates)
-    skb = lane(pk, n, s, p, rates)
+    if w.transform == "SRHT":
+        ska, skb = (_srht_lane_cost(e, n, s, rates) for e in (m, pk))
+    else:
+        ska, skb = (_hash_lane_cost(e, n, s, p, rates) for e in (m, pk))
     gemm_flops = 2.0 * m * s * pk * MXU_PASSES["f32"]
     gemm_bytes = 4.0 * (m * s + s * pk + m * pk)
     gemm_s = max(gemm_flops / rates["mxu_flops_per_s"],
@@ -510,6 +482,10 @@ def _hash_or_serve_cost(w: Workload, p: Plan, rates: dict) -> dict:
     if p.backend not in ("pallas", "xla"):
         raise ValueError(
             f"unknown {w.op} backend {p.backend!r} (pallas|xla)")
+    if p.backend != "xla" and xla_only(w):
+        raise ValueError(
+            f"{w.op} ({w.transform}) has no pallas kernel; only the "
+            "XLA flush exists")
     m, n, s = w.bucket()
     if w.op == "serve_cmm":
         rec = _cmm_cost(w, p, rates)
@@ -518,11 +494,11 @@ def _hash_or_serve_cost(w: Workload, p: Plan, rates: dict) -> dict:
                   precision=p.precision)
         rec = _fastfood_cost(w, ff, rates)
     elif w.op in SPARSE_SERVE_OPS:
-        rec = _sparse_lane_cost(m, n, s, max(int(w.nnz), 1), p, rates)
+        rec = _sparse_lane_cost(m, n, s, max(int(w.nnz), 1), rates)
     elif w.op in HASH_OPS or w.transform == "CWT":
         rec = _hash_lane_cost(m, n, s, p, rates)
     elif w.transform == "SRHT":
-        rec = _srht_lane_cost(m, n, s, p, rates)
+        rec = _srht_lane_cost(m, n, s, rates)
     elif w.transform in SERVE_DENSE_FAMILIES:
         rec = _serve_dense_lane_cost(m, n, s, p, rates)
     else:

@@ -50,21 +50,19 @@ HASH_OPS = ("hash_rowwise", "hash_columnwise")
 # workload per (endpoint/orientation, transform family, dtype, pow2
 # shape class, batch capacity class). The ``batch`` field carries the
 # capacity class; backends are "pallas" (the endpoint's batched kernel
-# — hash, dense, fused-fastfood, or sparse-CSR) vs "xla" (the vmapped
-# XLA flush). The sparse ops additionally carry the pow2 **nnz class**
-# (``Workload.nnz``) — the sparse kernel's cost is a function of the
-# nonzero count, not the dense extents.
+# — hash, dense or fused-fastfood) vs "xla" (the vmapped XLA flush).
+# The sparse ops additionally carry the pow2 **nnz class**
+# (``Workload.nnz``) — the scatter's cost is a function of the nonzero
+# count, not the dense extents.
 SERVE_OPS = ("serve_sketch_cw", "serve_sketch_rw", "serve_fastfood",
              "serve_sparse_cw", "serve_sparse_rw", "serve_cmm")
 
-# the sparse-CSR serve sites (subset of SERVE_OPS): scatter-free
-# sparse-CountSketch kernel (sketch/pallas_sparse.py) vs the XLA
-# O(nnz) scatter
+# the sparse-CSR serve sites (subset of SERVE_OPS): the XLA O(nnz)
+# scatter, the one program they have
 SPARSE_SERVE_OPS = ("serve_sparse_cw", "serve_sparse_rw")
 
-# dense-family and SRHT serve buckets enumerate a small m-tile ladder
-# (the batched kernel's only knob); CWT/fastfood serve kernels are
-# knobless.
+# dense-family serve buckets enumerate a small m-tile ladder (the
+# batched kernel's only knob); CWT/fastfood serve kernels are knobless.
 SERVE_DENSE_M_TILES = (128, 256, 512)
 
 # serve families whose sketch operator is a dense virtual stream, and
@@ -224,28 +222,26 @@ def _fastfood_candidates(precisions: Sequence[str]) -> Iterator[Plan]:
     yield Plan("xla_chain")
 
 
+def xla_only(w: Workload) -> bool:
+    """Serve buckets whose flush is the vmapped lane program alone: the
+    sparse ones, the SRHT ones and the compressed-matmul endpoint."""
+    return (w.op == "serve_cmm" or w.op in SPARSE_SERVE_OPS
+            or w.transform == "SRHT")
+
+
 def _serve_candidates(w: Workload) -> Iterator[Plan]:
     """Kernel-vs-XLA candidates for one serve bucket. The dense
-    families enumerate the batched kernel's m-tile ladder; the hash,
-    fastfood and sparse serve kernels are knobless — precision stays
-    the serve layer's own policy (oracle regimes only), so a committed
-    cache entry can never opt a flush into bf16. Sparse buckets whose
-    family is not CWT have no kernel (the dense-family sparse flush is
-    an in-executable densify + the dense program) and enumerate only
-    the XLA path. The compressed-matmul endpoint is always-XLA (two
-    sketch programs plus a small GEMM; no fused kernel exists), so it
-    enumerates exactly one plan. SRHT buckets ride the same m-tile
-    ladder as the dense families: the in-kernel FWHT sweeps the batch
-    in row panels and the panel height is its only knob."""
-    if w.op == "serve_cmm":
+    families enumerate the batched kernel's m-tile ladder; the hash
+    and fastfood serve kernels are knobless — precision stays the
+    serve layer's own policy (oracle regimes only), so a committed
+    cache entry can never opt a flush into bf16. The sparse buckets,
+    the SRHT buckets and the compressed-matmul endpoint have no
+    batched kernel (their flush is the vmapped lane program) and
+    enumerate exactly one plan."""
+    if xla_only(w):
         yield Plan("xla")
         return
-    if w.op in SPARSE_SERVE_OPS:
-        if w.transform == "CWT":
-            yield Plan("pallas")
-        yield Plan("xla")
-        return
-    if w.transform in SERVE_DENSE_FAMILIES or w.transform == "SRHT":
+    if w.transform in SERVE_DENSE_FAMILIES:
         m, _n, _s = w.bucket()
         for mt in SERVE_DENSE_M_TILES:
             if mt <= max(m, SERVE_DENSE_M_TILES[0]):

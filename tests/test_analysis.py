@@ -286,14 +286,13 @@ _TOP_LEVEL = sorted(
     if os.path.isfile(os.path.join(_PKG, d, "__init__.py")))
 
 
-def _imports_of_tune(path):
-    """(line, statement) of every import of libskylark_tpu.tune in one
-    file, at any depth (a function-level import counts)."""
+def _imports(path):
+    """(line, statement, dotted names) of every import in one file, at
+    any depth (a function-level import counts)."""
     import ast
 
     with open(path) as fh:
         tree = ast.parse(fh.read(), filename=path)
-    hits = []
     for node in ast.walk(tree):
         if isinstance(node, ast.Import):
             names = [a.name for a in node.names]
@@ -302,10 +301,21 @@ def _imports_of_tune(path):
                                      for a in node.names]
         else:
             continue
-        if any(n == "libskylark_tpu.tune"
-               or n.startswith("libskylark_tpu.tune.") for n in names):
-            hits.append((node.lineno, ast.unparse(node)))
-    return hits
+        yield node.lineno, ast.unparse(node), names
+
+
+def _imports_of_tune(path):
+    """(line, statement) of every import of libskylark_tpu.tune."""
+    return [(line, stmt) for line, stmt, names in _imports(path)
+            if any(n == "libskylark_tpu.tune"
+                   or n.startswith("libskylark_tpu.tune.") for n in names)]
+
+
+def _kernel_modules_imported(path):
+    """The ``sketch/pallas_*`` modules one file imports."""
+    return {part for _line, _stmt, names in _imports(path)
+            for n in names for part in n.split(".")
+            if part.startswith("pallas_")}
 
 
 def test_layering_covers_every_package():
@@ -326,6 +336,54 @@ def test_only_engine_imports_tune(package):
                 path = os.path.join(root, f)
                 hits += [(os.path.relpath(path, REPO), *h)
                          for h in _imports_of_tune(path)]
+    assert not hits, hits
+
+
+# The two kernels the v5e's compiler refused are gone with their pins (PR
+# 47): the sparse table-and-gather body of pallas_sparse.py and
+# pallas_fwht.py. What is left of the choice of a kernel: ``sketch/`` from
+# the backend and the shapes for an eager apply, ``engine/serve.py`` over
+# three batched kernels for a flush.
+
+_GONE = ("pallas_fwht", "cwt_sparse_apply_batched", "SKYLARK_SPARSE_KERNEL",
+         "SKYLARK_FWHT_KERNEL", "SKYLARK_FWHT_MIN_N")
+
+
+def test_the_rows_kernel_stands_without_the_hash_kernel():
+    import ast
+
+    path = os.path.join(_PKG, "sketch", "pallas_sparse.py")
+    assert _kernel_modules_imported(path) == {"pallas_dense"}
+    with open(path) as fh:
+        defined = {n.name for n in ast.parse(fh.read()).body
+                   if isinstance(n, ast.FunctionDef)}
+    assert {"rows_plan", "rows_visits", "hash_rows_apply"} <= defined
+    assert not defined & {"qualify", "_kernel_sparse", "_sparse_call",
+                          "cwt_sparse_apply", "cwt_sparse_apply_batched"}
+    assert not os.path.exists(os.path.join(_PKG, "sketch", "pallas_fwht.py"))
+
+
+def test_the_serve_flush_chooses_among_three_kernel_modules():
+    assert _kernel_modules_imported(
+        os.path.join(_PKG, "engine", "serve.py")) == {
+            "pallas_dense", "pallas_hash", "pallas_fastfood"}
+
+
+def test_no_module_or_document_names_what_was_removed():
+    hits = []
+    tops = [os.path.join(REPO, d) for d in
+            ("libskylark_tpu", "docs", "benchmarks", "script")]
+    paths = [os.path.join(REPO, "bench.py")]
+    for top in tops:
+        for root, dirs, files in os.walk(top):
+            dirs[:] = [d for d in dirs if not d.startswith((".", "__"))]
+            paths += [os.path.join(root, f) for f in files
+                      if not f.endswith(".so")]
+    for path in paths:
+        with open(path, errors="replace") as fh:
+            text = fh.read()
+        hits += [(os.path.relpath(path, REPO), name) for name in _GONE
+                 if name in text]
     assert not hits, hits
 
 

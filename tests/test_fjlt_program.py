@@ -5,11 +5,13 @@ sketch/pallas_wht.py), on the CPU:
 - *plain reference*: ``cellbench/references/srht.py`` (imports nothing of the
   program; D and idx from the published stream definition, the transform a
   butterfly of adds) — both orientations, ragged free extents, unequal
-  Kronecker factors, more samples than ``pallas_fwht`` takes, with repeats;
+  Kronecker factors, as many samples as the axis is long, with repeats;
 - *operator oracle*: ``FJLT.operator_panel`` (the closed-form sampled
   Hadamard rows) as a dense matmul;
 - *dyadic bit-equality* with ``fut.fwht_sketch`` where tests/test_fwht.py
-  promises it (n, s even powers of two, lattice data);
+  promises it (n, s even powers of two, lattice data), on this backend's
+  routes and on the v5e's (the block kernel interpreted, the bfloat16
+  three-way split);
 - the sampled last Kronecker factor against the full transform then gather;
 - the block kernel, interpreted, against its XLA twin;
 - one program, no recompile, the span's attributes and the counter.
@@ -80,13 +82,29 @@ def test_program_against_the_operator_panel(n, s):
 
 @pytest.mark.parametrize("n,s", [(256, 64), (4096, 256), (4096, 1024)])
 @pytest.mark.parametrize("rowwise", [False, True])
-def test_dyadic_bit_equality_with_fwht_sketch(n, s, rowwise):
+@pytest.mark.parametrize("kernel", ["xla", "pallas_blocks"])
+def test_dyadic_bit_equality_with_fwht_sketch(n, s, rowwise, kernel,
+                                              monkeypatch):
     """n, s even powers of two + lattice data: every intermediate is an
     exact dyadic rational, so the program, the fused serve composition and
-    the operator-panel matmul agree bit for bit."""
+    the operator-panel matmul agree bit for bit — on this backend's routes
+    (``"xla"``) and on the ones a v5e takes (``"pallas_blocks"``: the block
+    kernel, interpreted here, for a columnwise operand, in blocks the small
+    axis admits; the bfloat16 three-way split for a rowwise one, which the
+    kernel does not serve). The operand split in three bfloat16 parts is
+    exact on the lattice and every sum is an integer under 2²⁴, so the
+    order of accumulation cannot show."""
     A = jnp.asarray(np.random.default_rng(5).integers(-8, 9, (n, 12)),
                     jnp.float32)
     T = sk.FJLT(n, s, Context(7), fut="wht")
+    if kernel == "pallas_blocks":
+        block = min(n, 1024)            # 256: one block of two groups
+        monkeypatch.setattr(pallas_wht, "mix_blocks", functools.partial(
+            pallas_wht.mix_blocks.__wrapped__, interpret=True))
+        monkeypatch.setattr(
+            sk.FJLT, "mix_plan",
+            lambda self, A, rowwise: (("xla_bf16x3", block, 128) if rowwise
+                                      else ("pallas_blocks", block, 12)))
     got = T.apply(A.T if rowwise else A, sk.ROWWISE if rowwise else sk.COLUMNWISE)
     got = got.T if rowwise else got
     fused = fut.fwht_sketch(A, T.diagonal(), T.sample_indices(),
@@ -168,8 +186,11 @@ def test_bf16_operand_control_fails_the_configurations_rel_max():
 # -- the block kernel, interpreted ------------------------------------------
 
 
-@pytest.mark.parametrize("n,block,tile", [(2048, 1024, 128), (4096, 2048, 256),
-                                          (4096, 4096, 128)])
+@pytest.mark.parametrize("n,block,tile", [
+    (2048, 1024, 128), (4096, 2048, 256), (4096, 4096, 128),
+    # the butterflies' other shapes: two groups (one radix-2 stage, under
+    # the plan's floor of eight) and four (one radix-4 stage, eight blocks)
+    (256, 256, 128), (4096, 512, 128)])
 def test_block_kernel_against_its_xla_twin(n, block, tile):
     A = _operand(n, 256, 3)
     D = jnp.asarray(np.random.default_rng(0).choice([-1.0, 1.0], n), jnp.float32)
@@ -189,6 +210,7 @@ def test_block_kernel_plan_declines_off_the_tpu_and_odd_shapes():
     assert ok((1 << 12, 384), jnp.float32) == (4096, 128)
     assert ok((1 << 12, 200), jnp.float32) is None        # no lane multiple
     assert ok((512, 256), jnp.float32) is None            # under eight groups
+    assert ok((256, 128), jnp.float32) is None
     assert ok((3 << 10, 256), jnp.float32) is None        # no power of two
     assert ok((1 << 12, 256), jnp.bfloat16) is None
 

@@ -1,6 +1,6 @@
 """FWHT-native tier (docs/performance, "In-kernel FWHT and compressed
-matmul"): the panel-free SRHT lowering, the in-kernel Pallas butterfly,
-and the compressed approximate-matmul endpoint.
+matmul"): the panel-free SRHT lowering and the compressed
+approximate-matmul endpoint.
 
 Oracles:
 
@@ -8,21 +8,17 @@ Oracles:
   ``_hadamard_np`` matmul bit for bit on integer-valued f32 lattices
   (exact adds both ways), allclose on general floats.
 - *dyadic bit-equality*: the fused ``fwht_sketch`` / serve /
-  ``fold_rows`` / Pallas programs are bit-equal to the
+  ``fold_rows`` programs are bit-equal to the
   ``operator_panel`` matmul whenever every intermediate is exactly
   representable — integer-valued operands with ``n`` and ``s`` EVEN
   powers of two (``1/sqrt(n)`` dyadic). Odd powers (n = 2^13, ...)
   are allclose only: the scales are irrational and summation orders
   legitimately differ in the last ulp.
-- *stream bit-identity*: the in-kernel Threefry regeneration draws the
-  SAME sign diagonal and sample coordinates as the transform's own
-  ``diagonal()`` / ``sample_indices()`` — pinned end-to-end by
-  requiring the Pallas path bit-equal to the XLA twin on dyadic input
-  (one flipped sign or swapped sample would break equality).
-- *selection precedence* for the SRHT family: executor ``kernel=``
-  argument > ``SKYLARK_FWHT_KERNEL`` > ``SKYLARK_SERVE_KERNEL`` >
-  plan cache > xla default, with the FWHT pin invisible to non-SRHT
-  buckets and outranking warmup-pack restoration.
+- *one flush program* for the SRHT family: the vmapped lane function
+  (``fjlt.srht_serve_apply``; tests/test_fjlt_program.py holds it and
+  the block kernel of the direct apply). A pallas intent — executor
+  ``kernel=`` argument or ``SKYLARK_SERVE_KERNEL`` — declines to it,
+  counted; the family pins older trees read are not read.
 - *compressed matmul*: ``(A Sᵀ)(S B)`` is within the returned
   ``‖A‖_F·‖B‖_F·√(2/s)`` scale on well-conditioned data; the sparse-A
   CWT lane is bit-equal to its densified twin.
@@ -46,7 +42,6 @@ from libskylark_tpu.base.context import Allocation
 from libskylark_tpu.base.errors import UnsupportedError
 from libskylark_tpu.sketch import fjlt as _fjlt
 from libskylark_tpu.sketch import fut as _fut
-from libskylark_tpu.sketch import pallas_fwht
 from libskylark_tpu.sketch.fjlt import FJLT
 from libskylark_tpu.sketch.hash import CWT
 
@@ -193,73 +188,6 @@ class TestPanelFree:
 
 
 # ---------------------------------------------------------------------------
-# the Pallas in-kernel butterfly (interpret mode on the CPU mesh)
-# ---------------------------------------------------------------------------
-
-
-class TestPallasKernel:
-    @pytest.mark.parametrize("n,s,m", [(256, 16, 3), (4096, 64, 37)])
-    def test_kernel_bit_equal_to_xla_twin_dyadic(self, n, s, m):
-        """Bit-equality pins BOTH the butterfly arithmetic and the
-        in-kernel Threefry streams: one flipped Rademacher sign or one
-        swapped sample index would break it."""
-        rng = np.random.default_rng(n + s)
-        t = FJLT(n, s, Context(seed=31), fut="wht")
-        A = _lattice(rng, (m, n))
-        ker = np.asarray(pallas_fwht.srht_apply(
-            _kd(t), jnp.asarray(A), s_dim=s, rowwise=True,
-            interpret=True))
-        twin = np.asarray(_fjlt.srht_serve_apply(
-            _kd(t), jnp.asarray(A), s_dim=s, rowwise=True))
-        assert np.array_equal(ker, twin)
-
-    def test_kernel_columnwise_and_floats(self):
-        n, s, m = 1024, 128, 11
-        rng = np.random.default_rng(6)
-        t = FJLT(n, s, Context(seed=17), fut="wht")
-        A = rng.standard_normal((n, m)).astype(np.float32)
-        ker = np.asarray(pallas_fwht.srht_apply(
-            _kd(t), jnp.asarray(A), s_dim=s, rowwise=False,
-            interpret=True))
-        ref = np.asarray(t.apply(A, sk.COLUMNWISE))
-        np.testing.assert_allclose(ker, ref, rtol=1e-4, atol=1e-4)
-
-    def test_batched_lane_invariance(self):
-        """A lane out of a B=3 cohort is bit-equal to its own B=1
-        run — capacity never reaches per-lane arithmetic."""
-        n, s, m = 512, 32, 5
-        rng = np.random.default_rng(7)
-        kds = np.stack([_kd(FJLT(n, s, Context(seed=40 + i),
-                                 fut="wht")) for i in range(3)])
-        A = np.stack([_lattice(rng, (m, n)) for _ in range(3)])
-        out = np.asarray(pallas_fwht.srht_apply_batched(
-            kds, jnp.asarray(A), s_dim=s, rowwise=True,
-            interpret=True))
-        for i in range(3):
-            solo = np.asarray(pallas_fwht.srht_apply(
-                kds[i], jnp.asarray(A[i]), s_dim=s, rowwise=True,
-                interpret=True))
-            assert np.array_equal(out[i], solo)
-
-    def test_qualify_declines(self):
-        ok, why = pallas_fwht.qualify(16, 1000, 4, jnp.float32,
-                                      interpret=True)
-        assert not ok and "power of two" in why
-        ok, why = pallas_fwht.qualify(16, 64, 4, jnp.float32,
-                                      interpret=True)
-        assert not ok     # below one lane block
-        ok, why = pallas_fwht.qualify(4096, 8192, 4, jnp.float32,
-                                      interpret=True)
-        assert not ok and "cipher sweep" in why
-        ok, why = pallas_fwht.qualify(16, 1024, 4, jnp.bfloat16,
-                                      interpret=True)
-        assert not ok and "float32" in why
-        ok, why = pallas_fwht.qualify(16, 1024, 4, jnp.float32,
-                                      interpret=True)
-        assert ok
-
-
-# ---------------------------------------------------------------------------
 # serve integration: the SRHT sketch_apply family
 # ---------------------------------------------------------------------------
 
@@ -336,122 +264,81 @@ class TestServeSRHT:
             assert engine.stats().misses - m0 == 0
             assert engine.stats().recompiles - r0 == 0
 
-    def test_pallas_pin_bit_equal_and_counted(self, fresh_engine,
-                                              monkeypatch):
-        """SKYLARK_FWHT_KERNEL=pallas routes the flush through the
-        interpret-mode kernel; dyadic input stays bit-equal and the
-        flush is attributed to the pallas backend."""
-        monkeypatch.setenv("SKYLARK_FWHT_KERNEL", "pallas")
+    @pytest.mark.parametrize("dimension,intent", [
+        (sk.ROWWISE, "arg"), (sk.COLUMNWISE, "env")],
+        ids=["rowwise", "columnwise"])
+    def test_pallas_intent_declines_to_the_lane_program(
+            self, fresh_engine, monkeypatch, dimension, intent):
+        """The SRHT flush has one program, the vmapped lane function: a
+        pallas intent (executor argument or SKYLARK_SERVE_KERNEL) declines
+        to it with one counted reason, and a warm-up pack cannot seed one."""
         rng = np.random.default_rng(14)
         n, s = 4096, 256
         t = FJLT(n, s, Context(seed=23), fut="wht")
-        A = _lattice(rng, (16, n))
+        A = _lattice(rng, (16, n) if dimension == sk.ROWWISE else (n, 16))
         with _executor() as ex:
-            out = np.asarray(ex.submit_sketch(
-                t, A, dimension=sk.ROWWISE).result(timeout=120))
-            st = ex.stats()["fwht"]
-        assert np.array_equal(out, np.asarray(t.apply(A, sk.ROWWISE)))
-        assert st["by_backend"]["pallas"]["flushes"] == 1
-
-    def test_min_n_decline(self, fresh_engine, monkeypatch):
-        """Below SKYLARK_FWHT_MIN_N a pallas intent declines (counted
-        reason) back to the XLA program."""
-        monkeypatch.setenv("SKYLARK_FWHT_KERNEL", "pallas")
-        rng = np.random.default_rng(15)
-        t = FJLT(1024, 64, Context(seed=29), fut="wht")
-        A = _lattice(rng, (4, 1024))
-        with _executor() as ex:
-            out = np.asarray(ex.submit_sketch(
-                t, A, dimension=sk.ROWWISE).result(timeout=60))
+            want = np.asarray(ex.submit_sketch(
+                t, A, dimension=dimension).result(timeout=120))
+            (memo_key,) = ex._kernel_memo
+            assert not ex.restore_kernel_choice(memo_key[0], 4, "pallas")
+            assert ex.restore_kernel_choice(memo_key[0], 4, "xla")
+        if intent == "env":
+            monkeypatch.setenv("SKYLARK_SERVE_KERNEL", "pallas")
+        with _executor(**({"kernel": "pallas"} if intent == "arg"
+                          else {})) as ex:
+            got = np.asarray(ex.submit_sketch(
+                t, A, dimension=dimension).result(timeout=120))
             st = ex.stats()
-        assert np.array_equal(out, np.asarray(t.apply(A, sk.ROWWISE)))
+            (choice,) = ex._kernel_memo.values()
+        assert np.array_equal(got, want)
+        assert np.array_equal(got, np.asarray(t.apply(A, dimension)))
+        slug = "no-batched-kernel-the-lane-program-serves"
+        assert choice == ("xla", None, intent, slug)
+        assert st["kernel"]["by_reason"] == {slug: {"declined_flushes": 1}}
         assert st["fwht"]["by_backend"] == {"xla": {"flushes": 1}}
-        assert any("fwht-min-n" in k.replace("_", "-")
-                   for k in st["kernel"]["by_reason"])
 
 
-# ---------------------------------------------------------------------------
-# selection precedence for the SRHT family
-# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("name,value", [
+    ("SKYLARK_SPARSE_KERNEL", "pallas"), ("SKYLARK_FWHT_KERNEL", "pallas"),
+    ("SKYLARK_FWHT_MIN_N", "1")])
+def test_removed_pins_are_not_read(fresh_engine, monkeypatch, name, value):
+    """The family pins of older trees: set, they move neither the memoised
+    choice nor the executable keys nor the compile count of a warmed
+    executor, and the registry declares none of them."""
+    from libskylark_tpu.base import env as _env
+    from libskylark_tpu.base.sparse import SparseMatrix
 
+    assert name not in _env.REGISTRY
+    rng = np.random.default_rng(15)
+    t = FJLT(4096, 64, Context(seed=29), fut="wht")
+    A = _lattice(rng, (4, 4096))
+    c = CWT(256, 16, Context(seed=31))
+    S = SparseMatrix.from_scipy(sp.random(
+        256, 6, density=0.02, random_state=7, dtype=np.float32))
 
-class TestFWHTPrecedence:
-    def _flush_one(self, ex):
-        rng = np.random.default_rng(16)
-        t = FJLT(4096, 64, Context(seed=7), fut="wht")
-        A = rng.standard_normal((4, 4096)).astype(np.float32)
-        fut = ex.submit_sketch(t, A, dimension=sk.ROWWISE)
+    def storm(ex):
+        futs = [ex.submit_sketch(t, A, dimension=sk.ROWWISE),
+                ex.submit_sparse(c, S, dimension=sk.COLUMNWISE)]
         ex.flush()
-        fut.result(timeout=120)
-        (choice,) = ex._kernel_memo.values()
-        return choice
+        return [np.asarray(f.result(timeout=120)) for f in futs]
 
-    def test_arg_beats_env(self, fresh_engine, monkeypatch):
-        monkeypatch.setenv("SKYLARK_FWHT_KERNEL", "pallas")
-        with _executor(kernel="xla") as ex:
-            backend, _plan, source, declined = self._flush_one(ex)
-        assert (backend, source, declined) == ("xla", "arg", None)
-
-    def test_env_pin_resolves(self, fresh_engine, monkeypatch):
-        monkeypatch.setenv("SKYLARK_FWHT_KERNEL", "pallas")
-        prev = tune.set_cache(tune.PlanCache(path=None))
-        try:
-            with _executor() as ex:
-                backend, _plan, source, _d = self._flush_one(ex)
-        finally:
-            tune.set_cache(prev)
-        # interpret-mode pallas qualifies on the CPU mesh (the CI
-        # bit-equality leg); the pin is attributed to the env
-        assert source == "env"
-        assert backend == "pallas"
-
-    def test_fwht_pin_beats_general_serve_env(self, fresh_engine,
-                                              monkeypatch):
-        monkeypatch.setenv("SKYLARK_SERVE_KERNEL", "pallas")
-        monkeypatch.setenv("SKYLARK_FWHT_KERNEL", "xla")
-        with _executor() as ex:
-            backend, _plan, source, declined = self._flush_one(ex)
-        assert (backend, source, declined) == ("xla", "env", None)
-
-    def test_pin_invisible_to_cwt_buckets(self, fresh_engine,
-                                          monkeypatch):
-        monkeypatch.setenv("SKYLARK_FWHT_KERNEL", "pallas")
-        rng = np.random.default_rng(17)
-        t = CWT(512, 32, Context(seed=9))
-        A = rng.standard_normal((512, 4)).astype(np.float32)
-        prev = tune.set_cache(tune.PlanCache(path=None))
-        try:
-            with _executor() as ex:
-                fut = ex.submit_sketch(t, A, dimension=sk.COLUMNWISE)
-                ex.flush()
-                fut.result(timeout=60)
-                (choice,) = ex._kernel_memo.values()
-        finally:
-            tune.set_cache(prev)
-        assert choice[2] == "default"
-
-    def test_pin_outranks_pack_restore(self, fresh_engine,
-                                       monkeypatch):
-        statics = ("sketch_apply", "SRHT", "None", 64, True,
-                   "float32", (8, 4096))
-        with _executor() as ex:
-            monkeypatch.setenv("SKYLARK_FWHT_KERNEL", "xla")
-            assert not ex.restore_kernel_choice(statics, 4, "pallas")
-            monkeypatch.delenv("SKYLARK_FWHT_KERNEL")
-            assert ex.restore_kernel_choice(statics, 4, "pallas")
-
-    def test_ladder_has_mtile_candidates(self):
-        w = tune.serve_workload("sketch_apply", "SRHT", "float32",
-                                (512, 4096), 256, 4, rowwise=True)
-        cands = tune.enumerate_candidates(w)
-        mtiles = sorted(p.m_tile for p in cands
-                        if p.backend == "pallas")
-        assert mtiles == [128, 256, 512]
-        ranked = tune.rank_candidates(w)
-        assert ranked[0][0].backend == "xla"   # CPU host certifies xla
-        pallas_rec = next(c for p, c in ranked
-                          if p.backend == "pallas")
-        assert pallas_rec.get("interpret")
+    with _executor() as ex:
+        before = storm(ex)
+        memo = dict(ex._kernel_memo)
+        keys = set(engine.cache().keys())
+        compiles = engine.stats().compiles
+        monkeypatch.setenv(name, value)
+        ex._kernel_memo.clear()             # resolve again, the name set
+        after = storm(ex)
+        assert dict(ex._kernel_memo) == memo
+        assert set(memo.values()) == {("xla", None, "default", None)}
+    with _executor() as fresh:              # and an executor born under it
+        again = storm(fresh)
+        assert dict(fresh._kernel_memo) == memo
+    assert set(engine.cache().keys()) == keys
+    assert engine.stats().compiles == compiles
+    for x, y, z in zip(before, after, again):
+        assert np.array_equal(x, y) and np.array_equal(x, z)
 
 
 # ---------------------------------------------------------------------------
@@ -565,14 +452,23 @@ class TestCompressedMatmul:
         assert st["cm_submits"] == 1
         assert engine.serve_stats()["fwht"]["cm_submits"] >= 1
 
-    def test_tune_workload_is_xla_only(self):
-        w = tune.serve_workload("compressed_matmul", "SRHT",
-                                "float32", (64, 2048), 512, 2,
-                                nnz=64)
+    @pytest.mark.parametrize("endpoint,family,kw", [
+        ("compressed_matmul", "SRHT", {"nnz": 64}),
+        ("sketch_apply", "SRHT", {"rowwise": True}),
+        ("sparse_sketch_apply", "CWT", {"rowwise": False, "nnz": 1024}),
+        ("sparse_sketch_apply", "CWT", {"rowwise": True, "nnz": 1024}),
+    ], ids=["cmm", "srht", "sparse_cw", "sparse_rw"])
+    def test_tune_workload_is_xla_only(self, endpoint, family, kw):
+        """The flushes that are the vmapped lane program and nothing
+        else: one candidate, and the cost model refuses another."""
+        w = tune.serve_workload(endpoint, family, "float32", (64, 2048),
+                                512, 2, **kw)
         cands = tune.enumerate_candidates(w)
         assert [p.backend for p in cands] == ["xla"]
         ranked = tune.rank_candidates(w)
         assert ranked[0][1]["modeled_s"] > 0
+        with pytest.raises(ValueError, match="no pallas kernel"):
+            tune.plan_cost(w, tune.Plan("pallas"))
 
 
 # ---------------------------------------------------------------------------

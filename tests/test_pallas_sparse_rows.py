@@ -294,6 +294,19 @@ class TestEngagementRule:
         assert sparse_serve.sparse_kernel(**QUALIFIED) == "xla_scatter"
         assert pallas_sparse.rows_plan(512, 1024, 32768, jnp.float32) == (8, 8, 32)
 
+    @pytest.mark.parametrize("n_rows,s_dim,lanes,plan", [
+        (512, 384, 32768, (16, 3, 16)),     # odd H: sixteen rows a tile
+        (16, 128, 16384, (16, 1, 1)),       # one tile, the least lanes
+        (384, 2048, 16384, (8, 16, 16)),    # 48 tiles: G halves to a divisor
+        (((1 << 16) - 1) * 8, 1024, 1 << 20, (8, 8, 1)),  # the table's last
+        (0, 1024, 32768, None),             # no row at all
+        (1 << 19, 1024, 1 << 20, None),     # one tile past the table
+    ])
+    def test_the_plan_at_the_edges_of_its_conditions(self, n_rows, s_dim,
+                                                     lanes, plan):
+        assert pallas_sparse.rows_plan(
+            n_rows, s_dim, lanes, jnp.float32) == plan
+
     @pytest.mark.parametrize("change,kernel", [
         ({}, "pallas_rows"),
         ({"s_dim": 128}, "pallas_rows"), ({"s_dim": 384}, "pallas_rows"),

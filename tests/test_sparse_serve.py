@@ -14,12 +14,11 @@ Oracles:
 - *bucket discipline*: the pow2 nnz class rides the statics — ragged
   nnz inside one class coalesces into one bucket (zero recompiles
   after warmup), across classes it keys separate buckets.
-- *selection precedence* for the sparse family: executor ``kernel=``
-  argument > ``SKYLARK_SPARSE_KERNEL`` > plan cache > xla default,
-  with the sparse Pallas kernel declining off-TPU (counted reason).
-- *kernel exactness* (interpret mode, direct): ``accum="exact"`` is
-  bit-equal to the serve scatter; ``"mxu"`` is allclose (and bit-equal
-  on lattice data).
+- *one flush program* for the sparse family: the vmapped lane function
+  with the XLA scatter, whatever the shape and whatever
+  ``sparse_serve.sparse_kernel`` says of a direct apply there; a pallas
+  intent (executor ``kernel=`` argument or ``SKYLARK_SERVE_KERNEL``)
+  declines to it, counted.
 """
 
 from __future__ import annotations
@@ -27,7 +26,6 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 import scipy.sparse as sp
 
@@ -325,115 +323,88 @@ class TestDensifyAndCounters:
 
 
 # ---------------------------------------------------------------------------
-# autotuner precedence for the sparse family
+# the sparse flush has one program: the vmapped lane function
 # ---------------------------------------------------------------------------
 
 
-class TestSelectionPrecedence:
-    def _flush_one(self, ex):
+class TestSparseFlush:
+    @pytest.mark.parametrize("family", [sk.CWT, sk.JLT])
+    @pytest.mark.parametrize("dimension,intent", [
+        (sk.COLUMNWISE, "arg"), (sk.ROWWISE, "env")],
+        ids=["columnwise", "rowwise"])
+    def test_pallas_intent_declines(self, fresh_engine, monkeypatch,
+                                    family, dimension, intent):
+        """A pallas intent (executor argument or SKYLARK_SERVE_KERNEL)
+        on a sparse bucket: the lane program serves, bit-equal to the
+        default executor, with one counted reason; a warm-up pack
+        cannot seed one either."""
         rng = np.random.default_rng(7)
-        ctx = Context(seed=7)
-        T = sk.CWT(256, 16, ctx)
-        A = _rand_sparse(rng, 256, 6, nnz=20)
-        fut = ex.submit_sparse(T, A, dimension=sk.COLUMNWISE)
-        ex.flush()
-        fut.result(timeout=60)
-        (choice,) = ex._kernel_memo.values()
-        return choice
+        N, m, s_dim = 256, 6, 16
+        T = family(N, s_dim, Context(seed=7))
+        shape = (m, N) if dimension == sk.ROWWISE else (N, m)
+        A = _rand_sparse(rng, *shape, nnz=20)
+        with _executor() as ex:
+            want = np.asarray(ex.submit_sparse(
+                T, A, dimension=dimension).result(timeout=60))
+            (memo_key,) = ex._kernel_memo
+            assert ex._kernel_memo[memo_key] == (
+                "xla", None, "default", None)
+            assert not ex.restore_kernel_choice(memo_key[0], 4, "pallas")
+            assert ex.restore_kernel_choice(memo_key[0], 4, "xla")
+        if intent == "env":
+            monkeypatch.setenv("SKYLARK_SERVE_KERNEL", "pallas")
+        with _executor(**({"kernel": "pallas"} if intent == "arg"
+                          else {})) as ex:
+            got = np.asarray(ex.submit_sparse(
+                T, A, dimension=dimension).result(timeout=60))
+            st = ex.stats()
+            (choice,) = ex._kernel_memo.values()
+        assert np.array_equal(got, want)
+        slug = "no-batched-kernel-the-lane-program-serves"
+        assert choice == ("xla", None, intent, slug)
+        assert st["kernel"]["by_reason"] == {slug: {"declined_flushes": 1}}
+        assert st["sparse"]["by_backend"] == {"xla": {"kernel_flushes": 1}}
 
-    def test_arg_beats_env(self, fresh_engine, monkeypatch):
-        monkeypatch.setenv("SKYLARK_SPARSE_KERNEL", "pallas")
-        with _executor(kernel="xla") as ex:
-            backend, _plan, source, declined = self._flush_one(ex)
-        assert (backend, source, declined) == ("xla", "arg", None)
+    @pytest.mark.parametrize("shape,s_dim,nnz", [
+        ((64, 3000), 128, 9000), ((256, 1000), 384, 20000)])
+    def test_the_flush_takes_the_scatter_whatever_the_shape(
+            self, fresh_engine, monkeypatch, shape, s_dim, nnz):
+        """Shapes at which a direct rowwise apply on a TPU takes the rows
+        kernel (``sparse_kernel``): the flush asks no such rule, it is
+        the scatter lane — bit-equal to the dense reference, the kernel
+        never traced, and a plan-cache entry for the bucket unread."""
+        monkeypatch.setattr(pallas_sparse, "available", lambda: True)
 
-    def test_env_beats_plan_cache(self, fresh_engine, monkeypatch):
-        monkeypatch.setenv("SKYLARK_SPARSE_KERNEL", "pallas")
+        def never(*a, **kw):
+            raise AssertionError("the serve flush traced the rows kernel")
+
+        monkeypatch.setattr(pallas_sparse, "hash_rows_apply", never)
+        rng = np.random.default_rng(nnz)
+        T = sk.CWT(shape[1], s_dim, Context(seed=5))
+        A = _rand_sparse(rng, *shape, nnz=nnz)
+        padded = bucketing.pad_shape(A.shape, (0, 1))
+        lanes = bucketing.nnz_class(A.nnz)
+        assert sparse_serve.sparse_kernel(
+            padded, s_dim, lanes, jnp.float32, True) == "pallas_rows"
         prev = tune.set_cache(tune.PlanCache(path=None))
         try:
-            with _executor() as ex:
-                backend, _plan, source, declined = self._flush_one(ex)
-        finally:
-            tune.set_cache(prev)
-        # the pin resolved from env; off-TPU the sparse kernel
-        # DECLINES (counted) and the flush falls back to xla
-        assert source == "env"
-        assert backend == "xla"
-        assert declined is not None
-        assert "not-a-tpu" in declined or "tpu" in declined
-
-    def test_sparse_pin_does_not_touch_dense_buckets(
-            self, fresh_engine, monkeypatch):
-        monkeypatch.setenv("SKYLARK_SPARSE_KERNEL", "pallas")
-        rng = np.random.default_rng(8)
-        ctx = Context(seed=8)
-        T = sk.CWT(64, 16, ctx)
-        A = rng.standard_normal((64, 6)).astype(np.float32)
-        prev = tune.set_cache(tune.PlanCache(path=None))
-        try:
-            with _executor() as ex:
-                fut = ex.submit_sketch(T, A, dimension=sk.COLUMNWISE)
-                ex.flush()
-                fut.result(timeout=60)
+            tune.get_cache().put(
+                tune.serve_workload(
+                    "sparse_sketch_apply", "CWT", "float32", padded,
+                    s_dim, 1, rowwise=True, nnz=lanes),
+                tune.Plan("pallas"), source="measured")
+            with _executor(max_batch=1, linger_us=100) as ex:
+                out = np.asarray(ex.submit_sparse(
+                    T, A, dimension=sk.ROWWISE).result(timeout=120))
+                st = ex.stats()
                 (choice,) = ex._kernel_memo.values()
         finally:
             tune.set_cache(prev)
-        # dense bucket: the sparse pin is invisible; default xla
-        assert choice[2] == "default"
-
-    def test_plan_cache_beats_default(self, fresh_engine):
-        prev = tune.set_cache(tune.PlanCache(path=None))
-        try:
-            w = tune.serve_workload(
-                "sparse_sketch_apply", "CWT", "float32", (256, 8),
-                16, 1, rowwise=False, nnz=64)
-            tune.get_cache().put(w, tune.Plan("pallas"),
-                                 source="measured")
-            with _executor(max_batch=1, linger_us=100) as ex:
-                backend, _plan, source, declined = self._flush_one(ex)
-        finally:
-            tune.set_cache(prev)
-        assert source == "plan"
-        assert backend == "xla" and declined is not None  # CPU decline
-
-    def test_sparse_pin_outranks_pack_restore(self, fresh_engine,
-                                              monkeypatch):
-        """A warmup-pack-recorded decision must NOT seed the memo when
-        the operator pinned the sparse family — the memo is consulted
-        before the pin, so seeding would silently override it."""
-        statics = ("sparse_sketch_apply", "CWT", "None", 16, False,
-                   "float32", (256, 8), 64)
-        with _executor() as ex:
-            monkeypatch.setenv("SKYLARK_SPARSE_KERNEL", "xla")
-            assert not ex.restore_kernel_choice(statics, 4, "pallas")
-            monkeypatch.delenv("SKYLARK_SPARSE_KERNEL")
-            assert ex.restore_kernel_choice(statics, 4, "pallas")
-            # dense statics are unaffected by the sparse pin
-            monkeypatch.setenv("SKYLARK_SPARSE_KERNEL", "xla")
-            dense = ("sketch_apply", "CWT", "None", 16, False,
-                     "float32", (64, 8))
-            assert ex.restore_kernel_choice(dense, 4, "xla")
-
-    def test_default_is_xla(self, fresh_engine):
-        prev = tune.set_cache(tune.PlanCache(path=None))
-        try:
-            with _executor() as ex:
-                backend, _plan, source, declined = self._flush_one(ex)
-        finally:
-            tune.set_cache(prev)
-        assert (backend, source, declined) == ("xla", "default", None)
-
-    def test_ranked_certifies_xla_off_tpu(self, fresh_engine):
-        w = tune.serve_workload(
-            "sparse_sketch_apply", "CWT", "float32", (4096, 16), 32,
-            8, rowwise=False, nnz=1024)
-        assert "z1024" in w.key()
-        ranked = tune.rank_candidates(w)
-        assert ranked[0][0].backend == "xla"
-        assert any(p.backend == "pallas" for p, _ in ranked)
-        pallas_rec = next(c for p, c in ranked
-                          if p.backend == "pallas")
-        assert pallas_rec.get("interpret")  # penalty applied off-TPU
+        assert np.array_equal(
+            out, np.asarray(T.apply(A.todense(), sk.ROWWISE)))
+        assert choice == ("xla", None, "default", None)
+        assert st["sparse"]["by_backend"] == {"xla": {"kernel_flushes": 1}}
+        assert st["kernel"]["by_reason"] == {}
 
 
 # ---------------------------------------------------------------------------
@@ -470,106 +441,11 @@ class TestSparseSolve:
 
 
 # ---------------------------------------------------------------------------
-# the Pallas sparse kernel (direct, interpret mode)
+# the lane's row ids (base.sparse.csr_row_ids, what the scatter reads)
 # ---------------------------------------------------------------------------
 
 
-class TestPallasSparseKernel:
-    def _lanes(self, A, rng_dtype=np.float32):
-        padded = bucketing.pad_shape(A.shape, (0, 1))
-        nnz_cls = bucketing.nnz_class(A.nnz)
-        data, idx, ptr = A.csr_parts(rng_dtype)
-        d = np.zeros(nnz_cls, rng_dtype)
-        d[: len(data)] = data
-        ix = np.zeros(nnz_cls, np.int32)
-        ix[: len(idx)] = idx
-        pt = np.full(padded[0] + 1, len(data), np.int32)
-        pt[: len(ptr)] = ptr
-        rows = np.asarray(sparse_serve.csr_row_ids(
-            jnp.asarray(pt), nnz_cls))
-        return padded, d, ix, pt, rows
-
-    @pytest.mark.parametrize("rowwise", [False, True])
-    def test_exact_accum_bit_equal_to_serve_scatter(self, rowwise):
-        rng = np.random.default_rng(11)
-        ctx = Context(seed=11)
-        N, m, s_dim = 200, 11, 16
-        shape = (m, N) if rowwise else (N, m)
-        A = _rand_sparse(rng, *shape, nnz=70)
-        T = sk.CWT(N, s_dim, ctx)
-        kd = np.asarray(jax.random.key_data(T.allocation.key),
-                        dtype=np.uint32)
-        padded, d, ix, pt, rows = self._lanes(A)
-        ref = np.asarray(sparse_serve.cwt_sparse_serve_apply(
-            kd, jnp.asarray(d), jnp.asarray(ix), jnp.asarray(pt),
-            s_dim=s_dim, rowwise=rowwise, shape=padded))
-        out = np.asarray(pallas_sparse.cwt_sparse_apply(
-            kd, d, rows, ix, s_dim=s_dim, rowwise=rowwise,
-            shape=padded, accum="exact", interpret=True))
-        assert np.array_equal(out, ref)
-
-    @pytest.mark.parametrize("rowwise", [False, True])
-    def test_mxu_accum_allclose_and_lattice_bitwise(self, rowwise):
-        rng = np.random.default_rng(12)
-        ctx = Context(seed=12)
-        N, m, s_dim = 128, 9, 16
-        shape = (m, N) if rowwise else (N, m)
-        T = sk.CWT(N, s_dim, ctx)
-        kd = np.asarray(jax.random.key_data(T.allocation.key),
-                        dtype=np.uint32)
-        A = _rand_sparse(rng, *shape, nnz=50)
-        padded, d, ix, pt, rows = self._lanes(A)
-        ref = np.asarray(sparse_serve.cwt_sparse_serve_apply(
-            kd, jnp.asarray(d), jnp.asarray(ix), jnp.asarray(pt),
-            s_dim=s_dim, rowwise=rowwise, shape=padded))
-        out = np.asarray(pallas_sparse.cwt_sparse_apply(
-            kd, d, rows, ix, s_dim=s_dim, rowwise=rowwise,
-            shape=padded, accum="mxu", interpret=True))
-        assert np.allclose(out, ref, rtol=1e-5, atol=1e-6)
-        L = _lattice_sparse(rng, *shape, nnz=50)
-        padded, d, ix, pt, rows = self._lanes(L)
-        ref = np.asarray(sparse_serve.cwt_sparse_serve_apply(
-            kd, jnp.asarray(d), jnp.asarray(ix), jnp.asarray(pt),
-            s_dim=s_dim, rowwise=rowwise, shape=padded))
-        out = np.asarray(pallas_sparse.cwt_sparse_apply(
-            kd, d, rows, ix, s_dim=s_dim, rowwise=rowwise,
-            shape=padded, accum="mxu", interpret=True))
-        assert np.array_equal(out, ref)
-
-    def test_batched_lanes_capacity_invariant(self):
-        rng = np.random.default_rng(13)
-        ctx = Context(seed=13)
-        N, m, s_dim = 128, 8, 16
-        ops = [_rand_sparse(rng, N, m, nnz=30 + i) for i in range(4)]
-        Ts = [sk.CWT(N, s_dim, ctx) for _ in ops]
-        kds, ds, rs, cs = [], [], [], []
-        padded = bucketing.pad_shape((N, m), (0, 1))
-        for T, A in zip(Ts, ops):
-            _, d, ix, pt, rows = self._lanes(A)
-            kds.append(np.asarray(
-                jax.random.key_data(T.allocation.key), np.uint32))
-            ds.append(d)
-            rs.append(rows)
-            cs.append(ix)
-        full = np.asarray(pallas_sparse.cwt_sparse_apply_batched(
-            np.stack(kds), np.stack(ds), np.stack(rs), np.stack(cs),
-            s_dim=s_dim, rowwise=False, shape=padded, accum="exact",
-            interpret=True))
-        for i in range(4):
-            one = np.asarray(pallas_sparse.cwt_sparse_apply(
-                kds[i], ds[i], rs[i], cs[i], s_dim=s_dim,
-                rowwise=False, shape=padded, accum="exact",
-                interpret=True))
-            assert np.array_equal(full[i], one)
-
-    def test_qualify_declines_off_tpu(self):
-        ok, why = pallas_sparse.qualify(16, 128, 8, 64, "float32",
-                                        interpret=True)
-        assert not ok and "TPU" in why
-        ok, why = pallas_sparse.qualify(16, 128, 8, 64, "float32",
-                                        interpret=False)
-        assert not ok  # CPU backend: available() is False
-
+class TestRowIds:
     def test_row_id_expansion(self):
         ptr = jnp.asarray(np.array([0, 2, 2, 5, 5], np.int32))
         rows = np.asarray(sparse_serve.csr_row_ids(ptr, 8))
