@@ -61,6 +61,12 @@ METRICS: Dict[str, str] = {
     # "pallas_generate" | "xla") — the cross-check of the features that
     # feature_rate.apply reads from the sketch.dispatch spans
     "sketch.features": "counter",
+    # the Fastfood apply (sketch/frft.py): feature values produced (rows ×
+    # s), by family and route ("fastfood_blocks" = the one compiled
+    # program of frft.fastfood_features | "chain" = the eager chain of a
+    # declined route) — the cross-check of the features that
+    # feature_rate.apply reads from its sketch.dispatch spans
+    "sketch.fastfood_features": "counter",
     # the compiled FJLT/wht apply (sketch/fjlt.py): operand entries sign-
     # and Hadamard-mixed (transform axis × free axis), by family and kernel
     # ("pallas_blocks" | "xla_bf16x3" | "xla_f32") — the cross-check of

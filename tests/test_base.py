@@ -325,8 +325,15 @@ class TestPrecisionPolicy:
         X = jnp.asarray(
             np.random.default_rng(0).standard_normal((4, 64)), jnp.float32)
         from libskylark_tpu.sketch import ROWWISE
-        T.apply(X, ROWWISE)                      # library default ambient
+        # library default ambient: apply() is the one compiled program,
+        # whose stages state their own precision; the chain opts into HIGH
+        T.apply(X, ROWWISE)
+        assert seen == []
+        T._features_rows(X)
         with jax.default_matmul_precision("tensorfloat32"):
-            T.apply(X, ROWWISE)                  # user-pinned ambient
+            # user-pinned ambient: apply() keeps the chain, and the pin
+            # governs it
+            assert T.features_plan(X, True) == "precision=pinned"
+            T.apply(X, ROWWISE)
         assert seen[0] is jax.lax.Precision.HIGH  # opt-in active
         assert seen[2] is None                    # user pin honored

@@ -363,9 +363,11 @@ class TestFastfoodExplicitCall:
             pf.features_rows(T, A, precision="f32", variant=variant)
 
     def test_transform_apply_takes_the_xla_chain(self, monkeypatch):
-        """``FastRFT.apply`` does not reach the kernel: Mosaic rejects
-        both variants on a v5e, so the transform's own path is the XLA
-        chain wherever it runs."""
+        """``FastRFT.apply`` does not reach the fused kernel: Mosaic
+        rejects both its variants on a v5e, so the transform's own path
+        is the one compiled program of ``frft.fastfood_features`` (PR 48),
+        equal to the XLA chain to float32 tolerance (another summation
+        order, ``cos_turns`` for the cosine)."""
         from libskylark_tpu.sketch import ROWWISE, COLUMNWISE
         from libskylark_tpu.sketch import pallas_fastfood as pf
 
@@ -375,9 +377,11 @@ class TestFastfoodExplicitCall:
         monkeypatch.setattr(pf, "features_rows", no_kernel)
         T, A = self._transform(), self._input()
         ref = np.asarray(T._features_rows(A))
-        np.testing.assert_array_equal(np.asarray(T.apply(A, ROWWISE)), ref)
-        np.testing.assert_array_equal(
-            np.asarray(T.apply(A.T, COLUMNWISE)), ref.T)
+        tol = 2e-5 * T.scale
+        np.testing.assert_allclose(np.asarray(T.apply(A, ROWWISE)), ref,
+                                   rtol=0, atol=tol)
+        np.testing.assert_allclose(np.asarray(T.apply(A.T, COLUMNWISE)),
+                                   ref.T, rtol=0, atol=tol)
 
 
 class TestCostCalibration:

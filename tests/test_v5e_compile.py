@@ -427,3 +427,79 @@ def test_cell_shape_fjlt_dct_mix_sample(one_chip, cols, slabs):
     assert (2 * gathered * cols * 4 < memory.temp_size_in_bytes
             < 2 * gathered * cols * 4 + (32 << 20))
     assert memory.temp_size_in_bytes < min(fjlt._DFT_TEMP_BYTES, 4.3e9)
+
+
+# -- the fastfood_features_apply cell: Fastfood at CIFAR-10 widths ------------
+
+FF_ROWS, FF_N, FF_S = 50000, 3072, 16384
+
+
+def test_cell_shape_fastfood_features(one_chip):
+    """``Gaussian(3072, 78).create_rft(16384, ctx, "fast")`` rowwise of
+    50,000 × 3072 as the one program: the transposed, padded operand made
+    once, then a walk of 4 blocks × 2 chunks of 49 tiles of 512 examples —
+    a step is ``mix_chunk`` (Mosaic), the gather of whole rows by Π in ONE
+    fusion (a stage array of 25,088 entries a row: the compiler cuts a wider
+    one in halves first and joins them after) and ``mix_cos_rows`` (Mosaic),
+    which writes its slab of the result in place. Nothing initialises the
+    result, nothing copies it, and beside the operand and the result the
+    program holds the copy of the operand and two chunk-stage arrays:
+    1.65 GB, not a whole stage's 3.28."""
+    from libskylark_tpu.ml import kernels
+    from libskylark_tpu.base.context import Context
+    from libskylark_tpu.sketch import frft
+
+    T = kernels.Gaussian(FF_N, 78.0).create_rft(FF_S, Context(1), "fast")
+    tile = T.kernel_tile(FF_ROWS, interpret=True)
+    assert tile == 512 and frft.walk_geometry(FF_ROWS, tile) == (2, 49)
+    spec = (T.sketch_type, FF_N, FF_S, tuple(sorted(T._extra_params().items())))
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    program = jax.jit(functools.partial(
+        frft.fastfood_features, spec=spec, rowwise=True, kernel="pallas_wht",
+        tile=tile))
+    compiled = program.lower(arg((2,), jnp.uint32),
+                             arg((FF_ROWS, FF_N), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 2                      # one loop body
+    called = [i for i in _instructions(text) if "fused_computation" not in i[0]]
+    chunk = 4096 * 25088
+    # a step: the two kernels and one gather fusion between them
+    staged = [i for i in called if i[2] == chunk]
+    assert sorted(i[3] for i in staged) == ["custom-call", "fusion"], staged
+    # the result: made by no pass (an uninitialised buffer), written by the
+    # second kernel alone, never copied
+    result = [i for i in called if i[2] == FF_ROWS * FF_S
+              and i[3] not in ("parameter", "get-tuple-element", "bitcast", "while")]
+    assert sorted(i[3] for i in result) == ["custom-call", "custom-call"], result
+    assert 'custom_call_target="AllocateBuffer"' in text or "empty" in text
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == FF_ROWS * FF_S * 4
+    assert memory.temp_size_in_bytes < 1.7e9
+
+
+def test_fastfood_block_kernels_other_shapes(one_chip):
+    """The block kernels at the plan's other corners: the smallest block
+    (1024 × 128), the widest (16384 × 256, ``pallas_wht``'s own VMEM plan),
+    and the one-pass ``"bf16"`` regime of the benchmark's control."""
+    from libskylark_tpu.sketch import pallas_wht
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    for n, tile, steps, passes in [(1024, 128, 3, 3), (16384, 256, 2, 3),
+                                   (4096, 512, 2, 1)]:
+        cols = tile * steps
+        text = pallas_wht.mix_chunk.lower(
+            arg((n, 2 * cols), jnp.float32), arg((n,), jnp.float32),
+            arg((2,), jnp.int32), tile=tile, cols=cols,
+            passes=passes).compile().as_text()
+        assert text.count(KERNEL) == 1
+        text = pallas_wht.mix_cos_rows.lower(
+            arg((n, cols), jnp.float32), arg((n,), jnp.float32),
+            arg((n,), jnp.float32), arg((n,), jnp.float32),
+            arg((2 * cols - 5, 2 * n), jnp.float32), arg((2,), jnp.int32),
+            tile=tile, outscale=0.01, passes=passes).compile().as_text()
+        assert text.count(KERNEL) == 1
