@@ -53,16 +53,16 @@ def available() -> bool:
 
 
 def compiler_params(*dimension_semantics: str):
-    """Mosaic compiler params shared by every ``pallas_call`` in
-    sketch/pallas_*.py. No ``vmem_limit_bytes``: the tile plans target
-    Mosaic's default scoped VMEM (``_VMEM_BUDGET_BYTES``, 16 MiB of a
-    v5e core's 128), and at the headline widths every kernel that
-    compiles on a v5e compiles inside it (PERF.md, PR 21). The one
+    """Mosaic compiler params of every ``pallas_call`` in
+    sketch/pallas_*.py whose tile plan targets Mosaic's default scoped
+    VMEM (``_VMEM_BUDGET_BYTES``, 16 MiB of a v5e core's 128): no
+    ``vmem_limit_bytes``. At the headline widths every such kernel that
+    compiles on a v5e compiles inside it (PERF.md, PR 21); the one
     rejection seen since (m_tile 1024 at s_dim 1024, PR 27) was a plan
-    that left the matmul's result tile out, and was cured there
-    (:func:`_vmem_estimate`). Tiles past the default scope are faster
-    (sketch/params.py, m-tile note) and would start here, with the
-    budget the plans read — ROADMAP Queue 1."""
+    that left the matmul's result tile out (:func:`_vmem_estimate`).
+    Two families plan past the scope and pass what they fitted: the
+    "hbm" contraction's grown row tile (:func:`_contraction_params`,
+    PR 49) and pallas_wht.py's mixers (PR 39)."""
     return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
 
 
@@ -174,9 +174,9 @@ def _dot(lhs, rhs, dims, precision, gen_side=1):
 
 # Per-core VMEM budget the tile plans target: Mosaic's default SCOPED
 # limit (16 MiB — not the core's VMEM, which is 128 MiB on a v5e by
-# pltpu.get_tpu_info(); PERF.md §6, PR 27). No pallas_call passes
-# ``vmem_limit_bytes``, so a plan past the scope is a Mosaic rejection,
-# which raises.
+# pltpu.get_tpu_info(); PERF.md §6, PR 27). A plan past the scope is a
+# Mosaic rejection, which raises — but for the "hbm" contraction's row
+# tile, grown under :func:`_vmem_cap` with the limit passed (PR 49).
 _VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 
 # Cap on the "vmem" residency (:func:`operator_residency`): when the
@@ -542,12 +542,12 @@ def _plane_step_cols(n: int, m_tile: int, s_tile: int,
     plane tile is there the LEFT operand of the HIGHEST contraction,
     which Mosaic splits whole, 20 B an entry and not 10 (sixteen shapes,
     PR 36). That regime alone steps down to half a block where one does
-    not fit (s_dim 1536 at m_tile 512 asks 18.5 MiB at 256 columns)."""
-    per_plane_entry = 20 if lhs_f32 else 10
+    not fit (s_dim 1536 at m_tile 512 asks 18.5 MiB at 256 columns).
+    The plan is :func:`_contraction_vmem`; a row tile grown past the
+    scope keeps the step of the tile inside it (:func:`_contraction`)."""
     for cols in (2 * BLOCK_COLS, BLOCK_COLS):
-        plan = (4 * (5 * m_tile * cols + 3 * m_tile * s_tile)
-                + per_plane_entry * s_tile * cols)
-        if n % cols == 0 and plan <= _VMEM_BUDGET_BYTES:
+        if n % cols == 0 and _contraction_vmem(
+                m_tile, s_tile, cols, lhs_f32) <= _VMEM_BUDGET_BYTES:
             return cols
     return BLOCK_COLS // 2 if lhs_f32 else BLOCK_COLS
 
@@ -560,9 +560,9 @@ def _planes_call(A, keys, scale, extra_operands, *, s_dim, s_tile, dist_kind,
     ``scale``·S·A of A (n, m) over (column tile, k), the result tile all
     s_dim rows. With no scratch every axis but k stays "parallel"."""
     m, n = A.shape if rowwise else A.shape[::-1]
-    k_cols = _plane_step_cols(
-        n, m_tile, s_tile,
-        not rowwise and _plane_dtypes(precision)[0] == jnp.float32)
+    k_cols, vmem_limit = _contraction(
+        n, m_tile, s_tile, _lhs_f32(precision, rowwise),
+        epilogue is not None)
     n_blocks = n // k_cols
     planes = _operator_planes(keys, scale, s_dim=s_dim, dist_kind=dist_kind,
                               precision=precision, s_tile=s_tile,
@@ -595,8 +595,8 @@ def _planes_call(A, keys, scale, extra_operands, *, s_dim, s_tile, dist_kind,
         in_specs=in_specs,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
-        compiler_params=compiler_params(*["parallel"] * (len(grid) - 1),
-                                        "arbitrary"),
+        # a row tile past the default scope asks for the plan it fitted
+        compiler_params=_contraction_params(len(grid), vmem_limit),
         interpret=interpret,
     )(*operands)
 
@@ -753,8 +753,8 @@ def _resolve_knobs(m_tile, precision):
     """The two tuning knobs of an apply: the call-site argument, else the
     sketch.params setter (whose default is the heuristic). Returns
     ``(m_tile, precision, source)`` — ``source`` is "arg" when the call
-    site gave either knob, "heuristic" otherwise. The tile is a request:
-    :func:`_qualify` fits it to the operand."""
+    site gave either knob, "heuristic" otherwise. The tile is a request
+    that is only ever shrunk; None is the planner's (:func:`_planned`)."""
     source = "heuristic" if m_tile is None and precision is None else "arg"
     if m_tile is None:
         m_tile = sketch_params.get_pallas_m_tile()
@@ -787,9 +787,9 @@ def _tile_fits(m_tile: int, s_tile: int, epilogue: bool) -> bool:
     asked 17.2 MiB at 512 × 1536 where the plan without them says 16.0
     (compiled for a v5e, PR 32). Since the finisher works in slabs
     (:func:`_finish_in_place`) it asks 14.0 there, 13.8 at 512 × 1024
-    over 16 k steps (15.8 before) and 9.6 at the feature cell's plan, as
-    before (least ``vmem_limit_bytes`` that compiles, PR 38): the term is
-    room now, kept because the tiles are not that change's to move."""
+    over 16 k steps (15.8 before) and 9.6 at the feature cell's plan
+    (least limit that compiles, PR 38): the term is room now, kept —
+    by PR 49 too — because it decides the feature cell's 512 × 1024."""
     return _vmem_estimate(
         m_tile, s_tile,
         4 * m_tile * s_tile if epilogue else 0) <= _VMEM_BUDGET_BYTES
@@ -897,26 +897,26 @@ class Plan(NamedTuple):
 
 def _plan(dist, A, s_dim: int, seq_axis: int, m_tile, precision,
           interpret: bool, epilogue: bool = False) -> Optional[Plan]:
-    """The prelude of every fused apply: resolve the knobs
-    (:func:`_resolve_knobs`) and plan the tiles (:func:`_tile_plan`;
-    ``epilogue``: the kernel finishes its tiles with the cos).
-    Returns the :class:`Plan` with the effective tiles, or None when the
-    kernel declines and the caller takes the XLA path."""
+    """The prelude of every fused apply: the knobs, the tiles and the
+    residency by :func:`_planned` (``epilogue``: the kernel finishes its
+    tiles with the cos), under the ``sketch.plan`` span; what was chosen
+    is noted on the enclosing ``sketch.apply`` under the report's own
+    keys (:func:`effective_plan`), the "hbm" contraction's k step and
+    the ``vmem_limit_bytes`` it passes (0: none) among them. Returns the
+    :class:`Plan` with the effective tiles, or None when the kernel
+    declines and the caller takes the XLA path."""
     with _trace.span("sketch.plan") as sp:
-        m_tile, precision, source = _resolve_knobs(m_tile, precision)
-        tiles = _tile_plan(dist, A, seq_axis=seq_axis, m_tile=m_tile,
-                           interpret=interpret, s_dim=s_dim,
-                           epilogue=epilogue)
+        plan = _planned(dist, A, s_dim, seq_axis, m_tile, precision,
+                        interpret, epilogue)
         if sp is not None:
-            sp.set_attr("plan_source", source)
-    if tiles is None:
+            sp.set_attr("plan_source", plan["plan_source"])
+    if not plan["kernel"]:
         return None
-    mt, st = tiles
-    n_p, m_p = _padded_extents(A.shape[seq_axis], A.shape[1 - seq_axis], mt)
-    residency = operator_residency(s_dim, n_p, m_p, mt, st)
-    note_apply(path="pallas", m_tile=mt, s_tile=st, precision=precision,
-               plan_source=source, operator_residency=residency)
-    return Plan(mt, st, precision, residency, interpret)
+    note_apply(path="pallas", **{key: plan[key] for key in (
+        "m_tile", "s_tile", "precision", "plan_source", "operator_residency",
+        "k_cols", "vmem_limit_bytes")})
+    return Plan(plan["m_tile"], plan["s_tile"], plan["precision"],
+                plan["operator_residency"], interpret)
 
 
 @functools.partial(jax.jit, static_argnames="n")
@@ -1115,38 +1115,157 @@ def fused_partial(
     return _fused_call_cw(A_loc, keys, None, **kw)
 
 
-def effective_plan(dist, shape, dtype, s_dim: int, seq_axis: int,
-                   m_tile: int | None = None,
-                   interpret: bool = False,
-                   precision: str | None = None,
-                   epilogue: bool = False) -> dict:
-    """The plan a fused apply with these arguments would actually run —
-    WITHOUT running it. The requested tile can be adjusted downstream
-    (:func:`_tile_plan` shrinks an over-budget m-tile, or tiles s), so
-    anything recording a measurement labeled with the REQUESTED knobs must
-    ask for the EFFECTIVE ones or the record lies about what was measured
-    (e.g. the m-tile sweep rows in benchmarks/). Runs the SAME
-    resolution as the dispatch (:func:`_resolve_knobs`).
+# ---------------------------------------------------------------------------
+# the plan of an apply, and the "hbm" contraction's tile past the default scope
+# ---------------------------------------------------------------------------
+#
+# (Below the kernels and their call sites on purpose: a compiled program
+# carries the line of every frame that traced it, so what stands above
+# keeps its lines and a program this section does not change — the
+# feature cell's — stays the same bytes.)
 
-    Returns ``{"kernel": False, "plan_id": "xla"}`` when the apply would
-    take the XLA fallback, else ``kernel/m_tile/s_tile/
-    operator_residency/operator_cache/precision/plan_id/plan_source``
-    (``operator_cache`` is ``operator_residency == "vmem"``; ``s_tile``
-    is ``s_dim`` unless the plan tiles s). ``epilogue``: the plan of a
-    feature map's apply, whose kernel finishes its tiles with the cos."""
+# The row tile the planner asks for where nobody requested one
+# (sketch/params.py, m-tile note): 512 is the largest power of two whose
+# plan fits Mosaic's default scope at s_dim = 1024, and the ceiling is
+# where the measured gain of a larger contraction tile flattens (− 0.7 ms
+# an apply at 1024, − 0.2 more at 2048; PERF.md §6, PR 27 and PR 49).
+_M_TILE = 512
+_M_TILE_CEILING = 2048
+
+# What a grown contraction asks of Mosaic over its fitted plan. The plan
+# is a least-limit fit on twenty-seven shapes of at most 512 rows, not a
+# bound: at 2048 × 1024 × 512 Mosaic asks 51.1 MiB rowwise at "f32"
+# where it says 49.0 (40.0 at "bf16x3"; compiled for a v5e, PR 49).
+_VMEM_SLACK_BYTES = 8 * 1024 * 1024
+
+
+def _contraction_vmem(m_tile: int, s_tile: int, k_cols: int,
+                      lhs_f32: bool = False) -> int:
+    """The contraction kernel's own VMEM plan for a step of ``k_cols``
+    columns, in bytes — the fit :func:`_plane_step_cols` describes."""
+    return (4 * (5 * m_tile * k_cols + 3 * m_tile * s_tile)
+            + (20 if lhs_f32 else 10) * s_tile * k_cols)
+
+
+def _lhs_f32(precision: str, rowwise: bool) -> bool:
+    """Whether the plane tile is the split LEFT operand of a HIGHEST
+    contraction (:func:`_plane_step_cols`): columnwise "f32"."""
+    return not rowwise and _plane_dtypes(precision)[0] == jnp.float32
+
+
+def _contraction(n: int, m_tile: int, s_tile: int, lhs_f32: bool,
+                 epilogue: bool) -> tuple[int, int]:
+    """``(k_cols, vmem_limit)`` of the "hbm" contraction at ``m_tile``,
+    from the shapes alone — one rule, read by the call
+    (:func:`_planes_call`) and by the plan that reports it
+    (:func:`_planned`), as :func:`operator_residency` is. A tile of at
+    most ``_M_TILE`` rows that the default scope admits
+    (:func:`_tile_fits`: every tile the planner's own request can end
+    at) takes its own :func:`_plane_step_cols` and passes no limit, 0:
+    the program it always was. A larger tile — grown, or an explicit
+    request the scope let through — takes the k step of the
+    ``_M_TILE`` plan (halved until the scope admits it), so that each
+    result row keeps its k order, its products and its bits whatever the
+    tile, and passes its fitted plan plus the slack, never less than the
+    default scope."""
+    base = min(m_tile, _M_TILE)
+    while base > 8 and not _tile_fits(base, s_tile, epilogue):
+        base //= 2
+    k_cols = _plane_step_cols(n, base, s_tile, lhs_f32)
+    if base == m_tile:
+        return k_cols, 0
+    return k_cols, max(_contraction_vmem(m_tile, s_tile, k_cols, lhs_f32)
+                       + _VMEM_SLACK_BYTES, _VMEM_BUDGET_BYTES)
+
+
+def _contraction_params(grid_rank: int, vmem_limit: int):
+    """:func:`compiler_params` of the contraction's grid — every axis but
+    the last, k, "parallel" — with ``vmem_limit`` where there is one."""
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * (grid_rank - 1) + ("arbitrary",),
+        vmem_limit_bytes=vmem_limit or None)
+
+
+@functools.cache    # a process has one default device; the reading is 30 µs
+def _vmem_cap() -> int:
+    """The scoped VMEM a contraction may ask Mosaic for: half of the
+    core's VMEM as ``pltpu.get_tpu_info()`` reports it for the device
+    (64 MiB on a v5e; the other half stays the compiler's), never under
+    the default scope — and the default scope itself where there is no
+    TPU to ask (interpret mode on a CPU) or the core has no more (v2–v4:
+    16 MiB), so that every plan there is the one it always was."""
+    try:
+        core = pltpu.get_tpu_info().vmem_capacity_bytes
+    except ValueError:          # "Unsupported TPU device kind: cpu"
+        return _VMEM_BUDGET_BYTES
+    return max(core // 2, _VMEM_BUDGET_BYTES)
+
+
+def _grown_rows(n: int, m: int, m_tile: int, s_tile: int, precision: str,
+                rowwise: bool, epilogue: bool, vmem_cap: int) -> int:
+    """The row tile of an "hbm" contraction nobody requested a tile for:
+    the largest power of two ≤ ``_M_TILE_CEILING`` that divides the ``m``
+    rows as ``m_tile`` padded them (no shape pads further, or
+    differently, than it did), leaves more than one tile (the residency
+    stays "hbm") and whose limit (:func:`_contraction`) fits
+    ``vmem_cap``; ``m_tile`` itself where none does, where the cap
+    grants nothing over the default scope, where the contraction is one
+    k step (n ≤ 512, the feature maps: each tile is written once, there
+    is no read-modify-write to amortize, and the kernel stands at 98 %
+    of its MXU floor) and for the rowwise "f32" regime, whose A tile is
+    the left operand of a HIGHEST contraction that Mosaic splits whole:
+    on a v5e 2048 rows took 41.0 ms an apply against 37.0 at 512, where
+    every other regime and the columnwise "f32" gained 0.4–0.9 ms
+    (PERF.md §6, PR 49). A larger tile changes which rows share a grid
+    step — fewer steps, fewer reads of the planes, fewer
+    read-modify-writes of the out tile — and nothing of a row's
+    arithmetic."""
+    f32 = _plane_dtypes(precision)[0] == jnp.float32
+    lhs_f32 = _lhs_f32(precision, rowwise)
+    if (vmem_cap <= _VMEM_BUDGET_BYTES or (f32 and rowwise)
+            or n <= _contraction(n, m_tile, s_tile, lhs_f32, epilogue)[0]):
+        return m_tile
+    rows = _M_TILE_CEILING
+    while rows > m_tile:
+        if m % rows == 0 and m // rows > 1 and _contraction(
+                n, rows, s_tile, lhs_f32, epilogue)[1] <= vmem_cap:
+            return rows
+        rows //= 2
+    return m_tile
+
+
+def _planned(dist, A, s_dim: int, seq_axis: int, m_tile, precision,
+             interpret: bool, epilogue: bool = False,
+             vmem_cap: Optional[int] = None) -> dict:
+    """What a fused apply of ``A`` (anything with a shape and a dtype)
+    will run, from the shapes alone — the one resolution behind the
+    dispatch (:func:`_plan`) and the report (:func:`effective_plan`,
+    which documents the keys): the knobs (:func:`_resolve_knobs`), the
+    tiles the default scope admits (:func:`_tile_plan`) and the
+    residency at those tiles, so that a shape that is "vmem" or
+    "per_tile" there stays so. An "hbm" contraction whose tile nobody
+    requested then takes the row tile of :func:`_grown_rows` under
+    ``vmem_cap`` (default: :func:`_vmem_cap`, the device's)."""
     m_tile, precision, source = _resolve_knobs(m_tile, precision)
-    A = jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
-    tiles = _tile_plan(dist, A, seq_axis=seq_axis, m_tile=m_tile,
+    tiles = _tile_plan(dist, A, seq_axis=seq_axis, m_tile=m_tile or _M_TILE,
                        interpret=interpret, s_dim=s_dim, epilogue=epilogue)
     if tiles is None:
-        return {"kernel": False, "plan_id": "xla",
-                "plan_source": source}
+        return {"kernel": False, "plan_id": "xla", "plan_source": source}
     mt, st = tiles
     # the same padding/residency helpers the pallas_call sites use
-    n_p, m_p = _padded_extents(shape[seq_axis], shape[1 - seq_axis], mt)
+    n_p, m_p = _padded_extents(A.shape[seq_axis], A.shape[1 - seq_axis], mt)
     residency = operator_residency(s_dim, n_p, m_p, mt, st)
+    k_cols, vmem_limit = BLOCK_COLS, 0      # the generating kernels' step
+    if residency == "hbm":
+        if m_tile is None:
+            mt = _grown_rows(n_p, m_p, mt, st, precision, seq_axis == 1,
+                             epilogue,
+                             _vmem_cap() if vmem_cap is None else vmem_cap)
+        k_cols, vmem_limit = _contraction(
+            n_p, mt, st, _lhs_f32(precision, seq_axis == 1), epilogue)
     tile_id = f"mt{mt}" if st == s_dim else f"mt{mt}/st{st}"
-    return {"kernel": True, "m_tile": mt, "s_tile": st,
+    return {"kernel": True, "m_tile": mt, "s_tile": st, "k_cols": k_cols,
+            "vmem_limit_bytes": vmem_limit,
             "operator_residency": residency,
             "operator_cache": residency == "vmem",
             "precision": precision,
@@ -1154,6 +1273,37 @@ def effective_plan(dist, shape, dtype, s_dim: int, seq_axis: int,
             # Plan.plan_id writes the same string for the same plan
             "plan_id": f"pallas/{tile_id}/{precision}",
             "plan_source": source}
+
+
+def effective_plan(dist, shape, dtype, s_dim: int, seq_axis: int,
+                   m_tile: int | None = None,
+                   interpret: bool = False,
+                   precision: str | None = None,
+                   epilogue: bool = False,
+                   vmem_cap: int | None = None) -> dict:
+    """The plan a fused apply with these arguments would actually run —
+    WITHOUT running it. The requested tile can be adjusted downstream
+    (:func:`_tile_plan` shrinks an over-budget m-tile, or tiles s; a tile
+    nobody requested is grown for the "hbm" contraction,
+    :func:`_grown_rows`), so anything recording a measurement labeled
+    with the REQUESTED knobs must ask for the EFFECTIVE ones or the
+    record lies about what was measured (e.g. the m-tile sweep rows in
+    benchmarks/). It IS the dispatch's resolution (:func:`_planned`).
+
+    Returns ``{"kernel": False, "plan_id": "xla"}`` when the apply would
+    take the XLA fallback, else ``kernel/m_tile/s_tile/k_cols/
+    vmem_limit_bytes/operator_residency/operator_cache/precision/plan_id/
+    plan_source`` (``operator_cache`` is ``operator_residency == "vmem"``;
+    ``s_tile`` is ``s_dim`` unless the plan tiles s; ``k_cols`` is the
+    contraction's step and ``vmem_limit_bytes`` what the "hbm"
+    contraction passes Mosaic, 0 where it passes none). ``epilogue``: the
+    plan of a feature map's apply, whose kernel finishes its tiles with
+    the cos. ``vmem_cap`` (internal; default the device's,
+    :func:`_vmem_cap`) is the scope a grown tile may ask for: a compile
+    for a described chip hands over that chip's."""
+    A = jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
+    return _planned(dist, A, s_dim, seq_axis, m_tile, precision, interpret,
+                    epilogue, vmem_cap)
 
 
 # ---------------------------------------------------------------------------
@@ -1255,7 +1405,8 @@ def serve_qualify(dist, s_dim: int, n: int, m: int, dtype,
         return False, f"distribution/dtype unsupported ({dtype})"
     lane = jax.ShapeDtypeStruct((m, n), jnp.dtype(dtype))
     mt = _qualify(dist, lane, seq_axis=1,
-                  m_tile=m_tile or sketch_params.get_pallas_m_tile(),
+                  m_tile=(m_tile or sketch_params.get_pallas_m_tile()
+                          or _M_TILE),
                   interpret=interpret, s_dim=s_dim)
     if mt is None:
         return False, "no m-tile fits the VMEM budget"
@@ -1281,7 +1432,8 @@ def serve_batched_apply(key_data, scale, A, *, dist, s_dim: int,
     lane = jax.ShapeDtypeStruct(
         (m, n) if rowwise else (n, m), A.dtype)
     mt = _qualify(dist, lane, seq_axis=1 if rowwise else 0,
-                  m_tile=m_tile or sketch_params.get_pallas_m_tile(),
+                  m_tile=(m_tile or sketch_params.get_pallas_m_tile()
+                          or _M_TILE),
                   interpret=interpret, s_dim=s_dim)
     if mt is None:
         raise ValueError(

@@ -146,25 +146,46 @@ def set_pallas_precision(p: str) -> None:
 #   Columnwise, 8192 × 65536 → 1024 × 65536 (PR 36): 42.7 regenerated,
 #   20.2 under "hbm" at 512 columns a tile and a step, 21.4 at 256 × 512
 #   or 512 × 256, 23.0 at 256 × 256.
+#   With the limit the package passes since PR 49 (PERF.md §5 and §6):
+#   20.08 / 19.57 / 19.28 / 21.54 rowwise and 19.99 / 19.51 / 19.31 / 21.47
+#   columnwise at 512 / 1024 / 2048 / 4096 — the gain flattens at 2048
+#   and 4096 rows (101 MiB of VMEM asked) are slower than 512; the
+#   results of all four tiles are bit-equal. "f32" rowwise alone loses
+#   (41.0 at 2048 against 37.0 at 512) and keeps 512.
 # 512 is the largest power of two whose plan fits Mosaic's 16 MiB default
 # scoped VMEM at s_dim = 1024 (_vmem_estimate plans 11 MiB, Mosaic needs
 # 9.4–9.9); 1024 needs 17.0 MiB and Mosaic refused it on the chip. A v5e
-# core has 128 MiB of VMEM, so the larger tiles above ran only with
-# ``vmem_limit_bytes`` raised by the measuring script — no pallas_call in
-# the package passes one (ROADMAP Queue 1). _qualify still shrinks
-# per-call when s_dim is larger. A sweep passes ``m_tile=`` or calls
+# core has 128 MiB of VMEM, and since PR 49 the one call the larger tile
+# pays in — the "hbm" residency's contraction over several k steps,
+# pallas_dense._planes_call — passes ``vmem_limit_bytes``: its own
+# fitted plan plus a slack, under a cap of half the core's VMEM as
+# pltpu.get_tpu_info() reports it (pallas_dense._vmem_cap; no TPU to ask,
+# or a 16 MiB core: no growth, every plan as before). So the setter's
+# default is None, "the planner's choice": 512 as the request every
+# other kernel's plan starts from, grown for that contraction to the
+# largest power of two ≤ 2048 that divides the operand's tiled extent and
+# fits the cap (pallas_dense._grown_rows; 2048 at the headline shape). A
+# number — ``m_tile=`` at the call site or set_pallas_m_tile — is a
+# REQUEST, as it always was: fitted to the operand and shrunk where the
+# default scope refuses it (_qualify), never grown; set 512 to pin the
+# old plan. (A request above 512 that the scope lets through, s_dim ≤ 768,
+# now runs at the 512-row plan's k step under a limit of its own: the same
+# bits as 512 rows give.) A sweep passes ``m_tile=`` or calls
 # set_pallas_m_tile.
-_pallas_m_tile = 512
+_pallas_m_tile = None
 
 
-def get_pallas_m_tile() -> int:
+def get_pallas_m_tile() -> int | None:
     return _pallas_m_tile
 
 
-def set_pallas_m_tile(t: int) -> None:
-    t = int(t)
-    if t < 8:
-        raise ValueError(f"pallas_m_tile must be >= 8, got {t}")
+def set_pallas_m_tile(t: int | None) -> None:
+    """Request a row tile, or None to hand the choice back to the
+    planner."""
+    if t is not None:
+        t = int(t)
+        if t < 8:
+            raise ValueError(f"pallas_m_tile must be >= 8, got {t}")
     global _pallas_m_tile
     _pallas_m_tile = t
 

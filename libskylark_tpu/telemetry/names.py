@@ -196,6 +196,11 @@ SPANS: Dict[str, Tuple[str, str]] = {
     # passes an apply or a tile; for the operator), elements (= axis ×
     # columns mixed, which mix_rate.apply reads: the operand's, no pad
     # counted) and sampled (= s × columns kept)
+    # a fused dense apply notes its plan on sketch.apply
+    # (pallas_dense._plan): path="pallas", m_tile, s_tile, precision,
+    # plan_source, operator_residency and, since PR 49, k_cols (the
+    # contraction's step) and vmem_limit_bytes (what the "hbm" contraction
+    # passes Mosaic for a grown row tile; 0: none passed) — for the operator
     "sketch.apply": ("sketch kernel", "sketch_host_ms.apply"),
     "sketch.plan": ("sketch kernel", "sketch_plan_ms.apply"),
     "sketch.dispatch": ("sketch kernel", "sketch_dispatch_ms.apply"),

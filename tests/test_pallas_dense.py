@@ -343,7 +343,10 @@ def test_effective_plan_reports_actual_config():
     # (plan_id/precision/plan_source) and says who chose the knobs.
     p = pd.effective_plan(dist, (8192, 8192), jnp.float32, 512,
                           seq_axis=1, m_tile=1024, interpret=True)
+    # (past 512 rows the k step is the 512-row plan's and a limit passes)
     assert p == {"kernel": True, "m_tile": 1024, "s_tile": 512,
+                 "k_cols": 2 * BLOCK_COLS,
+                 "vmem_limit_bytes": _limit(1024, 512, 2 * BLOCK_COLS),
                  "operator_residency": "hbm", "operator_cache": False,
                  "precision": "bf16x3",
                  "plan_id": "pallas/mt1024/bf16x3",
@@ -844,6 +847,141 @@ def test_fused_on_chip_hbm_residency_at_the_cell_shape():
                                atol=1e-4 * float(np.abs(want).max()))
     second = pd.rowwise_apply(jlt._alloc.key, jlt.dist, A, s, jlt.scale)
     assert bool(jnp.array_equal(first, second))
+
+
+# --- the grown row tile of the "hbm" contraction (PR 49) -------------------
+
+CAP = 64 << 20          # half a v5e core's VMEM: what _vmem_cap reads there
+CELL, CELL_CW = (65536, 8192), (8192, 65536)
+
+
+def _limit(m_tile, s_tile, k_cols, lhs_f32=False):
+    return pd._contraction_vmem(m_tile, s_tile, k_cols,
+                                lhs_f32) + pd._VMEM_SLACK_BYTES
+
+
+@pytest.mark.parametrize("shape,seq_axis,s_dim,knobs,cap,want", [
+    # the two cells, both orientations: 2048 at the two-block k step of
+    # the 512-row plan, the limit the fitted plan plus the slack
+    (CELL, 1, 1024, {}, CAP, (2048, 512, "hbm", _limit(2048, 1024, 512))),
+    (CELL_CW, 0, 1024, {}, CAP, (2048, 512, "hbm", _limit(2048, 1024, 512))),
+    # "f32" columnwise: the plane tile is the split left operand, one
+    # block a step as at 512 columns, 20 B a plane entry in the limit
+    (CELL_CW, 0, 1024, {"precision": "f32"}, CAP,
+     (2048, 256, "hbm", _limit(2048, 1024, 256, True))),
+    # every other regime grows alike — but the rowwise "f32", whose A
+    # tile is the split left operand: 2048 rows read slower on the chip
+    (CELL, 1, 1024, {"precision": "bf16gen2"}, CAP,
+     (2048, 512, "hbm", _limit(2048, 1024, 512))),
+    (CELL, 1, 1024, {"precision": "bf16"}, CAP,
+     (2048, 512, "hbm", _limit(2048, 1024, 512))),
+    (CELL, 1, 1024, {"precision": "f32"}, CAP, (512, 512, "hbm", 0)),
+    # a wider sketch: 2048 rows would plan 66.1 MiB with the slack, 1024
+    # fit; the k step is the one block of the 256-row plan
+    (CELL, 1, 2048, {}, CAP, (1024, 256, "hbm", _limit(1024, 2048, 256))),
+    # the tile divides the extent AS THE PARENT PADS IT: 127 tiles of 512
+    # have no larger power of two, 130 take 1024 — and a ragged m pads
+    # to the same 66560 rows it padded to
+    ((65024, 8192), 1, 1024, {}, CAP, (512, 512, "hbm", 0)),
+    ((66557, 8192), 1, 1024, {}, CAP,
+     (1024, 512, "hbm", _limit(1024, 1024, 512))),
+    # two tiles of 512 stay two: one of 1024 would be "per_tile"
+    ((1024, 8192), 1, 1024, {}, CAP, (512, 512, "hbm", 0)),
+    # one k step (n ≤ 512, the feature maps) keeps its plan
+    ((32768, 440), 1, 16384, {"epilogue": True}, CAP, (512, 512, "hbm", 0)),
+    # "per_tile" and "vmem" at the parent's tile stay so
+    ((512, 8192), 1, 1024, {}, CAP, (512, 256, "per_tile", 0)),
+    ((4096, 1024), 1, 128, {}, CAP, (512, 256, "vmem", 0)),
+    # a request — argument or setter — is only ever shrunk
+    (CELL, 1, 1024, {"m_tile": 512}, CAP, (512, 512, "hbm", 0)),
+    (CELL, 1, 1024, {"m_tile": 4096}, CAP, (512, 512, "hbm", 0)),
+    (CELL, 1, 1024, {"setter": 512}, CAP, (512, 512, "hbm", 0)),
+    (CELL_CW, 0, 1024, {"setter": 256}, CAP, (256, 512, "hbm", 0)),
+    # no TPU to ask (this box), or a core with no more than the default
+    # scope: today's plans, even where a larger tile would fit the scope
+    (CELL, 1, 1024, {}, None, (512, 512, "hbm", 0)),
+    (CELL_CW, 0, 1024, {}, None, (512, 512, "hbm", 0)),
+    ((65536, 32768), 1, 128, {}, 16 << 20, (512, 512, "hbm", 0)),
+])
+def test_grown_tile_rule(shape, seq_axis, s_dim, knobs, cap, want,
+                         restore_knobs):
+    """Where nobody requested a tile, an "hbm" contraction of several k
+    steps takes the largest power of two ≤ 2048 that divides the rows as
+    the 512-row plan pads them, leaves more than one tile and fits the
+    cap by its own fitted plan; everything else keeps the plan it had."""
+    knobs = dict(knobs)
+    if "setter" in knobs:
+        sketch_params.set_pallas_m_tile(knobs.pop("setter"))
+    plan = pd.effective_plan(randgen.Normal(), shape, jnp.float32, s_dim,
+                             seq_axis, interpret=True, vmem_cap=cap, **knobs)
+    assert (plan["m_tile"], plan["k_cols"], plan["operator_residency"],
+            plan["vmem_limit_bytes"]) == want
+    assert plan["vmem_limit_bytes"] <= (cap or pd._VMEM_BUDGET_BYTES)
+    # no shape pads further than under the 512-row request
+    old = pd.effective_plan(randgen.Normal(), shape, jnp.float32, s_dim,
+                            seq_axis, interpret=True, vmem_cap=cap,
+                            **{"m_tile": 512, **knobs})
+    n, m = shape[seq_axis], shape[1 - seq_axis]
+    assert pd._padded_extents(n, m, plan["m_tile"]) == pd._padded_extents(
+        n, m, old["m_tile"])
+    assert plan["operator_residency"] == old["operator_residency"]
+
+
+@pytest.mark.parametrize("kind,cores,want", [
+    ("TPU v5 lite", 1, 64 << 20), ("TPU v6 lite", 1, 64 << 20),
+    ("TPU v5", 2, 32 << 20), ("TPU v4", 2, 16 << 20), (None, 0, 16 << 20)])
+def test_vmem_cap_is_read_from_the_device(kind, cores, want):
+    """Half the core's VMEM by ``pltpu.get_tpu_info()``, never under the
+    default scope; the default scope where there is no TPU to ask."""
+    read = pd._vmem_cap.__wrapped__     # the reading itself, uncached
+    if kind is None:
+        assert read() == pd._vmem_cap() == want == pd._VMEM_BUDGET_BYTES
+        return
+    from jax._src.mesh import AbstractDevice
+    from jax.sharding import AbstractMesh, use_abstract_mesh
+
+    with use_abstract_mesh(AbstractMesh((), (), abstract_device=AbstractDevice(
+            device_kind=kind, num_cores=cores))):
+        assert read() == want
+
+
+@pytest.mark.parametrize("precision", ["bf16x3", "f32"])
+@pytest.mark.parametrize("rowwise", [True, False],
+                         ids=["rowwise", "columnwise"])
+def test_grown_tile_is_bit_equal_to_the_512_tile(rowwise, precision,
+                                                 force_hbm, monkeypatch):
+    """A row tile changes which rows share a grid step, not a row's
+    arithmetic: the apply under the grown plan (4096 rows → two tiles of
+    2048, two k steps of 512) equals the apply at ``m_tile=512`` to the
+    bit, in both orientations, and says on its span what it ran."""
+    m, n, s = 4096, 1024, 1024
+    jlt = JLT(n, s, Context(seed=49))
+    A = jnp.asarray(np.random.default_rng(49).standard_normal(
+        (m, n) if rowwise else (n, m)), jnp.float32)
+    apply = pd.rowwise_apply if rowwise else pd.columnwise_apply
+    kw = dict(precision=precision, interpret=True)
+    key = jlt._alloc.key
+    old = apply(key, jlt.dist, A, s, jlt.scale, m_tile=512, **kw)
+    noted = []
+    monkeypatch.setattr(pd, "note_apply", lambda **kw: noted.append(kw))
+    monkeypatch.setattr(pd, "_vmem_cap", lambda: CAP)
+    new = apply(key, jlt.dist, A, s, jlt.scale, **kw)
+    lhs_f32 = precision == "f32" and not rowwise
+    k_cols = BLOCK_COLS if lhs_f32 else 2 * BLOCK_COLS
+    if rowwise and precision == "f32":
+        # the planner leaves this regime its 512 rows; the call itself
+        # takes any tile, and a row's bits do not depend on it
+        assert [p["m_tile"] for p in noted] == [512]
+        call = functools.partial(
+            pd._fused_call, A, jlt._alloc.key_data, jlt.scale, s_dim=s,
+            dist_kind="normal", precision=precision, interpret=True)
+        new, old = call(m_tile=2048), call(m_tile=512)
+    else:
+        assert [(p["m_tile"], p["k_cols"], p["vmem_limit_bytes"],
+                 p["operator_residency"]) for p in noted] == [
+            (2048, k_cols, _limit(2048, s, k_cols, lhs_f32), "hbm")]
+    assert new.shape == old.shape
+    assert bool(jnp.array_equal(new, old))
 
 
 @pytest.mark.tpu

@@ -135,19 +135,26 @@ class TestOperatorResidencyOnePredicate:
         [((65536, 8192), 1024, 512, 1, "hbm"),       # the benchmark cell
          ((1024, 1024), 128, 256, 1, "vmem"),        # small S: VMEM cache
          ((512, 8192), 1024, 512, 1, "per_tile"),    # one m-tile
-         ((8192, 65536), 1024, 512, 0, "hbm")],      # columnwise big S
-        ids=["headline_hbm", "small_vmem", "single_tile", "columnwise"])
+         ((8192, 65536), 1024, 512, 0, "hbm"),       # columnwise big S
+         # nobody's request, under a v5e's cap: the planner's 2048 (PR 49)
+         ((65536, 8192), 1024, None, 1, "hbm"),
+         ((8192, 65536), 1024, None, 0, "hbm")],
+        ids=["headline_hbm", "small_vmem", "single_tile", "columnwise",
+             "headline_grown", "columnwise_grown"])
     def test_cost_plan_and_kernel_agree(self, shape, s, m_tile, seq_axis,
                                         want):
         import functools
 
         n, m = shape[seq_axis], shape[1 - seq_axis]
-        m_tiles = m // m_tile
-        assert pd.operator_residency(s, n, m, m_tile) == want
         # reader 1: the reported plan
         plan = pd.effective_plan(randgen.Normal(), shape, jnp.float32, s,
-                                 seq_axis, m_tile=m_tile, interpret=True)
+                                 seq_axis, m_tile=m_tile, interpret=True,
+                                 vmem_cap=64 << 20)
+        m_tile = m_tile or 2048
+        m_tiles = m // m_tile
+        assert pd.operator_residency(s, n, m, m_tile) == want
         assert plan["m_tile"] == m_tile
+        assert (plan["vmem_limit_bytes"] > 0) == (m_tile == 2048)
         assert plan["operator_residency"] == want
         assert plan["operator_cache"] is (want == "vmem")
         # reader 2: the cost model generates once when resident
@@ -294,7 +301,7 @@ class TestEagerDispatchIgnoresCache:
                                      jnp.float32, self.S, 1, m_tile=32,
                                      interpret=True)
         finally:
-            sketch_params.set_pallas_m_tile(512)
+            sketch_params.set_pallas_m_tile(None)
         assert plan["m_tile"] == 32          # arg wins
         assert plan["plan_source"] == "arg"
         assert plan["precision"] == "bf16x3"  # open knob: the setter's
@@ -307,7 +314,7 @@ class TestEagerDispatchIgnoresCache:
                                      jnp.float32, self.S, 1,
                                      interpret=True)
         finally:
-            sketch_params.set_pallas_m_tile(512)
+            sketch_params.set_pallas_m_tile(None)
         assert plan["m_tile"] == 32
         assert plan["plan_source"] == "heuristic"
 

@@ -2,8 +2,9 @@
 feature-map kernels among them) and the rowwise sparse hash kernel —
 compile for a TPU v5e at the benchmark's widths — without a chip: the TPU compiler is installed here and compiles for a
 DESCRIBED topology, so what Mosaic would refuse on the chip (a tile past
-its 16 MiB scoped VMEM, a misaligned slice) is refused in tier-1. Nothing
-runs, so nothing here is a time or a result.
+its 16 MiB scoped VMEM, or past the ``vmem_limit_bytes`` the "hbm"
+contraction passes for its grown row tile; a misaligned slice) is refused
+in tier-1. Nothing runs, so nothing here is a time or a result.
 
 One file, the topology described inside a fixture: only one process may
 hold the TPU library, and every xdist worker imports every test file.
@@ -43,14 +44,35 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.fixture(scope="module")
+def vmem_cap(one_chip):
+    """The scope a grown contraction may ask for on the DESCRIBED chip:
+    the package's own reading (``pd._vmem_cap`` of
+    ``pltpu.get_tpu_info()``) with the described device standing in for
+    the attached one — on this box the default device is a CPU, whose
+    reading is the default scope and grows nothing."""
+    from jax._src.mesh import AbstractDevice
+    from jax.sharding import AbstractMesh, use_abstract_mesh
+
+    (device,) = one_chip.device_set
+    with use_abstract_mesh(AbstractMesh((), (), abstract_device=AbstractDevice(
+            device_kind=device.device_kind, num_cores=device.num_cores))):
+        cap = pd._vmem_cap.__wrapped__()    # the reading itself, uncached
+    assert pd._VMEM_BUDGET_BYTES < cap < 128 << 20, cap
+    return cap
+
+
 def _executable(call, one_chip, shape, s_dim, seq_axis, precision, *operands,
-                **statics):
+                vmem_cap=None, **statics):
     """Lower and compile ``call`` on the operand the dispatch would hand
-    it (the tile :func:`effective_plan` resolves); returns the plan and
+    it (the tile :func:`effective_plan` resolves, under the described
+    chip's ``vmem_cap`` where given; the call works its k step and its
+    limit out of that tile by the plan's own rule); returns the plan and
     the compiled executable."""
     plan = pd.effective_plan(randgen.Normal(), shape, jnp.float32, s_dim,
                              seq_axis, precision=precision, interpret=True,
-                             m_tile=statics.pop("m_tile", None))
+                             m_tile=statics.pop("m_tile", None),
+                             vmem_cap=vmem_cap)
     assert plan["kernel"], plan
 
     def arg(shape, dtype):
@@ -72,26 +94,69 @@ def _compile(*args, **statics):
     return plan, compiled.as_text().count(KERNEL)
 
 
-@pytest.mark.parametrize("precision", ["bf16x3", "f32", "bf16gen2", "bf16"])
-def test_cell_shape_rowwise_hbm(one_chip, precision):
-    """65536 × 8192 → 1024: a generation call and a contraction call."""
-    plan, kernels = _compile(pd._fused_call, one_chip, (ROWS, N), 1024, 1,
-                             precision, ((), jnp.float32))
-    assert plan["operator_residency"] == "hbm" and plan["m_tile"] == 512
-    assert kernels == 2
+def _scoped_in(compiled) -> int:
+    """The most scoped VMEM a Mosaic call of the compiled executable
+    holds (its ``scoped_memory_configs`` entry): what Mosaic took of the
+    scope it was given, in bytes."""
+    found = re.findall(r"scoped_memory_configs.{0,60}?size\D{1,6}(\d+)",
+                       compiled.as_text())
+    return max(map(int, found), default=0)
+
+
+def _grown(plan, compiled, vmem_cap, k_cols=2 * BLOCK_COLS):
+    """The cells' plan since PR 49: the 2048-row tile at the k step of
+    the 512-row one, and a limit on the contraction call that is past
+    the default scope, at least what Mosaic took (it compiled) and at
+    most the cap."""
+    assert (plan["operator_residency"], plan["m_tile"], plan["k_cols"]) == (
+        "hbm", 2048, k_cols)
+    assert (pd._VMEM_BUDGET_BYTES < _scoped_in(compiled)
+            <= plan["vmem_limit_bytes"] <= vmem_cap)
+    assert compiled.as_text().count(KERNEL) == 2
 
 
 @pytest.mark.parametrize("precision", ["bf16x3", "f32", "bf16gen2", "bf16"])
-def test_cell_shape_columnwise_hbm(one_chip, precision):
+def test_cell_shape_rowwise_hbm(one_chip, vmem_cap, precision):
+    """65536 × 8192 → 1024: a generation call and a contraction call,
+    the latter on 2048 rows a tile under the limit its plan passes."""
+    plan, compiled = _executable(pd._fused_call, one_chip, (ROWS, N), 1024, 1,
+                                 precision, ((), jnp.float32),
+                                 vmem_cap=vmem_cap)
+    if precision == "f32":
+        # the one regime that keeps 512 rows rowwise (slower at 2048 on
+        # the chip, PR 49): the parent's program, inside the default scope
+        assert (plan["m_tile"], plan["vmem_limit_bytes"]) == (512, 0)
+        assert 0 < _scoped_in(compiled) <= pd._VMEM_BUDGET_BYTES
+        return
+    _grown(plan, compiled, vmem_cap)
+
+
+def test_cell_shape_without_tpu_info_is_the_old_plan(one_chip):
+    """Planned where ``pltpu.get_tpu_info()`` has no TPU to describe (the
+    default device here is a CPU) the cell keeps the 512-row tile and
+    passes no limit: the parent's program."""
+    plan, compiled = _executable(pd._fused_call, one_chip, (ROWS, N), 1024, 1,
+                                 "bf16x3", ((), jnp.float32))
+    assert (plan["m_tile"], plan["k_cols"], plan["vmem_limit_bytes"]) == (
+        512, 2 * BLOCK_COLS, 0)
+    assert 0 < _scoped_in(compiled) <= pd._VMEM_BUDGET_BYTES
+    assert compiled.as_text().count(KERNEL) == 2
+
+
+@pytest.mark.parametrize("precision", ["bf16x3", "f32", "bf16gen2", "bf16"])
+def test_cell_shape_columnwise_hbm(one_chip, vmem_cap, precision):
     """The jlt_apply_cw cell, 8192 × 65536 → 1024 × 65536: the same
-    generation call and the columnwise contraction call, the scale folded
-    into the planes — no pass over the 256 MiB result outside them, and
-    no temporary but the planes (2 × 16 MiB at "bf16x3")."""
+    generation call and the columnwise contraction call (2048 columns a
+    tile; "f32", whose plane tile is the split left operand, at its one
+    block a step), the scale folded into the planes — no pass over the
+    256 MiB result outside them, and no temporary but the planes
+    (2 × 16 MiB at "bf16x3")."""
     plan, compiled = _executable(pd._fused_call_cw, one_chip, (N, ROWS), 1024,
-                                 0, precision, ((), jnp.float32))
-    assert plan["operator_residency"] == "hbm" and plan["m_tile"] == 512
+                                 0, precision, ((), jnp.float32),
+                                 vmem_cap=vmem_cap)
+    _grown(plan, compiled, vmem_cap,
+           BLOCK_COLS if precision == "f32" else 2 * BLOCK_COLS)
     text = compiled.as_text()
-    assert text.count(KERNEL) == 2
     assert not re.search(r"f32\[1024,65536\]\S* multiply\(", text)
     memory = compiled.memory_analysis()
     assert memory.output_size_in_bytes == 1024 * ROWS * 4
@@ -126,12 +191,39 @@ def test_other_widths_rowwise_hbm(one_chip, s_dim, m_tile):
     assert plan["operator_residency"] == "hbm" and kernels == 2
 
 
-def test_cell_shape_rft_cos_hbm(one_chip):
-    plan, kernels = _compile(
+@pytest.mark.parametrize("call,seq_axis,s_dim,precision,want", [
+    # the expression at its tightest: the plane tile the split left
+    # operand, 20 B an entry — planned 31.5 MiB, Mosaic's least 30.05
+    ("_fused_call_cw", 0, 2048, "f32", (1024, 128)),
+    ("_fused_call_cw", 0, 1536, "f32", (2048, 128)),
+    ("_fused_call_cw", 0, 2048, "bf16x3", (1024, 256)),
+    ("_fused_call", 1, 2048, "bf16x3", (1024, 256)),
+    ("_fused_call", 1, 512, "bf16x3", (2048, 512)),
+    ("_fused_call", 1, 1536, "bf16gen2", (2048, 256))])
+def test_other_widths_grown_hbm(one_chip, vmem_cap, call, seq_axis, s_dim,
+                                precision, want):
+    """Sketch sizes around the headline one at the tile the described
+    chip's cap admits, the k step that of the plan inside the default
+    scope: each compiles under the limit its plan passes."""
+    shape = (ROWS, N) if seq_axis else (N, ROWS)
+    plan, compiled = _executable(getattr(pd, call), one_chip, shape, s_dim,
+                                 seq_axis, precision, ((), jnp.float32),
+                                 vmem_cap=vmem_cap)
+    assert (plan["m_tile"], plan["k_cols"]) == want
+    assert plan["operator_residency"] == "hbm"
+    assert (pd._VMEM_BUDGET_BYTES < _scoped_in(compiled)
+            <= plan["vmem_limit_bytes"] <= vmem_cap)
+    assert compiled.as_text().count(KERNEL) == 2
+
+
+def test_cell_shape_rft_cos_hbm(one_chip, vmem_cap):
+    """A dense feature map past 512 inputs accumulates over k steps and
+    takes the grown tile: the cos finishes 2048 × 1024 in 32 slabs."""
+    plan, compiled = _executable(
         pd._fused_call_cos, one_chip, (ROWS, N), 1024, 1, "bf16x3",
         ((1, 1024), jnp.float32), ((1, 1024), jnp.float32),
-        inscale=0.5, outscale=0.25)
-    assert plan["operator_residency"] == "hbm" and kernels == 2
+        vmem_cap=vmem_cap, inscale=0.5, outscale=0.25)
+    _grown(plan, compiled, vmem_cap)
 
 
 def _feature_plan():
