@@ -575,6 +575,12 @@ class FJLT(SketchTransform):
             attrs["slabs"] = dft_slabs(self._N, split, tile, columns, rowwise)
             tables = _dft_tables_on((attrs["slabs"],) + split[1:],
                                     next(iter(A.devices())))
+        else:
+            # the Pallas pass leaves all the columns to the sampled factor,
+            # the XLA walk a tile of them
+            width = columns if kernel == "pallas_blocks" else min(tile, columns)
+            attrs["sample_chunk"] = _fut.sample_outer_chunk(
+                factors[0], width, self._S, A.dtype.itemsize)
         with _trace.span("sketch.dispatch", attrs):
             out = _mix_program()(key_data, A, *tables, **statics)
         _MIXED.inc_always(attrs["elements"], family=self.sketch_type,

@@ -263,17 +263,23 @@ def wht_blocks(X: jnp.ndarray, block: int, bf16_split: bool = False):
     return X
 
 
-#: Bytes of gathered rows :func:`sample_outer` holds at a time (on a v5e,
-#: 2²⁰ × 1024 by 4096 samples: 64 MiB 5.3 ms, 256 MiB 8.3).
+#: Bytes of gathered rows :func:`sample_outer` holds at a time. On a v5e,
+#: 2²⁰ × 1024 by 4096 samples, device ms of the gather and its sum an apply:
+#: 4.5 at 16, 32 and 64 MiB, 7.5 at 128 (PR 39 read 5.3 and, at 256 MiB, 8.3
+#: by the host's clock) where the compiler gathers 128 rows a step (whole
+#: index tiles: every power of two of samples × 64 rows); 256 rows a step
+#: (PR 50, :func:`_sample_chunk`) 2.95 / 3.10 / 3.04 at 16 / 32 / 64 MiB — by
+#: the padded samples, 11.2 ns a row at each — and 5.4 at 128: to 64 MiB the
+#: compiler keeps a chunk's gathered rows on the chip (memory space ``S(1)``
+#: of the compiled text; the sum reads them at 7 TB/s), past it in HBM.
 _SAMPLE_CHUNK_BYTES = 1 << 26
 
 
-def _sampled_in_chunks(rows, idx, sample_bytes: int, w: int, each: int = 0):
-    """``rows(idx)`` (s, w), the samples taken ``chunk`` at a time so that
-    what ``rows`` gathers for them (``sample_bytes`` a sample, ``each`` rows)
-    stays about ``_SAMPLE_CHUNK_BYTES`` (:func:`_sample_chunk`)."""
+def _sampled_in_chunks(rows, idx, chunk: int, w: int):
+    """``rows(idx)`` (s, w), the samples taken ``chunk`` at a time
+    (:func:`_sample_chunk`: what ``rows`` gathers for them stays about
+    ``_SAMPLE_CHUNK_BYTES``), the last chunk filled up with sample 0."""
     s = idx.shape[0]
-    chunk = _sample_chunk(sample_bytes, each)
     if chunk >= s:
         return rows(idx)
     pad = -s % chunk
@@ -287,7 +293,12 @@ def sample_outer(Y: jnp.ndarray, idx: jnp.ndarray, block: int) -> jnp.ndarray:
     sampled rows only. Row p·block + r of the full transform is
     Σ_q (−1)^popcount(p & q) · Y[q·block + r], so a sample costs a gather
     of a rows and their signed sum. The gathered rows are held ``chunk``
-    samples at a time (≤ ``_SAMPLE_CHUNK_BYTES``)."""
+    samples at a time (:func:`sample_outer_chunk`). The order of a sample's
+    sum is the compiler's: on a v5e its ``reduce`` adds the a rows in runs
+    whose number follows the chunk's shape (a = 64 × 1024 columns: 2 runs of
+    32 at 256 samples a chunk, 16 of 4 at 264, 4 of 16 at 280, one of 64 at
+    296), so another chunk is another last bit — the sum written out term by
+    term keeps one order for 0.23 ms an apply and 1 s of tracing (PR 50)."""
     n, w = Y.shape
     a = n // block
     if a == 1:
@@ -303,7 +314,17 @@ def sample_outer(Y: jnp.ndarray, idx: jnp.ndarray, block: int) -> jnp.ndarray:
         at = (q * block + (ix & (block - 1))[None, :]).reshape(-1)
         return jnp.sum(sign[:, :, None] * Y[at].reshape(a, -1, w), axis=0)
 
-    return _sampled_in_chunks(rows, idx, a * w * Y.dtype.itemsize, w)
+    chunk = sample_outer_chunk(a, w, idx.shape[0], Y.dtype.itemsize)
+    return _sampled_in_chunks(rows, idx, chunk, w)
+
+
+def sample_outer_chunk(a: int, w: int, s: int, itemsize: int = 4) -> int:
+    """Samples, of ``s``, whose rows :func:`sample_outer` gathers at a time
+    from a Y of ``a`` blocks and ``w`` columns: :func:`_sample_chunk` of the
+    a rows a sample gathers (2²⁰ = 64 × 16384 rows × 1024 float32: 264, where
+    the byte rule alone says 256 = sixteen whole index tiles), all of them
+    where one block holds the axis or one chunk the samples."""
+    return s if a == 1 else min(s, _sample_chunk(a * w * itemsize, a))
 
 
 # ---------------------------------------------------------------------------
@@ -400,17 +421,16 @@ def _gathers_fast(count: int) -> bool:
     return -count % _GATHER_INDEX_TILE > 256
 
 
-def _sample_chunk(sample_bytes: int, each: int = 0) -> int:
-    """Samples :func:`_sampled_in_chunks` takes at a time: what fits
-    ``_SAMPLE_CHUNK_BYTES`` at ``sample_bytes`` a sample — for a caller that
-    says how many rows it gathers a sample (``each``), the next multiple of
-    8 past that (at most 64 samples past) whose rows the compiler gathers
-    fast (:func:`_gathers_fast`; 10⁶ × 1024 on ρ = 50: 168 samples of 100
-    rows, 69 MB, not 163)."""
-    chunk = max(8, _SAMPLE_CHUNK_BYTES // sample_bytes)
-    if not each:
-        return chunk
-    chunk = _pad_to(chunk)                  # whole tiles of samples
+def _sample_chunk(sample_bytes: int, each: int) -> int:
+    """Samples :func:`_sampled_in_chunks` takes at a time, ``each`` rows
+    gathered a sample: what fits ``_SAMPLE_CHUNK_BYTES`` at ``sample_bytes``
+    a sample, then the next multiple of 8 past that (at most 64 samples
+    past) whose rows the compiler gathers fast (:func:`_gathers_fast`;
+    10⁶ × 1024 on ρ = 50: 168 samples of 100 rows, 69 MB, not 163; 2²⁰ × 1024
+    on blocks of 16384: 264 samples of 64 rows, not 256). Where ``each`` is a
+    multiple of 128 every such count fills whole tiles and the byte rule's
+    chunk stands."""
+    chunk = _pad_to(max(8, _SAMPLE_CHUNK_BYTES // sample_bytes))
     return next((c for c in range(chunk, chunk + 64, 8)
                  if _gathers_fast(c * each)), chunk)
 
@@ -615,8 +635,8 @@ def sample_outer_dft(Z: jnp.ndarray, idx: jnp.ndarray, n: int,
         return jnp.sum(weight[:, :, None] * Z[at].reshape(2 * r, -1, w),
                        axis=0)
 
-    return _sampled_in_chunks(rows, idx, 2 * r * w * Z.dtype.itemsize, w,
-                              each=2 * r)
+    chunk = _sample_chunk(2 * r * w * Z.dtype.itemsize, 2 * r)
+    return _sampled_in_chunks(rows, idx, chunk, w)
 
 
 class FUT:

@@ -35,12 +35,12 @@ from libskylark_tpu.sketch.fut import _hadamard_np
 GROUP = 128                 # rows the MXU factor mixes: the MXU's side
 _SLAB = 32                  # rows a butterfly step holds of each group
 _LANES = 128
-#: rows × columns a grid step holds. The scoped VMEM asked for is the in
-#: and out tiles double-buffered (4 × 16 MiB at 16384 × 256) plus room for
-#: the per-group temporaries; a v5e core has 128 MiB. On a v5e (PR 39, ms a
-#: pass over 2²⁰ × 1024, the gather of the factor above the block beside it):
-#: 16384 × 128 21.1 + 5.3, 8192 × 256 15.8 + 9.9, 16384 × 256 16.9 + 5.3,
-#: 4096 × 512 14.2 + ≈ 20; a kernel that only copies takes 13.8.
+#: rows × columns a grid step holds. The scoped VMEM asked for is the in and
+#: out tiles double-buffered (4 × 16 MiB at 16384 × 256) plus the per-group
+#: temporaries; a v5e core has 128 MiB. On a v5e (PR 39, host ms a pass over
+#: 2²⁰ × 1024 + the gather behind it, then 128 rows a step): 16384 × 128 21.1 +
+#: 5.3, 8192 × 256 15.8 + 9.9, 16384 × 256 16.9 + 5.3 (traced 15.3 + 4.3; PR 50,
+#: 256 rows a step: 15.3 + 2.9), 4096 × 512 14.2 + ≈ 20; a copy alone 13.8.
 BLOCK_ROWS = 16384
 TILE_COLS = 256
 _VMEM_SLACK_BYTES = 16 * 1024 * 1024

@@ -193,9 +193,12 @@ SPANS: Dict[str, Tuple[str, str]] = {
     # "xla_dft" a columnwise operand's whole free axis, else fjlt.dft_tile
     # of the axis; for the operator), slabs ("xla_dft" only: fjlt.dft_slabs,
     # the ρ of the R slabs of the sampled digit a pass takes — R ÷ slabs
-    # passes an apply or a tile; for the operator), elements (= axis ×
-    # columns mixed, which mix_rate.apply reads: the operand's, no pad
-    # counted) and sampled (= s × columns kept)
+    # passes an apply or a tile; for the operator), sample_chunk (fut="wht"
+    # only, since PR 50: fut.sample_outer_chunk, the samples whose rows the
+    # sampled outer factor gathers at a time — all s_dim where one block
+    # holds the axis or one chunk the samples; for the operator), elements
+    # (= axis × columns mixed, which mix_rate.apply reads: the operand's, no
+    # pad counted) and sampled (= s × columns kept)
     # a fused dense apply notes its plan on sketch.apply
     # (pallas_dense._plan): path="pallas", m_tile, s_tile, precision,
     # plan_source, operator_residency and, since PR 49, k_cols (the

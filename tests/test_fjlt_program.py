@@ -12,7 +12,9 @@ sketch/pallas_wht.py), on the CPU:
   promises it (n, s even powers of two, lattice data), on this backend's
   routes and on the v5e's (the block kernel interpreted, the bfloat16
   three-way split);
-- the sampled last Kronecker factor against the full transform then gather;
+- the sampled last Kronecker factor against the full transform then gather,
+  and its chunk of samples: a multiple of 8 whose rows fill no whole index
+  tiles where the window holds one, the same samples whatever the chunk;
 - the block kernel, interpreted, against its XLA twin;
 - one program, no recompile, the span's attributes and the counter.
 """
@@ -130,6 +132,53 @@ def test_sampled_last_factor_against_full_transform_then_gather(
     monkeypatch.setattr(fut, "_SAMPLE_CHUNK_BYTES", 1 << 12)
     chunked = fut.sample_outer(fut.wht_blocks(X, block), idx, block)
     assert np.array_equal(np.asarray(chunked), np.asarray(got))
+
+
+@pytest.mark.parametrize("w", [128, 256, 1024])
+@pytest.mark.parametrize("a", [2, 8, 64, 128])
+def test_the_sample_chunk_steps_off_whole_index_tiles(a, w):
+    """The chunk is the byte rule's, up to the next multiple of 8 (at most 64
+    samples past) whose ``chunk · a`` gathered rows the v5e compiler takes 256
+    a step — at the cell (a = 64, w = 1024) 264 samples, not 256 = sixteen
+    whole index tiles. Past a = 64 every multiple of 8 samples gathers whole
+    tiles (a · 8 = 1024 · k) and the byte rule's chunk stands."""
+    by_bytes = fut._SAMPLE_CHUNK_BYTES // (a * w * 4)
+    chunk = fut._sample_chunk(a * w * 4, a)
+    assert chunk % 8 == 0 and 0 <= chunk - by_bytes < 64
+    window = range(by_bytes, by_bytes + 64, 8)
+    fast = [c for c in window if fut._gathers_fast(c * a)]
+    assert not fut._gathers_fast(by_bytes * a)          # whole tiles, all twelve
+    assert bool(fast) == (a < 128)
+    assert chunk == (fast[0] if fast else by_bytes)
+    assert fut.sample_outer_chunk(a, w, 1 << 20) == chunk
+    assert fut.sample_outer_chunk(a, w, 100) == 100     # one chunk holds them
+    assert fut.sample_outer_chunk(1, w, 100) == 100     # one block: no factor
+    if (a, w) == (64, 1024):
+        assert chunk == 264
+
+
+@pytest.mark.parametrize("s", [263, 264, 265, 4096])
+def test_sampled_last_factor_chunked_is_the_unchunked_to_the_bit(
+        s, monkeypatch):
+    """A chunk only partitions the samples (the last one padded with sample 0,
+    whose rows are gathered and dropped): the cell's a = 64 rows a sample,
+    264 samples a chunk, against all samples at once — to the bit on this
+    backend, whose ``reduce`` adds a sample's rows in one order whatever the
+    chunk (the v5e's follows the chunk's shape: ``PERF.md`` §6, PR 50)."""
+    n, block, w = 1 << 12, 64, 16
+    a = n // block
+    Y = fut.wht_blocks(_operand(n, w, 4), block)
+    idx = jnp.asarray(np.random.default_rng(s).integers(0, n, s), jnp.int32)
+    monkeypatch.setattr(fut, "_SAMPLE_CHUNK_BYTES", 256 * a * w * 4)
+    assert fut._sample_chunk(a * w * 4, a) == 264
+    chunked = jax.jit(lambda Y, idx: fut.sample_outer(Y, idx, block))(Y, idx)
+    monkeypatch.setattr(fut, "_SAMPLE_CHUNK_BYTES", 1 << 40)
+    assert fut._sample_chunk(a * w * 4, a) > s
+    whole = jax.jit(lambda Y, idx: fut.sample_outer(Y, idx, block))(Y, idx)
+    assert chunked.shape == (s, w)
+    assert np.array_equal(np.asarray(chunked), np.asarray(whole))
+    assert _rel(chunked, fut.wht(Y.reshape(a, block, w), axis=0)
+                .reshape(n, w)[idx]) < 2e-6
 
 
 @pytest.mark.parametrize("block,factors", [
@@ -453,9 +502,33 @@ def test_span_attributes_and_the_counter():
     assert dispatch.attrs == {
         "path": "fut", "family": "FJLT", "fut": "wht", "kernel": "xla_f32",
         "factors": (2, 128, 128), "tile": fjlt.MIX_TILE, "elements": n * m,
-        "sampled": s * m}
+        "sampled": s * m, "sample_chunk": s}        # one chunk holds them all
     assert spans["stream.key"].attrs["cached"] in (True, False)
     assert fjlt._MIXED.value(family="FJLT", kernel="xla_f32") == counted + n * m
+
+
+@pytest.mark.parametrize("route,n,m,a,width", [
+    ("xla", 1 << 15, 130, 2, fjlt.MIX_TILE),        # the walk's tile of columns
+    ("pallas_blocks", 1 << 12, 256, 4, 256)])       # the kernel route: all of them
+def test_the_span_says_the_samples_a_chunk(route, n, m, a, width, monkeypatch,
+                                           request):
+    """Every ``wht`` dispatch carries ``sample_chunk``: the samples whose rows
+    the sampled factor gathers at a time."""
+    if route == "pallas_blocks":
+        request.getfixturevalue("interpreted")       # blocks of 1024 rows
+    s = 512
+    monkeypatch.setattr(fut, "_SAMPLE_CHUNK_BYTES", 40 * a * width * 4)
+    chunk = fut.sample_outer_chunk(a, width, s)
+    assert 40 <= chunk < s and fut._gathers_fast(chunk * a)
+    T = sk.FJLT(n, s, Context(31), fut="wht")
+    A = _operand(n, m, 5)
+    out, spans = _spans_of(
+        lambda: T.apply(A, sk.COLUMNWISE).block_until_ready())
+    dispatch = [sp for sp in spans if sp.name == "sketch.dispatch"]
+    assert [sp.attrs["sample_chunk"] for sp in dispatch] == [chunk]
+    assert dispatch[0].attrs["factors"][0] == a
+    D, idx = reference.streams(31, 0, n, s)
+    assert _rel(out, reference.apply_cols(A, D, idx)) < 5e-6
 
 
 # -- the sample indices' draw ----------------------------------------------

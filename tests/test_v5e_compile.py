@@ -369,33 +369,93 @@ def test_sparse_rows_kernel_other_shapes(one_chip, s_dim, rows, plan):
 FJLT_ROWS, FJLT_COLS, FJLT_S = 1 << 20, 1024, 4096
 
 
-def test_cell_shape_fjlt_mix_sample(one_chip):
-    """FJLT(2²⁰, 4096, wht) columnwise of 1,048,576 × 1024 as the one
-    program: the block-mix kernel (16384 × 256 tiles, 80 MiB of the core's
-    VMEM asked for) and the gather of the sampled rows — no workspace beyond
-    the one block-mixed matrix (4 GiB) and a few gathered chunks, no copy of
-    it in another layout."""
-    from libskylark_tpu.sketch import fjlt, pallas_wht
-
-    block, tile = pallas_wht.plan((FJLT_ROWS, FJLT_COLS), jnp.float32,
-                                  interpret=True)
-    assert (block, tile) == (16384, 256)
+def _fjlt_wht_program(one_chip, rows, cols, kernel, block, tile):
+    """``fjlt_mix_sample`` on the Hadamard route, columnwise of rows × cols,
+    compiled for the described chip."""
+    from libskylark_tpu.sketch import fjlt
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     program = jax.jit(functools.partial(
-        fjlt.fjlt_mix_sample, s_dim=FJLT_S, rowwise=False,
-        kernel="pallas_blocks", block=block, tile=tile))
-    compiled = program.lower(arg((2,), jnp.uint32),
-                             arg((FJLT_ROWS, FJLT_COLS), jnp.float32)).compile()
+        fjlt.fjlt_mix_sample, s_dim=FJLT_S, rowwise=False, kernel=kernel,
+        block=block, tile=tile))
+    return program.lower(arg((2,), jnp.uint32),
+                         arg((rows, cols), jnp.float32)).compile()
+
+
+def _row_gathers(text):
+    """``(rows, columns, rows a step)`` of every gather fusion of whole rows
+    in a compiled module's text: the step is the compiler's own choice, 256
+    or — where the index count fills whole 1024-index tiles, or ends within
+    ≈ 256 of a tile's end — 128 with a quarter of the buffers."""
+    found = re.findall(r"f32\[(\d+),(\d+)\]\S* fusion\(.*/gather\".*"
+                       r"\"integer_config\":\{\"integer\":\"(\d+)\"", text)
+    return [tuple(map(int, g)) for g in found]
+
+
+def test_cell_shape_fjlt_mix_sample(one_chip):
+    """FJLT(2²⁰, 4096, wht) columnwise of 1,048,576 × 1024 as the one
+    program: the block-mix kernel (16384 × 256 tiles, 80 MiB of the core's
+    VMEM asked for) and the gather of the sampled rows — no workspace beyond
+    the one block-mixed matrix (4 GiB) and a few gathered chunks, no copy of
+    it in another layout. The one gather fusion takes the rows of 264 samples
+    a chunk, 64 a sample (``fut._sample_chunk``: 256 samples would be sixteen
+    whole index tiles), 256 rows a step, and the gathered rows stay inside
+    the fusion: no array of them among the temporaries."""
+    from libskylark_tpu.sketch import fut, pallas_wht
+
+    block, tile = pallas_wht.plan((FJLT_ROWS, FJLT_COLS), jnp.float32,
+                                  interpret=True)
+    assert (block, tile) == (16384, 256)
+    a = FJLT_ROWS // block
+    chunk = fut.sample_outer_chunk(a, FJLT_COLS, FJLT_S)
+    assert (a, chunk) == (64, 264)
+    compiled = _fjlt_wht_program(one_chip, FJLT_ROWS, FJLT_COLS,
+                                 "pallas_blocks", block, tile)
     text = compiled.as_text()
     assert text.count(KERNEL) == 1
+    assert _row_gathers(text) == [(chunk * a, FJLT_COLS, 256)]
     operand = FJLT_ROWS * FJLT_COLS * 4
     assert not re.search(r"f32\[64,16384,1024\]\S* copy\(", text)
     memory = compiled.memory_analysis()
     assert memory.output_size_in_bytes == FJLT_S * FJLT_COLS * 4
     assert operand <= memory.temp_size_in_bytes < operand + (160 << 20)
+
+
+@pytest.mark.parametrize("rows,cols,kernel,tile,widths", [
+    (1 << 19, 1024, "pallas_blocks", 256, (1024,)),   # 32 rows a sample
+    (1 << 18, 2048, "pallas_blocks", 256, (2048,)),   # 16
+    (1 << 20, 128, "xla_bf16x3", 128, (128,)),        # the XLA walk's tile
+    (1 << 20, 1000, "xla_bf16x3", 128, (128, 104)),   # and its ragged rest
+    (1 << 21, 512, "pallas_blocks", 256, (512,))])    # 128: no chunk is fast
+def test_fjlt_sample_gather_other_shapes(one_chip, rows, cols, kernel, tile,
+                                         widths):
+    """Other heights and widths the Hadamard route serves: the sampled
+    factor's gather takes ``fut.sample_outer_chunk`` samples of a = rows ÷
+    16384 rows each, and the compiler gathers them 256 rows a step wherever
+    ``fut._gathers_fast`` says so — every shape whose chunk could step off
+    whole index tiles. At a = 128 (2²¹ rows) every multiple of 8 samples
+    gathers whole tiles: the byte rule's chunk stands, 128 rows a step as
+    before (a masked 129th row block would step off: compiled, not built)."""
+    from libskylark_tpu.sketch import fut
+
+    a = rows // 16384
+    compiled = _fjlt_wht_program(one_chip, rows, cols, kernel, 16384, tile)
+    want = []
+    for w in widths:
+        chunk = fut.sample_outer_chunk(a, w, FJLT_S)
+        assert chunk % 8 == 0 and chunk < FJLT_S
+        fast = fut._gathers_fast(chunk * a)
+        assert fast == (a < 128)
+        want.append((chunk * a, w, 256 if fast else 128))
+    assert _row_gathers(compiled.as_text()) == want
+    # no array of a chunk's gathered rows (64 MiB) among the temporaries:
+    # the kernel route holds the block-mixed matrix, the XLA walk three
+    # (rows × tile) arrays of its stages
+    held = rows * cols * 4 if kernel == "pallas_blocks" else 3 * rows * tile * 4
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert held <= temporaries < held + (32 << 20)
 
 
 @pytest.mark.parametrize("rows,cols,plan", [
