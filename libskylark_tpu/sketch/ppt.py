@@ -4,8 +4,28 @@ TPU-native analog of ref: sketch/PPT_data.hpp:24-120, sketch/PPT_Elemental.hpp:1
 Approximates the polynomial kernel (γ·xᵀy + c)^q: q independent CountSketches
 of x, each lifted by the homogeneity term √c·e_{h_i}·s_i, FFT'd, multiplied
 elementwise across q, and inverse-FFT'd. The reference loops columns with
-per-column FFTW plans; here the whole (S × m) batch goes through jnp.fft along
-the feature axis in one shot.
+per-column FFTW plans.
+
+An apply of a float32 operand on one device is ONE compiled program
+(``sketch.tensorsketch_features``, :func:`tensorsketch_features`): a pure
+function of the allocation's key words and the operand that walks the
+examples in blocks of rows, so that its temporaries are a few (block × S)
+arrays whatever the operand's height. In it no CountSketch is ever formed:
+the spectrum of a CountSketch is a dense product,
+FFT(C_k x)[κ] = Σ_j s_j x_j ω^{h_j κ} = x · (C_k F), and at N ≪ S the N × S
+operator C_k F (row j is s_j times row h_j of the DFT matrix, the half
+spectrum of S/2 + 1 bins as S real columns) is generated once a program
+from the buckets and signs and contracted with a block on the MXU — no
+scatter, no forward transform. The q spectra are multiplied in float32
+complex arithmetic and the one inverse transform, half spectrum → S reals
+along the feature axis, is a two-stage blocked DFT on the MXU (S = N1·N2,
+the twiddles applied between the stages). Every
+product carries float32 on both sides (``highest``).
+
+Everything else — another dtype, an operand on several devices, an S with
+no such split — keeps the eager chain (:meth:`PPT._sketch_columns`: q
+``cwt.apply``, ``jnp.fft`` over whole arrays), which stays the tests'
+oracle.
 
 Sub-allocations: child(i) = i-th internal CWT; sub-streams 100/101 = the
 homogeneity hash (idx, val) (ref: PPT_data.hpp:100-106).
@@ -13,14 +33,220 @@ homogeneity hash (idx, val) (ref: PPT_data.hpp:100-106).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 
 from libskylark_tpu.base import randgen
+from libskylark_tpu.sketch import fut as _fut
 from libskylark_tpu.sketch.hash import CWT
-from libskylark_tpu.sketch.transform import SketchTransform, register
+from libskylark_tpu.sketch.rft import _ProgramAllocation
+from libskylark_tpu.sketch.transform import (_REGISTRY, SketchTransform,
+                                             register)
+from libskylark_tpu.telemetry import metrics as _metrics
+from libskylark_tpu.telemetry import trace as _trace
+
+_ROWS = _metrics.counter(
+    "sketch.tensorsketch_rows",
+    "examples featurized by TensorSketch applies, by family and route")
+
+#: Longest factor of the inverse transform's split (a dense factor on the
+#: MXU), and with it the longest S the program serves (2¹⁶: the phases
+#: h·κ < S²/2 stay inside int32).
+_FACTOR_MAX = 256
+#: Entries (rows × S) of a block of the walk: 2²⁶ is 4096 examples at
+#: S = 16384, 256 MiB a stage array.
+_BLOCK_ENTRIES = 1 << 26
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+@functools.lru_cache(maxsize=256)
+def split(s: int):
+    """The split ``(N1, N2)`` of the feature axis, S = N1·N2, that the
+    inverse transform's two stages contract — N1 even (the half spectrum
+    fills N1/2 rows of N2 bins), both at most 256, the smallest N1 + N2
+    (the stages cost 4·S·(N1 + N2) multiply-adds an example), the shorter
+    first — or None where S has none (an odd S, a prime factor past 256,
+    S > 2¹⁶)."""
+    best = None
+    for n1 in range(2, min(s, _FACTOR_MAX) + 1, 2):
+        if s % n1 or s // n1 > _FACTOR_MAX:
+            continue
+        cost = (n1 + s // n1, n1)
+        if best is None or cost < best[0]:
+            best = (cost, (n1, s // n1))
+    return best and best[1]
+
+
+def block_rows(m: int, s: int) -> int:
+    """Examples a step of the walk takes: what :data:`_BLOCK_ENTRIES`
+    holds at S features, a multiple of 8, at most the m there are."""
+    return min(m, max(8, _BLOCK_ENTRIES // s // 8 * 8))
+
+
+def _grade(x, grade: str):
+    """An MXU product's operand at the program's grade: as it is
+    (``"float32"``), or rounded to one bfloat16 part (``"bf16"``: the
+    regime of sketch/params.py a benchmark's control runs)."""
+    if grade == "float32":
+        return x
+    return jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+
+
+def spectral_operator(h, v, s: int):
+    """Rows of the half-spectrum DFT operator: for buckets ``h`` (r,) int32
+    and signed scales ``v`` (r,) float32 the (r, S) real array whose row j is
+    the spectrum of v_j·e_{h_j} — columns κ < S/2 its real parts
+    v_j·cos(2πh_jκ/S), columns S/2 + κ its imaginary parts −v_j·sin(2πh_jκ/S),
+    and in column S/2 (bin 0 has no imaginary part) the Nyquist bin
+    v_j·(−1)^{h_j}. Phases reduced in int32, each entry right to an ulp or
+    two (``fut._cis_turns``)."""
+    half = s // 2
+    kappa = jnp.arange(half, dtype=jnp.int32)[None, :]
+    cos, sin = _fut._cis_turns((h[:, None] * kappa) % s, s)
+    nyquist = (1 - 2 * (h & 1)).astype(jnp.float32)[:, None]
+    return v[:, None] * jnp.concatenate(
+        [cos, jnp.where(kappa == 0, nyquist, -sin)], axis=1)
+
+
+def _inverse_factors(n1: int, n2: int):
+    """The inverse transform's factors, generated in the program: ``M1``
+    (2·N1, N1), stage one over the high digit κ1 < N1/2 of κ = N2·κ1 + κ2 —
+    rows (re | im, t1), columns (re | im, κ1), e^{+2πiκ1t1/N1} as a real
+    matrix, times 2/S (each bin but 0 and S/2 stands for its conjugate
+    too); the twiddles between the stages ``Tc``, ``Ts`` (N1, N2), cos and
+    sin of 2πκ2t1/S; and ``M2`` (2·N2, N2), stage two over the low digit —
+    rows (re | im, κ2), the real part of e^{2πiκ2t2/N2}·(re + i·im)."""
+    s = n1 * n2
+    t1 = jnp.arange(n1, dtype=jnp.int32)[:, None]
+    k1 = jnp.arange(n1 // 2, dtype=jnp.int32)[None, :]
+    cos, sin = _fut._cis_turns((t1 * k1) % n1, n1)
+    M1 = jnp.float32(2.0 / s) * jnp.concatenate(
+        [jnp.concatenate([cos, -sin], axis=1),
+         jnp.concatenate([sin, cos], axis=1)], axis=0)
+    k2 = jnp.arange(n2, dtype=jnp.int32)
+    Tc, Ts = _fut._cis_turns((t1 * k2[None, :]) % s, s)
+    cos, sin = _fut._cis_turns((k2[:, None] * k2[None, :]) % n2, n2)
+    return M1, Tc, Ts, jnp.concatenate([cos, -sin], axis=0)
+
+
+def _block_features(Xb, operators, factors, grade: str):
+    """(B, S) features of the examples ``Xb`` (B, N): the q spectra
+    x·(C_k F) + the homogeneity term's, their product, the inverse
+    transform — t = t1 + N1·t2: stage one makes t1 of κ1, the twiddle
+    couples (κ2, t1), stage two makes t2 of κ2, and the two digits of t
+    change places at the end."""
+    W, bias = operators
+    M1, Tc, Ts, M2 = factors
+    n1, n2 = Tc.shape
+    B, s = Xb.shape[0], n1 * n2
+    first = jnp.arange(s // 2, dtype=jnp.int32)[None, :] == 0
+    x = _grade(Xb, grade)
+    re = im = None
+    for k in range(W.shape[0]):
+        F = jnp.dot(x, _grade(W[k], grade), precision=_HIGHEST) + bias[k]
+        fre, fim = F[:, :s // 2], F[:, s // 2:]
+        if re is None:
+            re, im = fre, fim
+            continue
+        # bins 0 and S/2 are real and share column 0: re holds bin 0's
+        # product, im the Nyquist bin's
+        both = im * fim
+        re, im = (re * fre - jnp.where(first, 0.0, both),
+                  jnp.where(first, both, re * fim + im * fre))
+    nyquist = im[:, 0] * jnp.float32(1.0 / s)
+    U = jnp.concatenate([jnp.where(first, 0.5 * re, re),
+                         jnp.where(first, 0.0, im)], axis=1)
+    # the stages see a block as (B/8, ·, 8, ·): eight examples next to the
+    # lanes, as the (8, 128) tiles of a row-major (B, S) array lie in memory,
+    # so that neither end of the transform is laid out again
+    lo = 8 if B % 8 == 0 else 1
+    X = U.reshape(B // lo, lo, n1, n2).transpose(0, 2, 1, 3)  # (h, κ1, l, κ2)
+    R = jnp.einsum("uk,hklc->hulc", _grade(M1, grade), _grade(X, grade),
+                   precision=_HIGHEST)
+    Rre, Rim = R[:, :n1], R[:, n1:]                          # (h, t1, l, κ2)
+    # the Nyquist bin's (−1)^t = (−1)^{t1} joins bin κ2 = 0, whose factor
+    # in stage two is 1 for every t2
+    sign = (1 - 2 * (jnp.arange(n1, dtype=jnp.int32) & 1)).astype(jnp.float32)
+    low = jnp.arange(n2, dtype=jnp.int32)[None, None, None, :] == 0
+    tc, ts = Tc[None, :, None, :], Ts[None, :, None, :]
+    ny = nyquist.reshape(B // lo, 1, lo, 1) * sign[None, :, None, None]
+    V = jnp.concatenate([Rre * tc - Rim * ts + jnp.where(low, ny, 0.0),
+                         Rre * ts + Rim * tc], axis=3)       # (h, t1, l, re|im κ2)
+    Z = jnp.dot(_grade(V.reshape(-1, 2 * n2), grade), _grade(M2, grade),
+                precision=_HIGHEST).reshape(B // lo, n1, lo, n2)
+    return Z.transpose(0, 2, 3, 1).reshape(B, s)            # (h, l, t2, t1)
+
+
+def tensorsketch_features(key_data, A, *, spec, rowwise: bool,
+                          row_block: int = 0, grade: str = "float32"):
+    """One TensorSketch apply as a pure function of the transform's raw key
+    data ((2,) uint32) and a float32 operand: for every example x,
+    ``z = IFFT(∏_{k<q} FFT(√γ·C_k x + √c·s'_k·e_{h'_k}))``, real — what
+    :meth:`PPT._sketch_columns` computes. ``spec`` = (sketch_type, N, S,
+    sorted hyper-parameters) rebuilds the transform around the traced key, so
+    the buckets, the signs and the homogeneity hash are the transform's own
+    methods on the same sub-streams: the same bits as the eager chain's.
+
+    The examples are walked ``row_block`` at a time (0: :func:`block_rows`'s
+    own), the last block drawn back so that it ends with the operand — its
+    first rows are computed twice, to the same bits, and no row is padded —
+    and each block's features go into their rows of the result in place. A
+    columnwise operand (N, m) is walked by column blocks, a block transposed
+    on its way in and out."""
+    sketch_type, n, s, extra = spec
+    T = _REGISTRY[sketch_type]._from_parts(
+        n, s, _ProgramAllocation(key_data), dict(extra))
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    hidx, hval = T._hash_idx(), T._hash_val(jnp.float32)
+    sg, sc = jnp.float32(math.sqrt(T._gamma)), jnp.float32(math.sqrt(T._c))
+    operators = (
+        jnp.stack([spectral_operator(cwt.bucket_indices(),
+                                     sg * cwt.values(jnp.float32), s)
+                   for cwt in T._cwts]),
+        spectral_operator(hidx, sc * hval, s))
+    factors = _inverse_factors(*split(s))
+
+    def features(Xb):
+        # row-major, said outright: left to itself the v5e compiler carries
+        # the result through the walk column-major (stage two's product
+        # leaves the examples next to the lanes) and transposes all of it
+        # at the end — 3.9 GB of temporaries more at 60,000 × 16384
+        return with_layout_constraint(
+            _block_features(Xb, operators, factors, grade),
+            Layout(major_to_minor=(0, 1)))
+
+    m = A.shape[0] if rowwise else A.shape[1]
+    B = min(m, row_block) if row_block else block_rows(m, s)
+    if B == m:
+        return features(A) if rowwise else features(A.T).T
+
+    def step(i, Z):
+        lo = jnp.minimum(i * B, m - B)
+        if rowwise:
+            Xb = jax.lax.dynamic_slice(A, (lo, 0), (B, n))
+            return jax.lax.dynamic_update_slice(Z, features(Xb), (lo, 0))
+        Xb = jax.lax.dynamic_slice(A, (0, lo), (n, B)).T
+        return jax.lax.dynamic_update_slice(Z, features(Xb).T, (0, lo))
+
+    return jax.lax.fori_loop(
+        0, -(-m // B), step,
+        jax.lax.empty((m, s) if rowwise else (s, m), A.dtype))
+
+
+@functools.lru_cache(maxsize=None)
+def _features_program():
+    """The compiled apply, built at the first dense operand so that
+    importing the sketch layer never pulls the engine."""
+    from libskylark_tpu.engine.compiled import compiled
+
+    return compiled(tensorsketch_features, name="sketch.tensorsketch_features",
+                    static_argnames=("spec", "rowwise", "row_block", "grade"))
 
 
 @register
@@ -74,11 +300,73 @@ class PPT(SketchTransform):
             P = FW if P is None else P * FW
         return jnp.real(jnp.fft.ifft(P, axis=0)).astype(dt)
 
+    def features_plan(self, A, rowwise: bool) -> dict:
+        """What an apply does with this operand, as the attributes its
+        ``sketch.dispatch`` span carries: ``route`` ``"program"``
+        (:func:`tensorsketch_features`, with the ``row_block`` of its walk
+        and its ``grade``) or ``"chain"`` with the ``reason`` the eager chain
+        keeps it — another dtype than float32, an S the inverse transform
+        cannot split (:func:`split`), an operand that lies on more than one
+        device (the program's walk would gather it) — and the forms of the
+        CountSketch and of the transform on that route."""
+        from libskylark_tpu.sketch import params as sketch_params
+
+        m = A.shape[0] if rowwise else A.shape[1]
+        reason = None
+        if A.dtype != jnp.float32:
+            reason = f"dtype={A.dtype}"
+        elif split(self._S) is None:
+            reason = f"s={self._S}"
+        elif not isinstance(A, jax.core.Tracer) and len(A.devices()) > 1:
+            reason = f"devices={len(A.devices())}"
+        if reason is not None:
+            return {"route": "chain", "reason": reason, "row_block": m,
+                    "sketch": "segment_sum", "fft": "jnp.fft"}
+        bf16 = sketch_params.get_pallas_precision() == "bf16"
+        return {"route": "program", "row_block": block_rows(m, self._S),
+                "sketch": "spectral_operator", "fft": "mxu_two_stage",
+                "grade": "bf16" if bf16 else "float32"}
+
+    def _features(self, A: jnp.ndarray, rowwise: bool) -> jnp.ndarray:
+        """The dense apply: the one ``sketch.tensorsketch_features`` program
+        (under a caller's trace: part of the caller's program), or the eager
+        chain where :meth:`features_plan` names a reason."""
+        with _trace.span("sketch.plan"):
+            plan = self.features_plan(A, rowwise)
+        m = A.shape[0] if rowwise else A.shape[1]
+        traced = isinstance(A, jax.core.Tracer)
+        # path "features": the feature maps' own (rft.py, frft.py), which the
+        # benchmark's feature_rate reads; ``elements`` the entries transformed
+        attrs = {"path": "features", "family": self.sketch_type,
+                 "q": self._q, "s": self._S, "rows": m,
+                 "features": m * self._S,
+                 "elements": m * self._S * (self._q + 1), **plan}
+        if plan["route"] == "chain":
+            if traced:
+                return self._chain(A, rowwise)
+            with _trace.span("sketch.dispatch", attrs):
+                out = self._chain(A, rowwise)
+        else:
+            statics = dict(
+                spec=(self.sketch_type, self._N, self._S,
+                      tuple(sorted(self._extra_params().items()))),
+                rowwise=rowwise, grade=plan["grade"])
+            key_data = self._alloc.key_data
+            if traced:
+                return tensorsketch_features(key_data, A, **statics)
+            with _trace.span("sketch.dispatch", attrs):
+                out = _features_program()(key_data, A, **statics)
+        _ROWS.inc_always(m, family=self.sketch_type, route=plan["route"])
+        return out
+
+    def _chain(self, A: jnp.ndarray, rowwise: bool) -> jnp.ndarray:
+        return self._sketch_columns(A.T).T if rowwise else self._sketch_columns(A)
+
     def _apply_columnwise(self, A: jnp.ndarray) -> jnp.ndarray:
-        return self._sketch_columns(A)
+        return self._features(A, rowwise=False)
 
     def _apply_rowwise(self, A: jnp.ndarray) -> jnp.ndarray:
-        return self._sketch_columns(A.T).T
+        return self._features(A, rowwise=True)
 
     def _extra_params(self) -> dict[str, Any]:
         return {"q": self._q, "c": self._c, "gamma": self._gamma}

@@ -67,6 +67,12 @@ METRICS: Dict[str, str] = {
     # declined route) — the cross-check of the features that
     # feature_rate.apply reads from its sketch.dispatch spans
     "sketch.fastfood_features": "counter",
+    # the TensorSketch apply (sketch/ppt.py): examples featurized, by
+    # family and route ("program" = the one compiled program of
+    # ppt.tensorsketch_features | "chain" = the eager chain of a declined
+    # operand) — rows × S × (q + 1) of them are the elements that
+    # conv_rate.apply reads from its sketch.dispatch spans
+    "sketch.tensorsketch_rows": "counter",
     # the compiled FJLT/wht apply (sketch/fjlt.py): operand entries sign-
     # and Hadamard-mixed (transform axis × free axis), by family and kernel
     # ("pallas_blocks" | "xla_bf16x3" | "xla_f32") — the cross-check of

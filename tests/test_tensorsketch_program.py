@@ -1,0 +1,330 @@
+"""The TensorSketch apply as one compiled program (sketch/ppt.py,
+``sketch.tensorsketch_features``): against the plain reference of
+cellbench/references/tensorsketch_features.py and against the eager chain
+(``PPT._sketch_columns``) at small sizes, seeded, on the CPU.
+
+- the program, rowwise and columnwise, whole and walked in row blocks that
+  do not divide the rows, against both;
+- the tensor-power statement of the definition (the CountSketch of x'^{⊗q});
+- the lower-precision controls fail the tolerance the sound program holds;
+- each broken variant of the map — a sketch dropped, a sketch shared, a
+  truncated spectrum, the homogeneity term missing — fails the cell's check;
+- what the program declines keeps the chain and says so in the span;
+- the span's attributes and the counter; the streams' bits.
+
+Tolerances, each with its reason: ``REL`` 2e-6 of the result's largest
+entry — every product carries float32 on both sides, the operator's entries
+are right to an ulp or two, and three spectra's product and two stages of
+at most 256 terms each add up a few 1e-7 (read: 1.0e-7…4.4e-7 on these
+shapes); a control one bfloat16 part wide reads 1e-3…5e-3, three orders
+above.
+"""
+
+import json
+import pathlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from cellbench.drivers import tensorsketch_apply as driver
+from cellbench.references import tensorsketch_features as reference
+from libskylark_tpu import sketch as sk
+from libskylark_tpu.base.context import Context
+from libskylark_tpu.ml import kernels
+from libskylark_tpu.sketch import ppt
+
+REL = 2e-6
+SHAPES = [(20, 64, 2), (33, 256, 3), (784, 1024, 3)]
+CONFIG = json.loads((pathlib.Path(__file__).resolve().parent.parent / "cellbench"
+                     / "configs" / "ppt_mnist_d784_s16384_q3.json").read_text())
+
+
+def _map(d, s, q, seed=7, c=1.3):
+    return sk.PPT(d, s, Context(seed), q=q, c=c, gamma=0.7 / d)
+
+
+def _examples(rows, d, seed=0):
+    return jnp.asarray(np.random.default_rng(seed).standard_normal((rows, d)),
+                       jnp.float32)
+
+
+def _spec(T):
+    return (T.sketch_type, T.input_dim, T.sketch_dim,
+            tuple(sorted(T._extra_params().items())))
+
+
+def _rel(got, want):
+    want = np.asarray(want)
+    return float(np.abs(np.asarray(got) - want).max() / np.abs(want).max())
+
+
+# -- the program against the reference and the chain --------------------------
+
+
+@pytest.mark.parametrize("d,s,q", SHAPES)
+@pytest.mark.parametrize("rows,row_block", [(37, 0), (37, 16), (50, 24), (8, 8)])
+def test_rowwise_program_matches_reference_and_chain(d, s, q, rows, row_block):
+    """Whole and in row blocks that do not divide the rows (the last block is
+    drawn back to end with the operand): the same features either way."""
+    T, X = _map(d, s, q), _examples(rows, d)
+    out = ppt.tensorsketch_features(T._alloc.key_data, X, spec=_spec(T),
+                                    rowwise=True, row_block=row_block)
+    assert out.shape == (rows, s) and out.dtype == jnp.float32
+    parts = reference.streams(7, 0, d, s, q)
+    assert _rel(out, reference.features(X, parts, 0.7 / d, 1.3)) < REL
+    assert _rel(out, T._sketch_columns(X.T).T) < REL
+
+
+@pytest.mark.parametrize("d,s,q", SHAPES)
+def test_apply_is_the_program_both_ways(d, s, q):
+    """``apply`` rowwise and columnwise (column blocks, a block transposed on
+    its way in and out) gives what the direct call gives."""
+    T, X = _map(d, s, q), _examples(21, d)
+    want = reference.features(X, reference.streams(7, 0, d, s, q), 0.7 / d, 1.3)
+    assert _rel(T.apply(X, sk.ROWWISE), want) < REL
+    assert _rel(T.apply(X.T, sk.COLUMNWISE).T, want) < REL
+    walked = ppt.tensorsketch_features(T._alloc.key_data, X.T, spec=_spec(T),
+                                       rowwise=False, row_block=8)
+    assert walked.shape == (s, 21) and _rel(walked.T, want) < REL
+
+
+def test_kernel_create_rft_reaches_the_program():
+    T = kernels.Polynomial(33, q=3, c=1.0, gamma=1 / 33).create_rft(256, Context(5))
+    X = _examples(19, 33)
+    program = ppt._features_program()
+    ran = program.stats.executions
+    out = T.apply(X, sk.ROWWISE)
+    assert program.stats.executions == ran + 1
+    assert _rel(out, reference.features(X, reference.streams(5, 0, 33, 256, 3),
+                                        1 / 33, 1.0)) < REL
+    # under a caller's trace the same function is part of the caller's program
+    inside = jax.jit(lambda A: T.apply(A, sk.ROWWISE))(X)
+    assert program.stats.executions == ran + 1
+    assert _rel(inside, out) < REL
+
+
+def test_tensor_power_statement():
+    """The FFT form is the CountSketch of the explicit tensor power x'^{⊗3},
+    x' = (√γ·x, √c): bucket Σ h_k(j_k) mod S, sign ∏ s_k(j_k) — 7³ terms a
+    row in float64 on the host."""
+    d, s, q = 6, 64, 3
+    T, X = _map(d, s, q), _examples(5, d)
+    parts = reference.streams(7, 0, d, s, q)
+    power = reference.tensor_power_sketch(X, parts, 0.7 / d, 1.3)
+    assert _rel(reference.features(X, parts, 0.7 / d, 1.3), power) < REL
+    assert _rel(T.apply(X, sk.ROWWISE), power) < REL
+
+
+@pytest.mark.parametrize("d,s,q", SHAPES)
+@pytest.mark.parametrize("control", ["program_bf16", "reference_bf16"])
+def test_lower_precision_controls_fail_the_tolerance(d, s, q, control):
+    T, X = _map(d, s, q), _examples(37, d)
+    parts = reference.streams(7, 0, d, s, q)
+    want = reference.features(X, parts, 0.7 / d, 1.3)
+    if control == "program_bf16":
+        low = ppt.tensorsketch_features(T._alloc.key_data, X, spec=_spec(T),
+                                        rowwise=True, grade="bf16")
+    else:
+        low = reference.features(X, parts, 0.7 / d, 1.3, "bf16")
+    assert _rel(low, want) > 100 * REL
+
+
+def test_bf16_regime_reaches_the_program():
+    from libskylark_tpu.sketch import params
+
+    T, X = _map(33, 256, 3), _examples(16, 33)
+    sound = T.apply(X, sk.ROWWISE)
+    before = params.get_pallas_precision()
+    params.set_pallas_precision("bf16")
+    try:
+        assert T.features_plan(X, True)["grade"] == "bf16"
+        low = T.apply(X, sk.ROWWISE)
+    finally:
+        params.set_pallas_precision(before)
+    assert _rel(low, sound) > 100 * REL
+
+
+# -- the cell's check, on sound and on broken maps ----------------------------
+
+
+def _state(rows=301, d=33, s=256, seed=2 ** 31 + 77):
+    cfg = dict(CONFIG, n=d, s=s, gamma=1.0 / d, rows_per_panel=rows, check_rows=64)
+    return driver.setup(cfg, {}, seed)
+
+
+def _checked(state, out):
+    """The numbers of the check that pass their limits — the cell's own, but
+    for ``norm_dev``, statistical and restated for S = 256 (one row's squared
+    norm has sd √(11.5/S)·k: 0.21·k here, 2.6e-2·k at the cell's 16384)."""
+    limits = dict(CONFIG["limits"], norm_dev=8 * CONFIG["limits"]["norm_dev"])
+    got = driver.check(state, [(0, out)])
+    return {name: value for name, value in got.items() if value > limits[name]}
+
+
+def _variant(state, name):
+    """The features of a broken map, from the reference on altered parts."""
+    cfg = state.config
+    parts = reference.streams(state.context_seed, 0, cfg["n"], cfg["s"], cfg["q"])
+    X = state.panels[0]
+    if name == "sound":
+        return reference.features(X, parts, cfg["gamma"], cfg["c"])
+    if name == "dropped_sketch":                    # q = 2 in q = 3's place
+        parts = {k: (v if k == "s" else v[:2]) for k, v in parts.items()}
+    elif name == "shared_sketch":                   # h_1 = h_0, s_1 = s_0
+        parts = dict(parts, h=parts["h"].at[1].set(parts["h"][0]),
+                     v=parts["v"].at[1].set(parts["v"][0]))
+    elif name == "no_homogeneity":                  # the map of ⟨x, y⟩^q
+        parts = dict(parts, hv=jnp.zeros_like(parts["hv"]))
+    elif name == "truncated_spectrum":              # the upper half of the bins
+        Z = np.fft.rfft(np.asarray(reference.features(X, parts, cfg["gamma"],
+                                                      cfg["c"])), axis=1)
+        Z[:, cfg["s"] // 4:] = 0.0
+        return jnp.asarray(np.fft.irfft(Z, n=cfg["s"], axis=1), jnp.float32)
+    return reference.features(X, parts, cfg["gamma"], cfg["c"])
+
+
+def test_check_passes_the_program():
+    state = _state()
+    assert _checked(state, driver.step(state, 0)) == {}
+    assert _checked(state, _variant(state, "sound")) == {}
+    assert driver.describe(state)["route"] == "program"
+
+
+@pytest.mark.parametrize("broken", ["dropped_sketch", "shared_sketch",
+                                    "truncated_spectrum", "no_homogeneity"])
+def test_check_fails_a_broken_map(broken):
+    state = _state()
+    failed = _checked(state, _variant(state, broken))
+    assert "rel_max" in failed, failed
+
+
+@pytest.mark.parametrize("stream,wrong", [("h", "half_range"), ("h", "all_even"),
+                                          ("h", "past_the_end"), ("v", "all_plus")])
+def test_check_fails_broken_streams(stream, wrong):
+    parts = reference.streams(11, 0, 784, 16384, 3)
+    sound = reference.law_z_scores(parts, 64)
+    assert max(sound.values()) < 6.0
+    if wrong == "half_range":
+        parts = dict(parts, h=parts["h"].at[2].set(parts["h"][2] // 2))
+    elif wrong == "all_even":
+        parts = dict(parts, h=parts["h"].at[0].set(parts["h"][0] // 2 * 2))
+    elif wrong == "past_the_end":
+        parts = dict(parts, h=parts["h"].at[1, :8].set(16384))
+    else:
+        parts = dict(parts, v=jnp.ones_like(parts["v"]))
+    got = reference.law_z_scores(parts, 64)
+    assert got["bucket_chi2_z" if stream == "h" else "sign_mean_z"] > 6.0
+
+
+# -- what the program declines ------------------------------------------------
+
+
+def _dispatch_of(call):
+    from libskylark_tpu import telemetry
+    from libskylark_tpu.telemetry import metrics, trace
+
+    before = metrics._ENABLED
+    trace.clear_finished()
+    telemetry.set_enabled(True)
+    try:
+        out = call()
+        jax.block_until_ready(out)
+        spans = list(trace.finished_spans())
+    finally:
+        metrics._ENABLED = before
+        trace.clear_finished()
+    return out, spans
+
+
+def _sharded(X):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("rows",))
+    return jax.device_put(X, NamedSharding(mesh, PartitionSpec("rows")))
+
+
+@pytest.mark.parametrize("s,operand,reason", [
+    (256, lambda: _examples(16, 33).astype(jnp.bfloat16), "dtype=bfloat16"),
+    (514, lambda: _examples(16, 33), "s=514"),              # 2 · 257
+    (255, lambda: _examples(16, 33), "s=255"),              # odd
+    (256, lambda: _sharded(_examples(16, 33)), "devices=2"),
+])
+def test_declined_operands_keep_the_chain_and_say_why(s, operand, reason):
+    T, X = _map(33, s, 3), operand()
+    program = ppt._features_program()
+    ran = program.stats.executions
+    out, spans = _dispatch_of(lambda: T.apply(X, sk.ROWWISE))
+    assert program.stats.executions == ran
+    dispatch = next(sp for sp in spans if sp.name == "sketch.dispatch")
+    assert dispatch.attrs["route"] == "chain" and dispatch.attrs["reason"] == reason
+    assert dispatch.attrs["sketch"] == "segment_sum"
+    assert dispatch.attrs["fft"] == "jnp.fft"
+    assert np.array_equal(np.asarray(out), np.asarray(T._sketch_columns(X.T).T))
+
+
+@pytest.mark.parametrize("s,want", [
+    (16384, (128, 128)), (1024, (32, 32)), (256, (16, 16)), (64, (8, 8)),
+    (512, (16, 32)), (24, (4, 6)), (2, (2, 1)), (65536, (256, 256)),
+    (514, None), (255, None), (131072, None)])
+def test_split(s, want):
+    assert ppt.split(s) == want
+
+
+def test_span_attributes_and_the_counter():
+    from libskylark_tpu.telemetry.names import METRICS
+
+    assert METRICS["sketch.tensorsketch_rows"] == "counter"
+    rows, d, s, q = 24, 33, 256, 3
+    T, X = _map(d, s, q), _examples(rows, d)
+    counted = ppt._ROWS.value(family="PPT", route="program")
+    _, spans = _dispatch_of(lambda: T.apply(X, sk.ROWWISE))
+    by_name = {sp.name: sp for sp in spans}
+    dispatch, apply = by_name["sketch.dispatch"], by_name["sketch.apply"]
+    assert dispatch.parent_id == apply.span_id
+    assert by_name["sketch.plan"].parent_id == apply.span_id
+    assert by_name["stream.key"].attrs["cached"] in (True, False)
+    assert dispatch.attrs == {
+        "path": "features", "family": "PPT", "q": q, "s": s, "rows": rows,
+        "row_block": rows, "sketch": "spectral_operator", "fft": "mxu_two_stage",
+        "route": "program", "grade": "float32", "features": rows * s,
+        "elements": rows * s * (q + 1)}
+    assert ppt._ROWS.value(family="PPT", route="program") == counted + rows
+
+
+def test_block_rows():
+    assert ppt.block_rows(60000, 16384) == 4096
+    assert ppt.block_rows(100, 16384) == 100
+    assert ppt.block_rows(10 ** 6, 64) == 10 ** 6
+
+
+# -- the streams' bits --------------------------------------------------------
+
+
+@pytest.mark.parametrize("seed,counter", [(3, 0), (2 ** 31 - 1, 0), (41, 2)])
+def test_stream_bits_are_the_references(seed, counter):
+    """Child k's sub-streams 0 and 1 and the parent's 100 and 101, to the
+    bit, in the transform and inside the program's rebuilt one."""
+    ctx = Context(seed)
+    for _ in range(counter):
+        ctx.allocate()
+    T = sk.PPT(33, 256, ctx, q=3)
+    parts = reference.streams(seed, counter, 33, 256, 3)
+    inside = ppt.PPT._from_parts(33, 256, ppt._ProgramAllocation(
+        T._alloc.key_data), {"q": 3})
+    for which in (T, inside):
+        mine = driver.transform_streams(which)
+        for name in ("h", "v", "hh", "hv"):
+            assert np.array_equal(np.asarray(mine[name]), np.asarray(parts[name]))
+
+
+def test_one_program_a_shape():
+    T1, T2 = _map(33, 256, 3, seed=1), _map(33, 256, 3, seed=2)
+    X = _examples(12, 33)
+    program = ppt._features_program()
+    T1.apply(X, sk.ROWWISE)
+    compiles = program.stats.compiles
+    T2.apply(X, sk.ROWWISE)
+    T1.apply(X + 1.0, sk.ROWWISE)
+    assert program.stats.compiles == compiles

@@ -655,3 +655,56 @@ def test_fastfood_block_kernels_other_shapes(one_chip):
             arg((2 * cols - 5, 2 * n), jnp.float32), arg((2,), jnp.int32),
             tile=tile, outscale=0.01, passes=passes).compile().as_text()
         assert text.count(KERNEL) == 1
+
+
+TS_ROWS, TS_N, TS_S, TS_Q = 60000, 784, 16384, 3
+
+
+def test_cell_shape_tensorsketch_features(one_chip):
+    """``Polynomial(784, 3, 1, 1/784).create_rft(16384, ctx)`` rowwise of
+    60,000 × 784 as the one program: the three half-spectrum operators
+    (3 × 784 × 16384) generated once, then a walk of 15 blocks of 4096
+    examples — three dense products, the spectra's product, the two stages
+    of the inverse transform — whose features go into their rows of an
+    uninitialised result in place: the whole result is never copied, it
+    keeps its row-major layout through the loop (a constraint on each
+    block's features: left alone, the compiler carries it column-major and
+    transposes 3.9 GB at the end), and beside operand and result the
+    program holds 1.63 GB, not a whole stage's 3.9."""
+    from libskylark_tpu.base.context import Context
+    from libskylark_tpu.ml import kernels
+    from libskylark_tpu.sketch import ppt
+
+    T = kernels.Polynomial(TS_N, q=TS_Q, c=1.0, gamma=1.0 / TS_N).create_rft(
+        TS_S, Context(1))
+    assert ppt.split(TS_S) == (128, 128)
+    assert ppt.block_rows(TS_ROWS, TS_S) == 4096
+    spec = (T.sketch_type, TS_N, TS_S, tuple(sorted(T._extra_params().items())))
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    program = jax.jit(functools.partial(
+        ppt.tensorsketch_features, spec=spec, rowwise=True))
+    compiled = program.lower(arg((2,), jnp.uint32),
+                             arg((TS_ROWS, TS_N), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert KERNEL not in text                       # XLA alone
+    called = [i for i in _instructions(text) if "fused_computation" not in i[0]]
+    # the result: an uninitialised buffer, written block by block in place
+    whole = [i for i in called if i[2] == TS_ROWS * TS_S
+             and i[3] not in ("parameter", "get-tuple-element", "bitcast", "while")]
+    assert sorted(i[3] for i in whole) == ["custom-call", "dynamic-update-slice"], whole
+    assert all("{1,0" in i[4] or "]" == i[4][-1] for i in whole), whole
+    # a block: three spectral products, the spectra's product, stage one,
+    # stage two (the twiddles fused into its operand) and two re-layings
+    # (κ1 brought next to the lanes before stage one, the two digits of t
+    # changing places after stage two): eight passes over block-sized
+    # arrays, and no ninth
+    passes = [i for i in called if i[2] >= 4096 * TS_S and i[2] < TS_ROWS * TS_S
+              and i[3] in ("fusion", "copy", "convolution", "transpose")]
+    assert sum(1 for i in passes if "convolution" in i[1]) >= 4, passes
+    assert len(passes) <= 8, passes
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == TS_ROWS * TS_S * 4
+    assert memory.temp_size_in_bytes < 1.8e9
