@@ -22,6 +22,18 @@ along the feature axis, is a two-stage blocked DFT on the MXU (S = N1·N2,
 the twiddles applied between the stages). Every
 product carries float32 on both sides (``highest``).
 
+What the v5e compiler makes of a block (``tests/test_v5e_compile.py`` holds
+it): the block's examples sliced out of the operand once; the q products of
+that slice; the spectra's product in two fusions, each storing its
+half of stage one's operand in place (the first hands the pair product on to
+the second); stage one, which reads that operand as the tiles of a (B, S)
+array lie; the twiddles; stage two; and one fusion that turns the two digits
+of t and stores the block into its rows of the result — nine passes over
+block-sized arrays at q = 3, every array written once and read once but the
+pair product, and no pass that only lays an array out again. That holds where
+rows and row block are multiples of 8 and N1 of 128; other shapes turn a
+block in a pass of its own and copy it in.
+
 Everything else — another dtype, an operand on several devices, an S with
 no such split — keeps the eager chain (:meth:`PPT._sketch_columns`: q
 ``cwt.apply``, ``jnp.fft`` over whole arrays), which stays the tests'
@@ -135,35 +147,46 @@ def _inverse_factors(n1: int, n2: int):
 
 
 def _block_features(Xb, operators, factors, grade: str):
-    """(B, S) features of the examples ``Xb`` (B, N): the q spectra
-    x·(C_k F) + the homogeneity term's, their product, the inverse
-    transform — t = t1 + N1·t2: stage one makes t1 of κ1, the twiddle
-    couples (κ2, t1), stage two makes t2 of κ2, and the two digits of t
-    change places at the end."""
+    """The features of the examples ``Xb`` (B, N) as (B/8, N2, 8, N1) —
+    (h, t2, l, t1): example 8·h + l, feature t = t1 + N1·t2, which is how
+    the (8, 128) tiles of a row-major (B, S) array lie in memory where N1 is
+    a lane's 128; (B, N2, 1, N1) where B is no multiple of 8. The q spectra
+    x·(C_k F) + the homogeneity term's, their product, the inverse transform:
+    stage one makes t1 of κ1, the twiddle couples (κ2, t1), stage two makes
+    t2 of κ2 and leaves the two digits of t to the caller's store."""
     W, bias = operators
     M1, Tc, Ts, M2 = factors
     n1, n2 = Tc.shape
     B, s = Xb.shape[0], n1 * n2
     first = jnp.arange(s // 2, dtype=jnp.int32)[None, :] == 0
-    x = _grade(Xb, grade)
-    re = im = None
+    # the block's examples as an array of their own, made once: a product
+    # that reads them through the walk's dynamic slice of the whole operand
+    # is a tenth slower on a v5e (4.16 against 3.77 ms), three times a block
+    x = jax.lax.optimization_barrier(_grade(Xb, grade))
+    re = im = nyquist = None
     for k in range(W.shape[0]):
         F = jnp.dot(x, _grade(W[k], grade), precision=_HIGHEST) + bias[k]
         fre, fim = F[:, :s // 2], F[:, s // 2:]
         if re is None:
-            re, im = fre, fim
+            re, im, nyquist = fre, fim, fim[:, 0]
             continue
         # bins 0 and S/2 are real and share column 0: re holds bin 0's
-        # product, im the Nyquist bin's
+        # product, im the Nyquist bin's — which the twiddles' pass takes as a
+        # vector, so it is multiplied up on its own (B,) column, to the same
+        # bits: a second reader of im would cost the product a whole array
+        nyquist = nyquist * fim[:, 0]
         both = im * fim
         re, im = (re * fre - jnp.where(first, 0.0, both),
                   jnp.where(first, both, re * fim + im * fre))
-    nyquist = im[:, 0] * jnp.float32(1.0 / s)
-    U = jnp.concatenate([jnp.where(first, 0.5 * re, re),
-                         jnp.where(first, 0.0, im)], axis=1)
+    # stage one's operand (re | im, κ1, κ2), each half stored where it lies:
+    # a concatenate is a pass of its own on a v5e (two pads and a maximum,
+    # which no producer is fused into), an update in place is its producer's
+    U = jax.lax.dynamic_update_slice(
+        jax.lax.empty((B, s), jnp.float32), jnp.where(first, 0.5 * re, re),
+        (0, 0))
+    U = jax.lax.dynamic_update_slice(U, jnp.where(first, 0.0, im), (0, s // 2))
     # the stages see a block as (B/8, ·, 8, ·): eight examples next to the
-    # lanes, as the (8, 128) tiles of a row-major (B, S) array lie in memory,
-    # so that neither end of the transform is laid out again
+    # lanes, as the tiles of (B, S) lie, so that stage one reads U as it is
     lo = 8 if B % 8 == 0 else 1
     X = U.reshape(B // lo, lo, n1, n2).transpose(0, 2, 1, 3)  # (h, κ1, l, κ2)
     R = jnp.einsum("uk,hklc->hulc", _grade(M1, grade), _grade(X, grade),
@@ -174,12 +197,12 @@ def _block_features(Xb, operators, factors, grade: str):
     sign = (1 - 2 * (jnp.arange(n1, dtype=jnp.int32) & 1)).astype(jnp.float32)
     low = jnp.arange(n2, dtype=jnp.int32)[None, None, None, :] == 0
     tc, ts = Tc[None, :, None, :], Ts[None, :, None, :]
-    ny = nyquist.reshape(B // lo, 1, lo, 1) * sign[None, :, None, None]
+    ny = ((nyquist * jnp.float32(1.0 / s)).reshape(B // lo, 1, lo, 1)
+          * sign[None, :, None, None])
     V = jnp.concatenate([Rre * tc - Rim * ts + jnp.where(low, ny, 0.0),
                          Rre * ts + Rim * tc], axis=3)       # (h, t1, l, re|im κ2)
-    Z = jnp.dot(_grade(V.reshape(-1, 2 * n2), grade), _grade(M2, grade),
-                precision=_HIGHEST).reshape(B // lo, n1, lo, n2)
-    return Z.transpose(0, 2, 3, 1).reshape(B, s)            # (h, l, t2, t1)
+    return jnp.einsum("hulk,kt->htlu", _grade(V, grade), _grade(M2, grade),
+                      precision=_HIGHEST)                    # (h, t2, l, t1)
 
 
 def tensorsketch_features(key_data, A, *, spec, rowwise: bool,
@@ -195,9 +218,14 @@ def tensorsketch_features(key_data, A, *, spec, rowwise: bool,
     The examples are walked ``row_block`` at a time (0: :func:`block_rows`'s
     own), the last block drawn back so that it ends with the operand — its
     first rows are computed twice, to the same bits, and no row is padded —
-    and each block's features go into their rows of the result in place. A
-    columnwise operand (N, m) is walked by column blocks, a block transposed
-    on its way in and out."""
+    and each block's features go into their rows of the result in place.
+    Where rows and block are whole (8, 128) tiles of a result whose split has
+    N1 a multiple of the 128 lanes, the result is carried through the walk as
+    (m/8, N2, 8, N1) — the bytes of the row-major (m, S) — and ONE fusion
+    turns the digits of t and stores the block at its offset on the untiled
+    leading dimension; any other shape turns a block's features first and
+    copies them in. A columnwise operand (N, m) is walked by column blocks, a
+    block transposed on its way in and out."""
     sketch_type, n, s, extra = spec
     T = _REGISTRY[sketch_type]._from_parts(
         n, s, _ProgramAllocation(key_data), dict(extra))
@@ -210,7 +238,11 @@ def tensorsketch_features(key_data, A, *, spec, rowwise: bool,
                                      sg * cwt.values(jnp.float32), s)
                    for cwt in T._cwts]),
         spectral_operator(hidx, sc * hval, s))
-    factors = _inverse_factors(*split(s))
+    n1, n2 = split(s)
+    factors = _inverse_factors(n1, n2)
+
+    turned = functools.partial(_block_features, operators=operators,
+                               factors=factors, grade=grade)
 
     def features(Xb):
         # row-major, said outright: left to itself the v5e compiler carries
@@ -218,25 +250,32 @@ def tensorsketch_features(key_data, A, *, spec, rowwise: bool,
         # leaves the examples next to the lanes) and transposes all of it
         # at the end — 3.9 GB of temporaries more at 60,000 × 16384
         return with_layout_constraint(
-            _block_features(Xb, operators, factors, grade),
+            turned(Xb).transpose(0, 2, 1, 3).reshape(Xb.shape[0], s),
             Layout(major_to_minor=(0, 1)))
 
     m = A.shape[0] if rowwise else A.shape[1]
     B = min(m, row_block) if row_block else block_rows(m, s)
     if B == m:
         return features(A) if rowwise else features(A.T).T
+    tiled = rowwise and m % 8 == 0 and B % 8 == 0 and n1 % 128 == 0
 
     def step(i, Z):
         lo = jnp.minimum(i * B, m - B)
-        if rowwise:
-            Xb = jax.lax.dynamic_slice(A, (lo, 0), (B, n))
+        if not rowwise:
+            Xb = jax.lax.dynamic_slice(A, (0, lo), (n, B)).T
+            return jax.lax.dynamic_update_slice(Z, features(Xb).T, (0, lo))
+        Xb = jax.lax.dynamic_slice(A, (lo, 0), (B, n))
+        if not tiled:
             return jax.lax.dynamic_update_slice(Z, features(Xb), (lo, 0))
-        Xb = jax.lax.dynamic_slice(A, (0, lo), (n, B)).T
-        return jax.lax.dynamic_update_slice(Z, features(Xb).T, (0, lo))
+        return with_layout_constraint(
+            jax.lax.dynamic_update_slice(Z, turned(Xb), (lo // 8, 0, 0, 0)),
+            Layout(major_to_minor=(0, 1, 2, 3)))
 
-    return jax.lax.fori_loop(
+    shape = (m, s) if rowwise else (s, m)
+    Z = jax.lax.fori_loop(
         0, -(-m // B), step,
-        jax.lax.empty((m, s) if rowwise else (s, m), A.dtype))
+        jax.lax.empty((m // 8, n2, 8, n1) if tiled else shape, A.dtype))
+    return Z.transpose(0, 2, 1, 3).reshape(shape) if tiled else Z
 
 
 @functools.lru_cache(maxsize=None)

@@ -68,6 +68,28 @@ def _rel(got, want):
 def test_rowwise_program_matches_reference_and_chain(d, s, q, rows, row_block):
     """Whole and in row blocks that do not divide the rows (the last block is
     drawn back to end with the operand): the same features either way."""
+    _rowwise_against_both(d, s, q, rows, row_block)
+
+
+# what the walk adapts on — rows % 8, block % 8, the split's N1 % 128 — and
+# the degrees and splits beside the cell's: (d, s, q, rows, row_block)
+WALKS = [
+    (20, 16384, 3, 40, 16),   # whole tiles, N1 = 128: the turned store, drawn back
+    (20, 16384, 2, 32, 8),    # the same, the blocks dividing the rows
+    (20, 32768, 2, 24, 16),   # the turned store on a split that is not square
+    (20, 16384, 3, 40, 12),   # rows whole tiles, the block not: the plain store
+    (20, 16384, 3, 37, 16),   # the block whole tiles, the rows not
+    (33, 256, 3, 40, 16),     # whole tiles, N1 = 16: the plain store
+    (33, 256, 3, 40, 12),     # neither a multiple of 8
+    (33, 256, 1, 40, 16),     # q = 1: no product
+    (33, 256, 2, 40, 16),
+    (33, 256, 4, 40, 16),
+    (20, 8192, 3, 24, 16),    # S = 8192 splits 64 · 128
+    (20, 8192, 4, 21, 0),
+]
+
+
+def _rowwise_against_both(d, s, q, rows, row_block):
     T, X = _map(d, s, q), _examples(rows, d)
     out = ppt.tensorsketch_features(T._alloc.key_data, X, spec=_spec(T),
                                     rowwise=True, row_block=row_block)
@@ -75,9 +97,69 @@ def test_rowwise_program_matches_reference_and_chain(d, s, q, rows, row_block):
     parts = reference.streams(7, 0, d, s, q)
     assert _rel(out, reference.features(X, parts, 0.7 / d, 1.3)) < REL
     assert _rel(out, T._sketch_columns(X.T).T) < REL
+    return out
 
 
-@pytest.mark.parametrize("d,s,q", SHAPES)
+@pytest.mark.parametrize("d,s,q,rows,row_block", WALKS)
+def test_every_walk_matches_reference_and_chain(d, s, q, rows, row_block):
+    """Each store the walk chooses from its shapes, each degree, each kind
+    of split: the reference's and the chain's features; and a block drawn
+    back writes the rows it shares with its neighbour to the same bits as
+    the whole operand in one block does."""
+    out = _rowwise_against_both(d, s, q, rows, row_block)
+    if row_block and rows % row_block:
+        T = _map(d, s, q)
+        whole = ppt.tensorsketch_features(T._alloc.key_data, _examples(rows, d),
+                                          spec=_spec(T), rowwise=True)
+        assert _rel(out, whole) < REL / 4
+
+
+@pytest.mark.parametrize("d,s,q,rows,row_block", [WALKS[0], WALKS[5], WALKS[7],
+                                                  WALKS[9], WALKS[10]])
+def test_program_is_the_parents_formula(d, s, q, rows, row_block):
+    """Against the block formula of PR 51 kept here word for word (halves
+    joined by ``concatenate``, the Nyquist bin read off the running product,
+    stage two a plain ``dot``, the digits turned by a transpose): the same
+    products in the same order, so the two agree to the rounding of a
+    differently blocked float32 sum — an eighth of what either is held to
+    against the reference."""
+    T, X = _map(d, s, q), _examples(rows, d)
+    out = ppt.tensorsketch_features(T._alloc.key_data, X, spec=_spec(T),
+                                    rowwise=True, row_block=row_block)
+    hi = jax.lax.Precision.HIGHEST
+    W = jnp.stack([ppt.spectral_operator(
+        c.bucket_indices(), jnp.float32(np.sqrt(0.7 / d)) * c.values(jnp.float32), s)
+        for c in T._cwts])
+    bias = ppt.spectral_operator(T._hash_idx(), jnp.float32(np.sqrt(1.3))
+                                 * T._hash_val(jnp.float32), s)
+    n1, n2 = ppt.split(s)
+    M1, Tc, Ts, M2 = ppt._inverse_factors(n1, n2)
+    first = jnp.arange(s // 2, dtype=jnp.int32)[None, :] == 0
+    re = im = None
+    for k in range(q):
+        F = jnp.dot(X, W[k], precision=hi) + bias[k]
+        fre, fim = F[:, :s // 2], F[:, s // 2:]
+        if re is None:
+            re, im = fre, fim
+            continue
+        both = im * fim
+        re, im = (re * fre - jnp.where(first, 0.0, both),
+                  jnp.where(first, both, re * fim + im * fre))
+    nyquist = im[:, 0] * jnp.float32(1.0 / s)
+    U = jnp.concatenate([jnp.where(first, 0.5 * re, re),
+                         jnp.where(first, 0.0, im)], axis=1)
+    R = jnp.einsum("uk,bkc->buc", M1, U.reshape(rows, n1, n2), precision=hi)
+    Rre, Rim = R[:, :n1], R[:, n1:]
+    sign = (1 - 2 * (jnp.arange(n1, dtype=jnp.int32) & 1)).astype(jnp.float32)
+    low = jnp.arange(n2, dtype=jnp.int32)[None, None, :] == 0
+    ny = nyquist[:, None, None] * sign[None, :, None]
+    V = jnp.concatenate([Rre * Tc[None] - Rim * Ts[None] + jnp.where(low, ny, 0.0),
+                         Rre * Ts[None] + Rim * Tc[None]], axis=2)
+    Z = jnp.dot(V.reshape(-1, 2 * n2), M2, precision=hi).reshape(rows, n1, n2)
+    assert _rel(out, Z.transpose(0, 2, 1).reshape(rows, s)) < REL / 8
+
+
+@pytest.mark.parametrize("d,s,q", SHAPES + [(20, 16384, 3), (20, 8192, 2)])
 def test_apply_is_the_program_both_ways(d, s, q):
     """``apply`` rowwise and columnwise (column blocks, a block transposed on
     its way in and out) gives what the direct call gives."""

@@ -660,17 +660,72 @@ def test_fastfood_block_kernels_other_shapes(one_chip):
 TS_ROWS, TS_N, TS_S, TS_Q = 60000, 784, 16384, 3
 
 
-def test_cell_shape_tensorsketch_features(one_chip):
+def _passes(text, least, below):
+    """``(name, opcode, kind, shapes, operands)`` of every instruction outside the
+    fused computations whose largest array has ``least`` ≤ elements <
+    ``below`` — the arrays of a multi-output fusion's tuple each counted
+    (:func:`_instructions` reads single-array instructions only) — but for
+    the instructions that move nothing: parameters, tuples and their
+    elements, bitcasts, the loop itself. ``shapes`` are ``dtype[dims]{layout``
+    strings."""
+    computation, found = None, []
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            computation = head.group(1)
+            continue
+        op = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\(?\w+\[.*?) ([\w\-]+)\(([^)]*)\)",
+                      line)
+        if not op or computation is None or "fused_computation" in computation:
+            continue
+        if op.group(3) in ("parameter", "get-tuple-element", "bitcast", "while",
+                           "tuple"):
+            continue
+        shapes = re.findall(r"\w+\[[\d,]*\](?:\{[\d,]*)?", op.group(2))
+        largest = max(math.prod(int(d) for d in
+                                re.search(r"\[([\d,]*)\]", sh).group(1).split(",") if d)
+                      for sh in shapes)
+        if least <= largest < below:
+            kind = re.search(r"kind=(\w+)", line)
+            found.append((op.group(1), op.group(3), kind and kind.group(1), shapes,
+                          re.findall(r"%([\w.\-]+)", op.group(4))))
+    return found
+
+
+@pytest.mark.parametrize("rows", [TS_ROWS, TS_ROWS - 4])
+def test_cell_shape_tensorsketch_features(one_chip, rows):
     """``Polynomial(784, 3, 1, 1/784).create_rft(16384, ctx)`` rowwise of
     60,000 × 784 as the one program: the three half-spectrum operators
     (3 × 784 × 16384) generated once, then a walk of 15 blocks of 4096
-    examples — three dense products, the spectra's product, the two stages
-    of the inverse transform — whose features go into their rows of an
-    uninitialised result in place: the whole result is never copied, it
-    keeps its row-major layout through the loop (a constraint on each
-    block's features: left alone, the compiler carries it column-major and
-    transposes 3.9 GB at the end), and beside operand and result the
-    program holds 1.63 GB, not a whole stage's 3.9."""
+    examples whose features go into their rows of an uninitialised result in
+    place. A block is EIGHT passes over block-sized arrays and a ninth that
+    stores it, every one a fusion, no ``copy`` / ``reshape`` / ``transpose``
+    among them and no ``concatenate`` pass:
+
+    1–3. the three spectral products x·(C_k F) (``kOutput``, MXU), each of
+         the block's examples as ONE small fusion slices them out of the
+         operand (read through that dynamic slice a product is a tenth
+         slower on the chip), each at ``highest`` on both sides;
+    4.   the spectra's product, first half: reads the three spectra, stores
+         the real half of stage one's operand in place and hands on the pair
+         product F_0·F_1 (``kLoop``, three outputs);
+    5.   its second half: the imaginary half stored in place beside the real
+         one (``kLoop``, a ``dynamic-update-slice`` root);
+    6.   stage one, reading that operand as it lies (``kOutput``);
+    7.   the twiddles and the Nyquist term (``kLoop``, two outputs);
+    8.   stage two (``kOutput``);
+    9.   the store: ONE ``kLoop`` fusion that turns the two digits of t and
+         writes the block at its offset of the result, carried as
+         (7500, 128, 8, 128) row-major — the bytes of (60000, 16384).
+
+    The whole result is touched by the uninitialised ``custom-call`` and by
+    that fusion alone, keeps its row-major layout through the loop and is
+    never copied; beside operand and result the program holds 1.50 GB.
+
+    Rows that are no whole (8, 128) tiles (59,996) keep the store of PR 51:
+    the same eight passes, a ninth that turns the digits (a fusion around a
+    ``copy``) and a bare ``dynamic-update-slice`` of the (rows, 16384)
+    result."""
     from libskylark_tpu.base.context import Context
     from libskylark_tpu.ml import kernels
     from libskylark_tpu.sketch import ppt
@@ -678,7 +733,7 @@ def test_cell_shape_tensorsketch_features(one_chip):
     T = kernels.Polynomial(TS_N, q=TS_Q, c=1.0, gamma=1.0 / TS_N).create_rft(
         TS_S, Context(1))
     assert ppt.split(TS_S) == (128, 128)
-    assert ppt.block_rows(TS_ROWS, TS_S) == 4096
+    assert ppt.block_rows(rows, TS_S) == 4096
     spec = (T.sketch_type, TS_N, TS_S, tuple(sorted(T._extra_params().items())))
 
     def arg(shape, dtype):
@@ -687,24 +742,31 @@ def test_cell_shape_tensorsketch_features(one_chip):
     program = jax.jit(functools.partial(
         ppt.tensorsketch_features, spec=spec, rowwise=True))
     compiled = program.lower(arg((2,), jnp.uint32),
-                             arg((TS_ROWS, TS_N), jnp.float32)).compile()
+                             arg((rows, TS_N), jnp.float32)).compile()
     text = compiled.as_text()
     assert KERNEL not in text                       # XLA alone
-    called = [i for i in _instructions(text) if "fused_computation" not in i[0]]
+    tiled = rows % 8 == 0
     # the result: an uninitialised buffer, written block by block in place
-    whole = [i for i in called if i[2] == TS_ROWS * TS_S
-             and i[3] not in ("parameter", "get-tuple-element", "bitcast", "while")]
-    assert sorted(i[3] for i in whole) == ["custom-call", "dynamic-update-slice"], whole
-    assert all("{1,0" in i[4] or "]" == i[4][-1] for i in whole), whole
-    # a block: three spectral products, the spectra's product, stage one,
-    # stage two (the twiddles fused into its operand) and two re-layings
-    # (κ1 brought next to the lanes before stage one, the two digits of t
-    # changing places after stage two): eight passes over block-sized
-    # arrays, and no ninth
-    passes = [i for i in called if i[2] >= 4096 * TS_S and i[2] < TS_ROWS * TS_S
-              and i[3] in ("fusion", "copy", "convolution", "transpose")]
-    assert sum(1 for i in passes if "convolution" in i[1]) >= 4, passes
-    assert len(passes) <= 8, passes
+    whole = _passes(text, rows * TS_S, rows * TS_S + 1)
+    assert sorted(i[1] for i in whole) == [
+        "custom-call", "fusion" if tiled else "dynamic-update-slice"], whole
+    carried = "f32[7500,128,8,128]{3,2,1,0" if tiled else f"f32[{rows},{TS_S}]{{1,0"
+    assert all(i[3] == [carried] for i in whole), whole
+    if tiled:
+        (store,) = [i for i in whole if i[1] == "fusion"]
+        assert store[2] == "kLoop" and "dynamic-update-slice" in store[0], store
+    # a block's passes
+    passes = _passes(text, 4096 * TS_S, rows * TS_S)
+    assert all(i[1] == "fusion" for i in passes), passes
+    products = [i for i in passes if i[2] == "kOutput"]
+    assert len(products) == TS_Q + 2, passes
+    sliced = {i[4][0] for i in products if i[3] == [f"f32[4096,{TS_S}]{{1,0"]}
+    assert len(sliced) == 1 and "dynamic-slice" in sliced.pop(), products
+    assert text.count("convolution(") == TS_Q + 2 == text.count(
+        "operand_precision={highest,highest}")
+    loops = [i for i in passes if i[2] == "kLoop"]
+    assert sorted(len(i[3]) for i in loops) == [1] * (not tiled) + [1, 2, 3], passes
+    assert len(passes) == (8 if tiled else 9), passes
     memory = compiled.memory_analysis()
-    assert memory.output_size_in_bytes == TS_ROWS * TS_S * 4
+    assert memory.output_size_in_bytes == -(-rows // 8) * 8 * TS_S * 4   # whole tiles
     assert memory.temp_size_in_bytes < 1.8e9
