@@ -232,8 +232,11 @@ class DenseTransform(OperatorCache, SketchTransform):
             path = "xla_full"
             S = self.s_panel(0, self._N, A.dtype)
         note_apply(path=path)
+        # the transpose is a dispatch of its own: ahead of the handover
+        # span (telemetry/names.py HANDOVER), which holds the one matmul
+        left, right = (A, S.T) if rowwise else (S, A)
         with _trace.span("sketch.dispatch", {"padded": False}):
-            return (A @ S.T) if rowwise else (S @ A)
+            return left @ right
 
     def _try_pallas(self, A, which: str):
         return try_pallas_apply(

@@ -989,16 +989,19 @@ def rowwise_apply(
         return None
     kd = _key_data(key)
     with _trace.span("sketch.dispatch") as sp:
+        # the handover (telemetry/names.py HANDOVER): the body starts
+        # with the call of the one executable — the block-key table, the
+        # padding (where the operand is ragged), the kernel(s) and the
+        # scale, folded into the planes under the "hbm" residency, a pass
+        # over the result otherwise; the attribute waits until the
+        # runtime has the work
+        out = _fused_call(A, kd, scale, s_dim=s_dim,
+                          dist_kind=_DIST_KINDS[type(dist)],
+                          m_tile=plan.m_tile, s_tile=plan.s_tile,
+                          precision=plan.precision, interpret=interpret)
         if sp is not None:
             sp.set_attr("padded", _is_padded(A, 1, plan.m_tile))
-        # one executable: the block-key table, the padding (where the
-        # operand is ragged), the kernel(s) and the scale — folded into
-        # the planes under the "hbm" residency, a pass over the result
-        # otherwise
-        return _fused_call(A, kd, scale, s_dim=s_dim,
-                           dist_kind=_DIST_KINDS[type(dist)],
-                           m_tile=plan.m_tile, s_tile=plan.s_tile,
-                           precision=plan.precision, interpret=interpret)
+        return out
 
 
 def columnwise_apply(
@@ -1020,13 +1023,15 @@ def columnwise_apply(
         return None
     kd = _key_data(key)
     with _trace.span("sketch.dispatch") as sp:
+        # the handover, as rowwise: one executable (table, padding,
+        # kernel(s), scale) called first, the attribute after it
+        out = _fused_call_cw(A, kd, scale, s_dim=s_dim,
+                             dist_kind=_DIST_KINDS[type(dist)],
+                             m_tile=plan.m_tile, precision=plan.precision,
+                             interpret=interpret)
         if sp is not None:
             sp.set_attr("padded", _is_padded(A, 0, plan.m_tile))
-        # one executable, as rowwise: table, padding, kernel(s), scale
-        return _fused_call_cw(A, kd, scale, s_dim=s_dim,
-                              dist_kind=_DIST_KINDS[type(dist)],
-                              m_tile=plan.m_tile, precision=plan.precision,
-                              interpret=interpret)
+        return out
 
 
 def rft_rowwise_apply(
@@ -1054,17 +1059,20 @@ def rft_rowwise_apply(
     if plan is None:
         return None
     kd = _key_data(key)
+    # the vectors' conversions are dispatches of their own: ahead of the
+    # handover span, whose body starts with the executable's call
+    sc = jnp.asarray(sc, jnp.float32).reshape(1, s_dim)
+    sh = jnp.asarray(sh, jnp.float32).reshape(1, s_dim)
     with _trace.span("sketch.dispatch") as sp:
-        if sp is not None:
-            sp.set_attr("padded", _is_padded(A, 1, plan.m_tile))
-        return _fused_call_cos(
-            A, kd,
-            jnp.asarray(sc, jnp.float32).reshape(1, s_dim),
-            jnp.asarray(sh, jnp.float32).reshape(1, s_dim),
+        out = _fused_call_cos(
+            A, kd, sc, sh,
             s_dim=s_dim, dist_kind=_DIST_KINDS[type(dist)],
             m_tile=plan.m_tile, s_tile=plan.s_tile,
             precision=plan.precision, inscale=float(inscale),
             outscale=float(outscale), interpret=interpret)
+        if sp is not None:
+            sp.set_attr("padded", _is_padded(A, 1, plan.m_tile))
+        return out
 
 
 def features_rows(key, dist, A, s_dim: int, inscale: float, outscale: float,

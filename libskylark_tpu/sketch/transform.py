@@ -103,7 +103,13 @@ class OperatorCache:
         trace runs once, and materializing inside it would pin a tracer.
         Steady-state reuse (a serving predict path, a feature map inside
         an eager solver loop) thus amortizes generation to zero without
-        anyone calling :meth:`materialize`."""
+        anyone calling :meth:`materialize`. The decision — on the kernel
+        route a second resolution of the apply's plan — and, the Nth time,
+        the pin itself run under the ``sketch.materialize`` span."""
+        with _trace.span("sketch.materialize"):
+            self._auto_materialize(A, seq_axis)
+
+    def _auto_materialize(self, A, seq_axis: int | None) -> None:
         dtype = A.dtype
         if self._op_cache is not None and \
                 jnp.dtype(dtype).itemsize <= self._op_cache.dtype.itemsize:
@@ -256,21 +262,22 @@ class SketchTransform:
                     f"rowwise apply expects {self._N} cols, got {A.shape}"
                 )
             return self._apply_rowwise_sparse(A)
-        A = jnp.asarray(A)
-        if A.ndim == 1:
-            A = A[:, None] if dimension == COLUMNWISE else A[None, :]
-        if dimension == COLUMNWISE:
-            if A.shape[0] != self._N:
+        columnwise = dimension == COLUMNWISE
+        axis, name, extent = ((0, "columnwise", "rows") if columnwise
+                              else (1, "rowwise", "cols"))
+        # the largest piece of an apply's own time ahead of its handover
+        # (telemetry/names.py HANDOVER), under a name of its own
+        with _trace.span("sketch.operand"):
+            A = jnp.asarray(A)
+            if A.ndim == 1:
+                A = A[:, None] if columnwise else A[None, :]
+            if A.shape[axis] != self._N:
                 raise errors.SketchError(
-                    f"columnwise apply expects A with {self._N} rows, got {A.shape}"
-                )
+                    f"{name} apply expects A with {self._N} {extent}, "
+                    f"got {A.shape}")
+        if columnwise:
             return self._apply_columnwise(A)
-        else:
-            if A.shape[1] != self._N:
-                raise errors.SketchError(
-                    f"rowwise apply expects A with {self._N} cols, got {A.shape}"
-                )
-            return self._apply_rowwise(A)
+        return self._apply_rowwise(A)
 
     def _apply_columnwise(self, A: jnp.ndarray) -> jnp.ndarray:
         raise errors.NotImplementedYetError(

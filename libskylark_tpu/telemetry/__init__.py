@@ -45,9 +45,9 @@ from libskylark_tpu.telemetry.metrics import (
     snapshot,
 )
 from libskylark_tpu.telemetry.trace import (
-    Span, SpanContext, add_event, add_sink, attach, clear_finished,
-    current_span, finished_spans, get_context, new_request_id, span,
-    stage_seconds,
+    Span, SpanContext, add_event, add_sink, apply_periods, attach,
+    clear_finished, current_span, finished_spans, get_context,
+    new_request_id, span, stage_seconds,
 )
 from libskylark_tpu.telemetry.export import (
     JsonlExporter, get_exporter, install_exporter, prometheus_text,
@@ -65,9 +65,9 @@ if _env.TELEMETRY_DIR.get():
 __all__ = [
     "Counter", "DEFAULT_BUCKETS", "Gauge", "Histogram", "JsonlExporter",
     "MetricsRegistry", "Span", "SpanContext", "add_event", "add_sink",
-    "attach", "clear_finished", "counter", "current_span", "enabled",
-    "finished_spans", "gauge", "get_context", "get_exporter", "histogram",
-    "install_exporter", "new_request_id", "prometheus_text",
+    "apply_periods", "attach", "clear_finished", "counter", "current_span",
+    "enabled", "finished_spans", "gauge", "get_context", "get_exporter",
+    "histogram", "install_exporter", "new_request_id", "prometheus_text",
     "register_collector", "registry", "set_enabled", "setup",
     "shutdown_exporter", "snapshot", "span", "stage_seconds",
 ]

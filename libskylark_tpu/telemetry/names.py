@@ -211,6 +211,18 @@ SPANS: Dict[str, Tuple[str, str]] = {
     # contraction's step) and vmem_limit_bytes (what the "hbm" contraction
     # passes Mosaic for a grown row tile; 0: none passed) — for the operator
     "sketch.apply": ("sketch kernel", "sketch_host_ms.apply"),
+    # a dense operand made a jax array and held to the transform's N
+    # (SketchTransform._apply: jnp.asarray, the 1-D lift, the shape check) —
+    # since PR 53, the largest piece of sketch.apply's own time ahead of the
+    # handover (HANDOVER below), a part of apply_periods' before_by_name
+    "sketch.operand": ("sketch kernel", "idle_before_enqueue_ms.apply"),
+    # the auto-materialize dispatch of a dense transform or feature map
+    # (OperatorCache._note_eager_apply): whether this eager apply pins the
+    # operator — on the kernel route the apply's plan resolved a second
+    # time (pallas_serves_eager -> effective_plan), and the Nth time off it
+    # the pin itself; since PR 53 the other large piece of sketch.apply's
+    # own time in the dense and feature cells
+    "sketch.materialize": ("sketch kernel", "idle_before_enqueue_ms.apply"),
     "sketch.plan": ("sketch kernel", "sketch_plan_ms.apply"),
     "sketch.dispatch": ("sketch kernel", "sketch_dispatch_ms.apply"),
     # every access of an allocation's key material (base/context.py
@@ -219,11 +231,15 @@ SPANS: Dict[str, Tuple[str, str]] = {
     # table where it is a dispatch of its own (pallas_dense._block_keys,
     # the sharded apply's; a fused apply derives it inside its program)
     "stream.key": ("streams", "stream_key_ms.apply"),
-    # the measured solve (nla/svd.py, engine/compiled.py)
+    # the measured solve (nla/svd.py, engine/compiled.py); under a
+    # sketch.apply the same spans are the compiled applies' way to the
+    # runtime: engine.lookup is a part of what idle_before_enqueue_ms.apply
+    # reads (apply_periods' before_by_name), and engine.execute — exactly
+    # entry.executable(*args) — is the handover (HANDOVER below) that ends it
     "nla.approximate_svd": ("solver phases", "operator"),
     "engine.call": ("solver phases", "operator"),
-    "engine.lookup": ("solver phases", "operator"),
-    "engine.execute": ("solver phases", "operator"),
+    "engine.lookup": ("solver phases", "idle_before_enqueue_ms.apply"),
+    "engine.execute": ("solver phases", "idle_before_enqueue_ms.apply"),
     "engine.compile": ("solver phases", "operator"),
     "engine.lower": ("solver phases", "operator"),
     "engine.backend_compile": ("solver phases", "operator"),
@@ -240,6 +256,20 @@ SPANS: Dict[str, Tuple[str, str]] = {
     "io.chunked.read": ("ingest", "operator"),
     "io.webhdfs.open": ("ingest", "operator"),
 }
+
+#: The handover of an apply: the span that wraps the ONE call that hands its
+#: compiled program to jax and the runtime, in order of preference — the
+#: first ``engine.execute`` among an apply's descendants (the routes through
+#: ``engine.compiled``), else its first ``sketch.dispatch`` (a ``jax.jit``
+#: called directly: the dense kernels of sketch/pallas_dense.py, the XLA
+#: contractions of sketch/dense.py). In a closed blocking loop the device is
+#: idle from an apply's first line to this span's start, and every other
+#: idle nanosecond of the period comes after it: ``trace.apply_periods``
+#: splits there, ``idle_before_enqueue_ms.apply`` and
+#: ``idle_after_enqueue_ms.apply`` read the two sides. The body of such a
+#: span holds the executable's call and nothing the program could do
+#: earlier. Every name here is declared in ``SPANS``.
+HANDOVER: Tuple[str, ...] = ("engine.execute", "sketch.dispatch")
 
 #: phase of a set-up record (telemetry/setup.py ``PHASES``) -> the
 #: per-layer metric of BENCHMARK.json that reads it (layer "set-up" of
@@ -260,4 +290,4 @@ SETUP_PHASES: Dict[str, str] = {
     "cache_load": "operator",
 }
 
-__all__ = ["METRICS", "SETUP_PHASES", "SPANS"]
+__all__ = ["HANDOVER", "METRICS", "SETUP_PHASES", "SPANS"]

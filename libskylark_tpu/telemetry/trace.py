@@ -378,12 +378,7 @@ def stage_seconds(root_name: str, last: Optional[int] = None
         roots = roots[-last:] if last > 0 else []
     if not roots:
         return []
-    if len(spans) == _FINISHED.maxlen and (
-            (last is not None and len(roots) < last)
-            or spans[0].t_end_ns >= roots[0].t_start_ns):
-        # a full ring has (or may have) dropped spans; all of them
-        # ended before its oldest did — whole only if that was before
-        # the oldest root began, and no asked-for root is among them
+    if not _ring_is_whole(spans, roots, last):
         return None
     by_id = {r.span_id: (r, {}, []) for r in roots}
     for s in spans:
@@ -406,8 +401,122 @@ def stage_seconds(root_name: str, last: Optional[int] = None
     return stages
 
 
+def _ring_is_whole(spans: list, roots: list, last: Optional[int]) -> bool:
+    """False when the ring may have dropped a span from the oldest of
+    ``roots`` on: a full ring has (or may have) dropped spans, all of
+    which ended before its oldest did — whole only if that was before
+    the oldest root began, and no asked-for root is among them."""
+    return not (len(spans) == _FINISHED.maxlen and (
+        (last is not None and len(roots) < last)
+        or spans[0].t_end_ns >= roots[0].t_start_ns))
+
+
+def _split_by_innermost(inside: list, lo: int, hi: int) -> dict:
+    """[lo, hi) in nanoseconds by the name of the innermost of the spans
+    ``inside`` open at each instant; what none of them covers goes to
+    ``None``. The values add up to ``hi - lo`` exactly (integers)."""
+    by_name: dict = {}
+    cursor = lo
+    stack: list = []     # (end, name) of the open spans, outermost first
+
+    def credit(until: int) -> None:
+        nonlocal cursor
+        until = min(until, hi)
+        if until > cursor:
+            name = stack[-1][1] if stack else None
+            by_name[name] = by_name.get(name, 0) + until - cursor
+            cursor = until
+
+    for s in sorted(inside, key=lambda s: (s.t_start_ns, -s.t_end_ns)):
+        if s.t_start_ns >= hi:
+            break
+        while stack and stack[-1][0] <= s.t_start_ns:
+            credit(stack[-1][0])
+            stack.pop()
+        credit(s.t_start_ns)
+        stack.append((s.t_end_ns, s.name))
+    while stack:
+        credit(stack[-1][0])
+        stack.pop()
+    credit(hi)
+    return by_name
+
+
+def apply_periods(root_name: str, last: Optional[int] = None
+                  ) -> Optional[list]:
+    """The periods of a closed blocking loop, read off the ring: for the
+    last ``last`` finished spans named ``root_name`` (all when ``None``)
+    of the newest one's thread, oldest first and without the newest
+    (it has no successor), a dict each of
+
+    - ``period_s``: the next root's start minus this root's start;
+    - ``before_s``: the root's start to the start of its **handover**
+      span (``names.HANDOVER``: the first ``engine.execute`` among its
+      descendants — same ``trace_id``, same thread, inside its
+      interval —, else its first ``sketch.dispatch``): the program's own
+      Python ahead of the call that hands the compiled program to the
+      runtime. Nothing is in flight when such a loop's operation begins,
+      so the device is idle for all of it;
+    - ``before_by_name``: that interval by the name of the innermost
+      span open at each instant (the own time of the handover's
+      ancestors ahead of the call among them), and ``before_self_s``:
+      what only the root covers — together exactly ``before_s``;
+    - ``call_s``: the handover span's duration, ``handover``: its name;
+    - ``handovers``: how many spans of that name the root holds (one
+      runtime hand-off an operation is the rule).
+
+    A root that holds no handover span (an eager composition that opens
+    none) has ``handovers`` 0 and ``None`` for the other entries but
+    ``period_s``. ``None`` for the whole list where
+    :func:`stage_seconds` gives ``None`` (a wrapped ring). All stamps
+    are ``perf_counter_ns`` of one process."""
+    from libskylark_tpu.telemetry.names import HANDOVER
+
+    spans = list(_FINISHED)
+    roots = [s for s in spans if s.name == root_name]
+    if roots:
+        thread = roots[-1].thread
+        roots = [s for s in roots if s.thread == thread]
+    if last is not None:
+        roots = roots[-last:] if last > 0 else []
+    if not roots:
+        return []
+    if not _ring_is_whole(spans, roots, last):
+        return None
+    roots.sort(key=lambda s: s.t_start_ns)
+    by_trace: dict = {}
+    wanted = {r.trace_id for r in roots}
+    for s in spans:
+        if s.trace_id in wanted and s.thread == thread:
+            by_trace.setdefault(s.trace_id, []).append(s)
+    periods = []
+    for root, successor in zip(roots, roots[1:]):
+        inside = [s for s in by_trace[root.trace_id]
+                  if s is not root and s.t_start_ns >= root.t_start_ns
+                  and s.t_end_ns <= root.t_end_ns]
+        period = {"period_s": (successor.t_start_ns - root.t_start_ns) * 1e-9,
+                  "before_s": None, "before_by_name": None,
+                  "before_self_s": None, "call_s": None, "handover": None,
+                  "handovers": 0}
+        for name in HANDOVER:
+            calls = [s for s in inside if s.name == name]
+            if calls:
+                first = min(calls, key=lambda s: s.t_start_ns)
+                parts = _split_by_innermost(
+                    inside, root.t_start_ns, first.t_start_ns)
+                period.update(
+                    before_s=(first.t_start_ns - root.t_start_ns) * 1e-9,
+                    before_self_s=parts.pop(None, 0) * 1e-9,
+                    before_by_name={k: v * 1e-9 for k, v in parts.items()},
+                    call_s=first.duration_s, handover=name,
+                    handovers=len(calls))
+                break
+        periods.append(period)
+    return periods
+
+
 __all__ = [
-    "Span", "SpanContext", "add_event", "add_sink", "attach",
-    "clear_finished", "current_span", "finished_spans", "get_context",
-    "new_request_id", "span", "stage_seconds",
+    "Span", "SpanContext", "add_event", "add_sink", "apply_periods",
+    "attach", "clear_finished", "current_span", "finished_spans",
+    "get_context", "new_request_id", "span", "stage_seconds",
 ]

@@ -139,13 +139,16 @@ def test_off_the_kernel_one_compile_one_dispatch(fresh):
     spans = trace.finished_spans()
     root = next(s for s in spans if s.name == "sketch.apply")
     kids = [s for s in spans if s.parent_id == root.span_id]
-    # the plan is asked for (and declines off the TPU), the key derived,
-    # and the one enqueue is the engine's call under the dispatch span
-    assert [s.name for s in kids] == ["sketch.plan", "stream.key",
-                                      "sketch.dispatch"]
-    assert kids[1].attrs["what"] == "allocation"
-    assert isinstance(kids[1].attrs["cached"], bool)
-    dispatch = kids[2]
+    # the operand is made an array and checked, the auto-materialize
+    # dispatch decides, the plan is asked for (and declines off the TPU),
+    # the key derived, and the one enqueue is the engine's call under the
+    # dispatch span
+    assert [s.name for s in kids] == [
+        "sketch.operand", "sketch.materialize", "sketch.plan", "stream.key",
+        "sketch.dispatch"]
+    assert kids[3].attrs["what"] == "allocation"
+    assert isinstance(kids[3].attrs["cached"], bool)
+    dispatch = kids[4]
     calls = [s for s in spans if s.name == "engine.call"]
     assert len(calls) == 1 and calls[0].parent_id == dispatch.span_id
     assert calls[0].attrs["name"] == "sketch.rft_features"
@@ -247,11 +250,12 @@ def test_kernel_route_spans_and_counter(fresh, interpreted, monkeypatch, scope,
                                atol=1e-4 * T.outscale)
     root = next(s_ for s_ in spans if s_.name == "sketch.apply")
     kids = [s_ for s_ in spans if s_.parent_id == root.span_id]
-    assert [s_.name for s_ in kids] == ["sketch.plan", "stream.key",
-                                        "sketch.dispatch"]
+    assert [s_.name for s_ in kids] == [
+        "sketch.operand", "sketch.materialize", "sketch.plan", "stream.key",
+        "sketch.dispatch"]
     # one key access an apply: the block-key table is the program's
-    assert kids[1].attrs["what"] == "allocation"
-    dispatch = kids[2].attrs
+    assert kids[3].attrs["what"] == "allocation"
+    dispatch = kids[4].attrs
     assert dispatch["path"] == "features" and dispatch["epilogue"] == "cos"
     assert dispatch["finisher"] == "cos_turns"
     assert dispatch["family"] == "GaussianRFT" and dispatch["kernel"] == kernel

@@ -518,7 +518,8 @@ def _interpreted_kernel(monkeypatch):
 
 
 class TestHotPathSpans:
-    APPLY_STAGES = {"stream.key", "sketch.plan", "sketch.dispatch"}
+    APPLY_STAGES = {"sketch.operand", "sketch.materialize", "stream.key",
+                    "sketch.plan", "sketch.dispatch"}
 
     def test_gate_shut_leaves_the_ring_empty(self):
         from libskylark_tpu import nla
@@ -794,6 +795,17 @@ class TestSpanNames:
                   and getattr(call.func, "attr", None) == "inc_always"
                   for kw in call.keywords}
         assert labels == {"result"}
+
+    def test_every_handover_name_is_a_declared_span_with_a_site(self):
+        """``HANDOVER`` (what ``apply_periods`` splits an apply at) names
+        spans the package opens: a rename fails here, not as a metric
+        that turns into ``None``."""
+        from libskylark_tpu.telemetry.names import HANDOVER
+
+        assert len(HANDOVER) == len(set(HANDOVER)) >= 1
+        for name in HANDOVER:
+            assert name in self.SPANS, f"undeclared handover {name!r}"
+            assert name in _span_literals(), f"no call site for {name!r}"
 
     @pytest.mark.parametrize("name", sorted(SPANS))
     def test_declared_span_has_a_call_site(self, name):
