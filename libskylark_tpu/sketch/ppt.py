@@ -19,12 +19,19 @@ from the buckets and signs and contracted with a block on the MXU — no
 scatter, no forward transform. The q spectra are multiplied in float32
 complex arithmetic and the one inverse transform, half spectrum → S reals
 along the feature axis, is a two-stage blocked DFT on the MXU (S = N1·N2,
-the twiddles applied between the stages). Every
-product carries float32 on both sides (``highest``).
+the twiddles applied between the stages). Every product carries float32 on
+both sides: the six bfloat16 partial products of its operands' three parts
+that ``Precision.HIGHEST`` forms on a TPU, accumulated in float32. The two
+stages (K = N1, 2·N2) say ``highest`` and leave the passes to the compiler.
+The spectral products lay the six out themselves, side by side along K
+(:func:`packed`): one bfloat16 product over 6N columns, because
+the compiler's six passes each round K up to whole 128-deep MXU tiles by
+themselves — at N = 784 = 6·128 + 16 that is 6 × 7 = 42 tile passes of which
+36.75 carry data, where 6·784 = 4704 columns round up once, to 37.
 
 What the v5e compiler makes of a block (``tests/test_v5e_compile.py`` holds
-it): the block's examples sliced out of the operand once; the q products of
-that slice; the spectra's product in two fusions, each storing its
+it): the block's examples sliced out of the operand and packed once; the q
+products of those; the spectra's product in two fusions, each storing its
 half of stage one's operand in place (the first hands the pair product on to
 the second); stage one, which reads that operand as the tiles of a (B, S)
 array lie; the twiddles; stage two; and one fusion that turns the two digits
@@ -74,6 +81,14 @@ _FACTOR_MAX = 256
 _BLOCK_ENTRIES = 1 << 26
 
 _HIGHEST = jax.lax.Precision.HIGHEST
+#: The partial products (part of x, part of w) of a float32-grade product —
+#: the six of ``Precision.HIGHEST``, every pair of parts whose orders add up
+#: to at most two — in the order they lie along the packed K, the smallest
+#: first: the MXU adds K up in order, and 5N small terms added to a sum that
+#: already holds hi·hi round at its size, not at theirs (on a v5e 3.6e-7 of
+#: the largest entry against float64 at N = 784 where this order reads
+#: 1.5e-7, ``highest`` itself 1.5e-7; the time is the same).
+_TERMS = ((2, 0), (0, 2), (1, 1), (1, 0), (0, 1), (0, 0))
 
 
 @functools.lru_cache(maxsize=256)
@@ -107,6 +122,47 @@ def _grade(x, grade: str):
     if grade == "float32":
         return x
     return jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+
+
+def bf16_parts(a, count: int = 3):
+    """The leading ``count`` bfloat16 parts of a float32 array: hi = bf16(a),
+    mid = bf16(a − hi), lo = bf16(a − hi − mid) — each difference exact in
+    float32, so that the three add back up to ``a`` bit for bit (8 + 8 + 8
+    bits of significand). Rounded by :func:`_grade`'s ``reduce_precision``,
+    which no compiler flag folds away as it may a convert there and back."""
+    parts = []
+    for _ in range(count):
+        part = _grade(a, "bf16")
+        parts.append(part.astype(jnp.bfloat16))
+        a = a - part
+    return parts
+
+
+def _terms(grade: str):
+    """The partial products a spectral product at ``grade`` is made of: all
+    of :data:`_TERMS`, or for ``"bf16"`` the one term x_hi·w_hi."""
+    return _TERMS if grade == "float32" else _TERMS[-1:]
+
+
+def k_tiles(n: int, grade: str = "float32") -> int:
+    """128-deep MXU tile passes a spectral product of N input columns takes
+    over its packed K: ⌈6N/128⌉ — never more than the 6·⌈N/128⌉ of six
+    products each padded by itself, fewer wherever N's last tile is under
+    five sixths full, six times fewer at N ≤ 21."""
+    return -(-len(_terms(grade)) * n // 128)
+
+
+def packed(a, side: int, grade: str = "float32"):
+    """One side of a float32-grade product x·w as ONE bfloat16 product at
+    default precision, accumulated in float32: the bfloat16 parts of the
+    examples x (B, N) (``side`` 0) side by side, or of the operator w (N, S)
+    (``side`` 1) one above the other, as :data:`_TERMS` pairs them —
+    [x_lo | x_hi | x_mid | x_mid | x_hi | x_hi] (B, 6N) against
+    [w_hi ; w_lo ; w_mid ; w_hi ; w_mid ; w_hi] (6N, S). ``grade`` ``"bf16"``
+    keeps the one term x_hi·w_hi: K = N."""
+    terms = _terms(grade)
+    parts = bf16_parts(a, 1 + max(t[side] for t in terms))
+    return jnp.concatenate([parts[t[side]] for t in terms], axis=1 - side)
 
 
 def spectral_operator(h, v, s: int):
@@ -153,19 +209,28 @@ def _block_features(Xb, operators, factors, grade: str):
     a lane's 128; (B, N2, 1, N1) where B is no multiple of 8. The q spectra
     x·(C_k F) + the homogeneity term's, their product, the inverse transform:
     stage one makes t1 of κ1, the twiddle couples (κ2, t1), stage two makes
-    t2 of κ2 and leaves the two digits of t to the caller's store."""
+    t2 of κ2 and leaves the two digits of t to the caller's store.
+    ``operators`` are the q packed operators, (6N, S) bfloat16 each
+    (:func:`packed`), and the homogeneity term's spectra (q, S): a spectral
+    product is one bfloat16 product over the packed K, ⌈6N/128⌉ MXU tile
+    passes (:func:`k_tiles`) where ``highest`` on float32 operands pads each
+    of its six passes to ⌈N/128⌉ by itself (37 against 42 at N = 784)."""
     W, bias = operators
     M1, Tc, Ts, M2 = factors
     n1, n2 = Tc.shape
     B, s = Xb.shape[0], n1 * n2
     first = jnp.arange(s // 2, dtype=jnp.int32)[None, :] == 0
-    # the block's examples as an array of their own, made once: a product
-    # that reads them through the walk's dynamic slice of the whole operand
-    # is a tenth slower on a v5e (4.16 against 3.77 ms), three times a block
-    x = jax.lax.optimization_barrier(_grade(Xb, grade))
+    # the block's examples, packed, as an array of their own, made once: a
+    # product that reads them through the walk's dynamic slice of the whole
+    # operand is a tenth slower on a v5e (4.16 against 3.77 ms), three times
+    # a block
+    x = jax.lax.optimization_barrier(packed(Xb, 0, grade))
     re = im = nyquist = None
-    for k in range(W.shape[0]):
-        F = jnp.dot(x, _grade(W[k], grade), precision=_HIGHEST) + bias[k]
+    for k in range(len(W)):
+        # default precision, said outright: the parts are bfloat16 already,
+        # and the package's ambient ``highest`` has nothing to split
+        F = jnp.dot(x, W[k], precision=jax.lax.Precision.DEFAULT,
+                    preferred_element_type=jnp.float32) + bias[k]
         fre, fim = F[:, :s // 2], F[:, s // 2:]
         if re is None:
             re, im, nyquist = fre, fim, fim[:, 0]
@@ -233,10 +298,11 @@ def tensorsketch_features(key_data, A, *, spec, rowwise: bool,
 
     hidx, hval = T._hash_idx(), T._hash_val(jnp.float32)
     sg, sc = jnp.float32(math.sqrt(T._gamma)), jnp.float32(math.sqrt(T._c))
+    # the packed operators one array each: stacked, they are copied once more
     operators = (
-        jnp.stack([spectral_operator(cwt.bucket_indices(),
-                                     sg * cwt.values(jnp.float32), s)
-                   for cwt in T._cwts]),
+        [packed(spectral_operator(cwt.bucket_indices(),
+                                  sg * cwt.values(jnp.float32), s), 1, grade)
+         for cwt in T._cwts],
         spectral_operator(hidx, sc * hval, s))
     n1, n2 = split(s)
     factors = _inverse_factors(n1, n2)
@@ -342,8 +408,10 @@ class PPT(SketchTransform):
     def features_plan(self, A, rowwise: bool) -> dict:
         """What an apply does with this operand, as the attributes its
         ``sketch.dispatch`` span carries: ``route`` ``"program"``
-        (:func:`tensorsketch_features`, with the ``row_block`` of its walk
-        and its ``grade``) or ``"chain"`` with the ``reason`` the eager chain
+        (:func:`tensorsketch_features`, with the ``row_block`` of its walk,
+        its ``grade``, the form of its spectral ``product`` — ``"packed_k"``,
+        :func:`packed` — and the MXU ``k_tiles`` one takes, :func:`k_tiles`)
+        or ``"chain"`` with the ``reason`` the eager chain
         keeps it — another dtype than float32, an S the inverse transform
         cannot split (:func:`split`), an operand that lies on more than one
         device (the program's walk would gather it) — and the forms of the
@@ -362,9 +430,11 @@ class PPT(SketchTransform):
             return {"route": "chain", "reason": reason, "row_block": m,
                     "sketch": "segment_sum", "fft": "jnp.fft"}
         bf16 = sketch_params.get_pallas_precision() == "bf16"
+        grade = "bf16" if bf16 else "float32"
         return {"route": "program", "row_block": block_rows(m, self._S),
                 "sketch": "spectral_operator", "fft": "mxu_two_stage",
-                "grade": "bf16" if bf16 else "float32"}
+                "grade": grade, "product": "packed_k",
+                "k_tiles": k_tiles(self._N, grade)}
 
     def _features(self, A: jnp.ndarray, rowwise: bool) -> jnp.ndarray:
         """The dense apply: the one ``sketch.tensorsketch_features`` program

@@ -661,9 +661,9 @@ TS_ROWS, TS_N, TS_S, TS_Q = 60000, 784, 16384, 3
 
 
 def _passes(text, least, below):
-    """``(name, opcode, kind, shapes, operands)`` of every instruction outside the
-    fused computations whose largest array has ``least`` ≤ elements <
-    ``below`` — the arrays of a multi-output fusion's tuple each counted
+    """``(name, opcode, kind, shapes, operands, computation)`` of every
+    instruction outside the fused computations whose largest array has
+    ``least`` ≤ elements < ``below`` — the arrays of a multi-output fusion's tuple each counted
     (:func:`_instructions` reads single-array instructions only) — but for
     the instructions that move nothing: parameters, tuples and their
     elements, bitcasts, the loop itself. ``shapes`` are ``dtype[dims]{layout``
@@ -688,7 +688,7 @@ def _passes(text, least, below):
         if least <= largest < below:
             kind = re.search(r"kind=(\w+)", line)
             found.append((op.group(1), op.group(3), kind and kind.group(1), shapes,
-                          re.findall(r"%([\w.\-]+)", op.group(4))))
+                          re.findall(r"%([\w.\-]+)", op.group(4)), computation))
     return found
 
 
@@ -696,31 +696,41 @@ def _passes(text, least, below):
 def test_cell_shape_tensorsketch_features(one_chip, rows):
     """``Polynomial(784, 3, 1, 1/784).create_rft(16384, ctx)`` rowwise of
     60,000 × 784 as the one program: the three half-spectrum operators
-    (3 × 784 × 16384) generated once, then a walk of 15 blocks of 4096
-    examples whose features go into their rows of an uninitialised result in
-    place. A block is EIGHT passes over block-sized arrays and a ninth that
-    stores it, every one a fusion, no ``copy`` / ``reshape`` / ``transpose``
-    among them and no ``concatenate`` pass:
+    generated and packed once — each (6 × 784, 16384) bfloat16, its three
+    bfloat16 parts one above the other as the six partial products of a
+    float32-grade product pair them, made outside the loop and carried
+    through it —, then a walk of 15 blocks of 4096 examples whose features
+    go into their rows of an uninitialised result in place. A block is EIGHT
+    passes over block-sized arrays and a ninth that stores it, every one a
+    fusion, no ``copy`` / ``reshape`` / ``transpose`` among them and no
+    ``concatenate`` pass:
 
-    1–3. the three spectral products x·(C_k F) (``kOutput``, MXU), each of
-         the block's examples as ONE small fusion slices them out of the
-         operand (read through that dynamic slice a product is a tenth
-         slower on the chip), each at ``highest`` on both sides;
+    1–3. the three spectral products x·(C_k F) (``kOutput``, MXU): ONE
+         bfloat16 product each, at default precision into float32, over the
+         packed K of 4704 = 6 × 784 columns — 37 MXU tiles deep where six
+         ``highest`` passes over K = 784 padded to 896 are 42 — of the
+         block's examples as a few small fusions slice them out of the
+         operand and pack their parts side by side, (4096, 4704) bfloat16,
+         once for all three (read through the walk's dynamic slice a product
+         is a tenth slower on the chip);
     4.   the spectra's product, first half: reads the three spectra, stores
          the real half of stage one's operand in place and hands on the pair
          product F_0·F_1 (``kLoop``, three outputs);
     5.   its second half: the imaginary half stored in place beside the real
          one (``kLoop``, a ``dynamic-update-slice`` root);
-    6.   stage one, reading that operand as it lies (``kOutput``);
+    6.   stage one, reading that operand as it lies (``kOutput``, float32
+         operands at ``highest``: K = 128, nothing padded);
     7.   the twiddles and the Nyquist term (``kLoop``, two outputs);
-    8.   stage two (``kOutput``);
+    8.   stage two (``kOutput``, ``highest``: K = 256);
     9.   the store: ONE ``kLoop`` fusion that turns the two digits of t and
          writes the block at its offset of the result, carried as
          (7500, 128, 8, 128) row-major — the bytes of (60000, 16384).
 
     The whole result is touched by the uninitialised ``custom-call`` and by
     that fusion alone, keeps its row-major layout through the loop and is
-    never copied; beside operand and result the program holds 1.50 GB.
+    never copied; beside operand and result the program holds 2.27 GB (1.50
+    when the operators were 0.15 GB of float32: three packed operators are
+    0.46 GB, a block's packed examples 39 MB).
 
     Rows that are no whole (8, 128) tiles (59,996) keep the store of PR 51:
     the same eight passes, a ninth that turns the digits (a fusion around a
@@ -755,18 +765,34 @@ def test_cell_shape_tensorsketch_features(one_chip, rows):
     if tiled:
         (store,) = [i for i in whole if i[1] == "fusion"]
         assert store[2] == "kLoop" and "dynamic-update-slice" in store[0], store
-    # a block's passes
-    passes = _passes(text, 4096 * TS_S, rows * TS_S)
-    assert all(i[1] == "fusion" for i in passes), passes
+    # a block's passes: the loop's body; the packed operators: the entry
+    sized = _passes(text, 4096 * TS_S, rows * TS_S)
+    assert all(i[1] == "fusion" for i in sized), sized
+    entry = re.search(r"^ENTRY %([\w.\-]+) \(", text, re.M).group(1)
+    passes = [i for i in sized if i[5] != entry]
+    assert len({i[5] for i in passes}) == 1, passes
     products = [i for i in passes if i[2] == "kOutput"]
     assert len(products) == TS_Q + 2, passes
-    sliced = {i[4][0] for i in products if i[3] == [f"f32[4096,{TS_S}]{{1,0"]}
-    assert len(sliced) == 1 and "dynamic-slice" in sliced.pop(), products
-    assert text.count("convolution(") == TS_Q + 2 == text.count(
-        "operand_precision={highest,highest}")
+    # the three spectral products: one packed block of examples for all of
+    # them, made in the loop's body; a packed operator each, made outside it
+    assert ppt.k_tiles(TS_N) == 37
+    k = len(ppt._TERMS) * TS_N
+    spectral = [i for i in products if i[3] == [f"f32[4096,{TS_S}]{{1,0"]]
+    assert len(spectral) == TS_Q and len({i[4][0] for i in spectral}) == 1
+    assert len({i[4][1] for i in spectral}) == TS_Q, spectral
+    x_cat = [i for i in _passes(text, 4096 * k, 4096 * k + 1)
+             if i[0] == spectral[0][4][0]]
+    assert [i[3] for i in x_cat] == [[f"bf16[4096,{k}]{{0,1"]], x_cat
+    assert x_cat[0][5] == passes[0][5]
+    packing = [i for i in sized if i[5] == entry]
+    assert packing and all(f"bf16[{k},{TS_S}]{{1,0" in i[3] for i in packing), packing
+    loop = re.search(r"= \((.*?)\) while\(", text).group(1)
+    assert loop.count(f"bf16[{k},{TS_S}]") == TS_Q and f"f32[{TS_N},{TS_S}]" not in loop
+    assert text.count("convolution(") == TS_Q + 2
+    assert text.count("operand_precision={highest,highest}") == 2     # the stages
     loops = [i for i in passes if i[2] == "kLoop"]
     assert sorted(len(i[3]) for i in loops) == [1] * (not tiled) + [1, 2, 3], passes
     assert len(passes) == (8 if tiled else 9), passes
     memory = compiled.memory_analysis()
     assert memory.output_size_in_bytes == -(-rows // 8) * 8 * TS_S * 4   # whole tiles
-    assert memory.temp_size_in_bytes < 1.8e9
+    assert memory.temp_size_in_bytes < 2.4e9
