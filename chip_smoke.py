@@ -172,6 +172,27 @@ def feature_kernels(family: str):
                     if counter.value(family=family, kernel=k) > before[k]]
 
 
+def dispatch_span(call) -> dict:
+    """The attributes of the ``sketch.dispatch`` span one ``call`` opens
+    (telemetry on for its duration): which program served an apply."""
+    from libskylark_tpu import telemetry
+    from libskylark_tpu.telemetry import trace
+
+    was = telemetry.enabled()
+    trace.clear_finished()
+    telemetry.set_enabled(True)
+    try:
+        scalar(call())
+        spans = [s for s in trace.finished_spans()
+                 if s.name == "sketch.dispatch"]
+    finally:
+        telemetry.set_enabled(was)
+        trace.clear_finished()
+    if len(spans) != 1:
+        raise AssertionError(f"{len(spans)} sketch.dispatch spans an apply")
+    return dict(spans[0].attrs)
+
+
 def report(step: str, first_s: float, run_s: float, **fields) -> None:
     say(step, compile_s=f"{max(first_s - run_s, 0.0):.2f}",
         run_s=f"{run_s:.4f}", **fields)
@@ -658,9 +679,19 @@ def step_mesh() -> None:
         ref = np.asarray(T.apply(jnp.asarray(A_host), sk.ROWWISE))
     with par.use_mesh(mesh):
         out, first, run = timed(lambda: T.apply(A, sk.ROWWISE))
+        served = dispatch_span(lambda: T.apply(A, sk.ROWWISE))
     on_all_devices(out, "sharded JLT output")
     err = close(out, ref, "sharded JLT vs unsharded")
-    report("mesh.JLT.grid2d", first, run, mesh="2x2", err=f"{err:.2e}")
+    # which program served it: the span of parallel/shard_apply.py's route
+    if (served.get("route"), out.sharding.spec) != (
+            "program", par.grid2d(mesh).spec):
+        raise AssertionError(
+            f"T.apply of a grid2d operand was served by {served}, "
+            f"result laid {out.sharding.spec}")
+    report("mesh.JLT.grid2d", first, run, mesh="2x2", err=f"{err:.2e}",
+           route=served["route"], kernel=served["kernel"],
+           collective=served["collective"],
+           result=str(out.sharding.spec).replace(" ", ""))
     with par.use_mesh(mesh):
         step_solve(sharding=par.grid2d(mesh))
 

@@ -79,12 +79,14 @@ def serve_apply(key_data, scale, A, *, dist, s_dim: int,
 
 
 def pallas_ambient_ok(A) -> bool:
-    """True when the fused kernel may run on ``A`` in the ambient context:
-    use_pallas is on AND the array is single-device. Sharded applies keep
-    the XLA path (its partitioning XLA handles); on a tracer the sharding
-    is unreadable, so traced applies qualify only when the backend has a
-    single device and sharding is impossible (the multi-device kernel
-    route is the explicit shard_map pipeline, parallel/shard_apply.py)."""
+    """True when the ONE-CHIP fused kernel may run on ``A`` in the ambient
+    context: use_pallas is on AND the array is single-device. A concrete
+    operand on more than one device is :func:`on_mesh`'s: its apply is the
+    ``sketch.dense_mesh`` program (parallel/shard_apply.py), the same
+    kernel a device under ``shard_map``. On a tracer the sharding is
+    unreadable, so traced applies qualify only when the backend has a
+    single device and sharding is impossible; a traced apply of a sharded
+    operand stays XLA's to partition."""
     if not sketch_params.get_use_pallas():
         return False
     import jax
@@ -97,6 +99,16 @@ def pallas_ambient_ok(A) -> bool:
         except Exception:
             return False
     return False
+
+
+def on_mesh(A) -> bool:
+    """True for a concrete 2-D ``jax.Array`` that lies on more than one
+    device — what ``DenseTransform._apply_dense`` hands to the mesh
+    program. A tracer's placement is unreadable (``pallas_ambient_ok``'s
+    convention): never."""
+    if isinstance(A, jax.core.Tracer) or not isinstance(A, jax.Array):
+        return False
+    return A.ndim == 2 and len(A.sharding.device_set) > 1
 
 
 def pallas_serves_eager(A, dist, s_dim: int,
@@ -178,7 +190,8 @@ class DenseTransform(OperatorCache, SketchTransform):
         return self.s_panel(0, self._N, dtype)
 
     def _materialize_changes_numerics(self, A, seq_axis=None) -> bool:
-        return pallas_serves_eager(A, self.dist, self._S, seq_axis)
+        return (pallas_serves_eager(A, self.dist, self._S, seq_axis)
+                or self._mesh_serves(A))
 
     # -- apply --
 
@@ -211,23 +224,31 @@ class DenseTransform(OperatorCache, SketchTransform):
 
     def _apply_dense(self, A: jnp.ndarray, seq_axis: int) -> jnp.ndarray:
         """S·A (``seq_axis`` 0) or A·Sᵀ (1) by the first path that
-        serves: the pinned operator, the fused kernel, the blocked scan,
-        the materialized gemm."""
+        serves: the pinned operator, the fused kernel, the mesh program
+        (an operand on more than one device), the blocked scan, the
+        materialized gemm."""
         rowwise = seq_axis == 1
         self._note_eager_apply(A, seq_axis=seq_axis)
         S = self._cached_op(A.dtype)
         path = "cached_op"
+        attrs = {"padded": False}
         if S is None:
             out = self._try_pallas(
                 A, "rowwise_apply" if rowwise else "columnwise_apply")
             if out is not None:
                 return out
+            if on_mesh(A):
+                out, declined = self._mesh_apply(A, seq_axis)
+                if out is not None:
+                    return out
+                # XLA partitions what follows; the span says why
+                attrs["route"] = f"xla: {declined}"
             blocksize = self._effective_blocksize(A.dtype)
             if blocksize:
                 note_apply(path="xla_blocked")
                 blocked = (self._apply_rowwise_blocked if rowwise
                            else self._apply_columnwise_blocked)
-                with _trace.span("sketch.dispatch", {"padded": False}):
+                with _trace.span("sketch.dispatch", attrs):
                     return blocked(A, blocksize)
             path = "xla_full"
             S = self.s_panel(0, self._N, A.dtype)
@@ -235,13 +256,28 @@ class DenseTransform(OperatorCache, SketchTransform):
         # the transpose is a dispatch of its own: ahead of the handover
         # span (telemetry/names.py HANDOVER), which holds the one matmul
         left, right = (A, S.T) if rowwise else (S, A)
-        with _trace.span("sketch.dispatch", {"padded": False}):
+        with _trace.span("sketch.dispatch", attrs):
             return left @ right
 
     def _try_pallas(self, A, which: str):
         return try_pallas_apply(
             self._alloc.key_data, self.dist, A, self._S, self.scale, which
         )
+
+    # -- an operand on more than one device: parallel/shard_apply.py,
+    # imported (with jax.shard_map) at the first such operand --
+
+    def _mesh_serves(self, A) -> bool:
+        if not on_mesh(A):
+            return False
+        from libskylark_tpu.parallel import shard_apply
+
+        return shard_apply.serves(A)
+
+    def _mesh_apply(self, A, seq_axis: int):
+        from libskylark_tpu.parallel import shard_apply
+
+        return shard_apply.apply_on_mesh(self, A, seq_axis)
 
     # -- sparse input (ref: sketch/dense_transform_Mixed.hpp:19) --
 

@@ -940,8 +940,8 @@ def _key_data(key):
 
 
 def _block_keys(key, n: int) -> jnp.ndarray:
-    """:func:`_block_key_table` as a dispatch of its own, for the caller
-    that shards the table (parallel/shard_apply.py)."""
+    """:func:`_block_key_table` as a dispatch of its own, for a caller
+    that holds the table and slices it (:func:`fused_partial`'s tests)."""
     with _trace.span("stream.key", {"what": "block_table"}):
         return _block_key_table(_key_data(key), n)
 
@@ -1098,29 +1098,37 @@ def fused_partial(
     m_tile: int | None = None,
     precision: str | None = None,
     interpret: bool = False,
+    plan: Optional[Plan] = None,
+    scale=None,
 ) -> Optional[jnp.ndarray]:
-    """UNSCALED contraction of a local shard against the operator blocks
-    keyed by ``keys`` (n_blocks_local, 2) — the building block that lets
-    the ``shard_map`` panel apply (parallel/shard_apply.py) run the
-    fused kernel per device: each device passes its own slice of the
-    global key table, contracts its shard, and the caller psums.
+    """Contraction of a local shard against the operator blocks keyed by
+    ``keys`` (n_blocks_local, 2), unscaled unless ``scale`` is given (then
+    folded into the planes under the "hbm" residency, as the one-chip
+    apply's) — the building block that lets the ``shard_map`` program
+    (parallel/shard_apply.py ``dense_mesh``) run the fused kernel per
+    device: each device passes its own slice of the global key table,
+    contracts its shard, and the caller reduces.
 
     ``seq_axis`` is the contracted axis of ``A_loc`` (1 → A·Sᵀ partial,
     0 → S·A partial). The shard's sequence extent must equal
     ``keys.shape[0] * BLOCK_COLS`` (callers pre-pad to block multiples).
-    Returns None when the kernel isn't applicable (caller falls back;
-    backend/distribution qualification is _qualify's)."""
+    ``plan``: the :func:`_plan` of the shard made ahead of the trace (a
+    compiled program's static); None plans here. Returns None when the
+    kernel isn't applicable (caller falls back; backend/distribution
+    qualification is _qualify's)."""
     if A_loc.shape[seq_axis] != keys.shape[0] * BLOCK_COLS:
         return None
-    plan = _plan(dist, A_loc, s_dim, seq_axis, m_tile, precision, interpret)
     if plan is None:
-        return None
+        plan = _plan(dist, A_loc, s_dim, seq_axis, m_tile, precision,
+                     interpret)
+        if plan is None:
+            return None
     kw = dict(s_dim=s_dim, dist_kind=_DIST_KINDS[type(dist)],
               m_tile=plan.m_tile, precision=plan.precision,
-              interpret=interpret)
+              interpret=plan.interpret or interpret)
     if seq_axis == 1:
-        return _fused_call(A_loc, keys, None, s_tile=plan.s_tile, **kw)
-    return _fused_call_cw(A_loc, keys, None, **kw)
+        return _fused_call(A_loc, keys, scale, s_tile=plan.s_tile, **kw)
+    return _fused_call_cw(A_loc, keys, scale, **kw)
 
 
 # ---------------------------------------------------------------------------

@@ -78,6 +78,13 @@ METRICS: Dict[str, str] = {
     # ("pallas_blocks" | "xla_bf16x3" | "xla_f32") — the cross-check of
     # the elements that mix_rate.apply reads from the sketch.dispatch spans
     "sketch.mixed_elements": "counter",
+    # the dense apply of an operand on a mesh (parallel/shard_apply.py, the
+    # sketch.dense_mesh program): bytes ONE device sends in the apply's
+    # collective, reckoned from the shapes, by family and collective
+    # ("psum_scatter" | "psum" | "none") — the cross-check of the
+    # collective_bytes that collective_rate.apply reads from the
+    # sketch.dispatch spans with path="mesh"
+    "sketch.mesh_collective_bytes": "counter",
     # accesses of an allocation's key material (base/context.py), by
     # result ("hit": kept from an earlier access | "miss": derived now),
     # always on — over a benchmark window every access is a hit (the
@@ -205,6 +212,19 @@ SPANS: Dict[str, Tuple[str, str]] = {
     # holds the axis or one chunk the samples; for the operator), elements
     # (= axis × columns mixed, which mix_rate.apply reads: the operand's, no
     # pad counted) and sampled (= s × columns kept)
+    # the dense apply of an operand on more than one device
+    # (parallel/shard_apply.py apply_on_mesh, since PR 55) carries path="mesh",
+    # route ("program"; on the XLA route of sketch/dense.py that a declined
+    # sharding keeps, the span has no path and route="xla: <why>"), family,
+    # grid ("2x2": the mesh's shape), spec (the operand's PartitionSpec),
+    # orientation, local_shape (a device's shard, the contracted axis padded),
+    # kernel ("pallas_planes" | "pallas_generate" = the one-chip kernels of
+    # the device's plan | "xla_blocks" = the fori_loop over generated blocks)
+    # and, on a kernel, operator_residency, m_tile and precision, collective
+    # ("psum_scatter" | "psum" | "none"), reduce_over (the mesh axes that
+    # shard the contracted axis) and collective_bytes (what one device sends
+    # in that collective, from the shapes: collective_rate.apply reads it);
+    # its handover is the engine.execute inside it
     # a fused dense apply notes its plan on sketch.apply
     # (pallas_dense._plan): path="pallas", m_tile, s_tile, precision,
     # plan_source, operator_residency and, since PR 49, k_cols (the
@@ -228,8 +248,9 @@ SPANS: Dict[str, Tuple[str, str]] = {
     # every access of an allocation's key material (base/context.py
     # Allocation.key / key_data / key_words), attribute cached (True: kept
     # from an earlier access, False: derived now), and the block-key
-    # table where it is a dispatch of its own (pallas_dense._block_keys,
-    # the sharded apply's; a fused apply derives it inside its program)
+    # table where it is a dispatch of its own (pallas_dense._block_keys: a
+    # caller that holds the table; every apply, the mesh program's since
+    # PR 55 among them, derives it inside its program)
     "stream.key": ("streams", "stream_key_ms.apply"),
     # the measured solve (nla/svd.py, engine/compiled.py); under a
     # sketch.apply the same spans are the compiled applies' way to the

@@ -32,16 +32,21 @@ KERNEL = 'custom_call_target="tpu_custom_call"'
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topology():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — no compiler, or the library is held
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip(topology):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topology.devices[0])
 
 
 @pytest.fixture(scope="module")
@@ -796,3 +801,96 @@ def test_cell_shape_tensorsketch_features(one_chip, rows):
     memory = compiled.memory_analysis()
     assert memory.output_size_in_bytes == -(-rows // 8) * 8 * TS_S * 4   # whole tiles
     assert memory.temp_size_in_bytes < 2.4e9
+
+
+# -- the dense sketch of a distributed matrix: four described chips ----------
+
+MESH_M, MESH_N, MESH_S = 262144, 16384, 1024    # the jlt_apply_mesh4 cell's
+
+
+@pytest.fixture(scope="module")
+def grid2x2(topology):
+    """The four chips of the described v5e host as a 2 × 2 mesh."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(topology.devices).reshape(2, 2), ("rows", "cols"))
+
+
+def _mesh_program(grid2x2, vmem_cap, shape, spec, seq_axis, s_dim):
+    """``sketch.dense_mesh`` compiled for the described mesh on an operand
+    laid ``spec``, under the device's plan as the dispatch resolves it."""
+    from jax.sharding import NamedSharding
+
+    from libskylark_tpu.parallel import shard_apply
+
+    axes = shard_apply._spec_axes(spec)
+    local = shard_apply._local_shape(shape, grid2x2, axes, seq_axis)
+    planned = pd.effective_plan(randgen.Normal(), local, jnp.float32, s_dim,
+                                seq_axis, interpret=True, vmem_cap=vmem_cap)
+    plan = pd.Plan(planned["m_tile"], planned["s_tile"], planned["precision"],
+                   planned["operator_residency"])
+    compiled = jax.jit(functools.partial(
+        shard_apply.dense_mesh, mesh=grid2x2, spec=axes, seq_axis=seq_axis,
+        dist=randgen.Normal(), s_dim=s_dim, scale=(1.0 / s_dim) ** 0.5,
+        plan=plan, scatter=True)).lower(
+        jax.ShapeDtypeStruct((2,), jnp.uint32),
+        jax.ShapeDtypeStruct(shape, jnp.float32,
+                             sharding=NamedSharding(grid2x2, spec))).compile()
+    return local, planned, compiled
+
+
+def _collectives(text: str) -> dict:
+    return {op: len(re.findall(rf"= [^\n=]*\b{op}(?:-start)?\(", text))
+            for op in ("reduce-scatter", "all-reduce", "all-gather",
+                       "collective-permute", "all-to-all")}
+
+
+def test_cell_shape_mesh_program(grid2x2, vmem_cap):
+    """262144 × 16384 laid [MC,MR] over 2 × 2 → 1024: a device's local
+    problem is two ``jlt_apply`` panels under the cell's own plan (the
+    generation and the contraction call, "hbm", 2048 rows a tile); one
+    reduce-scatter over the pair that shares a grid row, nothing gathered;
+    a device holds its operand shard, one partial and its result shard."""
+    from jax.sharding import PartitionSpec as P
+
+    local, plan, compiled = _mesh_program(
+        grid2x2, vmem_cap, (MESH_M, MESH_N), P("rows", "cols"), 1, MESH_S)
+    assert local == (2 * ROWS, N)
+    assert (plan["operator_residency"], plan["m_tile"], plan["k_cols"],
+            plan["precision"]) == ("hbm", 2048, 2 * BLOCK_COLS, "bf16x3")
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 2
+    assert _collectives(text) == {"reduce-scatter": 1, "all-reduce": 0,
+                                  "all-gather": 0, "collective-permute": 0,
+                                  "all-to-all": 0}
+    assert "replica_groups={{0,1},{2,3}}" in text
+    memory = compiled.memory_analysis()
+    shard, partial, result = (4 * local[0] * local[1], 4 * local[0] * MESH_S,
+                              4 * local[0] * MESH_S // 2)
+    assert shard <= memory.argument_size_in_bytes <= shard + 4096
+    assert memory.output_size_in_bytes == result
+    # the partial and (inside it or beside it) the operator's planes
+    assert partial <= memory.temp_size_in_bytes < 1.2e9
+    (out,) = jax.tree.leaves(compiled.output_shardings)
+    assert out.spec == P("rows", "cols")
+
+
+@pytest.mark.parametrize("spec,seq_axis,want", [
+    (("rows", None), 1, "none"), ((None, ("rows", "cols")), 1, "reduce-scatter"),
+    (("rows", "cols"), 0, "reduce-scatter")],
+    ids=["row_sharded", "col_sharded_all_axes", "columnwise"])
+def test_other_layouts_mesh_program(grid2x2, vmem_cap, spec, seq_axis, want):
+    """The same program where the contracted axis is whole on a device (no
+    collective), over all four chips (one reduce-scatter over the four) and
+    columnwise (the mirror image), at a sixteenth of the cell's rows."""
+    from jax.sharding import PartitionSpec as P
+
+    shape = (MESH_M // 16, MESH_N) if seq_axis else (MESH_N, MESH_M // 16)
+    _, plan, compiled = _mesh_program(grid2x2, vmem_cap, shape, P(*spec),
+                                      seq_axis, MESH_S)
+    assert plan["kernel"]
+    found = _collectives(compiled.as_text())
+    assert found.pop("reduce-scatter") == (want == "reduce-scatter")
+    assert not any(found.values()), found
+    assert compiled.as_text().count(KERNEL) >= 1
