@@ -22,18 +22,32 @@ operand, compiled once a (mesh, spec, shape) through ``engine.compiled``:
   where the kernel declines (off the TPU, a distribution or dtype
   ``pallas_dense.supported`` refuses);
 * the float32 partials are reduced over exactly those axes and the result
-  stays distributed as upstream's: ``lax.psum_scatter`` along the sketch axis
+  stays distributed as upstream's: reduce-scattered along the sketch axis
   (``[MC,MR]`` in, ``[MC,MR]`` out) where s divides, ``psum`` to a replicated
   sketch axis where it does not, no collective where the contracted axis is
-  whole on every device.
+  whole on every device;
+* the reduce-scatter goes behind the contraction where the device's rowwise
+  "hbm" contraction holds at least two row panels (``_PANEL_TILES`` row
+  tiles each; :func:`_panels`): the planes of S are made once, the
+  contraction is called a panel on the whole shard with a row window of its
+  grid (``pallas_dense.window_partial``: no slice of the operand), and each
+  panel's partial is exchanged as a pipelined ring of ``ppermute``s — p − 1
+  asynchronous steps that hand on what has been summed of one sketch-axis
+  chunk and add the device's own share of the next, the chunks those of
+  ``psum_scatter(tiled=True)`` — in flight while the next panel contracts and
+  retired in order, each sum stored into its rows of the result in place.
+  Over a pair the result is the single collective's bit for bit (a + b in
+  either order). Everything else — fewer than two panels, ``psum``, the
+  columnwise orientation, the XLA block loop — is one ``lax.psum_scatter``.
 
 ``DenseTransform._apply_dense`` (sketch/dense.py) sends every concrete
 operand that lies on more than one device here (:func:`apply_on_mesh`), so
 ``T.apply(A, dimension)`` is the entry point; :func:`rowwise` and
 :func:`columnwise` are thin callers of the same program for an operand the
 caller has not placed, with the result's sketch axis replicated. Memory a
-device: its operand shard, one partial (free extent × s), its result shard
-and the kernel's planes. A ragged N (the local extent no multiple of
+device: its operand shard, one partial (free extent × s; three panels'
+partials where the exchange is pipelined), its result shard and the kernel's
+planes. A ragged N (the local extent no multiple of
 ``BLOCK_COLS``) is zero-padded inside the program — exact, but XLA re-lays
 the operand for it.
 """
@@ -60,7 +74,7 @@ from libskylark_tpu.telemetry import trace as _trace
 _COLLECTIVE_BYTES = _metrics.counter(
     "sketch.mesh_collective_bytes",
     "bytes one device sends in the collectives of dense applies on a mesh, "
-    "by family and collective (psum_scatter | psum | none)")
+    "by family and collective (psum_scatter | ppermute_ring | psum | none)")
 
 
 def _spec_axes(spec: P, ndim: int = 2) -> tuple:
@@ -102,12 +116,39 @@ def _collective(s_dim: int, p: int, scatter: bool) -> str:
     return "psum_scatter" if scatter and s_dim % p == 0 else "psum"
 
 
+# Row tiles (``plan.m_tile`` rows each) a panel of the pipelined exchange
+# holds. On four v5e chips, 64 tiles of 2048 rows a device (PERF.md §6,
+# PR 56), device time an apply read 37.73 / 37.56 / 38.15 ms at 4 / 8 / 16
+# tiles a panel (43.20 with the single collective): a call of the
+# contraction costs ≈ 30 µs whatever its window, the last panel's transfer
+# stays exposed (0.3 / 0.6 / 1.2 ms), and each panel's kernel is 2.6 MB of
+# code on the device.
+_PANEL_TILES = 8
+
+
+def _panels(plan, rows: int, s_dim: int, seq_axis: int,
+            collective: str) -> int:
+    """Row panels the reduce-scatter is pipelined over, from what the
+    program can observe; 1 is the single collective. Pipelined: the rowwise
+    kernel's "hbm" contraction (the planes made once, a row window a call,
+    the sketch axis in one tile) over ``rows`` local rows that are whole row
+    tiles and at least two panels, reduce-scattered."""
+    if (plan is None or seq_axis != 1 or collective != "psum_scatter"
+            or plan.operator_residency != "hbm" or plan.s_tile != s_dim
+            or rows % plan.m_tile):
+        return 1
+    tiles = rows // plan.m_tile
+    return -(-tiles // _PANEL_TILES) if tiles >= 2 * _PANEL_TILES else 1
+
+
 def collective_bytes(collective: str, p: int, part_bytes: int) -> int:
     """Bytes one device sends in the apply's collective, from the shapes: a
-    reduce-scatter over ``p`` devices sends (p − 1)/p of its partial, an
-    all-reduce (reduce-scatter + all-gather) twice that."""
+    reduce-scatter over ``p`` devices — the one op, or the ring of
+    ``ppermute``s a panel — sends (p − 1)/p of its partial, an all-reduce
+    (reduce-scatter + all-gather) twice that."""
     sent = part_bytes * (p - 1) // p
-    return {"none": 0, "psum_scatter": sent, "psum": 2 * sent}[collective]
+    return {"none": 0, "psum_scatter": sent, "ppermute_ring": sent,
+            "psum": 2 * sent}[collective]
 
 
 def dense_mesh(key_data, A, *, mesh: Mesh, spec: tuple, seq_axis: int,
@@ -132,6 +173,52 @@ def dense_mesh(key_data, A, *, mesh: Mesh, spec: tuple, seq_axis: int,
         A = jnp.pad(A, pads)
     blocks = pad_n // p // BLOCK_COLS
     collective = _collective(s_dim, p, scatter)
+    panels = _panels(plan, A.shape[0] // _extent(mesh, spec[0]), s_dim,
+                     seq_axis, collective)
+
+    def pipelined(keys, A_loc):
+        """The reduce-scatter behind the contraction, a row panel at a
+        time: panel q's partial leaves on a ring of p − 1 ``ppermute``s —
+        at step t a device hands what it has summed of the sketch-axis
+        chunk of the device t + 1 places ahead to its neighbour behind and
+        adds its own share of the next chunk to what arrives, so the last
+        sum is its own chunk, whole: the chunks of
+        ``psum_scatter(tiled=True)`` — while panel q + 1 contracts."""
+        me = lax.axis_index(seq_axes)
+        width = s_dim // p
+        ring = [(d, (d - 1) % p) for d in range(p)]
+        # the rows of S rolled so that the kernel's chunk t is the ring's
+        # step t: the contraction stores what leaves apart from what stays,
+        # and no pass over a partial picks a chunk out
+        planes = [jnp.roll(plane, -(me + 1) * width, axis=0)
+                  for plane in pd.partial_planes(keys, scale, dist=dist,
+                                                 s_dim=s_dim, plan=plan)]
+        rows = _PANEL_TILES * plan.m_tile
+        # every row is stored below, a panel's at a time: no fill
+        out = pd.unwritten((A_loc.shape[0], width), jnp.float32,
+                           plan.interpret)
+        sums = []
+
+        def stored(out, q):
+            return lax.dynamic_update_slice_in_dim(out, sums[q], q * rows,
+                                                   axis=0)
+
+        for q in range(panels):
+            if q >= 2:
+                # retired in order: panel q − 2's sum lands in its rows of
+                # the result before panel q contracts, so one transfer
+                # stands behind the last contraction and no more than three
+                # partials are alive
+                planes, out = lax.optimization_barrier(
+                    (planes, stored(out, q - 2)))
+            chunks = pd.window_partial(
+                A_loc, planes, q * _PANEL_TILES, scale, plan=plan, chunks=p,
+                count=min(rows, A_loc.shape[0] - q * rows) // plan.m_tile)
+            acc = chunks[0]
+            for mine in chunks[1:]:
+                acc = lax.ppermute(acc, seq_axes, ring) + mine
+            sums.append(acc)
+        return stored(stored(out, panels - 2), panels - 1)
 
     def local(key_data, A_loc):
         # this device's column blocks of S: its position along the axes
@@ -140,6 +227,8 @@ def dense_mesh(key_data, A, *, mesh: Mesh, spec: tuple, seq_axis: int,
         if plan is not None:
             keys = lax.dynamic_slice_in_dim(
                 pd._block_key_table(key_data, pad_n), first, blocks)
+            if panels > 1:
+                return pipelined(keys, A_loc)
             part = pd.fused_partial(keys, dist, A_loc, s_dim,
                                     seq_axis=seq_axis, plan=plan, scale=scale)
         else:
@@ -227,6 +316,9 @@ def _span_attrs(T, local: tuple, dtype, mesh: Mesh, spec: tuple,
     seq_axes = spec[seq_axis]
     p = _extent(mesh, seq_axes)
     collective = _collective(T.sketch_dim, p, True)
+    panels = _panels(plan, local[0], T.sketch_dim, seq_axis, collective)
+    if panels > 1:
+        collective = "ppermute_ring"
     part_bytes = (local[1 - seq_axis] * T.sketch_dim
                   * jnp.dtype(jnp.float32 if plan is not None
                               else dtype).itemsize)
@@ -237,6 +329,7 @@ def _span_attrs(T, local: tuple, dtype, mesh: Mesh, spec: tuple,
         "orientation": "rowwise" if seq_axis else "columnwise",
         "local_shape": local, "kernel": "xla_blocks",
         "collective": collective, "reduce_over": seq_axes,
+        "exchange": "pipelined" if panels > 1 else "single", "panels": panels,
         "collective_bytes": collective_bytes(collective, p, part_bytes),
     }
     if plan is not None:

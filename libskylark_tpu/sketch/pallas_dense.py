@@ -1323,6 +1323,90 @@ def effective_plan(dist, shape, dtype, s_dim: int, seq_axis: int,
 
 
 # ---------------------------------------------------------------------------
+# a row window of the "hbm" contraction: the mesh program's panels
+# ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.jit, static_argnames=("dist", "s_dim", "plan"))
+def partial_planes(keys, scale=None, *, dist, s_dim: int, plan: Plan) -> list:
+    """The planes of S an "hbm" rowwise apply under ``plan`` contracts
+    with (:func:`_operator_planes`, ``scale`` folded in as there), for a
+    caller that makes them once and contracts row windows against them
+    (:func:`window_partial`)."""
+    return _operator_planes(keys, scale, s_dim=s_dim,
+                            dist_kind=_DIST_KINDS[type(dist)],
+                            precision=plan.precision, s_tile=plan.s_tile,
+                            interpret=plan.interpret)
+
+
+def unwritten(shape: tuple, dtype, interpret: bool = False) -> jnp.ndarray:
+    """An array of ``shape`` that nothing has written (a call with no
+    operand and an empty body: the buffer as the allocator hands it), for a
+    caller that stores every entry itself (``dynamic_update_slice`` by
+    panels) and has no use for a fill pass."""
+    return pl.pallas_call(
+        lambda out_ref: None, out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY), interpret=interpret,
+        name="unwritten")()
+
+
+@functools.partial(jax.jit, static_argnames=("count", "chunks", "plan"))
+def window_partial(A, planes, first, scale=None, *, count: int,
+                   chunks: int = 1, plan: Plan) -> list:
+    """Row tiles [first, first + count) of A·Sᵀ — the rowwise
+    contraction of :func:`_planes_call` on a window of its row grid: the
+    WHOLE padded A (m, n) is the operand and the A tile's index map reads
+    ``(i + first, k)``, so no slice of A stands in front of the call; the
+    result is the window's (count · m_tile, s_dim), handed back as
+    ``chunks`` arrays of s_dim / chunks columns each, in order (the plan
+    must not tile s). ``first`` is a prefetched scalar and no static:
+    every window of an apply is one traced function and one kernel body.
+    Tile for tile the steps, their order and their bits are the whole
+    call's."""
+    m_tile, precision = plan.m_tile, plan.precision
+    n = A.shape[1]
+    s_dim = planes[0].shape[0]
+    if plan.s_tile != s_dim or s_dim % chunks:
+        raise ValueError(f"a window in {chunks} chunks needs the sketch axis "
+                         f"in one tile that they divide: s_dim {s_dim}, "
+                         f"s_tile {plan.s_tile}")
+    width = s_dim // chunks
+    k_cols, vmem_limit = _contraction(n, m_tile, s_dim, False, False)
+    n_blocks = n // k_cols
+    tile_scaled = _tile_scaled(precision, scale)
+    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    operands, in_specs = [], []
+    if tile_scaled:
+        operands.append(jnp.asarray(scale, jnp.float32).reshape(1))
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+    operands += [A, *planes]
+    in_specs += [vmem((m_tile, k_cols), lambda i, k, f: (i + f[0], k))] + [
+        vmem((s_dim, k_cols), lambda i, k, f: (0, k)) for _ in planes]
+
+    def kernel(first_ref, *refs):
+        del first_ref       # read by the A tile's index map alone
+        refs = list(refs)
+        finish = _finisher(refs.pop(0) if tile_scaled else None, None, ())
+        a_ref, plane_refs = refs[0], refs[1:1 + len(planes)]
+        acc = _dot_planes(a_ref[:], [p[:] for p in plane_refs], precision)
+        for c, out_ref in enumerate(refs[1 + len(planes):]):
+            _store(out_ref, acc[:, c * width:(c + 1) * width],
+                   pl.program_id(1), n_blocks, finish)
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(count, n_blocks), in_specs=in_specs,
+            out_specs=[vmem((m_tile, width), lambda i, k, f: (i, 0))
+                       for _ in range(chunks)]),
+        out_shape=[jax.ShapeDtypeStruct((count * m_tile, width), jnp.float32)
+                   for _ in range(chunks)],
+        compiler_params=_contraction_params(2, vmem_limit),
+        interpret=plan.interpret,
+    )(jnp.asarray(first, jnp.int32).reshape(1), *operands)
+
+
+# ---------------------------------------------------------------------------
 # batched (microbatch-flush) launchers: one kernel over a stacked cohort
 # ---------------------------------------------------------------------------
 #

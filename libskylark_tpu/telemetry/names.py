@@ -81,8 +81,8 @@ METRICS: Dict[str, str] = {
     # the dense apply of an operand on a mesh (parallel/shard_apply.py, the
     # sketch.dense_mesh program): bytes ONE device sends in the apply's
     # collective, reckoned from the shapes, by family and collective
-    # ("psum_scatter" | "psum" | "none") — the cross-check of the
-    # collective_bytes that collective_rate.apply reads from the
+    # ("psum_scatter" | "ppermute_ring" | "psum" | "none") — the cross-check
+    # of the collective_bytes that collective_rate.apply reads from the
     # sketch.dispatch spans with path="mesh"
     "sketch.mesh_collective_bytes": "counter",
     # accesses of an allocation's key material (base/context.py), by
@@ -221,9 +221,14 @@ SPANS: Dict[str, Tuple[str, str]] = {
     # kernel ("pallas_planes" | "pallas_generate" = the one-chip kernels of
     # the device's plan | "xla_blocks" = the fori_loop over generated blocks)
     # and, on a kernel, operator_residency, m_tile and precision, collective
-    # ("psum_scatter" | "psum" | "none"), reduce_over (the mesh axes that
-    # shard the contracted axis) and collective_bytes (what one device sends
-    # in that collective, from the shapes: collective_rate.apply reads it);
+    # ("psum_scatter" | "ppermute_ring" | "psum" | "none"), reduce_over (the
+    # mesh axes that shard the contracted axis) and collective_bytes (what one
+    # device sends in that collective, from the shapes: collective_rate.apply
+    # reads it); since PR 56 exchange ("pipelined": the reduce-scatter as a
+    # ring of ppermutes a row panel, in flight behind the next panel's
+    # contraction — collective then reads "ppermute_ring" and the same bytes
+    # travel | "single": one collective after the contraction) and panels
+    # (the row panels; 1 where single);
     # its handover is the engine.execute inside it
     # a fused dense apply notes its plan on sketch.apply
     # (pallas_dense._plan): path="pallas", m_tile, s_tile, precision,

@@ -312,11 +312,13 @@ def test_span_and_counter_carry_the_collective_bytes(grid):
     assert shard_apply._COLLECTIVE_BYTES.value(**labels) - counted == sent
     assert {k: attrs[k] for k in (
         "path", "route", "family", "grid", "spec", "orientation",
-        "local_shape", "kernel", "collective", "reduce_over")} == {
+        "local_shape", "kernel", "collective", "reduce_over", "exchange",
+        "panels")} == {
         "path": "mesh", "route": "program", "family": "JLT", "grid": "2x2",
         "spec": "PartitionSpec('rows', 'cols')", "orientation": "rowwise",
         "local_shape": (m // 2, n // 2), "kernel": "xla_blocks",
-        "collective": "psum_scatter", "reduce_over": ("cols",)}
+        "collective": "psum_scatter", "reduce_over": ("cols",),
+        "exchange": "single", "panels": 1}
     (root,) = [sp for sp in spans if sp.name == "sketch.apply"]
     assert root.attrs["path"] == "mesh"
     # one handover an apply: the engine.execute inside the dispatch span
@@ -325,6 +327,169 @@ def test_span_and_counter_carry_the_collective_bytes(grid):
 
 @pytest.mark.parametrize("collective,p,sent", [
     ("none", 1, 0), ("psum_scatter", 2, 512), ("psum", 2, 1024),
-    ("psum_scatter", 4, 768), ("psum", 4, 1536)])
+    ("psum_scatter", 4, 768), ("psum", 4, 1536), ("ppermute_ring", 2, 512),
+    ("ppermute_ring", 4, 768)])
 def test_collective_bytes_from_the_shapes(collective, p, sent):
     assert shard_apply.collective_bytes(collective, p, 1024) == sent
+
+
+# -- (8) the exchange behind the contraction: row panels, a ring of ppermutes --
+
+M_TILE = 8      # the interpreted kernel's row tile in these tests
+
+
+@pytest.fixture()
+def interpreted(monkeypatch):
+    """The mesh route under the interpreted kernel at ``M_TILE`` rows a tile
+    and the "hbm" residency (a zero scratch cap, as tests/test_pallas_dense.py
+    ``force_hbm``): what the dispatch plans on a TPU, driven on the CPU mesh."""
+    from libskylark_tpu.sketch import pallas_dense as pd
+    from libskylark_tpu.sketch import params
+
+    monkeypatch.setattr(pd, "_SCRATCH_CAP_BYTES", 0)
+    plan = shard_apply._kernel_plan
+    monkeypatch.setattr(
+        shard_apply, "_kernel_plan",
+        lambda T, local, dtype, seq_axis, use_pallas, interpret: plan(
+            T, local, dtype, seq_axis, True, True))
+    before = params.get_pallas_m_tile()
+    params.set_pallas_m_tile(M_TILE)
+    yield
+    params.set_pallas_m_tile(before)
+
+
+def _laid(request, layout: str, shape, seed: int):
+    """``(A_host, A)`` with the operand [MC,MR] over the 2 × 2 grid (the
+    contracted axis over a pair: p = 2) or its contracted axis over the line
+    of four (p = 4)."""
+    A_host = _operand(shape, seed=seed)
+    if layout == "grid":
+        sharding = par.grid2d(request.getfixturevalue("grid"))
+    else:
+        sharding = NamedSharding(request.getfixturevalue("line"),
+                                 P(None, "rows"))
+    return A_host, par.distribute(A_host, sharding)
+
+
+def _single(monkeypatch):
+    """The parent's program: no local extent holds two panels."""
+    monkeypatch.setattr(shard_apply, "_PANEL_TILES", 1 << 30)
+
+
+@pytest.mark.parametrize("tiles", [16, 19], ids=["whole_panels", "short_last"])
+@pytest.mark.parametrize("layout", ["grid", "line"], ids=["p2", "p4"])
+def test_pipelined_equals_single(request, monkeypatch, interpreted, layout,
+                                 tiles):
+    """Row panels, each panel's chunks on a ring of ppermutes, against the
+    one psum_scatter: the same bits over a pair (a + b in either order), the
+    file's tolerance over four (the ring adds in another order)."""
+    n, s = 1536, 64
+    rows = tiles * M_TILE           # a device's free extent
+    T = _transform("JLT", n, s)
+    A_host, A = _laid(request, layout, (2 * rows if layout == "grid" else rows,
+                                        n), seed=11)
+    statics, attrs = shard_apply.route(T, A, 1)
+    p = 2 if layout == "grid" else 4
+    assert (attrs["exchange"], attrs["panels"], attrs["collective"]) == (
+        "pipelined", -(-tiles // shard_apply._PANEL_TILES), "ppermute_ring")
+    assert attrs["collective_bytes"] == rows * s * 4 * (p - 1) // p
+    kd = T.allocation.key_data
+    program = jax.jit(functools.partial(shard_apply.dense_mesh, **statics))
+    pipelined = program(kd, A)
+    _single(monkeypatch)
+    assert shard_apply.route(T, A, 1)[1]["exchange"] == "single"
+    single = jax.jit(functools.partial(shard_apply.dense_mesh, **statics))(kd, A)
+    assert pipelined.sharding == single.sharding
+    if p == 2:
+        assert np.array_equal(np.asarray(pipelined), np.asarray(single))
+    else:
+        np.testing.assert_allclose(
+            np.asarray(pipelined), np.asarray(single),
+            atol=1e-5 * np.abs(np.asarray(single)).max())
+    _close(pipelined, _expected(T, A_host, True), tol=1e-4)
+
+
+@pytest.mark.parametrize("case", [
+    "one_panel", "s_not_divisible", "columnwise", "xla_blocks",
+    "replicated_result", "tracer"])
+def test_what_keeps_the_single_collective(grid, monkeypatch, interpreted,
+                                          case):
+    """Fewer than two panels in the local free extent, a sketch width the
+    pair does not divide, the columnwise orientation, the XLA block loop, the
+    thin callers' replicated result and a caller's trace keep the parent's
+    program: no collective-permute in what is lowered."""
+    n, s, m = 1536, 64, 2 * 16 * M_TILE
+    rowwise = case != "columnwise"
+    if case == "one_panel":
+        m = 2 * 15 * M_TILE
+    if case == "s_not_divisible":
+        s = 63
+    T = _transform("JLT", n, s)
+    A_host = _operand((m, n) if rowwise else (n, m), seed=12)
+    if case in ("replicated_result", "tracer"):
+        line = par.make_mesh((2,), devices=list(grid.devices.flat)[:2])
+        call = functools.partial(shard_apply.rowwise, T, mesh=line,
+                                 use_pallas=True, interpret=True)
+        text = jax.jit(lambda X: call(X)).lower(A_host).as_text()
+        if case == "replicated_result":
+            _close(call(A_host), _expected(T, A_host, True), tol=1e-4)
+    else:
+        A = par.distribute(A_host, par.grid2d(grid))
+        if case == "xla_blocks":
+            monkeypatch.setattr(shard_apply, "_kernel_plan",
+                                lambda *a, **k: None)
+        statics, attrs = shard_apply.route(T, A, 1 if rowwise else 0)
+        assert (attrs["exchange"], attrs["panels"]) == ("single", 1)
+        assert attrs["collective"] == (
+            "psum" if case == "s_not_divisible" else "psum_scatter")
+        assert (statics["plan"] is None) == (case == "xla_blocks")
+        text = jax.jit(functools.partial(
+            shard_apply.dense_mesh, **statics)).lower(
+            T.allocation.key_data, A).as_text()
+    assert "collective_permute" not in text
+    assert ("all_reduce" in text) != ("reduce_scatter" in text)
+
+
+def test_span_and_counter_of_the_pipelined_route(grid, interpreted):
+    n, s, rows = 1536, 64, 17 * M_TILE
+    T = _transform("JLT", n, s)
+    A_host = _operand((2 * rows, n), seed=13)
+    A = par.distribute(A_host, par.grid2d(grid))
+    T.apply(A, sk.ROWWISE)                          # compiled ahead of the span
+    labels = dict(family="JLT", collective="ppermute_ring")
+    counted = shard_apply._COLLECTIVE_BYTES.value(**labels)
+    out, spans = _spans_of(lambda: T.apply(A, sk.ROWWISE))
+    _close(out, _expected(T, A_host, True), tol=1e-4)
+    assert _spec(out) == (("rows",), ("cols",))
+    (dispatch,) = [sp for sp in spans if sp.name == "sketch.dispatch"]
+    attrs = dispatch.attrs
+    # the same halves travel: half of a device's (rows × s) float32 partial
+    sent = rows * s * 4 // 2
+    assert shard_apply._COLLECTIVE_BYTES.value(**labels) - counted == sent
+    assert {k: attrs[k] for k in (
+        "kernel", "operator_residency", "m_tile", "collective", "exchange",
+        "panels", "collective_bytes", "reduce_over")} == {
+        "kernel": "pallas_planes", "operator_residency": "hbm",
+        "m_tile": M_TILE, "collective": "ppermute_ring",
+        "exchange": "pipelined", "panels": 3, "collective_bytes": sent,
+        "reduce_over": ("cols",)}
+    assert len([sp for sp in spans if sp.name == "engine.execute"]) == 1
+
+
+def test_hlo_of_the_pipelined_route_permutes_and_never_gathers(grid,
+                                                               interpreted):
+    n, s, rows = 1536, 64, 16 * M_TILE
+    T = _transform("JLT", n, s)
+    A = jax.ShapeDtypeStruct((2 * rows, n), jnp.float32,
+                             sharding=par.grid2d(grid))
+    statics = shard_apply._statics(T, A, grid, (("rows",), ("cols",)), 1,
+                                   None, False, True)[1]
+    text = jax.jit(functools.partial(shard_apply.dense_mesh, **statics)).lower(
+        jax.ShapeDtypeStruct((2,), jnp.uint32), A).compile().as_text()
+    assert len(re.findall(r"\bcollective-permute(-start)?\(", text)) == 2
+    assert not re.search(r"\b(reduce-scatter|all-reduce)(-start)?\(", text)
+    operand_shard = rows * (n // 2)
+    for match in re.finditer(
+            r"=\s*\(?\w+\[([\d,]*)\][^=\n]*\ball-gather(-start)?\(", text):
+        dims = [int(d) for d in match.group(1).split(",") if d]
+        assert int(np.prod(dims)) < operand_shard, match.group(0)

@@ -1005,3 +1005,89 @@ def test_fused_on_chip_hbm_residency_at_the_columnwise_cell_shape():
                                atol=1e-4 * float(np.abs(want).max()))
     second = pd.columnwise_apply(jlt._alloc.key, jlt.dist, A, s, jlt.scale)
     assert bool(jnp.array_equal(first, second))
+
+
+# ---------------------------------------------------------------------------
+# the contraction's row window (the mesh program's panels, PR 56)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("chunks", [1, 2, 4])
+@pytest.mark.parametrize("window", [(0, 6), (0, 2), (2, 3), (5, 1)],
+                         ids=lambda w: f"first{w[0]}_count{w[1]}")
+@pytest.mark.parametrize("precision", ["bf16x3", "f32", "bf16gen2"])
+def test_window_partial_is_those_rows_of_the_whole_call(precision, window,
+                                                        chunks, force_hbm):
+    """A window (first, count) of the row grid on the WHOLE operand, the
+    planes made once: those row tiles of ``_fused_call``'s result, bit
+    for bit, whole or as column chunks — the scale in the planes or (the
+    "bf16gen2" regime) on each tile, as there."""
+    m_tile, tiles, n, s = 8, 6, 1024, 64
+    dist = randgen.Normal()
+    scale = 1.0 / np.sqrt(s)
+    keys = pd._block_keys(Context(seed=56).allocate().key, n)
+    A = jnp.asarray(np.random.default_rng(56).standard_normal(
+        (tiles * m_tile, n)), jnp.float32)
+    _assert_hbm(dist, A.shape, s, m_tile)
+    whole = pd._fused_call(A, keys, scale, s_dim=s, dist_kind="normal",
+                           m_tile=m_tile, precision=precision,
+                           interpret=True)
+    plan = pd.Plan(m_tile, s, precision, "hbm", True)
+    planes = pd.partial_planes(keys, scale, dist=dist, s_dim=s, plan=plan)
+    first, count = window
+    parts = pd.window_partial(A, planes, first, scale, count=count,
+                              chunks=chunks, plan=plan)
+    assert [p.shape for p in parts] == [(count * m_tile, s // chunks)] * chunks
+    rows = slice(first * m_tile, (first + count) * m_tile)
+    assert np.array_equal(np.concatenate([np.asarray(p) for p in parts], 1),
+                          np.asarray(whole)[rows])
+
+
+# sha256 (first 20 hex digits) of the traced program of each one-chip dense
+# cell's fused call — ``call.trace(...).jaxpr.pretty_print(source_info=True)``
+# with the checkout's root taken out: every equation with the file, line and
+# column that traced it. PR 56's parent (e0ee881) and PR 56 read the same.
+_CELL_PROGRAMS = {
+    "jlt_apply": "ca904f65d43bed5e2e3d",
+    "jlt_apply_cw": "675c9bf370bcb19be5ef",
+    "rft_features_apply": "1cc2f980489afa9359ae",
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_PROGRAMS))
+def test_one_chip_cells_trace_the_program_they_did(cell):
+    """The three one-chip dense cells' calls at the cells' shapes and
+    plans trace to what they traced at PR 56's parent, equation for
+    equation and line for line: a compiled program carries the lines
+    that traced it, so code added to sketch/pallas_dense.py goes below
+    its marked section (or into a sibling, as ``window_partial``). A PR
+    that means to change one of these programs updates the digest, and
+    says so."""
+    import hashlib
+    import os
+
+    import libskylark_tpu
+
+    def shaped(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    words = shaped(2, dtype=jnp.uint32)
+    call, args, statics = {
+        "jlt_apply": (pd._fused_call, (shaped(*CELL), words, shaped()),
+                      dict(s_dim=1024, m_tile=2048, s_tile=1024)),
+        "jlt_apply_cw": (pd._fused_call_cw,
+                         (shaped(*CELL_CW), words, shaped()),
+                         dict(s_dim=1024, m_tile=2048)),
+        "rft_features_apply": (
+            pd._fused_call_cos,
+            (shaped(32768, 440), words, shaped(1, 16384), shaped(1, 16384)),
+            dict(s_dim=16384, m_tile=512, s_tile=1024, inscale=0.5,
+                 outscale=0.25)),
+    }[cell]
+    text = call.trace(*args, dist_kind="normal", precision="bf16x3",
+                      **statics).jaxpr.pretty_print(source_info=True)
+    root = os.path.dirname(os.path.dirname(
+        os.path.abspath(libskylark_tpu.__file__)))
+    assert root in text
+    digest = hashlib.sha256(text.replace(root, "<root>").encode())
+    assert digest.hexdigest()[:20] == _CELL_PROGRAMS[cell]

@@ -846,51 +846,111 @@ def _collectives(text: str) -> dict:
                        "collective-permute", "all-to-all")}
 
 
+def _schedule(text: str) -> list:
+    """``[(name, opcode, operands)]`` of the entry computation of a compiled
+    (scheduled) module, in the order the device runs them."""
+    entry = text[text.index("\nENTRY "):]
+    return re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = .*? ([\w\-]+)\((.*)$",
+                      entry, re.M)
+
+
+def _ring(schedule: list) -> list:
+    """``[(start, done)]`` positions of the schedule's collective-permutes,
+    in the order their ``start``s are issued."""
+    where = {name: i for i, (name, _, _) in enumerate(schedule)}
+    pairs = []
+    for i, (_, opcode, operands) in enumerate(schedule):
+        if opcode == "collective-permute-done":
+            start = re.match(r"[^%]*%([\w.\-]+)", operands).group(1)
+            pairs.append((where[start], i))
+    return sorted(pairs)
+
+
 def test_cell_shape_mesh_program(grid2x2, vmem_cap):
     """262144 × 16384 laid [MC,MR] over 2 × 2 → 1024: a device's local
-    problem is two ``jlt_apply`` panels under the cell's own plan (the
-    generation and the contraction call, "hbm", 2048 rows a tile); one
-    reduce-scatter over the pair that shares a grid row, nothing gathered;
-    a device holds its operand shard, one partial and its result shard."""
+    problem is two ``jlt_apply`` panels under the cell's own plan ("hbm",
+    2048 rows a tile), contracted in eight row panels against planes made
+    once. The off-chip witness of the overlap, in the scheduled module:
+    panel q's half leaves by a ``collective-permute-start`` after contraction
+    q and before contraction q + 1, its ``done`` (and the add into the
+    result's rows) after contraction q + 1 and before contraction q + 2, and
+    only the last panel's ``start`` stands behind the last contraction. No
+    reduce-scatter, nothing gathered, no copy of the operand; a device holds
+    its operand shard, less than one partial of temporaries and its result
+    shard."""
     from jax.sharding import PartitionSpec as P
+
+    from libskylark_tpu.parallel import shard_apply
 
     local, plan, compiled = _mesh_program(
         grid2x2, vmem_cap, (MESH_M, MESH_N), P("rows", "cols"), 1, MESH_S)
     assert local == (2 * ROWS, N)
     assert (plan["operator_residency"], plan["m_tile"], plan["k_cols"],
             plan["precision"]) == ("hbm", 2048, 2 * BLOCK_COLS, "bf16x3")
+    k = local[0] // plan["m_tile"] // shard_apply._PANEL_TILES
+    assert k == 8
     text = compiled.as_text()
-    assert text.count(KERNEL) == 2
-    assert _collectives(text) == {"reduce-scatter": 1, "all-reduce": 0,
-                                  "all-gather": 0, "collective-permute": 0,
+    assert _collectives(text) == {"reduce-scatter": 0, "all-reduce": 0,
+                                  "all-gather": 0, "collective-permute": k,
                                   "all-to-all": 0}
-    assert "replica_groups={{0,1},{2,3}}" in text
+    assert "source_target_pairs={{0,1},{1,0},{2,3},{3,2}}" in text
+    schedule = _schedule(text)
+    kernels = [(name.split(".")[0], i)
+               for i, (name, opcode, operands) in enumerate(schedule)
+               if opcode == "custom-call" and KERNEL in operands]
+    # the planes once, the result's buffer (an empty body), k contractions
+    assert sorted(name for name, _ in kernels) == (
+        ["partial_planes", "unwritten"] + ["window_partial"] * k)
+    contractions = [i for name, i in kernels if name == "window_partial"]
+    ring = _ring(schedule)
+    assert len(ring) == k
+    for q, (start, done) in enumerate(ring):
+        assert contractions[q] < start
+        if q + 1 < k:
+            assert start < contractions[q + 1] < done
+        if q + 2 < k:
+            assert done < contractions[q + 2]       # retired in order
+    assert sum(start > contractions[-1] for start, _ in ring) == 1
+    # the operand is read where it lies: nothing makes an f32[…, 8192] of it
+    made = {opcode for rows, opcode in re.findall(
+        rf"= f32\[(\d+),{N}\]\S* ([\w\-]+)\(", text)
+        if int(rows) >= plan["m_tile"]}
+    assert made == {"parameter"}, made
     memory = compiled.memory_analysis()
     shard, partial, result = (4 * local[0] * local[1], 4 * local[0] * MESH_S,
                               4 * local[0] * MESH_S // 2)
     assert shard <= memory.argument_size_in_bytes <= shard + 4096
     assert memory.output_size_in_bytes == result
-    # the partial and (inside it or beside it) the operator's planes
-    assert partial <= memory.temp_size_in_bytes < 1.2e9
+    # the planes and three panels' partials: less than the parent's one
+    # whole partial (0.537 GB)
+    assert 2 * 2 * MESH_S * N <= memory.temp_size_in_bytes < partial
     (out,) = jax.tree.leaves(compiled.output_shardings)
     assert out.spec == P("rows", "cols")
 
 
-@pytest.mark.parametrize("spec,seq_axis,want", [
-    (("rows", None), 1, "none"), ((None, ("rows", "cols")), 1, "reduce-scatter"),
-    (("rows", "cols"), 0, "reduce-scatter")],
-    ids=["row_sharded", "col_sharded_all_axes", "columnwise"])
-def test_other_layouts_mesh_program(grid2x2, vmem_cap, spec, seq_axis, want):
-    """The same program where the contracted axis is whole on a device (no
-    collective), over all four chips (one reduce-scatter over the four) and
-    columnwise (the mirror image), at a sixteenth of the cell's rows."""
+@pytest.mark.parametrize("spec,seq_axis,rows,want", [
+    (("rows", None), 1, MESH_M // 16, {}),
+    ((None, ("rows", "cols")), 1, MESH_M // 16, {"reduce-scatter": 1}),
+    (("rows", "cols"), 0, MESH_M // 16, {"reduce-scatter": 1}),
+    (("rows", "cols"), 1, MESH_M // 8, {"reduce-scatter": 1}),
+    ((None, ("rows", "cols")), 1, MESH_M // 4, {"collective-permute": 12})],
+    ids=["row_sharded", "col_sharded_all_axes", "columnwise", "one_panel",
+         "ring_of_four"])
+def test_other_layouts_mesh_program(grid2x2, vmem_cap, spec, seq_axis, rows,
+                                    want):
+    """Which layouts keep the parent's single collective and which pipeline.
+    The parent's programs: the contracted axis whole on a device (no
+    collective); over all four chips at eight row tiles a device and [MC,MR]
+    at eight (fewer than two panels: one reduce-scatter); columnwise (the
+    mirror image, not pipelined). Over all four chips with thirty-two row
+    tiles a device the exchange is the ring of three steps a panel, four
+    panels: twelve collective-permutes."""
     from jax.sharding import PartitionSpec as P
 
-    shape = (MESH_M // 16, MESH_N) if seq_axis else (MESH_N, MESH_M // 16)
+    shape = (rows, MESH_N) if seq_axis else (MESH_N, rows)
     _, plan, compiled = _mesh_program(grid2x2, vmem_cap, shape, P(*spec),
                                       seq_axis, MESH_S)
     assert plan["kernel"]
     found = _collectives(compiled.as_text())
-    assert found.pop("reduce-scatter") == (want == "reduce-scatter")
-    assert not any(found.values()), found
+    assert {op: n for op, n in found.items() if n} == want
     assert compiled.as_text().count(KERNEL) >= 1
