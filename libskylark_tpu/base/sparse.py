@@ -8,16 +8,33 @@ read-only column views (ref: view:256).
 On the device a matrix is placed once per value dtype, as row-major CSR
 lanes (:meth:`SparseMatrix.csr_device`: data, column ids, row pointers,
 the nnz extent zero-padded to its ``engine.bucket.lane_class``) — what the
-compiled sparse hash sketch scatters from — or as the row-major COO triplets
-(:meth:`SparseMatrix.coo`) the sparse×dense products contract over
-(``segment_sum`` over the nonzeros, the XLA-friendly formulation of the
-reference's CSC scatter loops, ref: base/Gemm.hpp:335-519), the row ids
-expanded on the device. All nnz-shaped arrays have static shapes, so
+compiled sparse hash sketch and :func:`spmm` walk —, as those lanes
+regrouped by (row block, column tile) for the sparse × dense kernel
+(:meth:`SparseMatrix.tiled_device`, under a ``sparse.place`` span), or as
+the row-major COO triplets (:meth:`SparseMatrix.coo`) that :func:`spmm_t`
+contracts over, the row ids expanded on the device.
+
+The products (ref: base/Gemm.hpp:335-519). :func:`spmm` (A·B) is one
+compiled program (``sparse.spmm``) whose workspace does not grow with
+nnz × k: on a TPU, where ``sketch.sparse_serve.product_kernel`` finds the
+shapes, the Pallas walk of ``sketch/pallas_spmm.py`` over the regrouped
+lanes (VMEM blocks only); anywhere else a loop over spans of
+``_SPAN_LANES`` CSR lanes, each span one gather of its rows of B, one
+multiply and one scatter-add into the rows the span touches
+(``_SPAN_LANES`` × k values at a time; before PR 57 the whole nnz × k
+gather was one array, 79 GB at 19.4 M nonzeros × 1024).
+``DenseTransform.apply`` on a sparse operand, rowwise, is the same program
+body behind an operator generated in the program. :func:`spmm_t` (Aᵀ·B)
+keeps ``segment_sum(v[:, None] * B[r], c)`` over all nonzeros at once: its
+nnz × k temporary stands, and an operand of the size :func:`spmm` now
+serves does not fit it. All nnz-shaped arrays have static shapes, so the
 products are jittable.
 """
 
 from __future__ import annotations
 
+import functools
+import time
 from typing import Tuple
 
 import jax
@@ -55,7 +72,8 @@ class SparseMatrix:
         if len(self._rowind) != len(self._values):
             raise errors.InvalidParametersError("rowind/values length mismatch")
         # device-resident layouts by value dtype: {"csr": the placed
-        # lanes, "coo": the triplets derived from them on first coo()}
+        # lanes, "coo": the triplets derived from them on first coo(),
+        # ("tiled", layout): the lanes regrouped for the product kernel}
         self._device: dict = {}
         # the canonical scipy CSR this was attached from, when it was one
         self._row_major = None
@@ -206,6 +224,41 @@ class SparseMatrix:
             layouts["csr"] = self._place_lanes(eff, lane_class(self.nnz))
         return layouts["csr"]
 
+    def tiled_device(self, layout: tuple, dtype=None) -> Tuple[jax.Array, ...]:
+        """The lanes of :meth:`csr_device` regrouped for the sparse × dense
+        kernel (``sketch/pallas_spmm.py``, which says what the layout
+        means): ``layout`` = (row_block, col_tile, chunk, n_chunks) →
+        ``(segment, count, packed, vals)``, the two chunk tables
+        (n_chunks,) int32 and the slots (n_chunks, 1, chunk) int32 /
+        values. Regrouped on the host (:func:`_tile_lanes`: a stable sort
+        of the stored lanes by segment; a device sort of 19.9 M lanes
+        compiles for 40–86 s) and placed on the first call for a (dtype,
+        layout), under a ``sparse.place`` span that carries the bytes
+        placed and the seconds it took; kept like the lanes: later calls
+        move nothing."""
+        eff = self._device_dtype_of(dtype)
+        layouts = self._device.setdefault(eff, {})
+        key = ("tiled",) + tuple(layout)
+        if key not in layouts:
+            from libskylark_tpu.telemetry import trace as _trace
+
+            row_block, col_tile, chunk, n_chunks = layout
+            with _trace.span("sparse.place", {"layout": "tiled",
+                                              "nnz": self.nnz}) as sp:
+                t0 = time.perf_counter()
+                placed = jax.block_until_ready(tuple(
+                    _place(a) for a in _tile_lanes(
+                        *self.csr_parts(eff), shape=self._shape,
+                        row_block=row_block, col_tile=col_tile, chunk=chunk,
+                        n_chunks=n_chunks)))
+                if sp is not None:
+                    sp.attrs.update(
+                        bytes=sum(int(a.nbytes) for a in placed),
+                        lane_slots=n_chunks * chunk,
+                        seconds=time.perf_counter() - t0)
+            layouts[key] = placed
+        return layouts[key]
+
     def coo(self, dtype=None) -> Tuple[jax.Array, jax.Array, jax.Array]:
         """Device COO triplets (rows, cols, vals) in row-major order;
         cached per resolved dtype. The row ids are expanded on the device
@@ -316,6 +369,59 @@ _row_ids = jax.jit(lambda indptr, *, nnz: csr_row_ids(indptr, nnz),
                    static_argnames=("nnz",))
 
 
+def _tile_lanes(data: np.ndarray, indices: np.ndarray, indptr: np.ndarray, *,
+                shape: Tuple[int, int], row_block: int, col_tile: int,
+                chunk: int, n_chunks: int) -> Tuple[np.ndarray, ...]:
+    """Host CSR parts → the (segment, count, packed, vals) of
+    :meth:`SparseMatrix.tiled_device`, numpy arrays: one stable sort of the
+    stored lanes by segment (a radix sort where the segment ids fit 16
+    bits) and one scatter into the chunk slots, a segment's ⌈stored ÷
+    chunk⌉ chunks full but its first, which holds the remainder.
+    ``n_chunks`` bounds the chunks of any operand of these extents; chunks
+    past the last live one repeat its segment with count 0, so the
+    kernel's pipeline fetches nothing for them."""
+    rows, n = int(shape[0]), int(shape[1])
+    col_tiles = -(-n // col_tile)
+    n_seg = -(-rows // row_block) * col_tiles
+    row = np.repeat(np.arange(rows, dtype=np.int32), np.diff(indptr))
+    seg = (row // row_block) * col_tiles + indices // col_tile
+    order = np.argsort(seg.astype(np.int16 if n_seg < 1 << 15 else np.int32),
+                       kind="stable")               # row-major in a segment
+    stored = np.bincount(seg, minlength=n_seg)
+    chunks = -(-stored // chunk)
+    chunks[::col_tiles] = np.maximum(chunks[::col_tiles], 1)   # a row block
+    chunk_end = np.cumsum(chunks)                               # owns a chunk
+    live = int(chunk_end[-1])
+    if live > n_chunks:
+        raise errors.InvalidParametersError(
+            f"tiled layout needs {live} chunks, the plan holds {n_chunks}")
+    of = np.repeat(np.arange(n_seg, dtype=np.int32), chunks)
+    # a segment's remainder goes into its FIRST chunk and the others are
+    # full: the walk of a segment's last chunk is what hides the copy of the
+    # next segment's tile of B (and, at a row block's end, the write of the
+    # result block), so the last chunk must be the long one — a short first
+    # chunk exposes nothing, its successor reuses both blocks (PERF.md
+    # PR 57: a nearly empty last chunk cost what a full one did, and a
+    # block whose last segment was short read 2.4 ms longer)
+    nth = np.arange(live) - (chunk_end - chunks)[of]        # chunk of its segment
+    segment = np.full(n_chunks, of[-1], np.int32)
+    segment[:live] = of
+    count = np.zeros(n_chunks, np.int32)
+    count[:live] = np.where(
+        nth == 0, stored[of] - (np.maximum(chunks[of], 1) - 1) * chunk, chunk)
+    # sorted lanes lie segment after segment and, dealt in order, chunk
+    # after chunk: a lane's slot is its chunk's first slot plus its place
+    first_lane = np.cumsum(count[:live]) - count[:live]
+    slot = np.arange(order.shape[0]) + np.repeat(
+        np.arange(live) * chunk - first_lane, count[:live])
+    packed = np.zeros(n_chunks * chunk, np.int32)
+    vals = np.zeros(n_chunks * chunk, data.dtype)
+    packed[slot] = (((row % row_block) << 16) | (indices % col_tile))[order]
+    vals[slot] = data[order]
+    return (segment, count, packed.reshape(n_chunks, 1, chunk),
+            vals.reshape(n_chunks, 1, chunk))
+
+
 def is_sparse_operand(A) -> bool:
     """True for the framework's sparse matrix kinds (local
     :class:`SparseMatrix` or mesh-distributed ``DistSparseMatrix``) —
@@ -326,16 +432,42 @@ def is_sparse_operand(A) -> bool:
 
 
 # The sparse products route through the engine's executable cache
-# (:mod:`libskylark_tpu.engine.compiled`): eagerly, every spmm call
-# re-dispatched a gather + multiply + segment_sum op-by-op — repeated
-# sparse products over the same shapes (ADMM sweeps, blocked sketch
-# loops, the serve layer's densify A/B) paid per-call op dispatch and
-# jax-level retracing instead of one cached executable. The wrappers
-# are built lazily (first product) so importing ``base.sparse`` never
-# pulls the engine, and keyed on the op name + avals (nnz and operand
-# shapes are static per call signature), so the jit-leak gate's
-# zero-recompile contract covers them.
+# (:mod:`libskylark_tpu.engine.compiled`): one cached executable per op
+# name, kernel, avals and shapes, built lazily (first product) so that
+# importing ``base.sparse`` never pulls the engine or the sketch layer, and
+# covered by the jit-leak gate's zero-recompile contract.
 _COMPILED_PRODUCTS: dict = {}
+
+# lanes one step of the XLA product gathers rows of B for: its workspace is
+# this many rows of k values (128 MiB at k = 1024), whatever nnz is
+_SPAN_LANES = 1 << 15
+
+
+def spans_product(data, indices, indptr, B, *, n_rows: int) -> jnp.ndarray:
+    """``A·B`` from CSR lanes by XLA, in spans of ``_SPAN_LANES`` lanes: a
+    span gathers its lanes' rows of B, scales them and adds them to the
+    rows of the result its lanes lie in (sorted row ids: CSR order). The
+    temporaries are one span's (``_SPAN_LANES`` × k) and the row ids (one
+    int32 a lane); the lane padding carries value 0.0 and adds exact zeros
+    to the last row. Traceable; the product every backend can run."""
+    lanes = data.shape[0]
+    span = min(_SPAN_LANES, lanes)
+    row = csr_row_ids(indptr, lanes)
+    pad = -lanes % span
+    if pad:
+        data, indices = jnp.pad(data, (0, pad)), jnp.pad(indices, (0, pad))
+        row = jnp.pad(row, (0, pad), mode="edge")
+
+    def add_span(q, out):
+        at = q * span
+        v, c, r = (jax.lax.dynamic_slice_in_dim(x, at, span)
+                   for x in (data, indices, row))
+        return out.at[r].add(v[:, None] * B[c], indices_are_sorted=True)
+
+    out = jnp.zeros((n_rows, B.shape[1]), B.dtype)
+    if lanes + pad == span:
+        return add_span(0, out)
+    return jax.lax.fori_loop(0, (lanes + pad) // span, add_span, out)
 
 
 def _product_kernel(op: str):
@@ -344,26 +476,55 @@ def _product_kernel(op: str):
         from libskylark_tpu.engine.compiled import compiled as _compiled
 
         if op == "spmm":
-            def kern(r, c, v, B, *, segments: int):
-                return jax.ops.segment_sum(v[:, None] * B[c], r,
-                                           num_segments=segments)
+            from libskylark_tpu.sketch.sparse_serve import product_lanes
+
+            cf = _compiled(product_lanes, name="sparse.spmm",
+                           static_argnames=("kernel", "shape", "plan"))
         else:
             def kern(r, c, v, B, *, segments: int):
                 return jax.ops.segment_sum(v[:, None] * B[r], c,
                                            num_segments=segments)
-        cf = _compiled(kern, name=f"sparse.{op}",
-                       static_argnames=("segments",),
-                       key_fn=lambda *a, **k: (op,))
+
+            cf = _compiled(kern, name=f"sparse.{op}",
+                           static_argnames=("segments",),
+                           key_fn=lambda *a, **k: (op,))
         _COMPILED_PRODUCTS[op] = cf
     return cf
 
 
-def spmm(A: SparseMatrix, B) -> jax.Array:
-    """A @ B with A sparse (h×w), B dense (w×k) → dense (h×k).
+def product_operands(A: SparseMatrix, k: int, dtype) -> tuple:
+    """What the ``sparse.spmm`` program body takes for ``A`` against a
+    right factor of ``k`` columns: ``(lanes, kernel, plan, attrs)`` — the
+    device arrays (regrouped for the kernel, else the CSR lanes), the
+    kernel's name as ``sparse_serve.product_kernel`` decides it, its plan
+    (None off the kernel) and the counts a ``sketch.dispatch`` span
+    carries. Placement happens here, once per layout."""
+    from libskylark_tpu.engine.bucket import lane_class
+    from libskylark_tpu.sketch.sparse_serve import product_kernel
 
-    Segment-sum over nonzeros (ref: base/Gemm.hpp:335-519 CSC kernels):
-    out[r] += v · B[c] for each (r, c, v) — one cached executable per
-    (nnz, operand-shape) class via ``engine.compiled``."""
+    nnz_class = lane_class(A.nnz)
+    kernel, plan = product_kernel(A.shape, k, nnz_class,
+                                  A._device_dtype_of(dtype))
+    attrs = {"kernel": kernel, "nnz": A.nnz, "nnz_class": nnz_class}
+    if plan is None:
+        attrs.update(lane_slots=nnz_class, segments=1)
+        return A.csr_device(dtype), kernel, None, attrs
+    attrs.update(lane_slots=plan.n_chunks * plan.chunk,
+                 segments=plan.row_blocks * plan.col_tiles,
+                 row_block=plan.row_block, col_tile=plan.col_tile,
+                 chunk=plan.chunk)
+    return A.tiled_device(plan.layout, dtype), kernel, plan, attrs
+
+
+def spmm(A: SparseMatrix, B) -> jax.Array:
+    """A @ B with A sparse (h×w), B dense (w×k) → dense (h×k), the
+    reference's local sparse × dense kernels (ref: base/Gemm.hpp:335-519)
+    as ONE compiled program (``sparse.spmm``, ``engine.compiled``) whose
+    workspace is bounded independently of nnz × k: the Pallas walk of
+    ``sketch/pallas_spmm.py`` where ``sparse_serve.product_kernel`` finds
+    the shapes (a TPU, float32, k a multiple of 128 up to 2048), else
+    :func:`spans_product`. Counts the stored nonzeros under
+    ``sparse.spmm_nnz``."""
     B = jnp.asarray(B)
     squeeze = B.ndim == 1
     if squeeze:
@@ -372,9 +533,20 @@ def spmm(A: SparseMatrix, B) -> jax.Array:
         raise errors.InvalidParametersError(
             f"spmm: A is {A.shape}, B is {B.shape}"
         )
-    r, c, v = A.coo(B.dtype)
-    out = _product_kernel("spmm")(r, c, v, B, segments=A.height)
+    lanes, kernel, plan, _ = product_operands(A, int(B.shape[1]), B.dtype)
+    out = _product_kernel("spmm")(*lanes, B, kernel=kernel, shape=A.shape,
+                                  plan=plan)
+    _spmm_nnz().inc_always(A.nnz, kernel=kernel)
     return out[:, 0] if squeeze else out
+
+
+@functools.lru_cache(maxsize=None)
+def _spmm_nnz():
+    from libskylark_tpu.telemetry import metrics as _metrics
+
+    return _metrics.counter(
+        "sparse.spmm_nnz",
+        "Stored nonzeros multiplied through base.sparse.spmm, by kernel")
 
 
 def spmm_t(A: SparseMatrix, B) -> jax.Array:

@@ -296,16 +296,50 @@ class DenseTransform(OperatorCache, SketchTransform):
         return spmm_t(A, S.T).T          # S·A = (Aᵀ·Sᵀ)ᵀ
 
     def _apply_rowwise_sparse(self, A) -> jnp.ndarray:
-        from libskylark_tpu.base.sparse import spmm
+        """A·Sᵀ of a ``SparseMatrix``: ONE compiled program an apply
+        (``sketch.dense_sparse``, ``engine.compiled``) — the operator
+        generated inside it from the allocation's key words, the same S the
+        dense apply of ``A.todense()`` contracts with, and the sparse ×
+        dense product ``base.sparse.spmm`` runs
+        (``sparse_serve.dense_sparse_apply`` states the workspace: it does
+        not grow with nnz × S). A pinned operator is ``spmm``'s right
+        factor. The program holds the whole operator, so one past
+        ``auto_block_bytes`` (2 GiB unless set) keeps the host loop over
+        column panels; the ``blocksize`` knob alone chooses nothing here."""
+        from libskylark_tpu.base.sparse import product_operands, spmm
 
         S = self._cached_op(A.device_dtype)
         if S is not None:
             return spmm(A, S.T)          # A·Sᵀ
-        blocksize = self._effective_blocksize(A.device_dtype)
-        if blocksize:
-            return self._sparse_panel_loop(A, blocksize)
-        S = self.s_panel(0, self._N, A.device_dtype)
-        return spmm(A, S.T)              # A·Sᵀ
+        if self._op_bytes(A.device_dtype) > sketch_params.get_auto_block_bytes():
+            return self._sparse_panel_loop(
+                A, self._effective_blocksize(A.device_dtype))
+        lanes, kernel, plan, attrs = product_operands(
+            A, self._S, A.device_dtype)
+        key_data = self._alloc.key_data
+        scale = self._device_scale()
+        with _trace.span("sketch.dispatch",
+                         {"path": "sparse", "family": self.sketch_type,
+                          "s": self._S, **attrs}):
+            out = _sparse_program()(
+                key_data, scale, *lanes, dist=self.dist,
+                s_dim=self._S, shape=A.shape, kernel=kernel, plan=plan)
+        from libskylark_tpu.sketch.hash import _SPARSE_NNZ
+
+        _SPARSE_NNZ.inc_always(A.nnz, family=self.sketch_type, kernel=kernel)
+        return out
+
+    def _device_scale(self) -> jnp.ndarray:
+        """``self.scale`` as a scalar on the device, placed once a
+        transform (as ``Allocation.key_data`` is once a process): handed
+        over as a Python float it is one host-to-device transfer an apply,
+        which the program's launch waits for — a call into the runtime and
+        a completion more on every apply's critical path. Weakly typed,
+        as ``jnp.asarray(float)`` is: the same executable either way."""
+        held = self.__dict__.get("_scale_on_device")
+        if held is None:
+            held = self.__dict__["_scale_on_device"] = jnp.asarray(self.scale)
+        return held
 
     def _sparse_panel_loop(self, A, blocksize: int) -> jnp.ndarray:
         """A·Sᵀ for sparse (m, N) A without ever materializing S beyond an
@@ -447,3 +481,24 @@ class CT(DenseTransform):
     @classmethod
     def _from_parts(cls, N, S, alloc, d):
         return cls(N, S, alloc, C=float(d.get("C", 1.0)))
+
+
+# -- the compiled dense sketch of a sparse operand (below everything the
+# dense cells' programs trace through, whose lines keep their numbers) --
+
+_SPARSE_PROGRAM = None
+
+
+def _sparse_program():
+    """``sketch.dense_sparse``, built at the first sparse operand so that
+    importing the sketch layer pulls neither the engine nor a Pallas
+    module."""
+    global _SPARSE_PROGRAM
+    if _SPARSE_PROGRAM is None:
+        from libskylark_tpu.engine.compiled import compiled
+        from libskylark_tpu.sketch.sparse_serve import dense_sparse_apply
+
+        _SPARSE_PROGRAM = compiled(
+            dense_sparse_apply, name="sketch.dense_sparse",
+            static_argnames=("dist", "s_dim", "shape", "kernel", "plan"))
+    return _SPARSE_PROGRAM

@@ -214,3 +214,102 @@ def sparse_solve_serve(key_data, scale, data, indices, indptr, B, *,
             f"sparse solve serve path supports JLT/CWT sketches, got "
             f"{sketch_type!r}")
     return solve_l2_exact(SA, SB, method=method)
+
+
+# -- sparse × dense: the product of base.sparse.spmm and the dense sketch of
+# a sparse operand (ref: base/Gemm.hpp:335-519 through
+# sketch/dense_transform_Mixed.hpp:19). Below the hash endpoints, whose
+# traced lines keep their numbers. --
+
+
+def _compiles_mosaic() -> bool:
+    """True when the default backend compiles Mosaic kernels (a TPU); asked
+    before a Pallas module is imported, so that a CPU process never pays
+    for one."""
+    return jax.default_backend() == "tpu"
+
+
+def product_kernel(shape: tuple, k: int, lanes: int, dtype,
+                   rowwise: bool = True) -> tuple:
+    """Which program multiplies a sparse operand of ``shape`` (``lanes``
+    lane positions placed) by a dense right factor of ``k`` columns:
+    ``("pallas_tiles", plan)`` — the walk of
+    :mod:`libskylark_tpu.sketch.pallas_spmm` over lanes regrouped by (row
+    block, column tile) — on a TPU, for ``A·B`` (a rowwise sketch), where
+    its plan fits (float32, k a multiple of 128 up to 2048, a chunk table
+    inside SMEM), else ``("xla: <why>", None)``: the span loop of
+    ``base.sparse.spans_product``. Decided from what the caller can
+    observe — the backend and the shapes — by ``base.sparse.spmm`` and
+    ``DenseTransform._apply_rowwise_sparse``; the ``sketch.dispatch`` span
+    and the ``sketch.sparse_nnz`` / ``sparse.spmm_nnz`` counters carry the
+    name."""
+    if not rowwise:
+        return "xla: columnwise contracts down the rows", None
+    if not _compiles_mosaic():
+        return f"xla: backend {jax.default_backend()}", None
+    from libskylark_tpu.sketch import pallas_spmm
+
+    plan, why = pallas_spmm.tiles_plan(shape, k, lanes, dtype)
+    return ("pallas_tiles", plan) if plan is not None else (f"xla: {why}",
+                                                            None)
+
+
+def product_lanes(*operands, kernel: str, shape: tuple,
+                  plan=None) -> jnp.ndarray:
+    """``A·B`` — the body of the ``sparse.spmm`` program. ``operands`` are
+    A's device arrays as ``base.sparse.product_operands`` placed them for
+    ``kernel`` (the regrouped lanes of ``SparseMatrix.tiled_device``, else
+    the CSR lanes) followed by B (at least n rows; float32 for the
+    kernel). A kernel that does not compile raises: nothing falls back."""
+    *lanes, B = operands
+    if kernel == "pallas_tiles":
+        from libskylark_tpu.sketch import pallas_spmm
+
+        return pallas_spmm.tiles_apply(
+            *lanes, B, shape=shape, plan=plan,
+            interpret=not _compiles_mosaic())
+    from libskylark_tpu.base.sparse import spans_product
+
+    data, indices, indptr = lanes
+    return spans_product(data, indices, indptr, B[:shape[1]],
+                         n_rows=int(shape[0]))
+
+
+def operator_rows(key_data, scale, *, dist, s_dim: int, n: int,
+                  dtype) -> jnp.ndarray:
+    """Sᵀ, rows [0, n) — row c is column c of the scaled virtual operator in
+    the dense-block stream format (``sketch.dense.virtual_panel``: the same
+    S the dense apply contracts with, entry for entry), generated block by
+    block under one ``vmap`` instead of one traced copy of the cipher a
+    block. ``n`` may run past the transform's N: a position's entry does
+    not depend on the width generated."""
+    import jax.random as jr
+
+    from libskylark_tpu.sketch.dense import BLOCK_COLS
+
+    key = jr.wrap_key_data(jnp.asarray(key_data))
+    blocks = jax.vmap(lambda b: randgen.dense_block(
+        key, dist, s_dim, b, BLOCK_COLS, dtype))(
+            jnp.arange(-(-n // BLOCK_COLS), dtype=jnp.int32))
+    rows = jnp.swapaxes(blocks, 1, 2).reshape(-1, s_dim)[:n]
+    return jnp.asarray(scale, dtype) * rows
+
+
+def dense_sparse_apply(key_data, scale, *lanes, dist, s_dim: int,
+                       shape: tuple, kernel: str, plan=None) -> jnp.ndarray:
+    """One rowwise dense-family (JLT/CT) sketch of a sparse operand,
+    ``X·Sᵀ`` (rows × s_dim): the program ``sketch.dense_sparse``, a pure
+    function of the allocation's key words, the scale and the operand's
+    lanes. The operator is generated here (:func:`operator_rows`; for the
+    kernel straight at the width its column tiles pad N to) and the product
+    is :func:`product_lanes`, the body ``base.sparse.spmm`` runs.
+
+    Workspace, whatever nnz is: the operator (N × s_dim values, twice while
+    it is laid out), the kernel's relayout of the result (rows × s_dim) or
+    the span loop's ``_SPAN_LANES`` × s_dim rows and one int32 a lane. At
+    262144 × 47236 and s_dim 1024 under the kernel: 1.5 GB."""
+    n = int(shape[1]) if plan is None else plan.col_tiles * plan.col_tile
+    Bt = operator_rows(key_data, scale, dist=dist, s_dim=s_dim, n=n,
+                       dtype=lanes[-1].dtype if plan is not None
+                       else lanes[0].dtype)
+    return product_lanes(*lanes, Bt, kernel=kernel, shape=shape, plan=plan)

@@ -56,6 +56,12 @@ METRICS: Dict[str, str] = {
     # benchmark's cross-check of the nnz that sparse_nnz_rate.apply reads
     # from the sketch.dispatch spans
     "sketch.sparse_nnz": "counter",
+    # base.sparse.spmm called directly (the sparse power iterations of
+    # nla/svd.py, Krylov solvers, kernels on sparse inputs): stored
+    # nonzeros multiplied, by kernel (sparse_serve.product_kernel:
+    # "pallas_tiles" | "xla: <why>"); a dense sketch of a sparse operand
+    # counts under sketch.sparse_nnz instead
+    "sparse.spmm_nnz": "counter",
     # the compiled dense feature-map apply (sketch/rft.py): feature values
     # produced (rows × s), by family and kernel ("pallas_planes" |
     # "pallas_generate" | "xla") — the cross-check of the features that
@@ -212,6 +218,17 @@ SPANS: Dict[str, Tuple[str, str]] = {
     # holds the axis or one chunk the samples; for the operator), elements
     # (= axis × columns mixed, which mix_rate.apply reads: the operand's, no
     # pad counted) and sampled (= s × columns kept)
+    # the dense sketch of a sparse operand (sketch/dense.py
+    # _apply_rowwise_sparse, the sketch.dense_sparse program, since PR 57)
+    # carries path="sparse", family, s, kernel (sparse_serve.product_kernel:
+    # "pallas_tiles" = the walk of sketch/pallas_spmm.py | "xla: <why>" =
+    # base.sparse.spans_product), nnz and nnz_class (sparse_nnz_rate.apply
+    # reads nnz), lane_slots (the lane positions the walk's layout holds and
+    # its copies move, chunk padding included — the lane class on the XLA
+    # route; lane_fill.apply reads nnz ÷ lane_slots), segments (the (row
+    # block, column tile) pairs the lanes are regrouped by; 1 on the XLA
+    # route) and, on the kernel, row_block, col_tile and chunk; its handover
+    # is the engine.execute inside it
     # the dense apply of an operand on more than one device
     # (parallel/shard_apply.py apply_on_mesh, since PR 55) carries path="mesh",
     # route ("program"; on the XLA route of sketch/dense.py that a declined
@@ -257,6 +274,12 @@ SPANS: Dict[str, Tuple[str, str]] = {
     # caller that holds the table; every apply, the mesh program's since
     # PR 55 among them, derives it inside its program)
     "stream.key": ("streams", "stream_key_ms.apply"),
+    # a sparse operand's lanes regrouped on the device for the sparse ×
+    # dense kernel (base/sparse.py SparseMatrix.tiled_device), once per
+    # (dtype, layout): attributes layout, nnz, lane_slots, bytes (placed)
+    # and seconds (the regrouping program, waited for) — set-up, never
+    # inside a measured apply
+    "sparse.place": ("set-up", "operator"),
     # the measured solve (nla/svd.py, engine/compiled.py); under a
     # sketch.apply the same spans are the compiled applies' way to the
     # runtime: engine.lookup is a part of what idle_before_enqueue_ms.apply

@@ -1,0 +1,423 @@
+"""The sparse × dense program: ``JLT|CT.apply(SparseMatrix, ROWWISE)`` is one
+``engine.compiled`` program an apply (``sketch.dense_sparse``), its operator
+generated inside it, and its product the body ``base.sparse.spmm`` runs
+(``sparse.spmm``) — the Pallas walk of ``sketch/pallas_spmm.py`` over lanes
+regrouped at placement (interpreted here, off the TPU), else the span loop.
+
+Oracles:
+
+- *plain reference*: ``cellbench/references/sparse_dense_sketch.py`` (imports
+  nothing of the program): S from (seed, counter) by the stream definition,
+  and ``X.toarray()·Sᵀ`` at the highest matmul precision;
+- ``T.apply(X.todense())``: the same S, entry for entry;
+- scipy's ``A @ B`` for ``spmm`` with a supplied right factor;
+- a single bfloat16 pass of the reference fails the tolerance the program
+  holds;
+- one ``sketch.dispatch`` span and one handover an apply, the counters'
+  labels, ``lane_slots ≥ nnz``, the operand placed once.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+import scipy.sparse as sp
+
+from cellbench.references import sparse_dense_sketch as reference
+from libskylark_tpu import Context, engine
+from libskylark_tpu import sketch as sk
+from libskylark_tpu.base import sparse as sparse_mod
+from libskylark_tpu.base.sparse import SparseMatrix, spmm
+from libskylark_tpu.sketch import pallas_spmm, sparse_serve
+from libskylark_tpu.telemetry import metrics, trace
+from libskylark_tpu.telemetry.names import HANDOVER
+
+N = 1181            # 47236-like: no multiple of 8, 128 or 256
+S = 256
+ROWS = 77           # no multiple of a row block either
+SEED, COUNTER = 11, 0
+TOL = 1e-4          # of the largest entry: the cells' rel_max limit
+ROUTES = ["xla", "pallas_tiles"]
+
+
+def operand(rows: int = ROWS, n: int = N, seed: int = 4) -> sp.csr_matrix:
+    """Ragged unit rows: row 3 empty, row 5 of 1024 nonzeros, the others
+    1..40 features under a Zipf law over scattered ids."""
+    rng = np.random.default_rng(seed)
+    weights = 1.0 / np.arange(1, n + 1)
+    weights /= weights.sum()
+    ids = rng.permutation(n)
+    r, c = [], []
+    for row in range(rows):
+        if row == 3:
+            continue
+        length = min(1024, n) if row == 5 else int(rng.integers(1, 40))
+        feats = ids[rng.choice(n, size=length, replace=False, p=weights)]
+        r += [row] * length
+        c += list(feats)
+    v = np.abs(rng.standard_normal(len(r))).astype(np.float32)
+    X = sp.csr_matrix((v, (r, c)), shape=(rows, n))
+    X.sort_indices()
+    norms = np.sqrt(np.asarray(X.multiply(X).sum(axis=1))).ravel()
+    X = sp.diags(1.0 / np.where(norms > 0, norms, 1.0)).dot(X).tocsr()
+    X.sort_indices()
+    return X.astype(np.float32)
+
+
+@pytest.fixture()
+def fresh():
+    engine.reset()
+    before = metrics._ENABLED
+    trace.clear_finished()
+    yield
+    metrics._ENABLED = before
+    trace.clear_finished()
+    engine.reset()
+
+
+@pytest.fixture(params=ROUTES)
+def route(request, monkeypatch):
+    """The program under each of its products. Off the TPU the rule picks
+    the span loop; the kernel (interpreted) is put in its place with small
+    blocks, so that the operand spans several row blocks and column tiles
+    and its lanes end inside a chunk."""
+    if request.param == "pallas_tiles":
+        monkeypatch.setattr(pallas_spmm, "_BLOCK_ROWS", 32)
+
+        def rule(shape, k, lanes, dtype, rowwise=True):
+            plan, why = pallas_spmm.tiles_plan(shape, k, lanes, dtype)
+            return (("pallas_tiles", plan) if plan is not None and rowwise
+                    else (f"xla: {why}", None))
+
+        monkeypatch.setattr(sparse_serve, "product_kernel", rule)
+    return request.param
+
+
+FAMILIES = [(sk.JLT, {}), (sk.CT, {"C": 2.0})]
+
+
+@pytest.mark.parametrize("family,kwargs", FAMILIES)
+class TestAgainstTheOracles:
+    def test_matches_the_densified_apply(self, fresh, route, family, kwargs):
+        T = family(N, S, Context(SEED), **kwargs)
+        X = operand()
+        A = SparseMatrix.from_scipy(X)
+        got = np.asarray(T.apply(A, sk.ROWWISE))
+        want = np.asarray(T.apply(jnp.asarray(X.toarray()), sk.ROWWISE))
+        assert got.shape == want.shape == (ROWS, S)
+        assert np.abs(got - want).max() <= TOL * np.abs(want).max()
+        assert not got[3].any()             # the empty row stays empty
+
+    def test_operator_is_the_dense_applys(self, fresh, family, kwargs):
+        T = family(N, S, Context(SEED), **kwargs)
+        rows = sparse_serve.operator_rows(
+            T.allocation.key_data, T.scale, dist=T.dist, s_dim=S, n=N + 299,
+            dtype=jnp.float32)
+        assert rows.shape == (N + 299, S)
+        assert np.array_equal(np.asarray(rows[:N]),
+                              np.asarray(T.s_panel(0, N)).T)
+
+
+class TestJLTAgainstThePlainReference:
+    def reference(self, X, precision="highest"):
+        Sref = reference.operator(SEED, COUNTER, S, N)
+        return np.asarray(reference.apply_rows(X, Sref, precision))
+
+    def test_matches(self, fresh, route):
+        X = operand()
+        T = sk.JLT(N, S, Context(SEED))
+        got = np.asarray(T.apply(SparseMatrix.from_scipy(X), sk.ROWWISE))
+        want = self.reference(X)
+        assert np.abs(got - want).max() <= TOL * np.abs(want).max()
+
+    def test_whole_block_reference_agrees_with_its_rows(self):
+        X = operand(rows=2 * reference.ROW_BLOCK + 5, n=300)
+        Sref = reference.operator(SEED, COUNTER, 128, 300)
+        whole = np.asarray(reference.apply_block(X, Sref))
+        rows = np.asarray(reference.apply_rows(X, Sref))
+        assert whole.shape == rows.shape
+        assert np.abs(whole - rows).max() <= 1e-6 * np.abs(rows).max()
+
+    def test_a_single_bfloat16_pass_fails_the_tolerance(self):
+        X = operand()
+        want = self.reference(X)
+        low = self.reference(X, "bf16")
+        assert np.abs(low - want).max() > 10 * TOL * np.abs(want).max()
+
+    def test_another_counter_is_another_operator(self, fresh):
+        X = operand()
+        ctx = Context(SEED)
+        first, second = sk.JLT(N, S, ctx), sk.JLT(N, S, ctx)
+        A = SparseMatrix.from_scipy(X)
+        want = self.reference(X)
+        assert np.abs(np.asarray(first.apply(A, sk.ROWWISE)) - want).max() \
+            <= TOL * np.abs(want).max()
+        assert np.abs(np.asarray(second.apply(A, sk.ROWWISE)) - want).max() \
+            > 0.1 * np.abs(want).max()
+
+    def test_served_law_z_scores(self, fresh):
+        X = operand(rows=64)
+        X = X[np.diff(X.indptr) > 0]
+        T = sk.JLT(N, S, Context(SEED))
+        Y = np.asarray(T.apply(SparseMatrix.from_scipy(X), sk.ROWWISE))
+        mean_z, var_z = reference.law_z_scores(X, Y, S)
+        assert mean_z < 6 and var_z < 6
+        _, scaled = reference.law_z_scores(X, 1.1 * Y, S)
+        assert scaled > 6
+
+
+@pytest.mark.parametrize("k", [128, 256, 384])
+def test_spmm_with_a_supplied_factor_against_scipy(fresh, route, k):
+    X = operand()
+    B = np.random.default_rng(3).standard_normal((N, k)).astype(np.float32)
+    got = np.asarray(spmm(SparseMatrix.from_scipy(X), B))
+    want = X.astype(np.float64) @ B.astype(np.float64)
+    assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+
+
+@pytest.mark.parametrize("k", [1, 5, 130])
+def test_spmm_widths_the_kernel_declines_take_the_span_loop(fresh, route, k):
+    X = operand()
+    A = SparseMatrix.from_scipy(X)
+    B = np.random.default_rng(3).standard_normal((N, k)).astype(np.float32)
+    kernel, plan = sparse_serve.product_kernel(A.shape, k, 4096, jnp.float32)
+    assert plan is None and kernel.startswith("xla: ")
+    got = np.asarray(spmm(A, B[:, 0] if k == 1 else B))
+    want = X @ (B[:, 0] if k == 1 else B)
+    assert got.shape == want.shape
+    assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+
+
+def test_span_loop_over_several_spans(fresh, monkeypatch):
+    monkeypatch.setattr(sparse_mod, "_SPAN_LANES", 512)
+    X = operand()
+    A = SparseMatrix.from_scipy(X)
+    assert A.csr_device()[0].shape[0] > 4 * 512
+    B = np.random.default_rng(3).standard_normal((N, 8)).astype(np.float32)
+    got = np.asarray(spmm(A, B))
+    assert np.abs(got - X @ B).max() <= 1e-5 * np.abs(X @ B).max()
+
+
+class TestThePlacement:
+    def plan(self, A, k=S):
+        lanes = int(A.csr_device()[0].shape[0])
+        plan, why = pallas_spmm.tiles_plan(A.shape, k, lanes, jnp.float32)
+        assert plan is not None, why
+        return plan
+
+    def test_layout_holds_every_lane_once(self, fresh, monkeypatch):
+        monkeypatch.setattr(pallas_spmm, "_BLOCK_ROWS", 32)
+        X = operand()
+        A = SparseMatrix.from_scipy(X)
+        plan = self.plan(A)
+        assert (plan.row_block, plan.col_tile) == (32, 32)
+        assert plan.row_blocks == 3 and plan.col_tiles == 37
+        segment, count, packed, vals = map(np.asarray,
+                                           A.tiled_device(plan.layout))
+        assert count.sum() == X.nnz
+        assert (np.diff(segment) >= 0).all()
+        # every row block owns a chunk, the lanes end inside one
+        assert set(segment // plan.col_tiles) == {0, 1, 2}
+        assert (count % plan.chunk != 0).any()
+        live = np.arange(plan.chunk)[None, :] < count[:, None]
+        row = (segment // plan.col_tiles)[:, None] * 32 + (packed[:, 0] >> 16)
+        col = (segment % plan.col_tiles)[:, None] * 32 + (packed[:, 0] & 0xFFFF)
+        back = sp.csr_matrix((vals[:, 0][live], (row[live], col[live])),
+                             shape=X.shape)
+        assert (back != X).nnz == 0
+        assert not vals[:, 0][~live].any()
+
+    def test_a_segments_last_chunk_is_its_fullest(self, fresh, monkeypatch):
+        """Chunks of 16 slots, so that segments take several: every chunk
+        of a segment is full but its first (the last one's walk hides the
+        next tile's copy), and the product through them is the densified
+        one."""
+        monkeypatch.setattr(pallas_spmm, "_BLOCK_ROWS", 32)
+        monkeypatch.setattr(pallas_spmm, "_CHUNKS", (16,))
+        X = operand()
+        A = SparseMatrix.from_scipy(X)
+        plan = self.plan(A, k=128)
+        assert plan.chunk == 16
+        placed = A.tiled_device(plan.layout)
+        segment, count = np.asarray(placed[0]), np.asarray(placed[1])
+        assert count.sum() == X.nnz
+        several = 0
+        for seg in np.unique(segment[count > 0]):
+            counts = count[(segment == seg) & (count > 0)]
+            assert (counts[1:] == 16).all() and 0 < counts[0] <= 16
+            several += len(counts) > 1
+        assert several > 3
+        B = np.random.default_rng(2).standard_normal((N, 128)).astype(np.float32)
+        out = pallas_spmm.tiles_apply(*placed, jnp.asarray(B), shape=A.shape,
+                                      plan=plan, interpret=True)
+        want = X.astype(np.float64) @ B.astype(np.float64)
+        assert np.abs(np.asarray(out) - want).max() <= 1e-5 * np.abs(want).max()
+
+    @pytest.mark.parametrize("col_tile,chunk", [(32, 16), (40, 2048),
+                                                (1184, 64), (8, 2048)])
+    def test_the_result_is_the_layouts_to_the_bit(self, fresh, col_tile,
+                                                   chunk):
+        """Whatever the tile width and the chunk size, a row's terms are
+        added by rising column: every layout gives the same bits."""
+        X = operand()
+        A = SparseMatrix.from_scipy(X)
+        B = jnp.asarray(np.random.default_rng(5).standard_normal(
+            (N, 128)).astype(np.float32))
+
+        def product(col_tile, chunk):
+            tiles = -(-N // col_tile)
+            plan = pallas_spmm.TilesPlan(
+                32, col_tile, chunk, 1, 3, tiles, -(-X.nnz // chunk) + 3 * tiles)
+            return np.asarray(pallas_spmm.tiles_apply(
+                *A.tiled_device(plan.layout), B, shape=A.shape, plan=plan,
+                interpret=True))
+
+        assert np.array_equal(product(col_tile, chunk), product(32, 2048))
+
+    @pytest.mark.parametrize("n,tile,tiles", [
+        (47236, 1976, 24), (2048, 2048, 1), (2049, 1032, 2), (1181, 1184, 1)])
+    def test_column_tiles_are_of_one_width(self, n, tile, tiles):
+        """No whole tiles and a narrow rest: the rest's short segments
+        would end every row block (47236 = 23 · 2048 + 132)."""
+        plan, why = pallas_spmm.tiles_plan((4096, n), 1024, 1 << 20,
+                                           jnp.float32)
+        assert plan is not None, why
+        assert (plan.col_tile, plan.col_tiles) == (tile, tiles)
+        assert n - (tiles - 1) * tile > tile - 8 * tiles
+
+    def test_placed_once_under_a_span(self, fresh, route):
+        metrics._ENABLED = True
+        X = operand()
+        A = SparseMatrix.from_scipy(X)
+        T = sk.JLT(N, S, Context(SEED))
+        first = np.asarray(T.apply(A, sk.ROWWISE))
+        compiles = engine.stats().compiles
+        placed = [s for s in trace.finished_spans() if s.name == "sparse.place"]
+        second = np.asarray(T.apply(A, sk.ROWWISE))
+        assert np.array_equal(first, second)
+        assert engine.stats().compiles == compiles == 1
+        after = [s for s in trace.finished_spans() if s.name == "sparse.place"]
+        assert len(after) == len(placed) == (route == "pallas_tiles")
+        for span in placed:
+            assert span.attrs["bytes"] > 0 and span.attrs["seconds"] > 0
+            assert span.attrs["lane_slots"] >= X.nnz
+
+    @pytest.mark.parametrize("family,kwargs", FAMILIES)
+    def test_a_warm_apply_moves_nothing_to_the_device(self, fresh, route,
+                                                      family, kwargs):
+        """The key words, the scale and the lanes are on the device from the
+        first apply on: a later one is the executable's call alone (a
+        transfer an apply is a call into the runtime and a completion more
+        ahead of the program's launch)."""
+        A = SparseMatrix.from_scipy(operand())
+        T = family(N, S, Context(SEED), **kwargs)
+        first = np.asarray(T.apply(A, sk.ROWWISE))
+        with jax.transfer_guard_host_to_device("disallow"):
+            second = T.apply(A, sk.ROWWISE)
+        assert np.array_equal(first, np.asarray(second))
+
+    def test_plans_the_kernel_declines(self):
+        shape = (262144, 47236)
+        assert pallas_spmm.tiles_plan(shape, 1024, 19922944, jnp.float32)[0] \
+            == pallas_spmm.TilesPlan(2048, 1976, 2048, 8, 128, 24, 12800)
+        for k, dtype, lanes, why in [
+                (1000, jnp.float32, 1 << 20, "multiple of 128"),
+                (4096, jnp.float32, 1 << 20, "multiple of 128"),
+                (1024, jnp.bfloat16, 1 << 20, "dtype bfloat16"),
+                (1024, jnp.float64, 1 << 20, "dtype float64"),
+                (1024, jnp.float32, 1 << 28, "chunk table")]:
+            plan, said = pallas_spmm.tiles_plan(shape, k, lanes, dtype)
+            assert plan is None and why in said
+        # off the TPU and columnwise the rule says so
+        assert sparse_serve.product_kernel(
+            shape, 1024, 19922944, jnp.float32) == (
+                f"xla: backend {jax.default_backend()}", None)
+        assert sparse_serve.product_kernel(
+            shape, 1024, 19922944, jnp.float32, rowwise=False)[0].startswith(
+                "xla: columnwise")
+
+
+class TestSpansAndCounters:
+    def test_one_span_one_handover_and_the_labels(self, fresh, route):
+        metrics._ENABLED = True
+        X = operand()
+        A = SparseMatrix.from_scipy(X)
+        T = sk.JLT(N, S, Context(SEED))
+        T.apply(A, sk.ROWWISE)              # placement and compile
+        trace.clear_finished()
+        before = _counter("sketch.sparse_nnz")
+        for _ in range(2):      # a period ends at the next apply's start
+            T.apply(A, sk.ROWWISE).block_until_ready()
+        spans = trace.finished_spans()
+        dispatch, _ = [s for s in spans if s.name == "sketch.dispatch"]
+        attrs = dispatch.attrs
+        assert attrs["path"] == "sparse" and attrs["family"] == "JLT"
+        assert attrs["s"] == S and attrs["nnz"] == X.nnz
+        assert attrs["nnz_class"] == A.csr_device()[0].shape[0]
+        assert attrs["lane_slots"] >= attrs["nnz"]
+        if route == "pallas_tiles":
+            assert attrs["kernel"] == "pallas_tiles"
+            assert attrs["segments"] == 3 * 37 and attrs["chunk"] == 2048
+        else:
+            assert attrs["kernel"] == f"xla: backend {jax.default_backend()}"
+            assert attrs["segments"] == 1
+        assert len([s for s in spans if s.name == HANDOVER[0]]) == 2
+        assert not [s for s in spans if s.name == "sparse.place"]
+        periods = trace.apply_periods("sketch.apply")
+        assert [p["handovers"] for p in periods] == [1]
+        after = _counter("sketch.sparse_nnz")
+        key = (("family", "JLT"), ("kernel", attrs["kernel"]))
+        assert after.get(key, 0) - before.get(key, 0) == 2 * X.nnz
+
+    def test_spmm_counts_under_its_own_name(self, fresh, route):
+        X = operand()
+        A = SparseMatrix.from_scipy(X)
+        B = np.ones((N, 128), np.float32)
+        before = _counter("sparse.spmm_nnz")
+        sketched = _counter("sketch.sparse_nnz")
+        spmm(A, B)
+        after = _counter("sparse.spmm_nnz")
+        (key,) = [k for k in after if after[k] != before.get(k, 0)]
+        assert after[key] - before.get(key, 0) == X.nnz
+        assert dict(key)["kernel"] == (
+            "pallas_tiles" if route == "pallas_tiles"
+            else f"xla: backend {jax.default_backend()}")
+        assert _counter("sketch.sparse_nnz") == sketched
+
+
+def _counter(name: str) -> dict:
+    entry = metrics.snapshot()["metrics"].get(name)
+    if entry is None:
+        return {}
+    return {tuple(sorted(v["labels"].items())): int(v["value"])
+            for v in entry["values"]}
+
+
+def test_pinned_operator_is_spmms_right_factor(fresh, route):
+    X = operand()
+    A = SparseMatrix.from_scipy(X)
+    T = sk.JLT(N, S, Context(SEED))
+    virtual = np.asarray(T.apply(A, sk.ROWWISE))
+    T.materialize()
+    pinned = np.asarray(T.apply(A, sk.ROWWISE))
+    assert np.abs(pinned - virtual).max() <= 1e-6 * np.abs(virtual).max()
+
+
+def test_operator_past_auto_block_bytes_keeps_the_panel_loop(fresh):
+    from libskylark_tpu.sketch import params as sketch_params
+
+    X = operand()
+    A = SparseMatrix.from_scipy(X)
+    T = sk.JLT(N, S, Context(SEED))
+    want = np.asarray(T.apply(A, sk.ROWWISE))
+    old = sketch_params.get_auto_block_bytes()
+    sketch_params.set_auto_block_bytes(N * S * 4 - 1)
+    try:
+        got = np.asarray(T.apply(A, sk.ROWWISE))
+    finally:
+        sketch_params.set_auto_block_bytes(old)
+    assert engine.stats().compiles == 1     # the loop is eager: no program
+    assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()

@@ -954,3 +954,83 @@ def test_other_layouts_mesh_program(grid2x2, vmem_cap, spec, seq_axis, rows,
     found = _collectives(compiled.as_text())
     assert {op: n for op, n in found.items() if n} == want
     assert compiled.as_text().count(KERNEL) >= 1
+
+
+# -- the jlt_sparse_apply cell: the dense sketch of a sparse row block --------
+
+# a jlt_sparse_apply block is a cwt_sparse_apply block: SPARSE_ROWS ×
+# SPARSE_N, SPARSE_LANES lane positions; the sketch is 1024 wide
+SPARSE_S = 1024
+CHIP_HBM = 16 << 30
+
+
+def _sparse_arg(one_chip):
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+
+def test_cell_shape_sparse_dense_program(one_chip, monkeypatch):
+    """The whole ``sketch.dense_sparse`` program of the jlt_sparse_apply
+    cell under the tiles kernel: the operator generated in the program, ONE
+    Mosaic call, no array of nnz × s anywhere, and under 3 GB of
+    temporaries (the operator, its layout, the result's relayout)."""
+    from libskylark_tpu.sketch import pallas_spmm
+
+    # off the TPU the program would interpret the kernel
+    monkeypatch.setattr(sparse_serve, "_compiles_mosaic", lambda: True)
+    kernel, plan = sparse_serve.product_kernel(
+        (SPARSE_ROWS, SPARSE_N), SPARSE_S, SPARSE_LANES, jnp.float32)
+    assert kernel == "pallas_tiles"
+    assert plan == pallas_spmm.TilesPlan(2048, 1976, 2048, 8, 128, 24, 12800)
+    assert pallas_spmm.vmem_bytes(plan) == 2 * (2048 + 1976) * 4096
+    arg = _sparse_arg(one_chip)
+    program = jax.jit(functools.partial(
+        sparse_serve.dense_sparse_apply, dist=randgen.Normal(),
+        s_dim=SPARSE_S, shape=(SPARSE_ROWS, SPARSE_N), kernel=kernel,
+        plan=plan))
+    slots = (plan.n_chunks, 1, plan.chunk)
+    compiled = program.lower(
+        arg((2,), jnp.uint32), arg((), jnp.float32),
+        arg((plan.n_chunks,), jnp.int32), arg((plan.n_chunks,), jnp.int32),
+        arg(slots, jnp.int32), arg(slots, jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 1
+    assert not re.search(r"\b(sort|scatter)\(", text)
+    assert f"f32[{SPARSE_LANES},{SPARSE_S}]" not in text
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == SPARSE_ROWS * SPARSE_S * 4
+    assert memory.temp_size_in_bytes < 3 << 30, memory
+
+
+def test_cell_shape_sparse_product_replaced_the_whole_gather(one_chip):
+    """What ``spmm`` was before PR 57 — ``segment_sum(v[:, None] * B[c], r)``
+    over all stored nonzeros at once — does not fit a v5e at the cell's
+    shape (an nnz × s temporary, 79 GB), and the program ``spmm`` runs now
+    where the kernel declines (the span loop, any backend) stays under
+    3 GB: the whole gather must not come back through it."""
+    arg = _sparse_arg(one_chip)
+    lanes = [arg((SPARSE_LANES,), jnp.int32), arg((SPARSE_LANES,), jnp.int32),
+             arg((SPARSE_LANES,), jnp.float32)]
+    B = arg((SPARSE_N, SPARSE_S), jnp.float32)
+
+    def whole_gather(r, c, v, B):
+        return jax.ops.segment_sum(v[:, None] * B[c], r,
+                                   num_segments=SPARSE_ROWS)
+
+    try:
+        needs = jax.jit(whole_gather).lower(
+            *lanes, B).compile().memory_analysis().temp_size_in_bytes
+    except Exception as e:  # noqa: BLE001 — the compiler's own refusal
+        assert "RESOURCE_EXHAUSTED" in str(e), e
+        needs = SPARSE_LANES * SPARSE_S * 4
+    assert needs > CHIP_HBM
+
+    program = jax.jit(functools.partial(
+        sparse_serve.product_lanes, kernel="xla: declined",
+        shape=(SPARSE_ROWS, SPARSE_N)))
+    compiled = program.lower(
+        arg((SPARSE_LANES,), jnp.float32), arg((SPARSE_LANES,), jnp.int32),
+        arg((SPARSE_ROWS + 1,), jnp.int32), B).compile()
+    assert f"f32[{SPARSE_LANES},{SPARSE_S}]" not in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 3 << 30, memory
