@@ -21,13 +21,10 @@ error, not a default.
 Other modes (each in-process; the CPU ones count what a CPU can count —
 compiles, flushes, bit-equality — and label their rates with the host
 class): ``--solver`` (engine compile-vs-execute split), ``--serve``
-(microbatch serving A/B, batched vs sequential dispatch, plus the
-kernel-selection A/B), ``--fleet`` (N-replica router vs single-executor
+(microbatch serving A/B, batched vs sequential dispatch), ``--fleet`` (N-replica router vs single-executor
 A/B with a one-replica drain-failover leg), ``--boot`` (fleet-boot
 cold-start A/B with vs without a warmup pack), ``--sparse``, ``--cache``,
-``--net``, ``--fwht``, ``--qos``, ``--dist-serve``, and
-``--certify-kernels`` (on a TPU: compile each Pallas serve kernel at a
-real bucket shape, compare it with its XLA twin, time both).
+``--net``, ``--fwht``, ``--qos`` and ``--dist-serve``.
 
 Each timed iteration consumes the FULL sketch output (the loop carries
 sum(abs(SA)) back into the next input), so XLA cannot dead-code-eliminate
@@ -703,131 +700,6 @@ def _serve(n_requests: int = 64, max_batch: int = 16,
 
     ex.shutdown()
 
-    # -- kernel-selection A/B: autotuned per-bucket selection vs forced
-    # XLA (r12). A 2-bucket serve mix is tuned OFFLINE (record_ranked
-    # into an in-memory cache — the committed benchmarks/plan_cache.json
-    # is never touched by a bench run), then the same storm runs once
-    # with selection enabled (arg > env > plan cache > default) and once
-    # forced onto the vmapped-XLA flush. On a CPU host the cost model's
-    # interpret-mode penalty makes the tuner certify XLA for EVERY serve
-    # bucket — interpret-mode pallas is a correctness surface, not a
-    # speed surface — so the honest CPU record shows ~1x with
-    # per-bucket "xla" outcomes; the kernel side of the A/B only opens
-    # up on a TPU.
-    from libskylark_tpu import tune as _tune
-
-    kab_nreq, kab_batch = 16, 8
-    cwt_reqs = []
-    for i in range(kab_nreq):
-        Tk = sk.CWT(40, 16, ctx)
-        Ak = rng.standard_normal((40, 3 + i % 4)).astype(np.float32)
-        cwt_reqs.append((Tk, Ak))
-    jlt_reqs = [(reqs[i][0], reqs[i][1]) for i in range(kab_nreq)]
-
-    prev_cache = _tune.set_cache(_tune.PlanCache(path=None))
-    try:
-        # tune every pow2 capacity class, not just kab_batch: the
-        # measured storm's linger-fragmented cohorts flush at any of
-        # them, and an untuned capacity would silently run the xla
-        # DEFAULT while the record claimed a tuner decision ran
-        buckets = {}
-        cap = 1
-        while cap <= kab_batch:
-            buckets[f"cwt_cw_64x8_s16/b{cap}"] = _tune.serve_workload(
-                "sketch_apply", "CWT", "float32", (64, 8), 16,
-                cap, rowwise=False)
-            buckets[f"jlt_rw_64x128_s32/b{cap}"] = _tune.serve_workload(
-                "sketch_apply", "JLT", "float32", (64, 128), 32,
-                cap, rowwise=True)
-            cap *= 2
-        outcomes = {}
-        for bname, w in buckets.items():
-            plan, _cost = _tune.record_ranked(w)
-            modeled = {}
-            for p, c in _tune.rank_candidates(w):
-                modeled.setdefault(
-                    p.backend,
-                    {"modeled_s": float(f"{c['modeled_s']:.3g}"),
-                     "interpret_penalized": bool(c.get("interpret"))})
-            ent = _tune.get_cache().entry(w)
-            outcomes[bname] = {
-                "selected": plan.backend,
-                "source": ent["source"] if ent else None,
-                "candidates": modeled,
-            }
-
-        def kab_run(exk):
-            futs = ([exk.submit_sketch(T, A, dimension=sk.COLUMNWISE)
-                     for (T, A) in cwt_reqs]
-                    + [exk.submit_sketch(T, A, dimension=sk.ROWWISE)
-                       for (T, A) in jlt_reqs])
-            outs = [f.result(timeout=60) for f in futs]
-            jax.block_until_ready(outs)
-            return outs
-
-        def kab_measure(kernel):
-            exk = engine.MicrobatchExecutor(
-                max_batch=kab_batch, linger_us=5000,
-                max_queue=8 * kab_nreq, kernel=kernel)
-            # warm every pow2 capacity class of both buckets up front —
-            # same provably-compile-free discipline as warm_capacities
-            # above: a linger-fragmented straggler cohort in the
-            # measured window must never hit a cold capacity class
-            cap = 1
-            while cap <= kab_batch:
-                futs = ([exk.submit_sketch(T, A, dimension=sk.COLUMNWISE)
-                         for (T, A) in cwt_reqs[:cap]]
-                        + [exk.submit_sketch(T, A, dimension=sk.ROWWISE)
-                           for (T, A) in jlt_reqs[:cap]])
-                exk.flush()
-                jax.block_until_ready(
-                    [f.result(timeout=120) for f in futs])
-                cap *= 2
-            kab_run(exk)                   # warm both buckets
-            m0 = engine.stats().misses
-            r0 = engine.stats().recompiles
-            best = float("inf")
-            for _ in range(rounds):
-                t0 = time.perf_counter()
-                outs = kab_run(exk)
-                best = min(best, time.perf_counter() - t0)
-            st_k = exk.stats()["kernel"]["by_backend"]
-            exk.shutdown()
-            return (2 * kab_nreq / best, outs,
-                    engine.stats().misses - m0,
-                    engine.stats().recompiles - r0, st_k)
-
-        rps_sel, out_sel, m_sel, r_sel, flushes_sel = kab_measure(None)
-        rps_xla, out_xla, _mx, _rx, _fx = kab_measure("xla")
-        kab_equal = all(
-            np.array_equal(np.asarray(a), np.asarray(b))
-            for a, b in zip(out_sel, out_xla))
-        kab_close = all(
-            np.allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
-                        atol=1e-5)
-            for a, b in zip(out_sel, out_xla))
-    finally:
-        _tune.set_cache(prev_cache)
-
-    on_tpu = jax.default_backend() == "tpu"
-    kernel_ab = {
-        "buckets": outcomes,
-        "rps_selected": round(rps_sel, 1),
-        "rps_forced_xla": round(rps_xla, 1),
-        "speedup_selected_vs_xla": round(rps_sel / rps_xla, 2),
-        "selected_flushes_by_backend": {
-            k: v["flushes"] for k, v in flushes_sel.items()},
-        "misses_after_warmup": m_sel,
-        "recompiles_after_warmup": r_sel,
-        "bit_equal_to_forced_xla": kab_equal,
-        "allclose_to_forced_xla": kab_close,
-        "note": None if on_tpu else (
-            "CPU host: the tuner correctly certifies XLA for every "
-            "serve bucket (interpret-mode pallas is a correctness "
-            "surface, not a speed surface — cost.INTERPRET_PENALTY); "
-            "the pallas side of this A/B only opens up on a TPU"),
-    }
-
     rec = {
         "metric": "serve_microbatch_throughput",
         "platform": jax.default_backend(),
@@ -852,7 +724,6 @@ def _serve(n_requests: int = 64, max_batch: int = 16,
         },
         "endpoints": {"solve_l2_sketched": solve_ab,
                       "krr_predict": krr_ab},
-        "kernel_ab": kernel_ab,
         "degraded_mode": degraded_mode,
         "telemetry": _telemetry_snapshot(),
     }
@@ -1730,7 +1601,6 @@ def _boot(capacity: int = 16) -> None:
         "bit_equal_cold": cold["bit_equal"],
         "bit_equal_pack": warm["bit_equal"],
         "pack_loaded": (warm.get("warmup") or {}).get("loaded"),
-        "plan_fingerprint": manifest["plan_fingerprint"],
         "backend": manifest["compat"]["backend"],
         "host_note": (
             "wall-from-spawn includes interpreter + jax import, which "
@@ -2062,7 +1932,7 @@ def _fwht(n_requests: int = 8, max_batch: int = 4, rounds: int = 5,
 
 
 # ---------------------------------------------------------------------------
-# dist-serve measurement: pipelined shard fan-out A/B + cost calibration
+# dist-serve measurement: pipelined shard fan-out A/B
 # ---------------------------------------------------------------------------
 
 
@@ -2098,20 +1968,12 @@ def _dist_serve(n_requests: int = 4, n_replicas: int = 4,
     host class (≥ 0.5×), and the ≥ 2x acceptance target is a
     multi-core/fleet-host expectation, not this host's.
 
-    Also times the XLA scatter-add retire rate (the ``segment_sum``
-    microbench) and appends it as ``cost_calib_scatter_rows_per_s`` —
-    the measured constant ``tune.cost.effective_rates`` overlays on
-    the analytic roofline for this host class
-    (``SKYLARK_COST_CALIB``).
-
     Prints exactly one JSON line; exits nonzero on any violation."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from libskylark_tpu import dist as _dist
     from libskylark_tpu import engine, fleet
-    from libskylark_tpu import tune as _tune
     from libskylark_tpu.dist import plan as _dplan
 
     rng = np.random.default_rng(0)
@@ -2211,21 +2073,6 @@ def _dist_serve(n_requests: int = 4, n_replicas: int = 4,
     rows_s_dist = n_rows * n_requests / best_dist
     speedup = round(rows_s_dist / rows_s_single, 3)
 
-    # -- cost calibration: measured scatter-add retire rate -------------
-    n_sc, s_sc = 1 << 18, 512
-    seg = jnp.asarray(rng.integers(0, s_sc, n_sc, dtype=np.int32))
-    Xs = jnp.asarray(
-        rng.standard_normal((n_sc, 8)).astype(np.float32))
-    scat = jax.jit(lambda x, g: jax.ops.segment_sum(
-        x, g, num_segments=s_sc))
-    scat(Xs, seg).block_until_ready()
-    best_sc = float("inf")
-    for _ in range(5):
-        t0 = time.perf_counter()
-        scat(Xs, seg).block_until_ready()
-        best_sc = min(best_sc, time.perf_counter() - t0)
-    scatter_rate = n_sc / best_sc
-
     rec = {
         "metric": "dist_serve_fanout_speedup",
         "value": speedup,
@@ -2239,175 +2086,18 @@ def _dist_serve(n_requests: int = 4, n_replicas: int = 4,
                  "best_s": round(best_dist, 4),
                  "replicas": n_replicas,
                  "shard_fanout": fanout},
-        "cost_calibration": {
-            "scatter_rows_per_s": round(scatter_rate, 1),
-            "analytic_scatter_rows_per_s":
-                _tune.RATES["scatter_rows_per_s"],
-        },
         "violations": violations,
         "telemetry": _telemetry_snapshot(),
     }
     print(json.dumps(rec), flush=True)
     if violations:
         sys.exit(1)
-    # calibration first, headline last: CI gates key off the ledger
-    # tail, and the dist gate reads the LAST dist_serve record
-    _ledger_append("cost_calib_scatter_rows_per_s",
-                   round(scatter_rate, 1))
     _ledger_append("dist_serve_fanout_speedup", speedup)
-
-
-# ---------------------------------------------------------------------------
-# kernel certification: measured (not ranked) plan-cache entries
-# ---------------------------------------------------------------------------
-
-
-def _certify_kernels(rounds: int = 5, capacity: int = 8) -> int:
-    """Serve-kernel certification (``python bench.py --certify-kernels``):
-    for each of the three Pallas serve kernels, at one real serve-bucket
-    shape and through the entry the serve layer compiles (``qualify`` +
-    the batched launcher): compile it with Mosaic, compare it with its
-    XLA twin, time one flush of each. Per kernel the outcome is
-    ``matches`` / ``rejected`` (with the Mosaic message) / ``mismatch``
-    / ``unqualified`` (with qualify's reason).
-
-    The kernel side runs on a TPU only: off-TPU a pallas flush is the
-    interpreter, a correctness surface the tests cover, and the job
-    times the XLA twins alone. ``--record-plan`` on a TPU writes the
-    winner of each bucket into the plan cache as a **measured** entry
-    (``tune.record_measurement``); nothing is written otherwise. Prints
-    exactly one JSON line; exits non-zero when a kernel mismatches."""
-    from functools import partial
-
-    import jax
-    import jax.numpy as jnp
-    import jax.random as jr
-    import numpy as np
-
-    from libskylark_tpu import tune
-    from libskylark_tpu.base import randgen
-    from libskylark_tpu.sketch import (pallas_dense, pallas_fastfood,
-                                       pallas_hash)
-    from libskylark_tpu.sketch.dense import serve_apply
-    from libskylark_tpu.sketch.frft import fastfood_serve_apply
-    from libskylark_tpu.sketch.hash import cwt_serve_apply
-
-    on_tpu = jax.default_backend() == "tpu"
-    record = on_tpu and "--record-plan" in sys.argv
-    small = not on_tpu  # CPU: XLA twins only, at a size a CPU finishes
-    rng = np.random.default_rng(0)
-    B = capacity
-    kd = np.stack([np.asarray(jr.key_data(jr.key(i)), dtype=np.uint32)
-                   for i in range(B)])
-
-    def time_flush(fn):
-        jax.block_until_ready(fn())
-        best = float("inf")
-        for _ in range(rounds):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn())
-            best = min(best, time.perf_counter() - t0)
-        return best
-
-    # (name, workload, (ok, why) of the kernel's qualify, pallas fn,
-    # xla fn, args): both candidates are jitted over the same
-    # device-resident args, the way the serve layer compiles them into
-    # one flush executable
-    buckets = []
-    kdj = jnp.asarray(kd)
-    normal = randgen.Normal()
-
-    m, n, s_dim = (64, 512, 64) if small else (2048, 8192, 1024)
-    geo = dict(dist=normal, s_dim=s_dim, rowwise=True)
-    buckets.append((
-        f"pallas_dense jlt_rw_{m}x{n}_s{s_dim}",
-        tune.serve_workload("sketch_apply", "JLT", "float32", (m, n),
-                            s_dim, B, rowwise=True),
-        pallas_dense.serve_qualify(normal, s_dim, n, m, "float32"),
-        partial(pallas_dense.serve_batched_apply, **geo),
-        jax.vmap(partial(serve_apply, **geo)),
-        (kdj, jnp.full((B,), 1.0 / np.sqrt(s_dim), jnp.float32),
-         jnp.asarray(rng.standard_normal((B, m, n), dtype=np.float32)))))
-
-    n, m, s_dim = (512, 16, 64) if small else (8192, 512, 1024)
-    geo = dict(s_dim=s_dim, rowwise=False)
-    buckets.append((
-        f"pallas_hash cwt_cw_{n}x{m}_s{s_dim}",
-        tune.serve_workload("sketch_apply", "CWT", "float32", (n, m),
-                            s_dim, B, rowwise=False),
-        pallas_hash.qualify(s_dim, n, m, "float32"),
-        partial(pallas_hash.cwt_apply_batched, accum="mxu", **geo),
-        jax.vmap(partial(cwt_serve_apply, **geo)),
-        (kdj, jnp.asarray(
-            rng.standard_normal((B, n, m), dtype=np.float32)))))
-
-    m, d = (16, 512) if small else (2048, 4096)
-    geo = dict(n_dim=d, s_dim=d, fut="wht", sm_kind="gauss", sm_param=1.0)
-    buckets.append((
-        f"pallas_fastfood ff_{m}x{d}_s{d}",
-        tune.serve_workload("fastfood_features", "FastGaussianRFT",
-                            "float32", (m, d), d, B),
-        pallas_fastfood.serve_qualify(d, d, m, "float32", "wht"),
-        partial(pallas_fastfood.serve_features_batched, **geo),
-        jax.vmap(partial(fastfood_serve_apply, **geo)),
-        (kdj, jnp.asarray(
-            rng.standard_normal((B, m, d), dtype=np.float32)))))
-
-    results, mismatched, written = {}, [], 0
-    for name, w, (ok, why), pallas_fn, xla_fn, args in buckets:
-        row = {"workload": w.key()}
-        xla_fn, pallas_fn = jax.jit(xla_fn), jax.jit(pallas_fn)
-        ref = np.asarray(xla_fn(*args))
-        xla_s = time_flush(lambda: xla_fn(*args))
-        row["xla_flush_s"] = round(xla_s, 6)
-        winner = ("xla", xla_s)
-        if not on_tpu:
-            row["outcome"] = "not run (no TPU; interpret mode is for tests)"
-        elif not ok:
-            row["outcome"] = f"unqualified: {why}"
-        else:
-            try:
-                got = np.asarray(pallas_fn(*args))
-            except Exception as e:  # noqa: BLE001 — the Mosaic message IS
-                # the finding this job exists to record
-                row["outcome"] = "rejected: " + " ".join(
-                    str(e).split())[:600]
-            else:
-                err = float(np.max(np.abs(got - ref))
-                            / max(np.max(np.abs(ref)), 1e-30))
-                row["rel_max_err"] = err
-                if err <= 1e-4:
-                    row["outcome"] = "matches"
-                    pallas_s = time_flush(lambda: pallas_fn(*args))
-                    row["pallas_flush_s"] = round(pallas_s, 6)
-                    if pallas_s < xla_s:
-                        winner = ("pallas", pallas_s)
-                else:
-                    row["outcome"] = f"mismatch: rel-max {err:.3e}"
-                    mismatched.append(name)
-        row["winner"] = winner[0]
-        if record:
-            written += int(tune.record_measurement(
-                w, tune.Plan(winner[0]), 1.0 / winner[1],
-                unit="flushes/s",
-                extra={"certified_by": "bench.py --certify-kernels",
-                       "capacity": capacity}))
-        results[name] = row
-
-    print(json.dumps({
-        "metric": "kernel_certification",
-        **_device_block(),
-        "capacity": capacity,
-        "rounds": rounds,
-        "measured_entries_written": written,
-        "buckets": results,
-    }), flush=True)
-    return 1 if mismatched else 0
 
 
 def _telemetry_snapshot():
     """The unified registry snapshot every benchmarks record embeds, so
-    BENCH_*.json trajectories carry the cache/serve/resilience/tune/io
+    BENCH_*.json trajectories carry the cache/serve/resilience/io
     counters alongside the timings (docs/observability). Collectors
     report with telemetry disabled too — they re-home counters the
     subsystems maintain anyway — so this costs nothing extra in the
@@ -2486,7 +2176,6 @@ if __name__ == "__main__":
         "--fleet": _fleet, "--boot": _boot, "--sparse": _sparse,
         "--cache": _cache, "--net": _net, "--fwht": _fwht,
         "--dist-serve": _dist_serve,
-        "--certify-kernels": _certify_kernels,
     }
     for _flag, _mode in _MODES.items():
         if _flag in sys.argv:

@@ -52,12 +52,21 @@ def _sds(shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
 
 
-def _analyze(name, jitted, *avals) -> dict:
-    # promoted into the package as the autotuner's compiled-HLO cost
-    # oracle; this script keeps the CI-gate orchestration around it
-    from libskylark_tpu.tune.cost import analyze_jitted
-
-    return analyze_jitted(name, jitted, *avals)
+def _analyze(name: str, jitted, *avals) -> dict:
+    """Lower+compile ``jitted`` at ``avals`` and return its XLA cost /
+    memory analysis as a flat record. Deterministic for fixed shapes and
+    toolchain — zero hardware, zero timing noise."""
+    compiled = jitted.lower(*avals).compile()
+    ca = compiled.cost_analysis()
+    mem = compiled.memory_analysis()
+    return {
+        "config": name,
+        "flops": float(ca.get("flops", 0.0)),
+        "bytes_accessed": float(ca.get("bytes accessed", 0.0)),
+        "argument_bytes": int(getattr(mem, "argument_size_in_bytes", 0)),
+        "output_bytes": int(getattr(mem, "output_size_in_bytes", 0)),
+        "temp_bytes": int(getattr(mem, "temp_size_in_bytes", 0)),
+    }
 
 
 def cfg_jlt_xla():

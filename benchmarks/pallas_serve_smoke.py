@@ -3,23 +3,15 @@
 A 2-bucket serve mix asserting the r12 flush-kernel selection contract
 end to end, fast enough for the per-commit gate:
 
-- **offline tuning**: every (bucket, capacity class) workload of the
-  mix is ranked by the hardware-free cost model (``tune.record_
-  ranked``) into an in-memory plan cache — the committed
-  ``benchmarks/plan_cache.json`` is never touched — and the gate
-  asserts the cache then holds a ranked kernel decision (backend
-  pallas|xla, source "ranked") for every bucket. On a CPU host the
-  decision must be "xla" for every serve bucket: interpret-mode pallas
-  is a correctness surface, not a speed surface, and the cost model's
-  interpret penalty encodes exactly that (the honesty the committed
-  bench record carries in prose);
-- **zero recompiles with selection enabled**: a selection-enabled
-  executor (``kernel=None`` — arg > env > plan cache > default) warms
+- **the default is XLA**: an executor built with no pin
+  (``kernel=None`` — arg > env > default) flushes every bucket of the
+  mix through the vmapped XLA program, counted by backend;
+- **zero recompiles with selection enabled**: that executor warms
   the capacity ladder of both buckets, then two measured storms run
   with ZERO engine cache misses and ZERO recompiles — the kernel
   choice is a static of the executable key resolved from a memoized
-  (bucket, capacity, plan-fingerprint) triple, so steady-state
-  selection can never retrace a warm bucket;
+  (bucket, capacity) pair, so steady-state selection can never
+  retrace a warm bucket;
 - **bit-equality of the kernel path**: a forced-pallas coalesced CWT
   flush (exact-accumulation under the interpreter) is bit-equal to
   the capacity-1 forced-XLA dispatch, request by request — the
@@ -53,7 +45,7 @@ CAPACITIES = (1, 2, 4, 8)
 def main() -> int:
     import jax
 
-    from libskylark_tpu import Context, engine, tune
+    from libskylark_tpu import Context, engine
     from libskylark_tpu import sketch as sk
 
     rng = np.random.default_rng(0)
@@ -73,138 +65,104 @@ def main() -> int:
         jlt_reqs.append((T, A))
 
     engine.reset()
-    prev_cache = tune.set_cache(tune.PlanCache(path=None))
-    try:
-        # -- offline tuning: rank every (bucket, capacity) workload ------
-        decisions = {}
+    # -- selection enabled: warm ladder, then zero-compile storms ----
+    ex = engine.MicrobatchExecutor(max_batch=MAX_BATCH,
+                                   linger_us=5000,
+                                   max_queue=8 * N_REQUESTS)
+
+    def storm():
+        futs = ([ex.submit_sketch(T, A, dimension=sk.COLUMNWISE)
+                 for (T, A) in cwt_reqs]
+                + [ex.submit_sketch(T, A, dimension=sk.ROWWISE)
+                   for (T, A) in jlt_reqs])
+        outs = [f.result(timeout=120) for f in futs]
+        jax.block_until_ready(outs)
+        return outs
+
+    for reqs, dim in ((cwt_reqs, sk.COLUMNWISE),
+                      (jlt_reqs, sk.ROWWISE)):
         for cap in CAPACITIES:
-            buckets = {
-                f"cwt_cw_64x8_s16/b{cap}": tune.serve_workload(
-                    "sketch_apply", "CWT", "float32", (64, 8), 16, cap,
-                    rowwise=False),
-                f"jlt_rw_64x128_s32/b{cap}": tune.serve_workload(
-                    "sketch_apply", "JLT", "float32", (64, 128), 32,
-                    cap, rowwise=True),
-            }
-            for bname, w in buckets.items():
-                plan, _cost = tune.record_ranked(w)
-                ent = tune.get_cache().entry(w)
-                decisions[bname] = {
-                    "backend": plan.backend,
-                    "source": ent["source"] if ent else None,
-                }
-                if ent is None or ent.get("source") != "ranked":
-                    violations.append(
-                        f"{bname}: no ranked plan-cache entry after "
-                        "record_ranked")
-                elif ent["plan"]["backend"] not in ("pallas", "xla"):
-                    violations.append(
-                        f"{bname}: unranked backend "
-                        f"{ent['plan']['backend']!r}")
-                if (jax.default_backend() != "tpu"
-                        and plan.backend != "xla"):
-                    violations.append(
-                        f"{bname}: tuner picked {plan.backend!r} on a "
-                        "non-TPU host — the interpret penalty must "
-                        "certify XLA off-silicon")
+            futs = [ex.submit_sketch(T, A, dimension=dim)
+                    for (T, A) in reqs[:cap]]
+            ex.flush()
+            [f.result(timeout=120) for f in futs]
+    storm()
+    misses_before = engine.stats().misses
+    recompiles_before = engine.stats().recompiles
+    sel_outs = storm()
+    storm()
+    misses = engine.stats().misses - misses_before
+    recompiles = engine.stats().recompiles - recompiles_before
+    sel_flushes = ex.stats()["kernel"]["by_backend"]
+    ex.shutdown()
+    if misses:
+        violations.append(
+            f"{misses} engine cache miss(es) after per-bucket "
+            "warmup with selection enabled")
+    if recompiles:
+        violations.append(
+            f"{recompiles} executable recompile(s) with selection "
+            "enabled")
+    if not sel_flushes:
+        violations.append(
+            "selection-enabled executor counted no kernel flushes "
+            "— the by_backend counter went inert")
+    if set(sel_flushes) - {"xla"}:
+        violations.append(
+            "an executor with no pin flushed through "
+            f"{sorted(sel_flushes)} — the default is XLA")
 
-        # -- selection enabled: warm ladder, then zero-compile storms ----
-        ex = engine.MicrobatchExecutor(max_batch=MAX_BATCH,
-                                       linger_us=5000,
-                                       max_queue=8 * N_REQUESTS)
-
-        def storm():
-            futs = ([ex.submit_sketch(T, A, dimension=sk.COLUMNWISE)
-                     for (T, A) in cwt_reqs]
-                    + [ex.submit_sketch(T, A, dimension=sk.ROWWISE)
-                       for (T, A) in jlt_reqs])
-            outs = [f.result(timeout=120) for f in futs]
-            jax.block_until_ready(outs)
-            return outs
-
-        for reqs, dim in ((cwt_reqs, sk.COLUMNWISE),
-                          (jlt_reqs, sk.ROWWISE)):
-            for cap in CAPACITIES:
-                futs = [ex.submit_sketch(T, A, dimension=dim)
-                        for (T, A) in reqs[:cap]]
-                ex.flush()
-                [f.result(timeout=120) for f in futs]
-        storm()
-        misses_before = engine.stats().misses
-        recompiles_before = engine.stats().recompiles
-        sel_outs = storm()
-        storm()
-        misses = engine.stats().misses - misses_before
-        recompiles = engine.stats().recompiles - recompiles_before
-        sel_flushes = ex.stats()["kernel"]["by_backend"]
-        ex.shutdown()
-        if misses:
+    # -- bit-equality: forced kernel path vs capacity-1 XLA ----------
+    with engine.MicrobatchExecutor(max_batch=MAX_BATCH,
+                                   linger_us=5000,
+                                   kernel="pallas") as exp:
+        pfuts = ([exp.submit_sketch(T, A, dimension=sk.COLUMNWISE)
+                  for (T, A) in cwt_reqs]
+                 + [exp.submit_sketch(T, A, dimension=sk.ROWWISE)
+                    for (T, A) in jlt_reqs])
+        pouts = [np.asarray(f.result(timeout=120)) for f in pfuts]
+        pstats = exp.stats()["kernel"]["by_backend"]
+    if not pstats.get("pallas", {}).get("flushes"):
+        violations.append(
+            "forced-pallas executor served no pallas flushes "
+            f"(by_backend={pstats})")
+    with engine.MicrobatchExecutor(max_batch=1, linger_us=100,
+                                   kernel="xla") as ex1:
+        xouts = []
+        for (T, A) in cwt_reqs:
+            xouts.append(np.asarray(ex1.submit_sketch(
+                T, A, dimension=sk.COLUMNWISE).result(timeout=120)))
+        for (T, A) in jlt_reqs:
+            xouts.append(np.asarray(ex1.submit_sketch(
+                T, A, dimension=sk.ROWWISE).result(timeout=120)))
+    n_cwt = len(cwt_reqs)
+    for i in range(n_cwt):
+        if not np.array_equal(pouts[i], xouts[i]):
             violations.append(
-                f"{misses} engine cache miss(es) after per-bucket "
-                "warmup with selection enabled")
-        if recompiles:
+                f"CWT request {i}: kernel-path flush not bit-equal "
+                "to capacity-1 XLA dispatch")
+            break
+    # the dense-kernel oracle band (test_pallas_dense): the batched
+    # kernel's bf16x3 regime reorders f32 sums the XLA vmapped path
+    # accumulates exactly
+    for i in range(n_cwt, len(pouts)):
+        if not np.allclose(pouts[i], xouts[i], rtol=1e-4,
+                           atol=1e-4):
             violations.append(
-                f"{recompiles} executable recompile(s) with selection "
-                "enabled")
-        if not sel_flushes:
+                f"JLT request {i - n_cwt}: kernel-path flush "
+                "diverged from capacity-1 XLA dispatch")
+            break
+    for i in range(n_cwt):
+        if not np.array_equal(np.asarray(sel_outs[i]), xouts[i]):
             violations.append(
-                "selection-enabled executor counted no kernel flushes "
-                "— the by_backend counter went inert")
-
-        # -- bit-equality: forced kernel path vs capacity-1 XLA ----------
-        with engine.MicrobatchExecutor(max_batch=MAX_BATCH,
-                                       linger_us=5000,
-                                       kernel="pallas") as exp:
-            pfuts = ([exp.submit_sketch(T, A, dimension=sk.COLUMNWISE)
-                      for (T, A) in cwt_reqs]
-                     + [exp.submit_sketch(T, A, dimension=sk.ROWWISE)
-                        for (T, A) in jlt_reqs])
-            pouts = [np.asarray(f.result(timeout=120)) for f in pfuts]
-            pstats = exp.stats()["kernel"]["by_backend"]
-        if not pstats.get("pallas", {}).get("flushes"):
-            violations.append(
-                "forced-pallas executor served no pallas flushes "
-                f"(by_backend={pstats})")
-        with engine.MicrobatchExecutor(max_batch=1, linger_us=100,
-                                       kernel="xla") as ex1:
-            xouts = []
-            for (T, A) in cwt_reqs:
-                xouts.append(np.asarray(ex1.submit_sketch(
-                    T, A, dimension=sk.COLUMNWISE).result(timeout=120)))
-            for (T, A) in jlt_reqs:
-                xouts.append(np.asarray(ex1.submit_sketch(
-                    T, A, dimension=sk.ROWWISE).result(timeout=120)))
-        n_cwt = len(cwt_reqs)
-        for i in range(n_cwt):
-            if not np.array_equal(pouts[i], xouts[i]):
-                violations.append(
-                    f"CWT request {i}: kernel-path flush not bit-equal "
-                    "to capacity-1 XLA dispatch")
-                break
-        # the dense-kernel oracle band (test_pallas_dense): the batched
-        # kernel's bf16x3 regime reorders f32 sums the XLA vmapped path
-        # accumulates exactly
-        for i in range(n_cwt, len(pouts)):
-            if not np.allclose(pouts[i], xouts[i], rtol=1e-4,
-                               atol=1e-4):
-                violations.append(
-                    f"JLT request {i - n_cwt}: kernel-path flush "
-                    "diverged from capacity-1 XLA dispatch")
-                break
-        for i in range(n_cwt):
-            if not np.array_equal(np.asarray(sel_outs[i]), xouts[i]):
-                violations.append(
-                    f"CWT request {i}: selection-enabled flush not "
-                    "bit-equal to capacity-1 XLA dispatch")
-                break
-    finally:
-        tune.set_cache(prev_cache)
+                f"CWT request {i}: selection-enabled flush not "
+                "bit-equal to capacity-1 XLA dispatch")
+            break
 
     rec = {
         "metric": "pallas_serve_smoke",
         "n_requests": 2 * N_REQUESTS,
         "max_batch": MAX_BATCH,
-        "decisions": decisions,
         "selection_flushes_by_backend": {
             k: v["flushes"] for k, v in sel_flushes.items()},
         "forced_pallas_flushes_by_backend": {

@@ -4,12 +4,10 @@ A CSR serve mix asserting the sparse-operand hot-path contract
 (docs/serving, "Sparse operands on the serve path") end to end, fast
 enough for the per-commit gate:
 
-- **offline tuning**: every (sparse bucket, capacity class) workload —
-  keyed on the pow2 nnz class as well as the padded dims — is ranked
-  by the nnz-aware cost model into an in-memory plan cache (the
-  committed ``benchmarks/plan_cache.json`` is never touched), and the
-  decision must be "xla": a sparse flush has one program, the vmapped
-  lane function with the scatter;
+- **one program**: the executor is pinned ``kernel="pallas"`` and
+  every sparse flush still counts as "xla" — a sparse flush has one
+  program, the vmapped lane function with the scatter, and a pallas
+  intent on it is a counted decline;
 - **ragged-nnz coalescing**: requests whose nnz differ inside one
   class land in ONE bucket and flush as one executable — asserted via
   ``request_statics`` identity, the coalesced counter, and ZERO engine
@@ -54,7 +52,7 @@ def main() -> int:
     import jax
     import scipy.sparse as sp
 
-    from libskylark_tpu import Context, engine, tune
+    from libskylark_tpu import Context, engine
     from libskylark_tpu import sketch as sk
     from libskylark_tpu.base.sparse import SparseMatrix
     from libskylark_tpu.engine.serve import request_statics
@@ -96,131 +94,111 @@ def main() -> int:
             "is not in the bucket statics")
 
     engine.reset()
-    prev_cache = tune.set_cache(tune.PlanCache(path=None))
-    decisions = {}
-    try:
-        # -- offline tuning: rank every (bucket, capacity) workload ----
-        for cap in CAPACITIES:
-            w = tune.serve_workload(
-                "sparse_sketch_apply", "CWT", "float32",
-                (N_DIM, M_DIM), S_DIM, cap, rowwise=False,
-                nnz=64)
-            plan, _cost = tune.record_ranked(w)
-            ent = tune.get_cache().entry(w)
-            decisions[f"sparse_cwt/b{cap}"] = {
-                "backend": plan.backend,
-                "source": ent["source"] if ent else None,
-            }
-            if ent is None or ent.get("source") != "ranked":
-                violations.append(
-                    f"sparse_cwt/b{cap}: no ranked plan-cache entry")
-            if plan.backend != "xla":
-                violations.append(
-                    f"sparse_cwt/b{cap}: tuner picked {plan.backend!r} "
-                    "— the sparse flush has no batched kernel")
+    # -- warm ladder, then zero-compile storms ---------------------
+    ex = engine.MicrobatchExecutor(max_batch=MAX_BATCH,
+                                   linger_us=5000,
+                                   max_queue=8 * N_REQUESTS,
+                                   kernel="pallas")
 
-        # -- warm ladder, then zero-compile storms ---------------------
-        ex = engine.MicrobatchExecutor(max_batch=MAX_BATCH,
-                                       linger_us=5000,
-                                       max_queue=8 * N_REQUESTS)
-
-        def storm():
-            futs = ([ex.submit_sparse(T_cwt, A, dimension=sk.COLUMNWISE)
-                     for A in cwt_reqs]
-                    + [ex.submit_sparse(T_jlt, A,
-                                        dimension=sk.COLUMNWISE)
-                       for A in jlt_reqs])
-            outs = [f.result(timeout=120) for f in futs]
-            jax.block_until_ready(outs)
-            return outs
-
-        for T, reqs in ((T_cwt, cwt_reqs), (T_jlt, jlt_reqs)):
-            for cap in CAPACITIES:
-                futs = [ex.submit_sparse(T, A, dimension=sk.COLUMNWISE)
-                        for A in reqs[:cap]]
-                ex.flush()
-                [f.result(timeout=120) for f in futs]
-        storm()
-        misses_before = engine.stats().misses
-        recompiles_before = engine.stats().recompiles
-        outs = storm()
-        storm()
-        misses = engine.stats().misses - misses_before
-        recompiles = engine.stats().recompiles - recompiles_before
-        st = ex.stats()
-        if misses:
-            violations.append(
-                f"{misses} engine cache miss(es) after per-bucket "
-                "warmup on the sparse path")
-        if recompiles:
-            violations.append(
-                f"{recompiles} executable recompile(s) on the warm "
-                "sparse path")
-        if not st["coalesced"]:
-            violations.append("no coalesced sparse requests — the "
-                              "ragged-nnz cohort never shared a flush")
-        if not st["sparse"]["submits"]:
-            violations.append("sparse submit counter inert")
-
-        # -- bit-equality: densified reference + capacity-1 ------------
-        refs = ([np.asarray(T_cwt.apply(A.todense(), sk.COLUMNWISE))
+    def storm():
+        futs = ([ex.submit_sparse(T_cwt, A, dimension=sk.COLUMNWISE)
                  for A in cwt_reqs]
-                + [np.asarray(T_jlt.apply(A.todense(), sk.COLUMNWISE))
+                + [ex.submit_sparse(T_jlt, A,
+                                    dimension=sk.COLUMNWISE)
                    for A in jlt_reqs])
-        for i, (o, r) in enumerate(zip(outs, refs)):
-            if not np.array_equal(np.asarray(o), r):
+        outs = [f.result(timeout=120) for f in futs]
+        jax.block_until_ready(outs)
+        return outs
+
+    for T, reqs in ((T_cwt, cwt_reqs), (T_jlt, jlt_reqs)):
+        for cap in CAPACITIES:
+            futs = [ex.submit_sparse(T, A, dimension=sk.COLUMNWISE)
+                    for A in reqs[:cap]]
+            ex.flush()
+            [f.result(timeout=120) for f in futs]
+    storm()
+    misses_before = engine.stats().misses
+    recompiles_before = engine.stats().recompiles
+    outs = storm()
+    storm()
+    misses = engine.stats().misses - misses_before
+    recompiles = engine.stats().recompiles - recompiles_before
+    st = ex.stats()
+    if misses:
+        violations.append(
+            f"{misses} engine cache miss(es) after per-bucket "
+            "warmup on the sparse path")
+    if recompiles:
+        violations.append(
+            f"{recompiles} executable recompile(s) on the warm "
+            "sparse path")
+    if not st["coalesced"]:
+        violations.append("no coalesced sparse requests — the "
+                          "ragged-nnz cohort never shared a flush")
+    if not st["sparse"]["submits"]:
+        violations.append("sparse submit counter inert")
+    if set(st["kernel"]["by_backend"]) != {"xla"}:
+        violations.append(
+            "a pallas pin on a sparse bucket flushed through "
+            f"{sorted(st['kernel']['by_backend'])} — the sparse "
+            "flush has no batched kernel")
+
+    # -- bit-equality: densified reference + capacity-1 ------------
+    refs = ([np.asarray(T_cwt.apply(A.todense(), sk.COLUMNWISE))
+             for A in cwt_reqs]
+            + [np.asarray(T_jlt.apply(A.todense(), sk.COLUMNWISE))
+               for A in jlt_reqs])
+    for i, (o, r) in enumerate(zip(outs, refs)):
+        if not np.array_equal(np.asarray(o), r):
+            violations.append(
+                f"request {i}: sparse flush not bit-equal to the "
+                "densified reference (todense -> transform.apply)")
+            break
+    with engine.MicrobatchExecutor(max_batch=1,
+                                   linger_us=100) as ex1:
+        for i, (T, A) in enumerate(
+                [(T_cwt, A) for A in cwt_reqs]
+                + [(T_jlt, A) for A in jlt_reqs]):
+            one = np.asarray(ex1.submit_sparse(
+                T, A, dimension=sk.COLUMNWISE).result(timeout=120))
+            if not np.array_equal(np.asarray(outs[i]), one):
                 violations.append(
-                    f"request {i}: sparse flush not bit-equal to the "
-                    "densified reference (todense -> transform.apply)")
+                    f"request {i}: coalesced sparse flush not "
+                    "bit-equal to capacity-1 dispatch")
                 break
-        with engine.MicrobatchExecutor(max_batch=1,
-                                       linger_us=100) as ex1:
-            for i, (T, A) in enumerate(
-                    [(T_cwt, A) for A in cwt_reqs]
-                    + [(T_jlt, A) for A in jlt_reqs]):
-                one = np.asarray(ex1.submit_sparse(
-                    T, A, dimension=sk.COLUMNWISE).result(timeout=120))
-                if not np.array_equal(np.asarray(outs[i]), one):
-                    violations.append(
-                        f"request {i}: coalesced sparse flush not "
-                        "bit-equal to capacity-1 dispatch")
-                    break
 
-        # -- densify fallback ------------------------------------------
-        dense_ish = rand_sparse(int(N_DIM * M_DIM * 0.5))
-        d0 = ex.stats()["sparse"]["densified"]
-        fut = ex.submit_sparse(T_cwt, dense_ish,
-                               dimension=sk.COLUMNWISE)
-        got = np.asarray(fut.result(timeout=120))
-        if ex.stats()["sparse"]["densified"] != d0 + 1:
-            violations.append(
-                "densify fallback not counted for a 50%-dense operand")
-        if not np.array_equal(
-                got, np.asarray(T_cwt.apply(dense_ish.todense(),
-                                            sk.COLUMNWISE))):
-            violations.append("densified fallback result diverged")
+    # -- densify fallback ------------------------------------------
+    dense_ish = rand_sparse(int(N_DIM * M_DIM * 0.5))
+    d0 = ex.stats()["sparse"]["densified"]
+    fut = ex.submit_sparse(T_cwt, dense_ish,
+                           dimension=sk.COLUMNWISE)
+    got = np.asarray(fut.result(timeout=120))
+    if ex.stats()["sparse"]["densified"] != d0 + 1:
+        violations.append(
+            "densify fallback not counted for a 50%-dense operand")
+    if not np.array_equal(
+            got, np.asarray(T_cwt.apply(dense_ish.todense(),
+                                        sk.COLUMNWISE))):
+        violations.append("densified fallback result diverged")
 
-        # -- sparse solve ----------------------------------------------
-        T_s = sk.CWT(64, 32, ctx)
-        A_s = rand_sparse(30, h=64, w=6)
-        B_s = rng.standard_normal((64, 2)).astype(np.float32)
-        xs = np.asarray(ex.submit_sparse_solve(
-            A_s, B_s, T_s).result(timeout=120))
-        xd = np.asarray(ex.submit_solve(
-            np.asarray(A_s.todense()), B_s, T_s).result(timeout=120))
-        if not np.array_equal(xs, xd):
-            violations.append(
-                "sparse solve not bit-equal to the dense serve solve "
-                "on the densified operand")
-        ex.shutdown()
-    finally:
-        tune.set_cache(prev_cache)
+    # -- sparse solve ----------------------------------------------
+    T_s = sk.CWT(64, 32, ctx)
+    A_s = rand_sparse(30, h=64, w=6)
+    B_s = rng.standard_normal((64, 2)).astype(np.float32)
+    xs = np.asarray(ex.submit_sparse_solve(
+        A_s, B_s, T_s).result(timeout=120))
+    xd = np.asarray(ex.submit_solve(
+        np.asarray(A_s.todense()), B_s, T_s).result(timeout=120))
+    if not np.array_equal(xs, xd):
+        violations.append(
+            "sparse solve not bit-equal to the dense serve solve "
+            "on the densified operand")
+    ex.shutdown()
 
     rec = {
         "metric": "sparse_serve_smoke",
         "n_requests": 2 * N_REQUESTS,
         "max_batch": MAX_BATCH,
-        "decisions": decisions,
         "misses_after_warmup": misses,
         "recompiles_after_warmup": recompiles,
         "sparse_stats": st["sparse"],

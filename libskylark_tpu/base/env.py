@@ -29,7 +29,7 @@ Parse conventions (the repo's, now in one place):
 
 - *flag*: set-and-not-``"0"``/empty is on (``SKYLARK_TELEMETRY``);
 - *off-words*: ``0/off/no/false/""`` disable a path-valued variable
-  (``SKYLARK_PLAN_CACHE=off``);
+  (``SKYLARK_AOT_DIR=off``);
 - *typo degrades to default*: a malformed int/float never crashes a
   sketch apply — it falls back to the declared default.
 """
@@ -51,7 +51,7 @@ def parse_flag(raw: str) -> bool:
 
 
 def parse_bool_default_on(raw: str) -> bool:
-    """Off only for an explicit off-word (``SKYLARK_USE_PLAN_CACHE``)."""
+    """Off only for an explicit off-word (``SKYLARK_FLEET_SHM``)."""
     return raw.strip().lower() not in OFF_WORDS
 
 
@@ -248,8 +248,8 @@ SERVE_KERNEL = declare(
                         if raw.strip().lower() in SERVE_KERNEL_BACKENDS
                         else None),
     doc="One-shot flush-kernel override between the executor argument "
-        "and the tune plan cache (``pallas`` | ``xla``; anything else "
-        "degrades to cache consultation).")
+        "and the XLA default (``pallas`` | ``xla``; anything else "
+        "degrades to the default).")
 
 BOOT_T0 = declare(
     "SKYLARK_BOOT_T0", default=None, parser=parse_float, kind="float",
@@ -518,32 +518,6 @@ LOCK_WITNESS = declare(
     doc="Instrumented-lock mode: locks built by ``base.locks`` record "
         "their runtime acquisition order and the witness fails on "
         "cycles (enabled in the CI chaos battery; docs/analysis).")
-
-# -- tune / plan cache ------------------------------------------------------
-
-PLAN_CACHE = declare(
-    "SKYLARK_PLAN_CACHE", default=None, parser=parse_path_or_off,
-    kind="path", propagate=True,
-    doc="Autotuner plan-cache file. Unset: the repo/benchmarks or "
-        "``~/.cache`` default; an off-word disables persistence.")
-
-USE_PLAN_CACHE = declare(
-    "SKYLARK_USE_PLAN_CACHE", default=True, parser=parse_bool_default_on,
-    kind="flag",
-    doc="The serve tier consults the plan cache when it picks a "
-        "bucket's flush kernel (default on); ``0`` disables all "
-        "cached-plan consultation.")
-
-COST_CALIB = declare(
-    "SKYLARK_COST_CALIB", default=None, parser=parse_path_or_off,
-    kind="path",
-    doc="Measured calibration source for the analytic cost model "
-        "(``tune/cost.py``): a ``benchmarks/ledger.json``-format file "
-        "whose ``cost_calib_<rate>`` records (written by ``bench.py`` "
-        "modes) override the hand-set roofline rates for the matching "
-        "host class, with provenance tracked per rate. ``auto`` "
-        "resolves the repo ledger; unset or an off-word keeps the "
-        "pure analytic model (docs/performance).")
 
 # -- sparse serve operands (engine/serve.py, docs/serving) ------------------
 

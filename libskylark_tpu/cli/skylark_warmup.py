@@ -4,15 +4,12 @@ The deployment half of the zero-recompile fleet boot
 (docs/performance, "Persistent AOT artifacts & warmup packs"):
 
 ``build``
-    Select the top-N hot serve buckets — from the tune plan cache and
-    optionally a serve-stats JSON (telemetry snapshot or
-    ``SKYLARK_ENGINE_STATS_DUMP`` artifact) — or take explicit
-    ``--spec`` JSON bucket specs, precompile every (bucket, capacity)
-    executable, and serialize the pack (artifacts + ``pack.json``
-    manifest) into ``--pack``.
+    Take ``--spec`` JSON bucket specs, precompile every (bucket,
+    capacity) executable, and serialize the pack (artifacts +
+    ``pack.json`` manifest) into ``--pack``.
 ``inspect``
     Print the manifest summary and whether THIS host/runtime would
-    accept the pack (compat probe + plan-fingerprint check).
+    accept the pack (schema + compat probe).
 ``verify``
     Actually load the pack into this process and report the loader's
     counts — a booted replica should see ``loaded == entries`` and
@@ -20,8 +17,6 @@ The deployment half of the zero-recompile fleet boot
 
 Examples::
 
-    skylark_warmup build --pack /var/skylark/pack --top 8 \\
-        --stats /var/skylark/engine_stats.json
     skylark_warmup build --pack pack --spec '{"endpoint": \\
         "sketch_apply", "family": "JLT", "n": 128, "m": 64, \\
         "s_dim": 32, "rowwise": true, "capacities": [1, 8, 16]}'
@@ -46,16 +41,9 @@ def build_parser() -> argparse.ArgumentParser:
     b = sub.add_parser("build", help="precompile + serialize a pack")
     b.add_argument("--pack", required=True,
                    help="pack directory (created if missing)")
-    b.add_argument("--top", type=int, default=8,
-                   help="top-N buckets from the tune plan cache "
-                        "(ignored when --spec is given)")
-    b.add_argument("--stats", default=None,
-                   help="serve-stats JSON (telemetry snapshot or "
-                        "dump_stats artifact) ranking hot capacity "
-                        "classes for selection")
-    b.add_argument("--spec", action="append", default=[],
-                   help="explicit bucket spec as JSON (repeatable); "
-                        "see engine.warmup.BucketSpec")
+    b.add_argument("--spec", action="append", required=True,
+                   help="bucket spec as JSON (repeatable); see "
+                        "engine.warmup.BucketSpec")
     b.add_argument("--pad-floor", type=int, default=None)
 
     for name, hlp in (("inspect", "manifest summary + compat probe"),
@@ -76,35 +64,11 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
-def _load_stats(path: str) -> dict:
-    with open(path) as fh:
-        doc = json.load(fh)
-    # accept a dump_stats artifact ({"serve": {...}}), a telemetry
-    # snapshot ({"collectors": {"serve": {...}}}), or a bare block
-    if "batch_capacity_hist" in doc:
-        return doc
-    if isinstance(doc.get("serve"), dict):
-        return doc["serve"]
-    coll = doc.get("collectors")
-    if isinstance(coll, dict) and isinstance(coll.get("serve"), dict):
-        return coll["serve"]
-    return {}
-
-
 def _cmd_build(args) -> int:
     from libskylark_tpu.engine import warmup
 
-    if args.spec:
-        specs = [warmup.BucketSpec.from_dict(json.loads(s))
-                 for s in args.spec]
-    else:
-        stats = _load_stats(args.stats) if args.stats else None
-        specs = warmup.select_top_buckets(args.top, stats=stats)
-        if not specs:
-            print("no serve buckets found in the tune plan cache; "
-                  "pass explicit --spec JSON (see docs/performance)",
-                  file=sys.stderr)
-            return 2
+    specs = [warmup.BucketSpec.from_dict(json.loads(s))
+             for s in args.spec]
     manifest = warmup.build_pack(args.pack, specs,
                                  pad_floor=args.pad_floor)
     missing = [e["digest"] for e in manifest["entries"]
@@ -112,7 +76,6 @@ def _cmd_build(args) -> int:
     print(json.dumps({
         "pack": args.pack,
         "entries": len(manifest["entries"]),
-        "plan_fingerprint": manifest["plan_fingerprint"],
         "compat": manifest["compat"],
         "artifact_missing": missing,
     }, indent=1))
@@ -128,9 +91,9 @@ def _cmd_inspect(args) -> int:
         print(f"error: unreadable manifest: {e!r}", file=sys.stderr)
         return 2
     ok, why = aot.compat_probe(manifest.get("compat"))
-    from libskylark_tpu import engine
-
-    fp = engine.plan_fingerprint()
+    if manifest.get("schema") != warmup.PACK_SCHEMA:
+        ok, why = False, (f"schema {manifest.get('schema')!r} != "
+                          f"{warmup.PACK_SCHEMA}")
     print(json.dumps({
         "schema": manifest.get("schema"),
         "entries": [
@@ -140,10 +103,6 @@ def _cmd_inspect(args) -> int:
         ],
         "compat_ok_here": ok,
         "compat_reason": why,
-        "plan_fingerprint": manifest.get("plan_fingerprint"),
-        "plan_fingerprint_here": fp,
-        "plan_fingerprint_match":
-            fp == manifest.get("plan_fingerprint"),
     }, indent=1))
     return 0 if ok else 1
 

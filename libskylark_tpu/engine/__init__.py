@@ -2,11 +2,8 @@
 
 The NLA/ML layers' headline algorithms (randomized SVD, sketch-
 preconditioned least squares, random-features KRR) are whole-solver
-``jax.jit`` programs served from a donation-aware executable cache —
-the layer above the sketch-apply autotuner (:mod:`libskylark_tpu.tune`):
-tune certifies *kernel plans*, the engine caches the *compiled solver
-executables* whose keys include the plan fingerprint, so a certified
-plan change recompiles exactly the affected pipelines.
+``jax.jit`` programs served from a donation-aware executable cache
+whose key is a function of the program and its arguments alone.
 
 Public surface::
 
@@ -39,8 +36,7 @@ from libskylark_tpu.engine.compiled import (CompiledFn, cache, code_version,
                                             compiled, digest,
                                             donation_enabled, dump_stats,
                                             enable_persistent_cache,
-                                            maybe_donate, plan_fingerprint,
-                                            reset, stats)
+                                            maybe_donate, reset, stats)
 from libskylark_tpu.engine.serve import (DEGRADED, DRAINING, SERVING,
                                          STOPPED, MicrobatchExecutor,
                                          ServeOverloadedError,
@@ -51,6 +47,6 @@ __all__ = [
     "ExecutableCache", "MicrobatchExecutor", "SERVING", "STOPPED",
     "ServeOverloadedError", "aot", "bucket", "cache",
     "code_version", "compiled", "digest", "donation_enabled", "dump_stats",
-    "enable_persistent_cache", "maybe_donate", "plan_fingerprint",
-    "request_statics", "reset", "serve_stats", "stats", "warmup",
+    "enable_persistent_cache", "maybe_donate", "request_statics", "reset",
+    "serve_stats", "stats", "warmup",
 ]

@@ -6,8 +6,8 @@ compile that goes through :mod:`libskylark_tpu.engine.compiled` is
 serialized (``jax.experimental.serialize_executable``) into an artifact
 store under ``SKYLARK_AOT_DIR``, addressed by a digest of the exact
 executable-cache key — (solver name, code-version hash, statics,
-key_fn extras incl. the serve kernel ``plan_id``, avals, sharding,
-donation, plan fingerprint, precision regime, backend) — so a fresh
+key_fn extras incl. the serve flush's kernel token, avals, sharding,
+donation, precision regime, backend) — so a fresh
 process (or a :class:`~libskylark_tpu.fleet.ProcessReplica` child)
 **loads instead of compiling** and serves the same bits from its first
 request (docs/performance, "Persistent AOT artifacts & warmup packs").
@@ -17,9 +17,9 @@ Safety model:
 - **The key is the contract.** Anything that would change the traced
   program changes a key component and therefore the digest — a stale
   artifact can never be *served*, only *ignored*. Invalidation is
-  automatic: a plan-cache edit, a code change in the wrapped solver or
-  the engine itself, a precision flip, a sharding change each land on
-  a fresh digest.
+  automatic: a code change in the wrapped solver or the engine
+  itself, a precision flip, a sharding change each land on a fresh
+  digest.
 - **Compatibility probing.** The key does not capture the runtime, so
   every artifact carries a compat stamp (schema, jax/jaxlib version,
   backend, device kind, device count) checked before deserialization;

@@ -5,13 +5,12 @@ explicit static arguments and (opt-in) buffer donation, AOT-compiles it
 (``lower().compile()``) and serves the executable from an in-process
 LRU (:mod:`libskylark_tpu.engine.cache`). The cache key is explicit —
 nothing is left to jit's implicit closure identity, so two *different*
-transform objects with the same (seed, counter) share one executable,
-and a plan-cache edit (``tune``) invalidates exactly the executables
-whose dispatch it could change:
+transform objects with the same (seed, counter) share one executable.
+The key is a function of the program and its arguments alone:
 
     (solver name, code-version hash, static args, key_fn extras,
-     abstract shapes/dtypes, sharding/mesh fingerprint,
-     autotuner plan fingerprint, solver-precision regime, backend)
+     abstract shapes/dtypes, sharding/mesh fingerprint, donation,
+     solver-precision regime, backend)
 
 The AOT discipline buys a hard property: an entry can never silently
 recompile — ``jax.stages.Compiled`` raises on a signature mismatch
@@ -239,21 +238,6 @@ def code_version(fn: Callable) -> str:
     return "-".join(_file_hash(p) for p in paths)
 
 
-def plan_fingerprint() -> str:
-    """The autotuner plan cache's content fingerprint
-    (:func:`libskylark_tpu.tune.plan_fingerprint` — one implementation,
-    re-exported here for the key path): part of every engine key, so a
-    certified-plan change triggers — and a no-op write avoids —
-    recompilation. Never raises: a broken plan cache must not take down
-    a solver call."""
-    try:
-        from libskylark_tpu import tune
-
-        return tune.plan_fingerprint()
-    except Exception:
-        return "no-plan-cache"
-
-
 def digest(obj) -> str:
     """Stable identity of a closed-over collaborator (sketch transform,
     kernel, params block) for ``key_fn`` extras: the hash of its JSON
@@ -348,7 +332,6 @@ class CompiledFn:
             tuple(_aval_key(a) for a in args),
             tuple(_sharding_key(a) for a in args),
             donate_argnums,
-            plan_fingerprint(),
             _precision_fingerprint(),
             jax.default_backend(),
         )
@@ -546,7 +529,7 @@ def dump_stats(path: str) -> None:
     the reset-proof rollup (current window included) — what the gate
     keys off; ``telemetry`` is the unified registry snapshot
     (docs/observability) so the artifact carries the serve/resilience/
-    tune/io counters alongside the engine's own."""
+    io counters alongside the engine's own."""
     doc = {"stats": _CACHE.stats.to_dict(),
            "lifetime": _lifetime_rollup().to_dict(),
            "entries": _CACHE.snapshot(),

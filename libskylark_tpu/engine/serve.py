@@ -29,10 +29,10 @@ stacked cohort — ``sketch/pallas_hash.py`` scatter-free CountSketch,
 pallas_fastfood.py`` fused SHGΠHB chain) instead of the vmapped XLA
 path. Which program serves a (bucket, capacity) flush is resolved by
 :meth:`MicrobatchExecutor._resolve_flush_kernel` with the precedence
-``kernel=`` argument > ``SKYLARK_SERVE_KERNEL`` env > tune plan cache >
-default (xla); the resolved choice is a **static of the executable
-cache key**, so selection can never retrace a warm bucket
-(docs/performance, "Serve-bucket kernel selection").
+``kernel=`` argument > ``SKYLARK_SERVE_KERNEL`` env > default (xla);
+the resolved choice is a **static of the executable cache key**, so
+selection can never retrace a warm bucket (docs/performance,
+"Serve-bucket kernel selection").
 A cohort flushes as ONE ``jax.vmap``-batched executable when it reaches
 ``max_batch`` or its oldest request has lingered ``linger_us``; past
 ``max_queue`` pending requests, ``submit`` blocks (backpressure) and
@@ -174,11 +174,11 @@ ENDPOINTS = ("sketch_apply", "fastfood_features", "solve_l2_sketched",
              "condest", "lowrank", "rlsc_predict",
              "compressed_matmul")
 
-# endpoints behind the selection seam (arg > env > plan cache >
-# default), whose flush runs at the ambient matmul precision and is
-# counted by backend; the others always flush through the vmapped XLA
-# path under solver_precision(). The sparse sketch endpoint is here for
-# the precision and the counters: it has no batched kernel, so a pallas
+# endpoints behind the selection seam (arg > env > default), whose
+# flush runs at the ambient matmul precision and is counted by backend;
+# the others always flush through the vmapped XLA path under
+# solver_precision(). The sparse sketch endpoint is here for the
+# precision and the counters: it has no batched kernel, so a pallas
 # intent on it resolves to a counted decline
 _KERNEL_ENDPOINTS = ("sketch_apply", "fastfood_features",
                      "sparse_sketch_apply")
@@ -343,34 +343,21 @@ def _percentile(sorted_vals: list, q: float) -> Optional[float]:
 
 def _serve_kernel_env():
     """``SKYLARK_SERVE_KERNEL`` — the one-shot override between the
-    executor argument and the tune plan cache in the flush-kernel
+    executor argument and the XLA default in the flush-kernel
     precedence (``pallas`` | ``xla``; anything else is ignored so a
-    typo degrades to cache consultation, the repo's env-parse
-    convention — the registry parser encodes exactly that)."""
+    typo degrades to the default, the repo's env-parse convention —
+    the registry parser encodes exactly that)."""
     return _env.SERVE_KERNEL.get()
 
 
 def _pallas_native() -> bool:
     """Whether this backend compiles Mosaic kernels natively; off-TPU a
     pallas flush runs the interpreter (a correctness surface the tests
-    and the CI bit-equality leg use — the tuner never *selects* it for
-    throughput off-TPU, the cost model's interpret penalty sees to
-    that)."""
+    and the CI bit-equality leg use, reached only by an explicit
+    pin)."""
     from libskylark_tpu.sketch.pallas_dense import available
 
     return available()
-
-
-def _parse_plan_token(token: str):
-    """Invert :meth:`libskylark_tpu.tune.Plan.plan_id` for warmup-pack
-    kernel restoration (``pallas/mt128/f32`` → a Plan). None when the
-    token is not a plan id this build understands. The real decoder
-    lives next to the encoder (``Plan.from_plan_id``) so the formats
-    cannot drift apart; this wrapper only narrows the backends to the
-    serve-kernel set."""
-    from libskylark_tpu.tune import Plan
-
-    return Plan.from_plan_id(token, known_backends=_KERNEL_BACKENDS)
 
 
 def _lane_program_only(statics) -> bool:
@@ -1043,7 +1030,7 @@ class MicrobatchExecutor:
         if kernel is not None and kernel not in _KERNEL_BACKENDS:
             raise ValueError(
                 f"kernel must be one of {_KERNEL_BACKENDS} or None "
-                f"(autotuned selection), got {kernel!r}")
+                f"(SKYLARK_SERVE_KERNEL, else xla), got {kernel!r}")
         if not 0.0 < degraded_threshold <= 1.0:
             raise ValueError("degraded_threshold must be in (0, 1]")
         if not 0.0 < shed_fraction <= 1.0:
@@ -1094,11 +1081,9 @@ class MicrobatchExecutor:
         # flush-kernel selection (docs/performance "Serve-bucket kernel
         # selection"): the explicit argument tops the precedence; the
         # memo makes key_fn's per-call re-resolution a dict hit, keyed
-        # on (bucket statics, capacity, plan fingerprint) so a plan
-        # edit re-resolves while steady-state traffic never recomputes
+        # on (bucket statics, capacity) for the executor's life
         self.kernel = kernel
         self._kernel_memo: dict = {}
-        self._kernel_memo_fp: Optional[str] = None
 
         self._stats_lock = _locks.make_lock("serve.stats")
         self._counts = collections.Counter()
@@ -2737,31 +2722,11 @@ class MicrobatchExecutor:
     # selection"): which program serves a (bucket, capacity) flush —
     # the endpoint's batched Pallas kernel or the vmapped XLA path.
     # Precedence: executor ``kernel=`` argument > SKYLARK_SERVE_KERNEL
-    # env > tune plan cache > default (xla). A pallas intent that fails
-    # host-side qualification declines (reason counted) back to xla.
+    # env > default (xla). A pallas intent that fails host-side
+    # qualification declines (reason counted) back to xla.
     # ------------------------------------------------------------------
 
-    def _kernel_workload(self, b: _Bucket, capacity: int):
-        """The tune serve-bucket workload of a flush — (endpoint /
-        orientation, family, dtype, padded lane class, capacity class)
-        — or None when the endpoint has no kernel decision."""
-        from libskylark_tpu import tune
-
-        endpoint = b.statics[0]
-        ctx = b.ctx
-        if endpoint == "sketch_apply":
-            return tune.serve_workload(
-                "sketch_apply", ctx["family"], ctx["dtype"],
-                ctx["padded"], ctx["s_dim"], capacity,
-                rowwise=ctx["rowwise"])
-        if endpoint == "fastfood_features":
-            return tune.serve_workload(
-                "fastfood_features", ctx["family"], ctx["dtype"],
-                ctx["padded"], ctx["s_dim"], capacity)
-        return None
-
-    def _qualify_serve_kernel(self, b: _Bucket,
-                              m_tile: Optional[int] = None):
+    def _qualify_serve_kernel(self, b: _Bucket):
         """Host-side (ok, why) qualification of the bucket's batched
         kernel at the padded lane class — run BEFORE a pallas choice is
         committed to the executable key, so an unqualified bucket keys
@@ -2790,125 +2755,71 @@ class MicrobatchExecutor:
 
         return pallas_dense.serve_qualify(
             ctx["dist"], ctx["s_dim"], n, m, ctx["dtype"],
-            interpret=interpret, m_tile=m_tile)
+            interpret=interpret)
 
     def _resolve_flush_kernel(self, b: _Bucket, capacity: int) -> tuple:
-        """``(backend, plan, source, declined)`` for one (bucket,
-        capacity) flush. Memoized per plan-cache fingerprint: the
-        engine key_fn re-resolves on every call (the kernel choice is
-        a STATIC of the executable key — the r7 jit-leak gate's
-        zero-recompile contract holds because this is a dict hit with
-        a stable answer), and a plan edit changes the fingerprint,
-        which both re-resolves the choice and re-keys the executable.
+        """``(backend, source, declined)`` for one (bucket, capacity)
+        flush. Memoized for the executor's life: the engine key_fn
+        re-resolves on every call (the kernel choice is a STATIC of the
+        executable key — the r7 jit-leak gate's zero-recompile contract
+        holds because this is a dict hit with a stable answer).
         ``declined`` is the reason slug when a pallas intent fell back
         to xla (the ``by_reason`` counter), else None."""
         if b.statics[0] not in _KERNEL_ENDPOINTS:
-            return ("xla", None, "endpoint", None)
-        from libskylark_tpu.engine.compiled import plan_fingerprint
-
-        fp = plan_fingerprint()
-        if fp != self._kernel_memo_fp:
-            # new fingerprint era: every memoized choice (including
-            # mosaic-reject poisonings — they hold "for the fingerprint
-            # era") is stale; drop them so the memo stays bounded by
-            # the live (bucket, capacity) population
-            self._kernel_memo.clear()
-            self._kernel_memo_fp = fp
-        memo_key = (b.statics, int(capacity), fp)
+            return ("xla", "endpoint", None)
+        memo_key = (b.statics, int(capacity))
         got = self._kernel_memo.get(memo_key)
         if got is not None:
             return got
-        plan = None
         if self.kernel is not None:
             choice, source = self.kernel, "arg"
-        elif _serve_kernel_env() is not None:
-            choice, source = _serve_kernel_env(), "env"
+        elif (pin := _serve_kernel_env()) is not None:
+            choice, source = pin, "env"
         else:
-            from libskylark_tpu.sketch import params as sketch_params
-
-            if sketch_params.get_use_plan_cache():
-                try:
-                    from libskylark_tpu import tune
-
-                    w = self._kernel_workload(b, capacity)
-                    plan = tune.plan_for(w) if w is not None else None
-                except Exception:
-                    plan = None
-            if plan is not None and plan.backend in _KERNEL_BACKENDS:
-                choice, source = plan.backend, "plan"
-            else:
-                plan = None
-                choice, source = "xla", "default"
-        out = (choice, plan, source, None)
+            choice, source = "xla", "default"
+        out = (choice, source, None)
         if choice == "pallas":
-            ok, why = self._qualify_serve_kernel(
-                b, m_tile=plan.m_tile if plan else None)
+            ok, why = self._qualify_serve_kernel(b)
             if not ok:
-                out = ("xla", None, source, _decline_slug(why))
+                out = ("xla", source, _decline_slug(why))
         self._kernel_memo[memo_key] = out
         return out
 
     def _kernel_key_token(self, b: _Bucket, capacity: int) -> str:
-        """The kernel-choice static the flush executable is keyed on
-        (plan_id carries the m-tile for the dense family — two plans
-        trace different programs and must key differently)."""
-        backend, plan, _src, _why = self._resolve_flush_kernel(
-            b, capacity)
-        return plan.plan_id() if (backend == "pallas"
-                                  and plan is not None) else backend
+        """The kernel-choice static the flush executable is keyed on:
+        the resolved backend's name."""
+        return self._resolve_flush_kernel(b, capacity)[0]
 
     def _poison_kernel(self, b: _Bucket, capacity: int,
                        reason: str) -> None:
         """Force (bucket, capacity) onto the XLA path for the rest of
-        this fingerprint era — the compile-time Mosaic-rejection
+        the executor's life — the compile-time Mosaic-rejection
         fallback (a rejection is a decline, not an outage)."""
-        from libskylark_tpu.engine.compiled import plan_fingerprint
-
-        memo_key = (b.statics, int(capacity), plan_fingerprint())
-        self._kernel_memo[memo_key] = ("xla", None, "fallback", reason)
+        self._kernel_memo[(b.statics, int(capacity))] = (
+            "xla", "fallback", reason)
 
     def restore_kernel_choice(self, statics, capacity: int,
                               token: str) -> bool:
         """Seed the flush-kernel memo for one (bucket statics,
-        capacity) with a warmup-pack-recorded decision — the r12
-        kernel choice ships *with* the compiled artifact instead of
-        being re-resolved (plan-cache consult + host qualification)
-        per process (docs/performance, "Persistent AOT artifacts &
-        warmup packs"). The seed is keyed under the CURRENT plan
-        fingerprint; the pack loader only calls this after verifying
-        the fingerprints match, so the memoized choice is exactly what
-        live resolution would certify. Returns whether the decision
-        was restored (an unparseable token falls back to live
-        resolution — a decline, not an error). An explicit pin —
-        executor ``kernel=`` argument or ``SKYLARK_SERVE_KERNEL`` —
-        outranks the pack: the memo is consulted before either, so
-        seeding it would silently override the operator's pin; decline
-        instead and let live resolution honor the precedence. The same
-        goes for a disabled plan cache (``SKYLARK_USE_PLAN_CACHE=0``)
-        — the pack's decisions ARE plan-cache decisions, and restoring
-        them would re-enable the selection the operator turned off."""
-        from libskylark_tpu.engine.compiled import plan_fingerprint
-        from libskylark_tpu.sketch import params as sketch_params
-
+        capacity) with a warmup-pack-recorded decision — the kernel
+        choice ships *with* the compiled artifact instead of being
+        re-resolved (host qualification) per process (docs/performance,
+        "Persistent AOT artifacts & warmup packs"). ``token`` is a
+        backend's name; returns whether the decision was restored (any
+        other token, a pre-schema-2 ``pallas/mt128/f32`` among them,
+        falls back to live resolution — a decline, not an error). An
+        explicit pin — executor ``kernel=`` argument or
+        ``SKYLARK_SERVE_KERNEL`` — outranks the pack: the memo is
+        consulted before either, so seeding it would silently override
+        the operator's pin; decline instead and let live resolution
+        honor the precedence."""
         if self.kernel is not None or _serve_kernel_env() is not None:
             return False
         statics = tuple(statics)
-        if not sketch_params.get_use_plan_cache():
+        if token not in _KERNEL_BACKENDS or (
+                token != "xla" and _lane_program_only(statics)):
             return False
-        value = None
-        if token == "xla":
-            value = ("xla", None, "pack", None)
-        elif not _lane_program_only(statics):
-            plan = _parse_plan_token(token)
-            if plan is not None:
-                value = (plan.backend, plan, "pack", None)
-        if value is None:
-            return False
-        fp = plan_fingerprint()
-        if fp != self._kernel_memo_fp:
-            self._kernel_memo.clear()
-            self._kernel_memo_fp = fp
-        self._kernel_memo[(statics, int(capacity), fp)] = value
+        self._kernel_memo[(statics, int(capacity))] = (token, "pack", None)
         return True
 
     def load_warmup_pack(self, pack_dir: str, *,
@@ -2931,9 +2842,8 @@ class MicrobatchExecutor:
         endpoint = statics[0]
         # kernel-selecting endpoints key their executables on the
         # resolved kernel-choice token too: the choice is derived from
-        # the SAME (bucket, capacity, plan-fingerprint) triple at key
-        # time and at trace time, so the key can never disagree with
-        # the program it names
+        # the SAME (bucket, capacity) pair at key time and at trace
+        # time, so the key can never disagree with the program it names
         def serve_key(*a):
             return statics + (
                 "kernel", self._kernel_key_token(b, int(a[0].shape[0])))
@@ -2967,7 +2877,7 @@ class MicrobatchExecutor:
             inner = jax.vmap(one)
 
             def batched_sketch(kd, scale, A):
-                backend, plan, _src, _why = self._resolve_flush_kernel(
+                backend, _src, _why = self._resolve_flush_kernel(
                     b, int(A.shape[0]))
                 if backend == "pallas":
                     interpret = not _pallas_native()
@@ -2985,9 +2895,7 @@ class MicrobatchExecutor:
 
                     return pallas_dense.serve_batched_apply(
                         kd, scale, A, dist=ctx["dist"], s_dim=s_dim,
-                        rowwise=rowwise,
-                        m_tile=plan.m_tile if plan else None,
-                        interpret=interpret)
+                        rowwise=rowwise, interpret=interpret)
                 return inner(kd, scale, A)
 
             return engine_compile(
@@ -3009,7 +2917,7 @@ class MicrobatchExecutor:
             inner_ff = jax.vmap(one_ff)
 
             def batched_fastfood(kd, A):
-                backend, _plan, _src, _why = self._resolve_flush_kernel(
+                backend, _src, _why = self._resolve_flush_kernel(
                     b, int(A.shape[0]))
                 if backend == "pallas":
                     from libskylark_tpu.sketch import pallas_fastfood
@@ -3056,8 +2964,6 @@ class MicrobatchExecutor:
             # always-xla flush (like the solve endpoints): the two
             # family sketch programs each run panel-free already, and
             # the closing (m, s)x(s, p) gemm is XLA's bread and butter
-            # — tune covers it as the xla-only "serve_cmm" op for
-            # roofline/certification, not as a kernel decision
             family, s_dim = ctx["family"], ctx["s_dim"]
             padded_a = ctx["padded_A"]
             if family == "SRHT":
@@ -3340,7 +3246,7 @@ class MicrobatchExecutor:
         # are on the fast path and WHY the others are not
         kernel_backend, kdeclined = "xla", None
         if endpoint in _KERNEL_ENDPOINTS:
-            kernel_backend, _kp, _ks, kdeclined = \
+            kernel_backend, _ks, kdeclined = \
                 self._resolve_flush_kernel(b, capacity)
         if endpoint == "sketch_apply":
             padded = cohort[0].meta["padded"]

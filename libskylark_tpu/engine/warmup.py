@@ -6,9 +6,9 @@ artifact per hot ``(serve bucket, capacity)`` executable — the exact
 programs a :class:`~libskylark_tpu.engine.serve.MicrobatchExecutor`
 flushes — and (b) a ``pack.json`` manifest recording, per entry, the
 artifact digest, the endpoint/bucket statics, the capacity class, and
-the **kernel decision** the tuner certified for that bucket (the r12
-``plan_id`` static), plus the pack-wide compat stamp and the plan-cache
-fingerprint everything was keyed under.
+the **kernel decision** the builder's executor resolved for that bucket
+(the backend's name, a static of the key), plus the pack-wide compat
+stamp.
 
 Boot flow (docs/performance, "Persistent AOT artifacts & warmup
 packs"): a fresh process — a cold autoscaled replica, a
@@ -21,19 +21,15 @@ request of every packed bucket is a cache **hit**: zero tracing, zero
 backend compiles, bit-equal results (the executable is byte-identical
 to the builder's).
 
-Invalidation is inherited from the key, not re-implemented: a plan
-edit changes the plan fingerprint (pack skipped, buckets recompile), a
-code change re-keys (artifacts never hit), a jax upgrade / backend /
-device change fails the compat probe (pack skipped). A skipped or
-partial pack is never an error unless ``strict=True`` — boot degrades
-to the ordinary compile path.
+Invalidation is inherited from the key, not re-implemented: a code
+change re-keys (artifacts never hit), a jax upgrade / backend / device
+change fails the compat probe (pack skipped), and a pack of another
+``PACK_SCHEMA`` (schema 1 keyed its entries on a part the key no longer
+has) is reported skipped. A skipped or partial pack is never an error
+unless ``strict=True`` — boot degrades to the ordinary compile path.
 
-Pack **selection** (:func:`select_top_buckets`) reads the tune plan
-cache's serve-bucket entries (``serve_sketch_rw`` / ``serve_sketch_cw``
-/ ``serve_fastfood`` workloads, measured entries first) and optionally
-a serve-stats block (``batch_capacity_hist`` from telemetry or a
-``dump_stats`` artifact) to order capacities by live traffic — the
-top-N (bucket, capacity) keys a fleet actually serves.
+Which buckets go into a pack is the builder's own list, one
+:class:`BucketSpec` each (``skylark-warmup build --spec``).
 """
 
 from __future__ import annotations
@@ -56,16 +52,9 @@ def _compiled_module():
     return importlib.import_module("libskylark_tpu.engine.compiled")
 
 
-PACK_SCHEMA = 1
+PACK_SCHEMA = 2
 MANIFEST = "pack.json"
 _ARTIFACTS = "artifacts"
-
-#: serve-tune op -> (endpoint, rowwise) for plan-cache selection
-_SERVE_OPS = {
-    "serve_sketch_rw": ("sketch_apply", True),
-    "serve_sketch_cw": ("sketch_apply", False),
-    "serve_fastfood": ("fastfood_features", True),
-}
 
 
 @dataclasses.dataclass
@@ -249,7 +238,6 @@ def build_pack(pack_dir: str, specs: Sequence, *,
         "schema": PACK_SCHEMA,
         "created": time.time(),
         "compat": _aot.compat_stamp(),
-        "plan_fingerprint": _compiled.plan_fingerprint(),
         "pad_floor": int(pad_floor) if pad_floor is not None
         else bucketing.PAD_FLOOR,
         "max_batch": max_cap,
@@ -285,10 +273,9 @@ def load_pack(pack_dir: str, executors: Sequence = (), *,
     recorded decisions. Returns a report::
 
         {"entries": N, "loaded": n, "resident": n, "failed": n,
-         "kernel_restored": n, "skipped": why-or-None,
-         "plan_fingerprint_match": bool}
+         "kernel_restored": n, "skipped": why-or-None}
 
-    Skips (compat mismatch, plan-fingerprint drift) are reported, not
+    Skips (another schema, compat mismatch) are reported, not
     raised — boot falls back to the compile path — unless ``strict``.
     Loads count as engine ``aot_loads`` (``load_seconds`` split), never
     as misses or compiles: a packed bucket's first request is a HIT.
@@ -300,8 +287,7 @@ def load_pack(pack_dir: str, executors: Sequence = (), *,
     _compiled = _compiled_module()
 
     report = {"entries": 0, "loaded": 0, "resident": 0, "failed": 0,
-              "kernel_restored": 0, "skipped": None,
-              "plan_fingerprint_match": None}
+              "kernel_restored": 0, "skipped": None}
 
     def _bail(why: str) -> dict:
         if strict:
@@ -319,15 +305,6 @@ def load_pack(pack_dir: str, executors: Sequence = (), *,
     ok, why = _aot.compat_probe(manifest.get("compat"))
     if not ok:
         return _bail(f"compat: {why}")
-    fp = _compiled.plan_fingerprint()
-    fp_match = fp == manifest.get("plan_fingerprint")
-    report["plan_fingerprint_match"] = fp_match
-    if not fp_match:
-        # every packed key embeds the builder's fingerprint — none
-        # could ever be hit; the tuner's plans changed, so the buckets
-        # must legitimately recompile under the new decisions
-        return _bail("plan-fingerprint drift (plan cache edited since "
-                     "the pack was built)")
 
     root = (os.path.dirname(pack_dir) if pack_dir.endswith(".json")
             else pack_dir)
@@ -488,91 +465,7 @@ def spawn_boot_probe(pack_dir: str, *, load: bool = True,
     return json.loads(m.group(1))
 
 
-# ---------------------------------------------------------------------------
-# pack selection: plan cache + serve telemetry
-# ---------------------------------------------------------------------------
-
-
-def _parse_workload_key(key: str) -> Optional[dict]:
-    """Recover a serve-bucket spec from one plan-cache key string
-    (``device|op|transform|dtype|MxNxS[|bC]``)."""
-    parts = key.split("|")
-    if len(parts) not in (5, 6):
-        return None
-    device, op, transform, dtype, shape = parts[:5]
-    if op not in _SERVE_OPS:
-        return None
-    try:
-        m, n, s = (int(x) for x in shape.split("x"))
-        cap = int(parts[5][1:]) if len(parts) == 6 else 1
-    except ValueError:
-        return None
-    endpoint, rowwise = _SERVE_OPS[op]
-    return {"device_kind": device, "endpoint": endpoint,
-            "family": transform, "dtype": dtype, "rowwise": rowwise,
-            "m": m, "n": n, "s_dim": s, "capacity": cap}
-
-
-def select_top_buckets(top_n: int = 8, *, stats: Optional[dict] = None,
-                       device_kind: Optional[str] = None
-                       ) -> list[BucketSpec]:
-    """The top-N (bucket, capacity) keys worth packing, from the tune
-    plan cache's serve entries — measured certifications first, then
-    ranked ones — optionally ordered by a serve-stats block's
-    ``batch_capacity_hist`` (hot capacity classes first). ``stats``
-    accepts an ``engine.serve_stats()`` dict, a telemetry ``serve``
-    collector block, or a ``dump_stats`` artifact's ``serve`` entry.
-
-    Fastfood buckets select at the default bandwidth (``sigma=1.0``) —
-    the plan cache's workload key does not carry the bandwidth, which
-    is a bucket static; pass explicit :class:`BucketSpec`\\ s to
-    :func:`build_pack` for non-default kernels."""
-    from libskylark_tpu import tune
-
-    device_kind = device_kind or tune.current_device_kind()
-    cap_weight: dict[int, int] = {}
-    if stats:
-        hist = stats.get("batch_capacity_hist") or {}
-        for k, v in hist.items():
-            try:
-                cap_weight[int(k)] = int(v)
-            except (TypeError, ValueError):
-                continue
-    rows = []
-    try:
-        entries = dict(tune.get_cache().entries)
-    except Exception:  # noqa: BLE001 — no cache, no selection
-        entries = {}
-    for key, ent in entries.items():
-        w = _parse_workload_key(key)
-        if w is None:
-            continue
-        if tune.normalize_device_kind(w["device_kind"]) != \
-                tune.normalize_device_kind(device_kind):
-            continue
-        measured = 1 if ent.get("source") == "measured" else 0
-        weight = cap_weight.get(w["capacity"], 0)
-        rows.append(((measured, weight, ent.get("recorded", "")), w))
-    rows.sort(key=lambda r: r[0], reverse=True)
-    specs: list[BucketSpec] = []
-    seen: set = set()
-    for _rank, w in rows:
-        ident = (w["endpoint"], w["family"], w["dtype"], w["rowwise"],
-                 w["m"], w["n"], w["s_dim"], w["capacity"])
-        if ident in seen:
-            continue
-        seen.add(ident)
-        specs.append(BucketSpec(
-            endpoint=w["endpoint"], family=w["family"], n=w["n"],
-            m=w["m"], s_dim=w["s_dim"], dtype=w["dtype"],
-            rowwise=w["rowwise"], capacities=(w["capacity"],)))
-        if len(specs) >= top_n:
-            break
-    return specs
-
-
 __all__ = [
     "BucketSpec", "MANIFEST", "PACK_SCHEMA", "build_pack", "load_pack",
-    "read_manifest", "result_digest", "select_top_buckets",
-    "serve_probe", "spawn_boot_probe",
+    "read_manifest", "result_digest", "serve_probe", "spawn_boot_probe",
 ]

@@ -73,9 +73,9 @@ from libskylark_tpu.base import locks as _locks
 from libskylark_tpu.engine.serve import ServeOverloadedError
 
 # Environment a replica child must agree with its parent on — the AOT
-# artifact store, the tune plan cache (its fingerprint is in every
-# executable key: a child on a different cache file would never hit
-# the parent's warmup pack), and the telemetry switches. Propagated
+# artifact store, the serve-kernel pin (it is in every flush
+# executable's key: a child on a different pin would never hit the
+# parent's warmup pack), and the telemetry switches. Propagated
 # EXPLICITLY through the spawn args and applied at child entry, not
 # left to the accident of what ``os.environ`` held when
 # ``Process.start()`` happened to run (a parent that configures its

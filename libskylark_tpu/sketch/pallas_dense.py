@@ -220,7 +220,7 @@ def operator_residency(s_dim: int, n: int, m: int, m_tile: int,
     apply, from the padded shapes alone, whichever the orientation (``m``
     is the extent that is tiled: rows of a rowwise operand, columns of a
     columnwise one) — the single rule the ``pallas_call`` sites,
-    :func:`effective_plan`, the ``sketch.apply`` span and tune/cost.py
+    :func:`effective_plan` and the ``sketch.apply`` span
     all read:
 
     ``"per_tile"``  a single m-tile: nothing to reuse, each block is
@@ -1285,8 +1285,8 @@ def _planned(dist, A, s_dim: int, seq_axis: int, m_tile, precision,
             "operator_residency": residency,
             "operator_cache": residency == "vmem",
             "precision": precision,
-            # the label bench records carry; tune/plans.py's
-            # Plan.plan_id writes the same string for the same plan
+            # the label bench records carry: backend, tiles and
+            # regime, one string for one plan
             "plan_id": f"pallas/{tile_id}/{precision}",
             "plan_source": source}
 

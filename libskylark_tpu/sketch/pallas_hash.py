@@ -39,9 +39,9 @@ replaces the scatter with MXU work it can pipeline:
    zero-padded serve lanes (padded coordinates contribute exact ±0.0,
    which can never flip an accumulator bit). This is the interpret-mode
    correctness surface CPU tier-1 pins and the CI serve gate's
-   bit-equality leg; it is VPU-serial over coordinates, so the
-   autotuner never selects it for throughput (on TPU the mxu mode
-   serves; on CPU the tuner correctly keeps XLA).
+   bit-equality leg; it is VPU-serial over coordinates, so nothing
+   selects it for throughput (on TPU the mxu mode serves; on CPU the
+   default keeps XLA).
 
 The batched entry point (:func:`cwt_apply_batched`) adds a leading
 cohort dimension as a grid axis — one ``pallas_call`` flushes a whole
@@ -61,7 +61,7 @@ Like every kernel in this tree, dispatch DECLINES (returns None /
 scatter. On a TPU v5e the batched kernel compiles and matches its XLA
 twin (rel-max 1.6e-7 at 8 × (8192, 512) → 1024) but does not beat it
 (PERF.md), so only an explicit override routes a direct apply here (the
-serve tier also takes it under a measured plan-cache entry). A selected
+serve tier takes it under a ``kernel=`` / env pin alone). A selected
 kernel that Mosaic rejects raises on the direct-apply path; the serve
 layer counts it (``mosaic-reject``) and serves the XLA program.
 """

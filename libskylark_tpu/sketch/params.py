@@ -69,28 +69,6 @@ def set_use_pallas(on: bool) -> None:
     _use_pallas = bool(on)
 
 
-# ``use_plan_cache`` — the serve tier (engine/serve.py) consults the
-# persistent autotuner plan cache (the ``tune`` package) when it picks
-# a bucket's flush kernel: explicit ``kernel=`` / env pin > cached plan
-# > XLA. The eager applies in sketch/ never read it: there the kernel,
-# tile and regime are the call-site argument, else the setters below
-# (set_pallas_m_tile / set_pallas_precision), else one rule from the
-# device and the shapes (pallas_dense._qualify / operator_residency).
-# Disabled entirely with SKYLARK_USE_PLAN_CACHE=0 (or
-# set_use_plan_cache(False)); the cache file location is
-# SKYLARK_PLAN_CACHE (tune/cache.py).
-_use_plan_cache = _env.USE_PLAN_CACHE.get()
-
-
-def get_use_plan_cache() -> bool:
-    return _use_plan_cache
-
-
-def set_use_plan_cache(on: bool) -> None:
-    global _use_plan_cache
-    _use_plan_cache = bool(on)
-
-
 # ``pallas_precision`` — contraction regime inside the fused kernel.
 # "bf16x3" (default): 3-pass error-compensated bf16 split — f32-grade
 # rounding at roughly twice the MXU rate of full-f32 passes;

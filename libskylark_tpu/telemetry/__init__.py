@@ -7,8 +7,8 @@ observability.rst``). Three modules:
 - :mod:`~libskylark_tpu.telemetry.metrics` — a thread-safe
   process-wide registry of labeled counters/gauges/histograms, plus
   **collector adapters** that re-home the pre-existing stats blocks
-  (``engine.stats()``, ``serve_stats()``, resilience fault log, tune
-  plan-cache lookups, WebHDFS reconnects) so every number the system
+  (``engine.stats()``, ``serve_stats()``, resilience fault log,
+  WebHDFS reconnects) so every number the system
   already tracks appears once, under one schema, via
   :func:`snapshot`.
 - :mod:`~libskylark_tpu.telemetry.trace` — ``with telemetry.span(...)``

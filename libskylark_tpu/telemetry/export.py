@@ -21,7 +21,7 @@ Line schema (``docs/observability.rst`` is the reference):
 **Prometheus** (:func:`prometheus_text`): the registry's counters,
 gauges and histograms in text exposition format, plus every collector
 block flattened to gauges — one scrape surface carrying the unified
-engine/serve/resilience/tune/io numbers. Naming: ``skylark_`` prefix,
+engine/serve/resilience/io numbers. Naming: ``skylark_`` prefix,
 dots to underscores, counters get ``_total``, histograms the classic
 ``_bucket``/``_sum``/``_count`` triplet. Collector sub-blocks named
 ``by_<label>`` (``serve_stats()``'s ``by_replica``, ``fleet_stats()``'s

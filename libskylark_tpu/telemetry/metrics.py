@@ -1,8 +1,8 @@
 """Process-wide metrics registry: labeled counters, gauges, histograms.
 
 The r8/r9 rounds grew observability piecemeal — ``engine.stats()``,
-``serve_stats()``, the resilience ``fired()`` log, tune plan-cache
-lookups, WebHDFS reconnect counting — each with a private schema and no
+``serve_stats()``, the resilience ``fired()`` log, WebHDFS reconnect
+counting — each with a private schema and no
 common export path. This registry is the one schema they all surface
 through: subsystems either **record directly** (a
 :class:`Counter`/:class:`Gauge`/:class:`Histogram` created once at

@@ -38,8 +38,6 @@ METRICS: Dict[str, str] = {
     # records — what summary() gives, for whoever scrapes instead of calls
     "setup.seconds": "counter",
     "setup.events": "counter",
-    # tune (tune/cache.py)
-    "tune.plan_cache_lookups": "counter",
     # ml (ml/admm.py)
     "ml.admm.iterations": "counter",
     "ml.admm.objective": "gauge",

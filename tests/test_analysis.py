@@ -253,31 +253,37 @@ def test_env_table_matches_committed(tmp_path):
                         "script/lint --env-table"
 
 
-def test_removed_pallas_knobs_are_gone_everywhere():
+# spelled in parts: the names appear nowhere in the tree any more
+_REMOVED_KNOBS = (["SKYLARK_PALLAS_" + tail for tail in
+                   ("PIPELINE", "MTILE", "VMEM_BUDGET", "SCRATCH_CAP")]
+                  + ["SKYLARK_" + tail for tail in
+                     ("PLAN_CACHE", "USE_PLAN_CACHE", "COST_CALIB")])
+
+
+@pytest.mark.parametrize("name", _REMOVED_KNOBS)
+def test_removed_pallas_knobs_are_gone_everywhere(name):
     """Four knobs left in PR 30 (the pipelined-generation switch, the
-    m-tile's env twin, two budgets no caller set): each is gone from the
-    registry, the generated table and the lint baseline together, so
-    none can come back in one place and be read from another."""
+    m-tile's env twin, two budgets no caller set) and three in PR 59 (the
+    plan cache's file, its gate and the cost model's calibration): each
+    is gone from the registry, the generated table and the lint baseline
+    together, so none can come back in one place and be read from
+    another."""
     from libskylark_tpu.base import env as sk_env
 
-    # spelled in parts: the names appear nowhere in the tree any more
-    removed = ["SKYLARK_PALLAS_" + tail for tail in
-               ("PIPELINE", "MTILE", "VMEM_BUDGET", "SCRATCH_CAP")]
     with open(os.path.join(REPO, "docs", "env_vars.rst")) as fh:
         table = fh.read()
     with open(os.path.join(REPO, "libskylark_tpu", "analysis",
                            "baseline.json")) as fh:
         baseline = fh.read()
-    for name in removed:
-        attr = name[len("SKYLARK_"):]
-        assert name not in sk_env.REGISTRY, name
-        assert not hasattr(sk_env, attr), attr
-        assert name not in table, name
-        assert attr not in baseline, attr
+    attr = name[len("SKYLARK_"):]
+    assert name not in sk_env.REGISTRY, name
+    assert not hasattr(sk_env, attr), attr
+    assert name not in table, name
+    assert attr not in baseline and attr.lower() not in baseline, attr
 
 
 # ---------------------------------------------------------------------------
-# layering: only engine/ reaches the autotuner
+# layering: which package may import which when it is imported
 # ---------------------------------------------------------------------------
 
 _PKG = os.path.join(REPO, "libskylark_tpu")
@@ -286,14 +292,25 @@ _TOP_LEVEL = sorted(
     if os.path.isfile(os.path.join(_PKG, d, "__init__.py")))
 
 
-def _imports(path):
+def _imports(path, module_level=False):
     """(line, statement, dotted names) of every import in one file, at
-    any depth (a function-level import counts)."""
+    any depth (a function-level import counts) or, with
+    ``module_level``, only those that run when the file is imported."""
     import ast
 
     with open(path) as fh:
         tree = ast.parse(fh.read(), filename=path)
-    for node in ast.walk(tree):
+
+    def walk(node):
+        for child in ast.iter_child_nodes(node):
+            if module_level and isinstance(
+                    child, (ast.FunctionDef, ast.AsyncFunctionDef,
+                            ast.Lambda)):
+                continue
+            yield child
+            yield from walk(child)
+
+    for node in walk(tree):
         if isinstance(node, ast.Import):
             names = [a.name for a in node.names]
         elif isinstance(node, ast.ImportFrom) and node.level == 0:
@@ -304,11 +321,92 @@ def _imports(path):
         yield node.lineno, ast.unparse(node), names
 
 
-def _imports_of_tune(path):
-    """(line, statement) of every import of libskylark_tpu.tune."""
-    return [(line, stmt) for line, stmt, names in _imports(path)
-            if any(n == "libskylark_tpu.tune"
-                   or n.startswith("libskylark_tpu.tune.") for n in names)]
+def _sibling_packages(names):
+    """The top-level packages of libskylark_tpu among dotted names."""
+    return {n.split(".")[1] for n in names
+            if n.startswith("libskylark_tpu.") and
+            n.split(".")[1] in _TOP_LEVEL}
+
+
+def _py_files(top):
+    for root, _dirs, files in os.walk(top):
+        for f in files:
+            if f.endswith(".py"):
+                yield os.path.join(root, f)
+
+
+# What each package may import of its siblings WHEN IT IS IMPORTED (a
+# function-level import is a call-time dependency and is not in this
+# table; ROADMAP Queue 3 item 22 lists the ones that point upward).
+# Written from the tree at PR 59; a package may use less than its set.
+_MAY_IMPORT = {
+    "analysis": set(), "base": set(), "cli": set(), "native": set(),
+    "telemetry": {"base"},
+    "utility": {"base"},
+    "qos": {"base", "telemetry"},
+    "resilience": {"base", "telemetry"},
+    "sketch": {"base", "telemetry"},
+    "sessions": {"base", "resilience", "telemetry"},
+    "engine": {"base", "qos", "resilience", "telemetry"},
+    "parallel": {"base", "sketch", "telemetry"},
+    "algorithms": {"base", "engine"},
+    "train": {"base", "resilience", "sessions", "telemetry"},
+    "fleet": {"base", "engine", "qos", "resilience", "telemetry"},
+    "net": {"base", "engine", "resilience", "telemetry"},
+    "nla": {"algorithms", "base", "engine", "telemetry"},
+    "ml": {"algorithms", "base", "engine", "nla", "resilience", "sketch",
+           "telemetry", "utility"},
+    "io": {"base", "ml", "resilience", "sketch", "telemetry"},
+    "dist": {"base", "engine", "io", "qos", "resilience", "telemetry"},
+}
+
+
+def test_layering_covers_every_package():
+    assert set(_MAY_IMPORT) == set(_TOP_LEVEL)
+    assert "tune" not in _TOP_LEVEL
+
+
+def test_the_layering_table_is_a_dag():
+    """The stated sets order the packages: importing any one of them
+    imports only packages below it, so no import order can deadlock on
+    a half-initialised module. ``engine`` is below ``sketch`` nowhere:
+    neither names the other at module level."""
+    import graphlib
+
+    order = list(graphlib.TopologicalSorter(_MAY_IMPORT).static_order())
+    assert sorted(order) == _TOP_LEVEL
+    assert "sketch" not in _MAY_IMPORT["engine"]
+    assert "engine" not in _MAY_IMPORT["sketch"]
+
+
+@pytest.mark.parametrize("package", _TOP_LEVEL)
+def test_module_level_imports_stay_inside_the_layering(package):
+    """The rule ``test_only_engine_imports_tune`` was one case of, until
+    PR 59 took ``tune/`` out: a package's module-level imports of its
+    siblings stay inside its stated set."""
+    hits = []
+    for path in _py_files(os.path.join(_PKG, package)):
+        for line, stmt, names in _imports(path, module_level=True):
+            extra = _sibling_packages(names) - {package} \
+                - _MAY_IMPORT[package]
+            if extra:
+                hits.append((os.path.relpath(path, REPO), line, stmt))
+    assert not hits, hits
+
+
+# The compiled-program layer — what sketch/, base/sparse.py, parallel/,
+# nla/, algorithms/ and ml/ all sit on — knows nothing above it, at any
+# depth: a function-level import of one of these would be the arrow
+# sketch -> engine.compiled -> (it) -> sketch that PR 59 removed.
+
+@pytest.mark.parametrize("module", ["compiled", "cache", "aot", "bucket"])
+def test_the_compiled_program_layer_reaches_nothing_above_it(module):
+    path = os.path.join(_PKG, "engine", module + ".py")
+    above = {"sketch", "nla", "ml", "dist", "fleet", "net", "tune"}
+    hits = [(line, stmt) for line, stmt, names in _imports(path)
+            if any(n.startswith("libskylark_tpu.") and
+                   n.split(".")[1] in above for n in names)]
+    assert not hits, hits
 
 
 def _kernel_modules_imported(path):
@@ -318,27 +416,6 @@ def _kernel_modules_imported(path):
             if part.startswith("pallas_")}
 
 
-def test_layering_covers_every_package():
-    assert {"engine", "tune", "sketch", "base"} <= set(_TOP_LEVEL)
-
-
-@pytest.mark.parametrize(
-    "package", [p for p in _TOP_LEVEL if p not in ("engine", "tune")])
-def test_only_engine_imports_tune(package):
-    """``tune/`` ranks and caches plans for the serve tier; ``engine/``
-    is its only importer. The kernels below (``sketch/``) decide from
-    the argument, the setter and the shapes, and what ``tune/`` needs of
-    them it imports from them, never the reverse."""
-    hits = []
-    for root, _dirs, files in os.walk(os.path.join(_PKG, package)):
-        for f in files:
-            if f.endswith(".py"):
-                path = os.path.join(root, f)
-                hits += [(os.path.relpath(path, REPO), *h)
-                         for h in _imports_of_tune(path)]
-    assert not hits, hits
-
-
 # The two kernels the v5e's compiler refused are gone with their pins (PR
 # 47): the sparse table-and-gather body of pallas_sparse.py and
 # pallas_fwht.py. What is left of the choice of a kernel: ``sketch/`` from
@@ -346,7 +423,10 @@ def test_only_engine_imports_tune(package):
 # three batched kernels for a flush.
 
 _GONE = ("pallas_fwht", "cwt_sparse_apply_batched", "SKYLARK_SPARSE_KERNEL",
-         "SKYLARK_FWHT_KERNEL", "SKYLARK_FWHT_MIN_N")
+         "SKYLARK_FWHT_KERNEL", "SKYLARK_FWHT_MIN_N",
+         # PR 59: the tuner, its cache file, the key's fingerprint
+         "libskylark_tpu.tune", "plan_cache.json", "plan_fingerprint",
+         "record_ranked", "use_plan_cache")
 
 
 def test_the_rows_kernel_stands_without_the_hash_kernel():

@@ -38,7 +38,6 @@ import jax.numpy as jnp
 
 from libskylark_tpu import engine
 from libskylark_tpu.engine import aot
-from libskylark_tpu.engine import serve as serve_mod
 from libskylark_tpu.engine import warmup
 
 
@@ -359,21 +358,34 @@ class TestWarmupPack:
         assert warm["engine"]["aot_loads"] == 3
         assert warm["bit_equal"], warm["mismatches"]
 
-    def test_plan_fingerprint_drift_skips_pack(self, fresh_engine,
-                                               tmp_path):
+    def test_old_schema_pack_is_reported_skipped(self, fresh_engine,
+                                                 tmp_path, capsys):
+        """A pack built before schema 2 keyed its entries on a part the
+        key no longer has: none could ever hit, so the loader says so
+        instead of loading artifacts nothing will ask for."""
+        from libskylark_tpu.cli import skylark_warmup
+
         pack = str(tmp_path / "pack")
         warmup.build_pack(pack, _pack_specs()[:1])
         manifest = warmup.read_manifest(pack)
-        manifest["plan_fingerprint"] = "deadbeefdeadbeef"
+        assert manifest["schema"] == warmup.PACK_SCHEMA == 2
+        assert sorted(manifest) == ["compat", "created", "entries",
+                                    "max_batch", "pad_floor", "schema"]
+        manifest["schema"] = 1
         with open(os.path.join(pack, warmup.MANIFEST), "w") as fh:
             json.dump(manifest, fh)
         engine.reset()
         report = warmup.load_pack(pack)
         assert report["loaded"] == 0
-        assert report["plan_fingerprint_match"] is False
-        assert "drift" in report["skipped"]
-        with pytest.raises(RuntimeError, match="drift"):
+        assert report["skipped"] == "schema 1 != 2"
+        assert sorted(report) == ["entries", "failed", "kernel_restored",
+                                  "loaded", "resident", "skipped"]
+        with pytest.raises(RuntimeError, match="schema 1 != 2"):
             warmup.load_pack(pack, strict=True)
+        assert skylark_warmup.main(["inspect", "--pack", pack]) == 1
+        said = json.loads(capsys.readouterr().out)
+        assert said["compat_reason"] == "schema 1 != 2"
+        assert not said["compat_ok_here"]
 
     def test_compat_mismatch_skips_pack(self, fresh_engine, tmp_path):
         pack = str(tmp_path / "pack")
@@ -392,23 +404,22 @@ class TestWarmupPack:
         assert report["loaded"] == 0 and report["skipped"]
 
     def test_kernel_token_parse_and_restore(self, fresh_engine):
-        from libskylark_tpu.tune import Plan
-
-        # a pack written by an older tree may name its pipelined
-        # kernel: the token parses to the plan without it
-        p = serve_mod._parse_plan_token("pallas/mt128/pipe")
-        assert p == Plan(backend="pallas", m_tile=128)
-        assert serve_mod._parse_plan_token("pallas/mt128") == p
-        assert serve_mod._parse_plan_token("mosaic-nonsense") is None
+        """A token is a backend's name; anything else — an older
+        tree's ``pallas/mt128/f32`` among them — declines to live
+        resolution, which then answers by the rule."""
         ex = engine.MicrobatchExecutor(max_batch=2, linger_us=500)
         try:
             statics = ("sketch_apply", "CWT", "None", 16, False,
                        "float32", (64, 8))
             assert ex.restore_kernel_choice(statics, 2, "xla")
-            fp = engine.plan_fingerprint()
-            assert ex._kernel_memo[(statics, 2, fp)] == \
-                ("xla", None, "pack", None)
-            assert not ex.restore_kernel_choice(statics, 2, "garbage!")
+            assert ex._kernel_memo[(statics, 2)] == ("xla", "pack", None)
+            assert ex.restore_kernel_choice(statics, 4, "pallas")
+            assert ex._kernel_memo[(statics, 4)] == (
+                "pallas", "pack", None)
+            for old in ("pallas/mt128/f32", "pallas/mt128/pipe",
+                        "garbage!"):
+                assert not ex.restore_kernel_choice(statics, 8, old)
+            assert (statics, 8) not in ex._kernel_memo
         finally:
             ex.shutdown()
 
@@ -422,8 +433,7 @@ class TestWarmupPack:
         ex = engine.MicrobatchExecutor(max_batch=2, linger_us=500,
                                        kernel="xla")
         try:
-            assert not ex.restore_kernel_choice(statics, 2,
-                                                "pallas/mt128")
+            assert not ex.restore_kernel_choice(statics, 2, "pallas")
             assert not ex._kernel_memo
         finally:
             ex.shutdown()
@@ -433,19 +443,6 @@ class TestWarmupPack:
             assert not ex.restore_kernel_choice(statics, 2, "xla")
             assert not ex._kernel_memo
         finally:
-            ex.shutdown()
-        # disabling plan consultation also disables pack restoration —
-        # the pack's decisions ARE plan-cache decisions
-        monkeypatch.delenv("SKYLARK_SERVE_KERNEL")
-        from libskylark_tpu.sketch import params as sketch_params
-
-        ex = engine.MicrobatchExecutor(max_batch=2, linger_us=500)
-        try:
-            sketch_params.set_use_plan_cache(False)
-            assert not ex.restore_kernel_choice(statics, 2, "xla")
-            assert not ex._kernel_memo
-        finally:
-            sketch_params.set_use_plan_cache(True)
             ex.shutdown()
 
     def test_second_load_skips_resident_keys(self, fresh_engine,
@@ -470,26 +467,25 @@ class TestWarmupPack:
         finally:
             ex.shutdown()
 
-    def test_select_top_buckets_from_plan_cache(self, tmp_path):
-        from libskylark_tpu import tune
+    def test_cli_builds_from_specs_alone(self, fresh_engine, tmp_path,
+                                         capsys):
+        from libskylark_tpu.cli import skylark_warmup
 
-        cache = tune.PlanCache(path=None)
-        w1 = tune.serve_workload("sketch_apply", "JLT", "float32",
-                                 (64, 128), 32, 8, rowwise=True)
-        w2 = tune.serve_workload("sketch_apply", "CWT", "float32",
-                                 (64, 8), 16, 2, rowwise=False)
-        cache.put(w1, tune.Plan(backend="xla"), source="measured")
-        cache.put(w2, tune.Plan(backend="xla"), source="ranked")
-        prev = tune.set_cache(cache)
-        try:
-            specs = warmup.select_top_buckets(8)
-        finally:
-            tune.set_cache(prev)
-        assert len(specs) == 2
-        # measured entries rank ahead of ranked ones
-        assert specs[0].family == "JLT" and specs[0].capacities == (8,)
-        assert specs[0].rowwise and specs[0].s_dim == 32
-        assert specs[1].family == "CWT" and not specs[1].rowwise
+        pack = str(tmp_path / "pack")
+        with pytest.raises(SystemExit):       # --spec is required
+            skylark_warmup.main(["build", "--pack", pack])
+        capsys.readouterr()
+        spec = json.dumps(_pack_specs()[0].to_dict())
+        assert skylark_warmup.main(
+            ["build", "--pack", pack, "--spec", spec]) == 0
+        built = json.loads(capsys.readouterr().out)
+        assert built["entries"] == 2 and not built["artifact_missing"]
+        assert sorted(built) == ["artifact_missing", "compat", "entries",
+                                 "pack"]
+        assert skylark_warmup.main(["inspect", "--pack", pack]) == 0
+        said = json.loads(capsys.readouterr().out)
+        assert said["schema"] == 2 and said["compat_ok_here"]
+        assert {e["kernel"] for e in said["entries"]} == {"xla"}
 
     def test_artifact_headers_readable_without_unpickle(
             self, fresh_engine, tmp_path):
@@ -510,10 +506,11 @@ class TestEnvPropagation:
         from libskylark_tpu.fleet import replica as replica_mod
 
         monkeypatch.setenv("SKYLARK_AOT_DIR", "/tmp/a")
-        monkeypatch.setenv("SKYLARK_PLAN_CACHE", "/tmp/p.json")
+        monkeypatch.setenv("SKYLARK_SERVE_KERNEL", "pallas")
         monkeypatch.delenv("SKYLARK_TELEMETRY_DIR", raising=False)
         snap = replica_mod.propagated_env()
         assert snap["SKYLARK_AOT_DIR"] == "/tmp/a"
+        assert snap["SKYLARK_SERVE_KERNEL"] == "pallas"
         assert snap["SKYLARK_TELEMETRY_DIR"] is None
         # the parent moves on; the child still applies the snapshot
         monkeypatch.setenv("SKYLARK_AOT_DIR", "/tmp/CHANGED")

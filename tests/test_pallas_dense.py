@@ -351,9 +351,6 @@ def test_effective_plan_reports_actual_config():
                  "precision": "bf16x3",
                  "plan_id": "pallas/mt1024/bf16x3",
                  "plan_source": "arg"}
-    # the id is the one tune/plans.py writes for the same plan
-    from libskylark_tpu.tune.plans import Plan
-    assert p["plan_id"] == Plan("pallas", 1024, "bf16x3").plan_id()
 
     # requested tile exceeds the VMEM plan: pre-shrunk, and the plan says
     # so (this is the silent adjustment the record must surface)
@@ -1091,3 +1088,75 @@ def test_one_chip_cells_trace_the_program_they_did(cell):
     assert root in text
     digest = hashlib.sha256(text.replace(root, "<root>").encode())
     assert digest.hexdigest()[:20] == _CELL_PROGRAMS[cell]
+
+
+class TestOperatorResidencyOnePredicate:
+    """Where the generated operator lives between m-tiles is decided in
+    ONE place (``pallas_dense.operator_residency``); the reported plan
+    and the kernel call that is traced both read it, so they cannot
+    disagree."""
+
+    @pytest.mark.parametrize(
+        "shape,s,m_tile,seq_axis,want",
+        [((65536, 8192), 1024, 512, 1, "hbm"),       # the benchmark cell
+         ((1024, 1024), 128, 256, 1, "vmem"),        # small S: VMEM cache
+         ((512, 8192), 1024, 512, 1, "per_tile"),    # one m-tile
+         ((8192, 65536), 1024, 512, 0, "hbm"),       # columnwise big S
+         # nobody's request, under a v5e's cap: the planner's 2048 (PR 49)
+         ((65536, 8192), 1024, None, 1, "hbm"),
+         ((8192, 65536), 1024, None, 0, "hbm")],
+        ids=["headline_hbm", "small_vmem", "single_tile", "columnwise",
+             "headline_grown", "columnwise_grown"])
+    def test_plan_and_kernel_agree(self, shape, s, m_tile, seq_axis, want):
+        n, m = shape[seq_axis], shape[1 - seq_axis]
+        # reader 1: the reported plan
+        plan = pd.effective_plan(randgen.Normal(), shape, jnp.float32, s,
+                                 seq_axis, m_tile=m_tile, interpret=True,
+                                 vmem_cap=64 << 20)
+        m_tile = m_tile or 2048
+        assert pd.operator_residency(s, n, m, m_tile) == want
+        assert plan["m_tile"] == m_tile
+        assert (plan["vmem_limit_bytes"] > 0) == (m_tile == 2048)
+        assert plan["operator_residency"] == want
+        assert plan["operator_cache"] is (want == "vmem")
+        # reader 2: the call that is traced — a generation call plus a
+        # contraction call under "hbm", one fused call otherwise
+        call = pd._fused_call if seq_axis == 1 else pd._fused_call_cw
+        traced = jax.make_jaxpr(functools.partial(
+            call, s_dim=s, dist_kind="normal", m_tile=m_tile,
+            precision="bf16x3", interpret=True))(
+                jax.ShapeDtypeStruct(shape, jnp.float32),
+                jax.ShapeDtypeStruct((n // 256, 2), jnp.uint32))
+        assert str(traced).count("pallas_call") == (2 if want == "hbm"
+                                                    else 1)
+
+
+class TestEagerDispatchKnobs:
+    """The knobs of an eager apply are the call-site argument, else the
+    sketch.params setter, else the default."""
+
+    SHAPE = (64, 1024)
+    S = 96
+
+    def test_explicit_arg_beats_setter(self):
+        sketch_params.set_pallas_m_tile(8)
+        try:
+            plan = pd.effective_plan(randgen.Normal(), self.SHAPE,
+                                     jnp.float32, self.S, 1, m_tile=32,
+                                     interpret=True)
+        finally:
+            sketch_params.set_pallas_m_tile(None)
+        assert plan["m_tile"] == 32          # arg wins
+        assert plan["plan_source"] == "arg"
+        assert plan["precision"] == "bf16x3"  # open knob: the setter's
+
+    def test_runtime_setter_beats_default(self):
+        sketch_params.set_pallas_m_tile(32)
+        try:
+            plan = pd.effective_plan(randgen.Normal(), self.SHAPE,
+                                     jnp.float32, self.S, 1,
+                                     interpret=True)
+        finally:
+            sketch_params.set_pallas_m_tile(None)
+        assert plan["m_tile"] == 32
+        assert plan["plan_source"] == "heuristic"

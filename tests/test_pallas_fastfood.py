@@ -209,3 +209,62 @@ class TestOnChip:
         assert got is not None and pf.last_served_variant == variant
         np.testing.assert_allclose(np.asarray(got), _oracle(T, X),
                                    atol=1e-4, rtol=1e-4)
+
+
+class TestFastfoodExplicitCall:
+    def _transform(self):
+        return FastGaussianRFT(512, 512, Context(seed=9), sigma=2.0)
+
+    def _input(self):
+        return _X(32, 512, seed=3, scale=1.0)
+
+    def test_explicit_call_reaches_kernel(self):
+        """The kernel is reached by an explicit call and by nothing
+        else — otherwise a precision sweep silently measures the XLA
+        chain under a kernel label."""
+        T, A = self._transform(), self._input()
+        pf.last_served_variant = None
+        out = pf.features_rows(T, A, interpret=True, precision="f32",
+                               variant="split")
+        assert out is not None and pf.last_served_variant == "split"
+        ref = T._features_rows(A)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-4)
+
+    @pytest.mark.parametrize("variant,launcher",
+                             [("fused", "_launch"),
+                              ("split", "_launch_split")])
+    def test_requested_variant_rejection_is_loud(self, variant, launcher,
+                                                 monkeypatch):
+        """An explicitly requested variant that Mosaic rejects must
+        raise — never turn into the other variant or the XLA chain
+        silently (on the chip a silent fallback serves a different
+        program than the record and the caller believe)."""
+        T, A = self._transform(), self._input()
+        monkeypatch.setattr(pf, "supported", lambda *a: True)
+        monkeypatch.setattr(
+            pf, launcher,
+            lambda *a, **k: (_ for _ in ()).throw(
+                RuntimeError("simulated Mosaic rejection")))
+        with pytest.raises(RuntimeError, match="simulated Mosaic"):
+            pf.features_rows(T, A, precision="f32", variant=variant)
+
+    def test_transform_apply_takes_the_xla_chain(self, monkeypatch):
+        """``FastRFT.apply`` does not reach the fused kernel: Mosaic
+        rejects both its variants on a v5e, so the transform's own path
+        is the one compiled program of ``frft.fastfood_features`` (PR 48),
+        equal to the XLA chain to float32 tolerance (another summation
+        order, ``cos_turns`` for the cosine)."""
+        from libskylark_tpu.sketch import COLUMNWISE, ROWWISE
+
+        def no_kernel(*a, **k):
+            raise AssertionError("FastRFT.apply reached the kernel")
+
+        monkeypatch.setattr(pf, "features_rows", no_kernel)
+        T, A = self._transform(), self._input()
+        ref = np.asarray(T._features_rows(A))
+        tol = 2e-5 * T.scale
+        np.testing.assert_allclose(np.asarray(T.apply(A, ROWWISE)), ref,
+                                   rtol=0, atol=tol)
+        np.testing.assert_allclose(np.asarray(T.apply(A.T, COLUMNWISE)),
+                                   ref.T, rtol=0, atol=tol)

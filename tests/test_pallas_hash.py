@@ -19,10 +19,9 @@ Oracles, strongest first:
   differs, values don't).
 - serve integration: a forced-pallas flush is bit-equal to the
   capacity-1 XLA dispatch, the kernel choice is a static of the
-  executable key, declines are counted by reason, and on a CPU host the
-  tuner correctly certifies XLA for every serve bucket (the interpret
-  penalty) while a TPU device kind ranks the kernel where the model
-  says it wins.
+  executable key, declines are counted by reason, and the flush rule
+  (``kernel=`` argument > ``SKYLARK_SERVE_KERNEL`` > XLA) is held as a
+  table over every bucket family.
 """
 
 from __future__ import annotations
@@ -34,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import jax.random as jr
 
-from libskylark_tpu import Context, engine, tune
+from libskylark_tpu import Context, engine
 from libskylark_tpu import sketch as sk
 from libskylark_tpu.base import randgen
 from libskylark_tpu.base import threefry as tf
@@ -47,15 +46,6 @@ def fresh_engine():
     engine.reset()
     yield
     engine.reset()
-
-
-@pytest.fixture()
-def mem_plan_cache():
-    """In-memory plan cache (no disk, empty): tests that edit plans must
-    not touch the committed benchmarks/plan_cache.json."""
-    prev = tune.set_cache(tune.PlanCache(path=None))
-    yield tune.get_cache()
-    tune.set_cache(prev)
 
 
 def _cwt_and_ref(n, s, m, seed=7, rowwise=False):
@@ -194,35 +184,25 @@ class TestQualifyAndDispatch:
         assert ph.plan_tiles(4096, 8, 50_000_000) is None
 
     @pytest.mark.skipif(ph.available(), reason="CPU-host dispatch test")
-    def test_try_apply_declines_off_tpu(self, monkeypatch,
-                                        mem_plan_cache):
+    def test_try_apply_declines_off_tpu(self, monkeypatch):
         """The direct-apply hook: off-TPU the kernel always declines —
-        env override and even a (mis-)certified plan entry cannot route
-        an eager apply into uncompileable Mosaic."""
+        the env override cannot route an eager apply into uncompileable
+        Mosaic."""
         T = sk.CWT(40, 16, Context(seed=0))
         A = jnp.asarray(np.ones((40, 3), np.float32))
         assert ph.try_apply(T, A, rowwise=False) is None
         monkeypatch.setenv("SKYLARK_HASH_KERNEL", "pallas")
         assert ph.try_apply(T, A, rowwise=False) is None
         monkeypatch.delenv("SKYLARK_HASH_KERNEL")
-        w = tune.hash_workload("CWT", A.shape, A.dtype, 16, seq_axis=0)
-        mem_plan_cache.put(w, tune.Plan("pallas"), source="measured",
-                           value=1.0)
-        assert ph.try_apply(T, A, rowwise=False) is None
         # and the public apply still serves (the scatter)
         out = T.apply(A, sk.COLUMNWISE)
         assert np.isfinite(np.asarray(out)).all()
 
-    def test_try_apply_takes_only_the_env_pin(self, monkeypatch,
-                                              mem_plan_cache):
-        """Where the kernel qualifies (a TPU, played here), a cached
-        "pallas" plan for the workload does not steer an eager apply:
-        only ``SKYLARK_HASH_KERNEL`` routes it to the kernel."""
+    def test_try_apply_takes_only_the_env_pin(self, monkeypatch):
+        """Where the kernel qualifies (a TPU, played here), only
+        ``SKYLARK_HASH_KERNEL`` routes an eager apply to the kernel."""
         T = sk.CWT(40, 16, Context(seed=0))
         A = jnp.asarray(np.ones((40, 3), np.float32))
-        mem_plan_cache.put(
-            tune.hash_workload("CWT", A.shape, A.dtype, 16, seq_axis=0),
-            tune.Plan("pallas"), source="measured", value=1.0)
         monkeypatch.setattr(ph, "qualify", lambda *a, **k: (True, "ok"))
         calls = []
         monkeypatch.setattr(
@@ -239,61 +219,6 @@ class TestQualifyAndDispatch:
         assert calls == ["exact", "mxu"]
 
 
-class TestTuneServeBuckets:
-    def test_hash_candidates_and_cpu_ranking(self):
-        w = tune.hash_workload("CWT", (1000, 8), "float32", 32,
-                               seq_axis=0)
-        plans = tune.enumerate_candidates(w)
-        assert {p.backend for p in plans} == {"pallas", "xla"}
-        # on a CPU host the pallas plan means the interpreter: the
-        # penalty must rank XLA first, always
-        best, cost = tune.rank_candidates(w)[0]
-        assert best.backend == "xla"
-
-    def test_tpu_ranking_prefers_kernel_in_its_regime(self):
-        # long stream, narrow sketch: the scatter serializes n rows
-        # while the one-hot contraction is cheap — kernel wins
-        w = tune.serve_workload(
-            "sketch_apply", "CWT", "float32", (1024, 64), 32, 16,
-            rowwise=False, device_kind="tpu_v5_lite")
-        assert tune.rank_candidates(w)[0][0].backend == "pallas"
-        # fastfood: fused chain ~9x less HBM traffic than the XLA chain
-        wf = tune.serve_workload(
-            "fastfood_features", "FastGaussianRFT", "float32",
-            (512, 512), 512, 8, device_kind="tpu_v5_lite")
-        assert tune.rank_candidates(wf)[0][0].backend == "pallas"
-
-    def test_serve_key_carries_batch_class_legacy_keys_unchanged(self):
-        w = tune.serve_workload("sketch_apply", "JLT", "float32",
-                                (64, 128), 32, 8, rowwise=True)
-        assert w.key().endswith("|b8")
-        legacy = tune.dense_workload("normal", (64, 128), "float32", 32,
-                                     seq_axis=1)
-        assert "|b" not in legacy.key()
-
-    def test_record_ranked_persists_and_yields_to_measured(
-            self, mem_plan_cache):
-        w = tune.serve_workload("sketch_apply", "CWT", "float32",
-                                (64, 8), 16, 4, rowwise=False)
-        plan, cost = tune.record_ranked(w)
-        ent = mem_plan_cache.entry(w)
-        assert ent["source"] == "ranked"
-        assert ent["plan"]["backend"] == plan.backend == "xla"
-        # a measured certification is never displaced by a re-ranking
-        mem_plan_cache.put(w, tune.Plan("pallas"), source="measured",
-                           value=2.0)
-        tune.record_ranked(w)
-        assert mem_plan_cache.entry(w)["source"] == "measured"
-
-    def test_dense_serve_candidates_cross_m_tiles(self):
-        w = tune.serve_workload("sketch_apply", "JLT", "float32",
-                                (512, 1024), 64, 8, rowwise=True)
-        plans = tune.enumerate_candidates(w)
-        mts = {p.m_tile for p in plans if p.backend == "pallas"}
-        assert mts == {128, 256, 512}
-        assert any(p.backend == "xla" for p in plans)
-
-
 class TestServeKernelSelection:
     def _cwt_reqs(self, k=8, seed=21):
         rng = np.random.default_rng(seed)
@@ -303,7 +228,7 @@ class TestServeKernelSelection:
         return T, ops
 
     def test_forced_pallas_flush_bit_equal_to_capacity1_xla(
-            self, fresh_engine, mem_plan_cache):
+            self, fresh_engine):
         """The CI gate's bit-equality leg: a coalesced kernel-path
         flush equals the capacity-1 forced-XLA dispatch bitwise (exact
         accumulation under the interpreter)."""
@@ -322,8 +247,7 @@ class TestServeKernelSelection:
                     T, A, dimension=sk.COLUMNWISE).result(timeout=60))
                 assert np.array_equal(p, s)
 
-    def test_kernel_choice_is_executable_key_static(self, fresh_engine,
-                                                    mem_plan_cache):
+    def test_kernel_choice_is_executable_key_static(self, fresh_engine):
         """Forcing the other backend on an identical bucket compiles a
         DIFFERENT executable — the choice token is in the key, so a
         selection flip can never silently reuse the wrong program."""
@@ -342,51 +266,40 @@ class TestServeKernelSelection:
         assert engine.stats().misses > m0
         assert engine.stats().recompiles == 0
 
-    def test_env_override_beats_plan_cache(self, fresh_engine,
-                                           mem_plan_cache, monkeypatch):
+    def test_env_pin_beats_the_default(self, fresh_engine, monkeypatch):
+        """arg > env > default precedence, env leg: the pin routes the
+        flush through the kernel, bit-equal (exact accumulation) to the
+        default's."""
         T, ops = self._cwt_reqs(k=4)
-        w = tune.serve_workload("sketch_apply", "CWT", "float32",
-                                (64, 8), 16, 4, rowwise=False)
-        mem_plan_cache.put(w, tune.Plan("pallas"), source="measured",
-                           value=1.0)
-        monkeypatch.setenv("SKYLARK_SERVE_KERNEL", "xla")
+        outs = {}
+        for pin in (None, "pallas"):
+            if pin:
+                monkeypatch.setenv("SKYLARK_SERVE_KERNEL", pin)
+            with engine.MicrobatchExecutor(max_batch=4,
+                                           linger_us=1000) as ex:
+                futs = [ex.submit_sketch(T, A, dimension=sk.COLUMNWISE)
+                        for A in ops]
+                outs[pin] = [np.asarray(f.result(timeout=60))
+                             for f in futs]
+                assert (ex.stats()["kernel"]["by_backend"]
+                        == {pin or "xla": {"flushes": 1}})
+        for a, b in zip(outs[None], outs["pallas"]):
+            assert np.array_equal(a, b)
+
+    def test_default_is_xla(self, fresh_engine, monkeypatch):
+        monkeypatch.delenv("SKYLARK_SERVE_KERNEL", raising=False)
+        T, ops = self._cwt_reqs(k=4)
         with engine.MicrobatchExecutor(max_batch=4,
                                        linger_us=1000) as ex:
             futs = [ex.submit_sketch(T, A, dimension=sk.COLUMNWISE)
                     for A in ops]
             [f.result(timeout=60) for f in futs]
-            st = ex.stats()
-        assert st["kernel"]["by_backend"] == {"xla": {"flushes": 1}}
-
-    def test_plan_cache_routes_flush_and_default_is_xla(
-            self, fresh_engine, mem_plan_cache):
-        """arg > override > cache > default precedence, cache leg: a
-        certified pallas entry for EXACTLY this (bucket, capacity)
-        routes the flush through the kernel; without one the default
-        stays the vmapped XLA path."""
-        T, ops = self._cwt_reqs(k=4)
-        with engine.MicrobatchExecutor(max_batch=4,
-                                       linger_us=1000) as ex:
-            futs = [ex.submit_sketch(T, A, dimension=sk.COLUMNWISE)
-                    for A in ops]
-            xla_out = [np.asarray(f.result(timeout=60)) for f in futs]
             assert (ex.stats()["kernel"]["by_backend"]
                     == {"xla": {"flushes": 1}})
-        w = tune.serve_workload("sketch_apply", "CWT", "float32",
-                                (64, 8), 16, 4, rowwise=False)
-        mem_plan_cache.put(w, tune.Plan("pallas"), source="measured",
-                           value=1.0)
-        with engine.MicrobatchExecutor(max_batch=4,
-                                       linger_us=1000) as ex:
-            futs = [ex.submit_sketch(T, A, dimension=sk.COLUMNWISE)
-                    for A in ops]
-            pal_out = [np.asarray(f.result(timeout=60)) for f in futs]
-            assert (ex.stats()["kernel"]["by_backend"]
-                    == {"pallas": {"flushes": 1}})
-        for a, b in zip(xla_out, pal_out):
-            assert np.array_equal(a, b)   # exact accum: bit-equal
+            assert list(ex._kernel_memo.values()) == [
+                ("xla", "default", None)]
 
-    def test_decline_reason_counted(self, fresh_engine, mem_plan_cache):
+    def test_decline_reason_counted(self, fresh_engine):
         """A pallas intent the kernel can't serve (f64) falls back to
         XLA and the reason lands in the by_reason label set."""
         rng = np.random.default_rng(5)
@@ -404,7 +317,7 @@ class TestServeKernelSelection:
         assert agg["kernel"]["by_reason"]
 
     def test_prometheus_rendering_of_kernel_counters(
-            self, fresh_engine, mem_plan_cache):
+            self, fresh_engine):
         """The fleet-operator surface: kernel selection and decline
         reasons render through the by_<label> convention as Prometheus
         label sets — skylark_serve_kernel_flushes{backend="..."} and
@@ -433,7 +346,7 @@ class TestServeKernelSelection:
         assert declined and any("float64" in ln for ln in declined)
 
     def test_zero_recompiles_after_warmup_with_selection(
-            self, fresh_engine, mem_plan_cache):
+            self, fresh_engine):
         """The acceptance criterion: selection enabled, every capacity
         class warmed once, then a storm — zero misses, zero
         recompiles."""
@@ -455,41 +368,85 @@ class TestServeKernelSelection:
             assert engine.stats().recompiles == r0
 
 
-class TestPlanEditInvalidation:
-    def test_plan_edit_recompiles_measurement_rerecord_does_not(
-            self, fresh_engine, mem_plan_cache):
-        """The r7 fingerprint contract extended to serve buckets:
-        editing a bucket's PLAN re-keys (and recompiles) its flush
-        executable exactly once; re-recording a better measurement of
-        the SAME plan recompiles nothing."""
-        rng = np.random.default_rng(31)
-        T = sk.CWT(40, 16, Context(seed=31))
-        ops = [rng.standard_normal((40, 3)).astype(np.float32)
-               for _ in range(4)]
-        w = tune.serve_workload("sketch_apply", "CWT", "float32",
-                                (64, 8), 16, 4, rowwise=False)
-        mem_plan_cache.put(w, tune.Plan("xla"), source="ranked")
-        with engine.MicrobatchExecutor(max_batch=4,
-                                       linger_us=1000) as ex:
-            def storm():
-                futs = [ex.submit_sketch(T, A, dimension=sk.COLUMNWISE)
-                        for A in ops]
-                return [np.asarray(f.result(timeout=60)) for f in futs]
+# The flush rule as a table: which program serves a flush is the
+# executor's ``kernel=`` argument, else ``SKYLARK_SERVE_KERNEL``, else
+# XLA; a pallas intent on a family without a batched kernel declines.
 
-            first = storm()
-            m0 = engine.stats().misses
-            # measurement re-record, same plan: fingerprint unchanged
-            mem_plan_cache.record_measurement(w, tune.Plan("xla"), 5.0)
-            storm()
-            assert engine.stats().misses == m0
-            # plan EDIT: xla -> pallas — exactly one fresh compile for
-            # this bucket's capacity class, results still bit-equal
-            mem_plan_cache.put(w, tune.Plan("pallas"),
-                               source="measured", value=9.0)
-            edited = storm()
-            assert engine.stats().misses == m0 + 1
-            assert ex.stats()["kernel"]["by_backend"]["pallas"][
-                "flushes"] >= 1
-            for a, b in zip(first, edited):
-                assert np.array_equal(a, b)
-            assert engine.stats().recompiles == 0
+_NO_KERNEL = "no-batched-kernel-the-lane-program-serves"
+
+
+def _family_request(family):
+    """(submit, transform, operand) of one tiny request of a bucket
+    family."""
+    import scipy.sparse as sp
+
+    from libskylark_tpu.base.sparse import SparseMatrix
+    from libskylark_tpu.sketch.fjlt import FJLT
+    from libskylark_tpu.sketch.frft import FastGaussianRFT
+
+    rng = np.random.default_rng(3)
+    ctx = Context(seed=3)
+
+    def dense(shape):
+        return rng.standard_normal(shape).astype(np.float32)
+
+    def sketch(dimension):
+        return lambda ex, T, A: ex.submit_sketch(T, A, dimension=dimension)
+
+    if family == "jlt_rowwise":
+        return sketch(sk.ROWWISE), sk.JLT(128, 16, ctx), dense((8, 128))
+    if family == "jlt_columnwise":
+        return sketch(sk.COLUMNWISE), sk.JLT(128, 16, ctx), dense((128, 8))
+    if family == "cwt":
+        return sketch(sk.COLUMNWISE), sk.CWT(40, 16, ctx), dense((40, 3))
+    if family == "fastfood":
+        return (lambda ex, T, A: ex.submit_fastfood(T, A),
+                FastGaussianRFT(512, 512, ctx, sigma=2.0), dense((8, 512)))
+    if family == "srht":
+        return (sketch(sk.ROWWISE), FJLT(256, 16, ctx, fut="wht"),
+                dense((5, 256)))
+    assert family == "sparse"
+    A = SparseMatrix.from_scipy(sp.random(
+        256, 6, density=0.02, random_state=3, dtype=np.float32,
+        format="coo"))
+    return (lambda ex, T, A: ex.submit_sparse(T, A, dimension=sk.COLUMNWISE),
+            sk.CWT(256, 16, ctx), A)
+
+
+_BATCHED = ("jlt_rowwise", "jlt_columnwise", "cwt", "fastfood")
+_LANE_ONLY = ("sparse", "srht")
+
+
+@pytest.mark.parametrize("family", _BATCHED + _LANE_ONLY)
+@pytest.mark.parametrize("pin", ["none", "arg_xla", "arg_pallas",
+                                 "env_pallas"])
+def test_flush_rule(fresh_engine, monkeypatch, family, pin):
+    monkeypatch.delenv("SKYLARK_SERVE_KERNEL", raising=False)
+    if pin == "env_pallas":
+        monkeypatch.setenv("SKYLARK_SERVE_KERNEL", "pallas")
+    kernel = {"arg_xla": "xla", "arg_pallas": "pallas"}.get(pin)
+    source = {"none": "default", "env_pallas": "env"}.get(pin, "arg")
+    intent = "pallas" if pin.endswith("pallas") else "xla"
+    if intent == "pallas" and family in _LANE_ONLY:
+        want = ("xla", source, _NO_KERNEL)
+    else:
+        want = (intent, source, None)
+    submit, T, A = _family_request(family)
+    with engine.MicrobatchExecutor(max_batch=1, linger_us=100,
+                                   kernel=kernel) as ex:
+        out = np.asarray(submit(ex, T, A).result(timeout=120))
+        st = ex.stats()["kernel"]
+        assert list(ex._kernel_memo.values()) == [want]
+    assert np.isfinite(out).all()
+    assert st["by_backend"] == {want[0]: {"flushes": 1}}
+    assert st["by_reason"] == ({want[2]: {"declined_flushes": 1}}
+                               if want[2] else {})
+
+
+def test_the_argument_beats_the_env(fresh_engine, monkeypatch):
+    monkeypatch.setenv("SKYLARK_SERVE_KERNEL", "pallas")
+    submit, T, A = _family_request("cwt")
+    with engine.MicrobatchExecutor(max_batch=1, linger_us=100,
+                                   kernel="xla") as ex:
+        submit(ex, T, A).result(timeout=60)
+        assert list(ex._kernel_memo.values()) == [("xla", "arg", None)]

@@ -29,7 +29,7 @@ import pytest
 import jax.numpy as jnp
 import scipy.sparse as sp
 
-from libskylark_tpu import Context, engine, tune
+from libskylark_tpu import Context, engine
 from libskylark_tpu import sketch as sk
 from libskylark_tpu.base.sparse import SparseMatrix, spmm, spmm_t
 from libskylark_tpu.engine import bucket as bucketing
@@ -348,7 +348,7 @@ class TestSparseFlush:
                 T, A, dimension=dimension).result(timeout=60))
             (memo_key,) = ex._kernel_memo
             assert ex._kernel_memo[memo_key] == (
-                "xla", None, "default", None)
+                "xla", "default", None)
             assert not ex.restore_kernel_choice(memo_key[0], 4, "pallas")
             assert ex.restore_kernel_choice(memo_key[0], 4, "xla")
         if intent == "env":
@@ -361,7 +361,7 @@ class TestSparseFlush:
             (choice,) = ex._kernel_memo.values()
         assert np.array_equal(got, want)
         slug = "no-batched-kernel-the-lane-program-serves"
-        assert choice == ("xla", None, intent, slug)
+        assert choice == ("xla", intent, slug)
         assert st["kernel"]["by_reason"] == {slug: {"declined_flushes": 1}}
         assert st["sparse"]["by_backend"] == {"xla": {"kernel_flushes": 1}}
 
@@ -372,7 +372,7 @@ class TestSparseFlush:
         """Shapes at which a direct rowwise apply on a TPU takes the rows
         kernel (``sparse_kernel``): the flush asks no such rule, it is
         the scatter lane — bit-equal to the dense reference, the kernel
-        never traced, and a plan-cache entry for the bucket unread."""
+        never traced, whatever ``SKYLARK_SERVE_KERNEL`` says."""
         monkeypatch.setattr(pallas_sparse, "available", lambda: True)
 
         def never(*a, **kw):
@@ -386,25 +386,18 @@ class TestSparseFlush:
         lanes = bucketing.nnz_class(A.nnz)
         assert sparse_serve.sparse_kernel(
             padded, s_dim, lanes, jnp.float32, True) == "pallas_rows"
-        prev = tune.set_cache(tune.PlanCache(path=None))
-        try:
-            tune.get_cache().put(
-                tune.serve_workload(
-                    "sparse_sketch_apply", "CWT", "float32", padded,
-                    s_dim, 1, rowwise=True, nnz=lanes),
-                tune.Plan("pallas"), source="measured")
-            with _executor(max_batch=1, linger_us=100) as ex:
-                out = np.asarray(ex.submit_sparse(
-                    T, A, dimension=sk.ROWWISE).result(timeout=120))
-                st = ex.stats()
-                (choice,) = ex._kernel_memo.values()
-        finally:
-            tune.set_cache(prev)
+        monkeypatch.setenv("SKYLARK_SERVE_KERNEL", "pallas")
+        with _executor(max_batch=1, linger_us=100) as ex:
+            out = np.asarray(ex.submit_sparse(
+                T, A, dimension=sk.ROWWISE).result(timeout=120))
+            st = ex.stats()
+            (choice,) = ex._kernel_memo.values()
         assert np.array_equal(
             out, np.asarray(T.apply(A.todense(), sk.ROWWISE)))
-        assert choice == ("xla", None, "default", None)
+        slug = "no-batched-kernel-the-lane-program-serves"
+        assert choice == ("xla", "env", slug)
         assert st["sparse"]["by_backend"] == {"xla": {"kernel_flushes": 1}}
-        assert st["kernel"]["by_reason"] == {}
+        assert st["kernel"]["by_reason"] == {slug: {"declined_flushes": 1}}
 
 
 # ---------------------------------------------------------------------------
