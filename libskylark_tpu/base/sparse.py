@@ -73,8 +73,8 @@ class SparseMatrix:
             raise errors.InvalidParametersError("rowind/values length mismatch")
         # device-resident layouts by value dtype: {"csr": the placed
         # lanes, "coo": the triplets derived from them on first coo(),
-        # ("tiled", layout): the lanes regrouped for the product kernel and
-        # how many of them it walks in groups}
+        # ("tiled", layout): the lanes regrouped for the product kernel, how
+        # many of them it walks in groups and how many segments are covered}
         self._device: dict = {}
         # the canonical scipy CSR this was attached from, when it was one
         self._row_major = None
@@ -229,17 +229,18 @@ class SparseMatrix:
         """The lanes of :meth:`csr_device` regrouped for the sparse × dense
         kernel (``sketch/pallas_spmm.py``, which says what the layout
         means): ``layout`` = (row_block, col_tile, chunk, n_chunks, group,
-        stride) → ``(segment, count, packed, vals)``, the two chunk tables
-        (n_chunks,) int32 — ``count`` a chunk's stored lanes, 0 for an
-        empty one, and · 2¹⁶ its leading slots that lie in the segment's
-        grouped prefix — and the slots (n_chunks, 1, chunk) int32 /
-        values. Regrouped on the host (:func:`_tile_lanes`: one stable sort
-        of the stored lanes by (segment, rank); a device sort of 19.9 M
-        lanes compiles for 40–86 s) and placed on the first call for a
+        stride, cover) → ``(segment, count, packed, vals)``, the two chunk
+        tables (n_chunks,) int32 — ``count`` a chunk's stored lanes, 0 for
+        an empty one, and · 2¹⁶ its leading slots that lie in the segment's
+        grouped prefix — and the slots (n_chunks, 1, chunk) int32 / values.
+        Regrouped on the host (:func:`_tile_lanes`: one stable sort of the
+        stored lanes by (segment, rank); a device sort of 19.9 M lanes
+        compiles for 40–86 s) and placed on the first call for a
         (dtype, layout), under a ``sparse.place`` span that carries the
         bytes placed, the lanes the walk takes ``group`` at a time
-        (``grouped_lanes``) and the seconds it took; kept like the lanes:
-        later calls move nothing."""
+        (``grouped_lanes``), the segments whose last chunk outlasts the
+        copy of B's next tile (``covered_segments``) and the seconds it
+        took; kept like the lanes: later calls move nothing."""
         return self._tiled(layout, dtype)[0]
 
     def grouped_lanes(self, layout: tuple, dtype=None) -> int:
@@ -248,6 +249,12 @@ class SparseMatrix:
         of every chunk's grouped slots. The rest is walked lane by lane."""
         return self._tiled(layout, dtype)[1]
 
+    def covered_segments(self, layout: tuple, dtype=None) -> int:
+        """Of the live segments of :meth:`tiled_device` under ``layout``,
+        those whose last chunk holds at least ``cover`` stored lanes: its
+        walk outlasts the copy of the next segment's tile of B."""
+        return self._tiled(layout, dtype)[2]
+
     def _tiled(self, layout: tuple, dtype) -> tuple:
         eff = self._device_dtype_of(dtype)
         layouts = self._device.setdefault(eff, {})
@@ -255,22 +262,25 @@ class SparseMatrix:
         if key not in layouts:
             from libskylark_tpu.telemetry import trace as _trace
 
-            row_block, col_tile, chunk, n_chunks, group, stride = layout
+            (row_block, col_tile, chunk, n_chunks, group, stride,
+             cover) = layout
             with _trace.span("sparse.place", {"layout": "tiled",
                                               "nnz": self.nnz}) as sp:
                 t0 = time.perf_counter()
-                lanes, grouped = _tile_lanes(
+                lanes, grouped, covered = _tile_lanes(
                     *self.csr_parts(eff), shape=self._shape,
                     row_block=row_block, col_tile=col_tile, chunk=chunk,
-                    n_chunks=n_chunks, group=group, stride=stride)
+                    n_chunks=n_chunks, group=group, stride=stride,
+                    cover=cover)
                 placed = jax.block_until_ready(
                     tuple(_place(a) for a in lanes))
                 if sp is not None:
                     sp.attrs.update(
                         bytes=sum(int(a.nbytes) for a in placed),
                         lane_slots=n_chunks * chunk, grouped_lanes=grouped,
+                        covered_segments=covered,
                         seconds=time.perf_counter() - t0)
-            layouts[key] = placed, grouped
+            layouts[key] = placed, grouped, covered
         return layouts[key]
 
     def coo(self, dtype=None) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -388,11 +398,14 @@ _RANK_CLASSES = 16      # a lane's rank is kept to 15: later ones share a class
 
 def _tile_lanes(data: np.ndarray, indices: np.ndarray, indptr: np.ndarray, *,
                 shape: Tuple[int, int], row_block: int, col_tile: int,
-                chunk: int, n_chunks: int, group: int,
-                stride: int) -> tuple:
+                chunk: int, n_chunks: int, group: int, stride: int,
+                cover: int) -> tuple:
     """Host CSR parts → the (segment, count, packed, vals) of
-    :meth:`SparseMatrix.tiled_device`, numpy arrays, and the stored lanes
-    the walk takes ``group`` at a time.
+    :meth:`SparseMatrix.tiled_device`, numpy arrays, the stored lanes the
+    walk takes ``group`` at a time, and ``covered_segments``: the live
+    segments whose last chunk — a full one, or the only one — holds at
+    least ``cover`` stored lanes (``TilesPlan.cover``: enough for its walk
+    to outlast the copy of the next segment's tile of B).
 
     A lane's *rank* is its place among the lanes of its own row inside its
     segment (CSR order, sorted columns: its index less the index of the
@@ -481,9 +494,11 @@ def _tile_lanes(data: np.ndarray, indices: np.ndarray, indptr: np.ndarray, *,
     vals = np.zeros(n_chunks * chunk, data.dtype)
     packed[slot] = word[order]
     vals[slot] = data[order]
+    # a segment's last chunk is full, or the one chunk that holds it all
+    covered = (stored > 0) & (np.where(chunks > 1, chunk, stored) >= cover)
     return ((segment, count, packed.reshape(n_chunks, 1, chunk),
              vals.reshape(n_chunks, 1, chunk)),
-            int((ahead // group * group).sum()))
+            int((ahead // group * group).sum()), int(covered.sum()))
 
 
 def is_sparse_operand(A) -> bool:
@@ -573,8 +588,9 @@ def product_operands(A: SparseMatrix, k: int, dtype) -> tuple:
     if plan is None:
         attrs.update(lane_slots=nnz_class, segments=1)
         return A.csr_device(dtype), kernel, None, attrs
-    lanes, grouped = A._tiled(plan.layout, dtype)
+    lanes, grouped, covered = A._tiled(plan.layout, dtype)
     attrs.update(lane_slots=plan.n_chunks * plan.chunk, grouped_lanes=grouped,
+                 covered_segments=covered,
                  segments=plan.row_blocks * plan.col_tiles,
                  row_block=plan.row_block, col_tile=plan.col_tile,
                  chunk=plan.chunk)

@@ -228,8 +228,13 @@ SPANS: Dict[str, Tuple[str, str]] = {
     # route) and, on the kernel, row_block, col_tile, chunk and, since PR 58,
     # grouped_lanes (the stored lanes the walk takes a group of rows at a
     # time, loads ahead of stores: ÷ nnz, the share of the walk off the
-    # serial read-modify-write chain); its handover is the engine.execute
-    # inside it
+    # serial read-modify-write chain) and, since PR 60, covered_segments
+    # (the live segments whose last chunk — a full one, or the only one —
+    # holds at least TilesPlan.cover stored lanes, so that its walk outlasts
+    # the copy of the next segment's tile of B: ÷ segments, how often the
+    # chunk's size hides that copy; counted once at placement, an apply
+    # reads the stored integer); its handover is the engine.execute inside
+    # it
     # the dense apply of an operand on more than one device
     # (parallel/shard_apply.py apply_on_mesh, since PR 55) carries path="mesh",
     # route ("program"; on the XLA route of sketch/dense.py that a declined
@@ -277,9 +282,10 @@ SPANS: Dict[str, Tuple[str, str]] = {
     "stream.key": ("streams", "stream_key_ms.apply"),
     # a sparse operand's lanes regrouped on the device for the sparse ×
     # dense kernel (base/sparse.py SparseMatrix.tiled_device), once per
-    # (dtype, layout): attributes layout, nnz, lane_slots, grouped_lanes
-    # (as the dispatch's), bytes (placed) and seconds (the host regrouping
-    # and the upload, waited for) — set-up, never inside a measured apply
+    # (dtype, layout): attributes layout, nnz, lane_slots, grouped_lanes,
+    # covered_segments (as the dispatch's), bytes (placed) and seconds (the
+    # host regrouping and the upload, waited for) — set-up, never inside a
+    # measured apply
     "sparse.place": ("set-up", "operator"),
     # the measured solve (nla/svd.py, engine/compiled.py); under a
     # sketch.apply the same spans are the compiled applies' way to the

@@ -306,10 +306,12 @@ class TestThePlacement:
         assert np.abs(np.asarray(out) - want).max() <= 1e-5 * np.abs(want).max()
 
     @staticmethod
-    def tiles_plan(X, col_tile=32, chunk=16, group=4, k_tiles=1):
-        blocks, tiles = -(-X.shape[0] // 32), -(-X.shape[1] // col_tile)
+    def tiles_plan(X, col_tile=32, chunk=16, group=4, k_tiles=1,
+                   row_block=32):
+        blocks = -(-X.shape[0] // row_block)
+        tiles = -(-X.shape[1] // col_tile)
         return pallas_spmm.TilesPlan(
-            32, col_tile, chunk, k_tiles, blocks, tiles,
+            row_block, col_tile, chunk, k_tiles, blocks, tiles,
             -(-X.nnz // chunk) + blocks * tiles, group)
 
     @staticmethod
@@ -411,6 +413,72 @@ class TestThePlacement:
             interpret=True))
         assert np.array_equal(got, plain_product(X, B))
 
+    @pytest.mark.parametrize("chunk,chunks", [(2048, 3), (4096, 2),
+                                              (8192, 1)])
+    def test_the_result_is_the_same_under_the_chunks(self, fresh, chunk,
+                                                     chunks):
+        """One segment of 5845 lanes under the parent's chunk (2048 slots:
+        the remainder, then two full ones), the shipped one (4096) and
+        the fallback (8192: the whole segment, one chunk): a row's terms
+        are added by rising column wherever a chunk cuts the segment, so
+        each gives the plain loop's bits."""
+        X = operand(rows=256)
+        A = SparseMatrix.from_scipy(X)
+        B = np.random.default_rng(5).standard_normal(
+            (N, 128)).astype(np.float32)
+        plan = self.tiles_plan(X, 1184, chunk, 8, 1, row_block=256)
+        placed = A.tiled_device(plan.layout)
+        stored = np.asarray(placed[1]) & 0xFFFF
+        assert list(stored[stored > 0][1:]) == [chunk] * (chunks - 1)
+        assert stored.sum() == X.nnz and (stored > 0).sum() == chunks
+        got = np.asarray(pallas_spmm.tiles_apply(
+            *placed, jnp.asarray(B), shape=A.shape, plan=plan,
+            interpret=True))
+        assert np.array_equal(got, plain_product(X, B))
+
+    @pytest.mark.parametrize("chunk,covered", [(64, 1), (1024, 1), (4, 0)])
+    def test_covered_segments_counts_long_last_chunks(self, fresh, chunk,
+                                                      covered):
+        """Two live segments, one of 640 lanes and one of 5, under a tile
+        whose copy takes 8 lanes of walk to outlast (``TilesPlan.cover``):
+        the long one counts where its last chunk is a full one of 64 slots
+        or the one chunk that holds it all, the short one never, and under
+        full chunks of 4 slots — shorter than the copy, as the parent's
+        2048 were at the cell — nothing does."""
+        X = sp.lil_matrix((32, 64), dtype=np.float32)
+        X[:, :20] = 1.0
+        X[:5, 40] = 2.0
+        X = X.tocsr()
+        plan = self.tiles_plan(X, 32, chunk, 4, 1)
+        assert plan.cover == 8 and (plan.row_blocks, plan.col_tiles) == (1, 2)
+        A = SparseMatrix.from_scipy(X)
+        assert A.covered_segments(plan.layout) == covered
+        assert plan.layout[-1] == plan.cover
+        count = np.asarray(A.tiled_device(plan.layout)[1]) & 0xFFFF
+        assert count.sum() == X.nnz == 645
+
+    @pytest.mark.parametrize("n,k,lanes,chunk", [
+        (47236, 128, 19922944, 4096), (47236, 1024, 19922944, 4096),
+        (47236, 2048, 19922944, 4096), (47236, 1024, 1 << 27, 8192),
+        (4096, 1024, 19922944, 4096)])
+    def test_a_full_chunk_outlasts_the_tiles_copy(self, n, k, lanes, chunk):
+        """The rule behind ``_CHUNKS``: whatever the width, a full chunk
+        holds the lanes whose walk outlasts the copy of a tile of B
+        (``cover``: 2048 bytes of the tile a lane; the largest tile a plan
+        makes is 8 MiB, 4096 lanes: n = 4096 here) — also where the chunk
+        tables of the first entry pass SMEM and the next one serves."""
+        plan, why = pallas_spmm.tiles_plan((262144, n), k, lanes,
+                                           jnp.float32)
+        assert plan is not None, why
+        assert plan.chunk == chunk >= plan.cover
+        assert plan.chunk % pallas_spmm._SPAN == 0 and plan.chunk < 1 << 16
+        assert plan.n_chunks == (-(-lanes // chunk)
+                                 + plan.row_blocks * plan.col_tiles)
+        assert plan.n_chunks <= pallas_spmm._MAX_CHUNKS
+        tile_bytes = plan.col_tile * k * 4
+        assert tile_bytes <= 8 << 20
+        assert plan.cover == -(-tile_bytes // 2048)     # 819 B/ns · 2.5 ns
+
     @pytest.mark.parametrize("n,tile,tiles", [
         (47236, 1976, 24), (2048, 2048, 1), (2049, 1032, 2), (1181, 1184, 1)])
     def test_column_tiles_are_of_one_width(self, n, tile, tiles):
@@ -439,6 +507,7 @@ class TestThePlacement:
             assert span.attrs["bytes"] > 0 and span.attrs["seconds"] > 0
             assert span.attrs["lane_slots"] >= X.nnz
             assert 0 <= span.attrs["grouped_lanes"] <= X.nnz
+            assert 0 <= span.attrs["covered_segments"] <= 3 * 37
 
     @pytest.mark.parametrize("family,kwargs", FAMILIES)
     def test_a_warm_apply_moves_nothing_to_the_device(self, fresh, route,
@@ -457,7 +526,7 @@ class TestThePlacement:
     def test_plans_the_kernel_declines(self):
         shape = (262144, 47236)
         assert pallas_spmm.tiles_plan(shape, 1024, 19922944, jnp.float32)[0] \
-            == pallas_spmm.TilesPlan(2048, 1976, 2048, 8, 128, 24, 12800, 8)
+            == pallas_spmm.TilesPlan(2048, 1976, 4096, 8, 128, 24, 7936, 8)
         for k, dtype, lanes, why in [
                 (1000, jnp.float32, 1 << 20, "multiple of 128"),
                 (4096, jnp.float32, 1 << 20, "multiple of 128"),
@@ -500,9 +569,15 @@ class TestSpansAndCounters:
                                           jnp.float32)[0]
             assert attrs["grouped_lanes"] == A.grouped_lanes(plan.layout)
             assert 0 < attrs["grouped_lanes"] <= X.nnz
+            # of the 3 × 37 segments those whose last chunk holds cover = 8
+            # lanes (81 here; two segments take a second chunk of 64 slots)
+            assert attrs["covered_segments"] \
+                == A.covered_segments(plan.layout)
+            assert 0 < attrs["covered_segments"] < attrs["segments"]
         else:
             assert attrs["kernel"] == f"xla: backend {jax.default_backend()}"
             assert attrs["segments"] == 1 and "grouped_lanes" not in attrs
+            assert "covered_segments" not in attrs
         assert len([s for s in spans if s.name == HANDOVER[0]]) == 2
         assert not [s for s in spans if s.name == "sparse.place"]
         periods = trace.apply_periods("sketch.apply")

@@ -981,7 +981,7 @@ def test_cell_shape_sparse_dense_program(one_chip, monkeypatch):
     kernel, plan = sparse_serve.product_kernel(
         (SPARSE_ROWS, SPARSE_N), SPARSE_S, SPARSE_LANES, jnp.float32)
     assert kernel == "pallas_tiles"
-    assert plan == pallas_spmm.TilesPlan(2048, 1976, 2048, 8, 128, 24, 12800,
+    assert plan == pallas_spmm.TilesPlan(2048, 1976, 4096, 8, 128, 24, 7936,
                                          8)
     assert pallas_spmm.vmem_bytes(plan) == 2 * (2048 + 1976) * 4096
     arg = _sparse_arg(one_chip)
@@ -1003,18 +1003,25 @@ def test_cell_shape_sparse_dense_program(one_chip, monkeypatch):
     assert memory.temp_size_in_bytes < 3 << 30, memory
 
 
-@pytest.mark.parametrize("k", [128, 2048])
-def test_sparse_dense_walk_at_the_plans_other_widths(one_chip, k):
+@pytest.mark.parametrize("k,lanes,chunk", [
+    (128, SPARSE_LANES, 4096), (2048, SPARSE_LANES, 4096),
+    (1024, 1 << 27, 8192)])
+def test_sparse_dense_walk_at_the_plans_other_widths(one_chip, k, lanes,
+                                                     chunk):
     """The walk's grouped span — 128 slots unrolled, eight rows loaded
-    ahead of their stores — at the narrowest and the widest k the plan
-    takes: a row of the blocks is an eighth of a vector register at
-    k = 128 and two registers (1024-row blocks) at k = 2048."""
+    ahead of their stores — under the shipped 4096-slot chunk at the
+    narrowest and the widest k the plan takes: a row of the blocks is an
+    eighth of a vector register at k = 128 and two registers (1024-row
+    blocks) at k = 2048; and under the 8192-slot chunk that serves an
+    operand whose chunk tables would pass SMEM (two slot blocks, two
+    buffers each, 128 KiB of SMEM)."""
     from libskylark_tpu.sketch import pallas_spmm
 
-    plan, why = pallas_spmm.tiles_plan((SPARSE_ROWS, SPARSE_N), k,
-                                       SPARSE_LANES, jnp.float32)
+    plan, why = pallas_spmm.tiles_plan((SPARSE_ROWS, SPARSE_N), k, lanes,
+                                       jnp.float32)
     assert plan is not None, why
-    assert plan.row_block == (2048 if k == 128 else 1024)
+    assert plan.chunk == chunk
+    assert plan.row_block == (1024 if k == 2048 else 2048)
     arg = _sparse_arg(one_chip)
     slots = (plan.n_chunks, 1, plan.chunk)
     compiled = jax.jit(functools.partial(
