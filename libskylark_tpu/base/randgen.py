@@ -400,3 +400,36 @@ def dense_panel(
     ]
     panel = blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=1)
     return panel[:, col_start - b0 * block_cols : col_stop - b0 * block_cols]
+
+
+def dense_block_rows(
+    key: jax.Array,
+    dist: Distribution,
+    rows: int,
+    block_id,
+    block_cols: int,
+    dtype=jnp.float32,
+    lanes: int | None = None,
+) -> jax.Array:
+    """:func:`dense_block` transposed, (block_cols × rows): row j is column j
+    of the block, the same words under the same counters (c[j, r] = r·half +
+    j), generated in this layout — the cipher and ``from_bits`` are
+    elementwise, so nothing is transposed. For an operator whose columns
+    are wanted as rows (the right factor of a transposed sparse product).
+    ``lanes`` (a divisor of ``rows``) gives each row as (rows / lanes,
+    lanes): with 128 a row of 1024 entries is one vector register, the view
+    the sparse × dense kernel reads, generated with no relayout."""
+    if dist.draws != 1 or block_cols % 2:
+        raise ValueError(
+            f"dense_block_rows needs a one-draw distribution and an even "
+            f"block width, got {dist.name} × {block_cols}")
+    kd = jr.key_data(chunk_key(key, block_id))
+    half = block_cols // 2
+    r = jnp.arange(rows, dtype=jnp.uint32)
+    if lanes is not None:
+        r = r.reshape(rows // lanes, lanes)
+    j = jnp.arange(half, dtype=jnp.uint32).reshape((half,) + (1,) * r.ndim)
+    c = r[None] * jnp.uint32(half) + j
+    b0, b1 = tf.threefry2x32(kd[0], kd[1], c, c + jnp.uint32(rows * half))
+    block = jnp.concatenate([dist.from_bits(b0), dist.from_bits(b1)], axis=0)
+    return block.astype(dtype)

@@ -11,8 +11,11 @@ the nnz extent zero-padded to its ``engine.bucket.lane_class``) — what the
 compiled sparse hash sketch and :func:`spmm` walk —, as those lanes
 regrouped by (row block, column tile) for the sparse × dense kernel
 (:meth:`SparseMatrix.tiled_device`, under a ``sparse.place`` span), or as
-the row-major COO triplets (:meth:`SparseMatrix.coo`) that :func:`spmm_t`
-contracts over, the row ids expanded on the device.
+the row-major COO triplets (:meth:`SparseMatrix.coo`), the row ids expanded
+on the device. The transposed product has a placement of its own, made at
+the first ``Aᵀ·B`` and never at an ``A·B``: A's column-major lanes
+(:meth:`SparseMatrix.csc_device`) or those regrouped by (block of A's
+columns, tile of A's rows) (``tiled_device(..., side="transposed")``).
 
 The products (ref: base/Gemm.hpp:335-519). :func:`spmm` (A·B) is one
 compiled program (``sparse.spmm``) whose workspace does not grow with
@@ -24,11 +27,12 @@ multiply and one scatter-add into the rows the span touches
 (``_SPAN_LANES`` × k values at a time; before PR 57 the whole nnz × k
 gather was one array, 79 GB at 19.4 M nonzeros × 1024).
 ``DenseTransform.apply`` on a sparse operand, rowwise, is the same program
-body behind an operator generated in the program. :func:`spmm_t` (Aᵀ·B)
-keeps ``segment_sum(v[:, None] * B[r], c)`` over all nonzeros at once: its
-nnz × k temporary stands, and an operand of the size :func:`spmm` now
-serves does not fit it. All nnz-shaped arrays have static shapes, so the
-products are jittable.
+body behind an operator generated in the program. :func:`spmm_t` (Aᵀ·B) is
+the same body over the lanes of Aᵀ (``sparse.spmm_t``; before PR 61 a
+``segment_sum(v[:, None] * B[r], c)`` over all nonzeros at once, which no
+operand of the size :func:`spmm` serves fitted), and the columnwise dense
+sketch of a sparse operand runs it behind its generated operator. All
+nnz-shaped arrays have static shapes, so the products are jittable.
 """
 
 from __future__ import annotations
@@ -72,9 +76,10 @@ class SparseMatrix:
         if len(self._rowind) != len(self._values):
             raise errors.InvalidParametersError("rowind/values length mismatch")
         # device-resident layouts by value dtype: {"csr": the placed
-        # lanes, "coo": the triplets derived from them on first coo(),
-        # ("tiled", layout): the lanes regrouped for the product kernel, how
-        # many of them it walks in groups and how many segments are covered}
+        # lanes, "csc": the column-major ones, "coo": the triplets derived
+        # from the row-major lanes on first coo(), ("tiled", side, layout):
+        # the lanes regrouped for the product kernel and the counts of how
+        # the walk takes them}
         self._device: dict = {}
         # the canonical scipy CSR this was attached from, when it was one
         self._row_major = None
@@ -199,10 +204,11 @@ class SparseMatrix:
         return jax.dtypes.canonicalize_dtype(
             np.dtype(dtype) if dtype is not None else self.device_dtype)
 
-    def _place_lanes(self, eff, extent: int):
-        """:meth:`csr_parts` on the device, the nnz lanes zero-padded to
-        ``extent``."""
-        data, indices, indptr = self.csr_parts(eff)
+    def _place_lanes(self, eff, extent: int, transposed: bool = False):
+        """:meth:`csr_parts` (:meth:`csc_parts`: Aᵀ's) on the device, the
+        nnz lanes zero-padded to ``extent``."""
+        data, indices, indptr = (self.csc_parts(eff) if transposed
+                                 else self.csr_parts(eff))
         pad = extent - self.nnz
         return (_place(np.pad(data, (0, pad))),
                 _place(np.pad(indices, (0, pad))), _place(indptr))
@@ -225,7 +231,8 @@ class SparseMatrix:
             layouts["csr"] = self._place_lanes(eff, lane_class(self.nnz))
         return layouts["csr"]
 
-    def tiled_device(self, layout: tuple, dtype=None) -> Tuple[jax.Array, ...]:
+    def tiled_device(self, layout: tuple, dtype=None,
+                     side: str = "rows") -> Tuple[jax.Array, ...]:
         """The lanes of :meth:`csr_device` regrouped for the sparse × dense
         kernel (``sketch/pallas_spmm.py``, which says what the layout
         means): ``layout`` = (row_block, col_tile, chunk, n_chunks, group,
@@ -236,52 +243,84 @@ class SparseMatrix:
         Regrouped on the host (:func:`_tile_lanes`: one stable sort of the
         stored lanes by (segment, rank); a device sort of 19.9 M lanes
         compiles for 40–86 s) and placed on the first call for a
-        (dtype, layout), under a ``sparse.place`` span that carries the
-        bytes placed, the lanes the walk takes ``group`` at a time
-        (``grouped_lanes``), the segments whose last chunk outlasts the
-        copy of B's next tile (``covered_segments``) and the seconds it
-        took; kept like the lanes: later calls move nothing."""
-        return self._tiled(layout, dtype)[0]
+        (dtype, side, layout), under a ``sparse.place`` span that carries
+        the side, the bytes placed, the lanes the walk takes ``group`` at a
+        time (``grouped_lanes``), the segments whose last chunk outlasts
+        the copy of B's next tile (``covered_segments``) and the seconds it
+        took; kept like the lanes: later calls move nothing.
 
-    def grouped_lanes(self, layout: tuple, dtype=None) -> int:
+        ``side="transposed"`` is the second placement, for ``Aᵀ·B``: the
+        lanes of Aᵀ — this matrix's own CSC buffers — laid by
+        :func:`_tile_runs` (result rows are A's columns, ``layout`` a
+        ``runs`` plan's), made at the first transposed product and never at
+        a rowwise one; its span also counts ``run_lanes`` and
+        ``run_slots``."""
+        return self._tiled(layout, dtype, side)[0]
+
+    def grouped_lanes(self, layout: tuple, dtype=None,
+                      side: str = "rows") -> int:
         """Of the stored lanes of :meth:`tiled_device` under ``layout``,
         those the kernel walks ``group`` rows at a time: the whole groups
-        of every chunk's grouped slots. The rest is walked lane by lane."""
-        return self._tiled(layout, dtype)[1]
+        of every chunk's grouped slots. The rest is walked lane by lane
+        (as runs of one row on the transposed side)."""
+        return self._tiled(layout, dtype, side)[1]["grouped_lanes"]
 
-    def covered_segments(self, layout: tuple, dtype=None) -> int:
+    def covered_segments(self, layout: tuple, dtype=None,
+                         side: str = "rows") -> int:
         """Of the live segments of :meth:`tiled_device` under ``layout``,
         those whose last chunk holds at least ``cover`` stored lanes: its
         walk outlasts the copy of the next segment's tile of B."""
-        return self._tiled(layout, dtype)[2]
+        return self._tiled(layout, dtype, side)[1]["covered_segments"]
 
-    def _tiled(self, layout: tuple, dtype) -> tuple:
+    def _tiled(self, layout: tuple, dtype, side: str = "rows") -> tuple:
+        """``(placed arrays, counts)`` of one (dtype, side, layout)."""
         eff = self._device_dtype_of(dtype)
         layouts = self._device.setdefault(eff, {})
-        key = ("tiled",) + tuple(layout)
+        key = ("tiled", side) + tuple(layout)
         if key not in layouts:
             from libskylark_tpu.telemetry import trace as _trace
 
             (row_block, col_tile, chunk, n_chunks, group, stride,
              cover) = layout
-            with _trace.span("sparse.place", {"layout": "tiled",
-                                              "nnz": self.nnz}) as sp:
+            transposed = side == "transposed"
+            # set-up work, once a layout: timed whoever listens (the
+            # benchmark's setup_place_s reads these spans), like
+            # telemetry/setup.py's records
+            with _trace.span("sparse.place", {"layout": "tiled", "side": side,
+                                              "nnz": self.nnz},
+                             force=True) as sp:
                 t0 = time.perf_counter()
-                lanes, grouped, covered = _tile_lanes(
-                    *self.csr_parts(eff), shape=self._shape,
+                lanes, counts = (_tile_runs if transposed else _tile_lanes)(
+                    *(self.csc_parts(eff) if transposed
+                      else self.csr_parts(eff)),
+                    shape=self._shape[::-1] if transposed else self._shape,
                     row_block=row_block, col_tile=col_tile, chunk=chunk,
                     n_chunks=n_chunks, group=group, stride=stride,
                     cover=cover)
                 placed = jax.block_until_ready(
                     tuple(_place(a) for a in lanes))
-                if sp is not None:
-                    sp.attrs.update(
-                        bytes=sum(int(a.nbytes) for a in placed),
-                        lane_slots=n_chunks * chunk, grouped_lanes=grouped,
-                        covered_segments=covered,
-                        seconds=time.perf_counter() - t0)
-            layouts[key] = placed, grouped, covered
+                sp.attrs.update(
+                    bytes=sum(int(a.nbytes) for a in placed),
+                    lane_slots=n_chunks * chunk, **counts,
+                    seconds=time.perf_counter() - t0)
+            layouts[key] = placed, counts
         return layouts[key]
+
+    def csc_device(self, dtype=None) -> Tuple[jax.Array, jax.Array,
+                                              jax.Array]:
+        """:meth:`csr_device` of Aᵀ: this matrix's column-major lanes
+        ``(data, row indices, column pointers)`` on the device, the nnz
+        extent zero-padded to its lane class — what the transposed
+        product's span loop walks where the kernel does not serve. Placed
+        on the first call for a value dtype and kept."""
+        eff = self._device_dtype_of(dtype)
+        layouts = self._device.setdefault(eff, {})
+        if "csc" not in layouts:
+            from libskylark_tpu.engine.bucket import lane_class
+
+            layouts["csc"] = self._place_lanes(eff, lane_class(self.nnz),
+                                               transposed=True)
+        return layouts["csc"]
 
     def coo(self, dtype=None) -> Tuple[jax.Array, jax.Array, jax.Array]:
         """Device COO triplets (rows, cols, vals) in row-major order;
@@ -324,6 +363,22 @@ class SparseMatrix:
             A = self.to_scipy().tocsr()
             A.sum_duplicates()
             A.sort_indices()
+        return (np.asarray(A.data, dtype=eff),
+                np.asarray(A.indices, dtype=np.int32),
+                np.asarray(A.indptr, dtype=np.int32))
+
+    def csc_parts(self, dtype=None) -> Tuple[np.ndarray, np.ndarray,
+                                             np.ndarray]:
+        """:meth:`csr_parts` of Aᵀ: ``(data, row indices, column
+        pointers)`` column-major, sorted row indices, duplicates summed —
+        this matrix's own buffers where they are canonical (those of a
+        scipy CSC, or converted from a canonical CSR, are)."""
+        eff = np.dtype(dtype) if dtype is not None else np.dtype(
+            jax.dtypes.canonicalize_dtype(self.device_dtype))
+        A = self.to_scipy()
+        if not A.has_canonical_format:
+            A = A.copy()
+            A.sum_duplicates()
         return (np.asarray(A.data, dtype=eff),
                 np.asarray(A.indices, dtype=np.int32),
                 np.asarray(A.indptr, dtype=np.int32))
@@ -401,9 +456,10 @@ def _tile_lanes(data: np.ndarray, indices: np.ndarray, indptr: np.ndarray, *,
                 chunk: int, n_chunks: int, group: int, stride: int,
                 cover: int) -> tuple:
     """Host CSR parts → the (segment, count, packed, vals) of
-    :meth:`SparseMatrix.tiled_device`, numpy arrays, the stored lanes the
-    walk takes ``group`` at a time, and ``covered_segments``: the live
-    segments whose last chunk — a full one, or the only one — holds at
+    :meth:`SparseMatrix.tiled_device`, numpy arrays, and the counts
+    ``grouped_lanes``, the stored lanes the walk takes ``group`` at a time,
+    and ``covered_segments``: the live segments whose last chunk — a full
+    one, or the only one — holds at
     least ``cover`` stored lanes (``TilesPlan.cover``: enough for its walk
     to outlast the copy of the next segment's tile of B).
 
@@ -437,14 +493,7 @@ def _tile_lanes(data: np.ndarray, indices: np.ndarray, indptr: np.ndarray, *,
     ranks = _RANK_CLASSES
     key_t = np.uint16 if n_seg * ranks <= 1 << 16 else np.int32
     lengths = np.diff(indptr)
-    tile = (indices.view(np.uint32) // np.uint32(col_tile)).astype(key_t)
-    # a run starts where the tile changes or a row does
-    starts = np.empty(nnz, bool)
-    starts[:1] = True
-    np.not_equal(tile[1:], tile[:-1], out=starts[1:])
-    starts[indptr[:-1][lengths > 0]] = True
-    at = np.arange(nnz, dtype=np.int32)
-    rank = at - np.maximum.accumulate(np.where(starts, at, 0))
+    tile, _, at, rank = _lane_ranks(indices, indptr, col_tile, key_t)
     row = np.arange(rows, dtype=np.int32)
     key = np.repeat((row // row_block * (col_tiles * ranks)).astype(key_t),
                     lengths)
@@ -457,6 +506,183 @@ def _tile_lanes(data: np.ndarray, indices: np.ndarray, indptr: np.ndarray, *,
     stored = sizes.sum(axis=1)
     wide = np.minimum.accumulate(sizes[:, :-1] >= group, axis=1)
     grouped = (sizes[:, :-1] * wide).sum(axis=1)
+    segment, count, holds, ahead, _, _, covered = _deal_chunks(
+        stored, grouped, chunk=chunk, n_chunks=n_chunks, col_tiles=col_tiles,
+        cover=cover)
+    live = holds.shape[0]
+    # sorted lanes lie segment after segment and, dealt in order, chunk
+    # after chunk: a lane's slot is its chunk's first slot plus its place
+    first_lane = np.cumsum(holds) - holds
+    slot = at + np.repeat(
+        (np.arange(live) * chunk - first_lane).astype(np.int32), holds)
+    word = _packed_words(indices, lengths, tile, row_block, col_tile, stride)
+    packed = np.zeros(n_chunks * chunk, np.int32)
+    vals = np.zeros(n_chunks * chunk, data.dtype)
+    packed[slot] = word[order]
+    vals[slot] = data[order]
+    return ((segment, count, packed.reshape(n_chunks, 1, chunk),
+             vals.reshape(n_chunks, 1, chunk)),
+            {"grouped_lanes": int((ahead // group * group).sum()),
+             "covered_segments": covered})
+
+
+def _lane_ranks(indices: np.ndarray, indptr: np.ndarray, col_tile: int,
+                key_t) -> tuple:
+    """``(tile, starts, at, rank)`` of row-major lanes: a lane's column
+    tile, whether it starts a (row, tile) run — where the tile changes or a
+    row does —, its index and its place in its run."""
+    nnz = int(indices.shape[0])
+    lengths = np.diff(indptr)
+    tile = (indices.view(np.uint32) // np.uint32(col_tile)).astype(key_t)
+    starts = np.empty(nnz, bool)
+    starts[:1] = True
+    np.not_equal(tile[1:], tile[:-1], out=starts[1:])
+    starts[indptr[:-1][lengths > 0]] = True
+    at = np.arange(nnz, dtype=np.int32)
+    rank = at - np.maximum.accumulate(np.where(starts, at, 0))
+    return tile, starts, at, rank
+
+
+def _packed_words(indices: np.ndarray, lengths: np.ndarray, tile: np.ndarray,
+                  row_block: int, col_tile: int, stride: int) -> np.ndarray:
+    """A lane's packed word: row · stride in the block · 2¹⁶ + column ·
+    stride in the tile."""
+    row = np.arange(lengths.shape[0], dtype=np.int32)
+    word = np.repeat((row % row_block * stride) << 16, lengths)
+    word += indices * stride if stride != 1 else indices
+    word -= tile.astype(np.int32) * (col_tile * stride)
+    return word
+
+
+def _tile_runs(data: np.ndarray, indices: np.ndarray, indptr: np.ndarray, *,
+               shape: Tuple[int, int], row_block: int, col_tile: int,
+               chunk: int, n_chunks: int, group: int, stride: int,
+               cover: int) -> tuple:
+    """:func:`_tile_lanes` for a product whose result rows are long and
+    skewed — the transposed side, the lanes those of Aᵀ (A's CSC), a result
+    row a *feature*: a frequent one holds most of a tile's columns, most
+    rows hold a lane or none. The same tables and slots, another order
+    inside a segment (``TilesPlan.runs``):
+
+    * a row with at least ``pallas_spmm._RUN_ROW`` (≤ ``_RANK_CLASSES``)
+      lanes in the segment is kept whole, row-major, as a *run*; the
+      others' lanes lie by (rank, row) as :func:`_tile_lanes` lays them,
+      and the *grouped region* is the grouped prefix of those cut to whole
+      groups of ``group``;
+    * every other lane (the whole rows, the classes past the prefix, the
+      prefix's last few) joins the *run region* behind it, by (row,
+      column), each row's lanes padded to a multiple of ``group`` slots
+      with zero-valued copies of its last lane — so that ``group`` slots
+      address ONE row, and the kernel sums them in registers and loads and
+      stores the row once. A padding slot multiplies a row of B that its
+      own row multiplies anyway.
+
+    The padding is under ``group`` slots a run: (``group`` − 1) /
+    ``_RUN_ROW`` of the lanes of the whole rows, and at most ``_RUN_ROW`` ·
+    (``group`` − 1) other run lanes a segment — what ``tiles_plan``'s chunk
+    bound counts. ``count`` holds a chunk's slots in use (padding included)
+    and · 2¹⁶ those of the grouped region. Two radix sorts (16-bit keys up
+    to 4096 segments).
+    Returns the arrays and the counts ``grouped_lanes``, ``run_lanes``
+    (stored lanes in runs), ``run_slots`` (with their padding) and
+    ``covered_segments``."""
+    rows, n = int(shape[0]), int(shape[1])
+    col_tiles = -(-n // col_tile)
+    n_seg = -(-rows // row_block) * col_tiles
+    nnz = int(indices.shape[0])
+    if chunk >= 1 << 16 or chunk % group:
+        raise errors.InvalidParametersError(
+            f"a chunk of {chunk} slots does not pack into a count of runs "
+            f"of {group}")
+    # the plan's own number: its chunk bound counts the padding by it
+    from libskylark_tpu.sketch.pallas_spmm import _RUN_ROW
+
+    ranks = _RANK_CLASSES
+    key_t = np.uint16 if n_seg * ranks <= 1 << 16 else np.int32
+    lengths = np.diff(indptr)
+    tile, starts, at, rank = _lane_ranks(indices, indptr, col_tile, key_t)
+    begins = np.flatnonzero(starts)
+    held = np.diff(begins, append=nnz)          # lanes of a (row, tile) run
+    # a whole row goes to the last class
+    rank[np.repeat(held >= _RUN_ROW, held)] = ranks - 1
+    row = np.arange(rows, dtype=np.int32)
+    seg = np.repeat((row // row_block * col_tiles).astype(key_t), lengths)
+    seg += tile
+    key = seg * key_t(ranks) + rank.astype(key_t)
+    order = np.argsort(key, kind="stable")      # (segment, class, row) order
+    sizes = np.bincount(key, minlength=n_seg * ranks).reshape(n_seg, ranks)
+    wide = np.minimum.accumulate(sizes[:, :-1] >= group, axis=1)
+    grouped = (sizes[:, :-1] * wide).sum(axis=1) // group * group
+    lanes_of = sizes.sum(axis=1)
+    place = at - np.repeat(np.cumsum(lanes_of) - lanes_of,
+                           lanes_of).astype(np.int32)
+    ahead = place < np.repeat(grouped, lanes_of)    # sorted lanes, grouped
+    g_lane, g_place = order[ahead], place[ahead]
+    # the run region: what is left, in (segment, row, column) order — the
+    # lanes are row-major already, so one stable sort by segment
+    left = np.ones(nnz, bool)
+    left[g_lane] = False
+    r_lane = np.flatnonzero(left).astype(np.int32)
+    r_seg = seg[r_lane]
+    by_seg = np.argsort(r_seg, kind="stable")
+    r_lane, r_seg = r_lane[by_seg], r_seg[by_seg].astype(np.int32)
+    r_row = np.repeat(row, lengths)[r_lane]
+    new = np.ones(r_lane.shape[0], bool)
+    np.logical_or(r_row[1:] != r_row[:-1], r_seg[1:] != r_seg[:-1],
+                  out=new[1:])
+    r_begin = np.flatnonzero(new)
+    r_len = np.diff(r_begin, append=r_lane.shape[0])
+    r_slots = -(-r_len // group) * group
+    run_seg = r_seg[r_begin]
+    seg_slots = np.bincount(run_seg, weights=r_slots,
+                            minlength=n_seg).astype(np.int64)
+    stored = grouped + seg_slots
+    segment, count, _, _, first_chunk, rest, covered = _deal_chunks(
+        stored, grouped, chunk=chunk, n_chunks=n_chunks, col_tiles=col_tiles,
+        cover=cover)
+    # a run's first place in its segment: behind the grouped region and the
+    # runs before it
+    run_place = (np.cumsum(r_slots) - r_slots
+                 - (np.cumsum(seg_slots) - seg_slots)[run_seg]
+                 + grouped[run_seg])
+    r_place = (np.repeat(run_place - r_begin, r_len)
+               + np.arange(r_lane.shape[0]))
+    pads = r_slots - r_len
+    p_place = (np.repeat(run_place + r_len - (np.cumsum(pads) - pads), pads)
+               + np.arange(int(pads.sum())))
+
+    def slot(of, at_place):
+        """A segment's places dealt into its chunks: the first holds
+        ``rest`` of them, the others are full."""
+        return (first_chunk[of] * chunk + at_place
+                + (at_place >= rest[of]) * (chunk - rest[of]))
+
+    word = _packed_words(indices, lengths, tile, row_block, col_tile, stride)
+    packed = np.zeros(n_chunks * chunk, np.int32)
+    vals = np.zeros(n_chunks * chunk, data.dtype)
+    g_slot = slot(np.repeat(np.arange(n_seg), grouped), g_place)
+    packed[g_slot], vals[g_slot] = word[g_lane], data[g_lane]
+    r_slot = slot(r_seg, r_place)
+    packed[r_slot], vals[r_slot] = word[r_lane], data[r_lane]
+    # a padding slot repeats its run's last lane, value 0.0
+    packed[slot(np.repeat(run_seg, pads), p_place)] = np.repeat(
+        word[r_lane[r_begin + r_len - 1]], pads)
+    return ((segment, count, packed.reshape(n_chunks, 1, chunk),
+             vals.reshape(n_chunks, 1, chunk)),
+            {"grouped_lanes": int(grouped.sum()),
+             "run_lanes": int(r_lane.shape[0]),
+             "run_slots": int(r_slots.sum()), "covered_segments": covered})
+
+
+def _deal_chunks(stored: np.ndarray, grouped: np.ndarray, *, chunk: int,
+                 n_chunks: int, col_tiles: int, cover: int) -> tuple:
+    """Each segment's ``stored`` slots dealt into ⌈stored ÷ chunk⌉ chunks,
+    a row block's first segment into at least one: ``(segment, count, holds,
+    ahead, first_chunk, rest, covered)`` — the two chunk tables
+    (``n_chunks`` entries), a live chunk's slots held and how many of them
+    lie in its segment's first ``grouped`` slots, a segment's first chunk
+    and the slots that one holds, and the live segments whose last chunk
+    holds at least ``cover`` slots."""
     chunks = -(-stored // chunk)
     chunks[::col_tiles] = np.maximum(chunks[::col_tiles], 1)   # a row block
     chunk_end = np.cumsum(chunks)                               # owns a chunk
@@ -464,7 +690,7 @@ def _tile_lanes(data: np.ndarray, indices: np.ndarray, indptr: np.ndarray, *,
     if live > n_chunks:
         raise errors.InvalidParametersError(
             f"tiled layout needs {live} chunks, the plan holds {n_chunks}")
-    of = np.repeat(np.arange(n_seg, dtype=np.int32), chunks)
+    of = np.repeat(np.arange(stored.shape[0], dtype=np.int32), chunks)
     # a segment's remainder goes into its FIRST chunk and the others are
     # full: the walk of a segment's last chunk is what hides the copy of the
     # next segment's tile of B (and, at a row block's end, the write of the
@@ -472,33 +698,19 @@ def _tile_lanes(data: np.ndarray, indices: np.ndarray, indptr: np.ndarray, *,
     # chunk exposes nothing, its successor reuses both blocks (PERF.md
     # PR 57: a nearly empty last chunk cost what a full one did, and a
     # block whose last segment was short read 2.4 ms longer)
-    nth = np.arange(live) - (chunk_end - chunks)[of]        # chunk of its segment
-    rest = (stored - (np.maximum(chunks, 1) - 1) * chunk)[of]
-    holds = np.where(nth == 0, rest, chunk)
-    before = np.where(nth == 0, 0, rest + (nth - 1) * chunk)
+    first_chunk = chunk_end - chunks
+    nth = np.arange(live) - first_chunk[of]                 # chunk of its segment
+    rest = stored - (np.maximum(chunks, 1) - 1) * chunk
+    holds = np.where(nth == 0, rest[of], chunk)
+    before = np.where(nth == 0, 0, rest[of] + (nth - 1) * chunk)
     ahead = np.clip(grouped[of] - before, 0, holds)
     segment = np.full(n_chunks, of[-1], np.int32)
     segment[:live] = of
     count = np.zeros(n_chunks, np.int32)
     count[:live] = holds | ahead << 16
-    # sorted lanes lie segment after segment and, dealt in order, chunk
-    # after chunk: a lane's slot is its chunk's first slot plus its place
-    first_lane = np.cumsum(holds) - holds
-    slot = at + np.repeat(
-        (np.arange(live) * chunk - first_lane).astype(np.int32), holds)
-    # row · stride in the block · 2¹⁶ + column · stride in the tile
-    word = np.repeat((row % row_block * stride) << 16, lengths)
-    word += indices * stride if stride != 1 else indices
-    word -= tile.astype(np.int32) * (col_tile * stride)
-    packed = np.zeros(n_chunks * chunk, np.int32)
-    vals = np.zeros(n_chunks * chunk, data.dtype)
-    packed[slot] = word[order]
-    vals[slot] = data[order]
     # a segment's last chunk is full, or the one chunk that holds it all
     covered = (stored > 0) & (np.where(chunks > 1, chunk, stored) >= cover)
-    return ((segment, count, packed.reshape(n_chunks, 1, chunk),
-             vals.reshape(n_chunks, 1, chunk)),
-            int((ahead // group * group).sum()), int(covered.sum()))
+    return segment, count, holds, ahead, first_chunk, rest, int(covered.sum())
 
 
 def is_sparse_operand(A) -> bool:
@@ -549,48 +761,54 @@ def spans_product(data, indices, indptr, B, *, n_rows: int) -> jnp.ndarray:
     return jax.lax.fori_loop(0, (lanes + pad) // span, add_span, out)
 
 
-def _product_kernel(op: str):
+def _product(op: str, *operands, **statics) -> jax.Array:
+    """``spmm`` (``op`` ``"spmm"``, the program ``sparse.spmm``) or
+    ``spmm_t`` (``"spmm_t"``, ``sparse.spmm_t``): one body,
+    ``sparse_serve.product_lanes``, over the lanes of the side it serves
+    (A's, or Aᵀ's), as one compiled program. Under a caller's trace (a
+    Krylov loop's body: B a tracer) the body is traced into the caller's
+    program instead, the placed lanes its constants."""
+    from libskylark_tpu.sketch.sparse_serve import product_lanes
+
+    if isinstance(operands[-1], jax.core.Tracer):
+        return product_lanes(*operands, **statics)
     cf = _COMPILED_PRODUCTS.get(op)
     if cf is None:
         from libskylark_tpu.engine.compiled import compiled as _compiled
 
-        if op == "spmm":
-            from libskylark_tpu.sketch.sparse_serve import product_lanes
-
-            cf = _compiled(product_lanes, name="sparse.spmm",
-                           static_argnames=("kernel", "shape", "plan"))
-        else:
-            def kern(r, c, v, B, *, segments: int):
-                return jax.ops.segment_sum(v[:, None] * B[r], c,
-                                           num_segments=segments)
-
-            cf = _compiled(kern, name=f"sparse.{op}",
-                           static_argnames=("segments",),
-                           key_fn=lambda *a, **k: (op,))
-        _COMPILED_PRODUCTS[op] = cf
-    return cf
+        cf = _COMPILED_PRODUCTS[op] = _compiled(
+            product_lanes, name=f"sparse.{op}",
+            static_argnames=("kernel", "shape", "plan"))
+    return cf(*operands, **statics)
 
 
-def product_operands(A: SparseMatrix, k: int, dtype) -> tuple:
+def product_operands(A: SparseMatrix, k: int, dtype,
+                     side: str = "rows") -> tuple:
     """What the ``sparse.spmm`` program body takes for ``A`` against a
     right factor of ``k`` columns: ``(lanes, kernel, plan, attrs)`` — the
     device arrays (regrouped for the kernel, else the CSR lanes), the
     kernel's name as ``sparse_serve.product_kernel`` decides it, its plan
     (None off the kernel) and the counts a ``sketch.dispatch`` span
-    carries. Placement happens here, once per layout."""
+    carries. ``side="transposed"``: the same for ``Aᵀ`` against a factor of
+    ``A.height`` rows — Aᵀ's lanes (A's CSC), its own placement. Placement
+    happens here, once per side and layout."""
     from libskylark_tpu.engine.bucket import lane_class
     from libskylark_tpu.sketch.sparse_serve import product_kernel
 
     nnz_class = lane_class(A.nnz)
-    kernel, plan = product_kernel(A.shape, k, nnz_class,
-                                  A._device_dtype_of(dtype))
+    transposed = side == "transposed"
+    eff = A._device_dtype_of(dtype)
+    kernel, plan = product_kernel(A.shape, k, nnz_class, eff,
+                                  rowwise=not transposed)
     attrs = {"kernel": kernel, "nnz": A.nnz, "nnz_class": nnz_class}
+    if transposed:
+        attrs["side"] = side
     if plan is None:
         attrs.update(lane_slots=nnz_class, segments=1)
-        return A.csr_device(dtype), kernel, None, attrs
-    lanes, grouped, covered = A._tiled(plan.layout, dtype)
-    attrs.update(lane_slots=plan.n_chunks * plan.chunk, grouped_lanes=grouped,
-                 covered_segments=covered,
+        lanes = A.csc_device(dtype) if transposed else A.csr_device(dtype)
+        return lanes, kernel, None, attrs
+    lanes, counts = A._tiled(plan.layout, dtype, side)
+    attrs.update(lane_slots=plan.n_chunks * plan.chunk, **counts,
                  segments=plan.row_blocks * plan.col_tiles,
                  row_block=plan.row_block, col_tile=plan.col_tile,
                  chunk=plan.chunk)
@@ -615,8 +833,7 @@ def spmm(A: SparseMatrix, B) -> jax.Array:
             f"spmm: A is {A.shape}, B is {B.shape}"
         )
     lanes, kernel, plan, _ = product_operands(A, int(B.shape[1]), B.dtype)
-    out = _product_kernel("spmm")(*lanes, B, kernel=kernel, shape=A.shape,
-                                  plan=plan)
+    out = _product("spmm", *lanes, B, kernel=kernel, shape=A.shape, plan=plan)
     _spmm_nnz().inc_always(A.nnz, kernel=kernel)
     return out[:, 0] if squeeze else out
 
@@ -627,11 +844,21 @@ def _spmm_nnz():
 
     return _metrics.counter(
         "sparse.spmm_nnz",
-        "Stored nonzeros multiplied through base.sparse.spmm, by kernel")
+        "Stored nonzeros multiplied through base.sparse.spmm and spmm_t "
+        '(side="transposed"), by kernel')
 
 
 def spmm_t(A: SparseMatrix, B) -> jax.Array:
-    """Aᵀ @ B with A sparse (h×w), B dense (h×k) → dense (w×k)."""
+    """Aᵀ @ B with A sparse (h×w), B dense (h×k) → dense (w×k): ONE
+    compiled program (``sparse.spmm_t``), :func:`spmm`'s body over the
+    lanes of Aᵀ — A's own column-major buffers, placed at the first
+    transposed product: regrouped by (block of A's columns, tile of A's
+    rows) for the Pallas walk where ``sparse_serve.product_kernel(...,
+    rowwise=False)`` finds the shapes (a TPU, float32, k a multiple of 128
+    up to 2048), else the span loop. Workspace, whatever nnz is: the
+    kernel's VMEM blocks and the (w × k) relayout of its result, or the
+    span loop's ``_SPAN_LANES`` × k rows and one int32 a lane. Counts the
+    stored nonzeros under ``sparse.spmm_nnz`` with ``side="transposed"``."""
     B = jnp.asarray(B)
     squeeze = B.ndim == 1
     if squeeze:
@@ -640,8 +867,11 @@ def spmm_t(A: SparseMatrix, B) -> jax.Array:
         raise errors.InvalidParametersError(
             f"spmm_t: A is {A.shape}, B is {B.shape}"
         )
-    r, c, v = A.coo(B.dtype)
-    out = _product_kernel("spmm_t")(r, c, v, B, segments=A.width)
+    lanes, kernel, plan, _ = product_operands(A, int(B.shape[1]), B.dtype,
+                                              side="transposed")
+    out = _product("spmm_t", *lanes, B, kernel=kernel, shape=A.shape[::-1],
+                   plan=plan)
+    _spmm_nnz().inc_always(A.nnz, kernel=kernel, side="transposed")
     return out[:, 0] if squeeze else out
 
 

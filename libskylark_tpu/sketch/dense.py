@@ -282,18 +282,25 @@ class DenseTransform(OperatorCache, SketchTransform):
     # -- sparse input (ref: sketch/dense_transform_Mixed.hpp:19) --
 
     def _apply_columnwise_sparse(self, A) -> jnp.ndarray:
+        """S·A of a ``SparseMatrix`` = (Aᵀ·Sᵀ)ᵀ: ONE compiled program an
+        apply (``sketch.dense_sparse_cw``), as :meth:`_apply_rowwise_sparse`
+        is — the operator generated inside it from the allocation's key
+        words (``sparse_serve.dense_sparse_apply_cw`` states the
+        workspace), the transposed sparse product ``base.sparse.spmm_t``
+        runs, over the placement of A's transposed side. A pinned operator
+        is ``spmm_t``'s right factor; one past ``auto_block_bytes`` keeps
+        the host loop over panels of Aᵀ's columns."""
         from libskylark_tpu.base.sparse import spmm_t
 
         S = self._cached_op(A.device_dtype)
         if S is not None:
             return spmm_t(A, S.T).T      # S·A = (Aᵀ·Sᵀ)ᵀ
-        blocksize = self._effective_blocksize(A.device_dtype)
-        if blocksize:
-            # S·A = (Aᵀ·Sᵀ)ᵀ; Aᵀ's columns are A's rows = the sketched dim,
-            # so the panel loop runs over Aᵀ (host CSC transpose, O(nnz)).
-            return self._sparse_panel_loop(A.transpose(), blocksize).T
-        S = self.s_panel(0, self._N, A.device_dtype)
-        return spmm_t(A, S.T).T          # S·A = (Aᵀ·Sᵀ)ᵀ
+        if self._op_bytes(A.device_dtype) > sketch_params.get_auto_block_bytes():
+            # Aᵀ's columns are A's rows = the sketched dim, so the panel
+            # loop runs over Aᵀ (host CSC transpose, O(nnz))
+            return self._sparse_panel_loop(
+                A.transpose(), self._effective_blocksize(A.device_dtype)).T
+        return self._sparse_program_apply(A, "transposed")
 
     def _apply_rowwise_sparse(self, A) -> jnp.ndarray:
         """A·Sᵀ of a ``SparseMatrix``: ONE compiled program an apply
@@ -306,7 +313,7 @@ class DenseTransform(OperatorCache, SketchTransform):
         factor. The program holds the whole operator, so one past
         ``auto_block_bytes`` (2 GiB unless set) keeps the host loop over
         column panels; the ``blocksize`` knob alone chooses nothing here."""
-        from libskylark_tpu.base.sparse import product_operands, spmm
+        from libskylark_tpu.base.sparse import spmm
 
         S = self._cached_op(A.device_dtype)
         if S is not None:
@@ -314,14 +321,22 @@ class DenseTransform(OperatorCache, SketchTransform):
         if self._op_bytes(A.device_dtype) > sketch_params.get_auto_block_bytes():
             return self._sparse_panel_loop(
                 A, self._effective_blocksize(A.device_dtype))
+        return self._sparse_program_apply(A, "rows")
+
+    def _sparse_program_apply(self, A, side: str) -> jnp.ndarray:
+        """The compiled dense sketch of ``A`` on ``side`` (``"rows"``: A·Sᵀ,
+        ``"transposed"``: S·A): the side's placement, the ``sketch.dispatch``
+        span, the one program, the ``sketch.sparse_nnz`` count."""
+        from libskylark_tpu.base.sparse import product_operands
+
         lanes, kernel, plan, attrs = product_operands(
-            A, self._S, A.device_dtype)
+            A, self._S, A.device_dtype, side)
         key_data = self._alloc.key_data
         scale = self._device_scale()
         with _trace.span("sketch.dispatch",
                          {"path": "sparse", "family": self.sketch_type,
                           "s": self._S, **attrs}):
-            out = _sparse_program()(
+            out = _sparse_program(side)(
                 key_data, scale, *lanes, dist=self.dist,
                 s_dim=self._S, shape=A.shape, kernel=kernel, plan=plan)
         from libskylark_tpu.sketch.hash import _SPARSE_NNZ
@@ -486,19 +501,24 @@ class CT(DenseTransform):
 # -- the compiled dense sketch of a sparse operand (below everything the
 # dense cells' programs trace through, whose lines keep their numbers) --
 
-_SPARSE_PROGRAM = None
+_SPARSE_PROGRAMS: dict = {}
 
 
-def _sparse_program():
-    """``sketch.dense_sparse``, built at the first sparse operand so that
-    importing the sketch layer pulls neither the engine nor a Pallas
-    module."""
-    global _SPARSE_PROGRAM
-    if _SPARSE_PROGRAM is None:
+def _sparse_program(side: str):
+    """``sketch.dense_sparse`` (``side`` ``"rows"``) or
+    ``sketch.dense_sparse_cw`` (``"transposed"``), built at the side's first
+    sparse operand so that importing the sketch layer pulls neither the
+    engine nor a Pallas module."""
+    program = _SPARSE_PROGRAMS.get(side)
+    if program is None:
         from libskylark_tpu.engine.compiled import compiled
-        from libskylark_tpu.sketch.sparse_serve import dense_sparse_apply
+        from libskylark_tpu.sketch import sparse_serve
 
-        _SPARSE_PROGRAM = compiled(
-            dense_sparse_apply, name="sketch.dense_sparse",
+        body, name = ((sparse_serve.dense_sparse_apply, "sketch.dense_sparse")
+                      if side == "rows" else
+                      (sparse_serve.dense_sparse_apply_cw,
+                       "sketch.dense_sparse_cw"))
+        program = _SPARSE_PROGRAMS[side] = compiled(
+            body, name=name,
             static_argnames=("dist", "s_dim", "shape", "kernel", "plan"))
-    return _SPARSE_PROGRAM
+    return program

@@ -8,12 +8,13 @@ blocks), ``_kernel_tiles`` / ``_tiles_call`` (the kernel and its launch) and
 ``sparse_serve.product_kernel``, from the backend and the shapes alone, for
 ``base.sparse.spmm`` and for ``DenseTransform.apply`` on a ``SparseMatrix``
 rowwise (the dense sketch of a sparse operand, whose right factor is Sᵀ
-generated inside the same program). Where it does not apply: a backend that
-compiles no Mosaic kernel, values that are not float32, a width k that is
-no multiple of 128 or past 2048, a chunk table past SMEM; ``spmm_t`` and the
-columnwise sparse apply (their contraction runs down the rows: lanes
-regrouped by row block feed no resident block of the result). Off the TPU
-the kernel runs only interpreted, for the tests.
+generated inside the same program) — and, since PR 61, for the transposed
+side: ``base.sparse.spmm_t`` and the columnwise apply are this walk with Xᵀ
+in X's place, over a placement of their own (*The runs layout* below).
+Where it does not apply: a backend that compiles no Mosaic kernel, values
+that are not float32, a width k that is no multiple of 128 or past 2048, a
+chunk table past SMEM. Off the TPU the kernel runs only interpreted, for
+the tests.
 
 The layout (``SparseMatrix.tiled_device``, placed once). The row axis is
 cut into blocks of ``row_block`` rows, the column axis into tiles of
@@ -83,10 +84,30 @@ that walk's to the bit whatever the group. The loops stop at the chunk's
 counts: padding slots are moved, never multiplied, so a non-finite entry of
 B poisons only the rows whose lanes address it.
 
+The runs layout (``TilesPlan.runs``; ``base.sparse._tile_runs``, the
+transposed side). With Xᵀ in X's place a result row is a *feature* of a
+corpus: a frequent one stores a lane in most of a tile's 2048 examples,
+most hold one or none — the (rank, row) order puts such a row's lanes into
+high rank classes of few rows, the serial tail (15 % of the lanes at
+rcv1's skew). There a row with at least ``_RUN_ROW`` lanes in a segment is
+kept whole, row-major, and its lanes are padded to a multiple of ``group``
+slots (zero-valued copies of its last lane); the short rows lie by (rank,
+row) as above and their grouped prefix, cut to whole groups, is the chunk's
+grouped slots; what that leaves joins the runs. The kernel takes the slots
+past the grouped count ``group`` at a time as a *run*: ``group`` loads of
+B, their terms summed in registers (pairwise), ONE load and ONE store of
+the result row — a run's loads of B wait on no store, and a hot row costs
+a lane less than a grouped one (five scalar operations a lane, not ten).
+The sums of a row's terms are pairwise inside a run and by rising column
+across runs: float32 arithmetic, each stored nonzero once, not the
+row-major walk's bits. Every block of result rows streams all of B once,
+so the transposed plan's blocks are twice as tall (4096 rows at k ≤ 1024).
+
 Workspace. VMEM: 2 · (row_block + col_tile) rows of max(k, 1024) floats
-(at most 32 MiB at k = 1024). HBM, beside the operands and the result: none
-in the kernel; :func:`tiles_apply` adds the (rows, k) relayout of the
-result's (rows, k/128, 128) view. Nothing grows with nnz · k.
+(32 MiB at k = 1024; 48 MiB under the transposed plan's 4096-row blocks).
+HBM, beside the operands and the result: none in the kernel;
+:func:`tiles_apply` adds the (rows, k) relayout of the result's (rows,
+k/128, 128) view. Nothing grows with nnz · k.
 """
 
 from __future__ import annotations
@@ -127,6 +148,11 @@ _UNROLL = 8             # lanes an iteration of the serial walk
 _GROUP = 8              # rows whose loads go ahead of their stores (grouped walk)
 _SPAN = 128             # slots the grouped walk unrolls: an SMEM window's alignment
 _MAX_K = 2048
+_RUN_ROW = 16           # lanes of one result row in one segment from which
+                        # the runs layout keeps the row whole, as a run
+_RUN_UNROLL = 8         # runs an iteration of the run walk (ms a block of
+                        # the jlt_sparse_apply_cw cell: 1 124.6, 2 113.6,
+                        # 4 110.6, 8 108.8 — benchmarks/spmm_t_walk_steps.log)
 _COPY_BYTES_A_LANE = 2048   # of a tile of B, arriving while a grouped lane
                             # walks: the v5e's HBM rate, 819 B/ns, times the
                             # lane as scheduled, 2.5 ns (3.70 bundles, 1.5 GHz)
@@ -136,8 +162,11 @@ class TilesPlan(NamedTuple):
     """Blocks of one product: ``row_block`` result rows and ``col_tile``
     rows of B in VMEM, ``chunk`` lane slots a grid step, ``k_tiles`` =
     k / 128, the grid's blocks and tiles, ``n_chunks``, the static bound on
-    the chunks of any operand of these extents, and ``group``, the rows a
-    step of the grouped walk loads before it stores any."""
+    the chunks of any operand of these extents, ``group``, the rows a
+    step of the grouped walk loads before it stores any, and ``runs``: the
+    slots past a chunk's grouped ones are runs of ``group`` slots on ONE
+    result row each, summed in registers (the transposed side's layout),
+    not single lanes."""
     row_block: int
     col_tile: int
     chunk: int
@@ -146,6 +175,7 @@ class TilesPlan(NamedTuple):
     col_tiles: int
     n_chunks: int
     group: int
+    runs: bool = False
 
     @property
     def stride(self) -> int:
@@ -170,7 +200,8 @@ class TilesPlan(NamedTuple):
 
     @property
     def layout(self) -> tuple:
-        """What the placement depends on (``SparseMatrix.tiled_device``)."""
+        """What the placement depends on (``SparseMatrix.tiled_device``;
+        the side is the caller's word: ``runs`` plans lay A's columns)."""
         return (self.row_block, self.col_tile, self.chunk, self.n_chunks,
                 self.group, self.stride, self.cover)
 
@@ -179,11 +210,24 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def tiles_plan(shape: tuple, k: int, lanes: int, dtype) -> tuple:
+def tiles_plan(shape: tuple, k: int, lanes: int, dtype,
+               transposed: bool = False) -> tuple:
     """``(plan, None)`` when the kernel fits ``X (shape) · B (n × k)`` with
     ``lanes`` lane positions placed, else ``(None, why)``. Shapes only:
-    whether the backend compiles Mosaic kernels is the caller's question."""
+    whether the backend compiles Mosaic kernels is the caller's question.
+
+    ``transposed``: the plan of ``Xᵀ · B`` (B is rows × k) — the result's
+    rows are X's columns, the short and skewed side of a corpus, and B the
+    long streamed one. Every row block of the result streams all of B once,
+    so the blocks are twice as tall (4096 rows at k ≤ 1024: twelve passes
+    over B at 47236 columns, not twenty-four; a packed word holds
+    row · stride in 15 bits, which 4096 · 8 fills); the layout is the runs
+    one (``TilesPlan.runs``), whose padding is bounded by 7/16 of the lanes
+    and 896 slots a segment (``base.sparse._tile_runs``), and the chunk
+    bound counts it."""
     rows, n = int(shape[0]), int(shape[1])
+    if transposed:
+        rows, n = n, rows
     if jnp.dtype(dtype) != jnp.float32:
         return None, f"dtype {jnp.dtype(dtype).name}"
     if k % LANES or not LANES <= k <= _MAX_K:
@@ -192,17 +236,30 @@ def tiles_plan(shape: tuple, k: int, lanes: int, dtype) -> tuple:
         return None, "empty operand"
     k_tiles = k // LANES
     cap = _BLOCK_ROWS if k_tiles <= 8 else _BLOCK_ROWS // 2
-    row_block = min(cap, _round_up(rows, 8))
+    row_block = min(2 * cap if transposed else cap, _round_up(rows, 8))
     # column tiles of one width (to 8 columns), not whole ones and a rest: a
     # narrow last tile is a short last segment of every row block, which
     # hides nothing of the block's write and the next tile's copy
     col_tile = _round_up(-(-n // -(-n // cap)), 8)
     row_blocks, col_tiles = -(-rows // row_block), -(-n // col_tile)
-    for chunk in _CHUNKS:
-        n_chunks = -(-lanes // chunk) + row_blocks * col_tiles
+    # a run's slot walks in ≈ 1 ns, under half a grouped lane's time: the
+    # runs layout takes the longer chunk first (110.6 → 105.1 ms a block of
+    # the jlt_sparse_apply_cw cell, a third fewer steps)
+    for chunk in (_CHUNKS[::-1] if transposed else _CHUNKS):
+        if transposed:
+            # a run's padding is under _GROUP slots: 7/16 of the lanes of
+            # the rows kept whole, and a segment's other run lanes (under
+            # _GROUP a rank class, and the grouped prefix's remainder) are
+            # at most _RUN_ROW · (_GROUP − 1), each a run of its own at worst
+            n_chunks = (-(-lanes * (_RUN_ROW + _GROUP - 1)
+                          // (_RUN_ROW * chunk))
+                        + row_blocks * col_tiles * (1 + -(
+                            -_RUN_ROW * (_GROUP - 1) * _GROUP // chunk)))
+        else:
+            n_chunks = -(-lanes // chunk) + row_blocks * col_tiles
         if n_chunks <= _MAX_CHUNKS:
             return TilesPlan(row_block, col_tile, chunk, k_tiles, row_blocks,
-                             col_tiles, n_chunks, _GROUP), None
+                             col_tiles, n_chunks, _GROUP, transposed), None
     return None, f"chunk table {n_chunks} past {_MAX_CHUNKS} entries"
 
 
@@ -213,10 +270,11 @@ def vmem_bytes(plan: TilesPlan) -> int:
     return 2 * (plan.row_block + plan.col_tile) * row
 
 
-def _kernel_tiles(col_tiles, group, stride, segment, count, packed_ref,
+def _kernel_tiles(col_tiles, group, stride, runs, segment, count, packed_ref,
                   vals_ref, b_ref, out_ref):
     """One grid step: the stored lanes of one chunk into their row block,
-    the chunk's grouped slots ``group`` at a time, the rest one by one."""
+    the chunk's grouped slots ``group`` at a time, the rest one by one —
+    or, under ``runs``, ``group`` slots of one row at a time."""
     t = pl.program_id(0)
     block = segment[t] // col_tiles
     first = (t == 0) | (block != segment[jnp.maximum(t - 1, 0)] // col_tiles)
@@ -272,6 +330,37 @@ def _kernel_tiles(col_tiles, group, stride, segment, count, packed_ref,
     jax.lax.fori_loop(spans * (_SPAN // group), groups, one, 0)
     done = groups * group
 
+    if runs:
+        def run(at):
+            """``group`` slots on ONE result row (the placement's word; a
+            short run is padded with zero-valued copies of its last lane):
+            their terms summed in registers, the row loaded and stored
+            once. A run's loads of B wait on no store."""
+            row, col = rows(packed_ref[0, 0, at])
+            terms = [vals_ref[0, 0, at] * b_ref[col]]
+            for u in range(1, group):
+                _, col = rows(packed_ref[0, 0, at + u])
+                terms.append(vals_ref[0, 0, at + u] * b_ref[col])
+            while len(terms) > 1:       # pairwise: a short chain of adds
+                terms = ([a + b for a, b in zip(terms[::2], terms[1::2])]
+                         + terms[len(terms) & ~1:])
+            out_ref[row] = out_ref[row] + terms[0]
+
+        def some(g, carry):
+            for u in range(_RUN_UNROLL):
+                run(done + (g * _RUN_UNROLL + u) * group)
+            return carry
+
+        def one_run(q, carry):
+            run(done + q * group)
+            return carry
+
+        units = (n - done) // group
+        whole = units // _RUN_UNROLL
+        jax.lax.fori_loop(0, whole, some, 0)
+        jax.lax.fori_loop(whole * _RUN_UNROLL, units, one_run, 0)
+        return
+
     def serial(g, carry):
         for u in range(_UNROLL):
             lane(done + g * _UNROLL + u)
@@ -305,7 +394,7 @@ def _tiles_call(segment, count, packed, vals, B, *, plan: TilesPlan,
 
     return pl.pallas_call(
         functools.partial(_kernel_tiles, plan.col_tiles, plan.group,
-                          plan.stride),
+                          plan.stride, plan.runs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(plan.n_chunks,),
@@ -329,12 +418,16 @@ def tiles_apply(segment, count, packed, vals, B, *, shape: tuple,
                 plan: TilesPlan, interpret: bool = False) -> jnp.ndarray:
     """``X·B`` (rows × k, float32) for X placed as
     ``SparseMatrix.tiled_device(plan.layout)`` says and B (≥ n rows, k)
-    float32: rows of B past n are never addressed. Traceable."""
+    float32: rows of B past n are never addressed (a B shorter than the
+    column tiles reach is padded, a longer one handed over as it is: a
+    slice would be a copy). B may come in the kernel's view, (≥ n, k/128,
+    128): on a TPU the reshape of a (n, k) array to it is a copy of B.
+    Traceable."""
     rows = int(shape[0])
     k = plan.k_tiles * LANES
     n_pad = plan.col_tiles * plan.col_tile
     if B.shape[0] < n_pad:
-        B = jnp.pad(B, ((0, n_pad - B.shape[0]), (0, 0)))
-    out = _tiles_call(segment, count, packed, vals, B[:n_pad], plan=plan,
+        B = jnp.pad(B, ((0, n_pad - B.shape[0]),) + ((0, 0),) * (B.ndim - 1))
+    out = _tiles_call(segment, count, packed, vals, B, plan=plan,
                       interpret=interpret)
     return out.reshape(-1, k)[:rows]

@@ -216,8 +216,9 @@ def sparse_solve_serve(key_data, scale, data, indices, indptr, B, *,
     return solve_l2_exact(SA, SB, method=method)
 
 
-# -- sparse × dense: the product of base.sparse.spmm and the dense sketch of
-# a sparse operand (ref: base/Gemm.hpp:335-519 through
+# -- sparse × dense: the product of base.sparse.spmm / spmm_t and the dense
+# sketches of a sparse operand, rowwise and (at the end of the file)
+# columnwise (ref: base/Gemm.hpp:335-519 through
 # sketch/dense_transform_Mixed.hpp:19). Below the hash endpoints, whose
 # traced lines keep their numbers. --
 
@@ -231,38 +232,43 @@ def _compiles_mosaic() -> bool:
 
 def product_kernel(shape: tuple, k: int, lanes: int, dtype,
                    rowwise: bool = True) -> tuple:
-    """Which program multiplies a sparse operand of ``shape`` (``lanes``
-    lane positions placed) by a dense right factor of ``k`` columns:
-    ``("pallas_tiles", plan)`` — the walk of
-    :mod:`libskylark_tpu.sketch.pallas_spmm` over lanes regrouped by (row
-    block, column tile) — on a TPU, for ``A·B`` (a rowwise sketch), where
-    its plan fits (float32, k a multiple of 128 up to 2048, a chunk table
-    inside SMEM), else ``("xla: <why>", None)``: the span loop of
-    ``base.sparse.spans_product``. Decided from what the caller can
-    observe — the backend and the shapes — by ``base.sparse.spmm`` and
-    ``DenseTransform._apply_rowwise_sparse``; the ``sketch.dispatch`` span
-    and the ``sketch.sparse_nnz`` / ``sparse.spmm_nnz`` counters carry the
-    name."""
-    if not rowwise:
-        return "xla: columnwise contracts down the rows", None
+    """Which program multiplies a sparse operand A of ``shape`` (``lanes``
+    lane positions placed) by a dense right factor of ``k`` columns —
+    ``A·B`` (``rowwise``: a rowwise sketch, ``spmm``) or ``Aᵀ·B`` (not
+    ``rowwise``: the transposed side — a columnwise sketch, ``spmm_t``):
+    ``("pallas_tiles", plan)`` / ``("pallas_runs", plan)`` — the walk of
+    :mod:`libskylark_tpu.sketch.pallas_spmm` over the side's lanes regrouped
+    by (block of result rows, tile of B's rows), the transposed side's
+    under the runs layout — on a TPU where the side's plan fits (float32,
+    k a multiple of 128 up to 2048, a chunk table inside SMEM), else
+    ``("xla: <why>", None)``: the span loop of
+    ``base.sparse.spans_product`` over the side's lanes. Decided from what
+    the caller can observe — the backend and the shapes — by
+    ``base.sparse.product_operands`` for ``spmm``, ``spmm_t`` and the dense
+    sketches of a ``SparseMatrix``; the ``sketch.dispatch`` span and the
+    ``sketch.sparse_nnz`` / ``sparse.spmm_nnz`` counters carry the name."""
     if not _compiles_mosaic():
         return f"xla: backend {jax.default_backend()}", None
     from libskylark_tpu.sketch import pallas_spmm
 
-    plan, why = pallas_spmm.tiles_plan(shape, k, lanes, dtype)
-    return ("pallas_tiles", plan) if plan is not None else (f"xla: {why}",
-                                                            None)
+    plan, why = pallas_spmm.tiles_plan(shape, k, lanes, dtype,
+                                       transposed=not rowwise)
+    if plan is None:
+        return f"xla: {why}", None
+    return ("pallas_tiles" if rowwise else "pallas_runs"), plan
 
 
 def product_lanes(*operands, kernel: str, shape: tuple,
                   plan=None) -> jnp.ndarray:
-    """``A·B`` — the body of the ``sparse.spmm`` program. ``operands`` are
-    A's device arrays as ``base.sparse.product_operands`` placed them for
-    ``kernel`` (the regrouped lanes of ``SparseMatrix.tiled_device``, else
-    the CSR lanes) followed by B (at least n rows; float32 for the
-    kernel). A kernel that does not compile raises: nothing falls back."""
+    """``A·B`` — the body of the ``sparse.spmm`` program, and of
+    ``sparse.spmm_t`` with Aᵀ in A's place (``shape`` Aᵀ's, the lanes those
+    of A's transposed side). ``operands`` are the device arrays as
+    ``base.sparse.product_operands`` placed them for ``kernel`` (the
+    regrouped lanes of ``SparseMatrix.tiled_device``, else the CSR lanes)
+    followed by B (at least n rows; float32 for the kernel). A kernel that
+    does not compile raises: nothing falls back."""
     *lanes, B = operands
-    if kernel == "pallas_tiles":
+    if plan is not None:
         from libskylark_tpu.sketch import pallas_spmm
 
         return pallas_spmm.tiles_apply(
@@ -313,3 +319,66 @@ def dense_sparse_apply(key_data, scale, *lanes, dist, s_dim: int,
                        dtype=lanes[-1].dtype if plan is not None
                        else lanes[0].dtype)
     return product_lanes(*lanes, Bt, kernel=kernel, shape=shape, plan=plan)
+
+
+# -- the transposed side: S·X of a columnwise dense sketch, Aᵀ·B --
+
+
+def operator_rows_panels(key_data, scale, *, dist, s_dim: int, n: int,
+                         dtype, lanes: int | None = None,
+                         panel_blocks: int = 64) -> jnp.ndarray:
+    """:func:`operator_rows` for a long sketched axis (n the examples of a
+    corpus, not its features), at least ``n`` rows (to the stream's block:
+    the rows past ``n`` are the stream's next entries, which no lane
+    addresses): the same entries, each block generated already transposed
+    and scaled (``randgen.dense_block_rows``: no (s_dim × n) array, no
+    relayout and no second copy of one) and ``panel_blocks`` blocks at a
+    time under one loop, so that beside the result the program holds one
+    panel's cipher words (64 blocks: 16384 rows, 64 MiB a word array at
+    s_dim 1024), not the operator's. ``lanes`` = 128 gives the rows in the
+    kernel's view, (rows, s_dim / 128, 128) — on a TPU a (rows, s_dim) array
+    is tiled otherwise, and handing one to the kernel is a copy of it."""
+    import jax.random as jr
+
+    from libskylark_tpu.sketch.dense import BLOCK_COLS
+
+    key = jr.wrap_key_data(jnp.asarray(key_data))
+    scale = jnp.asarray(scale, dtype)
+    blocks = jax.lax.map(
+        lambda b: scale * randgen.dense_block_rows(key, dist, s_dim, b,
+                                                   BLOCK_COLS, dtype, lanes),
+        jnp.arange(-(-n // BLOCK_COLS), dtype=jnp.int32),
+        batch_size=panel_blocks)
+    return blocks.reshape((-1,) + blocks.shape[2:])
+
+
+def dense_sparse_apply_cw(key_data, scale, *lanes, dist, s_dim: int,
+                          shape: tuple, kernel: str, plan=None) -> jnp.ndarray:
+    """One columnwise dense-family (JLT/CT) sketch of a sparse operand X of
+    ``shape`` (m × n), ``S·X = (Xᵀ·Sᵀ)ᵀ`` (s_dim × n): the program
+    ``sketch.dense_sparse_cw``, a pure function of the allocation's key
+    words, the scale and the lanes of X's transposed side
+    (``base.sparse.product_operands(..., side="transposed")``). Sᵀ (m ×
+    s_dim: column c of S is the row of example c) is generated here
+    (:func:`operator_rows_panels`: the S the dense columnwise apply
+    contracts with, entry for entry; for the kernel in the kernel's view)
+    and stands where ``spmm_t``'s B stands: the product is
+    :func:`product_lanes` over Xᵀ.
+
+    Workspace, whatever nnz is: Sᵀ once (m × s_dim values, 2 GiB at 524288
+    × 1024), one generation panel, the product's (n × s_dim) result and its
+    relayout, or the span loop's ``_SPAN_LANES`` × s_dim rows and one int32
+    a lane."""
+    from libskylark_tpu.sketch.pallas_spmm import LANES
+
+    m, n = int(shape[0]), int(shape[1])
+    if plan is None:
+        St = operator_rows_panels(key_data, scale, dist=dist, s_dim=s_dim,
+                                  n=m, dtype=lanes[0].dtype)
+    else:
+        St = operator_rows_panels(
+            key_data, scale, dist=dist, s_dim=s_dim,
+            n=plan.col_tiles * plan.col_tile, dtype=lanes[-1].dtype,
+            lanes=LANES)
+    return product_lanes(*lanes, St, kernel=kernel, shape=(n, m),
+                         plan=plan).T

@@ -58,7 +58,9 @@ METRICS: Dict[str, str] = {
     # nla/svd.py, Krylov solvers, kernels on sparse inputs): stored
     # nonzeros multiplied, by kernel (sparse_serve.product_kernel:
     # "pallas_tiles" | "xla: <why>"); a dense sketch of a sparse operand
-    # counts under sketch.sparse_nnz instead
+    # counts under sketch.sparse_nnz instead. Since PR 61 base.sparse.spmm_t
+    # counts here too, with side="transposed" (kernel "pallas_runs" |
+    # "xla: <why>")
     "sparse.spmm_nnz": "counter",
     # the compiled dense feature-map apply (sketch/rft.py): feature values
     # produced (rows × s), by family and kernel ("pallas_planes" |
@@ -233,7 +235,15 @@ SPANS: Dict[str, Tuple[str, str]] = {
     # holds at least TilesPlan.cover stored lanes, so that its walk outlasts
     # the copy of the next segment's tile of B: ÷ segments, how often the
     # chunk's size hides that copy; counted once at placement, an apply
-    # reads the stored integer); its handover is the engine.execute inside
+    # reads the stored integer); since PR 61 the columnwise apply
+    # (_apply_columnwise_sparse, the sketch.dense_sparse_cw program) opens
+    # the same span with side="transposed", kernel "pallas_runs" | "xla:
+    # <why>", the blocks those of the transposed side (row_block A's
+    # columns, col_tile A's rows) and how the skew was taken: grouped_lanes
+    # (short result rows, by (rank, row)), run_lanes (the stored lanes in
+    # runs of ONE result row, summed in registers: whole rows of ≥ 16 lanes
+    # a segment, and what the grouped prefix leaves) and run_slots (those
+    # with their padding to whole groups); its handover is the engine.execute inside
     # it
     # the dense apply of an operand on more than one device
     # (parallel/shard_apply.py apply_on_mesh, since PR 55) carries path="mesh",
@@ -282,11 +292,15 @@ SPANS: Dict[str, Tuple[str, str]] = {
     "stream.key": ("streams", "stream_key_ms.apply"),
     # a sparse operand's lanes regrouped on the device for the sparse ×
     # dense kernel (base/sparse.py SparseMatrix.tiled_device), once per
-    # (dtype, layout): attributes layout, nnz, lane_slots, grouped_lanes,
-    # covered_segments (as the dispatch's), bytes (placed) and seconds (the
-    # host regrouping and the upload, waited for) — set-up, never inside a
-    # measured apply
-    "sparse.place": ("set-up", "operator"),
+    # (dtype, side, layout): attributes layout, side ("rows" | "transposed",
+    # since PR 61: the second placement, made at the first transposed
+    # product), nnz, lane_slots, grouped_lanes, covered_segments (as the
+    # dispatch's; run_lanes and run_slots on the transposed side), bytes
+    # (placed) and seconds (the host regrouping and the upload, waited for)
+    # — set-up, never inside a measured apply; opened whoever listens
+    # (force=True, since PR 61: the benchmark's setup_place_s sums the
+    # seconds of a run nobody traces)
+    "sparse.place": ("set-up", "setup_place_s"),
     # the measured solve (nla/svd.py, engine/compiled.py); under a
     # sketch.apply the same spans are the compiled applies' way to the
     # runtime: engine.lookup is a part of what idle_before_enqueue_ms.apply

@@ -535,13 +535,12 @@ class TestThePlacement:
                 (1024, jnp.float32, 1 << 28, "chunk table")]:
             plan, said = pallas_spmm.tiles_plan(shape, k, lanes, dtype)
             assert plan is None and why in said
-        # off the TPU and columnwise the rule says so
-        assert sparse_serve.product_kernel(
-            shape, 1024, 19922944, jnp.float32) == (
-                f"xla: backend {jax.default_backend()}", None)
-        assert sparse_serve.product_kernel(
-            shape, 1024, 19922944, jnp.float32, rowwise=False)[0].startswith(
-                "xla: columnwise")
+        # off the TPU the rule says so, on either side (since PR 61 the
+        # transposed side has a plan of its own: test_sparse_transposed_program)
+        for rowwise in (True, False):
+            assert sparse_serve.product_kernel(
+                shape, 1024, 19922944, jnp.float32, rowwise=rowwise) == (
+                    f"xla: backend {jax.default_backend()}", None)
 
 
 class TestSpansAndCounters:

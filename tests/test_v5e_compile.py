@@ -1065,3 +1065,81 @@ def test_cell_shape_sparse_product_replaced_the_whole_gather(one_chip):
     assert f"f32[{SPARSE_LANES},{SPARSE_S}]" not in compiled.as_text()
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 3 << 30, memory
+
+
+# -- the jlt_sparse_apply_cw cell: the columnwise sketch of a sparse row block -
+
+# a jlt_sparse_apply_cw block: twice the rows of a jlt_sparse_apply block,
+# the lane class of its ~38.8 M nonzeros
+SPARSE_CW_ROWS, SPARSE_CW_LANES = 524288, 39845888
+
+
+def test_cell_shape_sparse_dense_cw_program(one_chip, monkeypatch):
+    """The whole ``sketch.dense_sparse_cw`` program of the
+    jlt_sparse_apply_cw cell under the runs walk: Sᵀ (524288 × 1024, 2 GiB)
+    generated in the program a panel at a time, ONE Mosaic call, no array
+    of nnz × s and no second copy of the operator anywhere: under 3.5 GB
+    of temporaries (the operator, the result's relayout and transpose)."""
+    from libskylark_tpu.sketch import pallas_spmm
+
+    monkeypatch.setattr(sparse_serve, "_compiles_mosaic", lambda: True)
+    shape = (SPARSE_CW_ROWS, SPARSE_N)
+    kernel, plan = sparse_serve.product_kernel(
+        shape, SPARSE_S, SPARSE_CW_LANES, jnp.float32, rowwise=False)
+    assert kernel == "pallas_runs"
+    assert plan == pallas_spmm.TilesPlan(4096, 2048, 8192, 8, 12, 256, 13136,
+                                         8, True)
+    assert pallas_spmm.vmem_bytes(plan) == 2 * (4096 + 2048) * 4096
+    arg = _sparse_arg(one_chip)
+    program = jax.jit(functools.partial(
+        sparse_serve.dense_sparse_apply_cw, dist=randgen.Normal(),
+        s_dim=SPARSE_S, shape=shape, kernel=kernel, plan=plan))
+    slots = (plan.n_chunks, 1, plan.chunk)
+    compiled = program.lower(
+        arg((2,), jnp.uint32), arg((), jnp.float32),
+        arg((plan.n_chunks,), jnp.int32), arg((plan.n_chunks,), jnp.int32),
+        arg(slots, jnp.int32), arg(slots, jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 1
+    assert not re.search(r"\b(sort|scatter)\(", text)
+    assert f"f32[{SPARSE_CW_LANES},{SPARSE_S}]" not in text
+    memory = compiled.memory_analysis()
+    # the result's minor extent is laid out to a multiple of 8
+    assert memory.output_size_in_bytes == SPARSE_S * (-(-SPARSE_N // 8) * 8) * 4
+    assert memory.temp_size_in_bytes < 3.5e9, memory
+
+
+def test_cell_shape_transposed_product_replaced_the_whole_gather(one_chip):
+    """What ``spmm_t`` was before PR 61 — ``segment_sum(v[:, None] * B[r],
+    c)`` over all stored nonzeros at once — does not fit a v5e at the
+    jlt_sparse_apply_cw cell's shape (an nnz × s temporary, 159 GB), and the
+    program ``spmm_t`` runs now where the kernel declines (the span loop
+    over Aᵀ's lanes, any backend) stays under 3 GB."""
+    arg = _sparse_arg(one_chip)
+    lanes = [arg((SPARSE_CW_LANES,), jnp.int32),
+             arg((SPARSE_CW_LANES,), jnp.int32),
+             arg((SPARSE_CW_LANES,), jnp.float32)]
+    B = arg((SPARSE_CW_ROWS, SPARSE_S), jnp.float32)
+
+    def whole_gather(r, c, v, B):
+        return jax.ops.segment_sum(v[:, None] * B[r], c,
+                                   num_segments=SPARSE_N)
+
+    try:
+        needs = jax.jit(whole_gather).lower(
+            *lanes, B).compile().memory_analysis().temp_size_in_bytes
+    except Exception as e:  # noqa: BLE001 — the compiler's own refusal
+        assert "RESOURCE_EXHAUSTED" in str(e), e
+        needs = SPARSE_CW_LANES * SPARSE_S * 4
+    assert needs > CHIP_HBM
+
+    program = jax.jit(functools.partial(
+        sparse_serve.product_lanes, kernel="xla: declined",
+        shape=(SPARSE_N, SPARSE_CW_ROWS)))
+    compiled = program.lower(
+        arg((SPARSE_CW_LANES,), jnp.float32),
+        arg((SPARSE_CW_LANES,), jnp.int32),
+        arg((SPARSE_N + 1,), jnp.int32), B).compile()
+    assert f"f32[{SPARSE_CW_LANES},{SPARSE_S}]" not in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 3 << 30, memory
