@@ -1,13 +1,15 @@
 """What the transposed sparse × dense walk costs and where: one block of the
 ``jlt_sparse_apply_cw`` cell, ``Xᵀ·B`` with the operator supplied in the
 kernel's view, timed under the plan as shipped and under variants — every
-count zeroed (the empty walk: B's stream and the steps alone), the runs left
-out (the grouped region alone), the runs' unroll, 8192-slot chunks, 2048-row
-result blocks (24 passes over B, not 12), and the rowwise side's layout on
-the same lanes (PR 58's (rank, row) order, ranks past 14 walked lane by
-lane: what the transposed side read before it had a layout of its own) —
-with the host placement's seconds beside them. Run on the chip; one line a
-variant (``PERF.md`` §6 says what they mean).
+count zeroed (the empty walk: B's stream and the steps alone), every segment
+sent to B's first tile (the whole walk with B's stream taken away: wrong
+sums, right time — shipped less this one is the part of the stream that no
+walk hides), the runs left out (the grouped region alone), the runs' unroll,
+8192-slot chunks, 2048-row result blocks (24 passes over B, not 12), and the
+rowwise side's layout on the same lanes (PR 58's (rank, row) order, ranks
+past 14 walked lane by lane: what the transposed side read before it had a
+layout of its own) — with the host placement's seconds beside them. Run on
+the chip; one line a variant (``PERF.md`` §6 says what they mean).
 
     python3 benchmarks/spmm_t_walk_steps.py [seed [start of a variant's name ...]]
 
@@ -93,6 +95,14 @@ timed(plan, placed, "shipped", said)
 segment, count, packed, vals = placed
 if wanted("empty"):
     timed(plan, (segment, jnp.zeros_like(count), packed, vals), "empty_walk")
+if wanted("b_pinned"):
+    # a segment's tile of B is segment % col_tiles, its block of the result
+    # segment // col_tiles: with the remainder struck every chunk walks its
+    # own lanes into its own block over ONE resident tile, fetched once —
+    # the steps, the lanes and the result's writes of the shipped walk,
+    # none of B's 12 × 2 GiB (31.5 ms at the HBM's rate, if nothing hid it)
+    timed(plan, (segment - segment % plan.col_tiles, count, packed, vals),
+          "b_pinned")
 if wanted("grouped_only"):
     grouped = count >> 16
     timed(plan, (segment, grouped | grouped << 16, packed, vals),

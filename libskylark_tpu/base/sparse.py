@@ -800,7 +800,11 @@ def product_operands(A: SparseMatrix, k: int, dtype,
     eff = A._device_dtype_of(dtype)
     kernel, plan = product_kernel(A.shape, k, nnz_class, eff,
                                   rowwise=not transposed)
-    attrs = {"kernel": kernel, "nnz": A.nnz, "nnz_class": nnz_class}
+    # "kernel_view": the product leaves the Mosaic call in the kernel's view
+    # and XLA relays it into rows (pallas_spmm.tiles_apply)
+    attrs = {"kernel": kernel, "nnz": A.nnz, "nnz_class": nnz_class,
+             "result_layout": ("rows" if plan is None or plan.stride > 1
+                               else "kernel_view")}
     if transposed:
         attrs["side"] = side
     if plan is None:
@@ -856,9 +860,10 @@ def spmm_t(A: SparseMatrix, B) -> jax.Array:
     rows) for the Pallas walk where ``sparse_serve.product_kernel(...,
     rowwise=False)`` finds the shapes (a TPU, float32, k a multiple of 128
     up to 2048), else the span loop. Workspace, whatever nnz is: the
-    kernel's VMEM blocks and the (w × k) relayout of its result, or the
-    span loop's ``_SPAN_LANES`` × k rows and one int32 a lane. Counts the
-    stored nonzeros under ``sparse.spmm_nnz`` with ``side="transposed"``."""
+    kernel's VMEM blocks (and, at a k that is no multiple of 1024, the
+    (w × k) relayout of its result), or the span loop's ``_SPAN_LANES`` × k
+    rows and one int32 a lane. Counts the stored nonzeros under
+    ``sparse.spmm_nnz`` with ``side="transposed"``."""
     B = jnp.asarray(B)
     squeeze = B.ndim == 1
     if squeeze:

@@ -333,9 +333,9 @@ class DenseTransform(OperatorCache, SketchTransform):
             A, self._S, A.device_dtype, side)
         key_data = self._alloc.key_data
         scale = self._device_scale()
-        with _trace.span("sketch.dispatch",
-                         {"path": "sparse", "family": self.sketch_type,
-                          "s": self._S, **attrs}):
+        with _trace.span("sketch.dispatch", {
+                "path": "sparse", "family": self.sketch_type, "s": self._S,
+                **attrs, "operator_view": "kernel" if plan else "rows"}):
             out = _sparse_program(side)(
                 key_data, scale, *lanes, dist=self.dist,
                 s_dim=self._S, shape=A.shape, kernel=kernel, plan=plan)

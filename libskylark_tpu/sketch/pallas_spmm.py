@@ -103,11 +103,28 @@ across runs: float32 arithmetic, each stored nonzero once, not the
 row-major walk's bits. Every block of result rows streams all of B once,
 so the transposed plan's blocks are twice as tall (4096 rows at k ≤ 1024).
 
-Workspace. VMEM: 2 · (row_block + col_tile) rows of max(k, 1024) floats
-(32 MiB at k = 1024; 48 MiB under the transposed plan's 4096-row blocks).
-HBM, beside the operands and the result: none in the kernel;
-:func:`tiles_apply` adds the (rows, k) relayout of the result's (rows,
-k/128, 128) view. Nothing grows with nnz · k.
+The door. An array that crosses a Mosaic call in another tiling than its
+other owner's is a silent copy by XLA, so each crosses in the layout of the
+side that is NOT the kernel. B comes in the kernel's view where its maker
+can write it so (``sparse_serve.operator_rows_panels(..., lanes=128)``: Sᵀ
+of both dense sketches; a supplied (n, k) factor is relaid, a copy of B).
+The result, under the flat views (k a multiple of 1024), leaves as (rows, k)
+rows: the walk accumulates in a VMEM scratch block of the flat view, the
+call's output block is the plain (row_block, k) one, and the last chunk of
+a row block hands the block over, one sublane-strided load and one store a
+result register (``_hand_over``; 262,144 pairs at 262144 × 1024) — the
+call's output is the product, ragged last block clipped, and nothing relays
+it. At the other widths a row is part of a register behind a leading index
+and such a load would gather single sublanes: the result keeps the
+kernel's view and :func:`tiles_apply` reshapes it (at k = 128 the two
+layouts are the same bytes; from 256 to 896 XLA copies the result).
+
+Workspace. VMEM: 2 · (row_block + col_tile) rows of max(k, 1024) floats, and
+row_block more for the scratch block under the flat views (39.4 MiB at
+k = 1024; 64 MiB under the transposed plan's 4096-row blocks). HBM, beside
+the operands and the result: none under the flat views; at the other
+widths :func:`tiles_apply` adds the (rows, k) relayout of the result's
+(rows, k/128, 128) view. Nothing grows with nnz · k.
 """
 
 from __future__ import annotations
@@ -264,24 +281,61 @@ def tiles_plan(shape: tuple, k: int, lanes: int, dtype,
 
 
 def vmem_bytes(plan: TilesPlan) -> int:
-    """The VMEM the pipeline holds: two buffers each of the result block
-    and of B's tile (a row under 8 sublanes is padded to 8)."""
+    """The VMEM the call holds: two buffers each of the result block and of
+    B's tile (a row under 8 sublanes is padded to 8) and, under the flat
+    views, the scratch block the walk accumulates in."""
     row = _round_up(plan.k_tiles, 8) * LANES * 4
-    return 2 * (plan.row_block + plan.col_tile) * row
+    return (2 * (plan.row_block + plan.col_tile)
+            + plan.row_block * (plan.stride > 1)) * row
 
 
 def _kernel_tiles(col_tiles, group, stride, runs, segment, count, packed_ref,
-                  vals_ref, b_ref, out_ref):
+                  vals_ref, b_ref, rows_ref, *scratch):
     """One grid step: the stored lanes of one chunk into their row block,
     the chunk's grouped slots ``group`` at a time, the rest one by one —
-    or, under ``runs``, ``group`` slots of one row at a time."""
+    or, under ``runs``, ``group`` slots of one row at a time. Under the
+    flat views (``stride`` > 1) the block is a VMEM scratch, and the row
+    block's last chunk hands it over to ``rows_ref`` as (row_block, k)
+    rows (:func:`_hand_over`); else ``rows_ref`` is the block itself."""
     t = pl.program_id(0)
     block = segment[t] // col_tiles
     first = (t == 0) | (block != segment[jnp.maximum(t - 1, 0)] // col_tiles)
+    out_ref = scratch[0] if scratch else rows_ref
 
     @pl.when(first)
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
+
+    _walk(group, stride, runs, count[t], packed_ref, vals_ref, b_ref, out_ref)
+    if scratch:
+        # the tables' segments never fall, so the next chunk is another
+        # block's as soon as it is past this block's tiles: no divide a step
+        end = pl.num_programs(0) - 1
+        last = (t == end) | (segment[jnp.minimum(t + 1, end)]
+                             >= (block + 1) * col_tiles)
+        pl.when(last)(lambda: _hand_over(stride, out_ref, rows_ref))
+
+
+def _hand_over(stride, acc_ref, rows_ref):
+    """A finished block out of the walk's flat view (row_block · stride,
+    128) into the call's own (row_block, k) block: register h of rows
+    8g … 8g + 7 is the sublanes 8g · stride + h, + stride, … of the flat
+    view — one strided load and one store a result register. Movement
+    only; what leaves the call is laid as XLA lays a (rows, k) array, so
+    nothing relays it."""
+    def eight_rows(g, carry):
+        at = pl.multiple_of(g * 8, 8)
+        for h in range(stride):
+            rows_ref[pl.ds(at, 8), h * LANES:(h + 1) * LANES] = acc_ref[
+                pl.ds(at * stride + h, 8, stride=stride), :]
+        return carry
+
+    jax.lax.fori_loop(0, rows_ref.shape[0] // 8, eight_rows, 0)
+
+
+def _walk(group, stride, runs, counts, packed_ref, vals_ref, b_ref, out_ref):
+    """A chunk's stored lanes (``counts``: its entry of the count table)
+    into the block ``out_ref``, in the walk's view."""
 
     def rows(word):
         """A lane's row of the result block and its row of B's tile."""
@@ -321,7 +375,7 @@ def _kernel_tiles(col_tiles, group, stride, runs, segment, count, packed_ref,
         ahead(packed_ref, vals_ref, g * group, 1)
         return carry
 
-    n, grouped = count[t] & 0xFFFF, count[t] >> 16
+    n, grouped = counts & 0xFFFF, counts >> 16
     spans = 0
     if packed_ref.shape[-1] % _SPAN == 0 and _SPAN % group == 0:
         spans = grouped // _SPAN
@@ -375,15 +429,17 @@ def _kernel_tiles(col_tiles, group, stride, runs, segment, count, packed_ref,
     jax.lax.fori_loop(done + whole * _UNROLL, n, single, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("plan", "interpret"))
-def _tiles_call(segment, count, packed, vals, B, *, plan: TilesPlan,
-                interpret: bool):
-    """The walk over B (tiles · col_tile, k): the result in the kernel's
-    view, (blocks · row_block · k/128, 128) where ``plan.stride`` says flat,
-    else (blocks · row_block, k/128, 128)."""
+@functools.partial(jax.jit, static_argnames=("rows", "plan", "interpret"))
+def _tiles_call(segment, count, packed, vals, B, *, rows: int,
+                plan: TilesPlan, interpret: bool):
+    """The walk over B (tiles · col_tile, k). Where ``plan.stride`` says
+    flat, the result is (rows, k) as it stands — the blocks handed over as
+    rows, the last one clipped to ``rows`` —; else it is in the kernel's
+    view, (blocks · row_block, k/128, 128)."""
     slots = pl.BlockSpec((1, 1, plan.chunk), lambda t, seg, cnt: (t, 0, 0),
                          memory_space=pltpu.SMEM)
     flat = plan.stride > 1
+    k = plan.k_tiles * LANES
 
     def view(rows):
         return ((rows * plan.k_tiles, LANES) if flat
@@ -402,10 +458,14 @@ def _tiles_call(segment, count, packed, vals, B, *, plan: TilesPlan,
                 slots, slots,
                 pl.BlockSpec(view(plan.col_tile), lambda t, seg, cnt:
                              at(seg[t] % plan.col_tiles))],
-            out_specs=pl.BlockSpec(view(plan.row_block), lambda t, seg, cnt:
-                                   at(seg[t] // plan.col_tiles))),
+            out_specs=pl.BlockSpec(
+                (plan.row_block, k) if flat else view(plan.row_block),
+                lambda t, seg, cnt: at(seg[t] // plan.col_tiles)),
+            scratch_shapes=([pltpu.VMEM(view(plan.row_block), jnp.float32)]
+                            if flat else [])),
         out_shape=jax.ShapeDtypeStruct(
-            view(plan.row_blocks * plan.row_block), jnp.float32),
+            (rows, k) if flat else view(plan.row_blocks * plan.row_block),
+            jnp.float32),
         # sequential: a row block's chunks follow each other and add up
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -422,12 +482,15 @@ def tiles_apply(segment, count, packed, vals, B, *, shape: tuple,
     column tiles reach is padded, a longer one handed over as it is: a
     slice would be a copy). B may come in the kernel's view, (≥ n, k/128,
     128): on a TPU the reshape of a (n, k) array to it is a copy of B.
-    Traceable."""
+    Where k is a multiple of 1024 (``plan.stride`` > 1) the result is the
+    Mosaic call's own output, rows as XLA lays them: nothing relays it.
+    At the other widths it leaves the call in the kernel's view and the
+    reshape to (rows, k) is a copy of it. Traceable."""
     rows = int(shape[0])
     k = plan.k_tiles * LANES
     n_pad = plan.col_tiles * plan.col_tile
     if B.shape[0] < n_pad:
         B = jnp.pad(B, ((0, n_pad - B.shape[0]),) + ((0, 0),) * (B.ndim - 1))
-    out = _tiles_call(segment, count, packed, vals, B, plan=plan,
+    out = _tiles_call(segment, count, packed, vals, B, rows=rows, plan=plan,
                       interpret=interpret)
-    return out.reshape(-1, k)[:rows]
+    return out if plan.stride > 1 else out.reshape(-1, k)[:rows]

@@ -306,18 +306,27 @@ def dense_sparse_apply(key_data, scale, *lanes, dist, s_dim: int,
     """One rowwise dense-family (JLT/CT) sketch of a sparse operand,
     ``X·Sᵀ`` (rows × s_dim): the program ``sketch.dense_sparse``, a pure
     function of the allocation's key words, the scale and the operand's
-    lanes. The operator is generated here (:func:`operator_rows`; for the
-    kernel straight at the width its column tiles pad N to) and the product
-    is :func:`product_lanes`, the body ``base.sparse.spmm`` runs.
+    lanes. The operator is generated here — for the kernel in the kernel's
+    view and in one panel (:func:`operator_rows_panels`: to the stream's
+    block past the width the column tiles pad N to, handed over unsliced),
+    else :func:`operator_rows` — and the product is :func:`product_lanes`,
+    the body ``base.sparse.spmm`` runs.
 
-    Workspace, whatever nnz is: the operator (N × s_dim values, twice while
-    it is laid out), the kernel's relayout of the result (rows × s_dim) or
-    the span loop's ``_SPAN_LANES`` × s_dim rows and one int32 a lane. At
-    262144 × 47236 and s_dim 1024 under the kernel: 1.5 GB."""
-    n = int(shape[1]) if plan is None else plan.col_tiles * plan.col_tile
-    Bt = operator_rows(key_data, scale, dist=dist, s_dim=s_dim, n=n,
-                       dtype=lanes[-1].dtype if plan is not None
-                       else lanes[0].dtype)
+    Workspace, whatever nnz is: under the kernel the operator (N × s_dim
+    values, once: 0.18 GiB at 47236 × 1024 — and, at an s_dim that is no
+    multiple of 1024, the relayout of the result, rows × s_dim); else the
+    operator twice while it is laid out, the span loop's ``_SPAN_LANES`` ×
+    s_dim rows and one int32 a lane."""
+    if plan is None:
+        Bt = operator_rows(key_data, scale, dist=dist, s_dim=s_dim,
+                           n=int(shape[1]), dtype=lanes[0].dtype)
+    else:
+        from libskylark_tpu.sketch.pallas_spmm import LANES
+
+        Bt = operator_rows_panels(
+            key_data, scale, dist=dist, s_dim=s_dim,
+            n=plan.col_tiles * plan.col_tile, dtype=lanes[-1].dtype,
+            lanes=LANES, panel_blocks=0)
     return product_lanes(*lanes, Bt, kernel=kernel, shape=shape, plan=plan)
 
 
@@ -335,9 +344,11 @@ def operator_rows_panels(key_data, scale, *, dist, s_dim: int, n: int,
     relayout and no second copy of one) and ``panel_blocks`` blocks at a
     time under one loop, so that beside the result the program holds one
     panel's cipher words (64 blocks: 16384 rows, 64 MiB a word array at
-    s_dim 1024), not the operator's. ``lanes`` = 128 gives the rows in the
-    kernel's view, (rows, s_dim / 128, 128) — on a TPU a (rows, s_dim) array
-    is tiled otherwise, and handing one to the kernel is a copy of it."""
+    s_dim 1024), not the operator's; ``panel_blocks`` = 0 is one panel of
+    them all, for a short axis (the features'). ``lanes`` = 128 gives the
+    rows in the kernel's view, (rows, s_dim / 128, 128) — on a TPU a (rows,
+    s_dim) array is tiled otherwise, and handing one to the kernel is a
+    copy of it."""
     import jax.random as jr
 
     from libskylark_tpu.sketch.dense import BLOCK_COLS
@@ -366,9 +377,9 @@ def dense_sparse_apply_cw(key_data, scale, *lanes, dist, s_dim: int,
     :func:`product_lanes` over Xᵀ.
 
     Workspace, whatever nnz is: Sᵀ once (m × s_dim values, 2 GiB at 524288
-    × 1024), one generation panel, the product's (n × s_dim) result and its
-    relayout, or the span loop's ``_SPAN_LANES`` × s_dim rows and one int32
-    a lane."""
+    × 1024), one generation panel, the product's (n × s_dim) result — the
+    sketch's own bytes where s_dim is a multiple of 1024, else relaid —, or
+    the span loop's ``_SPAN_LANES`` × s_dim rows and one int32 a lane."""
     from libskylark_tpu.sketch.pallas_spmm import LANES
 
     m, n = int(shape[0]), int(shape[1])

@@ -235,7 +235,11 @@ SPANS: Dict[str, Tuple[str, str]] = {
     # holds at least TilesPlan.cover stored lanes, so that its walk outlasts
     # the copy of the next segment's tile of B: ÷ segments, how often the
     # chunk's size hides that copy; counted once at placement, an apply
-    # reads the stored integer); since PR 61 the columnwise apply
+    # reads the stored integer) and, since PR 62, how the arrays crossed the
+    # Mosaic call's door: result_layout ("rows": the call's own (rows, s)
+    # output, s a multiple of 1024 — and the XLA route — | "kernel_view":
+    # relaid by XLA) and operator_view ("kernel": Sᵀ generated in the
+    # kernel's view | "rows": the XLA route); since PR 61 the columnwise apply
     # (_apply_columnwise_sparse, the sketch.dense_sparse_cw program) opens
     # the same span with side="transposed", kernel "pallas_runs" | "xla:
     # <why>", the blocks those of the transposed side (row_block A's

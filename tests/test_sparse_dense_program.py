@@ -19,6 +19,8 @@ Oracles:
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -122,6 +124,25 @@ class TestAgainstTheOracles:
         assert rows.shape == (N + 299, S)
         assert np.array_equal(np.asarray(rows[:N]),
                               np.asarray(T.s_panel(0, N)).T)
+
+    @pytest.mark.parametrize("panel_blocks", [0, 2, 64])
+    def test_operator_in_the_kernels_view_is_the_same_operator(
+            self, fresh, family, kwargs, panel_blocks):
+        """What the kernel's program generates — the rows in the kernel's
+        view, one panel of all the blocks (0) or several — against
+        ``operator_rows`` at a padded n that ends inside a block: the same
+        entries to the bit, and whole blocks (the rows past n are the
+        stream's next entries)."""
+        T = family(N, S, Context(SEED), **kwargs)
+        said = dict(dist=T.dist, s_dim=S, n=N + 299, dtype=jnp.float32)
+        rows = sparse_serve.operator_rows(T.allocation.key_data, T.scale,
+                                          **said)
+        view = sparse_serve.operator_rows_panels(
+            T.allocation.key_data, T.scale, lanes=pallas_spmm.LANES,
+            panel_blocks=panel_blocks, **said)
+        assert view.shape == (6 * 256, S // 128, 128)
+        assert np.array_equal(np.asarray(view).reshape(-1, S)[:N + 299],
+                              np.asarray(rows))
 
 
 class TestJLTAgainstThePlainReference:
@@ -543,6 +564,140 @@ class TestThePlacement:
                     f"xla: backend {jax.default_backend()}", None)
 
 
+def kernel_view_product(placed, B, plan, rows: int) -> np.ndarray:
+    """The product as it left the call before the hand-over: the same
+    kernel accumulating straight into the pipeline's output block in the
+    flat view (rows · k/128, 128), and XLA's ``reshape(-1, k)`` after it."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    assert plan.stride > 1
+
+    def view(r):
+        return (r * plan.k_tiles, pallas_spmm.LANES)
+
+    slots = pl.BlockSpec((1, 1, plan.chunk), lambda t, seg, cnt: (t, 0, 0),
+                         memory_space=pltpu.SMEM)
+    flat = pl.pallas_call(
+        functools.partial(pallas_spmm._kernel_tiles, plan.col_tiles,
+                          plan.group, plan.stride, plan.runs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(plan.n_chunks,),
+            in_specs=[slots, slots,
+                      pl.BlockSpec(view(plan.col_tile), lambda t, seg, cnt:
+                                   (seg[t] % plan.col_tiles, 0))],
+            out_specs=pl.BlockSpec(view(plan.row_block), lambda t, seg, cnt:
+                                   (seg[t] // plan.col_tiles, 0))),
+        out_shape=jax.ShapeDtypeStruct(
+            view(plan.row_blocks * plan.row_block), jnp.float32),
+        interpret=True)(*placed, B.reshape(view(B.shape[0])))
+    return np.asarray(flat).reshape(-1, plan.k_tiles * pallas_spmm.LANES)[:rows]
+
+
+def hollow(rows: int = 128) -> sp.csr_matrix:
+    """``operand`` with the rows of its second and of its last block of 32
+    emptied: each of those blocks owns one chunk, an empty one."""
+    X = operand(rows=rows).tolil()
+    X[32:64] = 0
+    X[96:] = 0
+    return X.tocsr().astype(np.float32)
+
+
+class TestTheHandOver:
+    """Where a row of the blocks is whole vector registers (k a multiple of
+    1024) the walk accumulates in a scratch block and a row block's last
+    chunk hands it over as (row_block, k) rows: movement only, so the
+    result is, to the bit, what the call gave in the kernel's view."""
+
+    CASES = {
+        # name: (operand, side, k_tiles, chunk, group)
+        "tiles_1024": (operand, "rows", 8, 48, 8),
+        "tiles_2048": (operand, "rows", 16, 64, 8),
+        "tiles_whole_blocks": (lambda: operand(rows=64), "rows", 8, 48, 4),
+        "tiles_empty_blocks": (hollow, "rows", 8, 48, 8),
+        "runs_1024": (lambda: operand(n=211), "transposed", 8, 24, 8),
+        "runs_2048": (lambda: operand(n=211), "transposed", 16, 64, 4),
+        "runs_empty_blocks": (lambda: hollow().T.tocsr(), "transposed", 8,
+                              24, 8),
+    }
+
+    @pytest.mark.parametrize("name", list(CASES))
+    def test_the_rows_are_the_kernel_views_to_the_bit(self, fresh, name):
+        make, side, k_tiles, chunk, group = self.CASES[name]
+        X = make()
+        A = SparseMatrix.from_scipy(X)
+        runs = side == "transposed"
+        rows, n = X.shape[::-1] if runs else X.shape
+        blocks, tiles = -(-rows // 32), -(-n // 32)
+        plan = pallas_spmm.TilesPlan(
+            32, 32, chunk, k_tiles, blocks, tiles,
+            4 * -(-X.nnz // chunk) + 8 * blocks * tiles, group, runs)
+        assert plan.stride == k_tiles
+        placed = A.tiled_device(plan.layout, side=side)
+        stored = np.asarray(placed[1]) & 0xFFFF
+        assert stored.sum() >= X.nnz            # (a run's padding counts)
+        owned = np.asarray(placed[0]) // tiles
+        if "empty" in name:
+            # an emptied block owns one chunk, an empty one (the last
+            # block's is repeated to the end of the tables)
+            assert not stored[(owned == 1) | (owned == blocks - 1)].any()
+            assert (owned == 1).sum() == 1
+        else:
+            assert rows % 32 or name == "tiles_whole_blocks"
+        B = jnp.asarray(np.random.default_rng(5).standard_normal(
+            (tiles * 32 + 5, 128 * k_tiles)).astype(np.float32))
+        got = pallas_spmm.tiles_apply(*placed, B, shape=(rows, n), plan=plan,
+                                      interpret=True)
+        assert got.shape == (rows, 128 * k_tiles)
+        want = kernel_view_product(placed, B, plan, rows)
+        assert np.array_equal(np.asarray(got), want)
+        dense = X.toarray().T if runs else X.toarray()
+        exact = dense.astype(np.float64) @ np.asarray(B, np.float64)[:n]
+        assert np.abs(want - exact).max() <= 1e-5 * np.abs(exact).max()
+        if "empty" in name:
+            assert not want[32:64].any() and not want[96:].any()
+
+    def test_the_other_widths_keep_the_kernels_view(self, fresh):
+        """At a width whose row is part of a register behind a leading
+        index (``stride`` 1) the call's result stays in the kernel's view,
+        (rows, k/128, 128), and ``tiles_apply`` reshapes it."""
+        X = operand()
+        A = SparseMatrix.from_scipy(X)
+        plan = TestThePlacement.tiles_plan(X, 32, 48, 8, 3)
+        assert plan.stride == 1
+        placed = A.tiled_device(plan.layout)
+        B = jnp.asarray(np.random.default_rng(5).standard_normal(
+            (plan.col_tiles * 32, 384)).astype(np.float32))
+        out = pallas_spmm._tiles_call(*placed, B, rows=ROWS, plan=plan,
+                                      interpret=True)
+        assert out.shape == (plan.row_blocks * 32, 3, 128)
+        got = pallas_spmm.tiles_apply(*placed, B, shape=A.shape, plan=plan,
+                                      interpret=True)
+        assert np.array_equal(np.asarray(got),
+                              np.asarray(out).reshape(-1, 384)[:ROWS])
+
+    def test_vmem_counts_the_scratch_block(self):
+        flat = TestThePlacement.tiles_plan(operand(), 32, 48, 8, 8)
+        view = TestThePlacement.tiles_plan(operand(), 32, 48, 8, 4)
+        row = 8 * 128 * 4       # a row under 8 sublanes is padded to 8
+        assert pallas_spmm.vmem_bytes(flat) == (2 * (32 + 32) + 32) * row
+        assert pallas_spmm.vmem_bytes(view) == 2 * (32 + 32) * row
+
+    @pytest.mark.parametrize("family,kwargs", FAMILIES)
+    def test_the_sketch_at_a_handed_over_width(self, fresh, route, family,
+                                                kwargs):
+        """s = 1024 through the whole program: the operator generated in
+        the kernel's view, the result the call's own output, 77 rows under
+        blocks of 32 — against the densified apply."""
+        T = family(N, 1024, Context(SEED), **kwargs)
+        X = operand()
+        got = np.asarray(T.apply(SparseMatrix.from_scipy(X), sk.ROWWISE))
+        want = np.asarray(T.apply(jnp.asarray(X.toarray()), sk.ROWWISE))
+        assert got.shape == want.shape == (ROWS, 1024)
+        assert np.abs(got - want).max() <= TOL * np.abs(want).max()
+        assert not got[3].any()
+
+
 class TestSpansAndCounters:
     def test_one_span_one_handover_and_the_labels(self, fresh, route):
         metrics._ENABLED = True
@@ -563,6 +718,9 @@ class TestSpansAndCounters:
         assert attrs["lane_slots"] >= attrs["nnz"]
         if route == "pallas_tiles":
             assert attrs["kernel"] == "pallas_tiles"
+            # s = 256: a row is part of a register, the result is relaid
+            assert attrs["result_layout"] == "kernel_view"
+            assert attrs["operator_view"] == "kernel"
             assert attrs["segments"] == 3 * 37 and attrs["chunk"] == 64
             plan = pallas_spmm.tiles_plan(A.shape, S, attrs["nnz_class"],
                                           jnp.float32)[0]
@@ -575,6 +733,7 @@ class TestSpansAndCounters:
             assert 0 < attrs["covered_segments"] < attrs["segments"]
         else:
             assert attrs["kernel"] == f"xla: backend {jax.default_backend()}"
+            assert attrs["result_layout"] == attrs["operator_view"] == "rows"
             assert attrs["segments"] == 1 and "grouped_lanes" not in attrs
             assert "covered_segments" not in attrs
         assert len([s for s in spans if s.name == HANDOVER[0]]) == 2
@@ -584,6 +743,26 @@ class TestSpansAndCounters:
         after = _counter("sketch.sparse_nnz")
         key = (("family", "JLT"), ("kernel", attrs["kernel"]))
         assert after.get(key, 0) - before.get(key, 0) == 2 * X.nnz
+
+    @pytest.mark.parametrize("s_dim,layout", [(1024, "rows"), (2048, "rows"),
+                                              (384, "kernel_view")])
+    def test_the_span_says_how_the_arrays_crossed(self, fresh, route, s_dim,
+                                                  layout):
+        """``result_layout``: the call's own (rows, k) output where k is a
+        multiple of 1024, else the kernel's view relaid by XLA;
+        ``operator_view``: Sᵀ generated in the kernel's view whenever the
+        kernel runs. The span loop knows rows only."""
+        A = SparseMatrix.from_scipy(operand())
+        T = sk.JLT(N, s_dim, Context(SEED))
+        metrics._ENABLED = True
+        T.apply(A, sk.ROWWISE).block_until_ready()
+        (dispatch,) = [s for s in trace.finished_spans()
+                       if s.name == "sketch.dispatch"]
+        kernel = route == "pallas_tiles"
+        assert dispatch.attrs["result_layout"] == (layout if kernel
+                                                   else "rows")
+        assert dispatch.attrs["operator_view"] == ("kernel" if kernel
+                                                   else "rows")
 
     def test_spmm_counts_under_its_own_name(self, fresh, route):
         X = operand()

@@ -411,11 +411,15 @@ class TestDeclines:
         assert attrs["lane_slots"] >= attrs["nnz"]
         if route == "pallas_runs":
             assert attrs["kernel"] == "pallas_runs"
+            # s = 128: a row is part of a register, the result is relaid
+            assert attrs["result_layout"] == "kernel_view"
+            assert attrs["operator_view"] == "kernel"
             assert attrs["grouped_lanes"] + attrs["run_lanes"] == X.nnz
             assert attrs["run_slots"] >= attrs["run_lanes"]
             assert attrs["segments"] == -(-N // 64) * -(-M // 32)
         else:
             assert attrs["kernel"] == f"xla: backend {jax.default_backend()}"
+            assert attrs["result_layout"] == attrs["operator_view"] == "rows"
             assert attrs["segments"] == 1 and "run_lanes" not in attrs
         assert not [s for s in spans if s.name == "sparse.place"]
         assert [p["handovers"] for p in trace.apply_periods("sketch.apply")] \
@@ -430,6 +434,40 @@ class TestDeclines:
         (key,) = [k for k in after if after[k] != before.get(k, 0)]
         assert dict(key) == {"kernel": attrs["kernel"], "side": "transposed"}
         assert after[key] - before.get(key, 0) == X.nnz
+
+
+class TestTheHandedOverWidths:
+    """s a multiple of 1024: the runs walk accumulates in a scratch block
+    and hands each finished block of 64 features over as rows — 211
+    features, so the last block is clipped —; the result is the Mosaic
+    call's own output (``tests/test_sparse_dense_program.py``
+    ``TestTheHandOver`` holds it to the kernel's view bit by bit)."""
+
+    @pytest.mark.parametrize("s_dim", [1024, 2048])
+    def test_the_sketch_and_its_span(self, fresh, route, s_dim):
+        metrics._ENABLED = True
+        X = operand()
+        T = sk.JLT(M, s_dim, Context(SEED))
+        got = np.asarray(T.apply(SparseMatrix.from_scipy(X), sk.COLUMNWISE))
+        want = np.asarray(T.apply(jnp.asarray(X.toarray()), sk.COLUMNWISE))
+        assert got.shape == want.shape == (s_dim, N)
+        assert np.abs(got - want).max() <= TOL * np.abs(want).max()
+        assert not got[:, N - 1].any()          # the feature nobody holds
+        (dispatch,) = [s for s in trace.finished_spans()
+                       if s.name == "sketch.dispatch"
+                       and s.attrs.get("path") == "sparse"]
+        assert dispatch.attrs["result_layout"] == "rows"
+        assert dispatch.attrs["operator_view"] == (
+            "kernel" if route == "pallas_runs" else "rows")
+
+    def test_spmm_t_with_a_supplied_factor(self, fresh, route):
+        X = operand()
+        B = np.random.default_rng(3).standard_normal(
+            (M, 1024)).astype(np.float32)
+        got = np.asarray(spmm_t(SparseMatrix.from_scipy(X), B))
+        want = X.toarray().T.astype(np.float64) @ B.astype(np.float64)
+        assert got.shape == (N, 1024)
+        assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
 
 
 def _counter(name: str) -> dict:

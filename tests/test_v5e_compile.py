@@ -969,11 +969,23 @@ def _sparse_arg(one_chip):
                                                      sharding=one_chip)
 
 
+def _makers(text: str, shape: str) -> list:
+    """The opcodes of the compiled program's entry computation that
+    produce an array of ``shape``: a ``custom-call`` is the Mosaic call, a
+    ``bitcast`` moves nothing; anything else (``reshape``, ``copy``, a
+    fusion) reads and writes the array."""
+    entry = text[text.index("\nENTRY "):]
+    return re.findall(rf" = {re.escape(shape)}\S* ([\w\-]+)\(", entry)
+
+
 def test_cell_shape_sparse_dense_program(one_chip, monkeypatch):
     """The whole ``sketch.dense_sparse`` program of the jlt_sparse_apply
-    cell under the tiles kernel: the operator generated in the program, ONE
-    Mosaic call, no array of nnz × s anywhere, and under 3 GB of
-    temporaries (the operator, its layout, the result's relayout)."""
+    cell under the tiles kernel: the operator generated in the program in
+    the kernel's view, ONE Mosaic call whose (rows, s) output is the
+    program's result as it stands — no relayout of the result or of the
+    operator on either side of the call, no array of nnz × s anywhere —
+    and under 0.5 GiB of temporaries (the operator and its cipher words;
+    3 GiB held the parent's relayouts)."""
     from libskylark_tpu.sketch import pallas_spmm
 
     # off the TPU the program would interpret the kernel
@@ -983,7 +995,9 @@ def test_cell_shape_sparse_dense_program(one_chip, monkeypatch):
     assert kernel == "pallas_tiles"
     assert plan == pallas_spmm.TilesPlan(2048, 1976, 4096, 8, 128, 24, 7936,
                                          8)
-    assert pallas_spmm.vmem_bytes(plan) == 2 * (2048 + 1976) * 4096
+    # two buffers each of the result's block and of B's tile, and the
+    # scratch block the walk accumulates in: 39.4 MiB
+    assert pallas_spmm.vmem_bytes(plan) == (3 * 2048 + 2 * 1976) * 4096
     arg = _sparse_arg(one_chip)
     program = jax.jit(functools.partial(
         sparse_serve.dense_sparse_apply, dist=randgen.Normal(),
@@ -998,9 +1012,21 @@ def test_cell_shape_sparse_dense_program(one_chip, monkeypatch):
     assert text.count(KERNEL) == 1
     assert not re.search(r"\b(sort|scatter)\(", text)
     assert f"f32[{SPARSE_LANES},{SPARSE_S}]" not in text
+    result = f"f32[{SPARSE_ROWS},{SPARSE_S}]"
+    (call,) = [line for line in text.splitlines() if KERNEL in line]
+    assert re.search(rf"ROOT \S+ = {re.escape(result)}\S* custom-call\(",
+                     call), call
+    assert _makers(text, result) == ["custom-call"]
+    # the operator — the padded N to the stream's block, 186 blocks of 256
+    # rows — reaches the call through a bitcast of what generated it, in no
+    # (rows, s) tiling
+    rows = -(-plan.col_tiles * plan.col_tile // BLOCK_COLS) * BLOCK_COLS
+    assert _makers(text, f"f32[{rows * plan.k_tiles},128]") == ["bitcast"]
+    assert f"f32[{rows},{SPARSE_S}]" not in text
+    assert f"f32[{plan.col_tiles * plan.col_tile},{SPARSE_S}]" not in text
     memory = compiled.memory_analysis()
     assert memory.output_size_in_bytes == SPARSE_ROWS * SPARSE_S * 4
-    assert memory.temp_size_in_bytes < 3 << 30, memory
+    assert memory.temp_size_in_bytes < 1 << 29, memory
 
 
 @pytest.mark.parametrize("k,lanes,chunk", [
@@ -1030,7 +1056,13 @@ def test_sparse_dense_walk_at_the_plans_other_widths(one_chip, k, lanes,
             arg((plan.n_chunks,), jnp.int32), arg((plan.n_chunks,), jnp.int32),
             arg(slots, jnp.int32), arg(slots, jnp.float32),
             arg((plan.col_tiles * plan.col_tile, k), jnp.float32)).compile()
-    assert compiled.as_text().count(KERNEL) == 1
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 1
+    # k = 2048 hands its blocks over as rows, as the cells' 1024 does; at
+    # k = 128 the result leaves in the kernel's view, whose bytes are the
+    # rows' (at the widths between, 256 to 896, XLA relays it)
+    assert _makers(text, f"f32[{SPARSE_ROWS},{k}]") == [
+        "bitcast" if k == 128 else "custom-call"]
 
 
 def test_cell_shape_sparse_product_replaced_the_whole_gather(one_chip):
@@ -1077,9 +1109,11 @@ SPARSE_CW_ROWS, SPARSE_CW_LANES = 524288, 39845888
 def test_cell_shape_sparse_dense_cw_program(one_chip, monkeypatch):
     """The whole ``sketch.dense_sparse_cw`` program of the
     jlt_sparse_apply_cw cell under the runs walk: Sᵀ (524288 × 1024, 2 GiB)
-    generated in the program a panel at a time, ONE Mosaic call, no array
-    of nnz × s and no second copy of the operator anywhere: under 3.5 GB
-    of temporaries (the operator, the result's relayout and transpose)."""
+    generated in the program a panel at a time, ONE Mosaic call whose
+    (features, s) output is the transposed result's bytes, no array of
+    nnz × s and no second copy of the operator anywhere: under 2.3 GB of
+    temporaries (the operator; 2.55 GB with the parent's relayout of the
+    result)."""
     from libskylark_tpu.sketch import pallas_spmm
 
     monkeypatch.setattr(sparse_serve, "_compiles_mosaic", lambda: True)
@@ -1089,7 +1123,8 @@ def test_cell_shape_sparse_dense_cw_program(one_chip, monkeypatch):
     assert kernel == "pallas_runs"
     assert plan == pallas_spmm.TilesPlan(4096, 2048, 8192, 8, 12, 256, 13136,
                                          8, True)
-    assert pallas_spmm.vmem_bytes(plan) == 2 * (4096 + 2048) * 4096
+    # + the scratch block of 4096 rows: 64 MiB, and 16 MiB of slack
+    assert pallas_spmm.vmem_bytes(plan) == (3 * 4096 + 2 * 2048) * 4096
     arg = _sparse_arg(one_chip)
     program = jax.jit(functools.partial(
         sparse_serve.dense_sparse_apply_cw, dist=randgen.Normal(),
@@ -1103,10 +1138,15 @@ def test_cell_shape_sparse_dense_cw_program(one_chip, monkeypatch):
     assert text.count(KERNEL) == 1
     assert not re.search(r"\b(sort|scatter)\(", text)
     assert f"f32[{SPARSE_CW_LANES},{SPARSE_S}]" not in text
+    # the call's (features, s) output is the transposed result's bytes: no
+    # relayout of it, and none of the operator (m, s) before the call
+    assert _makers(text, f"f32[{SPARSE_N},{SPARSE_S}]") == ["custom-call"]
+    assert _makers(text, f"f32[{SPARSE_S},{SPARSE_N}]") == ["bitcast"]
+    assert not _makers(text, f"f32[{SPARSE_CW_ROWS},{SPARSE_S}]")
     memory = compiled.memory_analysis()
     # the result's minor extent is laid out to a multiple of 8
     assert memory.output_size_in_bytes == SPARSE_S * (-(-SPARSE_N // 8) * 8) * 4
-    assert memory.temp_size_in_bytes < 3.5e9, memory
+    assert memory.temp_size_in_bytes < 2.3e9, memory
 
 
 def test_cell_shape_transposed_product_replaced_the_whole_gather(one_chip):
