@@ -75,9 +75,12 @@ METRICS: Dict[str, str] = {
     "sketch.fastfood_features": "counter",
     # the TensorSketch apply (sketch/ppt.py): examples featurized, by
     # family and route ("program" = the one compiled program of
-    # ppt.tensorsketch_features | "chain" = the eager chain of a declined
-    # operand) — rows × S × (q + 1) of them are the elements that
-    # conv_rate.apply reads from its sketch.dispatch spans
+    # ppt.tensorsketch_features, with the label radix = the bucket classes
+    # its spectral products were formed by: "1" the whole product — shapes
+    # with no classes, or a transform one of whose classes overflowed its
+    # rows — | "chain" = the eager chain of a declined operand, no radix)
+    # — rows × S × (q + 1) of them are the elements that conv_rate.apply
+    # reads from its sketch.dispatch spans
     "sketch.tensorsketch_rows": "counter",
     # the compiled FJLT/wht apply (sketch/fjlt.py): operand entries sign-
     # and Hadamard-mixed (transform axis × free axis), by family and kernel

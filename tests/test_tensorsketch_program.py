@@ -7,6 +7,8 @@ cellbench/references/tensorsketch_features.py and against the eager chain
   do not divide the rows, against both;
 - the tensor-power statement of the definition (the CountSketch of x'^{⊗q});
 - the spectral product over a packed K alone, against a float64 product;
+- the products formed by bucket class (PR 63): every radix, both ways, each
+  degree, against the chain; the edge bins; an overfull class;
 - the lower-precision controls fail the tolerance the sound program holds;
 - each broken variant of the map — a sketch dropped, a sketch shared, a
   truncated spectrum, the homogeneity term missing — fails the cell's check;
@@ -140,7 +142,7 @@ def test_program_is_the_parents_formula(d, s, q, rows, row_block):
     bias = ppt.spectral_operator(T._hash_idx(), jnp.float32(np.sqrt(1.3))
                                  * T._hash_val(jnp.float32), s)
     n1, n2 = ppt.split(s)
-    M1, Tc, Ts, M2 = ppt._inverse_factors(n1, n2)
+    M1, Tc, Ts, M2 = ppt._inverse_factors(n1, n2)[:4]
     first = jnp.arange(s // 2, dtype=jnp.int32)[None, :] == 0
     re = im = None
     for k in range(q):
@@ -287,6 +289,236 @@ def test_bf16_regime_reaches_the_program():
     assert _rel(low, sound) > 100 * REL
 
 
+# -- the spectral products formed by bucket class (PR 63) ---------------------
+
+# N no multiple of R, S = 512 … 4096 (splits 16·32, 32·32, 32·64, 64·64)
+CLASS_SHAPES = [(33, 512), (61, 1024), (130, 2048), (100, 4096)]
+
+
+def _by_class(T, X, radix, rowwise=True, **statics):
+    """The program at ``radix``, compiled as ``apply`` compiles it."""
+    import functools
+
+    return jax.jit(functools.partial(
+        ppt.tensorsketch_features, spec=_spec(T), rowwise=rowwise, radix=radix,
+        **statics))(T._alloc.key_data, X if rowwise else X.T)
+
+
+@pytest.mark.parametrize("q", [1, 2, 3])
+@pytest.mark.parametrize("rowwise", [True, False])
+@pytest.mark.parametrize("radix", [1, 2, 4, 8])
+def test_class_products_match_the_chain(radix, rowwise, q):
+    """The program at every radix, rowwise and columnwise, each degree: the
+    eager chain's features to the file's float32 tolerance — every bin still
+    the sum of all N (+ 1) terms, a bin one float32 addition a doubling of R
+    further from them. The shapes turn with the case so that each split and
+    each class capacity meets each radix."""
+    d, s = CLASS_SHAPES[(q + radix.bit_length()) % len(CLASS_SHAPES)]
+    T, X = _map(d, s, q), _examples(21, d)
+    out = _by_class(T, X, radix, rowwise, row_block=8)
+    want = T._sketch_columns(X.T)
+    assert out.shape == ((21, s) if rowwise else (s, 21))
+    assert _rel(out if rowwise else out.T, want.T) < REL
+
+
+@pytest.mark.parametrize("c", [0.0, 1.3])
+@pytest.mark.parametrize("radix", [2, 4, 8])
+def test_class_products_edge_bins(radix, c):
+    """A unit input in a bucket of each class (of each sketch in turn), so
+    that every spectrum is a single tone and every bin weighs the same: a
+    group's first bin at another weight than ½, a midpoint left out or at
+    the wrong angle is an error of 1/S of the largest entry, alone — and with
+    c = 0 no homogeneity term stands beside it."""
+    d, s, q = 130, 2048, 3
+    T = _map(d, s, q, c=c)
+    picked = []
+    for cwt in T._cwts:
+        h = np.asarray(cwt.bucket_indices())
+        picked += [int(np.flatnonzero(h % radix == p)[0]) for p in range(radix)]
+    X = jnp.zeros((len(picked), d), jnp.float32).at[
+        jnp.arange(len(picked)), jnp.asarray(picked)].set(1.0)
+    want = T._sketch_columns(X.T).T
+    assert _rel(_by_class(T, X, radix), want) < REL
+    # the controls: the midpoints dropped, the first bins at full weight
+    factors = ppt._inverse_factors
+
+    def without_midpoints(n1, n2, r=1):
+        M1, Tc, Ts, M2, mid = factors(n1, n2, r)
+        return M1, Tc, Ts, M2, 0.0 * mid
+
+    try:
+        ppt._inverse_factors = without_midpoints
+        assert _rel(_by_class(T, X, radix), want) > 100 * REL
+    finally:
+        ppt._inverse_factors = factors
+
+
+@pytest.mark.parametrize("radix", [2, 4, 8])
+def test_class_products_bf16_grade_stays_one_pass(radix):
+    """The ``"bf16"`` grade (the cell's ``program_bf16`` control) takes the
+    same classes with one term a product, K = c: a one-part error, two to
+    three orders above the tolerance."""
+    d, s, q = 130, 2048, 3
+    T, X = _map(d, s, q), _examples(21, d)
+    low = _by_class(T, X, radix, grade="bf16")
+    assert 100 * REL < _rel(low, T._sketch_columns(X.T).T) < 3e-2
+    assert ppt.k_tiles(d, "bf16", radix) == radix * -(-ppt.class_cols(d, radix) // 128)
+
+
+def test_class_slots_and_operator():
+    """Every input has one slot of its class's columns, in input order; a
+    pad column's row of the operator is zero; the 0/1 matrix orders a
+    block's columns as the operator's rows lie."""
+    rng = np.random.default_rng(5)
+    n, s, radix = 61, 1024, 4
+    h = jnp.asarray(rng.integers(0, s, n), jnp.int32)
+    v = jnp.asarray(rng.choice([-1.0, 1.0], n), jnp.float32)
+    cols = ppt.class_cols(n, radix)
+    slot = np.asarray(ppt.class_slots(h, radix))
+    assert len(set(slot)) == n and slot.min() >= 0
+    assert np.array_equal(slot // cols, np.asarray(h) % radix)
+    for p in range(radix):
+        mine = slot[np.asarray(h) % radix == p]
+        assert np.array_equal(mine, p * cols + np.arange(len(mine)))
+    W, order, mid = ppt.class_operator(h, v, s, radix)
+    assert len(W) == radix and all(len(w) == 2 for w in W)
+    assert all(part.shape == (6 * cols, s // (2 * radix))
+               and part.dtype == jnp.bfloat16 for w in W for part in w)
+    assert order.shape == (n, radix * cols)
+    assert np.array_equal(np.asarray(order.astype(jnp.float32)).argmax(1), slot)
+    assert float(order.astype(jnp.float32).sum()) == n
+    free = np.setdiff1d(np.arange(radix * cols), slot)
+    # the hi·hi term's rows are the last c of a packed operator
+    hi = np.concatenate([np.asarray(w[0][-cols:].astype(jnp.float32)) for w in W])
+    assert np.all(hi[free] == 0.0) and np.all(np.abs(hi[slot]).max(1) > 0.0)
+    # the midpoints' operator: ±v on an input's own class, by its bucket's
+    # next bit
+    want = np.zeros((n, radix), np.float32)
+    want[np.arange(n), np.asarray(h) % radix] = np.asarray(v) * (
+        1 - 2 * ((np.asarray(h) // radix) & 1))
+    assert np.array_equal(np.asarray(mid), want)
+    # an input past its class's capacity has no slot (and no transform with
+    # one takes the classes: test_overfull_class_takes_radix_1)
+    crowded = np.asarray(ppt.class_slots(h // radix * radix, radix))
+    assert (crowded == -1).sum() == n - cols
+
+
+@pytest.mark.parametrize("n,radix,cols,tiles", [
+    (784, 1, 784, 37), (784, 2, 448, 42), (784, 4, 256, 48), (784, 8, 144, 56),
+    (130, 4, 64, 12), (33, 2, 40, 4), (440, 4, 168, 32)])
+def test_class_cols_and_k_tiles(n, radix, cols, tiles):
+    """N/R and four standard deviations, in sublanes, filled up to the MXU
+    tile the packed K ends in; R·⌈6c/128⌉ tiles, each over S/R columns — at
+    784 inputs 37 × S, 21 × S, 12 × S, 7 × S tile-columns a sketch."""
+    assert ppt.class_cols(n, radix) == cols and cols % 8 == 0
+    assert ppt.k_tiles(n, "float32", radix) == tiles == radix * -(-6 * cols // 128)
+    if radix > 1:
+        mean = n / radix
+        assert cols >= mean + 4 * np.sqrt(mean * (1 - 1 / radix))
+        assert -(-6 * (cols + 8) // 128) > tiles // radix   # the tile is full
+
+
+@pytest.mark.parametrize("n,s,want", [
+    (784, 16384, ppt._RADIX_MAX), (784, 1024, ppt._RADIX_MAX), (20, 16384, 1),
+    (33, 256, 1), (61, 1024, 1), (784, 514, 1), (784, 24, 1)])
+def test_radix_is_the_shapes(n, s, want):
+    """A function of N and S alone: 1 where a class would be mostly padding,
+    where S has no split or its N1 no whole groups."""
+    assert ppt.radix(n, s) == want
+
+
+def test_overfull_class_takes_radix_1(monkeypatch):
+    """A transform whose buckets crowd one class (forced: every bucket a
+    multiple of R) overflows ``class_cols``: it takes radix 1 — the whole
+    product, today's program to the bit, never a dropped input — and the
+    plan and the counter say so; the same shapes with the stream's own
+    buckets take the classes."""
+    d, s, q = 130, 2048, 3
+    R = ppt.radix(d, s)
+    assert R > 1
+    X = _examples(23, d)                 # a shape no other test compiles
+    sound = _map(d, s, q)
+    assert sound.radix() == R
+    plan = sound.features_plan(X, True)
+    assert (plan["radix"], plan["class_cols"], plan["k_tiles"]) == (
+        R, ppt.class_cols(d, R), ppt.k_tiles(d, "float32", R))
+    counted = ppt._ROWS.value(family="PPT", route="program", radix=str(R))
+    assert _rel(sound.apply(X, sk.ROWWISE), sound._sketch_columns(X.T).T) < REL
+    assert ppt._ROWS.value(family="PPT", route="program",
+                           radix=str(R)) == counted + 23
+
+    cwt = type(sound._cwts[0])
+    own = cwt.bucket_indices
+    monkeypatch.setattr(cwt, "bucket_indices", lambda self: own(self) // R * R)
+    T = _map(d, s, q)
+    assert T.radix() == 1
+    plan = T.features_plan(X, True)
+    assert (plan["radix"], plan["class_cols"], plan["k_tiles"]) == (
+        1, d, ppt.k_tiles(d))
+    counted = ppt._ROWS.value(family="PPT", route="program", radix="1")
+    out = T.apply(X, sk.ROWWISE)
+    assert ppt._ROWS.value(family="PPT", route="program", radix="1") == counted + 23
+    assert np.array_equal(np.asarray(out), np.asarray(_by_class(T, X, 1)))
+    assert _rel(out, T._sketch_columns(X.T).T) < REL
+
+
+@pytest.mark.parametrize("grade", ["float32", "bf16"])
+@pytest.mark.parametrize("d,s,q,rows,row_block", [WALKS[0], WALKS[5], WALKS[10]])
+def test_radix_1_is_the_parents_program(d, s, q, rows, row_block, grade,
+                                        monkeypatch):
+    """At radix 1 the block is PR 62's, kept here word for word
+    (``_parent_block``) and run in its place: the same bits, both grades."""
+    T, X = _map(d, s, q), _examples(rows, d)
+    out = _by_class(T, X, 1, row_block=row_block, grade=grade)
+
+    def parents(Xb, operators, factors, grade, radix):
+        return _parent_block(Xb, operators[:2], factors[:4], grade)
+
+    monkeypatch.setattr(ppt, "_block_features", parents)
+    parent = _by_class(T, X, 1, row_block=row_block, grade=grade)
+    assert np.array_equal(np.asarray(out), np.asarray(parent))
+
+
+def _parent_block(Xb, operators, factors, grade):
+    """``ppt._block_features`` of PR 62 (commit 04c4efe), word for word."""
+    W, bias = operators
+    M1, Tc, Ts, M2 = factors
+    n1, n2 = Tc.shape
+    B, s = Xb.shape[0], n1 * n2
+    first = jnp.arange(s // 2, dtype=jnp.int32)[None, :] == 0
+    x = jax.lax.optimization_barrier(ppt.packed(Xb, 0, grade))
+    re = im = nyquist = None
+    for k in range(len(W)):
+        F = jnp.dot(x, W[k], precision=jax.lax.Precision.DEFAULT,
+                    preferred_element_type=jnp.float32) + bias[k]
+        fre, fim = F[:, :s // 2], F[:, s // 2:]
+        if re is None:
+            re, im, nyquist = fre, fim, fim[:, 0]
+            continue
+        nyquist = nyquist * fim[:, 0]
+        both = im * fim
+        re, im = (re * fre - jnp.where(first, 0.0, both),
+                  jnp.where(first, both, re * fim + im * fre))
+    U = jax.lax.dynamic_update_slice(
+        jax.lax.empty((B, s), jnp.float32), jnp.where(first, 0.5 * re, re),
+        (0, 0))
+    U = jax.lax.dynamic_update_slice(U, jnp.where(first, 0.0, im), (0, s // 2))
+    lo = 8 if B % 8 == 0 else 1
+    X = U.reshape(B // lo, lo, n1, n2).transpose(0, 2, 1, 3)
+    R = jnp.einsum("uk,hklc->hulc", ppt._grade(M1, grade), ppt._grade(X, grade),
+                   precision=jax.lax.Precision.HIGHEST)
+    Rre, Rim = R[:, :n1], R[:, n1:]
+    sign = (1 - 2 * (jnp.arange(n1, dtype=jnp.int32) & 1)).astype(jnp.float32)
+    low = jnp.arange(n2, dtype=jnp.int32)[None, None, None, :] == 0
+    tc, ts = Tc[None, :, None, :], Ts[None, :, None, :]
+    ny = ((nyquist * jnp.float32(1.0 / s)).reshape(B // lo, 1, lo, 1)
+          * sign[None, :, None, None])
+    V = jnp.concatenate([Rre * tc - Rim * ts + jnp.where(low, ny, 0.0),
+                         Rre * ts + Rim * tc], axis=3)
+    return jnp.einsum("hulk,kt->htlu", ppt._grade(V, grade), ppt._grade(M2, grade),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
 # -- the cell's check, on sound and on broken maps ----------------------------
 
 
@@ -419,7 +651,7 @@ def test_span_attributes_and_the_counter():
     assert METRICS["sketch.tensorsketch_rows"] == "counter"
     rows, d, s, q = 24, 33, 256, 3
     T, X = _map(d, s, q), _examples(rows, d)
-    counted = ppt._ROWS.value(family="PPT", route="program")
+    counted = ppt._ROWS.value(family="PPT", route="program", radix="1")
     _, spans = _dispatch_of(lambda: T.apply(X, sk.ROWWISE))
     by_name = {sp.name: sp for sp in spans}
     dispatch, apply = by_name["sketch.dispatch"], by_name["sketch.apply"]
@@ -430,8 +662,10 @@ def test_span_attributes_and_the_counter():
         "path": "features", "family": "PPT", "q": q, "s": s, "rows": rows,
         "row_block": rows, "sketch": "spectral_operator", "fft": "mxu_two_stage",
         "route": "program", "grade": "float32", "product": "packed_k",
-        "k_tiles": 2, "features": rows * s, "elements": rows * s * (q + 1)}
-    assert ppt._ROWS.value(family="PPT", route="program") == counted + rows
+        "radix": 1, "class_cols": d, "k_tiles": 2, "features": rows * s,
+        "elements": rows * s * (q + 1)}
+    assert ppt._ROWS.value(family="PPT", route="program",
+                           radix="1") == counted + rows
 
 
 def test_block_rows():

@@ -663,6 +663,8 @@ def test_fastfood_block_kernels_other_shapes(one_chip):
 
 
 TS_ROWS, TS_N, TS_S, TS_Q = 60000, 784, 16384, 3
+# the cell's bucket classes: radix, class_cols, k_tiles (R·⌈6c/128⌉)
+TS_CLASSES = (4, 256, 48)
 
 
 def _passes(text, least, below):
@@ -700,45 +702,50 @@ def _passes(text, least, below):
 @pytest.mark.parametrize("rows", [TS_ROWS, TS_ROWS - 4])
 def test_cell_shape_tensorsketch_features(one_chip, rows):
     """``Polynomial(784, 3, 1, 1/784).create_rft(16384, ctx)`` rowwise of
-    60,000 × 784 as the one program: the three half-spectrum operators
-    generated and packed once — each (6 × 784, 16384) bfloat16, its three
-    bfloat16 parts one above the other as the six partial products of a
-    float32-grade product pair them, made outside the loop and carried
+    60,000 × 784 as the one program, its spectral products formed by bucket
+    class (PR 63): R = ``radix(784, 16384)`` classes of ``class_cols`` c
+    operator rows each; the three sketches' operators generated once — 2R
+    packed arrays a sketch, (6c, S/2R) bfloat16, a class's real and its
+    imaginary columns each by itself, made outside the loop and carried
     through it —, then a walk of 15 blocks of 4096 examples whose features
-    go into their rows of an uninitialised result in place. A block is EIGHT
-    passes over block-sized arrays and a ninth that stores it, every one a
-    fusion, no ``copy`` / ``reshape`` / ``transpose`` among them and no
-    ``concatenate`` pass:
+    go into their rows of an uninitialised result in place. A block:
 
-    1–3. the three spectral products x·(C_k F) (``kOutput``, MXU): ONE
-         bfloat16 product each, at default precision into float32, over the
-         packed K of 4704 = 6 × 784 columns — 37 MXU tiles deep where six
-         ``highest`` passes over K = 784 padded to 896 are 42 — of the
-         block's examples as a few small fusions slice them out of the
-         operand and pack their parts side by side, (4096, 4704) bfloat16,
-         once for all three (read through the walk's dynamic slice a product
-         is a tenth slower on the chip);
-    4.   the spectra's product, first half: reads the three spectra, stores
-         the real half of stage one's operand in place and hands on the pair
-         product F_0·F_1 (``kLoop``, three outputs);
-    5.   its second half: the imaginary half stored in place beside the real
-         one (``kLoop``, a ``dynamic-update-slice`` root);
-    6.   stage one, reading that operand as it lies (``kOutput``, float32
-         operands at ``highest``: K = 128, nothing padded);
-    7.   the twiddles and the Nyquist term (``kLoop``, two outputs);
-    8.   stage two (``kOutput``, ``highest``: K = 256);
-    9.   the store: ONE ``kLoop`` fusion that turns the two digits of t and
+    -    the examples' three bfloat16 parts brought into each sketch's class
+         order by an exact 0/1 product (``kOutput``, K = 784) and laid along K
+         as the six partial products pair them, (6c, 4096) bfloat16 a class,
+         examples next to the lanes: small passes;
+    -    the 2R·q class sums (``kOutput``, MXU): ONE bfloat16 product each at
+         default precision into float32, (4096, 6c)·(6c, S/2R), row-major —
+         ``k_tiles`` = R·⌈6c/128⌉ MXU tiles deep a sketch, each over S/R
+         columns where the whole product's 37 were over S;
+    1.   the spectra's product, first half: reads all the class sums — the R
+         groups an axis between the examples' two digits, (512, R, 8, S/2R),
+         made of a class sum by broadcast against the R × R factors —, and
+         stores the real half of stage one's operand (512, 2, R, 8, S/2R) in
+         place (``kLoop``);
+    2.   its second half: reads them again (no pair product is handed on:
+         0.8 GB read twice a block where the whole product's two fusions
+         moved 1.87 GB) and stores the imaginary half in place (``kLoop``);
+    3.   stage one, reading that operand as it lies — (h, κ1, l, κ2) tile for
+         tile — (``kOutput``, float32 operands at ``highest``: K = 128);
+    4.   the twiddles and the midpoints' term (``kLoop``, two outputs);
+    5.   stage two (``kOutput``, ``highest``: K = 256);
+    6.   the store: ONE ``kLoop`` fusion that turns the two digits of t and
          writes the block at its offset of the result, carried as
          (7500, 128, 8, 128) row-major — the bytes of (60000, 16384).
 
+    Six passes over block-sized arrays (the store among them) where PR 54's
+    whole products made nine, every one a fusion, no ``copy`` / ``reshape`` / ``transpose`` among
+    them and no ``concatenate`` pass; no class sum is copied on its way from
+    its product to the spectra's (the layout constraints of ``ppt.class_sums``
+    and ``ppt._block_features`` say row-major: left to itself the compiler
+    lays the spectra's product examples-minor, 2R·q + 1 copies a block).
     The whole result is touched by the uninitialised ``custom-call`` and by
-    that fusion alone, keeps its row-major layout through the loop and is
-    never copied; beside operand and result the program holds 2.27 GB (1.50
-    when the operators were 0.15 GB of float32: three packed operators are
-    0.46 GB, a block's packed examples 39 MB).
+    the store alone; beside operand and result the program holds under
+    1.5 GB (2.27 with the whole products: the packed operators are a third).
 
     Rows that are no whole (8, 128) tiles (59,996) keep the store of PR 51:
-    the same eight passes, a ninth that turns the digits (a fusion around a
+    the same passes, one more that turns the digits (a fusion around a
     ``copy``) and a bare ``dynamic-update-slice`` of the (rows, 16384)
     result."""
     from libskylark_tpu.base.context import Context
@@ -749,13 +756,17 @@ def test_cell_shape_tensorsketch_features(one_chip, rows):
         TS_S, Context(1))
     assert ppt.split(TS_S) == (128, 128)
     assert ppt.block_rows(rows, TS_S) == 4096
+    R = ppt.radix(TS_N, TS_S)
+    cols, half = ppt.class_cols(TS_N, R), TS_S // (2 * R)
+    assert (R, cols, ppt.k_tiles(TS_N, "float32", R)) == TS_CLASSES
+    assert T.radix() == R                   # this seed's classes fit
     spec = (T.sketch_type, TS_N, TS_S, tuple(sorted(T._extra_params().items())))
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     program = jax.jit(functools.partial(
-        ppt.tensorsketch_features, spec=spec, rowwise=True))
+        ppt.tensorsketch_features, spec=spec, rowwise=True, radix=R))
     compiled = program.lower(arg((2,), jnp.uint32),
                              arg((rows, TS_N), jnp.float32)).compile()
     text = compiled.as_text()
@@ -770,37 +781,42 @@ def test_cell_shape_tensorsketch_features(one_chip, rows):
     if tiled:
         (store,) = [i for i in whole if i[1] == "fusion"]
         assert store[2] == "kLoop" and "dynamic-update-slice" in store[0], store
-    # a block's passes: the loop's body; the packed operators: the entry
+    # a block's passes: the loop's body, every one a fusion
     sized = _passes(text, 4096 * TS_S, rows * TS_S)
     assert all(i[1] == "fusion" for i in sized), sized
     entry = re.search(r"^ENTRY %([\w.\-]+) \(", text, re.M).group(1)
     passes = [i for i in sized if i[5] != entry]
     assert len({i[5] for i in passes}) == 1, passes
-    products = [i for i in passes if i[2] == "kOutput"]
-    assert len(products) == TS_Q + 2, passes
-    # the three spectral products: one packed block of examples for all of
-    # them, made in the loop's body; a packed operator each, made outside it
-    assert ppt.k_tiles(TS_N) == 37
-    k = len(ppt._TERMS) * TS_N
-    spectral = [i for i in products if i[3] == [f"f32[4096,{TS_S}]{{1,0"]]
-    assert len(spectral) == TS_Q and len({i[4][0] for i in spectral}) == 1
-    assert len({i[4][1] for i in spectral}) == TS_Q, spectral
-    x_cat = [i for i in _passes(text, 4096 * k, 4096 * k + 1)
-             if i[0] == spectral[0][4][0]]
-    assert [i[3] for i in x_cat] == [[f"bf16[4096,{k}]{{0,1"]], x_cat
-    assert x_cat[0][5] == passes[0][5]
-    packing = [i for i in sized if i[5] == entry]
-    assert packing and all(f"bf16[{k},{TS_S}]{{1,0" in i[3] for i in packing), packing
-    loop = re.search(r"= \((.*?)\) while\(", text).group(1)
-    assert loop.count(f"bf16[{k},{TS_S}]") == TS_Q and f"f32[{TS_N},{TS_S}]" not in loop
-    assert text.count("convolution(") == TS_Q + 2
-    assert text.count("operand_precision={highest,highest}") == 2     # the stages
+    body = passes[0][5]
+    stages = [i for i in passes if i[2] == "kOutput"]
+    assert len(stages) == 2, passes
     loops = [i for i in passes if i[2] == "kLoop"]
-    assert sorted(len(i[3]) for i in loops) == [1] * (not tiled) + [1, 2, 3], passes
-    assert len(passes) == (8 if tiled else 9), passes
+    assert sorted(len(i[3]) for i in loops) == [1] * (not tiled) + [1, 1, 2], passes
+    assert len(passes) == (5 if tiled else 6), passes
+    # the spectra's product: two fusions that store stage one's operand in
+    # place, row-major, the groups between the examples' digits
+    operand = f"f32[512,2,{R},8,{half}]{{4,3,2,1,0"
+    stored = [i for i in loops if operand in i[3]]
+    assert len(stored) == 2 and all("dynamic-update-slice" in i[0] for i in stored)
+    # the class sums: 2R products a sketch, each its own row-major array
+    # that the spectra's product reads as the product wrote it
+    smaller = [i for i in _passes(text, 4096 * half, 4096 * half + 1)
+               if i[5] == body]
+    sums = [i for i in smaller if i[2] == "kOutput" and i[3][0].startswith("f32[")]
+    assert len(sums) == 2 * R * TS_Q, smaller
+    assert all(i[3][0] in (f"f32[4096,{half}]{{1,0", f"f32[512,8,{half}]{{2,1,0")
+               for i in sums), sums
+    assert not [i for i in smaller if i[1] in ("copy", "transpose", "concatenate")
+                and i[3][0].startswith("f32[")], smaller
+    # the packed operators: made outside the loop, 2R a sketch carried in
+    k = len(ppt._TERMS) * cols
+    loop = re.search(r"= \((.*?)\) while\(", text).group(1)
+    assert loop.count(f"bf16[{k},{half}]") >= 2 * R * TS_Q, loop
+    assert f"f32[{TS_N},{TS_S}]" not in loop
+    assert text.count("operand_precision={highest,highest}") >= 2     # the stages
     memory = compiled.memory_analysis()
     assert memory.output_size_in_bytes == -(-rows // 8) * 8 * TS_S * 4   # whole tiles
-    assert memory.temp_size_in_bytes < 2.4e9
+    assert memory.temp_size_in_bytes < 1.5e9
 
 
 # -- the dense sketch of a distributed matrix: four described chips ----------
