@@ -55,6 +55,16 @@ class SparseMatrix:
     external-ownership ``attach`` semantics, ref: base/sparse_matrix.hpp:82);
     device placement happens lazily, once per value dtype, at the first
     ``csr_device()`` or ``coo()``.
+
+    A matrix may also be **born on the device**
+    (:meth:`from_device_csr`: the result of ``HashTransform.apply_sparse``):
+    its row-major lanes are device arrays in the :meth:`csr_device` format,
+    its stored count a device scalar until someone asks for :attr:`nnz`, and
+    :meth:`csr_device`, :meth:`coo` and :meth:`todense` serve from those
+    lanes with no host round trip. Its host side — the CSC buffers behind
+    :attr:`indptr` / :attr:`indices` / :attr:`data`, :meth:`to_scipy`,
+    :meth:`csc_parts` and the tiled placements — materializes lazily, once,
+    at the first of those (:attr:`host_materialized` says whether it has).
     """
 
     def __init__(
@@ -75,6 +85,10 @@ class SparseMatrix:
             )
         if len(self._rowind) != len(self._values):
             raise errors.InvalidParametersError("rowind/values length mismatch")
+        self._init_state(born=None, nnz=len(self._values), row_cap=None)
+
+    def _init_state(self, born, nnz, row_cap) -> None:
+        """What every matrix keeps beside its host buffers."""
         # device-resident layouts by value dtype: {"csr": the placed
         # lanes, "csc": the column-major ones, "coo": the triplets derived
         # from the row-major lanes on first coo(), ("tiled", side, layout):
@@ -83,8 +97,36 @@ class SparseMatrix:
         self._device: dict = {}
         # the canonical scipy CSR this was attached from, when it was one
         self._row_major = None
+        # the lanes a device-born matrix was born as (from_device_csr), the
+        # stored count (None until such a matrix is asked for it), a bound
+        # on a row's lanes, what to tell when the count is read
+        self._born = born
+        self._nnz = nnz
+        self._row_cap = row_cap
+        self._count_hooks: list = []
 
     # -- constructors --
+
+    @classmethod
+    def from_device_csr(cls, data, indices, indptr, shape: Tuple[int, int],
+                        row_cap=None) -> "SparseMatrix":
+        """A matrix born on the device: canonical row-major lanes ``(data,
+        indices, indptr)`` in the :meth:`csr_device` format — a row's
+        columns ascending and distinct, ``indptr`` exact ((height + 1,)
+        int32, ``indptr[-1]`` the stored count), the lanes past it 0.0 at
+        column 0, the lane extent whatever the producer chose (for a hash
+        sketch's result its operand's, ``engine.bucket.result_lanes``).
+        Nothing is read back: the count stays on the device until
+        :attr:`nnz` is asked for, the host buffers until they are.
+        ``row_cap`` is a bound on the lanes of one row where the producer
+        knows one (:attr:`row_cap`)."""
+        out = cls.__new__(cls)
+        out._shape = (int(shape[0]), int(shape[1]))
+        out._colptr = out._rowind = out._values = None
+        out._init_state(born=(data, indices, indptr), nnz=None,
+                        row_cap=None if row_cap is None else int(row_cap))
+        out._device[jnp.dtype(data.dtype)] = {"csr": out._born}
+        return out
 
     @classmethod
     def from_scipy(cls, A) -> "SparseMatrix":
@@ -164,39 +206,110 @@ class SparseMatrix:
 
     @property
     def nnz(self) -> int:
-        return len(self._values)
+        """The stored count. A device-born matrix reads it off the device
+        at the first ask (one scalar; it waits for the program that makes
+        the lanes) and tells whoever asked to be told (:meth:`when_counted`)."""
+        if self._nnz is None:
+            self._nnz = int(self._born[2][-1])
+            hooks, self._count_hooks = self._count_hooks, []
+            for hook in hooks:
+                hook(self._nnz)
+        return self._nnz
+
+    @property
+    def nnz_known(self) -> bool:
+        """Whether :attr:`nnz` answers without reading the device."""
+        return self._nnz is not None
+
+    def when_counted(self, hook) -> None:
+        """``hook(nnz)`` now, where the count is known, else when it is
+        first read — never by a read of its own (the sparse → sparse
+        apply's span and counter learn the result's count this way)."""
+        if self._nnz is not None:
+            hook(self._nnz)
+        else:
+            self._count_hooks.append(hook)
+
+    @property
+    def host_materialized(self) -> bool:
+        """Whether the host CSC buffers exist (always, for a host-born
+        matrix)."""
+        return self._values is not None
+
+    @property
+    def lanes(self) -> int:
+        """The lane extent of :meth:`csr_device`: a device-born matrix's
+        own, else the ``lane_class`` of the stored count."""
+        if self._born is not None:
+            return int(self._born[0].shape[0])
+        from libskylark_tpu.engine.bucket import lane_class
+
+        return lane_class(self.nnz)
+
+    @property
+    def row_cap(self):
+        """A bound on the stored lanes of one row, or ``None`` where none
+        is known without reading the device: a host-born matrix's longest
+        row (counted once), a device-born one's as its producer gave it."""
+        if self._row_cap is None and self._values is not None:
+            if self._row_major is not None:
+                lengths = np.diff(self._row_major.indptr)
+            else:
+                lengths = np.bincount(self._rowind, minlength=self._shape[0])
+            self._row_cap = int(lengths.max()) if lengths.size else 0
+        return self._row_cap
 
     @property
     def density(self) -> float:
         """nnz / (height·width) — the serve layer's auto-densify signal
         (``SKYLARK_SPARSE_MIN_DENSITY``, docs/serving)."""
         cells = self._shape[0] * self._shape[1]
-        return (len(self._values) / cells) if cells else 0.0
+        return (self.nnz / cells) if cells else 0.0
 
     @property
     def dtype(self):
+        if self._values is None:
+            return np.dtype(self._born[0].dtype)
         return self._values.dtype
+
+    def _host(self) -> "SparseMatrix":
+        """The host CSC buffers of a device-born matrix, made once: the
+        lanes read back (the one device → host crossing of such a matrix),
+        attached as the canonical scipy CSR they are, converted to CSC."""
+        if self._values is None:
+            import scipy.sparse as sp
+
+            data, indices, indptr = (np.asarray(x) for x in self._born)
+            nnz = self.nnz
+            A = sp.csr_matrix((data[:nnz], indices[:nnz], indptr),
+                              shape=self._shape)
+            csc = A.tocsc()
+            self._colptr = np.asarray(csc.indptr, dtype=np.int64)
+            self._rowind = np.asarray(csc.indices, dtype=np.int32)
+            self._values = np.asarray(csc.data)
+            self._row_major = A
+        return self
 
     @property
     def device_dtype(self):
         """dtype of the device-side values (f64 host buffers land as f32 —
         the TPU-native precision policy; pass an explicit dtype to ``coo``
         to override)."""
-        return jnp.float32 if self._values.dtype == np.float64 else jnp.dtype(
-            self._values.dtype
+        return jnp.float32 if self.dtype == np.float64 else jnp.dtype(
+            self.dtype
         )
 
     @property
     def indptr(self) -> np.ndarray:
-        return self._colptr
+        return self._host()._colptr
 
     @property
     def indices(self) -> np.ndarray:
-        return self._rowind
+        return self._host()._rowind
 
     @property
     def data(self) -> np.ndarray:
-        return self._values
+        return self._host()._values
 
     # -- conversions --
 
@@ -226,6 +339,10 @@ class SparseMatrix:
         eff = self._device_dtype_of(dtype)
         layouts = self._device.setdefault(eff, {})
         if "csr" not in layouts:
+            if self._born is not None:      # another dtype: cast where it is
+                data, indices, indptr = self._born
+                layouts["csr"] = (data.astype(eff), indices, indptr)
+                return layouts["csr"]
             from libskylark_tpu.engine.bucket import lane_class
 
             layouts["csr"] = self._place_lanes(eff, lane_class(self.nnz))
@@ -336,6 +453,13 @@ class SparseMatrix:
         call is never returned for a default-dtype request."""
         eff = self._device_dtype_of(dtype)
         layouts = self._device.setdefault(eff, {})
+        if "coo" not in layouts and self._born is not None:
+            # a device-born matrix's triplets are its lanes, padding and all
+            # (value 0.0 at column 0 of the last row: exact zeros wherever
+            # triplets are summed) — cutting them to nnz would read the count
+            data, indices, indptr = self.csr_device(eff)
+            layouts["coo"] = (_row_ids(indptr, nnz=int(data.shape[0])),
+                              indices, data)
         if "coo" not in layouts:
             data, indices, indptr = (layouts.get("csr")
                                      or self._place_lanes(eff, self.nnz))
@@ -358,7 +482,7 @@ class SparseMatrix:
         f32 precision-policy default)."""
         eff = np.dtype(dtype) if dtype is not None else np.dtype(
             jax.dtypes.canonicalize_dtype(self.device_dtype))
-        A = self._row_major
+        A = self._host()._row_major
         if A is None:
             A = self.to_scipy().tocsr()
             A.sum_duplicates()
@@ -390,6 +514,7 @@ class SparseMatrix:
     def to_scipy(self):
         import scipy.sparse as sp
 
+        self._host()
         return sp.csc_matrix(
             (self._values, self._rowind, self._colptr), shape=self._shape
         )
@@ -407,6 +532,7 @@ class SparseMatrix:
     def column_view(self, j0: int, j1: int) -> "SparseMatrix":
         """Read-only view of columns [j0, j1) (ref: view:256) — shares the
         rowind/values buffers."""
+        self._host()
         lo, hi = self._colptr[j0], self._colptr[j1]
         return SparseMatrix(
             self._colptr[j0 : j1 + 1] - lo,
@@ -416,8 +542,9 @@ class SparseMatrix:
         )
 
     def __repr__(self) -> str:
+        nnz = self._nnz if self._nnz is not None else "on device"
         return (
-            f"SparseMatrix({self.height}x{self.width}, nnz={self.nnz}, "
+            f"SparseMatrix({self.height}x{self.width}, nnz={nnz}, "
             f"dtype={self.dtype})"
         )
 

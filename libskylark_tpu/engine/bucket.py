@@ -89,6 +89,17 @@ def lane_class(nnz: int) -> int:
     return -(-n // granule) * granule
 
 
+def result_lanes(lanes_in: int) -> int:
+    """The lane extent of a sparse → sparse hash sketch's **result**
+    (``HashTransform.apply_sparse``): its operand's. A hash sketch relabels
+    each stored nonzero 1:1 and sums the collisions, so ``nnz_out ≤ nnz_in``
+    and the operand's :func:`lane_class` always holds the result, whose own
+    count is data the program cannot size an array by; the blocks of one
+    corpus then share one executable on the way in and on the way out, and
+    a result fed to a second sketch presents the class it was born with."""
+    return int(lanes_in)
+
+
 def capacity_class(k: int, max_batch: int, multiple: int = 1) -> int:
     """Batch capacity for a cohort of ``k`` requests: pow2 ≥ k, clamped
     to ``max_batch``, then rounded up to ``multiple`` (the mesh device

@@ -33,6 +33,16 @@ each tile of result rows in VMEM from its own run of lanes
 ``hash_rows_apply``): the same exact products, the terms of one cell added
 in another order — last ulp against the scatter, a cell with one term to
 the bit.
+
+``apply_sparse`` keeps the result sparse (upstream's CSC → CSC engine, the
+hashing trick at its own width: a (rows × 2¹⁸) dense result cannot exist):
+one compiled program again (``sketch.hash_sparse_out``,
+:func:`libskylark_tpu.sketch.sparse_serve.cwt_sparse_out_serve_apply`) —
+the same lane streams, then the coalescing of
+:mod:`libskylark_tpu.sketch.sparse_coalesce` (sort each row's relabelled
+lanes by bucket, sum the collisions, compact, rebuild the row pointers) —
+whose result is a canonical ``SparseMatrix`` born on the device, its host
+side lazy and its stored count a device scalar until asked for.
 """
 
 from __future__ import annotations
@@ -52,6 +62,11 @@ _SPARSE_NNZ = _metrics.counter(
     "sketch.sparse_nnz",
     "Stored nonzeros sketched through the compiled sparse hash apply, "
     "by family and kernel")
+_SPARSE_MERGED = _metrics.counter(
+    "sketch.sparse_merged",
+    "Lanes a sparse -> sparse hash apply's collisions merged away (stored "
+    "nonzeros in less stored nonzeros out), counted when the result's count "
+    "is first read, by family and kernel")
 
 
 def value_stream(kind: tuple, key, n: int, dtype) -> jnp.ndarray:
@@ -83,6 +98,19 @@ def _sparse_program():
     return compiled(
         cwt_sparse_serve_apply, name="sketch.hash_sparse",
         static_argnames=("s_dim", "rowwise", "shape", "values", "kernel"))
+
+
+@functools.lru_cache(maxsize=None)
+def _sparse_out_program():
+    """The compiled sparse → sparse apply (``apply_sparse``), built like
+    :func:`_sparse_program` at the first call."""
+    from libskylark_tpu.engine.compiled import compiled
+    from libskylark_tpu.sketch.sparse_serve import cwt_sparse_out_serve_apply
+
+    return compiled(
+        cwt_sparse_out_serve_apply, name="sketch.hash_sparse_out",
+        static_argnames=("s_dim", "rowwise", "shape", "values", "form",
+                         "cap"))
 
 
 def cwt_serve_apply(key_data, A, *, s_dim: int, rowwise: bool) -> jnp.ndarray:
@@ -199,18 +227,41 @@ class HashTransform(SketchTransform):
         return dsa.hash_rowwise(self, A)
 
     def apply_sparse(self, A, dimension=None):
-        """Sparse→sparse apply: returns a :class:`SparseMatrix` with
-        duplicate-summed CSC structure (ref:
-        sketch/hash_transform_local_sparse.hpp — the sparse-output path).
-        Runs on host; the bucket/value streams are identical to the device
-        path, so results match ``apply`` elementwise. A
-        :class:`DistSparseMatrix` input returns a distributed sparse
-        result (the SpParMat→SpParMat analog, all device-side)."""
-        import numpy as np
+        """Sparse → sparse apply (ref: sketch/hash_transform_local_sparse
+        .hpp:12-152, CSC → CSC with duplicates summed): returns a
+        :class:`SparseMatrix` **born on the device**
+        (``SparseMatrix.from_device_csr``), its lanes made by ONE compiled
+        program (``engine.compiled``, ``sketch.hash_sparse_out``:
+        :func:`sparse_serve.cwt_sparse_out_serve_apply`) on the operand's
+        device-resident lanes.
 
+        Every stored nonzero (r, c, x) contributes v(c)·x to (r, h(c))
+        rowwise — v(r)·x to (h(r), c) columnwise — exactly once, h and v
+        the very streams :meth:`apply` uses, so ``apply_sparse(A)
+        .todense()`` equals ``apply(A)`` wherever the dense result can be
+        held. The result is canonical: each row's columns ascending and
+        distinct, collisions summed in float32, ``indptr`` exact, the lanes
+        past the stored count 0.0 at column 0, the lane extent the
+        operand's (``engine.bucket.result_lanes``: ``nnz_out ≤ nnz_in``, so
+        the blocks of one corpus share one executable). Nothing crosses to
+        the host inside the call — not the operand, not the streams, not
+        the result: the stored count stays a device scalar until someone
+        asks for ``.nnz``, the result's host CSC until someone asks for it
+        — and ``A`` is neither modified nor placed any differently.
+        ``sparse_serve.coalesce_kernel`` says which sort coalesces (the
+        span's ``kernel`` / ``why``). CWT, MMT and WZT, both dimensions,
+        take the same program.
+
+        Workspace: ``sparse_coalesce._WORKSPACE_WORDS`` (10) 4-byte words a
+        lane beside operand and result, whatever the shapes — 1.7 GB of
+        temporaries at 60.8 M lanes (524288 × 3231961 → 262144 buckets).
+
+        A :class:`DistSparseMatrix` input returns a distributed sparse
+        result (the SpParMat→SpParMat analog, all device-side; collisions
+        there stay separate COO entries)."""
         from libskylark_tpu.base.dist_sparse import DistSparseMatrix
-        from libskylark_tpu.base.sparse import SparseMatrix
-        from libskylark_tpu.sketch.transform import COLUMNWISE, Dimension
+        from libskylark_tpu.sketch.transform import (COLUMNWISE, Dimension,
+                                                     note_apply)
 
         if isinstance(A, DistSparseMatrix):
             from libskylark_tpu.sketch import dist_sparse_apply as dsa
@@ -219,7 +270,26 @@ class HashTransform(SketchTransform):
             return dsa.hash_apply_sparse(self, A, columnwise=cw)
 
         dimension = dimension or COLUMNWISE
-        if dimension == Dimension.COLUMNWISE:
+        # the root span of one apply, as SketchTransform.apply opens it: the
+        # benchmark's host-side readers key on it
+        with _trace.span("sketch.apply") as sp:
+            if sp is not None:
+                sp.attrs.update(
+                    family=self.sketch_type,
+                    dimension=getattr(dimension, "value", dimension),
+                    shape=tuple(A.shape), dtype=str(A.dtype), result="sparse")
+            note_apply(path="sparse")
+            return self._apply_sparse_out(
+                A, rowwise=dimension != Dimension.COLUMNWISE)
+
+    def _apply_sparse_out(self, A, *, rowwise: bool):
+        """:meth:`apply_sparse` of a local ``SparseMatrix``, inside its
+        ``sketch.apply`` span."""
+        from libskylark_tpu.base.sparse import SparseMatrix
+        from libskylark_tpu.engine.bucket import result_lanes
+        from libskylark_tpu.sketch.sparse_serve import coalesce_kernel, lookup
+
+        if not rowwise:
             if A.height != self._N:
                 raise errors.SketchError(
                     f"columnwise apply expects {self._N} rows, got {A.shape}"
@@ -228,18 +298,42 @@ class HashTransform(SketchTransform):
             raise errors.SketchError(
                 f"rowwise apply expects {self._N} cols, got {A.shape}"
             )
-        h = np.asarray(self.bucket_indices())
-        sp = A.to_scipy().tocoo()
-        v = np.asarray(self.values(A.device_dtype))
-        if dimension == Dimension.COLUMNWISE:
-            rows = h[sp.row]
-            vals = v[sp.row] * sp.data
-            return SparseMatrix.from_coo(
-                rows, sp.col, vals, (self._S, A.width)
-            )
-        cols = h[sp.col]
-        vals = v[sp.col] * sp.data
-        return SparseMatrix.from_coo(sp.row, cols, vals, (A.height, self._S))
+        data, indices, indptr = A.csr_device()
+        lanes = int(data.shape[0])
+        values = self._value_kind()
+        row_cap = A.row_cap if rowwise else None
+        kernel, form, cap, why = coalesce_kernel(A.shape, self._S, rowwise,
+                                                 row_cap)
+        family = self.sketch_type
+        with _trace.span("sketch.dispatch",
+                         {"path": "sparse", "family": family,
+                          "nnz_class": lanes, "lookup": lookup(values),
+                          "result": "sparse", "kernel": kernel, "why": why,
+                          "lanes_out": result_lanes(lanes)}) as sp:
+            d, i, p, merged = _sparse_out_program()(
+                self._alloc.key_data, data, indices, indptr, s_dim=self._S,
+                rowwise=rowwise, shape=A.shape, values=values, form=form,
+                cap=cap)
+        out = SparseMatrix.from_device_csr(
+            d, i, p, (A.height, self._S) if rowwise else (self._S, A.width),
+            row_cap=row_cap)
+
+        # the counts are told, never read: the operand's where it is known
+        # (a host-born operand's always is), the result's when someone asks
+        def counted_in(nnz):
+            if sp is not None:
+                sp.attrs["nnz"] = nnz
+            _SPARSE_NNZ.inc_always(nnz, family=family, kernel=kernel)
+            out.when_counted(lambda nnz_out: counted_out(nnz, nnz_out))
+
+        def counted_out(nnz, nnz_out):
+            if sp is not None:
+                sp.attrs["nnz_out"] = nnz_out
+            _SPARSE_MERGED.inc_always(nnz - nnz_out, family=family,
+                                      kernel=kernel)
+
+        A.when_counted(counted_in)
+        return out
 
 
 @register

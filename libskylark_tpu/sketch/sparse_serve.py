@@ -41,13 +41,13 @@ Exactness contract (the CI sparse-serve gate pins it):
   ``solve_l2_exact``, so the solve inherits the sketch's contract.
 
 The CWT path is where sparsity pays: O(nnz) scatter work instead of the
-dense path's O(N·m) segment-sum — the committed
-``benchmarks/results_sparse_cpu.json`` A/B quantifies it. On a TPU the
-direct rowwise apply (``HashTransform.apply`` on a ``SparseMatrix``)
-replaces this scatter by the kernel that builds each result tile in VMEM
-(:func:`libskylark_tpu.sketch.pallas_sparse.hash_rows_apply`) wherever
-:func:`sparse_kernel` finds its shapes; the serve flush's vmapped lanes,
-the columnwise apply and the CPU keep the scatter.
+dense path's O(N·m) segment-sum (``benchmarks/results_sparse_cpu.json``).
+On a TPU the direct rowwise apply (``HashTransform.apply``) replaces the
+scatter by the kernel that builds each result tile in VMEM
+(``pallas_sparse.hash_rows_apply``) wherever :func:`sparse_kernel` finds
+its shapes. Where the result stays sparse (``HashTransform.apply_sparse``),
+:func:`cwt_sparse_out_serve_apply` at the end of this file is the sibling:
+lanes → canonical lanes, :func:`coalesce_kernel` saying which sort serves.
 
 The CSR lane format (and :func:`scatter_dense`) is also the intake of
 the **graph serve endpoints** (docs/qos): ``submit_graph_ase`` /
@@ -393,3 +393,95 @@ def dense_sparse_apply_cw(key_data, scale, *lanes, dist, s_dim: int,
             lanes=LANES)
     return product_lanes(*lanes, St, kernel=kernel, shape=(n, m),
                          plan=plan).T
+
+
+# -- sparse → sparse: the hash sketch whose result stays sparse
+# (HashTransform.apply_sparse; ref: sketch/hash_transform_local_sparse.hpp:
+# 12-152). At the end of the file: the traced lines above keep their
+# numbers. --
+
+
+def coalesce_kernel(shape: tuple, s_dim: int, rowwise: bool,
+                    row_cap) -> tuple:
+    """Which program coalesces a sparse → sparse hash sketch of an operand
+    of ``shape`` whose longest row holds ``row_cap`` lanes (``None``:
+    unknown), ``(kernel, form, cap, why)``: ``"xla_window_sort"`` — rowwise,
+    the rows at most ``sparse_coalesce._WINDOW_CAP`` lanes and a window's row
+    rank and the bucket inside one 32-bit key: each row sorted inside a
+    window of 2·cap lanes, a batched minor-axis sort in VMEM — else
+    ``"xla_global_sort"``: one two-key sort of every lane by (row, column),
+    log²(lanes)/2 stages through HBM (a columnwise sketch regroups every
+    lane; a row bound nobody knows). The sibling of :func:`sparse_kernel`
+    for the sparse result, decided from what ``HashTransform.apply_sparse``
+    can observe; its ``sketch.dispatch`` span and the ``sketch.sparse_nnz``
+    counter carry the name, the span the reason too."""
+    from libskylark_tpu.sketch import sparse_coalesce
+
+    form, cap, why = sparse_coalesce.sort_form(s_dim, rowwise, row_cap)
+    return f"xla_{form}_sort", form, cap, why
+
+
+def cwt_sparse_out_serve_apply(key_data, data, indices, indptr, *,
+                               s_dim: int, rowwise: bool, shape: tuple,
+                               values: tuple = ("CWT",), form: str = "global",
+                               cap=None) -> tuple:
+    """One hash sketch of a CSR operand to a CSR result, lanes → lanes: the
+    sibling of :func:`cwt_sparse_serve_apply` whose result stays sparse, a
+    pure function of the allocation's key words and the operand's lanes
+    (``SparseMatrix.csr_device()``: ``shape`` the operand's, the lane
+    padding 0.0 at column 0) with every shape static — the program
+    ``sketch.hash_sparse_out``.
+
+    Every stored nonzero (r, c, x) contributes v(c)·x to (r, h(c)) rowwise,
+    v(r)·x to (h(r), c) columnwise, exactly once: bucket and sign computed
+    at the lane by ``randgen.stream_at`` as in the dense-result program (no
+    table, no gather; MMT's and WZT's value still gathered from its table,
+    :func:`lookup`), then :func:`sparse_coalesce.coalesce` under ``form`` /
+    ``cap`` (:func:`coalesce_kernel`). Returns ``(data, indices, indptr,
+    merged)``: canonical CSR lanes of the (rows × s_dim) / (s_dim × cols)
+    result in the operand's lane extent — a row's buckets ascending and
+    distinct, collisions summed in float32, ``indptr`` exact, lanes past
+    the stored count 0.0 at column 0 — and the lanes the collisions merged
+    away, a device scalar. Workspace: ``sparse_coalesce._WORKSPACE_WORDS``
+    4-byte words a lane."""
+    from libskylark_tpu.sketch import sparse_coalesce
+
+    n_rows, n_cols = int(shape[0]), int(shape[1])
+    count = indptr[-1]
+    # a lane's row is needed by the global sort and by the columnwise
+    # lookup; the windowed sort reads the row starts off ``indptr``
+    rows = (None if form == "window"
+            else csr_row_ids(indptr, data.shape[0]))
+    bucket, term = lane_terms(
+        key_data, data, indices if rowwise else rows, s_dim=s_dim,
+        values=values, n=n_cols if rowwise else n_rows)
+    if rowwise:
+        return sparse_coalesce.coalesce(
+            rows, bucket, term, count, n_major=n_rows, n_minor=s_dim,
+            form=form, cap=cap, starts=indptr if form == "window" else None)
+    return sparse_coalesce.coalesce(
+        bucket, indices, term, count, n_major=s_dim, n_minor=n_cols,
+        form=form)
+
+
+def lane_terms(key_data, data, by, *, s_dim: int, values: tuple = ("CWT",),
+               n: int | None = None) -> tuple:
+    """``(bucket, term)`` of each lane: h(by) in [0, s_dim) and v(by)·data,
+    ``by`` the coordinate a lane is hashed by (its column rowwise, its row
+    columnwise). Bucket — and for the CountSketch the sign — computed at
+    the lane by ``randgen.stream_at`` (−x is exactly (−1)·x); MMT's and
+    WZT's value gathered from its stream's table of ``n`` entries
+    (:func:`lookup`)."""
+    import jax.random as jr
+
+    from libskylark_tpu.sketch.hash import value_stream
+
+    key = jr.wrap_key_data(jnp.asarray(key_data))
+    bucket = randgen.stream_at(
+        jax.random.fold_in(key, 0), randgen.UniformInt(0, s_dim - 1), by,
+        dtype=jnp.int32)
+    if lookup(values) == "lane":
+        sign = randgen.stream_at(jax.random.fold_in(key, 1),
+                                 randgen.Rademacher(), by)
+        return bucket, jnp.where(sign < 0, -data, data)
+    return bucket, value_stream(values, key, n, data.dtype)[by] * data

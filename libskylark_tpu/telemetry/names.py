@@ -54,6 +54,16 @@ METRICS: Dict[str, str] = {
     # benchmark's cross-check of the nnz that sparse_nnz_rate.apply reads
     # from the sketch.dispatch spans
     "sketch.sparse_nnz": "counter",
+    # the sparse -> sparse hash apply (sketch/hash.py apply_sparse, the
+    # sketch.hash_sparse_out program, since PR 64): lanes its collisions
+    # merged away (nnz in - nnz out), by family and kernel
+    # (sparse_serve.coalesce_kernel: "xla_window_sort" | "xla_global_sort");
+    # counted when the result's stored count is first read (SparseMatrix.nnz
+    # of a device-born matrix), never by a sync inside the apply — the
+    # cross-check of the nnz_out that result_fill.apply reads from the
+    # sketch.dispatch spans; the apply's stored nonzeros count under
+    # sketch.sparse_nnz like the dense-result route's
+    "sketch.sparse_merged": "counter",
     # base.sparse.spmm called directly (the sparse power iterations of
     # nla/svd.py, Krylov solvers, kernels on sparse inputs): stored
     # nonzeros multiplied, by kernel (sparse_serve.product_kernel:
@@ -221,6 +231,17 @@ SPANS: Dict[str, Tuple[str, str]] = {
     # holds the axis or one chunk the samples; for the operator), elements
     # (= axis × columns mixed, which mix_rate.apply reads: the operand's, no
     # pad counted) and sampled (= s × columns kept)
+    # the sparse -> sparse hash apply (sketch/hash.py apply_sparse, the
+    # sketch.hash_sparse_out program, since PR 64) opens its own sketch.apply
+    # root (result="sparse") and a sketch.dispatch with path="sparse", family,
+    # nnz, nnz_class, lookup, result="sparse", kernel
+    # (sparse_serve.coalesce_kernel: "xla_window_sort" | "xla_global_sort",
+    # which sort coalesces) and why, lanes_out (the result's lane extent, the
+    # operand's) and nnz_out — filled when the result's count is first read,
+    # never by a sync inside the apply (result_fill.apply reads nnz_out ÷
+    # lanes_out; coalesce_share.apply reads the device ops under the
+    # program's "sparse_coalesce" named scope); its handover is the
+    # engine.execute inside it
     # the dense sketch of a sparse operand (sketch/dense.py
     # _apply_rowwise_sparse, the sketch.dense_sparse program, since PR 57)
     # carries path="sparse", family, s, kernel (sparse_serve.product_kernel:

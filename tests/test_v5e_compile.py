@@ -1199,3 +1199,48 @@ def test_cell_shape_transposed_product_replaced_the_whole_gather(one_chip):
     assert f"f32[{SPARSE_CW_LANES},{SPARSE_S}]" not in compiled.as_text()
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 3 << 30, memory
+
+
+# -- the sparse → sparse hash sketch (cwt_sparse_out_apply, PR 64) --
+
+OUT_ROWS, OUT_N, OUT_S = 524288, 3231961, 262144
+OUT_LANES = 60817408        # lane_class of 524288 × 115.6 stored nonzeros
+
+
+def test_cell_shape_sparse_out_program(one_chip):
+    """The whole ``sketch.hash_sparse_out`` program of the
+    cwt_sparse_out_apply cell under the windowed sort: two batched
+    minor-axis sorts of 2048-lane windows by one 32-bit key (no sort of the
+    whole lane extent, no two-key sort), no scatter or gather over the
+    lanes (the one scatter lays the 524289 row starts, the one gather reads
+    the new row pointers there), the coalescing stage named by its scope,
+    the result in the operand's lane extent, and under 2.5 GB of
+    temporaries: ten words a lane."""
+    from libskylark_tpu.engine.bucket import lane_class
+    from libskylark_tpu.sketch import sparse_coalesce
+
+    assert lane_class(round(OUT_ROWS * 115.6)) == OUT_LANES
+    kernel, form, cap, _ = sparse_serve.coalesce_kernel(
+        (OUT_ROWS, OUT_N), OUT_S, True, 1024)
+    assert (kernel, form, cap) == ("xla_window_sort", "window", 1024)
+    arg = _sparse_arg(one_chip)
+    program = jax.jit(functools.partial(
+        sparse_serve.cwt_sparse_out_serve_apply, s_dim=OUT_S, rowwise=True,
+        shape=(OUT_ROWS, OUT_N), values=("CWT",), form=form, cap=cap))
+    compiled = program.lower(
+        arg((2,), jnp.uint32), arg((OUT_LANES,), jnp.float32),
+        arg((OUT_LANES,), jnp.int32), arg((OUT_ROWS + 1,), jnp.int32)).compile()
+    text = compiled.as_text()
+    windows = OUT_LANES // 2048
+    sorts = re.findall(r" = \((\S+), (\S+)\) sort\(", text)
+    assert len(sorts) == 2, sorts
+    assert all(k.startswith(f"u32[{windows},2048]")
+               and v.startswith(f"f32[{windows},2048]") for k, v in sorts)
+    assert f"/{sparse_coalesce.SCOPE}/" in text
+    for lanes_wide in re.findall(r"\b(?:scatter|gather)\(([^)]*)\)", text):
+        assert f"[{OUT_LANES}]" not in lanes_wide.split(",")[1], lanes_wide
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes < 2 * OUT_LANES * 4 + OUT_ROWS * 4 + 16384
+    assert memory.temp_size_in_bytes < 2.5e9, memory
+    assert memory.temp_size_in_bytes \
+        < sparse_coalesce._WORKSPACE_WORDS * 4 * OUT_LANES, memory
